@@ -63,103 +63,13 @@ def _parse_drafts(drafts) -> dict:
         out[target] = draft
     return out
 
-async def cmd_run(args: argparse.Namespace) -> int:
-    pool = args.pool.split(",") if args.pool else None
-    rt = Runtime(RuntimeConfig(db_path=args.db, backend=args.backend,
-                               model_pool=pool,
-                               checkpoints=args.checkpoints, tp=args.tp,
-                               image_backend=args.image_backend,
-                               coordinator_address=args.coordinator,
-                               num_processes=args.num_processes,
-                               process_id=args.process_id,
-                               draft_map=_parse_drafts(args.drafts) or None,
-                               draft_k=args.draft_k,
-                               continuous=args.continuous,
-                               qos=args.qos or None,
-                               host_kv_mb=args.host_kv_mb,
-                               disk_kv_dir=args.disk_kv_dir,
-                               disk_kv_gb=args.disk_kv_gb,
-                               replicas=args.replicas,
-                               disaggregate=args.disaggregate,
-                               fabric_listen=args.fabric_listen,
-                               fabric_peers=(args.fabric_peers.split(",")
-                                             if args.fabric_peers else None),
-                               prefixd=args.prefixd,
-                               chaos_plan=args.chaos_plan,
-                               quantize_weights=args.quantize_weights,
-                               quantize_kv=args.quantize_kv,
-                               fleet_min=args.fleet_min,
-                               fleet_max=args.fleet_max,
-                               fleet_tick_s=args.fleet_tick_s,
-                               sim_trace=args.sim_trace,
-                               sim_seed=args.sim_seed,
-                               capture_dir=args.capture_dir,
-                               capture_mb=args.capture_mb))
-    _attach_printer(rt)
-    if pool is None and args.profile is None:
-        pool = rt.default_pool()
-    task_id, root = await rt.tasks.create_task(
-        args.description, model_pool=pool, profile=args.profile,
-        budget=args.budget, grove=args.grove)
-    rt.bus.subscribe(f"agents:{root.agent_id}:logs", _print_event)
-    rt.bus.subscribe(f"tasks:{task_id}:messages", _print_event)
-    print(f"task {task_id} started, root agent {root.agent_id}", flush=True)
-    try:
-        await asyncio.sleep(args.watch_seconds)
-    finally:
-        await rt.tasks.pause_task(task_id)
-        print(json.dumps(rt.status()), flush=True)
-        rt.close()
-    return 0
-
-
-async def cmd_resume(args: argparse.Namespace) -> int:
-    rt = Runtime(RuntimeConfig(db_path=args.db, backend=args.backend,
-                               checkpoints=args.checkpoints, tp=args.tp,
-                               image_backend=args.image_backend,
-                               coordinator_address=args.coordinator,
-                               num_processes=args.num_processes,
-                               process_id=args.process_id,
-                               draft_map=_parse_drafts(args.drafts) or None,
-                               draft_k=args.draft_k,
-                               continuous=args.continuous,
-                               qos=args.qos or None,
-                               host_kv_mb=args.host_kv_mb,
-                               disk_kv_dir=args.disk_kv_dir,
-                               disk_kv_gb=args.disk_kv_gb,
-                               replicas=args.replicas,
-                               disaggregate=args.disaggregate,
-                               fabric_listen=args.fabric_listen,
-                               fabric_peers=(args.fabric_peers.split(",")
-                                             if args.fabric_peers else None),
-                               prefixd=args.prefixd,
-                               chaos_plan=args.chaos_plan,
-                               quantize_weights=args.quantize_weights,
-                               quantize_kv=args.quantize_kv,
-                               fleet_min=args.fleet_min,
-                               fleet_max=args.fleet_max,
-                               fleet_tick_s=args.fleet_tick_s,
-                               sim_trace=args.sim_trace,
-                               sim_seed=args.sim_seed,
-                               capture_dir=args.capture_dir,
-                               capture_mb=args.capture_mb))
-    _attach_printer(rt)
-    result = await rt.boot()
-    print(json.dumps(result), flush=True)
-    try:
-        await asyncio.sleep(args.watch_seconds)
-    finally:
-        for task_id in result.get("revived", []):
-            await rt.tasks.pause_task(task_id)
-        rt.close()
-    return 0
-
-
-async def cmd_serve(args: argparse.Namespace) -> int:
-    from quoracle_tpu.web import DashboardServer
-    rt = Runtime(RuntimeConfig(
+def runtime_from_args(args: argparse.Namespace) -> Runtime:
+    """The Runtime a run/resume/serve invocation describes (one mapping
+    from the shared flags to RuntimeConfig)."""
+    pool = getattr(args, "pool", None)
+    return Runtime(RuntimeConfig(
         db_path=args.db, backend=args.backend,
-        model_pool=args.pool.split(",") if args.pool else None,
+        model_pool=pool.split(",") if pool else None,
         checkpoints=args.checkpoints, tp=args.tp,
         image_backend=args.image_backend,
         coordinator_address=args.coordinator,
@@ -182,6 +92,51 @@ async def cmd_serve(args: argparse.Namespace) -> int:
         fleet_tick_s=args.fleet_tick_s,
         sim_trace=args.sim_trace, sim_seed=args.sim_seed,
         capture_dir=args.capture_dir, capture_mb=args.capture_mb))
+
+
+async def cmd_run(args: argparse.Namespace) -> int:
+    pool = args.pool.split(",") if args.pool else None
+    rt = runtime_from_args(args)
+    _attach_printer(rt)
+    if pool is None and args.profile is None:
+        pool = rt.default_pool()
+    task_id, root = await rt.tasks.create_task(
+        args.description, model_pool=pool, profile=args.profile,
+        budget=args.budget, grove=args.grove)
+    rt.bus.subscribe(f"agents:{root.agent_id}:logs", _print_event)
+    rt.bus.subscribe(f"tasks:{task_id}:messages", _print_event)
+    print(f"task {task_id} started, root agent {root.agent_id}", flush=True)
+    try:
+        await asyncio.sleep(args.watch_seconds)
+    finally:
+        await rt.tasks.pause_task(task_id)
+        print(json.dumps(rt.status()), flush=True)
+        rt.close()
+    return 0
+
+
+async def cmd_resume(args: argparse.Namespace) -> int:
+    rt = runtime_from_args(args)
+    _attach_printer(rt)
+    result = await rt.boot()
+    print(json.dumps(result), flush=True)
+    try:
+        await asyncio.sleep(args.watch_seconds)
+    finally:
+        for task_id in result.get("revived", []):
+            await rt.tasks.pause_task(task_id)
+        rt.close()
+    return 0
+
+
+async def start_server(args: argparse.Namespace):
+    """Everything ``serve`` does before it idles: build the runtime, bind
+    the dashboard, revive persisted tasks. Returns (runtime, server), or
+    (None, None) after printing why the bind was refused. chip_smoke.py
+    starts the server through this, so that server and client can share
+    the one process that may hold the chip."""
+    from quoracle_tpu.web import DashboardServer
+    rt = runtime_from_args(args)
     # Validate host/token BEFORE boot so a refused bind exits with a clean
     # message instead of a traceback over a half-started runtime.
     try:
@@ -190,13 +145,20 @@ async def cmd_serve(args: argparse.Namespace) -> int:
     except ValueError as e:
         print(f"error: {e}", flush=True)
         rt.close()
-        return 2
+        return None, None
     _attach_printer(rt)
     result = await rt.boot()
     if result["revived"]:
         print(f"revived tasks: {result['revived']}", flush=True)
     server = await server.start()
     print(f"dashboard at {server.url}", flush=True)
+    return rt, server
+
+
+async def cmd_serve(args: argparse.Namespace) -> int:
+    rt, server = await start_server(args)
+    if rt is None:
+        return 2
     try:
         while True:
             await asyncio.sleep(3600)

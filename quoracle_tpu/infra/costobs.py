@@ -307,31 +307,34 @@ def reset() -> None:
 # ---------------------------------------------------------------------------
 
 # Device peak table by jax device_kind substring: (peak matmul FLOP/s at
-# the serving dtype, peak HBM bytes/s). Public spec-sheet numbers; the
-# CPU row is a deliberately conservative stand-in so MFU stays a
-# *relative* regression signal on the tier-1 host (absolute CPU MFU is
-# meaningless and nothing gates on it).
+# the serving dtype, peak HBM bytes/s), public spec-sheet numbers. A v5e
+# chip reports device_kind "TPU v5 lite": 197 TFLOP/s bf16 and 819 GB/s
+# (Google Cloud documentation, "TPU v5e"). A TPU that matches no row is
+# an error, never a default.
 _DEVICE_PEAKS: tuple = (
+    ("v5 lite", 197e12, 819e9),
     ("v6e", 918e12, 1640e9),
     ("v5p", 459e12, 2765e9),
-    ("v5e", 197e12, 819e9),
     ("v4", 275e12, 1228e9),
-    ("cpu", 1e11, 50e9),
 )
+# Deliberately conservative stand-in so MFU stays a *relative* regression
+# signal on the tier-1 host (absolute CPU MFU is meaningless and nothing
+# gates on it). Reachable only when the platform IS the CPU.
+_CPU_PEAKS = (1e11, 50e9)
 
 
 def device_peaks() -> tuple:
     """(peak FLOP/s, peak bytes/s) for the process's first device."""
-    kind = "cpu"
-    try:
-        import jax
-        kind = str(jax.devices()[0].device_kind).lower()
-    except Exception:                 # noqa: BLE001 — peaks must not throw
-        pass
+    import jax
+    dev = jax.devices()[0]
+    if dev.platform == "cpu":
+        return _CPU_PEAKS
+    kind = str(dev.device_kind).lower()
     for sub, fl, bw in _DEVICE_PEAKS:
         if sub in kind:
             return fl, bw
-    return _DEVICE_PEAKS[-1][1], _DEVICE_PEAKS[-1][2]
+    raise ValueError(f"no peak FLOP/s and bytes/s on file for device kind "
+                     f"{dev.device_kind!r} (infra/costobs._DEVICE_PEAKS)")
 
 
 @dataclasses.dataclass

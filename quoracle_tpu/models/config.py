@@ -185,6 +185,18 @@ MISTRAL_7B = register_model(ModelConfig(
     recommended_tp=2,
 ))
 
+# One v5e chip's cut of mistral-7b (chip_smoke.py; ROADMAP R1's control
+# cell builds on it). Source: the `mistral-7b` entry above. Keys changed:
+# n_layers 32 → 16. Every width is as published (dim 4096, 32/8 heads,
+# head_dim 128, ffn 14336, vocab 32000, window 4096) and all 16 layers are
+# the one layer kind the model has. A layer is 436 MB of bf16 weights and
+# the two embeddings 524 MB, so 16 layers are 7.5 GB beside the engine's
+# 2 GiB page pool (32,768 resident tokens at 64 KiB each) on a 16 GB chip;
+# full depth is 14.5 GB and needs tp >= 2 (`chip_smoke.py --chips 4`).
+# Weights are random, from a seed (transformer.init_params).
+MISTRAL_7B_L16 = register_model(dataclasses.replace(
+    MISTRAL_7B, name="mistral-7b-l16", n_layers=16, recommended_tp=1))
+
 GEMMA_7B = register_model(ModelConfig(
     name="gemma-7b",
     vocab_size=256000, dim=3072, n_layers=28, n_heads=16, n_kv_heads=16,

@@ -32,8 +32,11 @@ class EmbeddingEncoder:
 
     def __init__(self, cfg: ModelConfig, params: dict, tokenizer: Tokenizer,
                  max_tokens: int = 512, cache: Optional[TTLCache] = None,
-                 chunk_tokens: int = 256):
+                 chunk_tokens: int = 256, shard: Optional[tuple] = None):
+        """``shard``: the owning engine's ``attn_shard`` when ``params``
+        live on a mesh (ops/flash_attention.attend_auto)."""
         self.cfg = cfg
+        self.shard = shard
         self.params = params
         self.tokenizer = tokenizer
         self.max_tokens = max_tokens
@@ -56,7 +59,8 @@ class EmbeddingEncoder:
                 jnp.arange(T, dtype=jnp.int32)[None, :], (B, T))
             hidden, _ = forward_hidden(
                 params, cfg, tokens, positions, cache,
-                write_offset=jnp.zeros((B,), jnp.int32), kv_lens=lens)
+                write_offset=jnp.zeros((B,), jnp.int32), kv_lens=lens,
+                shard=self.shard)
             mask = (positions < lens[:, None]).astype(jnp.float32)[..., None]
             pooled = jnp.sum(hidden.astype(jnp.float32) * mask, axis=1) \
                 / jnp.maximum(jnp.sum(mask, axis=1), 1.0)

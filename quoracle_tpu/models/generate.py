@@ -44,6 +44,7 @@ def prefill_chunk(params: dict, cfg: ModelConfig, tokens: jax.Array,
                   kv_off: Optional[jax.Array] = None,
                   ring: Optional[tuple] = None,
                   input_embeds: Optional[jax.Array] = None,
+                  shard: Optional[tuple] = None,
                   ) -> tuple[jax.Array, KVCache]:
     """Fill the cache from a right-padded token CHUNK starting at per-row
     buffer index ``prefix_lens`` (0 = fresh prefill; >0 = resume on top
@@ -70,6 +71,7 @@ def prefill_chunk(params: dict, cfg: ModelConfig, tokens: jax.Array,
         kv_pos_offset=kv_off,
         ring=ring,
         input_embeds=input_embeds,
+        shard=shard,
     )
     last_h = jnp.take_along_axis(
         hidden, (chunk_lens - 1)[:, None, None].astype(jnp.int32), axis=1)
@@ -81,12 +83,13 @@ def prefill(params: dict, cfg: ModelConfig, tokens: jax.Array,
             prompt_lens: jax.Array, cache: KVCache,
             ring: Optional[tuple] = None,
             input_embeds: Optional[jax.Array] = None,
+            shard: Optional[tuple] = None,
             ) -> tuple[jax.Array, KVCache]:
     """Fresh prefill = prefill_chunk from position 0."""
     B = tokens.shape[0]
     return prefill_chunk(params, cfg, tokens,
                          jnp.zeros((B,), jnp.int32), prompt_lens, cache,
-                         ring=ring, input_embeds=input_embeds)
+                         ring=ring, input_embeds=input_embeds, shard=shard)
 
 
 def grammar_mask(logits: jax.Array, jstate: jax.Array,
@@ -383,7 +386,8 @@ def decode_ragged(
             lens + live,              # kv_len incl. the token just written
             lens - (1 - live),        # qpos0 (done rows: inert block)
             live,                     # nq
-        ], axis=1)
+            jnp.arange(R, dtype=jnp.int32),   # one tq=1 block per row
+        ])
         positions = lens + kv_off.astype(jnp.int32)
         if quant:
             hidden, kp, vp, ks, vs = forward_hidden_ragged(
@@ -435,6 +439,10 @@ def _round_up(n: int, buckets: Sequence[int]) -> int:
 RAGGED_TQ = 8
 RAGGED_TOKEN_BUCKETS = (16, 32, 64, 128, 256, 512, 1024, 2048, 4096,
                         8192, 16384, 32768)
+# Row slots (page tables, sampling state, the decode loop's batch) round
+# to these, independent of the token budget: the ContinuousBatcher's
+# default 8 slots are one f32 sublane tile and one program.
+RAGGED_ROW_BUCKETS = (8, 16, 32, 64)
 
 
 class ContextOverflowError(ValueError):
@@ -1057,11 +1065,9 @@ class GenerateEngine:
         self._grammar_lock = named_lock("cache.grammar")
         # Resident-size thresholds (max prompt tokens in the batch) for the
         # DIRECT (ragged-kernel) paged decode and paged PREFILL. These are
-        # MEASURED gates, not constants: where the kernels win depends on
-        # the deployment's launch cost (remote-dispatch relay ~2.7 ms vs
-        # local-dispatch ~µs — BASELINE.md "Long-context regime"), so
-        # tools/calibrate_paged.py measures the gather/direct crossover on
-        # the current host and the engine loads it
+        # MEASURED gates, not constants: tools/calibrate_paged.py
+        # measures the gather/direct crossover on the current device and
+        # the engine loads it
         # (utils/calibration.py; env QUORACLE_PAGED_CALIB). With no
         # calibration file both paths stay off — a documented absence of
         # data. Beyond latency the direct paths cap peak HBM (no
@@ -1144,10 +1150,21 @@ class GenerateEngine:
         regressed; SURVEY §5 tracing asks for the split)."""
         cfg = self.cfg
         mesh = self.mesh
+        # How the flash kernel is laid over this engine's mesh
+        # (ops/flash_attention.attend_auto): heads on tp when whole GQA
+        # groups divide, rows on dp; None on a single device.
+        attn_shard = None
         if mesh is not None:
             from jax.sharding import NamedSharding
             from quoracle_tpu.parallel.mesh import cache_spec
             kv_sharding = NamedSharding(mesh, cache_spec(cfg, mesh))
+            tp = int(mesh.shape.get("tp", 1))
+            attn_shard = (
+                mesh,
+                "tp" if tp > 1 and cfg.n_heads % tp == 0
+                and cfg.n_kv_heads % tp == 0 else None,
+                "dp" if int(mesh.shape.get("dp", 1)) > 1 else None)
+        self.attn_shard = attn_shard
 
         def _constrain(cache: KVCache) -> KVCache:
             if mesh is None:
@@ -1164,7 +1181,8 @@ class GenerateEngine:
             B = tokens.shape[0]
             cache = _constrain(init_cache(cfg, B, cache_len,
                                           dtype=self.cache_dtype))
-            return prefill(params, cfg, tokens, prompt_lens, cache)
+            return prefill(params, cfg, tokens, prompt_lens, cache,
+                           shard=attn_shard)
 
         if mesh is not None and int(mesh.shape.get("sp", 1)) > 1:
             ring_args = (mesh, "sp",
@@ -1215,7 +1233,7 @@ class GenerateEngine:
                 embeds = splice_image_embeds(embeds, tokens, img,
                                              cfg.image_token_id)
                 return prefill(params, cfg, tokens, prompt_lens, cache,
-                               input_embeds=embeds)
+                               input_embeds=embeds, shard=attn_shard)
 
             self._step_prefill_vlm = step_prefill_vlm
         else:
@@ -1284,13 +1302,8 @@ class GenerateEngine:
         # collective) — mesh engines keep the direct paths instead of
         # silently falling back to gather (VERDICT r4 item 3). Gated on
         # whole GQA groups per shard; _run_paged checks the same.
-        paged_shard = None
-        if (mesh is not None and int(mesh.shape.get("tp", 1)) > 1
-                and cfg.n_heads % int(mesh.shape["tp"]) == 0
-                and cfg.n_kv_heads % int(mesh.shape["tp"]) == 0):
-            paged_shard = (mesh, "tp",
-                           "dp" if int(mesh.shape.get("dp", 1)) > 1
-                           else None)
+        paged_shard = (attn_shard if attn_shard is not None
+                       and attn_shard[1] is not None else None)
         self._paged_shard = paged_shard
         # Unified ragged kernel sharding: token-major flat layout can't
         # ride a dp axis (rows interleave in one token axis), so the
@@ -1320,7 +1333,8 @@ class GenerateEngine:
             cache = _constrain(KVCache(k=kw, v=vw,
                                        lens=jnp.zeros((B,), jnp.int32)))
             return prefill_chunk(params, cfg, tokens, prefix_lens,
-                                 chunk_lens, cache, kv_off=kv_off)
+                                 chunk_lens, cache, kv_off=kv_off,
+                                 shard=attn_shard)
 
         if cfg.vision is not None:
             @functools.partial(jax.jit, static_argnames=())
@@ -1351,7 +1365,7 @@ class GenerateEngine:
                                              cfg.image_token_id)
                 return prefill_chunk(params, cfg, tokens, prefix_lens,
                                      chunk_lens, cache, kv_off=kv_off,
-                                     input_embeds=embeds)
+                                     input_embeds=embeds, shard=attn_shard)
             self._step_paged_prefill_vlm = step_paged_prefill_vlm
         else:
             self._step_paged_prefill_vlm = None
@@ -1419,7 +1433,7 @@ class GenerateEngine:
             hidden, cache = forward_hidden(
                 params, cfg, tokens, positions, cache,
                 write_offset=prefix_lens.astype(jnp.int32), kv_lens=total,
-                kv_pos_offset=kv_off)
+                kv_pos_offset=kv_off, shard=attn_shard)
             cache = cache._replace(lens=total)
             # verify window = each row's last k_arr chunk positions
             widx = jnp.clip(
@@ -1537,7 +1551,7 @@ class GenerateEngine:
             return (kf.reshape(k_pool.shape), vf.reshape(v_pool.shape))
 
         def _fwd_ragged(params, k_pool, v_pool, k_scale, v_scale,
-                        tokens_flat, positions_flat, block_tables,
+                        tokens_flat, positions_flat, row_tables,
                         block_meta, flat_dst, tq):
             """The one ragged forward call both unified steps share:
             int8 pools thread their scale pools through (quantize-on-
@@ -1545,12 +1559,12 @@ class GenerateEngine:
             if quant:
                 return forward_hidden_ragged(
                     params, cfg, tokens_flat[None], positions_flat[None],
-                    k_pool, v_pool, block_tables, block_meta, flat_dst,
+                    k_pool, v_pool, row_tables, block_meta, flat_dst,
                     tq=tq, shard=ragged_shard,
                     k_scale=k_scale, v_scale=v_scale)
             hidden, k_pool, v_pool = forward_hidden_ragged(
                 params, cfg, tokens_flat[None], positions_flat[None],
-                k_pool, v_pool, block_tables, block_meta, flat_dst,
+                k_pool, v_pool, row_tables, block_meta, flat_dst,
                 tq=tq, shard=ragged_shard)
             return hidden, k_pool, v_pool, k_scale, v_scale
 
@@ -1558,7 +1572,7 @@ class GenerateEngine:
                            static_argnames=("tq",))
         def step_paged_ragged(params, k_pool, v_pool, k_scale, v_scale,
                               tokens_flat,
-                              positions_flat, block_tables, block_meta,
+                              positions_flat, row_tables, block_meta,
                               flat_dst, last_idx, tq: int):
             # UNIFIED mixed chunk forward (ISSUE 8): one ragged launch
             # per layer over the token-major flattened tick — prefill
@@ -1568,7 +1582,7 @@ class GenerateEngine:
             # the batch-bucket × prompt-bucket program matrix collapses.
             hidden, k_pool, v_pool, k_scale, v_scale = _fwd_ragged(
                 params, k_pool, v_pool, k_scale, v_scale, tokens_flat,
-                positions_flat, block_tables, block_meta, flat_dst, tq)
+                positions_flat, row_tables, block_meta, flat_dst, tq)
             last_h = hidden[0][last_idx]                  # [R, D]
             last = project_logits(params, cfg, last_h[:, None])[:, 0, :]
             return last, k_pool, v_pool, k_scale, v_scale
@@ -1577,7 +1591,7 @@ class GenerateEngine:
                            static_argnames=("tq", "kmax", "need_probs"))
         def step_paged_ragged_verify(params, k_pool, v_pool, k_scale,
                                      v_scale, tokens_flat,
-                                     positions_flat, block_tables,
+                                     positions_flat, row_tables,
                                      block_meta, flat_dst, widx,
                                      temperature, json_table, json_state,
                                      tq: int, kmax: int, need_probs: bool):
@@ -1588,7 +1602,7 @@ class GenerateEngine:
             # project at the flat indices of each row's last K positions.
             hidden, k_pool, v_pool, k_scale, v_scale = _fwd_ragged(
                 params, k_pool, v_pool, k_scale, v_scale, tokens_flat,
-                positions_flat, block_tables, block_meta, flat_dst, tq)
+                positions_flat, row_tables, block_meta, flat_dst, tq)
             wh = hidden[0][widx]                          # [R, kmax, D]
             logits = project_logits(params, cfg, wh).astype(jnp.float32)
             R = widx.shape[0]
@@ -2457,19 +2471,20 @@ class GenerateEngine:
             return
         shape = (self.cfg.n_layers, st.n_pages, st.page,
                  self.cfg.n_kv_heads, self.cfg.head_dim)
-        k = jnp.zeros(shape, self.pool_dtype)
-        v = jnp.zeros(shape, self.pool_dtype)
+        sh = None
+        if self.mesh is not None:
+            # created in its sharding: no chip ever holds the whole pool
+            from jax.sharding import NamedSharding, PartitionSpec as P
+            tp = int(self.mesh.shape.get("tp", 1))
+            kv_axis = "tp" if self.cfg.n_kv_heads % tp == 0 else None
+            sh = NamedSharding(self.mesh, P(None, None, None, kv_axis, None))
+        k = jnp.zeros(shape, self.pool_dtype, device=sh)
+        v = jnp.zeros(shape, self.pool_dtype, device=sh)
         if self.quantize_kv:
             sshape = (self.cfg.n_layers, st.n_pages,
                       self.cfg.n_kv_heads, st.page)
             st.k_scale = jnp.ones(sshape, jnp.float32)
             st.v_scale = jnp.ones(sshape, jnp.float32)
-        if self.mesh is not None:
-            from jax.sharding import NamedSharding, PartitionSpec as P
-            tp = int(self.mesh.shape.get("tp", 1))
-            kv_axis = "tp" if self.cfg.n_kv_heads % tp == 0 else None
-            sh = NamedSharding(self.mesh, P(None, None, None, kv_axis, None))
-            k, v = jax.device_put(k, sh), jax.device_put(v, sh)
         st.k, st.v = k, v
 
     def _run_paged(self, prompts, suffixes, sess_rows, reuse_abs,
@@ -2862,10 +2877,10 @@ class GenerateEngine:
         either project verify-window verdicts or continue into the
         ragged decode loop. Device work and compile keys scale with the
         tick's real tokens (the flat budget), never with batch × max:
-        program identity is ("ragged", token budget, table width,
-        decode bound), which CompileRegistry ledgers for the collapse
-        assertion. Returns (out, n_emitted, final_lens, jstate_f, vout,
-        t_prefill, now) with all row-indexed arrays sized [NB] whose
+        program identity is ("ragged", token budget, row slots, table
+        width, decode bound), which CompileRegistry ledgers for the
+        collapse assertion. Returns (out, n_emitted, final_lens, jstate_f,
+        vout, t_prefill, now) with all row-indexed arrays sized [R] whose
         first ``n`` slots are the batch rows in order."""
         st = self.sessions
         page = st.page
@@ -2881,35 +2896,35 @@ class GenerateEngine:
         TB = _round_up(raw, RAGGED_TOKEN_BUCKETS)
         if TB == raw and raw > RAGGED_TOKEN_BUCKETS[-1]:
             TB = -(-raw // 4096) * 4096     # beyond the ladder: 4k steps
-        NB = TB // TQ                       # blocks; also the row slots
+        NB = TB // TQ                       # blocks
+        R = _round_up(n, RAGGED_ROW_BUCKETS)   # row slots
         maxp_p2 = 1 << max(0, maxp - 1).bit_length()   # pow2 table width
         pad_id = self.tokenizer.pad_id
         flat_tok = np.full((TB,), pad_id, np.int32)
         flat_pos = np.zeros((TB,), np.int32)
         flat_dst = np.full((TB,), n_tok, np.int32)     # OOB = drop
-        btab = np.zeros((NB, maxp_p2), np.int32)
-        bmeta = np.zeros((NB, 3), np.int32)            # kv_len, qpos0, nq
-        last_idx = np.zeros((NB,), np.int32)
-        r_tables = np.zeros((NB, maxp_p2), np.int32)
-        r_pool_lens = np.zeros((NB,), np.int32)
-        r_off = np.zeros((NB,), np.int32)
+        bmeta = np.zeros((4, NB), np.int32)     # kv_len, qpos0, nq, row
+        last_idx = np.zeros((R,), np.int32)
+        r_tables = np.zeros((R, maxp_p2), np.int32)
+        r_pool_lens = np.zeros((R,), np.int32)
+        r_off = np.zeros((R,), np.int32)
         temp_arr, top_arr, active, limits_np = samp_np
-        r_temp = np.zeros((NB,), np.float32)
-        r_top = np.ones((NB,), np.float32)
-        r_active = np.zeros((NB,), bool)
-        r_limits = np.ones((NB,), np.int32)
+        r_temp = np.zeros((R,), np.float32)
+        r_top = np.ones((R,), np.float32)
+        r_active = np.zeros((R,), bool)
+        r_limits = np.ones((R,), np.int32)
         r_temp[:n] = temp_arr[:n]
         r_top[:n] = top_arr[:n]
         r_active[:n] = active[:n]
         r_limits[:n] = limits_np[:n]
         js_dev = None
         if json_table is not None:
-            r_jstate = np.full((NB,), -1, np.int32)
+            r_jstate = np.full((R,), -1, np.int32)
             r_jstate[:n] = jstate_np[:n]
             js_dev = jnp.asarray(r_jstate)
         if verify is not None:
             k_arr, kmax, need_probs = verify
-            widx = np.zeros((NB, kmax), np.int32)
+            widx = np.zeros((R, kmax), np.int32)
         cur = 0
         for i in range(n):
             s, nb = segs[i], nb_rows[i]
@@ -2920,12 +2935,11 @@ class GenerateEngine:
             flat_pos[cur:cur + s] = int(off_arr[i]) + pos
             flat_dst[cur:cur + s] = dst[i, pos // page] * page + pos % page
             kv_len = pre + s
-            for b in range(nb):
-                blk = cur // TQ + b
-                btab[blk, :maxp] = dst[i]
-                bmeta[blk, 0] = kv_len
-                bmeta[blk, 1] = pre + b * TQ
-                bmeta[blk, 2] = min(TQ, s - b * TQ)
+            blk = cur // TQ + np.arange(nb)
+            bmeta[0, blk] = kv_len
+            bmeta[1, blk] = pre + np.arange(nb) * TQ
+            bmeta[2, blk] = np.minimum(TQ, s - np.arange(nb) * TQ)
+            bmeta[3, blk] = i
             last_idx[i] = cur + s - 1
             r_tables[i, :maxp] = dst[i]
             r_pool_lens[i] = kv_len
@@ -2938,12 +2952,13 @@ class GenerateEngine:
         self._pending.padded_tokens = TB
 
         if verify is not None:
-            self._pending.shape_key = ("ragged_verify", TB, maxp_p2, kmax)
+            self._pending.shape_key = ("ragged_verify", TB, R, maxp_p2,
+                                       kmax)
             (vids, vprobs, st.k, st.v, st.k_scale,
              st.v_scale) = self._step_paged_ragged_verify(
                 self.params, st.k, st.v, st.k_scale, st.v_scale,
                 jnp.asarray(flat_tok),
-                jnp.asarray(flat_pos), jnp.asarray(btab),
+                jnp.asarray(flat_pos), jnp.asarray(r_tables),
                 jnp.asarray(bmeta), jnp.asarray(flat_dst),
                 jnp.asarray(widx), jnp.asarray(r_temp), json_table,
                 js_dev, tq=TQ, kmax=kmax, need_probs=need_probs)
@@ -2953,18 +2968,18 @@ class GenerateEngine:
                     np.asarray(vprobs) if need_probs else None)
             jax.block_until_ready(st.k)
             now = time.monotonic()
-            out = np.zeros((NB, 0), np.int32)
-            n_emitted = np.zeros((NB,), np.int32)
-            jstate_f = np.full((NB,), -1, np.int32)
+            out = np.zeros((R, 0), np.int32)
+            n_emitted = np.zeros((R,), np.int32)
+            jstate_f = np.full((R,), -1, np.int32)
             return (out, n_emitted, r_pool_lens, jstate_f, vout,
                     t_prefill, now)
 
-        self._pending.shape_key = ("ragged", TB, maxp_p2, max_new)
+        self._pending.shape_key = ("ragged", TB, R, maxp_p2, max_new)
         last_logits, st.k, st.v, st.k_scale, st.v_scale = \
             self._step_paged_ragged(
                 self.params, st.k, st.v, st.k_scale, st.v_scale,
                 jnp.asarray(flat_tok),
-                jnp.asarray(flat_pos), jnp.asarray(btab),
+                jnp.asarray(flat_pos), jnp.asarray(r_tables),
                 jnp.asarray(bmeta),
                 jnp.asarray(flat_dst), jnp.asarray(last_idx), tq=TQ)
         jax.block_until_ready(last_logits)  # phase fence: prefill done

@@ -415,7 +415,7 @@ class TPUBackend(ModelBackend):
     def __init__(self, pool: Sequence[str], *, seed: int = 0,
                  embed_model: Optional[str] = None,
                  engines: Optional[dict[str, GenerateEngine]] = None,
-                 embedder=None, init_params_fn=None,
+                 embedder=None,
                  submeshes: Optional[Sequence] = None,
                  overlap: bool = True,
                  continuous: bool = False, continuous_chunk: int = 32,
@@ -456,7 +456,6 @@ class TPUBackend(ModelBackend):
         self.engines: dict[str, GenerateEngine] = dict(engines or {})
         self.overlap = overlap
         self._bus = None          # attach_bus: serving-telemetry broadcasts
-        init_fn = init_params_fn or init_params
         # Int8 quantized serving (ISSUE 13, models/quant.py): applied
         # uniformly to every engine this backend builds — pool members
         # AND their draft engines — so a member's whole decode stack
@@ -479,7 +478,10 @@ class TPUBackend(ModelBackend):
                 if mesh is None:
                     params = to_device(params)
             else:
-                params = init_fn(cfg, jax.random.PRNGKey(seed + i))
+                # with a mesh, every weight is created in its shard_params
+                # placement — a whole-model init would not fit chip 0
+                params = init_params(cfg, jax.random.PRNGKey(seed + i),
+                                     mesh=mesh)
             return GenerateEngine(cfg, params, get_tokenizer(spec),
                                   seed=seed + i, mesh=mesh,
                                   quantize_weights=self.quantize_weights,
@@ -598,14 +600,20 @@ class TPUBackend(ModelBackend):
             self.embedder = embedder
         else:
             espec = embed_model or self.pool[0]
+            eshard = None
             if espec in self.engines:
                 e = self.engines[espec]
                 eparams, ecfg, etok = e.params, e.cfg, e.tokenizer
+                if e.attn_shard is not None:
+                    # embedding batches are not padded to dp: rows stay
+                    # replicated, heads still split over tp
+                    eshard = (*e.attn_shard[:2], None)
             else:
                 ecfg = get_model_config(espec)
-                eparams = init_fn(ecfg, jax.random.PRNGKey(seed + 101))
+                eparams = init_params(ecfg, jax.random.PRNGKey(seed + 101))
                 etok = get_tokenizer(espec)
-            self.embedder = EmbeddingEncoder(ecfg, eparams, etok)
+            self.embedder = EmbeddingEncoder(ecfg, eparams, etok,
+                                             shard=eshard)
 
     def close(self) -> None:
         """Stop the continuous batcher threads (no-op otherwise). Queued

@@ -16,6 +16,7 @@ math locally, SURVEY.md §2.8):
 
 from __future__ import annotations
 
+import functools
 from typing import NamedTuple, Optional
 
 import jax
@@ -50,30 +51,59 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int,
     )
 
 
-def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16) -> dict:
-    """Random-init params pytree (normal/sqrt(dim)) — tests and bench only.
-    Real checkpoints load through models/loader.py (same structure, weights
-    from safetensors)."""
+@functools.partial(jax.jit, static_argnames=("shape", "fan_in", "dtype",
+                                             "sharding"))
+def _normal_leaf(key, shape, fan_in, dtype, sharding):
+    """One normal/sqrt(fan_in) weight as ONE program: the float32 draw
+    fuses into the cast, so only the ``dtype`` result is ever allocated,
+    and under ``sharding`` each device computes just its own shard."""
+    x = (jax.random.normal(key, shape, jnp.float32)
+         * (fan_in ** -0.5)).astype(dtype)
+    if sharding is not None:
+        x = jax.lax.with_sharding_constraint(x, sharding)
+    return x
+
+
+def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16,
+                mesh=None) -> dict:
+    """Random-init params pytree (normal/sqrt(dim)) — tests, bench and the
+    chip smoke. Real checkpoints load through models/loader.py (same
+    structure, weights from safetensors).
+
+    With ``mesh`` every weight is created in its parallel/mesh.param_specs
+    sharding, so no device ever holds a whole full-width tensor (w_gate of
+    mistral-7b is 3.8 GB in bf16, 7.5 GB as the float32 draw). Values do
+    not depend on the mesh (partitionable threefry)."""
     k_embed, k_layers, k_head = jax.random.split(key, 3)
     L, D, F = cfg.n_layers, cfg.dim, cfg.ffn_dim
     H, KV, HD = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+    if mesh is not None:
+        from jax.sharding import NamedSharding
+        from quoracle_tpu.parallel.mesh import param_specs
+        specs = param_specs(cfg)
 
-    def normal(key, shape, fan_in):
-        return (jax.random.normal(key, shape, jnp.float32) * (fan_in ** -0.5)).astype(dtype)
+    def normal(key, shape, fan_in, *path):
+        sharding = None
+        if mesh is not None:
+            spec = specs
+            for name in path:
+                spec = spec[name]
+            sharding = NamedSharding(mesh, spec)
+        return _normal_leaf(key, shape, fan_in, dtype, sharding)
 
     lk = jax.random.split(k_layers, 7)
     params = {
-        "embed": normal(k_embed, (cfg.vocab_size, D), D),
+        "embed": normal(k_embed, (cfg.vocab_size, D), D, "embed"),
         "layers": {
             "attn_norm": jnp.ones((L, D), dtype),
-            "wq": normal(lk[0], (L, D, H * HD), D),
-            "wk": normal(lk[1], (L, D, KV * HD), D),
-            "wv": normal(lk[2], (L, D, KV * HD), D),
-            "wo": normal(lk[3], (L, H * HD, D), H * HD),
+            "wq": normal(lk[0], (L, D, H * HD), D, "layers", "wq"),
+            "wk": normal(lk[1], (L, D, KV * HD), D, "layers", "wk"),
+            "wv": normal(lk[2], (L, D, KV * HD), D, "layers", "wv"),
+            "wo": normal(lk[3], (L, H * HD, D), H * HD, "layers", "wo"),
             "mlp_norm": jnp.ones((L, D), dtype),
-            "w_gate": normal(lk[4], (L, D, F), D),
-            "w_up": normal(lk[5], (L, D, F), D),
-            "w_down": normal(lk[6], (L, F, D), F),
+            "w_gate": normal(lk[4], (L, D, F), D, "layers", "w_gate"),
+            "w_up": normal(lk[5], (L, D, F), D, "layers", "w_up"),
+            "w_down": normal(lk[6], (L, F, D), F, "layers", "w_down"),
         },
         "final_norm": jnp.ones((D,), dtype),
     }
@@ -87,7 +117,8 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16) -> dict:
         params["layers"]["mlp_norm"] = jnp.zeros((L, D), dtype)
         params["final_norm"] = jnp.zeros((D,), dtype)
     if not cfg.tie_embeddings:
-        params["lm_head"] = normal(k_head, (D, cfg.vocab_size), D)
+        params["lm_head"] = normal(k_head, (D, cfg.vocab_size), D,
+                                   "lm_head")
     if cfg.vision is not None:
         from quoracle_tpu.models.vision import init_vision_params
         assert cfg.vision.out_dim == cfg.dim, \
@@ -223,6 +254,9 @@ def forward_hidden(
                                     # scale_embeddings is NOT re-applied
                                     # (image features splice in unscaled,
                                     # matching standard VLM semantics)
+    shard: Optional[tuple] = None,  # (mesh, tp_axis|None, dp_axis|None) of
+                                    # a mesh engine: the flash kernel runs
+                                    # per shard (ops/flash_attention.py)
 ) -> tuple[jax.Array, KVCache]:
     """Run the stack over a token chunk, updating the cache; returns final
     hidden states [B, T, D] (pre-head) — see project_logits.
@@ -276,7 +310,7 @@ def forward_hidden(
             attn = attend_auto(q, k_buf, v_buf, positions,
                                kv_len=kv_lens,
                                sliding_window=cfg.sliding_window,
-                               kv_pos_offset=kv_pos_offset)
+                               kv_pos_offset=kv_pos_offset, shard=shard)
         x = x + jnp.einsum("bthd,hdD->btD", attn, _wo(p, cfg, x.dtype))
         x = _mlp(x, p, cfg)
         return x, (k_buf, v_buf)
@@ -403,8 +437,8 @@ def forward_hidden_ragged(
     positions: jax.Array,    # [1, Tp] int32 absolute positions per token
     k_pool: jax.Array,       # [L, n_pages, page, n_kv, hd] (donated by jit)
     v_pool: jax.Array,
-    block_tables: jax.Array,  # [NB, maxp] int32 — owning row's page table
-    block_meta: jax.Array,    # [NB, 3] int32: kv_len, qpos0, nq
+    row_tables: jax.Array,   # [R, maxp] int32 — one page table per row
+    block_meta: jax.Array,   # [4, NB] int32: kv_len, qpos0, nq, row
     flat_dst: jax.Array,     # [Tp] int32 flat pool token slot per flattened
                              # token (OOB sentinel = drop), from the owning
                              # row's DST page table
@@ -476,7 +510,7 @@ def forward_hidden_ragged(
         kp2 = kf.reshape(kp.shape)
         vp2 = vf.reshape(vp.shape)
         attn = ragged_attend_auto(
-            q[0], kp2, vp2, block_tables, block_meta, tq=tq,
+            q[0], kp2, vp2, row_tables, block_meta, tq=tq,
             sliding_window=cfg.sliding_window, interpret=interpret,
             shard=shard, k_scale=ks, v_scale=vs)[None]   # [1, Tp, H, hd]
         x = x + jnp.einsum("bthd,hdD->btD", attn.astype(x.dtype),

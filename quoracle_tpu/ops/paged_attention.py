@@ -310,8 +310,8 @@ def paged_attend(
             grid=(B,),
             in_specs=[
                 pl.BlockSpec((1, H, hd_p), lambda b, *_: (b, 0, 0)),
-                pl.BlockSpec(memory_space=pltpu.ANY),     # k pool in HBM
-                pl.BlockSpec(memory_space=pltpu.ANY),     # v pool in HBM
+                pl.BlockSpec(memory_space=pl.ANY),     # k pool in HBM
+                pl.BlockSpec(memory_space=pl.ANY),     # v pool in HBM
             ],
             out_specs=[
                 pl.BlockSpec((1, H, hd_p), lambda b, *_: (b, 0, 0)),
@@ -435,9 +435,10 @@ def _paged_prefill_kernel(tables_ref, meta_ref, q_ref, k_hbm, v_hbm,
         start_dma(0, 0)
 
     # Window validity shared by every kv head: q_abs - s_abs = kv_len + t - s
-    # (the row's absolute offset cancels on both sides).
+    # (the row's absolute offset cancels on both sides). Built at its
+    # final shape: Mosaic has no [Tb, G] → [Tb·G, 1] cast.
     t_of_row = tb * t_blk + jax.lax.broadcasted_iota(
-        jnp.int32, (Tb, G), 0).reshape(Tb * G, 1)
+        jnp.int32, (Tb * G, 1), 0) // G
 
     def body(j, carry):
         # carry: per-kv-head tuples of (m [Tb·G,1], l [Tb·G,1], acc [Tb·G,hd])
@@ -487,6 +488,19 @@ def _paged_prefill_kernel(tables_ref, meta_ref, q_ref, k_hbm, v_hbm,
         stats_ref[0, :, 1, kv * G:(kv + 1) * G] = l.reshape(Tb, G)
 
 
+def _prefill_t_blk(row_elems: int) -> int:
+    """Queries per block of the paged-prefill kernel for a query row of
+    ``row_elems`` = H·hd elements. The kernel holds about 36 bytes per
+    (query, element) — double-buffered q and outputs plus the f32
+    accumulators — so 64 × 4096 (Mistral-7B, Gemma-7B) takes ~9.5 MiB of
+    the v5e's 16 MiB scoped VMEM and 128 × 4096 is refused at 18.8 MiB
+    (AOT compile, tests/test_kernels_compile_tpu.py)."""
+    t = 128
+    while t > 8 and t * row_elems > (1 << 18):
+        t //= 2
+    return t
+
+
 @functools.partial(jax.jit, static_argnames=("sliding_window", "interpret",
                                              "t_blk"))
 def paged_prefill_attend(
@@ -497,15 +511,19 @@ def paged_prefill_attend(
     kv_lens: jax.Array,    # [B] int32 resident prefix tokens
     sliding_window: Optional[int] = None,
     interpret: bool = False,
-    t_blk: int = 128,
+    t_blk: Optional[int] = None,
 ) -> tuple:
     """Pallas partials of a whole prefill chunk against the paged pool
     (same contract as paged_prefill_attend_ref; tests assert agreement).
     Grid is (B, T/t_blk): each launch streams the row's prefix pages once
-    for t_blk queries — launch cost amortizes over the chunk."""
+    for t_blk queries — launch cost amortizes over the chunk. ``t_blk``
+    defaults to the largest power of two that keeps the block's q, f32
+    accumulators and outputs inside the 16 MiB scoped VMEM."""
     B, T, H, hd = q.shape
     n_pages, page, KV, _ = k_pages.shape
     hd_p = max(128, ((hd + 127) // 128) * 128)
+    if t_blk is None:
+        t_blk = _prefill_t_blk(H * hd_p)
     if hd_p != hd:
         q = jnp.pad(q, [(0, 0), (0, 0), (0, 0), (0, hd_p - hd)])
         padkv = [(0, 0), (0, 0), (0, 0), (0, hd_p - hd)]
@@ -532,8 +550,8 @@ def paged_prefill_attend(
             in_specs=[
                 pl.BlockSpec((1, t_blk, H, hd_p),
                              lambda b, tb, *_: (b, tb, 0, 0)),
-                pl.BlockSpec(memory_space=pltpu.ANY),
-                pl.BlockSpec(memory_space=pltpu.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
             ],
             out_specs=[
                 pl.BlockSpec((1, t_blk, H, hd_p),
@@ -565,16 +583,18 @@ def paged_prefill_attend(
 # multiple of ``tq`` tokens so a tq-token BLOCK never spans two rows. The
 # grid is (Tp // tq,): one program per block, so device work is
 # proportional to the tick's real tokens (rounded per row to tq), never to
-# a [B, T_max] rectangle. Per-block scalar-prefetched metadata names the
-# owning row's page table and three ints:
+# a [B, T_max] rectangle. Scalar-prefetched metadata: one page table per
+# ROW (``row_tables [R, maxp]``) and four ints per block, block-minor so
+# the SMEM copy pads 4 → 8 sublanes instead of 3 → 128 lanes:
 #
-#   block_meta[i] = (kv_len, qpos0, nq)
+#   block_meta[:, i] = (kv_len, qpos0, nq, row)
 #     kv_len  row's valid KV tokens in its pages INCLUDING this chunk's
 #             queries (the layer scatters chunk KV to pages BEFORE the
 #             attention call — intra-chunk causality is pure masking);
 #     qpos0   buffer position of the block's first query
 #             (= kv_len_row - q_len_row + block_offset_in_row);
-#     nq      valid queries in this block (0 = inert padding block).
+#     nq      valid queries in this block (0 = inert padding block);
+#     row     index of the owning row's table in ``row_tables``.
 #
 # Because every key the block can see — resident prefix, earlier chunk
 # tokens, its own tokens — already sits in the pages, there is no
@@ -591,8 +611,8 @@ def ragged_attend_ref(
     q: jax.Array,            # [NB·tq, H, hd] token-major flattened queries
     k_pages: jax.Array,      # [n_pages, page, KV, hd]
     v_pages: jax.Array,
-    block_tables: jax.Array,  # [NB, maxp] int32 — owning row's page table
-    block_meta: jax.Array,    # [NB, 3] int32: kv_len, qpos0, nq
+    row_tables: jax.Array,   # [R, maxp] int32 — one page table per row
+    block_meta: jax.Array,   # [4, NB] int32: kv_len, qpos0, nq, row
     tq: int,
     sliding_window: Optional[int] = None,
     k_scale: Optional[jax.Array] = None,   # [n_pages, KV, page] f32
@@ -604,6 +624,9 @@ def ragged_attend_ref(
     are int8 and the gathered pages dequantize per (token, kv-head)
     before the scores — the dequantize-then-attend twin of the
     kernel's in-loop dequant."""
+    kv_len, qpos0, nq, row = (block_meta[j][:, None, None]   # [NB,1,1]
+                              for j in range(4))
+    block_tables = row_tables[row[:, 0, 0]]                  # [NB, maxp]
     NB, maxp = block_tables.shape
     _, H, hd = q.shape
     n_pages, page, KV, _ = k_pages.shape
@@ -618,9 +641,6 @@ def ragged_attend_ref(
         v = v.astype(jnp.float32) \
             * gather_scales(v_scale, block_tables)[..., None]
     scores = jnp.einsum("btkgd,bskd->bkgts", qb, k.astype(jnp.float32))
-    kv_len = block_meta[:, 0][:, None, None]       # [NB,1,1]
-    qpos0 = block_meta[:, 1][:, None, None]
-    nq = block_meta[:, 2][:, None, None]
     t_idx = jnp.arange(tq, dtype=jnp.int32)[None, :, None]
     s_idx = jnp.arange(maxp * page, dtype=jnp.int32)[None, None, :]
     qpos = qpos0 + t_idx                           # [NB,tq,1]
@@ -639,20 +659,39 @@ def ragged_attend_ref(
     return out
 
 
-def _ragged_kernel(tables_ref, meta_ref, q_ref, k_hbm, v_hbm,
-                   out_ref, k_scr, v_scr, sems, *,
+def _ragged_kernel(tables_ref, meta_ref, q_ref, k_hbm, v_hbm, *refs,
                    page: int, n_kv: int, hd: int, tq: int,
-                   scale: float, window: int):
+                   scale: float, window: int, quant: bool):
     """One tq-token block of the flattened batch: stream the owning row's
     VISIBLE pages through VMEM double-buffered (same DMA/layout recipe as
     _paged_kernel — kv heads flattened into the lane dim) and write the
     NORMALIZED attention output for the block. With the chunk KV already
     scattered into the pages there is no second partial to merge, so the
-    online-softmax accumulator normalizes in-kernel."""
+    online-softmax accumulator normalizes in-kernel.
+
+    Scalar-prefetched (SMEM): tables_ref [R, maxp] one page table per ROW,
+    meta_ref [4, NB] per-block (kv_len, qpos0, nq, row). A per-block copy
+    of the table, or a [NB, 3] meta (SMEM pads the minor dim to 128
+    lanes), overflows the v5e's 1 MiB of SMEM at an 8k-token tick.
+
+    ``quant`` (int8 pools, ISSUE 13): each page's fp32 scale block
+    ``[KV, page]`` rides the SAME double-buffered DMA stream, and the
+    dequant happens inside the streaming loop with zero lane transposes:
+    K's per-token scale multiplies the score columns
+    (``q·(k·s) = (q·k)·s``) and V's multiplies the probability columns
+    (``(p·s)·v = p·(v·s)``), both as a ``[1, page]`` lane broadcast."""
+    if quant:
+        ks_hbm, vs_hbm, out_ref, k_scr, v_scr, ks_scr, vs_scr, sems = refs
+        streams = ((k_hbm, k_scr), (v_hbm, v_scr),
+                   (ks_hbm, ks_scr), (vs_hbm, vs_scr))
+    else:
+        out_ref, k_scr, v_scr, sems = refs
+        streams = ((k_hbm, k_scr), (v_hbm, v_scr))
     i = pl.program_id(0)
-    kv_len = meta_ref[i, 0]
-    qpos0 = meta_ref[i, 1]
-    nq = meta_ref[i, 2]
+    kv_len = meta_ref[0, i]
+    qpos0 = meta_ref[1, i]
+    nq = meta_ref[2, i]
+    row = meta_ref[3, i]
     # last visible key + 1: nothing past the block's last query is visible
     kv_hi = jnp.minimum(kv_len, qpos0 + nq)
     if window >= 0:
@@ -665,28 +704,21 @@ def _ragged_kernel(tables_ref, meta_ref, q_ref, k_hbm, v_hbm,
     H = q.shape[1]
     G = H // n_kv
 
-    def start_dma(j, slot):
-        pid = tables_ref[i, p_lo + j]
-        pltpu.make_async_copy(k_hbm.at[pid], k_scr.at[slot],
-                              sems.at[slot, 0]).start()
-        pltpu.make_async_copy(v_hbm.at[pid], v_scr.at[slot],
-                              sems.at[slot, 1]).start()
-
-    def wait_dma(j, slot):
-        pid = tables_ref[i, p_lo + j]
-        pltpu.make_async_copy(k_hbm.at[pid], k_scr.at[slot],
-                              sems.at[slot, 0]).wait()
-        pltpu.make_async_copy(v_hbm.at[pid], v_scr.at[slot],
-                              sems.at[slot, 1]).wait()
+    def dmas(j, slot):
+        pid = tables_ref[row, p_lo + j]
+        return [pltpu.make_async_copy(hbm.at[pid], scr.at[slot],
+                                      sems.at[slot, s])
+                for s, (hbm, scr) in enumerate(streams)]
 
     @pl.when(n > 0)
     def _():
-        start_dma(0, 0)
+        for d in dmas(0, 0):
+            d.start()
 
     # per-score-row query index (tq·G rows, query-major like the prefill
-    # kernel) → buffer position and validity shared by every kv head
-    t_of_row = jax.lax.broadcasted_iota(
-        jnp.int32, (tq, G), 0).reshape(tq * G, 1)
+    # kernel) → buffer position and validity shared by every kv head.
+    # Built at its final shape: Mosaic has no [tq, G] → [tq·G, 1] cast.
+    t_of_row = jax.lax.broadcasted_iota(jnp.int32, (tq * G, 1), 0) // G
     qpos = qpos0 + t_of_row                              # [tq·G, 1]
     q_ok = t_of_row < nq
 
@@ -695,11 +727,16 @@ def _ragged_kernel(tables_ref, meta_ref, q_ref, k_hbm, v_hbm,
 
         @pl.when(j + 1 < n)
         def _():
-            start_dma(j + 1, jax.lax.rem(j + 1, 2))
+            for d in dmas(j + 1, jax.lax.rem(j + 1, 2)):
+                d.start()
 
-        wait_dma(j, slot)
+        for d in dmas(j, slot):
+            d.wait()
         k_blk = k_scr[slot].astype(jnp.float32)          # [page, KV·hd]
         v_blk = v_scr[slot].astype(jnp.float32)
+        if quant:
+            ks_blk = ks_scr[slot]                        # [KV, page] f32
+            vs_blk = vs_scr[slot]
         s_idx = (p_lo + j) * page + jax.lax.broadcasted_iota(
             jnp.int32, (1, page), 1)                     # [1, page]
         valid = (s_idx < kv_len) & (s_idx <= qpos) & q_ok
@@ -713,120 +750,17 @@ def _ragged_kernel(tables_ref, meta_ref, q_ref, k_hbm, v_hbm,
                 k_blk[:, kv * hd:(kv + 1) * hd],
                 (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32)
+            if quant:
+                scores = scores * ks_blk[kv:kv + 1, :]   # dequant K
             scores = jnp.where(valid, scores, NEG_INF)
             m_new = jnp.maximum(m, jnp.max(scores, axis=1, keepdims=True))
             p = jnp.where(valid, jnp.exp(scores - m_new), 0.0)
             corr = jnp.exp(m - m_new)
             l_new = l * corr + jnp.sum(p, axis=1, keepdims=True)
+            if quant:
+                p = p * vs_blk[kv:kv + 1, :]             # dequant V
             pv = jax.lax.dot_general(                    # [tq·G, hd]
                 p, v_blk[:, kv * hd:(kv + 1) * hd],
-                (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            out.append((m_new, l_new, acc * corr + pv))
-        return tuple(out)
-
-    init = tuple((jnp.full((tq * G, 1), NEG_INF, jnp.float32),
-                  jnp.zeros((tq * G, 1), jnp.float32),
-                  jnp.zeros((tq * G, hd), jnp.float32))
-                 for _ in range(n_kv))
-    final = jax.lax.fori_loop(0, n, body, init)
-    for kv in range(n_kv):
-        _, l, acc = final[kv]
-        norm = acc / jnp.where(l > 0, l, 1.0)
-        out_ref[0, :, kv * G:(kv + 1) * G] = norm.reshape(tq, G, hd)
-
-
-def _ragged_kernel_q8(tables_ref, meta_ref, q_ref, k_hbm, v_hbm,
-                      ks_hbm, vs_hbm, out_ref, k_scr, v_scr, ks_scr,
-                      vs_scr, sems, *, page: int, n_kv: int, hd: int,
-                      tq: int, scale: float, window: int):
-    """Int8 variant of :func:`_ragged_kernel` (ISSUE 13): the pools hold
-    int8 payloads and each page's fp32 scale block ``[KV, page]`` rides
-    the SAME double-buffered DMA stream. Dequant happens inside the
-    streaming loop with zero lane transposes: K's per-token scale
-    multiplies the score columns (``q·(k·s) = (q·k)·s``) and V's
-    multiplies the probability columns (``(p·s)·v = p·(v·s)``), both as
-    a ``[1, page]`` lane broadcast."""
-    i = pl.program_id(0)
-    kv_len = meta_ref[i, 0]
-    qpos0 = meta_ref[i, 1]
-    nq = meta_ref[i, 2]
-    kv_hi = jnp.minimum(kv_len, qpos0 + nq)
-    if window >= 0:
-        p_lo = jnp.maximum(qpos0 + 1 - window, 0) // page
-    else:
-        p_lo = jnp.int32(0)
-    n = jnp.maximum((kv_hi + page - 1) // page - p_lo, 0)
-
-    q = q_ref[0].astype(jnp.float32) * scale             # [tq, H, hd]
-    H = q.shape[1]
-    G = H // n_kv
-
-    def start_dma(j, slot):
-        pid = tables_ref[i, p_lo + j]
-        pltpu.make_async_copy(k_hbm.at[pid], k_scr.at[slot],
-                              sems.at[slot, 0]).start()
-        pltpu.make_async_copy(v_hbm.at[pid], v_scr.at[slot],
-                              sems.at[slot, 1]).start()
-        pltpu.make_async_copy(ks_hbm.at[pid], ks_scr.at[slot],
-                              sems.at[slot, 2]).start()
-        pltpu.make_async_copy(vs_hbm.at[pid], vs_scr.at[slot],
-                              sems.at[slot, 3]).start()
-
-    def wait_dma(j, slot):
-        pid = tables_ref[i, p_lo + j]
-        pltpu.make_async_copy(k_hbm.at[pid], k_scr.at[slot],
-                              sems.at[slot, 0]).wait()
-        pltpu.make_async_copy(v_hbm.at[pid], v_scr.at[slot],
-                              sems.at[slot, 1]).wait()
-        pltpu.make_async_copy(ks_hbm.at[pid], ks_scr.at[slot],
-                              sems.at[slot, 2]).wait()
-        pltpu.make_async_copy(vs_hbm.at[pid], vs_scr.at[slot],
-                              sems.at[slot, 3]).wait()
-
-    @pl.when(n > 0)
-    def _():
-        start_dma(0, 0)
-
-    t_of_row = jax.lax.broadcasted_iota(
-        jnp.int32, (tq, G), 0).reshape(tq * G, 1)
-    qpos = qpos0 + t_of_row                              # [tq·G, 1]
-    q_ok = t_of_row < nq
-
-    def body(j, carry):
-        slot = jax.lax.rem(j, 2)
-
-        @pl.when(j + 1 < n)
-        def _():
-            start_dma(j + 1, jax.lax.rem(j + 1, 2))
-
-        wait_dma(j, slot)
-        k_blk = k_scr[slot].astype(jnp.float32)          # [page, KV·hd]
-        v_blk = v_scr[slot].astype(jnp.float32)
-        ks_blk = ks_scr[slot]                            # [KV, page] f32
-        vs_blk = vs_scr[slot]
-        s_idx = (p_lo + j) * page + jax.lax.broadcasted_iota(
-            jnp.int32, (1, page), 1)                     # [1, page]
-        valid = (s_idx < kv_len) & (s_idx <= qpos) & q_ok
-        if window >= 0:
-            valid = valid & (qpos - s_idx < window)
-        out = []
-        for kv in range(n_kv):
-            m, l, acc = carry[kv]
-            scores = jax.lax.dot_general(                # [tq·G, page]
-                q[:, kv * G:(kv + 1) * G].reshape(tq * G, hd),
-                k_blk[:, kv * hd:(kv + 1) * hd],
-                (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            scores = scores * ks_blk[kv:kv + 1, :]       # dequant K
-            scores = jnp.where(valid, scores, NEG_INF)
-            m_new = jnp.maximum(m, jnp.max(scores, axis=1, keepdims=True))
-            p = jnp.where(valid, jnp.exp(scores - m_new), 0.0)
-            corr = jnp.exp(m - m_new)
-            l_new = l * corr + jnp.sum(p, axis=1, keepdims=True)
-            pv = jax.lax.dot_general(                    # [tq·G, hd]
-                p * vs_blk[kv:kv + 1, :],                # dequant V
-                v_blk[:, kv * hd:(kv + 1) * hd],
                 (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
             out.append((m_new, l_new, acc * corr + pv))
@@ -849,8 +783,8 @@ def ragged_attend(
     q: jax.Array,            # [NB·tq, H, hd] token-major flattened queries
     k_pages: jax.Array,      # [n_pages, page, KV, hd]
     v_pages: jax.Array,
-    block_tables: jax.Array,  # [NB, maxp] int32
-    block_meta: jax.Array,    # [NB, 3] int32: kv_len, qpos0, nq
+    row_tables: jax.Array,   # [R, maxp] int32
+    block_meta: jax.Array,   # [4, NB] int32: kv_len, qpos0, nq, row
     tq: int,
     sliding_window: Optional[int] = None,
     interpret: bool = False,
@@ -860,10 +794,10 @@ def ragged_attend(
     """Pallas unified ragged attention (same contract as ragged_attend_ref;
     tests/test_ragged_attention.py asserts numerical agreement). Grid is
     (NB,) — sized by the tick's real tokens / tq, never by batch × max.
-    With ``k_scale``/``v_scale`` the int8 kernel variant streams each
-    page's scale block alongside its payload and dequantizes in-loop."""
+    With ``k_scale``/``v_scale`` the kernel streams each int8 page's scale
+    block alongside its payload and dequantizes in-loop."""
     Tp, H, hd = q.shape
-    NB = block_tables.shape[0]
+    NB = block_meta.shape[1]
     n_pages, page, KV, _ = k_pages.shape
     hd_p = max(128, ((hd + 127) // 128) * 128)
     if hd_p != hd:
@@ -874,29 +808,17 @@ def ragged_attend(
     kf = k_pages.reshape(n_pages, page, KV * hd_p)
     vf = v_pages.reshape(n_pages, page, KV * hd_p)
     qb = q.reshape(NB, tq, H, hd_p)
-    scale = hd ** -0.5
     quant = k_scale is not None
+    kernel = functools.partial(
+        _ragged_kernel, page=page, n_kv=KV, hd=hd_p, tq=tq,
+        scale=hd ** -0.5, quant=quant,
+        window=-1 if sliding_window is None else int(sliding_window))
+    pools = [kf, vf]
+    scratch = [pltpu.VMEM((2, page, KV * hd_p), k_pages.dtype),
+               pltpu.VMEM((2, page, KV * hd_p), v_pages.dtype)]
     if quant:
-        kernel = functools.partial(
-            _ragged_kernel_q8, page=page, n_kv=KV, hd=hd_p, tq=tq,
-            scale=scale,
-            window=-1 if sliding_window is None else int(sliding_window))
-        extra_in = [pl.BlockSpec(memory_space=pltpu.ANY),   # k scales
-                    pl.BlockSpec(memory_space=pltpu.ANY)]   # v scales
-        extra_scratch = [pltpu.VMEM((2, KV, page), jnp.float32),
-                         pltpu.VMEM((2, KV, page), jnp.float32)]
-        sems = pltpu.SemaphoreType.DMA((2, 4))
-        args = (qb, kf, vf, k_scale.astype(jnp.float32),
-                v_scale.astype(jnp.float32))
-    else:
-        kernel = functools.partial(
-            _ragged_kernel, page=page, n_kv=KV, hd=hd_p, tq=tq,
-            scale=scale,
-            window=-1 if sliding_window is None else int(sliding_window))
-        extra_in = []
-        extra_scratch = []
-        sems = pltpu.SemaphoreType.DMA((2, 2))
-        args = (qb, kf, vf)
+        pools += [k_scale.astype(jnp.float32), v_scale.astype(jnp.float32)]
+        scratch += [pltpu.VMEM((2, KV, page), jnp.float32)] * 2
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -904,57 +826,29 @@ def ragged_attend(
             grid=(NB,),
             in_specs=[
                 pl.BlockSpec((1, tq, H, hd_p), lambda i, *_: (i, 0, 0, 0)),
-                pl.BlockSpec(memory_space=pltpu.ANY),     # k pool in HBM
-                pl.BlockSpec(memory_space=pltpu.ANY),     # v pool in HBM
-                *extra_in,
+                *[pl.BlockSpec(memory_space=pl.ANY)       # pools stay in HBM
+                  for _ in pools],
             ],
             out_specs=[
                 pl.BlockSpec((1, tq, H, hd_p), lambda i, *_: (i, 0, 0, 0)),
             ],
-            scratch_shapes=[
-                pltpu.VMEM((2, page, KV * hd_p), k_pages.dtype),
-                pltpu.VMEM((2, page, KV * hd_p), v_pages.dtype),
-                *extra_scratch,
-                sems,
-            ],
+            scratch_shapes=[*scratch,
+                            pltpu.SemaphoreType.DMA((2, len(pools)))],
         ),
         out_shape=[
             jax.ShapeDtypeStruct((NB, tq, H, hd_p), jnp.float32),
         ],
         interpret=interpret,
-    )(block_tables.astype(jnp.int32), block_meta.astype(jnp.int32),
-      *args)[0]
+    )(row_tables.astype(jnp.int32), block_meta.astype(jnp.int32),
+      qb, *pools)[0]
     return out.reshape(NB * tq, H, hd_p)[..., :hd]
-
-
-def _ragged_tp_shard(inner, shard, quant: bool):
-    """shard_map wrapper for the unified ragged kernel on tp meshes: every
-    head attends independently (whole GQA groups per shard — callers gate
-    on divisibility), block tables/metadata replicate, no collective.
-    Int8 scale pools shard on their KV axis beside the payload pools."""
-    try:
-        from jax import shard_map
-    except ImportError:                      # older jax
-        from jax.experimental.shard_map import shard_map
-    from jax.sharding import PartitionSpec as P
-    mesh, tp_ax = shard
-    head = P(None, tp_ax, None)              # [Tp, H, hd]
-    kv = P(None, None, tp_ax, None)          # [n_pages, page, KV, hd]
-    ins = [head, kv, kv, P(None, None), P(None, None)]
-    if quant:
-        ins += [P(None, tp_ax, None)] * 2    # [n_pages, KV, page]
-    specs = dict(in_specs=tuple(ins), out_specs=head)
-    try:
-        return shard_map(inner, mesh=mesh, check_rep=False, **specs)
-    except TypeError:
-        return shard_map(inner, mesh=mesh, **specs)
 
 
 def ragged_attend_auto(
     q: jax.Array,            # [NB·tq, H, hd]
     k_pages: jax.Array,
     v_pages: jax.Array,
-    block_tables: jax.Array,
+    row_tables: jax.Array,
     block_meta: jax.Array,
     tq: int,
     sliding_window: Optional[int] = None,
@@ -966,28 +860,37 @@ def ragged_attend_auto(
     """Unified ragged attention dispatcher: Pallas kernel on TPU (or under
     ``interpret``), XLA gather reference elsewhere (CPU tier-1 — same
     numerics, no paging win). With ``shard``, runs per-tp-shard under
-    shard_map (heads independent). ``k_scale``/``v_scale`` mark int8
-    pools and route to the in-kernel-dequant variant / dequantizing
-    reference."""
+    shard_map: every head attends independently (whole GQA groups per
+    shard — callers gate on divisibility), tables/metadata replicate, no
+    collective; int8 scale pools shard on their KV axis beside the
+    payload pools. ``k_scale``/``v_scale`` mark int8 pools and route to
+    the in-kernel dequant / dequantizing reference."""
     if shard is not None:
-        inner = functools.partial(ragged_attend_auto, tq=tq,
-                                  sliding_window=sliding_window,
-                                  interpret=interpret, shard=None)
+        from jax.sharding import PartitionSpec as P
+        mesh, tp_ax = shard
+        head = P(None, tp_ax, None)              # [Tp, H, hd]
+        kv = P(None, None, tp_ax, None)          # [n_pages, page, KV, hd]
+        ins = [head, kv, kv, P(None, None), P(None, None)]
+        args = [q, k_pages, v_pages, row_tables, block_meta]
         if k_scale is not None:
-            def inner_q(qq, kp, vp, bt, bm, ks, vs):
-                return inner(qq, kp, vp, bt, bm, k_scale=ks, v_scale=vs)
-            return _ragged_tp_shard(inner_q, shard, quant=True)(
-                q, k_pages, v_pages, block_tables, block_meta,
-                k_scale, v_scale)
-        return _ragged_tp_shard(inner, shard, quant=False)(
-            q, k_pages, v_pages, block_tables, block_meta)
+            ins += [P(None, tp_ax, None)] * 2    # [n_pages, KV, page]
+            args += [k_scale, v_scale]
+
+        def inner(qq, kp, vp, rt, bm, ks=None, vs=None):
+            return ragged_attend_auto(
+                qq, kp, vp, rt, bm, tq=tq, sliding_window=sliding_window,
+                interpret=interpret, k_scale=ks, v_scale=vs)
+        # check_vma off: a pallas_call's outputs carry no varying-axes
+        # annotation for the checker to verify
+        return jax.shard_map(inner, mesh=mesh, in_specs=tuple(ins),
+                             out_specs=head, check_vma=False)(*args)
     on_tpu = jax.devices()[0].platform == "tpu"
     if on_tpu or interpret:
-        return ragged_attend(q, k_pages, v_pages, block_tables, block_meta,
+        return ragged_attend(q, k_pages, v_pages, row_tables, block_meta,
                              tq=tq, sliding_window=sliding_window,
                              interpret=bool(interpret),
                              k_scale=k_scale, v_scale=v_scale)
-    return ragged_attend_ref(q, k_pages, v_pages, block_tables, block_meta,
+    return ragged_attend_ref(q, k_pages, v_pages, row_tables, block_meta,
                              tq=tq, sliding_window=sliding_window,
                              k_scale=k_scale, v_scale=v_scale)
 
@@ -999,10 +902,6 @@ def _tp_shard_map(inner, shard, q_rank4: bool):
     single-device kernel on its local heads with NO collective; dp shards
     the batch. This is how mesh engines keep the ragged kernels instead
     of falling back to gather (VERDICT r4 item 3)."""
-    try:
-        from jax import shard_map
-    except ImportError:                      # older jax
-        from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     mesh, tp_ax, dp_ax = shard
     head = P(dp_ax, None, tp_ax, None)       # [B, T|1, H, hd] (and tails)
@@ -1010,22 +909,12 @@ def _tp_shard_map(inner, shard, q_rank4: bool):
     row = P(dp_ax)
     tbl = P(dp_ax, None)
     if q_rank4:   # decode: q [B,1,H,hd]; prefill merge: q [B,T,H,hd]
-        specs = dict(in_specs=(head, kv, kv, tbl, row, row,
-                               head, head, P(), row),
-                     out_specs=head)
+        ins = (head, kv, kv, tbl, row, row, head, head, P(), row)
     else:
-        specs = dict(in_specs=(head, head, head, kv, kv, tbl, row, row),
-                     out_specs=head)
-    try:
-        # experimental shard_map needs replication checking OFF (pallas
-        # calls aren't analyzable); jax.shard_map (0.7+) dropped the kwarg
-        # and raises TypeError here — fall back to the bare call. This
-        # order matters: the bare call "succeeds" on the experimental API
-        # too (check_rep defaults ON) and would then fail later at trace
-        # time inside jit.
-        return shard_map(inner, mesh=mesh, check_rep=False, **specs)
-    except TypeError:
-        return shard_map(inner, mesh=mesh, **specs)
+        ins = (head, head, head, kv, kv, tbl, row, row)
+    # check_vma off: see ragged_attend_auto
+    return jax.shard_map(inner, mesh=mesh, in_specs=ins, out_specs=head,
+                         check_vma=False)
 
 
 def paged_prefill_merge(
@@ -1077,24 +966,31 @@ def paged_decode_attend(
     tail_len,              # scalar/[B] valid tail entries (incl. current)
     q_pos: jax.Array,      # [B] absolute query position
     sliding_window: Optional[int] = None,
+    interpret: Optional[bool] = None,
     shard: Optional[tuple] = None,   # (mesh, tp_axis, dp_axis|None)
 ) -> jax.Array:
     """Full decode attention = paged pool piece ⊕ tail piece → [B, 1, H, hd]
-    in q.dtype. Picks the Pallas kernel on TPU, the gather reference
-    elsewhere (CPU tests — same numerics, no paging win). With ``shard``,
-    runs per-tp-shard under shard_map (heads independent)."""
+    in q.dtype. Picks the Pallas kernel on TPU (or under ``interpret``),
+    the gather reference elsewhere (CPU tests — same numerics, no paging
+    win). With ``shard``, runs per-tp-shard under shard_map (heads
+    independent)."""
     if shard is not None:
         inner = functools.partial(paged_decode_attend,
-                                  sliding_window=sliding_window, shard=None)
+                                  sliding_window=sliding_window,
+                                  interpret=interpret, shard=None)
         return _tp_shard_map(inner, shard, q_rank4=True)(
             q, k_pages, v_pages, tables, pool_lens, kv_off, tail_k, tail_v,
             jnp.asarray(tail_len), q_pos)
     B, _, H, hd = q.shape
     q1 = q[:, 0]
     on_tpu = jax.devices()[0].platform == "tpu"
-    fn = paged_attend if on_tpu else paged_attend_ref
-    pooled = fn(q1, k_pages, v_pages, tables, pool_lens, kv_off, q_pos,
-                sliding_window)
+    if on_tpu or interpret:
+        pooled = paged_attend(q1, k_pages, v_pages, tables, pool_lens,
+                              kv_off, q_pos, sliding_window,
+                              interpret=bool(interpret))
+    else:
+        pooled = paged_attend_ref(q1, k_pages, v_pages, tables, pool_lens,
+                                  kv_off, q_pos, sliding_window)
     tail_pos0 = kv_off.astype(jnp.int32) + pool_lens.astype(jnp.int32)
     tail = tail_attend_partials(q1, tail_k, tail_v, tail_len, tail_pos0,
                                 q_pos, sliding_window)

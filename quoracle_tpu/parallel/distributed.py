@@ -66,14 +66,16 @@ def init_process(coordinator_address: Optional[str] = None,
                  num_processes: Optional[int] = None,
                  process_id: Optional[int] = None) -> ProcessInfo:
     """Join the JAX distributed system. On TPU pods all three arguments are
-    usually inferred from the environment (jax.distributed.initialize()
-    with no args); CPU/GPU clusters pass them explicitly. With no arguments
-    AND no cluster environment, degrades to single-process operation — but
-    when the environment says a cluster exists, an init failure re-raises:
-    swallowing it would leave this process training on 1/N of the pod or
-    hanging in the first collective its peers enter without it."""
-    import logging
-
+    inferred from the environment (jax.distributed.initialize() with no
+    args); CPU/GPU clusters pass them explicitly. With no arguments AND no
+    cluster environment there is nothing to join and nothing is called:
+    the no-argument form asks the cloud metadata server who the peers are,
+    and a host without one (a sealed single-host machine) pays its
+    connection timeouts at every start only to have the exception
+    swallowed. When the environment says a cluster exists, an init
+    failure raises: swallowing it would leave this process training on
+    1/N of the pod or hanging in the first collective its peers enter
+    without it."""
     import jax
 
     def _info() -> ProcessInfo:
@@ -84,12 +86,7 @@ def init_process(coordinator_address: Optional[str] = None,
             global_devices=jax.device_count(),
         )
 
-    try:
-        from jax._src import distributed as _dist
-        already = _dist.global_state.client is not None
-    except Exception:
-        already = False
-    if already:
+    if jax.distributed.is_initialized():
         # a second Runtime / repeated call in one process: the system is
         # up, just report it
         return _info()
@@ -106,14 +103,8 @@ def init_process(coordinator_address: Optional[str] = None,
         jax.distributed.initialize(
             coordinator_address=coordinator_address,
             num_processes=num_processes, process_id=process_id)
-    else:
-        try:
-            jax.distributed.initialize()
-        except Exception as e:
-            if _cluster_env_expects_peers():
-                raise
-            logging.getLogger(__name__).debug(
-                "no cluster environment; single-process operation (%s)", e)
+    elif _cluster_env_expects_peers():
+        jax.distributed.initialize()
     return _info()
 
 

@@ -162,11 +162,12 @@ POOL_TAIL_RESERVE = 1.25 * 1024 ** 3    # activations + compiled programs +
 
 
 def device_hbm_limit(device) -> int:
-    """Best-effort memory capacity of one jax device, in bytes: the live
-    runtime's ``memory_stats()`` limit when the backend exposes it (TPU
-    and GPU do), the public v5e spec as the TPU fallback, 0 for hosts
-    that report nothing (CPU) — callers treat 0 as "no budget known"
-    rather than inventing one (infra/resources.py headroom gauges)."""
+    """Memory capacity of one jax device, in bytes: the live runtime's
+    ``memory_stats()`` limit when the backend exposes it (TPU and GPU
+    do), the public spec of a v5e ("TPU v5 lite") that reports none, 0
+    for hosts that report nothing (CPU) — callers treat 0 as "no budget
+    known" rather than inventing one (infra/resources.py headroom
+    gauges). Any other TPU that reports no limit is an error."""
     try:
         stats = device.memory_stats()
     except Exception:                     # noqa: BLE001 — optional API
@@ -176,8 +177,13 @@ def device_hbm_limit(device) -> int:
                     or stats.get("bytes_reservable_limit") or 0)
         if limit > 0:
             return limit
-    return (V5E_HBM_BYTES
-            if getattr(device, "platform", "") == "tpu" else 0)
+    if getattr(device, "platform", "") != "tpu":
+        return 0
+    kind = getattr(device, "device_kind", "")
+    if "v5 lite" in kind:
+        return V5E_HBM_BYTES
+    raise ValueError(f"device kind {kind!r} reports no memory limit and "
+                     f"has no HBM size on file (parallel/mesh.py)")
 
 
 def pool_sizing(pool: Sequence[str], n_devices: int = 8,

@@ -505,16 +505,12 @@ class Runtime:
         )
         enable_compilation_cache()
         # Join the JAX distributed system BEFORE any jax.devices() call:
-        # explicit args when given, pod auto-detection otherwise (the
-        # no-arg form degrades cleanly off-cluster but re-raises when the
-        # environment says a cluster exists — parallel/distributed.py).
+        # explicit args when given, else only where the environment names
+        # peers — a one-host server has nothing to join
+        # (parallel/distributed.py).
         from quoracle_tpu.parallel.distributed import init_process
-        if (config.coordinator_address or config.num_processes
-                or config.process_id is not None):
-            info = init_process(config.coordinator_address,
-                                config.num_processes, config.process_id)
-        else:
-            info = init_process()
+        info = init_process(config.coordinator_address,
+                            config.num_processes, config.process_id)
         if info.num_processes > 1:
             logger.info("joined distributed system: process %d/%d, "
                         "%d global devices", info.process_id,

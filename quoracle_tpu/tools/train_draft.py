@@ -7,8 +7,7 @@ historical entry point stable:
     python -m quoracle_tpu.tools.train_draft --check
 
 and ``run_check``/``main`` importable from here (the tier-1 contract in
-tests/test_train_draft_check.py and run_live_bench.sh's bonus capture
-both use this path).
+tests/test_train_draft_check.py uses this path).
 """
 
 from __future__ import annotations

@@ -1,13 +1,16 @@
 """Persistent XLA compilation cache.
 
-First-touch compiles dominate cold starts here: a 1b-scale
-(prefill, decode) pair costs 10-20 s through the remote-compile helper,
-and a growing conversation crossing a shape bucket pays again. JAX's
-persistent cache keys compiled executables by (HLO, flags, platform) on
-disk, so every process after the first reuses them — measured on this
-deployment: 9.0 s → 1.1 s for a fresh-process recompile. bench.py and the
-Runtime's TPU backend both enable it (the mock backend never compiles, so
-it skips the setup).
+First-touch compiles dominate cold starts: every (prefill, decode) shape
+bucket compiles on first use, and a growing conversation crossing a bucket
+pays again. JAX's persistent cache keys compiled executables by (HLO,
+flags, platform) on disk, so every process after the first reuses them.
+The Runtime's TPU backend, bench.py, chip_smoke.py and the tools enable it
+(the mock backend never compiles, so it skips the setup).
+
+Placement: where ``JAX_COMPILATION_CACHE_DIR`` is set JAX already reads
+it, and this module sets no directory; where it is not, the cache lives
+at one fixed, git-ignored path inside the checkout — the path is part of
+the cache key, so a directory that moves never hits.
 """
 
 from __future__ import annotations
@@ -15,25 +18,25 @@ from __future__ import annotations
 import os
 from typing import Optional
 
-_DEFAULT = os.path.expanduser("~/.cache/quoracle_tpu/xla")
+IN_CHECKOUT_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".xla_cache")
 _enabled: Optional[str] = None
 
 
-def enable_compilation_cache(path: Optional[str] = None) -> str:
-    """Idempotent: points JAX's persistent compilation cache at ``path``
-    (default ~/.cache/quoracle_tpu/xla, overridable with
-    QUORACLE_XLA_CACHE; QUORACLE_XLA_CACHE=off disables). Returns the
-    directory in use ("" when disabled)."""
+def enable_compilation_cache() -> str:
+    """Idempotent: turn on JAX's persistent compilation cache and return
+    the directory in use — ``JAX_COMPILATION_CACHE_DIR`` where set (left
+    untouched), else ``IN_CHECKOUT_DIR``."""
     global _enabled
     if _enabled is not None:
         return _enabled
-    path = path or os.environ.get("QUORACLE_XLA_CACHE") or _DEFAULT
-    if path.lower() in ("off", "none", "0"):
-        _enabled = ""
-        return _enabled
     import jax
-    os.makedirs(path, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", path)
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = IN_CHECKOUT_DIR
+        os.makedirs(path, exist_ok=True)
+        jax.config.update("jax_compilation_cache_dir", path)
     # cache everything that took real compile time, however small the HLO
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)

@@ -124,7 +124,8 @@ def test_ragged_kernel_dequant_vs_oracle():
     ksl = jnp.transpose(ks, (0, 2, 1))        # [n_pages, KV, page]
     vsl = jnp.transpose(vs, (0, 2, 1))
     tables = jnp.array([[0, 1, 2], [3, 4, 5]], jnp.int32)
-    meta = jnp.array([[20, 16, 4], [10, 6, 4]], jnp.int32)
+    # [4, NB]: kv_len, qpos0, nq, row
+    meta = jnp.array([[20, 10], [16, 6], [4, 4], [0, 1]], jnp.int32)
     q = jax.random.normal(jax.random.fold_in(key, 2), (NB * tq, H, hd))
     # oracle: dequantize the pages, then attend with the plain reference
     oracle = ragged_attend_ref(q, kv_dequant(kq, ks), kv_dequant(vq, vs),

@@ -79,23 +79,23 @@ def _random_case(rng, rows, H, KV, hd, page, n_pages, window):
                      jnp.float32)
     vp = jnp.asarray(rng.standard_normal((n_pages, page, KV, hd)),
                      jnp.float32)
-    btab = np.zeros((NB, maxp), np.int32)
-    bmeta = np.zeros((NB, 3), np.int32)
+    rtab = np.zeros((len(rows), maxp), np.int32)
+    bmeta = np.zeros((4, NB), np.int32)     # kv_len, qpos0, nq, row
     next_page = 1
     cur_blk = 0
-    for pre, qlen in rows:
+    for r, (pre, qlen) in enumerate(rows):
         nb = -(-qlen // tq) if qlen else 1
-        pages = [(next_page + j) % (n_pages - 1) + 1 for j in range(maxp)]
+        rtab[r] = [(next_page + j) % (n_pages - 1) + 1
+                   for j in range(maxp)]
         next_page += maxp
         for b in range(nb):
-            btab[cur_blk + b, :] = pages
-            bmeta[cur_blk + b] = (pre + qlen, pre + b * tq,
-                                  max(0, min(tq, qlen - b * tq)))
+            bmeta[:, cur_blk + b] = (pre + qlen, pre + b * tq,
+                                     max(0, min(tq, qlen - b * tq)), r)
         cur_blk += nb
-    ref = ragged_attend_ref(q, kp, vp, jnp.asarray(btab),
+    ref = ragged_attend_ref(q, kp, vp, jnp.asarray(rtab),
                             jnp.asarray(bmeta), tq=tq,
                             sliding_window=window)
-    krn = ragged_attend(q, kp, vp, jnp.asarray(btab), jnp.asarray(bmeta),
+    krn = ragged_attend(q, kp, vp, jnp.asarray(rtab), jnp.asarray(bmeta),
                         tq=tq, sliding_window=window,
                         interpret=jax.devices()[0].platform != "tpu")
     np.testing.assert_allclose(np.asarray(ref), np.asarray(krn),
