@@ -448,13 +448,26 @@ def jax_trace_window(logdir: str):
     """A real ``jax.profiler`` trace window behind the introspect flag —
     device-level truth for TPU runs, where Python frame samples only see
     the host side. Yields whether the trace actually armed; degrades to
-    a no-op on CPU test runs or when the profiler backend is missing."""
+    a no-op on CPU test runs or when the profiler backend is missing.
+
+    The window holds, on one clock: each batcher worker's ``qtpu.tick``
+    and ``qtpu.tick.<phase>`` spans on its thread's line of the
+    ``/host:CPU`` plane (infra/telemetry.TICK_PHASES; the tick carries
+    ``model``, ``rows``, ``admitted``, ``real_tokens``,
+    ``padded_tokens``, ``decode_steps`` and the program key), the
+    runtime's own threads, and on the device planes every operation with
+    the ``jax.named_scope`` path it was traced under (``tf_op``). Python
+    frames are left out (``python_tracer_level`` 0): a window on a live
+    server must not slow its host."""
     if not _STATE.enabled:
         yield False
         return
     try:
         import jax
-        jax.profiler.start_trace(logdir)
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        opts.host_tracer_level = 1
+        jax.profiler.start_trace(logdir, profiler_options=opts)
     except Exception:                 # noqa: BLE001 — optional backend
         yield False
         return
@@ -474,7 +487,13 @@ def jax_trace_window(logdir: str):
 # The named wait vocabulary. "other" is the exact remainder bucket —
 # computed, never measured, so per-row waits sum to the wall by
 # construction (the ChipLedger remainder-booking idiom, ISSUE 17).
-WAIT_STATES: tuple = ("admission", "queue", "dispatch", "kv_restore",
+# A continuous-batcher tick books its split — ``host`` (prepare, pack,
+# dispatch, commit), ``device_prefill`` and ``device_decode`` (the two
+# fences), from the tick record (infra/telemetry.TickRecord); the
+# speculative sub-tick and front-door requests still book one
+# ``dispatch`` lump.
+WAIT_STATES: tuple = ("admission", "queue", "dispatch", "host",
+                      "device_prefill", "device_decode", "kv_restore",
                       "wire", "lock", "other")
 
 
@@ -554,11 +573,21 @@ def drain_inner_waits() -> tuple:
 
 _WAIT_TOTALS: dict = {}               # model -> {state: ns}
 _WAIT_ROWS: dict = {}                 # model -> rows recorded
+# The row record (ISSUE 24): one entry per retired batcher row — its
+# closed WaitClock beside its four stamps (monotonic ns, ``t_submit <=
+# t_admit <= t_first_token <= t_done``), the ticks it rode and its token
+# counts. Bounded; a row spans many ticks and is submitted from another
+# thread, so it is read from here and not from a profiler trace.
+ROW_RING_SIZE = 4096
+_ROW_RING: deque = deque(maxlen=ROW_RING_SIZE)
 
 
-def record_row_waits(model: str, closed: dict) -> None:
+def record_row_waits(model: str, closed: dict,
+                     row: Optional[dict] = None) -> None:
     """Book one closed WaitClock: per-state histograms + the running
-    totals ``/api/profile`` reports. Emission outside the plane lock."""
+    totals ``/api/profile`` reports, and — with ``row``, the batcher's
+    stamps and counts — one entry of the row ring. Emission outside the
+    plane lock."""
     if not _STATE.enabled:
         return
     waits = closed["waits_ns"]
@@ -567,6 +596,10 @@ def record_row_waits(model: str, closed: dict) -> None:
         for state, ns in waits.items():
             agg[state] = agg.get(state, 0) + ns
         _WAIT_ROWS[model] = _WAIT_ROWS.get(model, 0) + 1
+        if row is not None:
+            _ROW_RING.append({"model": model, **row,
+                              "wall_ns": closed["wall_ns"],
+                              "waits_ns": dict(waits)})
     from quoracle_tpu.infra.telemetry import INTROSPECT_WAIT_MS
     for state, ns in waits.items():
         if ns > 0:
@@ -585,6 +618,14 @@ def wait_totals() -> dict:
         return {m: {"rows": _WAIT_ROWS.get(m, 0),
                     "by_state_ns": dict(states)}
                 for m, states in _WAIT_TOTALS.items()}
+
+
+def row_ring(last: Optional[int] = None) -> list:
+    """The retired rows the ring holds, oldest first (the ``last`` most
+    recent when given)."""
+    with _LOCK:
+        rows = list(_ROW_RING)
+    return rows if last is None else rows[-last:]
 
 
 # ---------------------------------------------------------------------------
@@ -652,6 +693,7 @@ def profile_payload() -> dict:
         "heartbeats": heartbeats(),
         "stalls": STALLS.status(),
         "waits": wait_totals(),
+        "rows": row_ring(last=256),
     }
 
 
@@ -682,6 +724,7 @@ def reset() -> None:
         _HEARTBEATS.clear()
         _WAIT_TOTALS.clear()
         _WAIT_ROWS.clear()
+        _ROW_RING.clear()
     PROFILER = WallProfiler()
     STALLS = StallDetector()
     _ACC.restore_ns = 0

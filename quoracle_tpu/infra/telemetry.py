@@ -50,9 +50,6 @@ from quoracle_tpu.analysis.lockdep import named_lock
 # path spans (µs-scale cache lookups to multi-second compile rounds).
 DEFAULT_MS_BUCKETS: tuple[float, ...] = tuple(2.0 ** i for i in range(-1, 17))
 
-# Throughput buckets (tokens/second): powers of four, 1 .. ~4.2M tok/s.
-THROUGHPUT_BUCKETS: tuple[float, ...] = tuple(4.0 ** i for i in range(0, 12))
-
 
 def quantile(bounds: Sequence[float], counts: Sequence[int],
              p: float) -> Optional[float]:
@@ -634,15 +631,6 @@ ACTION_MS = METRICS.histogram(
 DECODE_STEP_MS = METRICS.histogram(
     "quoracle_decode_step_ms", "decode phase per emitted token (ms)",
     buckets=tuple(2.0 ** i for i in range(-4, 12)))
-PREFIX_LOOKUP_MS = METRICS.histogram(
-    "quoracle_prefix_lookup_ms", "radix prefix-cache lookup (ms)",
-    buckets=tuple(2.0 ** i for i in range(-6, 8)))
-PREFILL_TOKENS_PER_S = METRICS.histogram(
-    "quoracle_prefill_tokens_per_s", "per-wave prefill token throughput",
-    buckets=THROUGHPUT_BUCKETS)
-JIT_COMPILES = METRICS.counter(
-    "quoracle_jit_compiles_total",
-    "first-call shape-bucket compiles per engine (cache-miss rounds)")
 ROUNDS_TOTAL = METRICS.counter(
     "quoracle_consensus_rounds_total", "consensus query rounds run")
 ACTIONS_TOTAL = METRICS.counter(
@@ -707,9 +695,144 @@ SCHED_PADDED_TOKENS_TOTAL = METRICS.counter(
     "device chunk-token slots processed across generate ticks (real + "
     "padding), per model — [B·T] on the bucketed paths, the flat token "
     "budget on the unified ragged path")
-SCHED_PAD_WASTE_RATIO = METRICS.gauge(
-    "quoracle_sched_pad_waste_ratio",
-    "last tick's (padded - real) / padded chunk-token waste, per model")
+# -- the batcher's tick record (ISSUE 24) -----------------------------------
+# One record per ContinuousBatcher._loop iteration, built on the worker
+# thread where the work happens (models/scheduler.py, models/generate.py).
+# The phases tile the iteration: opening one closes the one before, so a
+# tick's phases sum to its wall by construction. Each phase is also a
+# ``jax.profiler.TraceAnnotation`` (a TraceMe: about half a microsecond
+# with no profiler session open), so a profiler trace holds ``qtpu.tick``
+# and ``qtpu.tick.<phase>`` on the worker thread's line, on the device's
+# clock. The record feeds the phase counter below, the rows' WaitClocks
+# (scheduler._book_step_waits) and the sampled ``sched.decode_tick`` span.
+TICK_PHASES: tuple = (
+    "admit",              # _admit: policy pop, deadline drops, queue-wait spans
+    "prepare",            # splice, session lookup, page alloc, prefix match,
+                          # tier restore (generate → _run_paged)
+    "pack",               # _run_unified: the numpy layout of the flat tick
+    "dispatch_prefill",   # host→device transfers + enqueue of the chunk program
+    "wait_prefill",       # block_until_ready(last_logits): the prefill fence
+    "dispatch_decode",    # enqueue of the decode program
+    "wait_decode",        # fetch of its outputs, block_until_ready(pool)
+    "commit",             # session put, prefix insert, telemetry, chip ledger
+    "retire",             # per-row bookkeeping, _finish_row for rows that ended
+    "idle",               # _wake.wait: nothing live
+)
+TICK_PHASE_MS_TOTAL = METRICS.counter(
+    "quoracle_tick_phase_ms_total",
+    "continuous-batcher worker time by tick phase (ms), per model: the "
+    "wait_* phases are the device, idle is an empty loop, the rest is "
+    "host work between device programs")
+_TICK_NAMES = {p: "qtpu.tick." + p for p in TICK_PHASES}
+
+
+def _annotation(name: str):
+    """An entered TraceAnnotation (jax imported at first use: the mock
+    backend's processes never open a tick)."""
+    from jax.profiler import TraceAnnotation
+    ann = TraceAnnotation(name)
+    ann.__enter__()
+    return ann
+
+
+class TickRecord:
+    """One batcher loop iteration: integer-ns time per phase, the
+    annotation arguments a trace reader needs (``model``, ``rows``,
+    ``admitted``, ``real_tokens``, ``padded_tokens``, ``decode_steps``,
+    ``program``), and the prefill fence of the tick (``fence_ns``: the
+    instant ``wait_prefill`` last ended — a row's first-token stamp)."""
+
+    __slots__ = ("t0_ns", "t1_ns", "phase_ns", "args", "fence_ns",
+                 "_phase", "_t_phase", "_ann", "_tick_ann")
+
+    def __init__(self, model: str):
+        self.args: dict = {"model": model}
+        self.phase_ns = dict.fromkeys(TICK_PHASES, 0)
+        self.fence_ns = 0
+        self.t1_ns = 0
+        self._tick_ann = _annotation("qtpu.tick")
+        self._phase = TICK_PHASES[0]      # an iteration begins by admitting
+        self._ann = _annotation(_TICK_NAMES[self._phase])
+        self.t0_ns = self._t_phase = time.monotonic_ns()
+
+    def _end_phase(self) -> int:
+        self._ann.__exit__(None, None, None)
+        now = time.monotonic_ns()
+        self.phase_ns[self._phase] += now - self._t_phase
+        if self._phase == "wait_prefill":
+            self.fence_ns = now
+        return now
+
+    def phase(self, name: str) -> None:
+        if name == self._phase:           # already there: one span, not two
+            return
+        now = self._end_phase()
+        self._ann = _annotation(_TICK_NAMES[name])
+        self._phase, self._t_phase = name, now
+
+    def snapshot(self) -> dict:
+        """phase -> ns so far, the open phase counted up to now: two
+        snapshots differ by exactly the wall between them."""
+        out = dict(self.phase_ns)
+        out[self._phase] += time.monotonic_ns() - self._t_phase
+        return out
+
+    def close(self) -> None:
+        self.t1_ns = self._end_phase()
+        # TraceMe arguments are "k=v,k=v": a value holds no comma
+        self._tick_ann.set_metadata(**self.args)
+        self._tick_ann.__exit__(None, None, None)
+        model = self.args["model"]
+        for name, ns in self.phase_ns.items():
+            if ns:
+                TICK_PHASE_MS_TOTAL.inc(ns / 1e6, model=model, phase=name)
+
+    def as_attrs(self) -> dict:
+        """The record as span attributes (``sched.decode_tick``)."""
+        return {**self.args, "wall_ns": self.t1_ns - self.t0_ns,
+                "phases_ns": {k: v for k, v in self.phase_ns.items() if v}}
+
+
+class _TickLocal(threading.local):
+    record: Optional[TickRecord] = None
+
+
+_TICK = _TickLocal()
+
+
+def tick_open(model: str) -> TickRecord:
+    """Start this thread's tick record, in phase ``admit`` (the batcher
+    worker, once per loop iteration)."""
+    _TICK.record = rec = TickRecord(model)
+    return rec
+
+
+def tick_phase(name: str) -> Optional[TickRecord]:
+    """THE phase helper: on a thread with an open tick record, close the
+    phase under way and open ``name`` (one of TICK_PHASES); returns the
+    record. Elsewhere — the engine driven directly, the baton batcher —
+    one thread-local read and nothing else."""
+    rec = _TICK.record
+    if rec is not None:
+        rec.phase(name)
+    return rec
+
+
+def tick_note(**args: Any) -> None:
+    """Arguments of this thread's open tick, known only where the work
+    happens (the engine's token counts and program key)."""
+    rec = _TICK.record
+    if rec is not None:
+        rec.args.update(args)
+
+
+def tick_close() -> Optional[TickRecord]:
+    rec, _TICK.record = _TICK.record, None
+    if rec is not None:
+        rec.close()
+    return rec
+
+
 WATCHDOG_STALLS = METRICS.counter(
     "quoracle_watchdog_stalls_total",
     "stall-watchdog trips (decode loop made no progress past deadline)")

@@ -22,8 +22,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from quoracle_tpu.infra.telemetry import (
-    DECODE_MS, DECODE_STEP_MS, JIT_COMPILES, PREFILL_MS,
-    PREFILL_TOKENS_PER_S, PREFIX_LOOKUP_MS, TRACER,
+    DECODE_MS, DECODE_STEP_MS, PREFILL_MS, TRACER, tick_note, tick_phase,
 )
 from quoracle_tpu.models.config import ModelConfig
 from quoracle_tpu.models.sampling import sample_tokens
@@ -100,11 +99,12 @@ def grammar_mask(logits: jax.Array, jstate: jax.Array,
     handling. logits [B, V], jstate [B]; jstate < 0 = unconstrained row;
     a dead-end state (vocab gap: no token allowed) permits eos so the row
     stops instead of sampling an all -inf distribution."""
-    allowed = json_table[jnp.clip(jstate, 0, None)] >= 0       # [B, V]
-    none_ok = ~jnp.any(allowed, axis=-1, keepdims=True)
-    eos_hot = (jnp.arange(logits.shape[-1]) == eos_id)[None, :]
-    allowed = allowed | (none_ok & eos_hot) | (jstate < 0)[:, None]
-    return jnp.where(allowed, logits, NEG_INF_LOGITS)
+    with jax.named_scope("grammar_mask"):
+        allowed = json_table[jnp.clip(jstate, 0, None)] >= 0       # [B, V]
+        none_ok = ~jnp.any(allowed, axis=-1, keepdims=True)
+        eos_hot = (jnp.arange(logits.shape[-1]) == eos_id)[None, :]
+        allowed = allowed | (none_ok & eos_hot) | (jstate < 0)[:, None]
+        return jnp.where(allowed, logits, NEG_INF_LOGITS)
 
 
 def _sampling_fns(json_table: Optional[jax.Array], eos_id: int,
@@ -133,6 +133,16 @@ def _sampling_fns(json_table: Optional[jax.Array], eos_id: int,
     return is_stop, mask_logits, advance, constrained
 
 
+def _draw(mask_logits, logits, jstate, rng, temperature, top_p):
+    """One sampling step, under the ``sample`` scope (the grammar mask
+    and the nucleus sort beneath it): split the key, mask, sample.
+    Returns (tokens [B], the carried key)."""
+    with jax.named_scope("sample"):
+        rng, k = jax.random.split(rng)
+        return (sample_tokens(mask_logits(logits, jstate), k, temperature,
+                              top_p), rng)
+
+
 def _first_token(fns, first_logits, rng, temperature, top_p, active,
                  row_limit, json_state, max_new: int, pad_id: int):
     """Shared decode bootstrap: sample token 0 from the prefill logits and
@@ -140,9 +150,8 @@ def _first_token(fns, first_logits, rng, temperature, top_p, active,
     is_stop, mask_logits, advance, constrained = fns
     B = first_logits.shape[0]
     jstate0 = json_state if constrained else jnp.zeros((B,), jnp.int32)
-    rng, k0 = jax.random.split(rng)
-    tok0 = sample_tokens(mask_logits(first_logits, jstate0), k0,
-                         temperature, top_p)
+    tok0, rng = _draw(mask_logits, first_logits, jstate0, rng, temperature,
+                      top_p)
     n0 = jnp.where(active, 1, 0).astype(jnp.int32)
     done0 = ~active | is_stop(tok0) | (n0 >= row_limit)
     # advance on tok0 for every active row (eos self-loops in accept states)
@@ -214,22 +223,24 @@ def decode(
             kv_pos_offset=kv_off,
         )
         logits = project_logits(params, cfg, hidden)
-        rng, k = jax.random.split(rng)
-        nxt = sample_tokens(mask_logits(logits[:, 0, :], jstate), k,
-                            temperature, top_p)
-        nxt = jnp.where(done, pad_id, nxt)
-        out = jax.lax.dynamic_update_slice_in_dim(out, nxt[:, None], i, axis=1)
-        n_emitted = n_emitted + jnp.where(done, 0, 1).astype(jnp.int32)
-        cache = cache._replace(lens=cache.lens + jnp.where(done, 0, 1))
-        jstate = advance(jstate, nxt, done)
-        done = done | is_stop(nxt) | (n_emitted >= row_limit)
+        nxt, rng = _draw(mask_logits, logits[:, 0, :], jstate, rng,
+                         temperature, top_p)
+        with jax.named_scope("row_state"):
+            nxt = jnp.where(done, pad_id, nxt)
+            out = jax.lax.dynamic_update_slice_in_dim(out, nxt[:, None], i,
+                                                      axis=1)
+            n_emitted = n_emitted + jnp.where(done, 0, 1).astype(jnp.int32)
+            cache = cache._replace(lens=cache.lens + jnp.where(done, 0, 1))
+            jstate = advance(jstate, nxt, done)
+            done = done | is_stop(nxt) | (n_emitted >= row_limit)
         return (i + 1, done, nxt, out, n_emitted, cache, rng, jstate)
 
     # Feed the first sampled token through the loop starting at step 1.
     init = (jnp.asarray(1, jnp.int32), done0, tok0, out0, n0, cache, rng,
             jstate0)
-    _, done, _, out, n_emitted, cache, _, jstate = \
-        jax.lax.while_loop(cond, body, init)
+    with jax.named_scope("decode_loop"):
+        _, done, _, out, n_emitted, cache, _, jstate = \
+            jax.lax.while_loop(cond, body, init)
     # jstate returned so chunked continuations (models/scheduler.py) can
     # resume the grammar mid-stream via initial_json_state.
     return out, n_emitted, cache, jstate
@@ -295,23 +306,24 @@ def decode_paged(
             params, cfg, cur[:, None], positions, k_pool, v_pool, tables,
             pool_lens, kv_off, tail_k, tail_v, step=i - 1, shard=shard)
         logits = project_logits(params, cfg, hidden)
-        rng, k = jax.random.split(rng)
-        nxt = sample_tokens(mask_logits(logits[:, 0, :], jstate), k,
-                            temperature, top_p)
-        nxt = jnp.where(done, pad_id, nxt)
-        out = jax.lax.dynamic_update_slice_in_dim(out, nxt[:, None], i,
-                                                  axis=1)
-        n_emitted = n_emitted + jnp.where(done, 0, 1).astype(jnp.int32)
-        lens = lens + jnp.where(done, 0, 1)
-        jstate = advance(jstate, nxt, done)
-        done = done | is_stop(nxt) | (n_emitted >= row_limit)
+        nxt, rng = _draw(mask_logits, logits[:, 0, :], jstate, rng,
+                         temperature, top_p)
+        with jax.named_scope("row_state"):
+            nxt = jnp.where(done, pad_id, nxt)
+            out = jax.lax.dynamic_update_slice_in_dim(out, nxt[:, None], i,
+                                                      axis=1)
+            n_emitted = n_emitted + jnp.where(done, 0, 1).astype(jnp.int32)
+            lens = lens + jnp.where(done, 0, 1)
+            jstate = advance(jstate, nxt, done)
+            done = done | is_stop(nxt) | (n_emitted >= row_limit)
         return (i + 1, done, nxt, out, n_emitted, lens, tail_k, tail_v,
                 rng, jstate)
 
     init = (jnp.asarray(1, jnp.int32), done0, tok0, out0, n0, lens0,
             tail_k0, tail_v0, rng, jstate0)
-    (_, done, _, out, n_emitted, lens, tail_k, tail_v, _, jstate) = \
-        jax.lax.while_loop(cond, body, init)
+    with jax.named_scope("decode_loop"):
+        (_, done, _, out, n_emitted, lens, tail_k, tail_v, _, jstate) = \
+            jax.lax.while_loop(cond, body, init)
     return out, n_emitted, lens, tail_k, tail_v, jstate
 
 
@@ -374,21 +386,22 @@ def decode_ragged(
     def body(carry):
         (i, done, cur, out, n_emitted, lens, kp, vp, ks, vs, rng,
          jstate) = carry
-        live = (~done).astype(jnp.int32)
-        # this step's token writes at buffer slot lens; done rows (and
-        # any row at its page-table edge) drop via the OOB sentinel
-        pg = jnp.take_along_axis(
-            tables, jnp.minimum(lens // page, maxp - 1)[:, None],
-            axis=1)[:, 0]
-        flat = jnp.where(done | (lens // page >= maxp), n_tok,
-                         pg * page + lens % page)
-        meta = jnp.stack([
-            lens + live,              # kv_len incl. the token just written
-            lens - (1 - live),        # qpos0 (done rows: inert block)
-            live,                     # nq
-            jnp.arange(R, dtype=jnp.int32),   # one tq=1 block per row
-        ])
-        positions = lens + kv_off.astype(jnp.int32)
+        with jax.named_scope("row_state"):
+            live = (~done).astype(jnp.int32)
+            # this step's token writes at buffer slot lens; done rows (and
+            # any row at its page-table edge) drop via the OOB sentinel
+            pg = jnp.take_along_axis(
+                tables, jnp.minimum(lens // page, maxp - 1)[:, None],
+                axis=1)[:, 0]
+            flat = jnp.where(done | (lens // page >= maxp), n_tok,
+                             pg * page + lens % page)
+            meta = jnp.stack([
+                lens + live,          # kv_len incl. the token just written
+                lens - (1 - live),    # qpos0 (done rows: inert block)
+                live,                 # nq
+                jnp.arange(R, dtype=jnp.int32),   # one tq=1 block per row
+            ])
+            positions = lens + kv_off.astype(jnp.int32)
         if quant:
             hidden, kp, vp, ks, vs = forward_hidden_ragged(
                 params, cfg, cur[None], positions[None], kp, vp, tables,
@@ -399,16 +412,16 @@ def decode_ragged(
                 params, cfg, cur[None], positions[None], kp, vp, tables,
                 meta, flat, tq=1, interpret=interpret, shard=shard)
         logits = project_logits(params, cfg, hidden)[0]      # [R, V]
-        rng, k = jax.random.split(rng)
-        nxt = sample_tokens(mask_logits(logits, jstate), k, temperature,
-                            top_p)
-        nxt = jnp.where(done, pad_id, nxt)
-        out = jax.lax.dynamic_update_slice_in_dim(out, nxt[:, None], i,
-                                                  axis=1)
-        n_emitted = n_emitted + jnp.where(done, 0, 1).astype(jnp.int32)
-        lens = lens + jnp.where(done, 0, 1)
-        jstate = advance(jstate, nxt, done)
-        done = done | is_stop(nxt) | (n_emitted >= row_limit)
+        nxt, rng = _draw(mask_logits, logits, jstate, rng, temperature,
+                         top_p)
+        with jax.named_scope("row_state"):
+            nxt = jnp.where(done, pad_id, nxt)
+            out = jax.lax.dynamic_update_slice_in_dim(out, nxt[:, None], i,
+                                                      axis=1)
+            n_emitted = n_emitted + jnp.where(done, 0, 1).astype(jnp.int32)
+            lens = lens + jnp.where(done, 0, 1)
+            jstate = advance(jstate, nxt, done)
+            done = done | is_stop(nxt) | (n_emitted >= row_limit)
         return (i + 1, done, nxt, out, n_emitted, lens, kp, vp, ks, vs,
                 rng, jstate)
 
@@ -416,8 +429,11 @@ def decode_ragged(
     # is a valid while_loop carry leaf-less node)
     init = (jnp.asarray(1, jnp.int32), done0, tok0, out0, n0, lens0,
             k_pool, v_pool, k_scale, v_scale, rng, jstate0)
-    (_, done, _, out, n_emitted, lens, k_pool, v_pool, k_scale, v_scale,
-     _, jstate) = jax.lax.while_loop(cond, body, init)
+    # what the loop itself emits — on the TPU a copy of each loop-carried
+    # pool every step — carries ``decode_loop`` and no sub-scope
+    with jax.named_scope("decode_loop"):
+        (_, done, _, out, n_emitted, lens, k_pool, v_pool, k_scale,
+         v_scale, _, jstate) = jax.lax.while_loop(cond, body, init)
     if quant:
         return (out, n_emitted, lens, k_pool, v_pool, k_scale, v_scale,
                 jstate)
@@ -476,6 +492,12 @@ class GenResult:
     # rode, split by real tokens. 0.0 with accounting off or on paths
     # that drive their own jits (v1 batch-1 speculative decoder).
     chip_ms: float = 0.0
+    # Device phase times of a continuous-batcher row (ISSUE 24): the
+    # prefill and decode fences it waited on, summed over the ticks it
+    # rode, from its closed WaitClock (0.0 with the introspect plane
+    # off, and on the paths that return phase times beside the result).
+    prefill_ms: float = 0.0
+    decode_ms: float = 0.0
 
 
 PAGE = 128   # tokens per KV page
@@ -2096,7 +2118,6 @@ class GenerateEngine:
                             # on the wrong image (the digest-keyed
                             # session safeguard, models/runtime.py)
                             and self.cfg.vision is None):
-                        t_pl = time.monotonic()
                         # verify mode: the last K_i positions are the
                         # verify window and must run through the chunk
                         # forward — never be served from reused KV
@@ -2108,9 +2129,6 @@ class GenerateEngine:
                             self._ensure_pool()
                         d = (self.sessions.match_prefix(prompts[i], cap)
                              if cap > 0 else None)
-                        PREFIX_LOOKUP_MS.observe(
-                            (time.monotonic() - t_pl) * 1000,
-                            model=self.cfg.name)
                         if d is not None:
                             sess_rows[i] = d
                             reuse_abs[i] = len(d.tokens)
@@ -2362,8 +2380,8 @@ class GenerateEngine:
                           latency: float) -> None:
         """Per-call histogram observations + first-shape (JIT compile)
         events for this generate (infra/telemetry.py): device phase
-        latencies, per-wave prefill token throughput, per-emitted-token
-        decode time. Pure observation — no RNG, no device work — so
+        latencies, per-emitted-token decode time, and the tick's
+        ``decode_steps`` / ``program`` arguments. Pure observation — no RNG, no device work — so
         temp-0 outputs are bit-identical with telemetry sinks on or off.
         A shape key unseen by this engine marks the call as a first-call
         compile (the wall time is compile-dominated unless the persistent
@@ -2371,9 +2389,6 @@ class GenerateEngine:
         name = self.cfg.name
         PREFILL_MS.observe(self.last_prefill_s * 1000, model=name)
         DECODE_MS.observe(self.last_decode_s * 1000, model=name)
-        if self.last_prefill_s > 0 and self.last_prefill_tokens:
-            PREFILL_TOKENS_PER_S.observe(
-                self.last_prefill_tokens / self.last_prefill_s, model=name)
         steps = max((int(n_emitted[i]) for i in range(n)), default=0)
         if steps > 0 and self.last_decode_s > 0:
             DECODE_STEP_MS.observe(self.last_decode_s * 1000 / steps,
@@ -2387,8 +2402,10 @@ class GenerateEngine:
         self._pending.shape_key = None
         if shape is None:
             shape = (B, T, cache_len, max_new, paged)
+        # the tick's annotation arguments: what ran, under CompileRegistry's
+        # own spelling of the key (TraceMe values hold no comma)
+        tick_note(decode_steps=steps, program="x".join(map(str, shape)))
         if self.compiles.record(shape, latency * 1000):
-            JIT_COMPILES.inc(model=name)
             if self.quantize_kv:
                 # the dequant path's program identity (ISSUE 13): a
                 # storm here is the quantized twin of a compile storm
@@ -2410,8 +2427,7 @@ class GenerateEngine:
         Counters feed Prometheus; the cumulative totals ride
         /api/resources via padding_stats()."""
         from quoracle_tpu.infra.telemetry import (
-            SCHED_PAD_WASTE_RATIO, SCHED_PADDED_TOKENS_TOTAL,
-            SCHED_REAL_TOKENS_TOTAL,
+            SCHED_PADDED_TOKENS_TOTAL, SCHED_REAL_TOKENS_TOTAL,
         )
         name = self.cfg.name
         self.pad_real_tokens += int(real)
@@ -2419,8 +2435,7 @@ class GenerateEngine:
         self.pad_ticks += 1
         SCHED_REAL_TOKENS_TOTAL.inc(int(real), model=name)
         SCHED_PADDED_TOKENS_TOTAL.inc(int(padded), model=name)
-        SCHED_PAD_WASTE_RATIO.set(
-            (padded - real) / padded if padded else 0.0, model=name)
+        tick_note(real_tokens=int(real), padded_tokens=int(padded))
 
     def padding_stats(self) -> dict:
         """Cumulative padding-waste view for /api/resources: what
@@ -2747,25 +2762,30 @@ class GenerateEngine:
                               maxp * page - int(pre_arr[i]))
                 pos = int(pre_arr[i]) + np.arange(max(0, n_chunk))
                 flat[i, :len(pos)] = dst[i, pos // page] * page + pos % page
+            tick_phase("dispatch_prefill")
             last_logits, st.k, st.v = self._step_paged_prefill_direct(
                 self.params, st.k, st.v, put(src, mat), put(tokens, mat),
                 put(pre_arr, row), put(chunk_arr, row), put(off_arr, row),
                 put(flat, mat))
             cache = None
             pool_lens_dev = put(pre_arr + chunk_arr, row)
+            tick_phase("wait_prefill")
             jax.block_until_ready(last_logits)  # phase fence: prefill done
             t_prefill = time.monotonic()
         else:
+            tick_phase("dispatch_prefill")
             last_logits, cache = self._step_paged_prefill(
                 self.params, st.k, st.v, st.k_scale, st.v_scale,
                 put(src, mat), put(tokens, mat),
                 put(pre_arr, row), put(chunk_arr, row), put(off_arr, row))
+            tick_phase("wait_prefill")
             jax.block_until_ready(last_logits)  # phase fence: prefill done
             t_prefill = time.monotonic()
 
         if use_unified or verify is not None:
             pass          # handled above (unified runs its own decode)
         elif use_direct:
+            tick_phase("dispatch_decode")
             # prompt KV → pages (unless the direct prefill already wrote
             # them there), free the working cache, decode straight off the
             # pool (ragged paged attention), then scatter only the
@@ -2782,6 +2802,7 @@ class GenerateEngine:
                     self.params, st.k, st.v, put(dst, mat), pool_lens_dev,
                     put(off_arr, row), last_logits, rng_key, *samp,
                     *json_args, max_new=max_new)
+            tick_phase("wait_decode")
             out = np.asarray(out)
             n_emitted = np.asarray(n_emitted)
             jstate_f = np.asarray(jstate_f)
@@ -2804,6 +2825,7 @@ class GenerateEngine:
             jax.block_until_ready(st.k)
             now = time.monotonic()
         else:
+            tick_phase("dispatch_decode")
             (out, n_emitted, final_lens, st.k, st.v, st.k_scale,
              st.v_scale, _, _, jstate_f) = \
                 self._step_paged_decode(
@@ -2811,11 +2833,13 @@ class GenerateEngine:
                     cache.k, cache.v, cache.lens,
                     put(dst, mat), put(off_arr, row), last_logits, rng_key,
                     *samp, *json_args, max_new=max_new)
+            tick_phase("wait_decode")
             out = np.asarray(out)
             n_emitted = np.asarray(n_emitted)
             jstate_f = np.asarray(jstate_f)
             now = time.monotonic()
 
+        tick_phase("commit")
         lens_host = np.asarray(final_lens)
         for i in range(n):
             sid, pages = store_sids[i], dst_lists[i]
@@ -2882,6 +2906,7 @@ class GenerateEngine:
         collapse assertion. Returns (out, n_emitted, final_lens, jstate_f,
         vout, t_prefill, now) with all row-indexed arrays sized [R] whose
         first ``n`` slots are the batch rows in order."""
+        tick_phase("pack")
         st = self.sessions
         page = st.page
         page_cap = maxp * page
@@ -2975,6 +3000,7 @@ class GenerateEngine:
                     t_prefill, now)
 
         self._pending.shape_key = ("ragged", TB, R, maxp_p2, max_new)
+        tick_phase("dispatch_prefill")
         last_logits, st.k, st.v, st.k_scale, st.v_scale = \
             self._step_paged_ragged(
                 self.params, st.k, st.v, st.k_scale, st.v_scale,
@@ -2982,8 +3008,10 @@ class GenerateEngine:
                 jnp.asarray(flat_pos), jnp.asarray(r_tables),
                 jnp.asarray(bmeta),
                 jnp.asarray(flat_dst), jnp.asarray(last_idx), tq=TQ)
+        tick_phase("wait_prefill")
         jax.block_until_ready(last_logits)  # phase fence: prefill done
         t_prefill = time.monotonic()
+        tick_phase("dispatch_decode")
         (out, n_emitted, final_lens, st.k, st.v, st.k_scale, st.v_scale,
          jstate_f) = \
             self._step_paged_decode_ragged(
@@ -2993,6 +3021,7 @@ class GenerateEngine:
                 rng_key, jnp.asarray(r_temp), jnp.asarray(r_top),
                 jnp.asarray(r_active), jnp.asarray(r_limits), json_table,
                 js_dev, max_new=max_new)
+        tick_phase("wait_decode")
         out = np.asarray(out)
         n_emitted = np.asarray(n_emitted)
         jstate_f = np.asarray(jstate_f)

@@ -991,7 +991,7 @@ class TPUBackend(ModelBackend):
                 usage=Usage(g.n_prompt_tokens, g.n_gen_tokens, cost),
                 latency_ms=latency_ms,
                 # draft/verify interleave: a prefill/decode split is not
-                # meaningful (same convention as continuous mode)
+                # meaningful
                 prefill_ms=0.0, decode_ms=0.0,
                 cached_tokens=getattr(g, "n_cached_tokens", 0),
                 spec_rounds=g.rounds,
@@ -1103,7 +1103,10 @@ class TPUBackend(ModelBackend):
             results[i] = QueryResult(
                 model_spec=spec, text=g.text,
                 usage=Usage(g.n_prompt_tokens, g.n_gen_tokens, cost),
-                latency_ms=latency_ms, prefill_ms=0.0, decode_ms=0.0,
+                latency_ms=latency_ms,
+                # the row's own device fences, from its row record
+                # (models/scheduler.py _finish_row)
+                prefill_ms=g.prefill_ms, decode_ms=g.decode_ms,
                 cached_tokens=g.n_cached_tokens,
                 spec_rounds=getattr(g, "spec_rounds", 0),
                 spec_accepted_tokens=getattr(g, "spec_accepted_tokens",

@@ -27,13 +27,15 @@ def sample_tokens(
     scaled = logits / temp
 
     # Nucleus mask: drop tokens beyond the top-p cumulative mass.
-    sorted_logits = jnp.sort(scaled, axis=-1)[:, ::-1]
-    sorted_probs = jax.nn.softmax(sorted_logits, axis=-1)
-    cum = jnp.cumsum(sorted_probs, axis=-1)
-    # Number of tokens to keep per row (always >= 1).
-    keep = jnp.sum(cum - sorted_probs < top_p[:, None], axis=-1)
-    cutoff = jnp.take_along_axis(sorted_logits, (keep - 1)[:, None], axis=-1)
-    masked = jnp.where(scaled < cutoff, -jnp.inf, scaled)
+    with jax.named_scope("top_p"):
+        sorted_logits = jnp.sort(scaled, axis=-1)[:, ::-1]
+        sorted_probs = jax.nn.softmax(sorted_logits, axis=-1)
+        cum = jnp.cumsum(sorted_probs, axis=-1)
+        # Number of tokens to keep per row (always >= 1).
+        keep = jnp.sum(cum - sorted_probs < top_p[:, None], axis=-1)
+        cutoff = jnp.take_along_axis(sorted_logits, (keep - 1)[:, None],
+                                     axis=-1)
+        masked = jnp.where(scaled < cutoff, -jnp.inf, scaled)
 
     sampled = jax.random.categorical(rng, masked, axis=-1)
     return jnp.where(temperature <= 0, greedy, sampled).astype(jnp.int32)

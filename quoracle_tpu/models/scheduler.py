@@ -68,7 +68,8 @@ from quoracle_tpu.infra import costobs, fleetobs, introspect, treeobs
 from quoracle_tpu.infra.flightrec import FLIGHT
 from quoracle_tpu.infra.telemetry import (
     QOS_ADMIT_WAIT_MS, SCHED_ADMIT_WAIT_MS, SCHED_QUEUE_DEPTH,
-    SCHED_ROWS_TOTAL, SCHED_SLOTS_BUSY, TRACER,
+    SCHED_ROWS_TOTAL, SCHED_SLOTS_BUSY, TRACER, tick_close, tick_note,
+    tick_open, tick_phase,
 )
 from quoracle_tpu.models.generate import GenResult
 from quoracle_tpu.serving.admission import (
@@ -116,6 +117,13 @@ class _Row:
     # span so queue wait is never double-counted in the decomposition.
     trace: Optional[Any] = None
     t_admit: float = 0.0
+    # The row record (ISSUE 24): admission and first token in monotonic
+    # ns — the first token is the prefill fence of the first tick the
+    # row rode (TickRecord.fence_ns) — and the ticks it rode. With the
+    # closed WaitClock they go into introspect's row ring at retire.
+    t_admit_ns: int = 0
+    t_first_token_ns: int = 0
+    ticks: int = 0
     # Chip economics (ISSUE 17): task/decide attribution keys carried
     # down from the consensus layer, and this row's accumulated share
     # of measured device wall across every chunk it rode.
@@ -179,7 +187,7 @@ class ContinuousBatcher:
         self.failed = 0
         self._model = engine.cfg.name
         self._thread = threading.Thread(
-            target=self._loop, name=f"batcher-{engine.cfg.name}",
+            target=self._loop, name=f"qtpu-batcher-{engine.cfg.name}",
             daemon=True)
         self._thread.start()
 
@@ -200,11 +208,12 @@ class ContinuousBatcher:
         decoding must continue from that grammar state, not from the
         block start — exactly the state the chunked loop already threads
         between its own chunks via GenResult.json_state."""
+        t_submit_ns = time.monotonic_ns()
         row = _Row(prompt=list(prompt), temperature=temperature,
                    top_p=top_p, max_new=max(1, max_new_tokens),
                    session_id=session_id or self._own_session_id(),
                    constrain=constrain_json, action_enum=action_enum,
-                   future=Future(), t_submit=time.monotonic(),
+                   future=Future(), t_submit=t_submit_ns / 1e9,
                    priority=int(coerce_priority(priority)),
                    tenant=tenant, deadline_s=deadline_s,
                    json_state=initial_json_state,
@@ -216,7 +225,7 @@ class ContinuousBatcher:
                           if TRACER.active() else None))
         row.owns_session = session_id is None
         if introspect.enabled():
-            row.waits = introspect.WaitClock()
+            row.waits = introspect.WaitClock(t_submit_ns)
         # Per-row admission check: an over-window prompt must fail ONLY
         # its own future — inside a shared chunk the engine's
         # ContextOverflowError would poison every live row's in-flight
@@ -350,13 +359,14 @@ class ContinuousBatcher:
 
     # ------------------------------------------------------------------
 
-    def _admit(self) -> None:
+    def _admit(self) -> int:
         admitted = 0
         while len(self._live) < self.max_slots:
             row = self._policy.pop()
             if row is None:
                 break
-            now = time.monotonic()
+            now_ns = time.monotonic_ns()
+            now = now_ns / 1e9
             # Deadline-aware drop (ISSUE 4): a row whose deadline passed
             # while queued is failed AT ADMIT — decoding tokens nobody
             # will wait for would steal the slot from a live request.
@@ -385,7 +395,7 @@ class ContinuousBatcher:
             SCHED_ADMIT_WAIT_MS.observe(wait_ms, model=self._model)
             QOS_ADMIT_WAIT_MS.observe(wait_ms,
                                       cls=class_name(row.priority))
-            row.t_admit = now
+            row.t_admit, row.t_admit_ns = now, now_ns
             if row.waits is not None:
                 # batch-queue wait = submit→admit minus the admission
                 # call's own wall (already booked as "admission")
@@ -408,35 +418,44 @@ class ContinuousBatcher:
                           live=len(self._live))
         SCHED_QUEUE_DEPTH.set(self._policy.qsize(), model=self._model)
         SCHED_SLOTS_BUSY.set(len(self._live), model=self._model)
+        return admitted
 
     def _loop(self) -> None:
         while not self._stop:
-            self._admit()
+            # One tick record per iteration (ISSUE 24): the phases the
+            # worker passes through from here to tick_close() tile the
+            # iteration, each a TraceAnnotation on this thread's line.
+            tick_open(self._model)
+            admitted = self._admit()
+            n_rows = len(self._live)
+            tick_note(rows=n_rows, admitted=admitted)
             if not self._live:
+                tick_phase("idle")
                 self._wake.wait(timeout=0.2)
                 self._wake.clear()
+                tick_close()
                 continue
-            # Sampled decode-tick span (ISSUE 15 satellite): 1-in-N
-            # ticks (QUORACLE_TRACE_DECODE_SAMPLE, keyed on the
-            # monotonic step counter — deterministic, no RNG) so
-            # serving decode traffic cannot starve consensus traces
-            # out of the bounded span rings.
-            t_tick = (time.monotonic()
-                      if TRACER.active() and fleetobs.sample_tick(
-                          self.steps) else None)
-            n_rows = len(self._live)
+            tick_phase("prepare")
             try:
                 self._live = self._step(self._live)
             except Exception:             # noqa: BLE001 — isolate, don't
                 self._live = self._isolate_failure(self._live)  # nuke all
-            if t_tick is not None:
-                TRACER.emit("sched.decode_tick",
-                            (time.monotonic() - t_tick) * 1000,
-                            model=self._model, rows=n_rows,
-                            step=self.steps)
+            step = self.steps
             self.steps += 1               # watchdog progress signal
             introspect.beat(f"sched.tick:{self._model}")
             self._chaos_tick()
+            rec = tick_close()
+            # Sampled decode-tick span (ISSUE 15 satellite): 1-in-N
+            # ticks (QUORACLE_TRACE_DECODE_SAMPLE, keyed on the
+            # monotonic step counter — deterministic, no RNG) so
+            # serving decode traffic cannot starve consensus traces
+            # out of the bounded span rings. The span IS the tick
+            # record: its phases and arguments ride as attributes.
+            if TRACER.active() and fleetobs.sample_tick(step):
+                attrs = rec.as_attrs()
+                TRACER.emit("sched.decode_tick", attrs["wall_ns"] / 1e6,
+                            ts=time.time() - attrs["wall_ns"] / 1e9,
+                            step=step, **attrs)
         # worker exit (close()): the worker owns _live, so it fails any
         # remaining rows itself — close() only takes over when this
         # thread is confirmed dead
@@ -511,13 +530,21 @@ class ContinuousBatcher:
         """Resolve a finished row's future from its accumulated state and
         account the retirement (shared by the vanilla and speculative
         paths — one retire semantics, zero drift)."""
+        # Wait-state decomposition (ISSUE 18): close the row's wait
+        # ledger at retire — the named waits + exact remainder sum to
+        # the row's observed wall by construction. Closed FIRST: the
+        # result's device phase times come from it (ISSUE 24).
+        t_done_ns = time.monotonic_ns()
+        t_done = t_done_ns / 1e9
+        closed = row.waits.close(t_done_ns) if row.waits is not None else None
+        waits = closed["waits_ns"] if closed is not None else {}
         if not row.future.done():           # close() may have failed it
             row.future.set_result(GenResult(
                 token_ids=list(row.emitted),
                 text=self.engine.tokenizer.decode(row.emitted),
                 n_prompt_tokens=len(row.prompt),
                 n_gen_tokens=len(row.emitted),
-                latency_s=time.monotonic() - row.t_submit,
+                latency_s=t_done - row.t_submit,
                 finish_reason=finish_reason,
                 n_cached_tokens=row.n_cached_first or 0,
                 json_state=json_state,
@@ -525,25 +552,33 @@ class ContinuousBatcher:
                 spec_drafted_tokens=row.spec_drafted,
                 spec_accepted_tokens=row.spec_accepted,
                 chip_ms=round(row.chip_ms, 6),
+                prefill_ms=waits.get("device_prefill", 0) / 1e6,
+                decode_ms=waits.get("device_decode", 0) / 1e6,
             ))
         self._drop_row_sessions(row)
         self.retired += 1
         # error-budget score (ISSUE 17): a retire past its deadline is
         # an SLO miss; everything else is budget-ok
-        t_done = time.monotonic()
         costobs.BUDGET.record(
             row.tenant, class_name(row.priority),
             ok=not (row.deadline_s is not None and t_done > row.deadline_s),
             t=t_done)
         SCHED_ROWS_TOTAL.inc(model=self._model, status="retired")
-        # Wait-state decomposition (ISSUE 18): close the row's wait
-        # ledger at retire — the named waits + exact remainder sum to
-        # the row's observed wall by construction — and ride it on the
-        # decode span so /api/timeline aggregates it per trace.
-        closed = None
-        if row.waits is not None:
-            closed = row.waits.close()
-            introspect.record_row_waits(self._model, closed)
+        # The closed ledger rides the decode span, so /api/timeline
+        # aggregates it per trace, and goes with the row's stamps and
+        # counts into introspect's row ring (/api/profile ``rows``).
+        if closed is not None:
+            introspect.record_row_waits(self._model, closed, row={
+                "session": row.session_id,
+                "t_submit_ns": row.waits.t0_ns,
+                "t_admit_ns": row.t_admit_ns,
+                "t_first_token_ns": row.t_first_token_ns,
+                "t_done_ns": t_done_ns,
+                "ticks": row.ticks,
+                "prompt_tokens": len(row.prompt),
+                "cached_tokens": row.n_cached_first or 0,
+                "emitted_tokens": len(row.emitted),
+                "finish": finish_reason})
             introspect.beat(f"sched.retired:{self._model}")
             # Session-graph rollup (ISSUE 20): the same exact-sum wait
             # decomposition, booked to the tree node this row belongs
@@ -615,6 +650,9 @@ class ContinuousBatcher:
         plain = [r for r in rows if id(r) not in spec_ids]
         still = self._plain_step(plain) if plain else []
         for row in spec_rows:
+            row.ticks += 1
+            if not row.t_first_token_ns:    # no fence of its own yet
+                row.t_first_token_ns = time.monotonic_ns()
             fin = finishes.get(id(row))
             finished = (fin == "stop"
                         or len(row.emitted) >= row.max_new
@@ -667,18 +705,31 @@ class ContinuousBatcher:
         return (str(row.tenant or "-"), class_name(row.priority),
                 str(row.task_id or "-"), str(row.decide or "-"))
 
-    def _book_step_waits(self, rows: list, step_ns: int) -> None:
-        """Partition one device call's wall across its rows' wait
+    def _book_step_waits(self, rows: list, step_ns: int,
+                         device_ns: Optional[tuple] = None) -> None:
+        """Partition one engine call's wall across its rows' wait
         ledgers (ISSUE 18). Every row in the batch waits the WHOLE call
-        concurrently, so each is booked the full wall — split into the
-        KV-restore and contended-lock walls this thread accumulated
-        inside the call, with the rest as device dispatch."""
+        concurrently, so each is booked the full wall — the KV-restore
+        and contended-lock walls this thread accumulated inside the
+        call, and the rest split by the tick record (ISSUE 24):
+        ``device_ns`` is its (wait_prefill, wait_decode) time over the
+        call, what remains is ``host`` (prepare, pack, dispatch,
+        commit). The speculative sub-tick has no such split yet and
+        books the rest as one ``dispatch`` lump."""
         restore_ns, lock_ns = introspect.drain_inner_waits()
-        dispatch_ns = max(0, step_ns - restore_ns - lock_ns)
+        rest = max(0, step_ns - restore_ns - lock_ns)
+        if device_ns is None:
+            split = {"dispatch": rest}
+        else:
+            prefill_ns, decode_ns = device_ns
+            split = {"host": max(0, rest - prefill_ns - decode_ns),
+                     "device_prefill": prefill_ns,
+                     "device_decode": decode_ns}
         for r in rows:
             if r.waits is None:
                 continue
-            r.waits.note("dispatch", dispatch_ns)
+            for state, ns in split.items():
+                r.waits.note(state, ns)
             r.waits.note("kv_restore", restore_ns)
             r.waits.note("lock", lock_ns)
 
@@ -686,10 +737,9 @@ class ContinuousBatcher:
         prompts = [r.prompt + r.emitted for r in rows]
         budgets = [min(self.chunk, r.max_new - len(r.emitted))
                    for r in rows]
-        t_step = (time.monotonic_ns()
-                  if any(r.waits is not None for r in rows) else None)
-        if t_step is not None:
-            introspect.drain_inner_waits()
+        tick = tick_phase("prepare")      # always inside _loop's tick
+        before = tick.snapshot()
+        introspect.drain_inner_waits()
         # declare this chunk's attribution keys on the worker thread —
         # the engine's charge site consumes them (one call, one set)
         costobs.set_row_keys([self._row_key(r) for r in rows])
@@ -703,10 +753,21 @@ class ContinuousBatcher:
             action_enums=[r.action_enum for r in rows],
             initial_json_state=[r.json_state for r in rows],
         )
-        if t_step is not None:
-            self._book_step_waits(rows, time.monotonic_ns() - t_step)
+        # the engine call's wall, split by the tick record: two
+        # snapshots differ by exactly the time between them
+        after = tick.snapshot()
+        self._book_step_waits(
+            rows, sum(after.values()) - sum(before.values()),
+            (after["wait_prefill"] - before["wait_prefill"],
+             after["wait_decode"] - before["wait_decode"]))
+        tick_phase("retire")
+        # a path with no prefill fence of its own: the call's end
+        fence_ns = tick.fence_ns or time.monotonic_ns()
         still = []
         for row, res, budget in zip(rows, results, budgets):
+            row.ticks += 1
+            if not row.t_first_token_ns:
+                row.t_first_token_ns = fence_ns
             if row.n_cached_first is None:
                 row.n_cached_first = res.n_cached_tokens
             row.chip_ms += res.chip_ms

@@ -12,6 +12,21 @@ math locally, SURVEY.md §2.8):
     ``lax.dynamic_update_slice_in_dim``; attention masks by integer lengths,
     so the whole step is shape-static under jit;
   * the same forward serves prefill (T = chunk) and decode (T = 1).
+
+Every forward names its device work with ``jax.named_scope`` (ISSUE 24),
+identically: ``embed``, ``layers`` around the scan and inside the layer
+body ``qkv``, ``rope``, ``kv_write``, ``attn`` (within it ``kv_layout``,
+the pools' re-layout for a paged kernel, ops/paged_attention.py),
+``attn_out``, ``mlp``; then ``final_norm`` and, in project_logits,
+``head``. The decode loops (models/generate.py) add ``decode_loop`` around
+the while loop, ``sample`` (``grammar_mask``, ``top_p``) and ``row_state``.
+The names reach the profiler as the operation's ``tf_op`` path, which the
+benchmark's scope metrics read (benchmark/scopes.json). What the scan
+itself emits to slice a layer's pool out and stack it back carries
+``layers`` and no sub-scope, and the loop-carried pools' copies
+``decode_loop`` and no sub-scope: those remainders, with ``kv_write`` and
+``kv_layout``, are the pool move. Scopes are metadata only — the computed
+values are bit-identical with and without them.
 """
 
 from __future__ import annotations
@@ -199,6 +214,16 @@ def _embed_lookup(params: dict, tokens: jax.Array) -> jax.Array:
     return e[tokens]
 
 
+def _embed(params: dict, cfg: ModelConfig, tokens: jax.Array) -> jax.Array:
+    """Token embeddings as the stack takes them (scope ``embed``)."""
+    with jax.named_scope("embed"):
+        x = _embed_lookup(params, tokens)   # gather: [B, T, D]
+        if cfg.scale_embeddings:
+            x = (x.astype(jnp.float32) * (cfg.dim ** 0.5)).astype(x.dtype)
+        return x
+
+
+@jax.named_scope("mlp")
 def _mlp(x: jax.Array, p: dict, cfg: ModelConfig) -> jax.Array:
     """The shared MLP block: rmsnorm → gate·up → down, weights
     dequantized on the fly when quantized (models/quant.py). One
@@ -212,26 +237,41 @@ def _mlp(x: jax.Array, p: dict, cfg: ModelConfig) -> jax.Array:
                           dequant_weight(p["w_down"], h.dtype))
 
 
-def _qkv(x: jax.Array, p: dict, cfg: ModelConfig, B: int,
-         T: int) -> tuple[jax.Array, jax.Array, jax.Array]:
+def _qkv(x: jax.Array, p: dict, cfg: ModelConfig, B: int, T: int,
+         positions: jax.Array) -> tuple[jax.Array, jax.Array, jax.Array]:
     """The shared attention-input block: rmsnorm → q/k/v projections
     (+ optional bias) reshaped to head layout, weights dequantized on
-    the fly when quantized."""
-    h = rmsnorm(x, p["attn_norm"], cfg.norm_eps, cfg.rmsnorm_plus_one)
-    q = jnp.einsum("btd,dh->bth", h, dequant_weight(p["wq"], h.dtype))
-    k = jnp.einsum("btd,dh->bth", h, dequant_weight(p["wk"], h.dtype))
-    v = jnp.einsum("btd,dh->bth", h, dequant_weight(p["wv"], h.dtype))
-    if cfg.attn_bias:               # Qwen2-style QKV biases
-        q, k, v = q + p["bq"], k + p["bk"], v + p["bv"]
-    q = q.reshape(B, T, cfg.n_heads, cfg.head_dim)
-    k = k.reshape(B, T, cfg.n_kv_heads, cfg.head_dim)
-    v = v.reshape(B, T, cfg.n_kv_heads, cfg.head_dim)
+    the fly when quantized (scope ``qkv``), then rotary embedding of q
+    and k at ``positions`` (scope ``rope``)."""
+    with jax.named_scope("qkv"):
+        h = rmsnorm(x, p["attn_norm"], cfg.norm_eps, cfg.rmsnorm_plus_one)
+        q = jnp.einsum("btd,dh->bth", h, dequant_weight(p["wq"], h.dtype))
+        k = jnp.einsum("btd,dh->bth", h, dequant_weight(p["wk"], h.dtype))
+        v = jnp.einsum("btd,dh->bth", h, dequant_weight(p["wv"], h.dtype))
+        if cfg.attn_bias:               # Qwen2-style QKV biases
+            q, k, v = q + p["bq"], k + p["bk"], v + p["bv"]
+        q = q.reshape(B, T, cfg.n_heads, cfg.head_dim)
+        k = k.reshape(B, T, cfg.n_kv_heads, cfg.head_dim)
+        v = v.reshape(B, T, cfg.n_kv_heads, cfg.head_dim)
+    with jax.named_scope("rope"):
+        q = rope(q, positions, cfg.rope_theta, cfg.rope_scaling)
+        k = rope(k, positions, cfg.rope_theta, cfg.rope_scaling)
     return q, k, v
 
 
-def _wo(p: dict, cfg: ModelConfig, dtype) -> jax.Array:
-    return dequant_weight(p["wo"], dtype).reshape(
+@jax.named_scope("attn_out")
+def _attn_out(x: jax.Array, attn: jax.Array, p: dict,
+              cfg: ModelConfig) -> jax.Array:
+    """Residual + output projection of the attended heads."""
+    wo = dequant_weight(p["wo"], x.dtype).reshape(
         cfg.n_heads, cfg.head_dim, cfg.dim)
+    return x + jnp.einsum("bthd,hdD->btD", attn, wo)
+
+
+@jax.named_scope("final_norm")
+def _final_norm(x: jax.Array, params: dict, cfg: ModelConfig) -> jax.Array:
+    return rmsnorm(x, params["final_norm"], cfg.norm_eps,
+                   cfg.rmsnorm_plus_one)
 
 
 def forward_hidden(
@@ -273,9 +313,7 @@ def forward_hidden(
     if input_embeds is not None:
         x = input_embeds                # prepared by the caller (VLM)
     else:
-        x = _embed_lookup(params, tokens)   # gather: [B, T, D]
-        if cfg.scale_embeddings:
-            x = (x.astype(jnp.float32) * (cfg.dim ** 0.5)).astype(x.dtype)
+        x = _embed(params, cfg, tokens)
 
     # Offsets are per-row; rows share one buffer write position only when all
     # offsets are equal. We write per-row with a vmap'd dynamic slice.
@@ -285,40 +323,43 @@ def forward_hidden(
 
     def layer_body(x, scanned):
         p, k_buf, v_buf = scanned  # p: one layer's params; bufs: [B, S, kv, hd]
-        q, k, v = _qkv(x, p, cfg, B, T)
-        q = rope(q, positions, cfg.rope_theta, cfg.rope_scaling)
-        k = rope(k, positions, cfg.rope_theta, cfg.rope_scaling)
+        q, k, v = _qkv(x, p, cfg, B, T, positions)
 
-        k_buf = jax.vmap(write_row)(k_buf, k, write_offset)
-        v_buf = jax.vmap(write_row)(v_buf, v, write_offset)
+        with jax.named_scope("kv_write"):
+            k_buf = jax.vmap(write_row)(k_buf, k, write_offset)
+            v_buf = jax.vmap(write_row)(v_buf, v, write_offset)
 
-        if ring is not None:
-            # Sequence-parallel prefill: the chunk IS the whole (fresh)
-            # prompt, so attention is chunk-vs-chunk — K/V shards rotate
-            # the ring while each device keeps its Q shard (SURVEY §5
-            # long-context; ops/ring_attention.py).
-            from quoracle_tpu.ops.ring_attention import ring_attend
-            mesh_, seq_ax, batch_ax, head_ax = ring
-            attn = ring_attend(mesh_, q, k, v, kv_len=kv_lens,
-                               axis_name=seq_ax,
-                               sliding_window=cfg.sliding_window,
-                               batch_axis=batch_ax, head_axis=head_ax)
-        else:
-            # attend_auto: pallas flash kernel for long prefill chunks on
-            # TPU, dense fused XLA otherwise (decode steps, CPU tests).
-            from quoracle_tpu.ops.flash_attention import attend_auto
-            attn = attend_auto(q, k_buf, v_buf, positions,
-                               kv_len=kv_lens,
-                               sliding_window=cfg.sliding_window,
-                               kv_pos_offset=kv_pos_offset, shard=shard)
-        x = x + jnp.einsum("bthd,hdD->btD", attn, _wo(p, cfg, x.dtype))
+        with jax.named_scope("attn"):
+            if ring is not None:
+                # Sequence-parallel prefill: the chunk IS the whole
+                # (fresh) prompt, so attention is chunk-vs-chunk — K/V
+                # shards rotate the ring while each device keeps its Q
+                # shard (SURVEY §5 long-context; ops/ring_attention.py).
+                from quoracle_tpu.ops.ring_attention import ring_attend
+                mesh_, seq_ax, batch_ax, head_ax = ring
+                attn = ring_attend(mesh_, q, k, v, kv_len=kv_lens,
+                                   axis_name=seq_ax,
+                                   sliding_window=cfg.sliding_window,
+                                   batch_axis=batch_ax, head_axis=head_ax)
+            else:
+                # attend_auto: pallas flash kernel for long prefill
+                # chunks on TPU, dense fused XLA otherwise (decode
+                # steps, CPU tests).
+                from quoracle_tpu.ops.flash_attention import attend_auto
+                attn = attend_auto(q, k_buf, v_buf, positions,
+                                   kv_len=kv_lens,
+                                   sliding_window=cfg.sliding_window,
+                                   kv_pos_offset=kv_pos_offset,
+                                   shard=shard)
+        x = _attn_out(x, attn, p, cfg)
         x = _mlp(x, p, cfg)
         return x, (k_buf, v_buf)
 
-    x, (new_k, new_v) = jax.lax.scan(layer_body, x, (params["layers"], cache.k, cache.v))
-
-    x = rmsnorm(x, params["final_norm"], cfg.norm_eps, cfg.rmsnorm_plus_one)
-    return x, KVCache(k=new_k, v=new_v, lens=cache.lens)
+    with jax.named_scope("layers"):
+        x, (new_k, new_v) = jax.lax.scan(
+            layer_body, x, (params["layers"], cache.k, cache.v))
+    return (_final_norm(x, params, cfg),
+            KVCache(k=new_k, v=new_v, lens=cache.lens))
 
 
 def forward_hidden_paged(
@@ -344,31 +385,30 @@ def forward_hidden_paged(
     new tail_k, new tail_v)."""
     from quoracle_tpu.ops.paged_attention import paged_decode_attend
     B, T = tokens.shape
-    x = _embed_lookup(params, tokens)
-    if cfg.scale_embeddings:
-        x = (x.astype(jnp.float32) * (cfg.dim ** 0.5)).astype(x.dtype)
+    x = _embed(params, cfg, tokens)
 
     def layer_body(x, scanned):
         p, kp, vp, tk, tv = scanned
-        q, k, v = _qkv(x, p, cfg, B, T)
-        q = rope(q, positions, cfg.rope_theta, cfg.rope_scaling)
-        k = rope(k, positions, cfg.rope_theta, cfg.rope_scaling)
+        q, k, v = _qkv(x, p, cfg, B, T, positions)
         # all rows write the same tail slot (done rows deposit junk there;
         # the causal mask excludes it — their frozen q_pos precedes it)
-        tk = jax.lax.dynamic_update_slice_in_dim(tk, k, step, axis=1)
-        tv = jax.lax.dynamic_update_slice_in_dim(tv, v, step, axis=1)
-        attn = paged_decode_attend(
-            q, kp, vp, tables, pool_lens, kv_off, tk, tv,
-            tail_len=step + 1, q_pos=positions[:, 0],
-            sliding_window=cfg.sliding_window, shard=shard)
-        x = x + jnp.einsum("bthd,hdD->btD", attn, _wo(p, cfg, x.dtype))
+        with jax.named_scope("kv_write"):
+            tk = jax.lax.dynamic_update_slice_in_dim(tk, k, step, axis=1)
+            tv = jax.lax.dynamic_update_slice_in_dim(tv, v, step, axis=1)
+        with jax.named_scope("attn"):
+            attn = paged_decode_attend(
+                q, kp, vp, tables, pool_lens, kv_off, tk, tv,
+                tail_len=step + 1, q_pos=positions[:, 0],
+                sliding_window=cfg.sliding_window, shard=shard)
+        x = _attn_out(x, attn, p, cfg)
         x = _mlp(x, p, cfg)
         return x, (tk, tv)
 
-    x, (new_tk, new_tv) = jax.lax.scan(
-        layer_body, x, (params["layers"], k_pool, v_pool, tail_k, tail_v))
-    x = rmsnorm(x, params["final_norm"], cfg.norm_eps, cfg.rmsnorm_plus_one)
-    return x, new_tk, new_tv
+    with jax.named_scope("layers"):
+        x, (new_tk, new_tv) = jax.lax.scan(
+            layer_body, x,
+            (params["layers"], k_pool, v_pool, tail_k, tail_v))
+    return _final_norm(x, params, cfg), new_tk, new_tv
 
 
 def forward_hidden_paged_prefill(
@@ -398,36 +438,35 @@ def forward_hidden_paged_prefill(
     from quoracle_tpu.ops.paged_attention import paged_prefill_merge
     B, T = tokens.shape
     n_tok = k_pool.shape[1] * k_pool.shape[2]
-    x = _embed_lookup(params, tokens)
-    if cfg.scale_embeddings:
-        x = (x.astype(jnp.float32) * (cfg.dim ** 0.5)).astype(x.dtype)
+    x = _embed(params, cfg, tokens)
 
     def layer_body(x, scanned):
         p, kp, vp = scanned          # kp/vp: [n_pages, page, kv, hd]
-        q, k, v = _qkv(x, p, cfg, B, T)
-        q = rope(q, positions, cfg.rope_theta, cfg.rope_scaling)
-        k = rope(k, positions, cfg.rope_theta, cfg.rope_scaling)
-        attn = paged_prefill_merge(
-            q, k.astype(kp.dtype), v.astype(vp.dtype), kp, vp, src_tables,
-            prefix_lens, chunk_lens, sliding_window=cfg.sliding_window,
-            interpret=interpret, shard=shard)
+        q, k, v = _qkv(x, p, cfg, B, T, positions)
+        with jax.named_scope("attn"):
+            attn = paged_prefill_merge(
+                q, k.astype(kp.dtype), v.astype(vp.dtype), kp, vp,
+                src_tables, prefix_lens, chunk_lens,
+                sliding_window=cfg.sliding_window, interpret=interpret,
+                shard=shard)
         # chunk KV → dst pages in place (padding/overflow slots carry the
         # OOB sentinel and drop). The attention above read the pool BEFORE
         # this write; chunk↔chunk attention used the dense piece, so
         # nothing this layer needs re-reading.
-        kf = kp.reshape(n_tok, *kp.shape[2:])
-        vf = vp.reshape(n_tok, *vp.shape[2:])
-        kf = kf.at[flat_dst].set(k.astype(kp.dtype), mode="drop")
-        vf = vf.at[flat_dst].set(v.astype(vp.dtype), mode="drop")
-        x = x + jnp.einsum("bthd,hdD->btD", attn.astype(x.dtype),
-                           _wo(p, cfg, x.dtype))
+        with jax.named_scope("kv_write"):
+            kf = kp.reshape(n_tok, *kp.shape[2:])
+            vf = vp.reshape(n_tok, *vp.shape[2:])
+            kf = kf.at[flat_dst].set(k.astype(kp.dtype), mode="drop")
+            vf = vf.at[flat_dst].set(v.astype(vp.dtype), mode="drop")
+            kp2, vp2 = kf.reshape(kp.shape), vf.reshape(vp.shape)
+        x = _attn_out(x, attn.astype(x.dtype), p, cfg)
         x = _mlp(x, p, cfg)
-        return x, (kf.reshape(kp.shape), vf.reshape(vp.shape))
+        return x, (kp2, vp2)
 
-    x, (new_k, new_v) = jax.lax.scan(
-        layer_body, x, (params["layers"], k_pool, v_pool))
-    x = rmsnorm(x, params["final_norm"], cfg.norm_eps, cfg.rmsnorm_plus_one)
-    return x, new_k, new_v
+    with jax.named_scope("layers"):
+        x, (new_k, new_v) = jax.lax.scan(
+            layer_body, x, (params["layers"], k_pool, v_pool))
+    return _final_norm(x, params, cfg), new_k, new_v
 
 
 def forward_hidden_ragged(
@@ -470,9 +509,7 @@ def forward_hidden_ragged(
     n_tok = n_pages * page
     KV = cfg.n_kv_heads
     quant = k_scale is not None
-    x = _embed_lookup(params, tokens)
-    if cfg.scale_embeddings:
-        x = (x.astype(jnp.float32) * (cfg.dim ** 0.5)).astype(x.dtype)
+    x = _embed(params, cfg, tokens)
 
     def layer_body(x, scanned):
         if quant:
@@ -480,54 +517,56 @@ def forward_hidden_ragged(
         else:
             p, kp, vp = scanned          # kp/vp: [n_pages, page, kv, hd]
             ks = vs = None
-        q, k, v = _qkv(x, p, cfg, B, Tp)
-        q = rope(q, positions, cfg.rope_theta, cfg.rope_scaling)
-        k = rope(k, positions, cfg.rope_theta, cfg.rope_scaling)
+        q, k, v = _qkv(x, p, cfg, B, Tp, positions)
         # KV → pages BEFORE attention (padding/overflow slots carry the
         # OOB sentinel and drop): intra-chunk visibility is then pure
         # causal masking inside the one kernel — no dense second piece.
-        kf = kp.reshape(n_tok, *kp.shape[2:])
-        vf = vp.reshape(n_tok, *vp.shape[2:])
-        if quant:
-            kq, ks_new = kv_quant(k[0])          # [Tp, KV, hd] / [Tp, KV]
-            vq, vs_new = kv_quant(v[0])
-            kf = kf.at[flat_dst].set(kq, mode="drop")
-            vf = vf.at[flat_dst].set(vq, mode="drop")
-            # scale slot for token t, head j in the [n_pages, KV, page]
-            # pool: ((pid·KV)+j)·page + off — OOB flat_dst (pid =
-            # n_pages) stays OOB and drops
-            pid, off = flat_dst // page, flat_dst % page
-            sidx = ((pid[:, None] * KV
-                     + jnp.arange(KV, dtype=jnp.int32)[None, :]) * page
-                    + off[:, None])              # [Tp, KV]
-            ks = ks.reshape(-1).at[sidx].set(
-                ks_new, mode="drop").reshape(ks.shape)
-            vs = vs.reshape(-1).at[sidx].set(
-                vs_new, mode="drop").reshape(vs.shape)
-        else:
-            kf = kf.at[flat_dst].set(k[0].astype(kp.dtype), mode="drop")
-            vf = vf.at[flat_dst].set(v[0].astype(vp.dtype), mode="drop")
-        kp2 = kf.reshape(kp.shape)
-        vp2 = vf.reshape(vp.shape)
-        attn = ragged_attend_auto(
-            q[0], kp2, vp2, row_tables, block_meta, tq=tq,
-            sliding_window=cfg.sliding_window, interpret=interpret,
-            shard=shard, k_scale=ks, v_scale=vs)[None]   # [1, Tp, H, hd]
-        x = x + jnp.einsum("bthd,hdD->btD", attn.astype(x.dtype),
-                           _wo(p, cfg, x.dtype))
+        with jax.named_scope("kv_write"):
+            kf = kp.reshape(n_tok, *kp.shape[2:])
+            vf = vp.reshape(n_tok, *vp.shape[2:])
+            if quant:
+                kq, ks_new = kv_quant(k[0])      # [Tp, KV, hd] / [Tp, KV]
+                vq, vs_new = kv_quant(v[0])
+                kf = kf.at[flat_dst].set(kq, mode="drop")
+                vf = vf.at[flat_dst].set(vq, mode="drop")
+                # scale slot for token t, head j in the [n_pages, KV,
+                # page] pool: ((pid·KV)+j)·page + off — OOB flat_dst
+                # (pid = n_pages) stays OOB and drops
+                pid, off = flat_dst // page, flat_dst % page
+                sidx = ((pid[:, None] * KV
+                         + jnp.arange(KV, dtype=jnp.int32)[None, :]) * page
+                        + off[:, None])          # [Tp, KV]
+                ks = ks.reshape(-1).at[sidx].set(
+                    ks_new, mode="drop").reshape(ks.shape)
+                vs = vs.reshape(-1).at[sidx].set(
+                    vs_new, mode="drop").reshape(vs.shape)
+            else:
+                kf = kf.at[flat_dst].set(k[0].astype(kp.dtype),
+                                         mode="drop")
+                vf = vf.at[flat_dst].set(v[0].astype(vp.dtype),
+                                         mode="drop")
+            kp2 = kf.reshape(kp.shape)
+            vp2 = vf.reshape(vp.shape)
+        with jax.named_scope("attn"):
+            attn = ragged_attend_auto(
+                q[0], kp2, vp2, row_tables, block_meta, tq=tq,
+                sliding_window=cfg.sliding_window, interpret=interpret,
+                shard=shard, k_scale=ks, v_scale=vs)[None]  # [1,Tp,H,hd]
+        x = _attn_out(x, attn.astype(x.dtype), p, cfg)
         x = _mlp(x, p, cfg)
         return x, ((kp2, vp2, ks, vs) if quant else (kp2, vp2))
 
+    with jax.named_scope("layers"):
+        if quant:
+            x, (new_k, new_v, new_ks, new_vs) = jax.lax.scan(
+                layer_body, x,
+                (params["layers"], k_pool, v_pool, k_scale, v_scale))
+        else:
+            x, (new_k, new_v) = jax.lax.scan(
+                layer_body, x, (params["layers"], k_pool, v_pool))
+    x = _final_norm(x, params, cfg)
     if quant:
-        x, (new_k, new_v, new_ks, new_vs) = jax.lax.scan(
-            layer_body, x,
-            (params["layers"], k_pool, v_pool, k_scale, v_scale))
-        x = rmsnorm(x, params["final_norm"], cfg.norm_eps,
-                    cfg.rmsnorm_plus_one)
         return x, new_k, new_v, new_ks, new_vs
-    x, (new_k, new_v) = jax.lax.scan(
-        layer_body, x, (params["layers"], k_pool, v_pool))
-    x = rmsnorm(x, params["final_norm"], cfg.norm_eps, cfg.rmsnorm_plus_one)
     return x, new_k, new_v
 
 
@@ -538,16 +577,17 @@ def project_logits(params: dict, cfg: ModelConfig, hidden: jax.Array) -> jax.Arr
     projecting — at llama-3-8b scale a full [B, 8192, 128256] fp32 logits
     tensor is ~4 GB/row and would blow HBM for a value that's 99.99% discarded.
     """
-    if cfg.tie_embeddings:
-        head = dequant_weight(params["embed"], jnp.float32).T
-    else:
-        head = dequant_weight(params["lm_head"], jnp.float32)
-    logits = jnp.einsum("btd,dv->btv", hidden.astype(jnp.float32),
-                        head.astype(jnp.float32))
-    if cfg.final_logit_softcap is not None:
-        c = cfg.final_logit_softcap
-        logits = c * jnp.tanh(logits / c)
-    return logits
+    with jax.named_scope("head"):
+        if cfg.tie_embeddings:
+            head = dequant_weight(params["embed"], jnp.float32).T
+        else:
+            head = dequant_weight(params["lm_head"], jnp.float32)
+        logits = jnp.einsum("btd,dv->btv", hidden.astype(jnp.float32),
+                            head.astype(jnp.float32))
+        if cfg.final_logit_softcap is not None:
+            c = cfg.final_logit_softcap
+            logits = c * jnp.tanh(logits / c)
+        return logits
 
 
 def forward(params: dict, cfg: ModelConfig, tokens: jax.Array,

@@ -175,6 +175,7 @@ def flash_attend(
         ),
         out_shape=jax.ShapeDtypeStruct((b, n_heads, t_p, hd_p), q.dtype),
         interpret=interpret,
+        name="flash_attend",    # pinned: the kernel's name in a trace
     )(jnp.stack([kv_len.astype(jnp.int32),
                  (jnp.zeros_like(kv_len, jnp.int32)
                   if kv_pos_offset is None

@@ -457,7 +457,7 @@ def test_profile_payload_shape_and_gate():
     assert out["enabled"] is True
     assert out["heartbeats"]["x.probe"] == 1
     assert set(out) == {"enabled", "profiler", "heartbeats", "stalls",
-                        "waits"}
+                        "waits", "rows"}
     assert "hz" in out["profiler"] and "windows" in out["profiler"]
     assert "watches" in out["stalls"]
     json.dumps(out)                       # wire/HTTP serializable

@@ -71,6 +71,25 @@ def test_ragged_kernel_compiles(on_v5e, tq, nb, rows, quant):
     compiles(fn, *args)
 
 
+def test_compiled_ragged_kernel_carries_its_pinned_name(on_v5e):
+    """A profiler trace shows the kernel as ``%ragged_attend.<n>``, under
+    the ``pallas_call``'s explicit ``name`` (ISSUE 24): the benchmark's
+    metric files match on it, and the pools' re-layout for the kernel
+    carries its own scope, ``kv_layout``."""
+    import re
+    S = on_v5e
+    pool = S((N_PAGES, PAGE, KV, HD), jnp.bfloat16)
+    text = jax.jit(functools.partial(
+        pa.ragged_attend, tq=1, sliding_window=WINDOW)).lower(
+        S((8, H, HD), jnp.bfloat16), pool, pool, S((8, 128), jnp.int32),
+        S((4, 8), jnp.int32)).compile().as_text()
+    call = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    assert len(call) == 1
+    assert re.match(r"\s*%ragged_attend(\.\d+)? = ", call[0])
+    assert "/ragged_attend/pallas_call" in call[0]
+    assert "kv_layout/reshape" in text
+
+
 def test_ragged_kernel_compiles_at_every_catalog_geometry(on_v5e):
     """The dispatcher routes every paged model to this kernel: all head
     geometries in the catalog must lower, prefill and decode."""

@@ -1,0 +1,515 @@
+"""One trace, one clock (ISSUE 24): the batcher's tick record, the row
+record, and the names on the device side.
+
+  * a tick's phases come from one fixed tuple and sum EXACTLY to its wall
+    (opening a phase closes the one before), with or without a profiler
+    session; outside a tick the helper does nothing;
+  * a ``jax.profiler`` trace holds ``qtpu.tick`` and ``qtpu.tick.<phase>``
+    on ONE thread line, the tick carrying its arguments;
+  * the one record feeds the phase counter, the rows' WaitClocks
+    (``host`` / ``device_prefill`` / ``device_decode``, sum-to-wall kept),
+    the sampled ``sched.decode_tick`` span and ``QueryResult.prefill_ms /
+    decode_ms``;
+  * a retired row's stamps are ordered and land in introspect's row ring;
+  * every forward names its device work (``jax.named_scope``) and every
+    ``pallas_call`` carries its pinned ``name``;
+  * read-only: temp-0 output is bit-identical with a profiler session
+    open and closed;
+  * scope names are part of the persistent compile cache's key, the
+    checkout's path is not (utils/compile_cache.py).
+
+No timing thresholds anywhere: times are compared with each other, never
+with a constant.
+"""
+
+import glob
+import json
+import os
+import subprocess
+import sys
+import textwrap
+import threading
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from quoracle_tpu.infra import introspect, telemetry
+from quoracle_tpu.infra.telemetry import (
+    TICK_PHASES, TRACER, tick_close, tick_note, tick_open, tick_phase,
+)
+from quoracle_tpu.models.config import get_model_config
+from quoracle_tpu.models.generate import GenerateEngine
+from quoracle_tpu.models.runtime import QueryRequest, TPUBackend
+from quoracle_tpu.models.tokenizer import ByteTokenizer
+from quoracle_tpu.models.transformer import init_params
+
+MEMBER = "xla:tiny"
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+@pytest.fixture(autouse=True)
+def _clean_plane(monkeypatch):
+    monkeypatch.setenv("QUORACLE_TRACE_DECODE_SAMPLE", "1")
+    introspect.reset()
+    introspect.enable()
+    yield
+    introspect.reset()
+    introspect.enable()
+
+
+def ask(backend, text="tick record probe", max_tokens=20):
+    out = backend.query([QueryRequest(
+        MEMBER, [{"role": "user", "content": text}], temperature=0.0,
+        max_tokens=max_tokens)])[0]
+    assert out.ok, out.error
+    return out
+
+
+@pytest.fixture
+def served():
+    """One greedy turn through a continuous backend, with a sink
+    listening: (result, spans emitted, model name)."""
+    events: list = []
+    TRACER.add_sink(events.append)
+    b = TPUBackend([MEMBER], continuous=True, continuous_chunk=8)
+    try:
+        out = ask(b)
+        name = b.engines[MEMBER].cfg.name
+    finally:
+        b.close()
+        TRACER.remove_sink(events.append)
+    return out, events, name
+
+
+# ---------------------------------------------------------------------------
+# The tick record
+# ---------------------------------------------------------------------------
+
+def test_tick_phases_tile_the_tick_and_come_from_the_fixed_list():
+    rec = tick_open("m")
+    for name in ("prepare", "pack", "wait_prefill", "dispatch_decode",
+                 "prepare", "commit"):             # a phase may come again
+        assert tick_phase(name) is rec
+    before = rec.snapshot()
+    tick_note(rows=3, program="raggedx64x8x4x64")
+    after = rec.snapshot()
+    assert tick_close() is rec
+    assert sum(rec.phase_ns.values()) == rec.t1_ns - rec.t0_ns
+    assert set(rec.phase_ns) == set(TICK_PHASES)
+    assert rec.fence_ns and rec.t0_ns <= rec.fence_ns <= rec.t1_ns
+    assert rec.args == {"model": "m", "rows": 3,
+                        "program": "raggedx64x8x4x64"}
+    # two snapshots differ by exactly the time between them, all of it
+    # in the phase that was open
+    grown = {k for k in after if after[k] != before[k]}
+    assert grown <= {"commit"}
+    assert rec.as_attrs()["wall_ns"] == rec.t1_ns - rec.t0_ns
+    with pytest.raises(KeyError):
+        tick_open("m").phase("not-a-phase")
+    tick_close()
+
+
+def test_outside_a_tick_the_phase_helper_does_nothing():
+    assert tick_close() is None
+    assert tick_phase("pack") is None
+    tick_note(rows=1)                              # no record: no error
+    seen = []
+    t = threading.Thread(target=lambda: seen.append(tick_phase("pack")))
+    rec = tick_open("m")                           # this thread's only
+    t.start()
+    t.join()
+    assert seen == [None]
+    assert tick_close() is rec
+
+
+def test_decode_tick_span_is_the_tick_record(served):
+    _, events, name = served
+    ticks = [e for e in events if e["name"] == "sched.decode_tick"]
+    assert ticks, "no tick span reached the sink"
+    for e in ticks:
+        phases = e["phases_ns"]
+        assert set(phases) <= set(TICK_PHASES)
+        assert sum(phases.values()) == e["wall_ns"]
+        assert e["duration_ms"] == pytest.approx(e["wall_ns"] / 1e6, abs=1e-3)
+        assert e["model"] == name and e["rows"] >= 1
+        for arg in ("admitted", "real_tokens", "padded_tokens",
+                    "decode_steps", "program", "step"):
+            assert arg in e, arg
+        assert "," not in str(e["program"])        # a TraceMe argument
+        assert phases["wait_prefill"] > 0 and phases["wait_decode"] > 0
+    assert ticks[0]["admitted"] == 1
+    assert [e["step"] for e in ticks] == list(range(len(ticks)))
+
+
+def test_phase_counter_grows_by_the_ticks_phases(served):
+    _, events, name = served
+    ticks = [e for e in events if e["name"] == "sched.decode_tick"]
+    for phase in ("admit", "prepare", "wait_prefill", "wait_decode",
+                  "commit", "retire"):
+        spanned = sum(e["phases_ns"].get(phase, 0) for e in ticks) / 1e6
+        # the counter also holds the idle iterations' admit time
+        assert telemetry.TICK_PHASE_MS_TOTAL.value(
+            model=name, phase=phase) >= spanned * 0.999 > 0
+    text = telemetry.METRICS.render_prometheus()
+    assert "quoracle_tick_phase_ms_total{" in text
+    assert "quoracle_sched_pad_waste_ratio" not in text
+
+
+# ---------------------------------------------------------------------------
+# The row record
+# ---------------------------------------------------------------------------
+
+def test_retired_row_stamps_are_ordered_and_waits_sum_to_wall(served):
+    out, _, name = served
+    rows = [r for r in introspect.row_ring() if r["model"] == name]
+    assert len(rows) == 1
+    r = rows[0]
+    assert (r["t_submit_ns"] <= r["t_admit_ns"] <= r["t_first_token_ns"]
+            <= r["t_done_ns"])
+    assert r["t_done_ns"] - r["t_submit_ns"] == r["wall_ns"]
+    assert sum(r["waits_ns"].values()) == r["wall_ns"]
+    assert set(r["waits_ns"]) <= set(introspect.WAIT_STATES)
+    for state in ("host", "device_prefill", "device_decode"):
+        assert r["waits_ns"][state] > 0, state
+    assert "dispatch" not in r["waits_ns"]         # the lump is split
+    assert r["ticks"] == 3                         # 20 tokens, chunk 8
+    assert r["emitted_tokens"] == out.usage.completion_tokens == 20
+    assert r["prompt_tokens"] == out.usage.prompt_tokens
+    # served under `rows` by /api/profile, beside the waits totals
+    payload = introspect.profile_payload()
+    assert payload["rows"][-1] == r and name in payload["waits"]
+    json.dumps(payload)
+
+
+def test_query_result_phase_times_come_from_the_row_record(served):
+    out, _, name = served
+    r = [r for r in introspect.row_ring() if r["model"] == name][0]
+    assert out.prefill_ms + out.decode_ms > 0
+    assert out.prefill_ms == pytest.approx(
+        r["waits_ns"]["device_prefill"] / 1e6)
+    assert out.decode_ms == pytest.approx(
+        r["waits_ns"]["device_decode"] / 1e6)
+    assert out.prefill_ms + out.decode_ms <= out.latency_ms
+
+
+def test_row_ring_is_bounded_and_gated():
+    closed = {"wall_ns": 5, "waits_ns": {"other": 5}, "skew_ns": 0}
+    for i in range(introspect.ROW_RING_SIZE + 10):
+        introspect.record_row_waits("m", closed, row={"session": str(i)})
+    ring = introspect.row_ring()
+    assert len(ring) == introspect.ROW_RING_SIZE
+    assert ring[-1]["session"] == str(introspect.ROW_RING_SIZE + 9)
+    assert introspect.row_ring(last=3) == ring[-3:]
+    introspect.reset()
+    introspect.disable()
+    introspect.record_row_waits("m", closed, row={"session": "x"})
+    assert introspect.row_ring() == []
+
+
+# ---------------------------------------------------------------------------
+# In the profiler's trace, and read-only
+# ---------------------------------------------------------------------------
+
+def test_profiler_trace_holds_tick_and_phases_on_one_thread_line(tmp_path):
+    from jax.profiler import ProfileData
+    b = TPUBackend([MEMBER], continuous=True, continuous_chunk=8)
+    try:
+        closed = ask(b, "bit equality probe").text
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        opts.host_tracer_level = 1
+        jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+        try:
+            opened = ask(b, "bit equality probe").text
+        finally:
+            jax.profiler.stop_trace()
+        again = ask(b, "bit equality probe").text
+    finally:
+        b.close()
+    # read-only: the same greedy tokens with a session open and closed
+    assert opened == closed == again
+    path = glob.glob(os.path.join(
+        str(tmp_path), "plugins", "profile", "*", "*.xplane.pb"))[-1]
+    lines = [line for plane in ProfileData.from_file(path).planes
+             if plane.name == "/host:CPU" for line in plane.lines]
+    holding = [line for line in lines
+               if any(ev.name.startswith("qtpu.") for ev in line.events)]
+    assert len(holding) == 1, "qtpu.* spans on more than one thread line"
+    events = list(holding[0].events)
+    ticks = [ev for ev in events if ev.name == "qtpu.tick"]
+    riding = [dict(ev.stats) for ev in ticks
+              if str(dict(ev.stats).get("rows", "0")) != "0"]
+    assert riding, "no tick with rows in the trace"
+    for args in riding:
+        for arg in ("model", "rows", "admitted", "real_tokens",
+                    "padded_tokens", "decode_steps", "program"):
+            assert arg in args, arg
+    names = {ev.name for ev in events if ev.name.startswith("qtpu.tick.")}
+    assert names <= {"qtpu.tick." + p for p in TICK_PHASES}
+    assert {"qtpu.tick.admit", "qtpu.tick.prepare",
+            "qtpu.tick.wait_prefill", "qtpu.tick.wait_decode",
+            "qtpu.tick.commit", "qtpu.tick.retire"} <= names
+    # the phases of a tick lie inside it
+    t = max(ticks, key=lambda ev: ev.duration_ns)
+    inside = [ev for ev in events if ev.name.startswith("qtpu.tick.")
+              and t.start_ns <= ev.start_ns and ev.end_ns <= t.end_ns]
+    assert sum(ev.duration_ns for ev in inside) <= t.duration_ns
+    assert sum(ev.duration_ns for ev in inside) > 0
+
+
+# ---------------------------------------------------------------------------
+# Names on the device side
+# ---------------------------------------------------------------------------
+
+def op_names(lowered) -> set:
+    """The name of every location of the lowered module: the scope path
+    an operation was traced under, its primitive last."""
+    import re
+    return set(re.findall(r'loc\("([^"]*)"', lowered.as_text(debug_info=True)))
+
+
+def has_scope(names: set, scope: str) -> bool:
+    """`sample/top_p`: some operation under `top_p` inside `sample`.
+    `layers/mlp`: the scan's body is a function of its own, whose
+    locations are relative to the call (`jit(step)/layers/while/body/
+    closed_call`) — the chip's profiler shows them joined, as
+    `…/layers/while/body/closed_call/mlp/dot_general`."""
+    outer, _, inner = scope.partition("/")
+    if outer == "layers":
+        return (any("layers" in n.split("/") for n in names)
+                and any(inner in n.split("/")[:-1] for n in names))
+    return any(scope in "/".join(n.split("/")[:-1]) + "/" for n in names)
+
+
+def make_engine():
+    cfg = get_model_config(MEMBER)
+    params = init_params(cfg, jax.random.PRNGKey(0), dtype=jnp.float32)
+    eng = GenerateEngine(cfg, params, ByteTokenizer(), max_seq=256,
+                         prompt_buckets=(32, 64, 128))
+    eng.unified_min_tokens = 0          # the unified ragged path
+    return eng
+
+
+@pytest.fixture(scope="module")
+def ragged_steps():
+    """The two jitted steps of a unified tick with the shapes they were
+    called with: {name: (jitted function, args, kwargs)}."""
+    eng = make_engine()
+    seen: dict = {}
+
+    def spy(name):
+        fn = getattr(eng, name)
+
+        def call(*args, **kwargs):
+            seen[name[1:]] = (fn, jax.tree.map(
+                lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype)
+                if hasattr(a, "shape") else a, args), kwargs)
+            return fn(*args, **kwargs)
+        setattr(eng, name, call)
+
+    spy("_step_paged_ragged")
+    spy("_step_paged_decode_ragged")
+    prompt = ByteTokenizer().encode("names on the device side",
+                                    add_bos=True)
+    eng.generate([prompt], temperature=0.0, max_new_tokens=4,
+                 session_ids=["s"])
+    assert set(seen) == {"step_paged_ragged", "step_paged_decode_ragged"}
+    return seen
+
+
+@pytest.mark.parametrize("step,scopes", [
+    ("step_paged_ragged",
+     ("embed", "layers/qkv", "layers/rope", "layers/kv_write",
+      "layers/attn", "layers/attn_out", "layers/mlp", "final_norm",
+      "head")),
+    ("step_paged_decode_ragged",
+     ("embed", "layers/qkv", "layers/rope", "layers/kv_write",
+      "layers/attn", "layers/attn_out", "layers/mlp", "final_norm", "head",
+      "decode_loop", "decode_loop/while/body/sample/top_p",
+      "decode_loop/while/body/row_state")),
+])
+def test_lowered_steps_carry_the_scope_names(ragged_steps, step, scopes):
+    fn, args, kwargs = ragged_steps[step]
+    names = op_names(fn.lower(*args, **kwargs))
+    assert any(n.startswith(f"jit({step})/") for n in names)   # its name
+    for scope in scopes:
+        assert has_scope(names, scope), scope
+
+
+def test_constrained_decode_names_the_grammar_mask_beneath_sample():
+    from quoracle_tpu.models.generate import _draw, grammar_mask
+    table = jnp.zeros((3, 16), jnp.int32)
+
+    def f(logits, jstate, key):
+        return _draw(lambda lg, js: grammar_mask(lg, js, table, 2),
+                     logits, jstate, key, jnp.zeros((2,)), jnp.ones((2,)))
+    names = op_names(jax.jit(f).lower(
+        jnp.zeros((2, 16)), jnp.zeros((2,), jnp.int32),
+        jax.random.PRNGKey(0)))
+    assert has_scope(names, "sample/grammar_mask")
+    assert has_scope(names, "sample/top_p")
+
+
+@pytest.mark.parametrize("forward", ["forward_hidden",
+                                     "forward_hidden_paged",
+                                     "forward_hidden_paged_prefill"])
+def test_the_other_forwards_name_the_same_scopes(forward):
+    from quoracle_tpu.models import transformer as tr
+    cfg = get_model_config(MEMBER)
+    params = init_params(cfg, jax.random.PRNGKey(0), dtype=jnp.float32)
+    L, KV, HD = cfg.n_layers, cfg.n_kv_heads, cfg.head_dim
+    B, T, page, n_pages = 2, 8, 16, 4
+    pool = jnp.zeros((L, n_pages, page, KV, HD))
+    tok = jnp.zeros((B, T), jnp.int32)
+    pos = jnp.zeros((B, T), jnp.int32)
+    lens = jnp.full((B,), T, jnp.int32)
+    tables = jnp.zeros((B, 2), jnp.int32)
+    if forward == "forward_hidden":
+        def f(p):
+            return tr.forward_hidden(p, cfg, tok, pos,
+                                     tr.init_cache(cfg, B, 32, jnp.float32),
+                                     jnp.zeros((B,), jnp.int32), lens)
+    elif forward == "forward_hidden_paged":
+        tail = jnp.zeros((L, B, 4, KV, HD))
+
+        def f(p):
+            return tr.forward_hidden_paged(
+                p, cfg, tok[:, :1], pos[:, :1], pool, pool, tables, lens,
+                jnp.zeros((B,), jnp.int32), tail, tail,
+                jnp.asarray(0, jnp.int32))
+    else:
+        def f(p):
+            return tr.forward_hidden_paged_prefill(
+                p, cfg, tok, pos, pool, pool, tables,
+                jnp.zeros((B,), jnp.int32), lens,
+                jnp.zeros((B, T), jnp.int32), interpret=True)
+    names = op_names(jax.jit(f).lower(params))
+    for scope in ("embed", "layers/qkv", "layers/rope", "layers/kv_write",
+                  "layers/attn", "layers/attn_out", "layers/mlp",
+                  "final_norm"):
+        assert has_scope(names, scope), scope
+
+
+def pallas_names(jaxpr) -> list:
+    out = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            out.append(eqn.params["name"])
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (list, tuple)) else [v]):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    out += pallas_names(inner)
+    return out
+
+
+def test_every_pallas_call_of_the_ragged_steps_has_its_pinned_name(
+        ragged_steps, monkeypatch):
+    """On the CPU the dispatcher picks the gather reference; with the
+    kernel forced (interpret mode) the steps' jaxprs hold one
+    ``pallas_call`` a layer body, under the name the trace shows."""
+    from quoracle_tpu.ops import paged_attention as pa
+    auto = pa.ragged_attend_auto
+
+    def kernel(*args, **kwargs):
+        kwargs["interpret"] = True
+        return auto(*args, **kwargs)
+    monkeypatch.setattr(pa, "ragged_attend_auto", kernel)
+    eng = make_engine()                  # fresh jits, traced through it
+    for step, (_, args, kwargs) in ragged_steps.items():
+        fn = getattr(eng, "_" + step)
+        names = pallas_names(fn.trace(*args, **kwargs).jaxpr.jaxpr)
+        assert names and set(names) == {"ragged_attend"}, (step, names)
+
+
+@pytest.mark.parametrize("kernel", ["ragged_attend", "paged_attend",
+                                    "paged_prefill_attend", "flash_attend"])
+def test_each_kernel_entry_point_pins_its_name(kernel):
+    from quoracle_tpu.ops import flash_attention as fa
+    from quoracle_tpu.ops import paged_attention as pa
+    H, KV, HD, page, n_pages, B = 4, 2, 128, 16, 4, 2
+    q3 = jnp.zeros((B, H, HD))
+    pool = jnp.zeros((n_pages, page, KV, HD))
+    tables = jnp.zeros((B, 2), jnp.int32)
+    lens = jnp.full((B,), 8, jnp.int32)
+    zero = jnp.zeros((B,), jnp.int32)
+    if kernel == "ragged_attend":
+        def f():
+            return pa.ragged_attend(
+                jnp.zeros((8, H, HD)), pool, pool, tables,
+                jnp.zeros((4, 1), jnp.int32), tq=8, interpret=True)
+    elif kernel == "paged_attend":
+        def f():
+            return pa.paged_attend(q3, pool, pool, tables, lens, zero,
+                                   lens, interpret=True)
+    elif kernel == "paged_prefill_attend":
+        def f():
+            return pa.paged_prefill_attend(
+                jnp.zeros((B, 8, H, HD)), pool, pool, tables, lens,
+                interpret=True)
+    else:
+        def f():
+            return fa.flash_attend(
+                jnp.zeros((B, 128, H, HD)), jnp.zeros((B, 128, KV, HD)),
+                jnp.zeros((B, 128, KV, HD)),
+                jnp.zeros((B, 128), jnp.int32), lens, interpret=True)
+    assert pallas_names(jax.make_jaxpr(f)().jaxpr) == [kernel]
+    if kernel != "flash_attend":
+        # the pools' re-layout for the kernel has a scope of its own
+        assert has_scope(op_names(jax.jit(f).lower()), "kv_layout")
+
+
+# ---------------------------------------------------------------------------
+# Names are part of a cached program's identity
+# ---------------------------------------------------------------------------
+
+MODULE = textwrap.dedent('''
+    import jax
+    @jax.jit
+    def f(x):
+        with jax.named_scope("SCOPE"):
+            return (x @ x).sum()
+''')
+RUN = textwrap.dedent('''
+    import json, os, sys
+    sys.path.insert(0, sys.argv[1])
+    import jax, jax.numpy as jnp
+    from quoracle_tpu.utils import compile_cache
+    compile_cache.REPO_ROOT = sys.argv[1]      # this copy is "the checkout"
+    hits, misses = [], []
+    jax.monitoring.register_event_listener(lambda e, **_: (
+        hits if e.endswith("/cache_hits") else
+        misses if e.endswith("/cache_misses") else []).append(e))
+    compile_cache.enable_compilation_cache()
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    import mod
+    mod.f(jnp.ones((64, 64))).block_until_ready()
+    print(json.dumps([len(hits), len(misses)]))
+''')
+
+
+def test_scope_names_are_in_the_cache_key_and_the_checkout_path_is_not(
+        tmp_path):
+    """Two copies of one module at different paths share their entries; the
+    same module with another scope name misses for the program that holds
+    it (JAX's default key strips the names, and a cache warmed by a build
+    without them would serve executables that carry none)."""
+    results = []
+    for copy, scope in (("a", "mlp"), ("b", "mlp"), ("c", "renamed")):
+        root = tmp_path / copy
+        root.mkdir()
+        (root / "mod.py").write_text(MODULE.replace("SCOPE", scope))
+        (root / "run.py").write_text(RUN)
+        env = {k: v for k, v in os.environ.items() if k != "XLA_FLAGS"}
+        env.update(JAX_PLATFORMS="cpu", PYTHONPATH=REPO,
+                   JAX_COMPILATION_CACHE_DIR=str(tmp_path / "cache"))
+        p = subprocess.run([sys.executable, str(root / "run.py"), str(root)],
+                           env=env, capture_output=True, text=True,
+                           timeout=120)
+        assert p.returncode == 0, p.stderr[-2000:]
+        results.append(json.loads(p.stdout.strip().splitlines()[-1]))
+    (hits_a, miss_a), (hits_b, miss_b), (hits_c, miss_c) = results
+    assert hits_a == 0 and miss_a >= 1
+    assert miss_b == 0 and hits_b == miss_a       # another path: all hits
+    assert miss_c == 1 and hits_c == miss_a - 1   # another name: f misses
