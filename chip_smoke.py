@@ -349,8 +349,10 @@ def kernels_phase(engine) -> tuple[Phase, dict]:
 
     if "ragged" in used:
         n_pages, maxp = 129, 64               # page 0 is the scratch page
-        kp, vp = (jnp.asarray(rng.standard_normal((n_pages, page, KV, hd)),
-                              st.k.dtype) for _ in range(2))
+        # a 2-layer pool in the stored layout; the kernel reads layer 1
+        kp, vp = (jnp.asarray(
+            rng.standard_normal((2, n_pages, page, KV * hd)), st.k.dtype)
+            for _ in range(2))
         kv_len = maxp * page - 5
         tables = np.stack([rng.permutation(n_pages - 1)[:maxp] + 1
                            for _ in range(8)]).astype(np.int32)
@@ -368,7 +370,7 @@ def kernels_phase(engine) -> tuple[Phase, dict]:
             q = jnp.asarray(rng.standard_normal((meta.shape[1] * tq, H,
                                                  hd)), jnp.bfloat16)
             args = (q, kp, vp, jnp.asarray(tables),
-                    jnp.asarray(meta.astype(np.int32)))
+                    jnp.asarray(meta.astype(np.int32)), 1)
             out = pa.ragged_attend(*args, tq=tq,
                                    sliding_window=cfg.sliding_window)
             with jax.default_matmul_precision("highest"):

@@ -249,7 +249,7 @@ def decode(
 def decode_paged(
     params: dict,
     cfg: ModelConfig,
-    k_pool: jax.Array,         # [L, n_pages, page, KV, hd] — READ-ONLY
+    k_pool: jax.Array,         # [L, n_pages, page, KV·hd] — READ-ONLY
     v_pool: jax.Array,
     tables: jax.Array,         # [B, maxp] int32
     pool_lens: jax.Array,      # [B] int32 valid pool tokens (the prompt)
@@ -284,7 +284,7 @@ def decode_paged(
     """
     from quoracle_tpu.models.transformer import forward_hidden_paged
     B = first_logits.shape[0]
-    L, _, page, KV, HD = k_pool.shape
+    L, KV, HD = cfg.n_layers, cfg.n_kv_heads, cfg.head_dim
     fns = _sampling_fns(json_table, eos_id, stop_ids)
     is_stop, mask_logits, advance, _ = fns
     tok0, n0, done0, jstate0, out0, rng = _first_token(
@@ -330,8 +330,8 @@ def decode_paged(
 def decode_ragged(
     params: dict,
     cfg: ModelConfig,
-    k_pool: jax.Array,         # [L, n_pages, page, KV, hd] (donated by jit)
-    v_pool: jax.Array,
+    k_pool: jax.Array,         # [L, n_pages, page, KV·hd] — the pool as
+    v_pool: jax.Array,         # stored (donated by jit), updated in place
     tables: jax.Array,         # [R, maxp] int32 dst page table per row
     pool_lens: jax.Array,      # [R] int32 valid pool tokens (prompt+chunk)
     kv_off: jax.Array,         # [R] int32 abs position of pool index 0
@@ -361,17 +361,22 @@ def decode_ragged(
     itself. Every step is one tq=1-block-per-row launch per layer of the
     same kernel that served the mixed prefill chunk.
 
+    The pools are loop-carried and every step updates them IN PLACE: the
+    forward scatters the step's R rows into the carried buffers and gives
+    the same buffers back (transformer.forward_hidden_ragged), so the
+    ``while`` has nothing to copy — tests/test_kernels_compile_tpu.py
+    holds the compiled program to that.
+
     Returns (tokens [R, max_new], n_emitted [R], lens [R], k_pool,
-    v_pool, jstate) where lens counts the row's valid pool tokens
-    (prompt + chunk + emitted-and-forwarded). With ``k_scale``/
-    ``v_scale`` (int8 pools, ISSUE 13) each step's token quantizes on
-    write inside the forward and the return grows (…, k_scale, v_scale,
-    jstate)."""
+    v_pool, k_scale, v_scale, jstate) where lens counts the row's valid
+    pool tokens (prompt + chunk + emitted-and-forwarded). With
+    ``k_scale``/``v_scale`` (int8 pools, ISSUE 13; None otherwise, and
+    returned as they came) each step's token quantizes on write inside
+    the forward."""
     R = first_logits.shape[0]
-    L, n_pages, page, KV, HD = k_pool.shape
+    _, n_pages, page, _ = k_pool.shape
     n_tok = n_pages * page
     maxp = tables.shape[1]
-    quant = k_scale is not None
     fns = _sampling_fns(json_table, eos_id, stop_ids)
     is_stop, mask_logits, advance, _ = fns
     tok0, n0, done0, jstate0, out0, rng = _first_token(
@@ -389,7 +394,8 @@ def decode_ragged(
         with jax.named_scope("row_state"):
             live = (~done).astype(jnp.int32)
             # this step's token writes at buffer slot lens; done rows (and
-            # any row at its page-table edge) drop via the OOB sentinel
+            # any row at its page-table edge) drop via the sentinel n_tok,
+            # which the forward turns into a drop in every layer
             pg = jnp.take_along_axis(
                 tables, jnp.minimum(lens // page, maxp - 1)[:, None],
                 axis=1)[:, 0]
@@ -402,15 +408,10 @@ def decode_ragged(
                 jnp.arange(R, dtype=jnp.int32),   # one tq=1 block per row
             ])
             positions = lens + kv_off.astype(jnp.int32)
-        if quant:
-            hidden, kp, vp, ks, vs = forward_hidden_ragged(
-                params, cfg, cur[None], positions[None], kp, vp, tables,
-                meta, flat, tq=1, interpret=interpret, shard=shard,
-                k_scale=ks, v_scale=vs)
-        else:
-            hidden, kp, vp = forward_hidden_ragged(
-                params, cfg, cur[None], positions[None], kp, vp, tables,
-                meta, flat, tq=1, interpret=interpret, shard=shard)
+        hidden, kp, vp, ks, vs = forward_hidden_ragged(
+            params, cfg, cur[None], positions[None], kp, vp, tables,
+            meta, flat, tq=1, interpret=interpret, shard=shard,
+            k_scale=ks, v_scale=vs)
         logits = project_logits(params, cfg, hidden)[0]      # [R, V]
         nxt, rng = _draw(mask_logits, logits, jstate, rng, temperature,
                          top_p)
@@ -429,15 +430,12 @@ def decode_ragged(
     # is a valid while_loop carry leaf-less node)
     init = (jnp.asarray(1, jnp.int32), done0, tok0, out0, n0, lens0,
             k_pool, v_pool, k_scale, v_scale, rng, jstate0)
-    # what the loop itself emits — on the TPU a copy of each loop-carried
-    # pool every step — carries ``decode_loop`` and no sub-scope
+    # what the loop itself emits carries ``decode_loop`` and no sub-scope
+    # (until PR 25: a copy of each loop-carried pool every step)
     with jax.named_scope("decode_loop"):
         (_, done, _, out, n_emitted, lens, k_pool, v_pool, k_scale,
          v_scale, _, jstate) = jax.lax.while_loop(cond, body, init)
-    if quant:
-        return (out, n_emitted, lens, k_pool, v_pool, k_scale, v_scale,
-                jstate)
-    return out, n_emitted, lens, k_pool, v_pool, jstate
+    return out, n_emitted, lens, k_pool, v_pool, k_scale, v_scale, jstate
 
 
 def _round_up(n: int, buckets: Sequence[int]) -> int:
@@ -1286,8 +1284,9 @@ class GenerateEngine:
 
         def _gather_work(k_pool, v_pool, k_scale, v_scale, src_pages):
             """Resident pages → dense working cache [L, B, maxp·page,
-            KV, HD] (int8 pools dequantize per (token, kv-head) on the
-            gather)."""
+            KV, HD], the [KV, HD] view of the pool's KV·HD lanes taken
+            on the gathered pages (int8 pools dequantize per (token,
+            kv-head) on the gather)."""
             B, maxp = src_pages.shape
             kw = k_pool[:, src_pages].reshape(L, B, maxp * page, KV, HD)
             vw = v_pool[:, src_pages].reshape(L, B, maxp * page, KV, HD)
@@ -1312,8 +1311,10 @@ class GenerateEngine:
             vp = v_work.reshape(L, B, maxp, page, KV, HD)
             kq, ks = kv_quant(kp)          # ks: [L, B, maxp, page, KV]
             vq, vs = kv_quant(vp)
-            k_pool = k_pool.at[:, dst_pages].set(kq, mode="drop")
-            v_pool = v_pool.at[:, dst_pages].set(vq, mode="drop")
+            k_pool = k_pool.at[:, dst_pages].set(
+                kq.reshape(L, B, maxp, page, KV * HD), mode="drop")
+            v_pool = v_pool.at[:, dst_pages].set(
+                vq.reshape(L, B, maxp, page, KV * HD), mode="drop")
             k_scale = k_scale.at[:, dst_pages].set(
                 ks.transpose(0, 1, 2, 4, 3), mode="drop")
             v_scale = v_scale.at[:, dst_pages].set(
@@ -1416,8 +1417,8 @@ class GenerateEngine:
                     k_pool, v_pool, k_scale, v_scale, cache.k, cache.v,
                     dst_pages)
             else:
-                kp = cache.k.reshape(L, B, maxp, page, KV, HD)
-                vp = cache.v.reshape(L, B, maxp, page, KV, HD)
+                kp = cache.k.reshape(L, B, maxp, page, KV * HD)
+                vp = cache.v.reshape(L, B, maxp, page, KV * HD)
                 k_pool = k_pool.at[:, dst_pages].set(kp, mode="drop")
                 v_pool = v_pool.at[:, dst_pages].set(vp, mode="drop")
             # cache.k/v returned (and discarded by the host) so the donated
@@ -1539,8 +1540,8 @@ class GenerateEngine:
                 return _quant_scatter(k_pool, v_pool, k_scale, v_scale,
                                       k_work, v_work, dst_pages)
             B, maxp = dst_pages.shape
-            kp = k_work.reshape(L, B, maxp, page, KV, HD)
-            vp = v_work.reshape(L, B, maxp, page, KV, HD)
+            kp = k_work.reshape(L, B, maxp, page, KV * HD)
+            vp = v_work.reshape(L, B, maxp, page, KV * HD)
             k_pool = k_pool.at[:, dst_pages].set(kp, mode="drop")
             v_pool = v_pool.at[:, dst_pages].set(vp, mode="drop")
             return k_pool, v_pool, k_scale, v_scale
@@ -1566,31 +1567,18 @@ class GenerateEngine:
             # tail slot t of row b → pool token slot flat_idx[b, t]
             # (host-computed; out-of-range = drop for invalid slots)
             n_tok = k_pool.shape[1] * page
-            kf = k_pool.reshape(L, n_tok, KV, HD)
-            vf = v_pool.reshape(L, n_tok, KV, HD)
-            kf = kf.at[:, flat_idx].set(tail_k, mode="drop")
-            vf = vf.at[:, flat_idx].set(tail_v, mode="drop")
+            kf = k_pool.reshape(L, n_tok, KV * HD)
+            vf = v_pool.reshape(L, n_tok, KV * HD)
+            kf = kf.at[:, flat_idx].set(
+                tail_k.reshape(*tail_k.shape[:3], KV * HD), mode="drop")
+            vf = vf.at[:, flat_idx].set(
+                tail_v.reshape(*tail_v.shape[:3], KV * HD), mode="drop")
             return (kf.reshape(k_pool.shape), vf.reshape(v_pool.shape))
 
-        def _fwd_ragged(params, k_pool, v_pool, k_scale, v_scale,
-                        tokens_flat, positions_flat, row_tables,
-                        block_meta, flat_dst, tq):
-            """The one ragged forward call both unified steps share:
-            int8 pools thread their scale pools through (quantize-on-
-            write inside the forward, in-kernel dequant on read)."""
-            if quant:
-                return forward_hidden_ragged(
-                    params, cfg, tokens_flat[None], positions_flat[None],
-                    k_pool, v_pool, row_tables, block_meta, flat_dst,
-                    tq=tq, shard=ragged_shard,
-                    k_scale=k_scale, v_scale=v_scale)
-            hidden, k_pool, v_pool = forward_hidden_ragged(
-                params, cfg, tokens_flat[None], positions_flat[None],
-                k_pool, v_pool, row_tables, block_meta, flat_dst,
-                tq=tq, shard=ragged_shard)
-            return hidden, k_pool, v_pool, k_scale, v_scale
-
-        @functools.partial(jax.jit, donate_argnums=(1, 2),
+        # the three unified programs donate the pools (and an int8
+        # engine's scale pools; None donates nothing): input and output
+        # are one buffer, updated in place
+        @functools.partial(jax.jit, donate_argnums=(1, 2, 3, 4),
                            static_argnames=("tq",))
         def step_paged_ragged(params, k_pool, v_pool, k_scale, v_scale,
                               tokens_flat,
@@ -1602,14 +1590,17 @@ class GenerateEngine:
             # chunk KV scattered to the rows' pages inside the forward.
             # Shapes key on (flat token budget, page-table width) only:
             # the batch-bucket × prompt-bucket program matrix collapses.
-            hidden, k_pool, v_pool, k_scale, v_scale = _fwd_ragged(
-                params, k_pool, v_pool, k_scale, v_scale, tokens_flat,
-                positions_flat, row_tables, block_meta, flat_dst, tq)
+            hidden, k_pool, v_pool, k_scale, v_scale = \
+                forward_hidden_ragged(
+                    params, cfg, tokens_flat[None], positions_flat[None],
+                    k_pool, v_pool, row_tables, block_meta, flat_dst,
+                    tq=tq, shard=ragged_shard, k_scale=k_scale,
+                    v_scale=v_scale)
             last_h = hidden[0][last_idx]                  # [R, D]
             last = project_logits(params, cfg, last_h[:, None])[:, 0, :]
             return last, k_pool, v_pool, k_scale, v_scale
 
-        @functools.partial(jax.jit, donate_argnums=(1, 2),
+        @functools.partial(jax.jit, donate_argnums=(1, 2, 3, 4),
                            static_argnames=("tq", "kmax", "need_probs"))
         def step_paged_ragged_verify(params, k_pool, v_pool, k_scale,
                                      v_scale, tokens_flat,
@@ -1622,9 +1613,12 @@ class GenerateEngine:
             # to pages — committed prefixes resident for the next round,
             # LCP resume is still the rollback) and verdict logits
             # project at the flat indices of each row's last K positions.
-            hidden, k_pool, v_pool, k_scale, v_scale = _fwd_ragged(
-                params, k_pool, v_pool, k_scale, v_scale, tokens_flat,
-                positions_flat, row_tables, block_meta, flat_dst, tq)
+            hidden, k_pool, v_pool, k_scale, v_scale = \
+                forward_hidden_ragged(
+                    params, cfg, tokens_flat[None], positions_flat[None],
+                    k_pool, v_pool, row_tables, block_meta, flat_dst,
+                    tq=tq, shard=ragged_shard, k_scale=k_scale,
+                    v_scale=v_scale)
             wh = hidden[0][widx]                          # [R, kmax, D]
             logits = project_logits(params, cfg, wh).astype(jnp.float32)
             R = widx.shape[0]
@@ -1659,7 +1653,7 @@ class GenerateEngine:
                 probs = jnp.zeros((1, 1, 1), jnp.float32)
             return ids, probs, k_pool, v_pool, k_scale, v_scale
 
-        @functools.partial(jax.jit, donate_argnums=(1, 2),
+        @functools.partial(jax.jit, donate_argnums=(1, 2, 3, 4),
                            static_argnames=("max_new",))
         def step_paged_decode_ragged(params, k_pool, v_pool, k_scale,
                                      v_scale, tables,
@@ -1671,18 +1665,13 @@ class GenerateEngine:
             # to pages inside the loop (no tail buffer, no tail scatter);
             # attention is the same ragged kernel at tq=1 (int8 pools
             # quantize each step's token on write).
-            res = decode_ragged(
+            return decode_ragged(
                 params, cfg, k_pool, v_pool, tables, pool_lens, kv_off,
                 last_logits, rng, temperature, top_p, max_new,
                 cfg.eos_token_id, active=active, row_limit=row_limit,
                 pad_id=self.tokenizer.pad_id, stop_ids=cfg.stop_token_ids,
                 json_table=json_table, json_state=json_state,
                 shard=ragged_shard, k_scale=k_scale, v_scale=v_scale)
-            if quant:
-                return res
-            out, n_emitted, lens, k_pool, v_pool, jstate = res
-            return (out, n_emitted, lens, k_pool, v_pool, k_scale,
-                    v_scale, jstate)
 
         self._step_paged_ragged = step_paged_ragged
         self._step_paged_ragged_verify = step_paged_ragged_verify
@@ -2477,22 +2466,35 @@ class GenerateEngine:
 
     def _ensure_pool(self) -> None:
         """Allocate the device page pool on first sessioned call (engines
-        that never see sessions never pay for it). Quantized-KV engines
-        allocate int8 pools plus the page-structured fp32 scale pools
-        ([L, n_pages, KV, page] — a page's scales are one contiguous
-        block that tier moves carry beside the page)."""
+        that never see sessions never pay for it).
+
+        ONE stored layout, ``[L, n_pages, page, KV·hd]``: a token's
+        kv-heads lie side by side in the lane dimension, which is the
+        form the ragged kernel streams a page in
+        (ops/paged_attention.ragged_attend). The serving programs carry
+        these two buffers through their layer scan and decode loop and
+        update them in place; who wants ``[…, KV, hd]`` takes a view —
+        a reshape of the fresh rows on the device, of the pages on the
+        host (serving/kvtier.py).
+
+        Quantized-KV engines allocate int8 pools plus the
+        page-structured fp32 scale pools ([L, n_pages, KV, page] — a
+        page's scales are one contiguous block that tier moves carry
+        beside the page)."""
         st = self.sessions
         if st.k is not None:
             return
         shape = (self.cfg.n_layers, st.n_pages, st.page,
-                 self.cfg.n_kv_heads, self.cfg.head_dim)
+                 self.cfg.n_kv_heads * self.cfg.head_dim)
         sh = None
         if self.mesh is not None:
             # created in its sharding: no chip ever holds the whole pool
             from jax.sharding import NamedSharding, PartitionSpec as P
             tp = int(self.mesh.shape.get("tp", 1))
+            # whole kv-heads per shard: a split of the KV·hd lanes into
+            # tp runs is the split of the KV axis, byte for byte
             kv_axis = "tp" if self.cfg.n_kv_heads % tp == 0 else None
-            sh = NamedSharding(self.mesh, P(None, None, None, kv_axis, None))
+            sh = NamedSharding(self.mesh, P(None, None, None, kv_axis))
         k = jnp.zeros(shape, self.pool_dtype, device=sh)
         v = jnp.zeros(shape, self.pool_dtype, device=sh)
         if self.quantize_kv:

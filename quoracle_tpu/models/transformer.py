@@ -21,12 +21,21 @@ the pools' re-layout for a paged kernel, ops/paged_attention.py),
 ``head``. The decode loops (models/generate.py) add ``decode_loop`` around
 the while loop, ``sample`` (``grammar_mask``, ``top_p``) and ``row_state``.
 The names reach the profiler as the operation's ``tf_op`` path, which the
-benchmark's scope metrics read (benchmark/scopes.json). What the scan
-itself emits to slice a layer's pool out and stack it back carries
-``layers`` and no sub-scope, and the loop-carried pools' copies
-``decode_loop`` and no sub-scope: those remainders, with ``kv_write`` and
-``kv_layout``, are the pool move. Scopes are metadata only — the computed
-values are bit-identical with and without them.
+benchmark's scope metrics read (benchmark/scopes.json). What a scan
+itself emits (slices of the stacked weights) carries ``layers`` and no
+sub-scope, what a decode loop itself emits ``decode_loop`` and no
+sub-scope: those remainders, with ``kv_write`` and ``kv_layout``, are
+where a move of the KV pool would show (two thirds of a decode step
+until PR 25; PERF.md §6). Scopes are metadata only — the computed values
+are bit-identical with and without them.
+
+The paged session pool is stored ONCE, ``[L, n_pages, page, KV·hd]``
+(kv-heads flattened into the lane dimension — the layout the ragged
+kernel streams; generate.py ``_ensure_pool``). ``forward_hidden_ragged``,
+the serving path, carries it through the layer scan in place and hands
+it to the kernel whole. The older split paths (``forward_hidden_paged``,
+``forward_hidden_paged_prefill``) still scan it as ``xs`` and take a
+``[n_pages, page, KV, hd]`` view of each layer at their entry.
 """
 
 from __future__ import annotations
@@ -367,7 +376,7 @@ def forward_hidden_paged(
     cfg: ModelConfig,
     tokens: jax.Array,       # [B, 1] int32 (decode step)
     positions: jax.Array,    # [B, 1] int32 absolute positions
-    k_pool: jax.Array,       # [L, n_pages, page, n_kv, hd] — read-only
+    k_pool: jax.Array,       # [L, n_pages, page, KV·hd] — read-only
     v_pool: jax.Array,
     tables: jax.Array,       # [B, maxp] int32 page table
     pool_lens: jax.Array,    # [B] int32 valid pool tokens (fixed in decode)
@@ -389,6 +398,9 @@ def forward_hidden_paged(
 
     def layer_body(x, scanned):
         p, kp, vp, tk, tv = scanned
+        # the split kernels take one layer's [n_pages, page, KV, hd] view
+        kp, vp = (a.reshape(*a.shape[:2], cfg.n_kv_heads, cfg.head_dim)
+                  for a in (kp, vp))
         q, k, v = _qkv(x, p, cfg, B, T, positions)
         # all rows write the same tail slot (done rows deposit junk there;
         # the causal mask excludes it — their frozen q_pos precedes it)
@@ -416,7 +428,7 @@ def forward_hidden_paged_prefill(
     cfg: ModelConfig,
     tokens: jax.Array,       # [B, T] int32 right-padded suffix chunk
     positions: jax.Array,    # [B, T] int32 absolute positions
-    k_pool: jax.Array,       # [L, n_pages, page, n_kv, hd] (donated by jit)
+    k_pool: jax.Array,       # [L, n_pages, page, KV·hd] (donated by jit)
     v_pool: jax.Array,
     src_tables: jax.Array,   # [B, maxp] pages holding the resident prefix
     prefix_lens: jax.Array,  # [B] int32 resident pool tokens per row
@@ -441,11 +453,14 @@ def forward_hidden_paged_prefill(
     x = _embed(params, cfg, tokens)
 
     def layer_body(x, scanned):
-        p, kp, vp = scanned          # kp/vp: [n_pages, page, kv, hd]
+        p, kp, vp = scanned          # kp/vp: [n_pages, page, KV·hd]
         q, k, v = _qkv(x, p, cfg, B, T, positions)
         with jax.named_scope("attn"):
+            # the split kernel takes the [n_pages, page, KV, hd] view
             attn = paged_prefill_merge(
-                q, k.astype(kp.dtype), v.astype(vp.dtype), kp, vp,
+                q, k.astype(kp.dtype), v.astype(vp.dtype),
+                kp.reshape(*kp.shape[:2], *k.shape[2:]),
+                vp.reshape(*vp.shape[:2], *v.shape[2:]),
                 src_tables, prefix_lens, chunk_lens,
                 sliding_window=cfg.sliding_window, interpret=interpret,
                 shard=shard)
@@ -454,10 +469,12 @@ def forward_hidden_paged_prefill(
         # this write; chunk↔chunk attention used the dense piece, so
         # nothing this layer needs re-reading.
         with jax.named_scope("kv_write"):
-            kf = kp.reshape(n_tok, *kp.shape[2:])
-            vf = vp.reshape(n_tok, *vp.shape[2:])
-            kf = kf.at[flat_dst].set(k.astype(kp.dtype), mode="drop")
-            vf = vf.at[flat_dst].set(v.astype(vp.dtype), mode="drop")
+            kf = kp.reshape(n_tok, kp.shape[2])
+            vf = vp.reshape(n_tok, vp.shape[2])
+            kf = kf.at[flat_dst].set(
+                k.reshape(B, T, -1).astype(kp.dtype), mode="drop")
+            vf = vf.at[flat_dst].set(
+                v.reshape(B, T, -1).astype(vp.dtype), mode="drop")
             kp2, vp2 = kf.reshape(kp.shape), vf.reshape(vp.shape)
         x = _attn_out(x, attn.astype(x.dtype), p, cfg)
         x = _mlp(x, p, cfg)
@@ -474,13 +491,14 @@ def forward_hidden_ragged(
     cfg: ModelConfig,
     tokens: jax.Array,       # [1, Tp] int32 token-major FLATTENED batch
     positions: jax.Array,    # [1, Tp] int32 absolute positions per token
-    k_pool: jax.Array,       # [L, n_pages, page, n_kv, hd] (donated by jit)
-    v_pool: jax.Array,
+    k_pool: jax.Array,       # [L, n_pages, page, KV·hd] — the pool AS
+    v_pool: jax.Array,       # STORED (donated by jit), updated in place
     row_tables: jax.Array,   # [R, maxp] int32 — one page table per row
     block_meta: jax.Array,   # [4, NB] int32: kv_len, qpos0, nq, row
-    flat_dst: jax.Array,     # [Tp] int32 flat pool token slot per flattened
-                             # token (OOB sentinel = drop), from the owning
-                             # row's DST page table
+    flat_dst: jax.Array,     # [Tp] int32 token slot per flattened token in
+                             # ONE layer's pages (page·128 + offset, from
+                             # the owning row's DST page table); any value
+                             # >= n_pages·page is the drop sentinel
     tq: int,
     interpret: Optional[bool] = None,
     shard: Optional[tuple] = None,   # (mesh, tp_axis)
@@ -495,79 +513,76 @@ def forward_hidden_ragged(
     block's real pages (ops/paged_attention.ragged_attend_auto) — the
     [B, maxp·page] working cache, the dense intra-chunk piece, and the
     decode tail buffer all cease to exist. Returns
-    (hidden [1, Tp, D], k_pool, v_pool) with the chunk KV written.
+    (hidden [1, Tp, D], k_pool, v_pool, k_scale, v_scale) with the chunk
+    KV written (the scales None as they came, on unquantized pools).
+
+    The pools never move (PR 25): they ride the layer scan as a CARRY,
+    whole and in their stored lane-flat layout, each layer writes its Tp
+    fresh rows into the pool viewed as [L·n_pages·page, KV·hd] (merging
+    major dimensions of 128-row pages is a bitcast) at
+    ``layer·n_tok + flat_dst``, and the kernel is handed the whole pool
+    and the layer index. Nothing the size of a layer's pool is sliced,
+    stacked, reshaped or copied in a step — scanned as ``xs``/``ys``
+    they were, four times over (PERF.md §6).
 
     With ``k_scale``/``v_scale`` (ISSUE 13) the pools are INT8: each
     layer quantizes the chunk's fresh KV per (token, kv-head)
     (models/quant.kv_quant), scatters int8 payloads into the pages and
-    fp32 scales into the page-structured scale pools, and the attention
-    dequantizes inside the kernel's streaming loop — returns a 5-tuple
-    (hidden, k_pool, v_pool, k_scale, v_scale)."""
+    fp32 scales into the page-structured scale pools — carried and
+    indexed by layer the same way — and the attention dequantizes inside
+    the kernel's streaming loop."""
     from quoracle_tpu.ops.paged_attention import ragged_attend_auto
     B, Tp = tokens.shape       # B == 1: the flat layout is the batch
-    n_pages, page = k_pool.shape[1], k_pool.shape[2]
+    L, n_pages, page, lanes = k_pool.shape
     n_tok = n_pages * page
-    KV = cfg.n_kv_heads
     quant = k_scale is not None
     x = _embed(params, cfg, tokens)
+    # A dropped write must stay dropped in EVERY layer: the per-layer
+    # sentinel n_tok is the next layer's first slot once the layer offset
+    # is added, so the drop is decided here, before it.
+    keep = flat_dst < n_tok
+    if quant:
+        pid = jnp.where(keep, flat_dst // page, n_pages)  # OOB page = drop
+        off = flat_dst % page
 
-    def layer_body(x, scanned):
-        if quant:
-            p, kp, vp, ks, vs = scanned  # ks/vs: [n_pages, KV, page]
-        else:
-            p, kp, vp = scanned          # kp/vp: [n_pages, page, kv, hd]
-            ks = vs = None
+    def layer_body(carry, scanned):
+        x, kp, vp, ks, vs = carry     # the pools, whole: [L, n_pages, ...]
+        p, layer = scanned
         q, k, v = _qkv(x, p, cfg, B, Tp, positions)
-        # KV → pages BEFORE attention (padding/overflow slots carry the
-        # OOB sentinel and drop): intra-chunk visibility is then pure
-        # causal masking inside the one kernel — no dense second piece.
+        # KV → pages BEFORE attention (padding/overflow slots drop):
+        # intra-chunk visibility is then pure causal masking inside the
+        # one kernel — no dense second piece.
         with jax.named_scope("kv_write"):
-            kf = kp.reshape(n_tok, *kp.shape[2:])
-            vf = vp.reshape(n_tok, *vp.shape[2:])
+            dst = jnp.where(keep, layer * n_tok + flat_dst, L * n_tok)
+            k_new, v_new = k[0], v[0]                     # [Tp, KV, hd]
             if quant:
-                kq, ks_new = kv_quant(k[0])      # [Tp, KV, hd] / [Tp, KV]
-                vq, vs_new = kv_quant(v[0])
-                kf = kf.at[flat_dst].set(kq, mode="drop")
-                vf = vf.at[flat_dst].set(vq, mode="drop")
-                # scale slot for token t, head j in the [n_pages, KV,
-                # page] pool: ((pid·KV)+j)·page + off — OOB flat_dst
-                # (pid = n_pages) stays OOB and drops
-                pid, off = flat_dst // page, flat_dst % page
-                sidx = ((pid[:, None] * KV
-                         + jnp.arange(KV, dtype=jnp.int32)[None, :]) * page
-                        + off[:, None])          # [Tp, KV]
-                ks = ks.reshape(-1).at[sidx].set(
-                    ks_new, mode="drop").reshape(ks.shape)
-                vs = vs.reshape(-1).at[sidx].set(
-                    vs_new, mode="drop").reshape(vs.shape)
-            else:
-                kf = kf.at[flat_dst].set(k[0].astype(kp.dtype),
-                                         mode="drop")
-                vf = vf.at[flat_dst].set(v[0].astype(vp.dtype),
-                                         mode="drop")
-            kp2 = kf.reshape(kp.shape)
-            vp2 = vf.reshape(vp.shape)
+                k_new, ks_new = kv_quant(k_new)           # int8 / [Tp, KV]
+                v_new, vs_new = kv_quant(v_new)
+                # token t's scales → [layer, page, :, offset] of the
+                # [L, n_pages, KV, page] scale pool
+                ks = ks.at[layer, pid, :, off].set(ks_new, mode="drop")
+                vs = vs.at[layer, pid, :, off].set(vs_new, mode="drop")
+            kp = kp.reshape(L * n_tok, lanes).at[dst].set(
+                k_new.reshape(Tp, lanes).astype(kp.dtype),
+                mode="drop").reshape(kp.shape)
+            vp = vp.reshape(L * n_tok, lanes).at[dst].set(
+                v_new.reshape(Tp, lanes).astype(vp.dtype),
+                mode="drop").reshape(vp.shape)
         with jax.named_scope("attn"):
             attn = ragged_attend_auto(
-                q[0], kp2, vp2, row_tables, block_meta, tq=tq,
+                q[0], kp, vp, row_tables, block_meta, layer, tq=tq,
                 sliding_window=cfg.sliding_window, interpret=interpret,
                 shard=shard, k_scale=ks, v_scale=vs)[None]  # [1,Tp,H,hd]
         x = _attn_out(x, attn.astype(x.dtype), p, cfg)
         x = _mlp(x, p, cfg)
-        return x, ((kp2, vp2, ks, vs) if quant else (kp2, vp2))
+        return (x, kp, vp, ks, vs), None
 
     with jax.named_scope("layers"):
-        if quant:
-            x, (new_k, new_v, new_ks, new_vs) = jax.lax.scan(
-                layer_body, x,
-                (params["layers"], k_pool, v_pool, k_scale, v_scale))
-        else:
-            x, (new_k, new_v) = jax.lax.scan(
-                layer_body, x, (params["layers"], k_pool, v_pool))
-    x = _final_norm(x, params, cfg)
-    if quant:
-        return x, new_k, new_v, new_ks, new_vs
-    return x, new_k, new_v
+        # unquantized engines carry the scale slots as empty pytrees
+        (x, k_pool, v_pool, k_scale, v_scale), _ = jax.lax.scan(
+            layer_body, (x, k_pool, v_pool, k_scale, v_scale),
+            (params["layers"], jnp.arange(L, dtype=jnp.int32)))
+    return _final_norm(x, params, cfg), k_pool, v_pool, k_scale, v_scale
 
 
 def project_logits(params: dict, cfg: ModelConfig, hidden: jax.Array) -> jax.Array:

@@ -46,6 +46,13 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -1e30
 
 
+def _on_tpu() -> bool:
+    """Whether the dispatchers below pick the Pallas kernels (else the
+    XLA gather references). One seam: an AOT compile for a described TPU
+    runs under the CPU backend and steers it from the test."""
+    return jax.devices()[0].platform == "tpu"
+
+
 # ---------------------------------------------------------------------------
 # Partials: dense pieces + merge (plain XLA)
 # ---------------------------------------------------------------------------
@@ -264,14 +271,18 @@ def _paged_kernel(tables_ref, meta_ref, q_ref, k_hbm, v_hbm,
 
 def _lane_flat_pools(k_pages: jax.Array, v_pages: jax.Array,
                      hd_p: int) -> tuple[jax.Array, jax.Array]:
-    """The pools as the kernels take them: head_dim padded to the lane
-    width (production models all have hd = 128, so the pad is a no-op
-    there; tiny test models pay a copy) and kv-heads flattened into the
-    lane dim, [n_pages, page, KV·hd_p] — every Mosaic memref slice stays
+    """One layer's ``[n_pages, page, KV, hd]`` view of the pool as the
+    SPLIT kernels (paged_attend, paged_prefill_attend) take it: head_dim
+    padded to the lane width and kv-heads flattened into the lane dim,
+    [n_pages, page, KV·hd_p] — every Mosaic memref slice stays
     (8, 128)-tiled for ANY head count (KV = 14 is not sublane-tileable).
-    Scope ``kv_layout``: on the TPU this merge of the two minor dims of a
-    tiled pool is no bitcast but a relayout copy of the layer's whole K
-    and V pool per call (PERF.md §5)."""
+    Scope ``kv_layout``: on the TPU this merge of the two minor dims of
+    a tiled array is no bitcast but a relayout copy of the layer's whole
+    K and V pool per call (PERF.md §5, PR 24) — which is why the pool is
+    STORED lane-flat (generate.py ``_ensure_pool``) and the unified
+    kernel, the serving path, never comes here: ``ragged_attend`` reads
+    the stored pool as it is. Only the split kernels, which no TPU cell
+    runs, still pay it for their 4-D view."""
     with jax.named_scope("kv_layout"):
         n_pages, page, KV, hd = k_pages.shape
         if hd_p != hd:
@@ -620,17 +631,24 @@ def paged_prefill_attend(
 # continuation rows, T=suffix prefill rows and T=K speculative-verify
 # rows are just blocks with different (qpos0, nq) — one program shape
 # serves the whole mixed tick.
+#
+# The pools arrive WHOLE and in their stored layout, [L, n_pages, page,
+# KV·hd] (generate.py ``_ensure_pool``), with the layer as one more
+# prefetched scalar: the kernel's DMA source is ``pool.at[layer, pid]``, so
+# no slice, reshape or copy of a layer's pool stands between the store and
+# the kernel (PERF.md §6, PR 25).
 
 
 def ragged_attend_ref(
     q: jax.Array,            # [NB·tq, H, hd] token-major flattened queries
-    k_pages: jax.Array,      # [n_pages, page, KV, hd]
-    v_pages: jax.Array,
+    k_pool: jax.Array,       # [L, n_pages, page, KV·hd] — the stored pool
+    v_pool: jax.Array,
     row_tables: jax.Array,   # [R, maxp] int32 — one page table per row
     block_meta: jax.Array,   # [4, NB] int32: kv_len, qpos0, nq, row
+    layer,                   # int32 scalar: which layer's pages to read
     tq: int,
     sliding_window: Optional[int] = None,
-    k_scale: Optional[jax.Array] = None,   # [n_pages, KV, page] f32
+    k_scale: Optional[jax.Array] = None,   # [L, n_pages, KV, page] f32
     v_scale: Optional[jax.Array] = None,   # (int8 pools, ISSUE 13)
 ) -> jax.Array:
     """XLA gather reference for the unified ragged kernel (CPU serving
@@ -644,17 +662,18 @@ def ragged_attend_ref(
     block_tables = row_tables[row[:, 0, 0]]                  # [NB, maxp]
     NB, maxp = block_tables.shape
     _, H, hd = q.shape
-    n_pages, page, KV, _ = k_pages.shape
+    _, n_pages, page, lanes = k_pool.shape
+    KV = lanes // hd
     G = H // KV
     qb = (q.astype(jnp.float32) * hd ** -0.5).reshape(NB, tq, KV, G, hd)
-    k = k_pages[block_tables].reshape(NB, maxp * page, KV, hd)
-    v = v_pages[block_tables].reshape(NB, maxp * page, KV, hd)
+    k = k_pool[layer, block_tables].reshape(NB, maxp * page, KV, hd)
+    v = v_pool[layer, block_tables].reshape(NB, maxp * page, KV, hd)
     if k_scale is not None:
         from quoracle_tpu.models.quant import gather_scales
         k = k.astype(jnp.float32) \
-            * gather_scales(k_scale, block_tables)[..., None]
+            * gather_scales(k_scale[layer], block_tables)[..., None]
         v = v.astype(jnp.float32) \
-            * gather_scales(v_scale, block_tables)[..., None]
+            * gather_scales(v_scale[layer], block_tables)[..., None]
     scores = jnp.einsum("btkgd,bskd->bkgts", qb, k.astype(jnp.float32))
     t_idx = jnp.arange(tq, dtype=jnp.int32)[None, :, None]
     s_idx = jnp.arange(maxp * page, dtype=jnp.int32)[None, None, :]
@@ -674,8 +693,8 @@ def ragged_attend_ref(
     return out
 
 
-def _ragged_kernel(tables_ref, meta_ref, q_ref, k_hbm, v_hbm, *refs,
-                   page: int, n_kv: int, hd: int, tq: int,
+def _ragged_kernel(tables_ref, meta_ref, layer_ref, q_ref, k_hbm, v_hbm,
+                   *refs, page: int, n_kv: int, hd: int, tq: int,
                    scale: float, window: int, quant: bool):
     """One tq-token block of the flattened batch: stream the owning row's
     VISIBLE pages through VMEM double-buffered (same DMA/layout recipe as
@@ -685,9 +704,12 @@ def _ragged_kernel(tables_ref, meta_ref, q_ref, k_hbm, v_hbm, *refs,
     online-softmax accumulator normalizes in-kernel.
 
     Scalar-prefetched (SMEM): tables_ref [R, maxp] one page table per ROW,
-    meta_ref [4, NB] per-block (kv_len, qpos0, nq, row). A per-block copy
-    of the table, or a [NB, 3] meta (SMEM pads the minor dim to 128
-    lanes), overflows the v5e's 1 MiB of SMEM at an 8k-token tick.
+    meta_ref [4, NB] per-block (kv_len, qpos0, nq, row), layer_ref [1] the
+    layer whose pages to stream — the pools stay in HBM WHOLE,
+    [L, n_pages, page, KV·hd], and a page's DMA source is
+    ``pool.at[layer, pid]``. A per-block copy of the table, or a [NB, 3]
+    meta (SMEM pads the minor dim to 128 lanes), overflows the v5e's
+    1 MiB of SMEM at an 8k-token tick.
 
     ``quant`` (int8 pools, ISSUE 13): each page's fp32 scale block
     ``[KV, page]`` rides the SAME double-buffered DMA stream, and the
@@ -707,6 +729,7 @@ def _ragged_kernel(tables_ref, meta_ref, q_ref, k_hbm, v_hbm, *refs,
     qpos0 = meta_ref[1, i]
     nq = meta_ref[2, i]
     row = meta_ref[3, i]
+    layer = layer_ref[0]
     # last visible key + 1: nothing past the block's last query is visible
     kv_hi = jnp.minimum(kv_len, qpos0 + nq)
     if window >= 0:
@@ -721,7 +744,7 @@ def _ragged_kernel(tables_ref, meta_ref, q_ref, k_hbm, v_hbm, *refs,
 
     def dmas(j, slot):
         pid = tables_ref[row, p_lo + j]
-        return [pltpu.make_async_copy(hbm.at[pid], scr.at[slot],
+        return [pltpu.make_async_copy(hbm.at[layer, pid], scr.at[slot],
                                       sems.at[slot, s])
                 for s, (hbm, scr) in enumerate(streams)]
 
@@ -796,44 +819,59 @@ def _ragged_kernel(tables_ref, meta_ref, q_ref, k_hbm, v_hbm, *refs,
                                              "interpret"))
 def ragged_attend(
     q: jax.Array,            # [NB·tq, H, hd] token-major flattened queries
-    k_pages: jax.Array,      # [n_pages, page, KV, hd]
-    v_pages: jax.Array,
+    k_pool: jax.Array,       # [L, n_pages, page, KV·hd] — the stored pool
+    v_pool: jax.Array,
     row_tables: jax.Array,   # [R, maxp] int32
     block_meta: jax.Array,   # [4, NB] int32: kv_len, qpos0, nq, row
+    layer,                   # int32 scalar: which layer's pages to stream
     tq: int,
     sliding_window: Optional[int] = None,
     interpret: bool = False,
-    k_scale: Optional[jax.Array] = None,   # [n_pages, KV, page] f32
+    k_scale: Optional[jax.Array] = None,   # [L, n_pages, KV, page] f32
     v_scale: Optional[jax.Array] = None,
 ) -> jax.Array:
     """Pallas unified ragged attention (same contract as ragged_attend_ref;
     tests/test_ragged_attention.py asserts numerical agreement). Grid is
     (NB,) — sized by the tick's real tokens / tq, never by batch × max.
-    With ``k_scale``/``v_scale`` the kernel streams each int8 page's scale
-    block alongside its payload and dequantizes in-loop."""
+    The pools are passed whole, as stored, and stay in HBM: the kernel
+    indexes ``layer`` itself, so nothing of a pool's size is sliced,
+    reshaped or copied on the way in. With ``k_scale``/``v_scale`` the
+    kernel streams each int8 page's scale block alongside its payload and
+    dequantizes in-loop."""
     Tp, H, hd = q.shape
     NB = block_meta.shape[1]
-    n_pages, page, KV, _ = k_pages.shape
+    _, n_pages, page, lanes = k_pool.shape
+    KV = lanes // hd
+    quant = k_scale is not None
+    layer = jnp.asarray(layer, jnp.int32).reshape(())
+    pools = [k_pool, v_pool]
+    if quant:
+        pools += [k_scale.astype(jnp.float32), v_scale.astype(jnp.float32)]
     hd_p = max(128, ((hd + 127) // 128) * 128)
     if hd_p != hd:
+        # tiny test models only (every production head_dim is a lane
+        # multiple and never comes here): the one layer the call reads,
+        # its head_dim zero-padded to the lane width, read as layer 0
         q = jnp.pad(q, [(0, 0), (0, 0), (0, hd_p - hd)])
-    kf, vf = _lane_flat_pools(k_pages, v_pages, hd_p)
+        one = [jax.lax.dynamic_index_in_dim(p, layer, 0, keepdims=False)
+               for p in pools]
+        kf, vf = _lane_flat_pools(*(p.reshape(n_pages, page, KV, hd)
+                                    for p in one[:2]), hd_p)
+        pools = [p[None] for p in (kf, vf, *one[2:])]
+        layer = jnp.zeros((), jnp.int32)
     qb = q.reshape(NB, tq, H, hd_p)
-    quant = k_scale is not None
     kernel = functools.partial(
         _ragged_kernel, page=page, n_kv=KV, hd=hd_p, tq=tq,
         scale=hd ** -0.5, quant=quant,
         window=-1 if sliding_window is None else int(sliding_window))
-    pools = [kf, vf]
-    scratch = [pltpu.VMEM((2, page, KV * hd_p), k_pages.dtype),
-               pltpu.VMEM((2, page, KV * hd_p), v_pages.dtype)]
+    scratch = [pltpu.VMEM((2, page, KV * hd_p), k_pool.dtype),
+               pltpu.VMEM((2, page, KV * hd_p), v_pool.dtype)]
     if quant:
-        pools += [k_scale.astype(jnp.float32), v_scale.astype(jnp.float32)]
         scratch += [pltpu.VMEM((2, KV, page), jnp.float32)] * 2
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,                        # tables, meta
+            num_scalar_prefetch=3,                 # tables, meta, layer
             grid=(NB,),
             in_specs=[
                 pl.BlockSpec((1, tq, H, hd_p), lambda i, *_: (i, 0, 0, 0)),
@@ -854,21 +892,22 @@ def ragged_attend(
         # the benchmark's metric files match on that name
         name="ragged_attend",
     )(row_tables.astype(jnp.int32), block_meta.astype(jnp.int32),
-      qb, *pools)[0]
+      layer.reshape(1), qb, *pools)[0]
     return out.reshape(NB * tq, H, hd_p)[..., :hd]
 
 
 def ragged_attend_auto(
     q: jax.Array,            # [NB·tq, H, hd]
-    k_pages: jax.Array,
-    v_pages: jax.Array,
+    k_pool: jax.Array,       # [L, n_pages, page, KV·hd] — the stored pool
+    v_pool: jax.Array,
     row_tables: jax.Array,
     block_meta: jax.Array,
+    layer,                   # int32 scalar
     tq: int,
     sliding_window: Optional[int] = None,
     interpret: Optional[bool] = None,
     shard: Optional[tuple] = None,   # (mesh, tp_axis)
-    k_scale: Optional[jax.Array] = None,   # [n_pages, KV, page] f32 —
+    k_scale: Optional[jax.Array] = None,   # [L, n_pages, KV, page] f32 —
     v_scale: Optional[jax.Array] = None,   # int8 pools (ISSUE 13)
 ) -> jax.Array:
     """Unified ragged attention dispatcher: Pallas kernel on TPU (or under
@@ -876,36 +915,38 @@ def ragged_attend_auto(
     numerics, no paging win). With ``shard``, runs per-tp-shard under
     shard_map: every head attends independently (whole GQA groups per
     shard — callers gate on divisibility), tables/metadata replicate, no
-    collective; int8 scale pools shard on their KV axis beside the
-    payload pools. ``k_scale``/``v_scale`` mark int8 pools and route to
-    the in-kernel dequant / dequantizing reference."""
+    collective; the pools' KV·hd lanes split into tp runs of whole
+    kv-heads, and int8 scale pools shard on their KV axis beside them.
+    ``k_scale``/``v_scale`` mark int8 pools and route to the in-kernel
+    dequant / dequantizing reference."""
     if shard is not None:
         from jax.sharding import PartitionSpec as P
         mesh, tp_ax = shard
         head = P(None, tp_ax, None)              # [Tp, H, hd]
-        kv = P(None, None, tp_ax, None)          # [n_pages, page, KV, hd]
-        ins = [head, kv, kv, P(None, None), P(None, None)]
-        args = [q, k_pages, v_pages, row_tables, block_meta]
+        kv = P(None, None, None, tp_ax)          # [L, n_pages, page, KV·hd]
+        ins = [head, kv, kv, P(None, None), P(None, None), P()]
+        args = [q, k_pool, v_pool, row_tables, block_meta,
+                jnp.asarray(layer, jnp.int32)]
         if k_scale is not None:
-            ins += [P(None, tp_ax, None)] * 2    # [n_pages, KV, page]
+            ins += [P(None, None, tp_ax, None)] * 2   # [L, n_pages, KV, page]
             args += [k_scale, v_scale]
 
-        def inner(qq, kp, vp, rt, bm, ks=None, vs=None):
+        def inner(qq, kp, vp, rt, bm, ly, ks=None, vs=None):
             return ragged_attend_auto(
-                qq, kp, vp, rt, bm, tq=tq, sliding_window=sliding_window,
-                interpret=interpret, k_scale=ks, v_scale=vs)
+                qq, kp, vp, rt, bm, ly, tq=tq,
+                sliding_window=sliding_window, interpret=interpret,
+                k_scale=ks, v_scale=vs)
         # check_vma off: a pallas_call's outputs carry no varying-axes
         # annotation for the checker to verify
         return jax.shard_map(inner, mesh=mesh, in_specs=tuple(ins),
                              out_specs=head, check_vma=False)(*args)
-    on_tpu = jax.devices()[0].platform == "tpu"
-    if on_tpu or interpret:
-        return ragged_attend(q, k_pages, v_pages, row_tables, block_meta,
-                             tq=tq, sliding_window=sliding_window,
+    if _on_tpu() or interpret:
+        return ragged_attend(q, k_pool, v_pool, row_tables, block_meta,
+                             layer, tq=tq, sliding_window=sliding_window,
                              interpret=bool(interpret),
                              k_scale=k_scale, v_scale=v_scale)
-    return ragged_attend_ref(q, k_pages, v_pages, row_tables, block_meta,
-                             tq=tq, sliding_window=sliding_window,
+    return ragged_attend_ref(q, k_pool, v_pool, row_tables, block_meta,
+                             layer, tq=tq, sliding_window=sliding_window,
                              k_scale=k_scale, v_scale=v_scale)
 
 
@@ -955,8 +996,7 @@ def paged_prefill_merge(
         return _tp_shard_map(inner, shard, q_rank4=False)(
             q, chunk_k, chunk_v, k_pages, v_pages, tables, prefix_lens,
             chunk_lens)
-    on_tpu = jax.devices()[0].platform == "tpu"
-    if on_tpu or interpret:
+    if _on_tpu() or interpret:
         pooled = paged_prefill_attend(q, k_pages, v_pages, tables,
                                       prefix_lens, sliding_window,
                                       interpret=bool(interpret))
@@ -997,8 +1037,7 @@ def paged_decode_attend(
             jnp.asarray(tail_len), q_pos)
     B, _, H, hd = q.shape
     q1 = q[:, 0]
-    on_tpu = jax.devices()[0].platform == "tpu"
-    if on_tpu or interpret:
+    if _on_tpu() or interpret:
         pooled = paged_attend(q1, k_pages, v_pages, tables, pool_lens,
                               kv_off, q_pos, sliding_window,
                               interpret=bool(interpret))

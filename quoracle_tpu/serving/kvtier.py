@@ -87,7 +87,8 @@ def _scatter_pages(k_pool, v_pool, k_host, v_host, pages):
     """Page-in: host block KV → pool pages in place (pools donated, same
     aliasing discipline as generate.py's step_scatter_prompt). ``pages``
     may be padded with 0 — page 0 is scratch by construction, so padded
-    writes land harmlessly."""
+    writes land harmlessly. The host pages arrive in the pool's stored
+    layout, [L, n, page, KV·HD] (``_as_stored``)."""
     k_pool = k_pool.at[:, pages].set(k_host.astype(k_pool.dtype))
     v_pool = v_pool.at[:, pages].set(v_host.astype(v_pool.dtype))
     return k_pool, v_pool
@@ -106,6 +107,15 @@ def _scatter_pages_q(k_pool, v_pool, ks_pool, vs_pool, k_host, v_host,
     return k_pool, v_pool, ks_pool, vs_pool
 
 
+def _as_stored(pages_kv: np.ndarray) -> np.ndarray:
+    """Host pages in the pool's stored layout, [L, n, page, KV·HD]
+    (generate.py ``_ensure_pool``). What ``_gather_host`` reads has it
+    already; an entry persisted before the pool was stored lane-flat
+    holds the same bytes as [L, n, page, KV, HD], and on the host the
+    view is free."""
+    return pages_kv.reshape(*pages_kv.shape[:3], -1)
+
+
 class _HostSession:
     __slots__ = ("tokens", "start_pos", "k", "v", "k_scale", "v_scale",
                  "nbytes", "ts")
@@ -114,7 +124,7 @@ class _HostSession:
                  v_scale=None):
         self.tokens = tokens
         self.start_pos = start_pos
-        self.k = k                      # np [L, n_pages, page, KV, HD]
+        self.k = k                      # np [L, n_pages, page, KV·HD]
         self.v = v
         # int8 entries (ISSUE 13): fp32 [L, n_pages, KV, page] — the
         # scales travel WITH the pages through every tier move
@@ -131,7 +141,7 @@ class _HostBlock:
 
     def __init__(self, tokens, k, v, k_scale=None, v_scale=None):
         self.tokens = tokens            # full token prefix (page-aligned)
-        self.k = k                      # np [L, page, KV, HD]
+        self.k = k                      # np [L, page, KV·HD]
         self.v = v
         self.k_scale = k_scale          # np [L, KV, page] (int8 entries)
         self.v_scale = v_scale
@@ -638,8 +648,9 @@ class TierManager:
         st = self.store
         n = len(pages)
         cap = _round_up_pow2(max(1, n))
+        k, v = _as_stored(k), _as_stored(v)
         if cap != n:
-            pad = ((0, 0), (0, cap - n), (0, 0), (0, 0), (0, 0))
+            pad = ((0, 0), (0, cap - n), (0, 0), (0, 0))
             k = np.pad(k, pad)
             v = np.pad(v, pad)
             if k_scale is not None:

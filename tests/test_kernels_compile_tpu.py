@@ -17,9 +17,15 @@ The second half runs the tp wrappers under ``jax.shard_map`` with the
 kernels in interpret mode on two virtual devices: on CPU the dispatchers
 normally pick the gather references, which hid a ``pallas_call`` that the
 installed ``shard_map`` rejected at trace time.
+
+Between the two: the compiled decode PROGRAM (``step_paged_decode_ragged``,
+donation and all) is read for anything that moves the page pool — the
+copies, slices and relayouts that were two thirds of a decode step until
+PR 25 carried the pool in place (PERF.md §6).
 """
 
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -36,6 +42,8 @@ CFG = get_model_config("mistral-7b")
 H, KV, HD, WINDOW = CFG.n_heads, CFG.n_kv_heads, CFG.head_dim, \
     CFG.sliding_window
 PAGE, N_PAGES = 128, 257
+LAYERS = 2      # the kernel takes the pool whole, as stored: [L, n_pages,
+                # page, KV·hd], and is told which layer to read
 
 
 @pytest.fixture(scope="module")
@@ -58,14 +66,16 @@ def compiles(fn, *args) -> None:
                          ids=["tq8-8k-tick", "tq1-decode"])
 def test_ragged_kernel_compiles(on_v5e, tq, nb, rows, quant):
     S = on_v5e
-    pool = S((N_PAGES, PAGE, KV, HD), jnp.int8 if quant else jnp.bfloat16)
+    pool = S((LAYERS, N_PAGES, PAGE, KV * HD),
+             jnp.int8 if quant else jnp.bfloat16)
     args = [S((nb * tq, H, HD), jnp.bfloat16), pool, pool,
-            S((rows, 128), jnp.int32), S((4, nb), jnp.int32)]
+            S((rows, 128), jnp.int32), S((4, nb), jnp.int32),
+            S((), jnp.int32)]
     if quant:
-        args += [S((N_PAGES, KV, PAGE), jnp.float32)] * 2
+        args += [S((LAYERS, N_PAGES, KV, PAGE), jnp.float32)] * 2
 
-    def fn(q, k, v, tables, meta, ks=None, vs=None):
-        return pa.ragged_attend(q, k, v, tables, meta, tq=tq,
+    def fn(q, k, v, tables, meta, layer, ks=None, vs=None):
+        return pa.ragged_attend(q, k, v, tables, meta, layer, tq=tq,
                                 sliding_window=WINDOW, k_scale=ks,
                                 v_scale=vs)
     compiles(fn, *args)
@@ -74,20 +84,19 @@ def test_ragged_kernel_compiles(on_v5e, tq, nb, rows, quant):
 def test_compiled_ragged_kernel_carries_its_pinned_name(on_v5e):
     """A profiler trace shows the kernel as ``%ragged_attend.<n>``, under
     the ``pallas_call``'s explicit ``name`` (ISSUE 24): the benchmark's
-    metric files match on it, and the pools' re-layout for the kernel
-    carries its own scope, ``kv_layout``."""
-    import re
+    metric files match on it. The pools reach it as they are stored: no
+    re-layout (scope ``kv_layout``) is left in the program."""
     S = on_v5e
-    pool = S((N_PAGES, PAGE, KV, HD), jnp.bfloat16)
+    pool = S((LAYERS, N_PAGES, PAGE, KV * HD), jnp.bfloat16)
     text = jax.jit(functools.partial(
         pa.ragged_attend, tq=1, sliding_window=WINDOW)).lower(
         S((8, H, HD), jnp.bfloat16), pool, pool, S((8, 128), jnp.int32),
-        S((4, 8), jnp.int32)).compile().as_text()
+        S((4, 8), jnp.int32), S((), jnp.int32)).compile().as_text()
     call = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
     assert len(call) == 1
     assert re.match(r"\s*%ragged_attend(\.\d+)? = ", call[0])
     assert "/ragged_attend/pallas_call" in call[0]
-    assert "kv_layout/reshape" in text
+    assert "kv_layout" not in text
 
 
 def test_ragged_kernel_compiles_at_every_catalog_geometry(on_v5e):
@@ -99,12 +108,13 @@ def test_ragged_kernel_compiles_at_every_catalog_geometry(on_v5e):
                                ("llama-3-8b", "mistral-7b", "gemma-7b",
                                 "llama-1b", "mistral-1b", "gemma-1b"))}
     for h, kv, hd, window in sorted(geometries, key=str):
-        pool = S((N_PAGES, PAGE, kv, hd), jnp.bfloat16)
+        pool = S((LAYERS, N_PAGES, PAGE, kv * hd), jnp.bfloat16)
         for tq, nb in ((8, 64), (1, 8)):
             compiles(functools.partial(pa.ragged_attend, tq=tq,
                                        sliding_window=window),
                      S((nb * tq, h, hd), jnp.bfloat16), pool, pool,
-                     S((8, 64), jnp.int32), S((4, nb), jnp.int32))
+                     S((8, 64), jnp.int32), S((4, nb), jnp.int32),
+                     S((), jnp.int32))
 
 
 def test_paged_decode_kernel_compiles(on_v5e):
@@ -145,6 +155,181 @@ def test_flash_kernel_compiles(on_v5e, b, t, s):
              S((b,), jnp.int32))
 
 
+# --- the compiled decode program: the pool stays where it is -----------------
+
+
+_ARRAY = re.compile(r"\s*(?:ROOT )?%(\S+) = (\w+)\[([\d,]*)\]\S* ([\w\-]+)\(")
+# what may hold an array of the pool's size without moving it: the entry's
+# donated parameter, the loops' carries, a view, the kernel's HBM operand
+_STAYS = {"parameter", "bitcast", "get-tuple-element", "custom-call"}
+
+
+def pool_moves(hlo: str, layer_elems: int) -> list:
+    """The instructions of an optimized HLO module that MOVE an array as
+    large as one layer's K (or V) pool: everything outside a fused
+    computation that produces or consumes such an array and is neither in
+    ``_STAYS`` nor a fusion that scatters rows into it in place. A
+    ``copy``, ``reshape``, ``dynamic-slice``, ``dynamic-update-slice`` —
+    or anything else XLA might think of — lands here; a free reshape is
+    printed as ``bitcast`` and does not. A pool-sized array is told from a
+    weight by its element count, a multiple of ``layer_elems``."""
+    elems, body_of, root_op, insts, comp = {}, {}, {}, [], None
+    for ln in hlo.splitlines():
+        head = re.match(r"(?:ENTRY )?%(\S+) \(.*\{\s*$", ln)
+        if head:
+            comp = head.group(1)
+            continue
+        m = _ARRAY.match(ln)
+        if not m:
+            continue
+        name, _, dims, op = m.groups()
+        n = 1
+        for d in filter(None, dims.split(",")):
+            n *= int(d)
+        elems[name] = n
+        calls = re.search(r"calls=%([\w.\-]+)", ln)
+        if op == "fusion" and calls:
+            body_of[name] = calls.group(1)
+        if "ROOT " in ln[:ln.index("=")]:
+            root_op[comp] = op
+        operands = re.findall(r"%([\w.\-]+)", ln[m.end():].split(
+            "), ")[0])
+        insts.append((comp, name, op, operands))
+    fused = set(body_of.values())
+
+    def pool_sized(n):
+        return n >= layer_elems and n % layer_elems == 0
+    out = []
+    for comp, name, op, operands in insts:
+        if comp in fused or op in _STAYS:
+            continue
+        if not (pool_sized(elems[name])
+                or any(pool_sized(elems.get(o, 0)) for o in operands)):
+            continue
+        if op == "fusion" and root_op.get(body_of[name]) == "scatter" \
+                and pool_sized(elems[name]):
+            continue                    # rows written into the pool in place
+        out.append((op, name, elems[name]))
+    return out
+
+
+def test_pool_moves_finds_what_the_parent_program_did():
+    """The reader itself, on lines of the kind PR 24's program held (one
+    layer's pool is 4 x 128 x 1024 elements here)."""
+    hlo = """
+%fused_computation.1 (p0: bf16[8,128,1024]) -> bf16[4,128,1024] {
+  %p0 = bf16[8,128,1024]{2,1,0} parameter(0)
+  ROOT %dynamic-slice.1 = bf16[4,128,1024]{2,1,0} dynamic-slice(%p0), dynamic_slice_sizes={4,128,1024}
+}
+
+%fused_computation.2 (p1: bf16[1024,1024], p2: s32[8], p3: bf16[8,1024]) -> bf16[1024,1024] {
+  %p1 = bf16[1024,1024]{1,0} parameter(0)
+  %p2 = s32[8]{0} parameter(1)
+  %p3 = bf16[8,1024]{1,0} parameter(2)
+  ROOT %scatter.1 = bf16[1024,1024]{1,0} scatter(%p1, %p2, %p3), update_window_dims={1}
+}
+
+ENTRY %main (a: bf16[2,4,128,1024]) -> bf16[2,4,128,1024] {
+  %a = bf16[2,4,128,1024]{3,2,1,0} parameter(0)
+  %copy.91 = bf16[2,4,128,1024]{3,2,1,0} copy(%a)
+  %bitcast.1 = bf16[1024,1024]{1,0} bitcast(%copy.91)
+  %fusion.7 = bf16[4,128,1024]{2,1,0} fusion(%bitcast.1), kind=kLoop, calls=%fused_computation.1
+  %reshape.477 = bf16[4,128,8,128]{3,2,1,0} reshape(%fusion.7)
+  %w = bf16[300,1024]{1,0} constant(0)
+  %copy.2 = bf16[300,1024]{1,0} copy(%w)
+  %i = s32[8]{0} constant(0)
+  %u = bf16[8,1024]{1,0} constant(0)
+  %fusion.9 = bf16[1024,1024]{1,0} fusion(%bitcast.1, %i, %u), kind=kCustom, calls=%fused_computation.2
+  ROOT %bitcast.2 = bf16[2,4,128,1024]{3,2,1,0} bitcast(%fusion.9)
+}
+"""
+    assert [(op, name) for op, name, _ in pool_moves(hlo, 4 * 128 * 1024)] \
+        == [("copy", "copy.91"), ("fusion", "fusion.7"),
+            ("reshape", "reshape.477")]
+
+
+def _decode_program(on_v5e, monkeypatch, cfg, max_seq, rows=8, width=4):
+    """``step_paged_decode_ragged`` of an engine at ``cfg``, compiled for
+    the v5e with donation as served: (optimized HLO, memory analysis, one
+    layer's pool in elements). The engine is built on shapes alone; its
+    dispatcher is told it is on the TPU, so the program holds the Mosaic
+    kernel and not the gather reference."""
+    from quoracle_tpu.models.generate import GenerateEngine
+    from quoracle_tpu.models.tokenizer import ByteTokenizer
+    from quoracle_tpu.models.transformer import init_params
+    monkeypatch.setattr(pa, "_on_tpu", lambda: True)
+    S = on_v5e
+    params = jax.tree.map(
+        lambda x: S(x.shape, x.dtype),
+        jax.eval_shape(lambda k: init_params(cfg, k), jax.random.PRNGKey(0)))
+    eng = GenerateEngine(cfg, params, ByteTokenizer(), max_seq=max_seq)
+    st = eng.sessions
+    lanes = cfg.n_kv_heads * cfg.head_dim
+    pool = S((cfg.n_layers, st.n_pages, st.page, lanes), eng.pool_dtype)
+    R, i32, f32 = rows, jnp.int32, jnp.float32
+    compiled = eng._step_paged_decode_ragged.lower(
+        params, pool, pool, None, None, S((R, width), i32), S((R,), i32),
+        S((R,), i32), S((R, cfg.vocab_size), f32), S((2,), jnp.uint32),
+        S((R,), f32), S((R,), f32), S((R,), jnp.bool_), S((R,), i32),
+        None, None, max_new=32).compile()
+    return (compiled.as_text(), compiled.memory_analysis(),
+            st.n_pages * st.page * lanes)
+
+
+def _narrow(name, n_kv_heads, **kw):
+    """Real pool tiling (head_dim 128, 128-token pages) under a model
+    narrow enough for tier-1's clock: every weight, stacked over the 3
+    layers, is smaller than one layer's pool (8.6 MB: 33 pages of 8
+    kv-heads, or 129 of 2), and none has a multiple of its rows. A pool
+    much smaller than these 26 MB the compiler would prefetch into fast
+    memory whole, which no serving pool fits."""
+    from quoracle_tpu.models.config import ModelConfig
+    return ModelConfig(name=name, vocab_size=512, dim=256, n_layers=3,
+                       n_heads=8, n_kv_heads=n_kv_heads, ffn_dim=512,
+                       head_dim=128, **kw)
+
+
+@pytest.mark.parametrize("cfg,max_seq", [
+    (_narrow("narrow-kv8-window", 8, sliding_window=4096), 128),
+    (_narrow("narrow-kv2-bias", 2, attn_bias=True, tie_embeddings=True),
+     512),
+], ids=lambda c: getattr(c, "name", None))
+def test_decode_program_leaves_the_pool_where_it_is(on_v5e, monkeypatch,
+                                                    cfg, max_seq):
+    """The optimized HLO of the decode program holds no operation that
+    moves a layer's pool or more, its temporaries stay under one layer's
+    pool, and both pools are donated into their outputs. This is what
+    keeps the copies from coming back unnoticed (PERF.md §6, PR 25)."""
+    hlo, mem, layer_elems = _decode_program(on_v5e, monkeypatch, cfg,
+                                            max_seq=max_seq)
+    assert layer_elems == (max_seq // 4 + 1) * 128 * cfg.n_kv_heads * 128
+    assert hlo.count("tpu_custom_call") == 1     # one kernel a layer body
+    assert pool_moves(hlo, layer_elems) == []
+    pool_bytes = 2 * cfg.n_layers * layer_elems * 2
+    assert mem.alias_size_in_bytes >= pool_bytes
+    assert mem.temp_size_in_bytes < layer_elems * 2
+
+
+@pytest.mark.slow
+def test_decode_program_leaves_the_pool_where_it_is_at_mistral_widths(
+        on_v5e, monkeypatch):
+    """The same reading at the benchmark's ``mistral-7b-l16`` (run by hand
+    before chip time: ``-m slow``, half a minute). There the head's and
+    the projections' weights are larger than a layer's pool, so the
+    temporaries are held under one WHOLE pool instead — a second pool, or
+    a copy of one, cannot hide."""
+    import dataclasses
+    cfg = dataclasses.replace(CFG, name="mistral-7b-l16", n_layers=16)
+    hlo, mem, layer_elems = _decode_program(on_v5e, monkeypatch, cfg,
+                                            max_seq=8192, width=64)
+    assert layer_elems == 257 * 128 * 1024
+    moves = pool_moves(hlo, layer_elems)
+    print("pool moves:", moves, "temp bytes:", mem.temp_size_in_bytes,
+          "alias bytes:", mem.alias_size_in_bytes)
+    assert moves == []
+    assert mem.temp_size_in_bytes < cfg.n_layers * layer_elems * 2
+
+
 # --- tp wrappers: shard_map around a pallas_call ----------------------------
 
 
@@ -159,6 +344,9 @@ def tp_case(eight_devices):
     return {
         "mesh": mesh, "h": h, "kv": kv, "hd": hd,
         "kp": arr(n_pages, page, kv, hd), "vp": arr(n_pages, page, kv, hd),
+        # the same pages as layer 1 of a stored 2-layer pool
+        "stored": lambda a: jnp.stack([jnp.zeros_like(a), a]).reshape(
+            2, n_pages, page, kv * hd),
         "tables": jnp.asarray([[1, 2, 3, 4], [5, 6, 7, 8]], jnp.int32),
         "pool_lens": jnp.asarray([20, 9], jnp.int32), "arr": arr,
     }
@@ -168,7 +356,8 @@ def test_ragged_tp_wrapper_runs_the_kernel_under_shard_map(tp_case):
     c = tp_case
     q = c["arr"](16, c["h"], c["hd"])
     meta = jnp.asarray([[20, 9], [12, 8], [8, 1], [0, 1]], jnp.int32)
-    args = (q, c["kp"], c["vp"], c["tables"], meta)
+    args = (q, c["stored"](c["kp"]), c["stored"](c["vp"]), c["tables"],
+            meta, jnp.asarray(1, jnp.int32))
     ref = pa.ragged_attend_ref(*args, tq=8)
     out = jax.jit(lambda *a: pa.ragged_attend_auto(
         *a, tq=8, interpret=True, shard=(c["mesh"], "tp")))(*args)

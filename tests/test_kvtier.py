@@ -479,18 +479,23 @@ def test_extend_prefix_survives_alloc_evicting_matched_path():
     page = 4
     store = SessionStore(max_tokens=4 * page, page=page)
     L, KV, HD = 2, 2, 4
-    store.k = jnp.zeros((L, store.n_pages, page, KV, HD), jnp.float32)
+    # the pool as the engine stores it: [L, n_pages, page, KV·HD]
+    store.k = jnp.zeros((L, store.n_pages, page, KV * HD), jnp.float32)
     store.v = jnp.zeros_like(store.k)
     tier = TierManager(store, model="m", host_mb=1)
     store.tier = tier
     tokens = list(range(2 * page))
 
-    def blk(depth):
-        return np.full((L, page, KV, HD), float(depth), np.float32)
+    def blk(depth, shape=(L, page, KV * HD)):
+        return np.full(shape, float(depth), np.float32)
 
-    # both blocks of the chain live in the host tier, content = depth
+    # both blocks of the chain live in the host tier, content = depth —
+    # the first as an entry persisted before the pool was stored
+    # lane-flat holds it ([L, page, KV, HD]: the same bytes, restored
+    # through a view)
+    old = (L, page, KV, HD)
     tier.host.put_prefix(tier._block_key(tokens[:page]),
-                         _HostBlock(tokens[:page], blk(1), blk(1)))
+                         _HostBlock(tokens[:page], blk(1, old), blk(1, old)))
     tier.host.put_prefix(tier._block_key(tokens),
                          _HostBlock(tokens, blk(2), blk(2)))
     # seed the tree with block 0 as a refcount-1 leaf (tree-only ref)
@@ -834,3 +839,33 @@ def test_demote_restore_flight_events():
     kinds = [e["kind"] for e in FLIGHT.snapshot()]
     assert "kv_demote" in kinds
     assert "kv_restore" in kinds
+
+
+@pytest.mark.parametrize("layout", ["stored", "kv-hd"])
+def test_page_in_takes_host_pages_in_either_layout(layout):
+    """The pool is stored [L, n_pages, page, KV·HD] (generate.py
+    _ensure_pool) and the host tiers hold pages the same way; an entry
+    persisted before that holds the same bytes as [L, n, page, KV, HD]
+    and pages in through a view — the padded page count (3 → 4, the
+    spare slot on scratch page 0) included."""
+    import jax.numpy as jnp
+    page, L, KV, HD = 4, 2, 2, 4
+    store = SessionStore(max_tokens=6 * page, page=page)
+    store.k = jnp.zeros((L, store.n_pages, page, KV * HD), jnp.float32)
+    store.v = jnp.zeros_like(store.k)
+    tier = TierManager(store, model="m", host_mb=1)
+    rng = np.random.default_rng(0)
+    k, v = (rng.standard_normal((L, 3, page, KV, HD)).astype(np.float32)
+            for _ in range(2))
+    flat = (L, 3, page, KV * HD)
+    host = (k, v) if layout == "kv-hd" else (k.reshape(flat),
+                                             v.reshape(flat))
+    tier._scatter_device([5, 2, 6], *host)
+    for pool, want in ((store.k, k), (store.v, v)):
+        got = np.asarray(pool)
+        assert np.array_equal(got[:, [5, 2, 6]], want.reshape(flat))
+        assert not got[:, [1, 3, 4]].any()
+    # and what a demotion reads back is the stored layout
+    back = tier._gather_host([2, 6])
+    assert back[0].shape == (L, 2, page, KV * HD)
+    assert np.array_equal(back[0], k.reshape(flat)[:, 1:])

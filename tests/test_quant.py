@@ -123,21 +123,34 @@ def test_ragged_kernel_dequant_vs_oracle():
     vq, vs = kv_quant(vf)
     ksl = jnp.transpose(ks, (0, 2, 1))        # [n_pages, KV, page]
     vsl = jnp.transpose(vs, (0, 2, 1))
+
+    def stored(pages, layer):
+        """One layer's pages as layer ``layer`` of a 2-layer pool in the
+        stored layout ([L, n_pages, page, KV·hd]; scales [L, n_pages, KV,
+        page]); the other layer holds garbage."""
+        flat = pages.reshape(*pages.shape[:2], -1) if pages.ndim == 4 \
+            else pages
+        pool = jnp.stack([flat, flat]).at[1 - layer].set(
+            jnp.full_like(flat, 77))
+        return pool
     tables = jnp.array([[0, 1, 2], [3, 4, 5]], jnp.int32)
     # [4, NB]: kv_len, qpos0, nq, row
     meta = jnp.array([[20, 10], [16, 6], [4, 4], [0, 1]], jnp.int32)
     q = jax.random.normal(jax.random.fold_in(key, 2), (NB * tq, H, hd))
     # oracle: dequantize the pages, then attend with the plain reference
-    oracle = ragged_attend_ref(q, kv_dequant(kq, ks), kv_dequant(vq, vs),
-                               tables, meta, tq=tq)
+    oracle = ragged_attend_ref(q, stored(kv_dequant(kq, ks), 0),
+                               stored(kv_dequant(vq, vs), 0),
+                               tables, meta, 0, tq=tq)
     # scaled reference must be EXACT (same math, dequant folded in)
-    ref = ragged_attend_ref(q, kq, vq, tables, meta, tq=tq,
-                            k_scale=ksl, v_scale=vsl)
+    ref = ragged_attend_ref(q, stored(kq, 1), stored(vq, 1), tables, meta,
+                            1, tq=tq, k_scale=stored(ksl, 1),
+                            v_scale=stored(vsl, 1))
     np.testing.assert_allclose(np.asarray(ref), np.asarray(oracle),
                                rtol=0, atol=1e-6)
     # in-kernel dequant (interpret-mode Pallas) within tolerance
-    out = ragged_attend(q, kq, vq, tables, meta, tq=tq, interpret=True,
-                        k_scale=ksl, v_scale=vsl)
+    out = ragged_attend(q, stored(kq, 1), stored(vq, 1), tables, meta, 1,
+                        tq=tq, interpret=True, k_scale=stored(ksl, 1),
+                        v_scale=stored(vsl, 1))
     np.testing.assert_allclose(np.asarray(out), np.asarray(oracle),
                                rtol=2e-5, atol=2e-5)
 

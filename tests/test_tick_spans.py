@@ -360,7 +360,7 @@ def test_the_other_forwards_name_the_same_scopes(forward):
     params = init_params(cfg, jax.random.PRNGKey(0), dtype=jnp.float32)
     L, KV, HD = cfg.n_layers, cfg.n_kv_heads, cfg.head_dim
     B, T, page, n_pages = 2, 8, 16, 4
-    pool = jnp.zeros((L, n_pages, page, KV, HD))
+    pool = jnp.zeros((L, n_pages, page, KV * HD))    # as stored
     tok = jnp.zeros((B, T), jnp.int32)
     pos = jnp.zeros((B, T), jnp.int32)
     lens = jnp.full((B,), T, jnp.int32)
@@ -435,10 +435,12 @@ def test_each_kernel_entry_point_pins_its_name(kernel):
     lens = jnp.full((B,), 8, jnp.int32)
     zero = jnp.zeros((B,), jnp.int32)
     if kernel == "ragged_attend":
+        stored = jnp.zeros((2, n_pages, page, KV * HD))
+
         def f():
             return pa.ragged_attend(
-                jnp.zeros((8, H, HD)), pool, pool, tables,
-                jnp.zeros((4, 1), jnp.int32), tq=8, interpret=True)
+                jnp.zeros((8, H, HD)), stored, stored, tables,
+                jnp.zeros((4, 1), jnp.int32), 1, tq=8, interpret=True)
     elif kernel == "paged_attend":
         def f():
             return pa.paged_attend(q3, pool, pool, tables, lens, zero,
@@ -455,9 +457,15 @@ def test_each_kernel_entry_point_pins_its_name(kernel):
                 jnp.zeros((B, 128, KV, HD)),
                 jnp.zeros((B, 128), jnp.int32), lens, interpret=True)
     assert pallas_names(jax.make_jaxpr(f)().jaxpr) == [kernel]
-    if kernel != "flash_attend":
-        # the pools' re-layout for the kernel has a scope of its own
-        assert has_scope(op_names(jax.jit(f).lower()), "kv_layout")
+    names = op_names(jax.jit(f).lower())
+    if kernel in ("paged_attend", "paged_prefill_attend"):
+        # the split kernels re-lay their 4-D view of a layer's pool,
+        # under a scope of its own
+        assert has_scope(names, "kv_layout")
+    elif kernel == "ragged_attend":
+        # the serving kernel reads the pool as stored: at a production
+        # head_dim nothing stands between the store and the kernel
+        assert not has_scope(names, "kv_layout")
 
 
 # ---------------------------------------------------------------------------
