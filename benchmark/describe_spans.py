@@ -3,7 +3,7 @@
 the builder's tool beside `describe_trace.py` (which lists planes and
 lines). No run of the benchmark calls it.
 
-    python3 -m benchmark.with_spans --workload <cell> --seed 1 --seconds 20 --trace 1
+    python3 -m benchmark.run --workload <cell> --seed 1 --seconds 20 --trace 1
     python3 -m benchmark.describe_spans <cell> FILE
 
 Writes to FILE: every line of the `/host:CPU` plane with its event count

@@ -9,12 +9,13 @@ def decode_step_seconds(ctx, metric):
         return None
     mods = matching(t["modules"], metric["module_pattern"])
     busy = sum(m["busy_s"] for m in mods.values())
-    # the operation that runs once per layer per step, counted inside the
-    # decode programs only
+    # the operation the configuration's family says marks a step, and how
+    # often it runs in one, counted inside the decode programs only
+    mark = ctx["family"].decode_step_mark(ctx["config"])
     hits = sum(sum(matching(t["module_ops"].get(m, {}),
-                            metric["step_op_pattern"]).values())
+                            mark["op_pattern"]).values())
                for m in mods)
-    steps = hits / int(ctx["config"]["num_hidden_layers"])
+    steps = hits / mark["per_step"]
     if not busy or not steps:
         return None
     return busy / steps

@@ -1,8 +1,9 @@
 """Device time of one decode step: the busy time of the decode program's
 executions in the trace, over the decode steps they ran. A step is counted
-by the operation that runs once per layer per step (`step_op_pattern`, the
-attention kernel), so steps = its executions inside decode programs / the
-configuration's layers."""
+by the operation the configuration's family names for it
+(`decode_step_mark`: for the dense family the attention kernel, once a
+layer), so steps = its executions inside decode programs / its runs a
+step."""
 
 from benchmark.readers._decode import decode_step_seconds
 

@@ -2,9 +2,9 @@
 innermost `jax.named_scope` name on each operation's `tf_op` path,
 `benchmark/scopes.json`), over the busy time of the programs whose name
 matches `module_pattern`. One reader, one data file a class: the scopes are
-data. `layers` alone, with no sub-scope, is what the scan itself emits to
-slice a layer's pool out and stack it back. A `[scopes]` line gives every
-scope's share, `(unscoped)` among them."""
+data. `layers` alone, with no sub-scope, is what the layer scan itself
+emits: its slices of the stacked weights (and, before PR 25, of the pools).
+A `[scopes]` line gives every scope's share, `(unscoped)` among them."""
 
 from benchmark import spans
 

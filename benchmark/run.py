@@ -12,12 +12,17 @@ round — from one thread per client, closed loop. Phases: set-up (weights on
 the device from the seed, warm-up, a lead-in that spreads the clients out),
 the measured window of `--seconds`, then — the window closed and the
 program's memory freed — the comparison of a seeded sample of the window's
-own greedy rows with `benchmark/reference.py`.
+own greedy rows with the plain reference of the configuration's family
+(`benchmark/families/<family>.py`; this file names no architecture).
 
 The last line of stdout is one JSON object: `correct`, `attempted`,
-`failed`, `metrics`, `device` and, with `--trace 1`, `breakdown`. Realised
-lengths, the generator's lateness, compile counters and every number of the
-output check beside its limit go on earlier lines.
+`failed`, `metrics`, `device`, with `--trace 1` `breakdown`, and last
+`checks`: every number of the output check beside its limit. Realised
+lengths, the generator's lateness, compile counters, the time clients spent
+dropping sessions (`[drops]`), with `--trace 1` what the profiler session
+cost the batcher's worker (`[tracing]`), and the same checks one to a
+`[check]` line go on earlier lines; the `[check]` lines are also the last
+lines of stderr.
 
 Exits non-zero, printing no result line, when the cell is one of
 `BENCHMARK.json` and JAX finds no TPU or another number of chips than the
@@ -61,20 +66,22 @@ def say(tag: str, obj) -> None:
                          else json.dumps(obj, default=str)), flush=True)
 
 
-def load_cells() -> tuple[dict, dict, dict]:
+def load_cells(root: str = HERE) -> tuple[dict, dict, dict]:
     """(cells of BENCHMARK.json, rehearsal cells), each by name, and the
     end-to-end metrics as BENCHMARK.json lists them."""
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         manifest = json.load(f)
-    with open(os.path.join(HERE, "cells_rehearsal.json")) as f:
-        tiny = {w["name"]: w for w in json.load(f)["workloads"]}
+    tiny = {w["name"]: w for w in configs.load_json(
+        root, "cells_rehearsal.json")["workloads"]}
     return ({w["name"]: w for w in manifest["workloads"]}, tiny,
             manifest["end_to_end"])
 
 
-def load_metric(name: str) -> dict:
-    with open(os.path.join(HERE, "metrics", f"{name}.json")) as f:
-        return json.load(f)
+def per_layer_names(mix: dict, raw_cfg: dict) -> list:
+    """The per-layer metrics a cell reports: its mix's list and then its
+    configuration's own, each name once."""
+    return list(dict.fromkeys(mix["per_layer"]
+                              + raw_cfg.get("per_layer", [])))
 
 
 class CompileLog:
@@ -127,8 +134,12 @@ def counters(backend, spec: str) -> dict:
 
 
 def client_loop(client, backend, spec: str, engine, log: list, lock,
-                stop: threading.Event, t_open: list) -> None:
-    """One closed-loop client: think, send, wait, note, again."""
+                stop: threading.Event, t_open: list, drops: list) -> None:
+    """One closed-loop client: think, send, wait, note, again. What it
+    does BETWEEN turns no latency sees: before its next turn it releases
+    the sessions that ended (a traffic mix's `drop`), and the engine's drop
+    waits for the lock the batcher holds through a whole tick; `drops`
+    takes the seconds of each such call."""
     from quoracle_tpu.models.runtime import QueryRequest
     prev = None
     while not stop.is_set():
@@ -141,7 +152,9 @@ def client_loop(client, backend, spec: str, engine, log: list, lock,
                             "completion_tokens": 0, "latency_ms": 0.0})
             return
         for sid in turn.drop:
+            t = time.monotonic()
             backend.drop_session(sid)
+            drops.append(time.monotonic() - t)
         due = time.monotonic() + turn.think_s
         if stop.wait(max(0.0, due - time.monotonic())):
             return
@@ -171,6 +184,29 @@ def client_loop(client, backend, spec: str, engine, log: list, lock,
         with lock:
             log.append(row)
         prev = res
+
+
+def tick_phase_ms(model: str) -> dict:
+    """The batcher worker's time by phase so far, from the program's own
+    counter (`quoracle_tick_phase_ms_total`), and when it was read."""
+    from quoracle_tpu.infra.telemetry import TICK_PHASE_MS_TOTAL, TICK_PHASES
+    return {"t": time.monotonic(),
+            "ms": {p: TICK_PHASE_MS_TOTAL.value(model=model, phase=p)
+                   for p in TICK_PHASES}}
+
+
+def worker_time(a: dict, b: dict) -> dict:
+    """Between two readings of `tick_phase_ms`: the worker's time waiting
+    for the device, idle, and in host work, by kind of phase."""
+    d = {p: b["ms"][p] - a["ms"][p] for p in a["ms"]}
+    device = d["wait_prefill"] + d["wait_decode"]
+    return {"seconds": round(b["t"] - a["t"], 3),
+            "device_wait_ms": round(device, 1),
+            "idle_ms": round(d["idle"], 1),
+            "host_ms": round(sum(d.values()) - device - d["idle"], 1),
+            "host_ms_by_phase": {p: round(v, 2) for p, v in d.items()
+                                 if v and not p.startswith("wait_")
+                                 and p != "idle"}}
 
 
 def drive(rt, spec: str, cell: dict, mix: dict, args, clog: CompileLog,
@@ -203,9 +239,10 @@ def drive(rt, spec: str, cell: dict, mix: dict, args, clog: CompileLog,
     lock = threading.Lock()
     stop = threading.Event()
     t_open: list = []
+    drops: list = []
     threads = [threading.Thread(
         target=client_loop, name=c.name, daemon=True,
-        args=(c, backend, spec, engine, log, lock, stop, t_open))
+        args=(c, backend, spec, engine, log, lock, stop, t_open, drops))
         for c in clients]
     for th in threads:
         th.start()
@@ -224,9 +261,18 @@ def drive(rt, spec: str, cell: dict, mix: dict, args, clog: CompileLog,
         opts = jax.profiler.ProfileOptions()
         opts.python_tracer_level = 0       # the device and the runtime's
         opts.host_tracer_level = 1         # own threads, not every frame
+        # what the session costs: the worker's time by phase while it is
+        # open, and over as long a stretch of the same window right after
+        marks = [tick_phase_ms(engine.cfg.name)]
         jax.profiler.start_trace(trace_dir, profiler_options=opts)
         time.sleep(min(TRACE_SECONDS, args.seconds / 2))
         jax.profiler.stop_trace()
+        marks.append(tick_phase_ms(engine.cfg.name))
+        time.sleep(max(0.0, min(marks[1]["t"] - marks[0]["t"],
+                                t0 + args.seconds - time.monotonic())))
+        marks.append(tick_phase_ms(engine.cfg.name))
+        say("tracing", {"session_open": worker_time(marks[0], marks[1]),
+                        "right_after": worker_time(marks[1], marks[2])})
     time.sleep(max(0.0, t0 + args.seconds - time.monotonic()))
     t1 = time.monotonic()
     after = counters(backend, spec)
@@ -236,6 +282,10 @@ def drive(rt, spec: str, cell: dict, mix: dict, args, clog: CompileLog,
         th.join(timeout=120)
     alive = [th.name for th in threads if th.is_alive()]
     mem = [d.memory_stats() or {} for d in jax.devices()]
+    say("drops", {"calls": len(drops), "total_s": round(sum(drops), 3),
+                  "max_ms": round(1000 * max(drops, default=0.0), 1),
+                  "over_100_ms": sum(t > 0.1 for t in drops),
+                  "note": "whole run, lead-in included"})
     return {"log": log, "t0": t0, "t1": t1, "before": before, "after": after,
             "trace_dir": trace_dir, "alive": alive,
             "warm_missed": report["missed"], "quant": quant,
@@ -244,7 +294,7 @@ def drive(rt, spec: str, cell: dict, mix: dict, args, clog: CompileLog,
             "compiles": clog.between(t0, t1)}
 
 
-def check_window(raw_cfg: dict, mix: dict, seen: dict) -> list:
+def check_window(family, raw_cfg: dict, mix: dict, seen: dict) -> list:
     """The conditions on the window's rows that are part of `correct`:
     (name, value, limit, passed)."""
     rows = stats.in_window(seen["log"], seen["t0"], seen["t1"])
@@ -263,13 +313,14 @@ def check_window(raw_cfg: dict, mix: dict, seen: dict) -> list:
         n = sum(r["cached_tokens"] != 0 for r in ok)
         checks.append(("rows_with_cached_tokens", n, 0, n == 0))
     # the precision the configuration states is the precision served: the
-    # engine's own account of what a resident token costs, against the
-    # configuration's shapes at its stated type, and no quantized weights.
-    # (A path as quiet as int8 KV pages is beneath what the comparison of
-    # served tokens below can tell from bfloat16's own rounding: PERF.md.)
-    stated = configs.kv_bytes_per_token(raw_cfg)
-    n = seen["quant"]["kv_bytes_per_token"]
-    checks.append(("kv_bytes_per_token", n, stated, n == stated))
+    # engine's own account of what a resident token costs, key by key
+    # against what the family reckons from the configuration's shapes at
+    # its stated type, and no quantized weights. (A path as quiet as int8
+    # KV pages is beneath what the comparison of served tokens below can
+    # tell from bfloat16's own rounding: PERF.md.)
+    for key, stated in family.stated_precision(raw_cfg).items():
+        n = seen["quant"][key]
+        checks.append((key, n, stated, n == stated))
     n = int(bool(seen["quant"]["quantize_weights"]))
     checks.append(("weights_quantized", n, 0, n == 0))
     # nothing compiles inside the window: a program key that warm-up did
@@ -282,16 +333,17 @@ def check_window(raw_cfg: dict, mix: dict, seen: dict) -> list:
     return checks
 
 
-def check_reference(raw_cfg: dict, limits: dict, seen: dict, seed: int,
-                    detail: dict | None = None) -> list:
+def check_reference(family, raw_cfg: dict, limits: dict, seen: dict,
+                    seed: int, detail: dict | None = None) -> list:
     """A seeded sample of the window's own greedy rows, the longest among
-    them, through the plain reference: the widest and the mean gap by which
-    a served token's logit lies below the reference's best. `detail`, the
-    builder's (benchmark/control.py), is filled with the reference, its
-    logits and each row's gaps, for the readings a limit is set from."""
+    them, through the family's plain reference: the widest and the mean gap
+    by which a served token's logit lies below the reference's best.
+    `detail`, the builder's (benchmark/control.py), is filled with the
+    reference, its logits and each row's gaps, for the readings a limit is
+    set from."""
     import numpy as np
     from benchmark import draws
-    from benchmark.reference import Reference, gaps_of, served_logits
+    from benchmark.reference import gaps_of, served_logits
     rows = [r for r in stats.in_window(seen["log"], seen["t0"], seen["t1"])
             if r.get("ids") and len(r["ids"]) > r["prompt_tokens"]]
     want = int(limits["reference_rows"])
@@ -305,7 +357,7 @@ def check_reference(raw_cfg: dict, limits: dict, seen: dict, seed: int,
     pad_to = max(int(limits["reference_pad_to"]),
                  -(-max(len(r["ids"]) for r in sample) // 512) * 512)
     t = time.monotonic()
-    ref = Reference(configs.model_kwargs(raw_cfg), seed)
+    ref = family.Reference(raw_cfg, seed)
     logits = [served_logits(ref, r["ids"], r["prompt_tokens"], pad_to)
               for r in sample]
     gaps = [gaps_of(lg, np.asarray(r["ids"][r["prompt_tokens"]:]))
@@ -342,10 +394,10 @@ def free_program(rt) -> None:
         a.delete()
 
 
-async def serve_and_drive(cell, mix, raw_cfg, args, clog, warm,
+async def serve_and_drive(family, cell, mix, raw_cfg, args, clog, warm,
                           more_serve_args=()) -> dict:
     from quoracle_tpu import cli
-    spec = configs.register(raw_cfg)
+    spec = family.register(raw_cfg)
     # `cli serve` has no --seed: the weights and the sampler take the
     # Runtime's seed, which the flags never set. Bind it here, through
     # RuntimeConfig's own field, so that --seed makes the weights too.
@@ -368,17 +420,20 @@ async def serve_and_drive(cell, mix, raw_cfg, args, clog, warm,
     return seen
 
 
-def run(args, more_serve_args=(), detail: dict | None = None) -> int:
+def run(args, more_serve_args=(), detail: dict | None = None,
+        root: str = HERE) -> int:
     """One run of one cell. `more_serve_args` and `detail` are the
     builder's (benchmark/control.py): the program's lower-precision flag,
-    and a place for what the output check read."""
-    real, tiny, end_to_end = load_cells()
+    and a place for what the output check read. `root` is a test's: where
+    the benchmark's data files and families are looked for first
+    (`configs.find`)."""
+    real, tiny, end_to_end = load_cells(root)
     cell = real.get(args.workload) or tiny.get(args.workload)
     if cell is None:
         print(f"benchmark: unknown workload {args.workload!r}; cells: "
               f"{sorted(real)}; rehearsal: {sorted(tiny)}", file=sys.stderr)
         return 2
-    raw_cfg = configs.load_config(cell["config"])
+    raw_cfg = configs.load_config(cell["config"], root)
     for key, value in raw_cfg.get("env", {}).items():
         # rehearsal configurations only: a file of the benchmark's own
         # that the program reads through its environment
@@ -411,11 +466,11 @@ def run(args, more_serve_args=(), detail: dict | None = None) -> int:
               f"{devs[0].device_kind!r} in benchmark/peaks.json",
               file=sys.stderr)
         return 2
-    mix = traffic.load_traffic(cell["traffic"])
+    family = configs.family(raw_cfg, root)
+    mix = traffic.load_traffic(cell["traffic"], root)
     # what belongs to the cell and not to its mix: the program keys to warm
     # and, where the cell's readings differ from the mix's, its own limits
-    with open(os.path.join(HERE, "warm", f"{cell['name']}.json")) as f:
-        warm = json.load(f)
+    warm = configs.load_json(root, "warm", f"{cell['name']}.json")
     mix["checks"] = {**mix["checks"], **warm.get("checks", {})}
     say("cell", {"name": cell["name"], "seed": args.seed,
                  "seconds": args.seconds, "trace": args.trace,
@@ -424,8 +479,8 @@ def run(args, more_serve_args=(), detail: dict | None = None) -> int:
                             len(devs)]})
     os.makedirs(OUT_DIR, exist_ok=True)
 
-    seen = asyncio.run(serve_and_drive(cell, mix, raw_cfg, args, clog, warm,
-                                       more_serve_args))
+    seen = asyncio.run(serve_and_drive(family, cell, mix, raw_cfg, args,
+                                       clog, warm, more_serve_args))
     t0, t1 = seen["t0"], seen["t1"]
     e2e = stats.end_to_end(seen["log"], t0, t1)
     rows = stats.in_window(seen["log"], t0, t1)
@@ -460,11 +515,13 @@ def run(args, more_serve_args=(), detail: dict | None = None) -> int:
         if v != seen["before"]["shapes"].get(k, 0)},
         "first_seen_in_window": new_shapes})
 
-    checks = check_window(raw_cfg, mix, seen)
-    checks += check_reference(raw_cfg, mix["checks"], seen, args.seed, detail)
-    for name, value, limit, passed in checks:
-        say("check", {"name": name, "value": value, "limit": limit,
-                      "passed": passed})
+    checks = check_window(family, raw_cfg, mix, seen)
+    checks += check_reference(family, raw_cfg, mix["checks"], seen,
+                              args.seed, detail)
+    said = [{"name": name, "value": value, "limit": limit, "passed": passed}
+            for name, value, limit, passed in checks]
+    for c in said:
+        say("check", c)
     correct = all(c[3] for c in checks)
     if detail is not None:
         detail["checks"] = checks
@@ -497,11 +554,12 @@ def run(args, more_serve_args=(), detail: dict | None = None) -> int:
         breakdown = trace_reduce.breakdown(reduced)
         ctx = {"rows": rows, "ok": ok, "before": seen["before"],
                "after": seen["after"], "compiles": seen["compiles"],
-               "trace": reduced, "config": raw_cfg, "mix": mix,
+               "trace": reduced, "config": raw_cfg, "family": family,
+               "mix": mix,
                "peaks": peaks.get(devs[0].device_kind),
                "seconds": t1 - t0}
-        for name in mix["per_layer"]:
-            m = load_metric(name)
+        for name in per_layer_names(mix, raw_cfg):
+            m = configs.load_json(root, "metrics", f"{name}.json")
             reader = importlib.import_module(
                 f"benchmark.readers.{m['reader']}")
             value = reader.read(ctx, m)
@@ -511,7 +569,14 @@ def run(args, more_serve_args=(), detail: dict | None = None) -> int:
             "failed": e2e["failed"], "metrics": metrics, "device": device}
     if breakdown is not None:
         line["breakdown"] = breakdown
-    print(json.dumps(line), flush=True)
+    # each number compared beside its limit: last in the result line, and
+    # the last lines of stderr
+    line["checks"] = {name: {"value": value, "limit": limit}
+                      for name, value, limit, _ in checks}
+    print(json.dumps(line, default=float), flush=True)
+    for c in said:
+        print("[check] " + json.dumps(c, default=str), file=sys.stderr,
+              flush=True)
     return 0
 
 
@@ -524,8 +589,8 @@ def parser(description: str) -> argparse.ArgumentParser:
     return ap
 
 
-def main(argv=None) -> int:
-    return run(parser(__doc__.split("\n\n")[0]).parse_args(argv))
+def main(argv=None, root: str = HERE) -> int:
+    return run(parser(__doc__.split("\n\n")[0]).parse_args(argv), root=root)
 
 
 if __name__ == "__main__":

@@ -21,8 +21,9 @@ import numpy as np
 import pytest
 
 from benchmark import configs
-from benchmark.reference import Reference, served_logits
+from benchmark.families import dense
 from benchmark.reference import gaps_of as gaps
+from benchmark.reference import served_logits
 
 
 def gaps_of(seed: int, quantize: bool) -> np.ndarray:
@@ -30,13 +31,13 @@ def gaps_of(seed: int, quantize: bool) -> np.ndarray:
     from quoracle_tpu.models.generate import GenerateEngine
     from quoracle_tpu.models.tokenizer import ByteTokenizer
     from quoracle_tpu.models.transformer import init_params
-    kw = configs.model_kwargs(configs.load_config("tiny-l2"))
-    cfg = ModelConfig(**kw)
+    raw = configs.load_config("tiny-l2")
+    cfg = ModelConfig(**dense.model_kwargs(raw))
     eng = GenerateEngine(cfg, init_params(cfg, jax.random.PRNGKey(seed)),
                          ByteTokenizer(), seed=seed,
                          quantize_weights=quantize)
     eng.unified_min_tokens = 0
-    ref = Reference(kw, seed)
+    ref = dense.Reference(raw, seed)
     rng = np.random.default_rng(seed)
     out = []
     for r in range(8):
@@ -130,8 +131,7 @@ def test_the_reference_lowered_to_int8_puts_other_tokens_first():
     """The control of the chip runs, at toy size: the same model from int8
     weights disagrees with the float32 reference about the best token often
     enough to read, and by margins no rounding of bfloat16 reaches."""
-    kw = configs.model_kwargs(configs.load_config("tiny-l2"))
-    ref = Reference(kw, 5)
+    ref = dense.Reference(configs.load_config("tiny-l2"), 5)
     tokens = np.random.default_rng(5).integers(3, 512, 512).astype(np.int32)
     rows = np.arange(512)
     sound = ref.logits(tokens, rows)
@@ -140,3 +140,23 @@ def test_the_reference_lowered_to_int8_puts_other_tokens_first():
     assert np.abs(low - sound).max() < 0.5          # the same model, still
     g = gaps(sound, low.argmax(-1))
     assert (g > 0).sum() >= 5 and g.mean() > 0.0005
+
+
+@pytest.mark.parametrize("sound,serve_args,correct,lowered_passes,want", [
+    (True, ["--quantize-kv"], True, False, True),     # --sound: correct
+    (True, [], False, False, False),
+    (False, ["--quantize-kv"], False, None, True),    # the program's path
+    (False, ["--quantize-kv"], True, False, False),
+    (False, [], True, False, True),     # no path: the lowered reference
+    (False, [], True, True, False),     # ... which passed both limits
+    (False, [], False, False, False),   # ... or the sound run was not sound
+    (False, [], True, None, False),     # ... or no row was compared
+])
+def test_what_a_control_run_has_to_come_out_as(sound, serve_args, correct,
+                                               lowered_passes, want):
+    """`control.serve_args` empty is what a family whose program has no
+    lower-precision path states: then the reference lowered to int8, in
+    the program's place, has to fail a limit the sound run passes."""
+    from benchmark import control
+    assert control.as_it_should(sound, serve_args, correct,
+                                lowered_passes) is want

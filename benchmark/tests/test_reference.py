@@ -1,33 +1,39 @@
-"""The plain reference against the program's own dense forward at a tiny
-size, for a windowed, a biased-and-tied and a plain configuration: the two
-are written apart and must agree; and the weights both draw from one seed
-are the same bits."""
-
-import dataclasses
+"""The dense family's plain reference against the program's own dense
+forward at a tiny size, for a windowed, a biased-and-tied and a plain
+configuration: the two are written apart and must agree; and the weights
+both draw from one seed are the same bits. Both sides are built from one
+configuration file's published keys, so the family's mapping is under test
+too."""
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from benchmark.reference import Reference, gaps_of, served_logits
+from benchmark.families import dense
+from benchmark.reference import gaps_of, served_logits
 from quoracle_tpu.models.config import ModelConfig
 from quoracle_tpu.models.transformer import (
     forward_hidden, init_cache, init_params, project_logits,
 )
 
-BASE = dict(name="t", vocab_size=512, dim=64, n_layers=2, n_heads=4,
-            n_kv_heads=2, ffn_dim=128, context_window=512, norm_eps=1e-6,
-            rope_theta=1e6)
+BASE = dict(name="t", family="dense", vocab_size=512, hidden_size=64,
+            num_hidden_layers=2, num_attention_heads=4,
+            num_key_value_heads=2, intermediate_size=128,
+            max_position_embeddings=512, rms_norm_eps=1e-6, rope_theta=1e6,
+            hidden_act="silu", tie_word_embeddings=False,
+            torch_dtype="bfloat16", eos_token_id=2, bos_token_id=1)
 
 
 @pytest.mark.parametrize("extra", [
     dict(sliding_window=24),
-    dict(attn_bias=True, tie_embeddings=True, n_kv_heads=1),
+    dict(attention_bias=True, tie_word_embeddings=True,
+         num_key_value_heads=1),
     dict(),
 ], ids=["windowed", "biased-tied", "plain"])
 def test_reference_agrees_with_forward_hidden(extra):
-    cfg = ModelConfig(**{**BASE, **extra})
+    raw = {**BASE, **extra}
+    cfg = ModelConfig(**dense.model_kwargs(raw))
     seed = 2 ** 31 + 11
     params = init_params(cfg, jax.random.PRNGKey(seed), dtype=jnp.bfloat16)
     T = 96
@@ -39,8 +45,7 @@ def test_reference_agrees_with_forward_hidden(extra):
             init_cache(cfg, 1, T, dtype=jnp.float32),
             jnp.zeros((1,), jnp.int32), jnp.asarray([T]))
         want = np.asarray(project_logits(p32, cfg, hid))[0]
-    ref = Reference({f.name: getattr(cfg, f.name)
-                     for f in dataclasses.fields(cfg)}, seed)
+    ref = dense.Reference(raw, seed)
     assert bool(jnp.all(ref.w["wq"] == params["layers"]["wq"]))
     assert bool(jnp.all(ref.w["embed"] == params["embed"]))
     got = ref.logits(np.pad(toks[0], (0, 32)), np.arange(T))

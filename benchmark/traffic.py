@@ -17,11 +17,10 @@ from __future__ import annotations
 
 import dataclasses
 import importlib
-import json
 import os
 import re
 
-from benchmark import draws
+from benchmark import configs, draws
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 
@@ -68,9 +67,8 @@ class SeededText:
         return len(self.tok.encode_chat([{"role": "user", "content": ""}]))
 
 
-def load_traffic(name: str) -> dict:
-    with open(os.path.join(HERE, "traffic", f"{name}.json")) as f:
-        return json.load(f)
+def load_traffic(name: str, root: str = HERE) -> dict:
+    return configs.load_json(root, "traffic", f"{name}.json")
 
 
 def load_generator(kind: str):
