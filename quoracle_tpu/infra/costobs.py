@@ -367,7 +367,10 @@ class Roofline:
         L = cfg.n_layers
         n_kv = getattr(cfg, "n_kv_heads", None) or cfg.n_heads
         hd = getattr(cfg, "head_dim", None) or (cfg.dim // cfg.n_heads)
-        if getattr(engine, "quantize_kv", False):
+        if getattr(cfg, "latent", None) is not None:
+            # one latent row a layer (config.kv_pools), never int8
+            self.kv_token_bytes = engine.kv_token_pool_bytes()
+        elif getattr(engine, "quantize_kv", False):
             # int8 K+V plus one f32 scale per (token, kv-head) each
             self.kv_token_bytes = 2 * L * n_kv * (hd + 4)
         else:
@@ -375,6 +378,17 @@ class Roofline:
                                            engine._raw_param_dtype)).itemsize
             self.kv_token_bytes = 2 * L * n_kv * hd * cache_item
         self.attn_flops_per_tok_ctx = 4 * L * cfg.dim   # x ctx at use
+        # what a token's forward multiplies by: of an expert layer's held
+        # experts only the few it is routed to (config.n_active_params)
+        self.flop_params = self.n_params
+        if getattr(cfg, "moe", None) is not None:
+            self.flop_params = cfg.n_active_params
+        if getattr(cfg, "latent", None) is not None:
+            # folded form: every head's score over the stored row and
+            # its value over the latent
+            la = cfg.latent
+            self.attn_flops_per_tok_ctx = 2 * L * cfg.n_heads * (
+                2 * la.kv_rank + la.rope_dim)
         self.peak_flops, self.peak_bw = device_peaks()
         self._lock = named_lock("costobs")
         self._best: dict[tuple, _MfuBest] = {}   # (stage, bucket)
@@ -386,7 +400,7 @@ class Roofline:
         Returns the observation dict (or None when unscorable)."""
         if wall_s <= 0 or real_tokens <= 0:
             return None
-        flops = real_tokens * (2 * self.n_params
+        flops = real_tokens * (2 * self.flop_params
                                + self.attn_flops_per_tok_ctx * ctx)
         byts = (max(1, steps) * self.weight_bytes
                 + real_tokens * (ctx + 1) * self.kv_token_bytes)

@@ -695,6 +695,22 @@ SCHED_PADDED_TOKENS_TOTAL = METRICS.counter(
     "device chunk-token slots processed across generate ticks (real + "
     "padding), per model — [B·T] on the bucketed paths, the flat token "
     "budget on the unified ragged path")
+# -- expert layers (ISSUE 27) ------------------------------------------------
+# Booked once a tick from the int32 [4] the ragged programs return with
+# their outputs (transformer._routed_experts): what the router sent where.
+MOE_ASSIGNMENTS_TOTAL = METRICS.counter(
+    "quoracle_moe_assignments_total",
+    "token-expert assignments the router made (valid tokens × experts per "
+    "token, summed over expert layers and steps), per model; held = true "
+    "for those to experts this process holds and computes")
+MOE_EXPERTS_REACHED_TOTAL = METRICS.counter(
+    "quoracle_moe_experts_reached_total",
+    "held experts that received at least one token, summed over expert "
+    "layers and steps, per model")
+MOE_LAYER_STEPS_TOTAL = METRICS.counter(
+    "quoracle_moe_layer_steps_total",
+    "expert layers run (one per layer per forward step with a valid "
+    "token), per model")
 # -- the batcher's tick record (ISSUE 24) -----------------------------------
 # One record per ContinuousBatcher._loop iteration, built on the worker
 # thread where the work happens (models/scheduler.py, models/generate.py).
@@ -739,7 +755,8 @@ class TickRecord:
     """One batcher loop iteration: integer-ns time per phase, the
     annotation arguments a trace reader needs (``model``, ``rows``,
     ``admitted``, ``real_tokens``, ``padded_tokens``, ``decode_steps``,
-    ``program``), and the prefill fence of the tick (``fence_ns``: the
+    ``program``, ``context_tokens``, an expert model's ``moe_*`` counts),
+    and the prefill fence of the tick (``fence_ns``: the
     instant ``wait_prefill`` last ended — a row's first-token stamp)."""
 
     __slots__ = ("t0_ns", "t1_ns", "phase_ns", "args", "fence_ns",

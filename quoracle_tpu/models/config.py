@@ -23,6 +23,60 @@ OUTPUT_FLOOR = 4096
 
 
 @dataclasses.dataclass(frozen=True)
+class LatentConfig:
+    """Latent attention (MLA, the DeepSeek-V2 form): queries through a
+    low-rank bottleneck, keys and values up-projected from ONE compressed
+    latent per token. A resident token holds ``kv_rank`` latent values and
+    ``rope_dim`` rotary key values a layer, shared by every head; the
+    per-head keys and values are never stored (the serving path folds the
+    up-projections into the query and the output)."""
+
+    q_rank: int          # q_lora_rank
+    kv_rank: int         # kv_lora_rank
+    nope_dim: int        # qk_nope_head_dim
+    rope_dim: int        # qk_rope_head_dim
+    v_dim: int           # v_head_dim
+
+    @property
+    def qk_dim(self) -> int:
+        return self.nope_dim + self.rope_dim
+
+    @property
+    def lanes(self) -> int:
+        """Lanes a resident token takes in a layer's page as STORED: the
+        latent and the rotary key side by side, rounded up to the TPU's
+        128-lane tile (a minor dimension of 576 is padded to 640 in HBM
+        whether or not the program says so; the pad lanes hold zeros)."""
+        return -(-(self.kv_rank + self.rope_dim) // 128) * 128
+
+
+@dataclasses.dataclass(frozen=True)
+class MoEConfig:
+    """Routed experts with a shared expert (the DeepSeek-V3 form): the
+    router scores ALL ``n_routed`` experts; this process HOLDS experts
+    ``held_start .. held_start + n_held`` (its share of an expert-parallel
+    deployment; the whole set when ``n_held == n_routed``) and computes
+    only their part of a token's result plus the shared expert."""
+
+    n_routed: int            # the router's width, as published
+    n_held: int              # experts held here
+    per_token: int           # num_experts_per_tok
+    expert_dim: int          # moe_intermediate_size
+    n_shared: int = 1        # shared experts (each expert_dim wide)
+    n_group: int = 1
+    topk_group: int = 1
+    routed_scale: float = 1.0
+    norm_topk: bool = True
+    first_dense: int = 0     # leading layers with the dense MLP
+    held_start: int = 0
+
+    def __post_init__(self):
+        assert self.n_routed % self.n_group == 0
+        assert 0 <= self.held_start \
+            and self.held_start + self.n_held <= self.n_routed
+
+
+@dataclasses.dataclass(frozen=True)
 class ModelConfig:
     """Architecture + serving config for one decoder-only transformer.
 
@@ -55,8 +109,17 @@ class ModelConfig:
     attn_bias: bool = False
     # RoPE frequency scaling, hashable: ("linear", factor) or
     # ("llama3", factor, low_freq_factor, high_freq_factor, original_max_pos).
+    # ("yarn", factor, beta_fast, beta_slow, original_max_pos, mscale,
+    # mscale_all_dim): NTK-by-parts frequencies, and the attention scale
+    # takes mscale squared (transformer.attn_softmax_scale).
     # None = unscaled. (Kept a tuple so ModelConfig stays hashable for jit.)
     rope_scaling: Optional[tuple] = None
+    # Latent attention with routed experts (frozen sub-records, so the
+    # config stays hashable; the two come together: no configuration asks
+    # for one without the other). Such a model is served on the ragged
+    # paged path only (``require_plain`` at every other path's entry).
+    latent: Optional[LatentConfig] = None
+    moe: Optional[MoEConfig] = None
 
     # --- serving metadata (what the reference pulled from LLMDB) ---
     context_window: int = 8192
@@ -91,27 +154,76 @@ class ModelConfig:
         if self.head_dim is None:
             object.__setattr__(self, "head_dim", self.dim // self.n_heads)
         assert self.n_heads % self.n_kv_heads == 0, "GQA requires n_heads % n_kv_heads == 0"
+        assert (self.latent is None) == (self.moe is None), \
+            "latent attention and routed experts are served together only"
+        if self.moe is not None:
+            assert 0 <= self.moe.first_dense < self.n_layers
 
     @property
     def q_per_kv(self) -> int:
         return self.n_heads // self.n_kv_heads
 
     @property
-    def n_params(self) -> int:
-        """Exact decoder parameter count (embeddings + per-layer attn/mlp/
-        norms + final norm + untied head) — the input to the HBM budget."""
+    def plain(self) -> bool:
+        """Dense decoder with per-head K and V: every path serves it. A
+        latent, routed-expert model runs on the ragged paged path alone."""
+        return self.latent is None
+
+    @property
+    def kv_pools(self) -> tuple:
+        """THE statement of what a resident token holds in one layer: the
+        lane count of each stored pool. Per-head K and V: two pools of
+        ``n_kv_heads·head_dim`` lanes; a latent cache: ONE pool of
+        ``latent.lanes``. Pool shapes (generate.py ``_ensure_pool``), the
+        byte rates below, ``kv_signature``, ``quant_stats`` and
+        ``pool_sizing`` all read this."""
+        if self.latent is not None:
+            return (self.latent.lanes,)
+        return (self.n_kv_heads * self.head_dim,) * 2
+
+    @property
+    def n_dense_layers(self) -> int:
+        return self.n_layers if self.moe is None else self.moe.first_dense
+
+    def _attn_params(self) -> int:
+        if self.latent is not None:
+            la, H = self.latent, self.n_heads
+            return (self.dim * la.q_rank + la.q_rank
+                    + la.q_rank * H * la.qk_dim
+                    + self.dim * (la.kv_rank + la.rope_dim) + la.kv_rank
+                    + la.kv_rank * H * (la.nope_dim + la.v_dim)
+                    + H * la.v_dim * self.dim)
         hd = self.head_dim
-        embed = self.vocab_size * self.dim
         q = self.dim * self.n_heads * hd + (self.n_heads * hd
                                             if self.attn_bias else 0)
         kv = 2 * (self.dim * self.n_kv_heads * hd
                   + (self.n_kv_heads * hd if self.attn_bias else 0))
-        o = self.n_heads * hd * self.dim
-        mlp = 3 * self.dim * self.ffn_dim          # gate + up + down
+        return q + kv + self.n_heads * hd * self.dim
+
+    def _layer_params(self, experts: Optional[int]) -> int:
+        """One layer's parameters; ``experts`` None = the dense MLP, else
+        that many routed experts beside the router and the shared one."""
         norms = 2 * self.dim
-        per_layer = q + kv + o + mlp + norms
+        if experts is None:
+            mlp = 3 * self.dim * self.ffn_dim      # gate + up + down
+        else:
+            m = self.moe
+            mlp = (self.dim * m.n_routed
+                   + 3 * self.dim * m.expert_dim * (experts + m.n_shared))
+        return self._attn_params() + mlp + norms
+
+    @property
+    def n_params(self) -> int:
+        """Exact decoder parameter count HELD here (embeddings + per-layer
+        attn/mlp/norms + final norm + untied head; an expert model's held
+        experts) — the input to the HBM budget."""
+        embed = self.vocab_size * self.dim
         head = 0 if self.tie_embeddings else self.vocab_size * self.dim
-        total = embed + self.n_layers * per_layer + self.dim + head
+        total = (embed + self.dim + head
+                 + self.n_dense_layers * self._layer_params(None))
+        if self.moe is not None:
+            total += (self.n_layers - self.n_dense_layers) \
+                * self._layer_params(self.moe.n_held)
         if self.vision is not None:
             # ViT tower + projector come out of the same HBM budget
             # (models/vision.py init_vision_params structure)
@@ -127,11 +239,38 @@ class ModelConfig:
                       + v.dim * v.out_dim)          # projector
         return total
 
+    @property
+    def n_active_params(self) -> int:
+        """Parameters one token's forward multiplies by: ``n_params`` with
+        ``per_token`` routed experts a layer in place of the held ones."""
+        if self.moe is None:
+            return self.n_params
+        m = self.moe
+        return self.n_params - (self.n_layers - m.first_dense) \
+            * 3 * self.dim * m.expert_dim * (m.n_held - m.per_token)
+
     def kv_bytes_per_token(self, tp: int = 1, dtype_bytes: int = 2) -> int:
         """KV cache bytes per resident token PER TP SHARD (whole GQA
-        groups per shard: kv heads divide across tp)."""
-        return 2 * (self.n_kv_heads // tp) * self.head_dim * \
-            self.n_layers * dtype_bytes
+        groups per shard: kv heads divide across tp; a latent cache has
+        no heads to divide and is whole on every shard)."""
+        lanes = sum(self.kv_pools)
+        if self.latent is None:
+            lanes //= tp
+        return lanes * self.n_layers * dtype_bytes
+
+
+def unsupported_path(cfg: ModelConfig, what: str) -> str:
+    """The one error text for a path that cannot serve a model with
+    latent attention or expert layers."""
+    return (f"model {cfg.name} (latent attention and routed experts) is "
+            f"served on the ragged paged path of one device only; {what} "
+            f"cannot run it")
+
+
+def require_plain(cfg: ModelConfig, what: str) -> None:
+    """Refuse, at the path's entry, a model it would compute wrongly."""
+    if not cfg.plain:
+        raise ValueError(unsupported_path(cfg, what))
 
 
 # ---------------------------------------------------------------------------

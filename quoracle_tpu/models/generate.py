@@ -24,7 +24,9 @@ import numpy as np
 from quoracle_tpu.infra.telemetry import (
     DECODE_MS, DECODE_STEP_MS, PREFILL_MS, TRACER, tick_note, tick_phase,
 )
-from quoracle_tpu.models.config import ModelConfig
+from quoracle_tpu.models.config import (
+    ModelConfig, require_plain, unsupported_path,
+)
 from quoracle_tpu.models.sampling import sample_tokens
 from quoracle_tpu.models.transformer import (
     KVCache, forward_hidden, forward_hidden_ragged, init_cache,
@@ -368,8 +370,10 @@ def decode_ragged(
     holds the compiled program to that.
 
     Returns (tokens [R, max_new], n_emitted [R], lens [R], k_pool,
-    v_pool, k_scale, v_scale, jstate) where lens counts the row's valid
-    pool tokens (prompt + chunk + emitted-and-forwarded). With
+    v_pool, k_scale, v_scale, jstate, moe_stats) where lens counts the
+    row's valid pool tokens (prompt + chunk + emitted-and-forwarded) and
+    ``moe_stats`` is the expert layers' int32 [4] summed over the steps
+    (transformer.forward_hidden_ragged; None without experts). With
     ``k_scale``/``v_scale`` (int8 pools, ISSUE 13; None otherwise, and
     returned as they came) each step's token quantizes on write inside
     the forward."""
@@ -390,7 +394,7 @@ def decode_ragged(
 
     def body(carry):
         (i, done, cur, out, n_emitted, lens, kp, vp, ks, vs, rng,
-         jstate) = carry
+         jstate, moe) = carry
         with jax.named_scope("row_state"):
             live = (~done).astype(jnp.int32)
             # this step's token writes at buffer slot lens; done rows (and
@@ -408,10 +412,12 @@ def decode_ragged(
                 jnp.arange(R, dtype=jnp.int32),   # one tq=1 block per row
             ])
             positions = lens + kv_off.astype(jnp.int32)
-        hidden, kp, vp, ks, vs = forward_hidden_ragged(
+        hidden, kp, vp, ks, vs, st = forward_hidden_ragged(
             params, cfg, cur[None], positions[None], kp, vp, tables,
             meta, flat, tq=1, interpret=interpret, shard=shard,
             k_scale=ks, v_scale=vs)
+        if st is not None:
+            moe = moe + st
         logits = project_logits(params, cfg, hidden)[0]      # [R, V]
         nxt, rng = _draw(mask_logits, logits, jstate, rng, temperature,
                          top_p)
@@ -424,18 +430,20 @@ def decode_ragged(
             jstate = advance(jstate, nxt, done)
             done = done | is_stop(nxt) | (n_emitted >= row_limit)
         return (i + 1, done, nxt, out, n_emitted, lens, kp, vp, ks, vs,
-                rng, jstate)
+                rng, jstate, moe)
 
     # unquantized loops carry scale placeholders as empty pytrees (None
     # is a valid while_loop carry leaf-less node)
     init = (jnp.asarray(1, jnp.int32), done0, tok0, out0, n0, lens0,
-            k_pool, v_pool, k_scale, v_scale, rng, jstate0)
+            k_pool, v_pool, k_scale, v_scale, rng, jstate0,
+            None if cfg.moe is None else jnp.zeros((4,), jnp.int32))
     # what the loop itself emits carries ``decode_loop`` and no sub-scope
     # (until PR 25: a copy of each loop-carried pool every step)
     with jax.named_scope("decode_loop"):
         (_, done, _, out, n_emitted, lens, k_pool, v_pool, k_scale,
-         v_scale, _, jstate) = jax.lax.while_loop(cond, body, init)
-    return out, n_emitted, lens, k_pool, v_pool, k_scale, v_scale, jstate
+         v_scale, _, jstate, moe) = jax.lax.while_loop(cond, body, init)
+    return (out, n_emitted, lens, k_pool, v_pool, k_scale, v_scale, jstate,
+            moe)
 
 
 def _round_up(n: int, buckets: Sequence[int]) -> int:
@@ -1018,6 +1026,13 @@ class GenerateEngine:
                 f"engine {cfg.name}: int8 quantized serving "
                 f"(--quantize-weights/--quantize-kv) serves on "
                 f"single-device engines; drop the mesh or the flags")
+        # latent attention / expert layers are served on the ragged paged
+        # path alone: one refusal per path, at start
+        for on, what in ((mesh is not None, "a device mesh (--tp > 1)"),
+                         (self.quantize_kv, "--quantize-kv"),
+                         (self.quantize_weights, "--quantize-weights")):
+            if on:
+                require_plain(cfg, what)
         # Params dtype drives the dense working-cache dtype; capture it
         # BEFORE weight quantization turns leaves int8.
         self._raw_param_dtype = jax.tree.leaves(params)[0].dtype
@@ -1058,10 +1073,7 @@ class GenerateEngine:
         # giant pool from the byte budget alone. Int8 pools count their
         # per-(token, head) scales, so resident_kv_tokens lands at ~2x
         # the bf16 figure at the same byte budget (ISSUE 13).
-        from quoracle_tpu.models.quant import kv_token_bytes
-        token_bytes = kv_token_bytes(
-            cfg.n_layers, cfg.n_kv_heads, cfg.head_dim,
-            jnp.dtype(self.pool_dtype).itemsize, self.quantize_kv)
+        token_bytes = self.kv_token_pool_bytes()
         self.sessions = SessionStore(
             max_tokens=max(PAGE, min(session_max_bytes // token_bytes,
                                      32 * self.max_seq)))
@@ -1110,6 +1122,11 @@ class GenerateEngine:
         # (0 on TPU, off elsewhere — CPU serving sticks with the fused
         # gather programs; tests force the unified path explicitly).
         self.unified_min_tokens = resolve_unified_gate(gates)
+        if not cfg.plain:
+            # the ragged path IS the serving path of a latent / expert
+            # model, on every platform (the CPU runs the kernels' gather
+            # references); where a tick cannot take it, it raises
+            self.unified_min_tokens = 0
         if self.quantize_kv:
             # Quantized KV serves through the unified ragged path (the
             # kernel dequantizes in its streaming loop; the gather refs
@@ -1590,7 +1607,7 @@ class GenerateEngine:
             # chunk KV scattered to the rows' pages inside the forward.
             # Shapes key on (flat token budget, page-table width) only:
             # the batch-bucket × prompt-bucket program matrix collapses.
-            hidden, k_pool, v_pool, k_scale, v_scale = \
+            hidden, k_pool, v_pool, k_scale, v_scale, moe = \
                 forward_hidden_ragged(
                     params, cfg, tokens_flat[None], positions_flat[None],
                     k_pool, v_pool, row_tables, block_meta, flat_dst,
@@ -1598,7 +1615,7 @@ class GenerateEngine:
                     v_scale=v_scale)
             last_h = hidden[0][last_idx]                  # [R, D]
             last = project_logits(params, cfg, last_h[:, None])[:, 0, :]
-            return last, k_pool, v_pool, k_scale, v_scale
+            return last, k_pool, v_pool, k_scale, v_scale, moe
 
         @functools.partial(jax.jit, donate_argnums=(1, 2, 3, 4),
                            static_argnames=("tq", "kmax", "need_probs"))
@@ -1613,7 +1630,7 @@ class GenerateEngine:
             # to pages — committed prefixes resident for the next round,
             # LCP resume is still the rollback) and verdict logits
             # project at the flat indices of each row's last K positions.
-            hidden, k_pool, v_pool, k_scale, v_scale = \
+            hidden, k_pool, v_pool, k_scale, v_scale, _ = \
                 forward_hidden_ragged(
                     params, cfg, tokens_flat[None], positions_flat[None],
                     k_pool, v_pool, row_tables, block_meta, flat_dst,
@@ -1903,8 +1920,10 @@ class GenerateEngine:
         # bytes move (and never share a disk-store directory) — the
         # degrade is a cold re-prefill, exactly the version-skew path.
         # Unquantized engines keep the historic signature unchanged.
+        geometry = (f"x{cfg.n_kv_heads}x{cfg.head_dim}" if cfg.latent is None
+                    else f"xlatent{cfg.kv_pools[0]}")
         return (f"{cfg.name.replace('/', '_')}-L{cfg.n_layers}"
-                f"x{cfg.n_kv_heads}x{cfg.head_dim}-p{self.sessions.page}"
+                f"{geometry}-p{self.sessions.page}"
                 f"-{jnp.dtype(self.pool_dtype).name}"
                 + ("-q8kv" if self.quantize_kv else ""))
 
@@ -1921,6 +1940,9 @@ class GenerateEngine:
         Returns the TierManager (also at ``sessions.tier``)."""
         from quoracle_tpu.serving.kvtier import TierManager
         cfg = self.cfg
+        require_plain(cfg, "the host and disk KV tiers (--host-kv-mb, "
+                           "--disk-kv-dir, and --disaggregate, which "
+                           "hands sessions over through them)")
         tier = TierManager(self.sessions, model=cfg.name,
                            host_mb=host_mb, disk_dir=disk_dir,
                            paged_lock=self._paged_lock,
@@ -2138,6 +2160,13 @@ class GenerateEngine:
                     reuse_abs[i] = p
                     kv_off_host[i] = s.start_pos
 
+        if not self.cfg.plain:
+            # no dense-cache forward for this family: rows without a
+            # session ride the paged path on scratch pages
+            if use_ring or images is not None:
+                require_plain(self.cfg,
+                              "the sequence-parallel ring / image rows")
+            paged = True
         prefixes = [r - o for r, o in zip(reuse_abs, kv_off_host)]  # buffer
         suffixes = [list(p[r:]) for p, r in zip(prompts, reuse_abs)]
         max_chunk = max(len(s) for s in suffixes)
@@ -2426,6 +2455,25 @@ class GenerateEngine:
         SCHED_PADDED_TOKENS_TOTAL.inc(int(padded), model=name)
         tick_note(real_tokens=int(real), padded_tokens=int(padded))
 
+    def _note_moe(self, stats) -> None:
+        """Book one tick's expert-layer counts (the int32 [4] the two
+        programs return with their outputs: assignments, of them to held
+        experts, held experts reached summed over layers and steps,
+        expert layers run), once a tick, on the counters and the tick
+        span."""
+        from quoracle_tpu.infra.telemetry import (
+            MOE_ASSIGNMENTS_TOTAL, MOE_EXPERTS_REACHED_TOTAL,
+            MOE_LAYER_STEPS_TOTAL,
+        )
+        total, held, reached, steps = (int(v) for v in stats)
+        name = self.cfg.name
+        MOE_ASSIGNMENTS_TOTAL.inc(held, model=name, held="true")
+        MOE_ASSIGNMENTS_TOTAL.inc(total - held, model=name, held="false")
+        MOE_EXPERTS_REACHED_TOTAL.inc(reached, model=name)
+        MOE_LAYER_STEPS_TOTAL.inc(steps, model=name)
+        tick_note(moe_assignments=total, moe_held=held,
+                  moe_reached=reached, moe_layer_steps=steps)
+
     def padding_stats(self) -> dict:
         """Cumulative padding-waste view for /api/resources: what
         raggedness reclaims, quantified per engine."""
@@ -2443,6 +2491,9 @@ class GenerateEngine:
         quantized; plain cache bytes otherwise) — the shared byte rate
         for resources attribution, /api/kv compression and planning."""
         from quoracle_tpu.models.quant import kv_token_bytes
+        if self.cfg.latent is not None:    # one pool, never int8
+            return self.cfg.kv_bytes_per_token(
+                dtype_bytes=jnp.dtype(self.pool_dtype).itemsize)
         return kv_token_bytes(
             self.cfg.n_layers, self.cfg.n_kv_heads, self.cfg.head_dim,
             jnp.dtype(self.pool_dtype).itemsize, self.quantize_kv)
@@ -2451,9 +2502,8 @@ class GenerateEngine:
         """The member's quantization posture for /api/kv and bench
         config 19: mode flags, the per-token KV byte rate vs the bf16
         rate, and the resulting compression ratio."""
-        bf16_rate = (2 * self.cfg.n_layers * self.cfg.n_kv_heads
-                     * self.cfg.head_dim
-                     * jnp.dtype(self.cache_dtype).itemsize)
+        bf16_rate = self.cfg.kv_bytes_per_token(
+            dtype_bytes=jnp.dtype(self.cache_dtype).itemsize)
         rate = self.kv_token_pool_bytes()
         return {
             "quantize_weights": self.quantize_weights,
@@ -2471,7 +2521,10 @@ class GenerateEngine:
         ONE stored layout, ``[L, n_pages, page, KV·hd]``: a token's
         kv-heads lie side by side in the lane dimension, which is the
         form the ragged kernel streams a page in
-        (ops/paged_attention.ragged_attend). The serving programs carry
+        (ops/paged_attention.ragged_attend). What a token holds in a
+        layer is ``cfg.kv_pools``: a latent model has ONE pool,
+        ``[L, n_pages, page, latent.lanes]`` under ``st.k`` (``st.v``
+        stays None, as the scale pools do). The serving programs carry
         these two buffers through their layer scan and decode loop and
         update them in place; who wants ``[…, KV, hd]`` takes a view —
         a reshape of the fresh rows on the device, of the pages on the
@@ -2484,8 +2537,8 @@ class GenerateEngine:
         st = self.sessions
         if st.k is not None:
             return
-        shape = (self.cfg.n_layers, st.n_pages, st.page,
-                 self.cfg.n_kv_heads * self.cfg.head_dim)
+        lanes = self.cfg.kv_pools
+        shape = (self.cfg.n_layers, st.n_pages, st.page, lanes[0])
         sh = None
         if self.mesh is not None:
             # created in its sharding: no chip ever holds the whole pool
@@ -2496,7 +2549,8 @@ class GenerateEngine:
             kv_axis = "tp" if self.cfg.n_kv_heads % tp == 0 else None
             sh = NamedSharding(self.mesh, P(None, None, None, kv_axis))
         k = jnp.zeros(shape, self.pool_dtype, device=sh)
-        v = jnp.zeros(shape, self.pool_dtype, device=sh)
+        v = (jnp.zeros(shape, self.pool_dtype, device=sh)
+             if len(lanes) == 2 else None)
         if self.quantize_kv:
             sshape = (self.cfg.n_layers, st.n_pages,
                       self.cfg.n_kv_heads, st.page)
@@ -2723,6 +2777,30 @@ class GenerateEngine:
                                for i in range(n)))
 
         vout = None
+        if not use_unified and not self.cfg.plain:
+            # give back what this call took, then refuse: the gather
+            # programs compute per-head K and V and a dense MLP. A row
+            # whose stored session keeps every page it had loses only
+            # the pages added now; any other row's session is forgotten
+            # with the pages this call left it (it re-prefills).
+            with st.lock:
+                for i in range(n):
+                    stored = st._sessions.get(store_sids[i] or "")
+                    pages = dst_lists[i]
+                    if pages is not None and stored is not None \
+                            and set(stored.pages) <= set(pages):
+                        had = set(stored.pages)
+                        st._release([pg for pg in pages if pg not in had])
+                    elif pages is not None:
+                        st._sessions.pop(store_sids[i], None)
+                        st._release(pages)
+                    for taken in (temp_lists[i], adopted_release[i]):
+                        if taken:
+                            st._release(taken)
+            raise RuntimeError(unsupported_path(
+                self.cfg, "the gather fallback of a paged tick (page pool "
+                "exhausted, a shared boundary page swapped, or "
+                "_force_gather_decode)"))
         if use_unified:
             (out, n_emitted, final_lens, jstate_f, vout, t_prefill,
              now) = self._run_unified(
@@ -3002,8 +3080,11 @@ class GenerateEngine:
                     t_prefill, now)
 
         self._pending.shape_key = ("ragged", TB, R, maxp_p2, max_new)
+        # resident tokens this tick's rows attend to (their kv length at
+        # the chunk's end): what the attention kernel streams each step
+        tick_note(context_tokens=int(r_pool_lens.sum()))
         tick_phase("dispatch_prefill")
-        last_logits, st.k, st.v, st.k_scale, st.v_scale = \
+        last_logits, st.k, st.v, st.k_scale, st.v_scale, moe_pre = \
             self._step_paged_ragged(
                 self.params, st.k, st.v, st.k_scale, st.v_scale,
                 jnp.asarray(flat_tok),
@@ -3015,7 +3096,7 @@ class GenerateEngine:
         t_prefill = time.monotonic()
         tick_phase("dispatch_decode")
         (out, n_emitted, final_lens, st.k, st.v, st.k_scale, st.v_scale,
-         jstate_f) = \
+         jstate_f, moe_dec) = \
             self._step_paged_decode_ragged(
                 self.params, st.k, st.v, st.k_scale, st.v_scale,
                 jnp.asarray(r_tables),
@@ -3030,6 +3111,19 @@ class GenerateEngine:
         final_lens = np.asarray(final_lens)
         jax.block_until_ready(st.k)
         now = time.monotonic()
+        if moe_pre is not None:
+            self._note_moe(np.asarray(moe_pre) + np.asarray(moe_dec))
+        # what the attention kernel had to do this tick, for its roofline
+        # (a reader's lower bounds): resident tokens streamed — each row's
+        # context once for its chunk and once per decode step — and
+        # query-key pairs attended under the causal mask
+        seg = np.asarray(segs, np.int64)
+        ctx = r_pool_lens[:n].astype(np.int64)
+        fwd = final_lens[:n].astype(np.int64) - ctx     # decode forwards
+        dec = int((fwd * ctx + fwd * (fwd + 1) // 2).sum())
+        tick_note(attn_kv_reads=int(ctx.sum()) + dec,
+                  attn_pairs=int((seg * (ctx - seg)
+                                  + seg * (seg + 1) // 2).sum()) + dec)
         return out, n_emitted, final_lens, jstate_f, None, t_prefill, now
 
     def _json_table_device(self, enum_set: tuple):

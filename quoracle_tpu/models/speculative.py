@@ -137,6 +137,9 @@ class SpeculativeDecoder:
                  draft_cfg: ModelConfig, draft_params: dict,
                  tokenizer, *, k: int = 6, max_seq: int = 2048,
                  seed: int = 0, cache_dtype=None):
+        from quoracle_tpu.models.config import require_plain
+        for c in (target_cfg, draft_cfg):
+            require_plain(c, "speculative serving (--draft)")
         assert target_cfg.vocab_size == draft_cfg.vocab_size, \
             "draft and target must share one tokenizer/vocab"
         assert target_cfg.sliding_window is None \
@@ -627,6 +630,9 @@ class BatchedSpeculator:
                  accept_floor: float = 0.35, shrink_below: float = 0.6,
                  grow_above: float = 0.85, ewma_alpha: float = 0.15,
                  reprobe_after: int = 24, seed: int = 0):
+        from quoracle_tpu.models.config import require_plain
+        for eng in (target_engine, draft_engine):
+            require_plain(eng.cfg, "speculative serving (--draft)")
         assert target_engine.cfg.vocab_size == draft_engine.cfg.vocab_size, \
             "draft and target must share one tokenizer/vocab"
         assert target_engine.cfg.sliding_window is None \

@@ -41,12 +41,13 @@ it to the kernel whole. The older split paths (``forward_hidden_paged``,
 from __future__ import annotations
 
 import functools
+import math
 from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
 
-from quoracle_tpu.models.config import ModelConfig
+from quoracle_tpu.models.config import ModelConfig, require_plain
 from quoracle_tpu.models.quant import (
     dequant_weight, is_quantized, kv_quant,
 )
@@ -101,6 +102,9 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16,
     k_embed, k_layers, k_head = jax.random.split(key, 3)
     L, D, F = cfg.n_layers, cfg.dim, cfg.ffn_dim
     H, KV, HD = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+    if not cfg.plain:
+        assert mesh is None, "latent/expert models are not sharded yet"
+        return _init_params_stacks(cfg, k_embed, k_layers, k_head, dtype)
     if mesh is not None:
         from jax.sharding import NamedSharding
         from quoracle_tpu.parallel.mesh import param_specs
@@ -152,6 +156,88 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16,
     return params
 
 
+# Leaves of a latent, routed-expert model's layer stacks, in the order that
+# numbers their keys (``_init_params_stacks``): (name, shape, fan-in).
+def _stack_leaves(cfg: ModelConfig, experts: bool) -> list:
+    D, H, la = cfg.dim, cfg.n_heads, cfg.latent
+    attn = [("wq_a", (D, la.q_rank), D),
+            ("wq_b", (la.q_rank, H * la.qk_dim), la.q_rank),
+            ("wkv_a", (D, la.kv_rank + la.rope_dim), D),
+            ("wkv_b", (la.kv_rank, H * (la.nope_dim + la.v_dim)),
+             la.kv_rank),
+            ("wo", (H * la.v_dim, D), H * la.v_dim)]
+    if not experts:
+        F = cfg.ffn_dim
+        return attn + [("w_gate", (D, F), D), ("w_up", (D, F), D),
+                       ("w_down", (F, D), F)]
+    m = cfg.moe
+    Fe, Fs = m.expert_dim, m.expert_dim * m.n_shared
+    return attn + [("router", (D, m.n_routed), D),
+                   ("we_gate", (D, Fe), D), ("we_up", (D, Fe), D),
+                   ("we_down", (Fe, D), Fe),
+                   ("ws_gate", (D, Fs), D), ("ws_up", (D, Fs), D),
+                   ("ws_down", (Fs, D), Fs)]
+
+
+@functools.partial(jax.jit, static_argnames=("n", "shape", "fan_in",
+                                             "dtype"))
+def _expert_leaf(key, first, n, shape, fan_in, dtype):
+    """[L, n, ...]: expert ``first + e`` of every layer drawn from
+    ``fold_in(key, first + e)``, so an expert's weights do not depend on
+    which share of the experts holds it."""
+    def one(e):
+        return (jax.random.normal(jax.random.fold_in(key, e), shape,
+                                  jnp.float32) * (fan_in ** -0.5)
+                ).astype(dtype)
+    return jax.vmap(one, out_axes=1)(first + jnp.arange(n))
+
+
+def _init_params_stacks(cfg: ModelConfig, k_embed, k_layers, k_head,
+                        dtype) -> dict:
+    """Random init of a latent, routed-expert model: two layer stacks, the
+    leading dense layers under ``dense_layers`` and the expert layers under
+    ``layers``. THE RULE
+    (the benchmark's reference draws the same bits from it): a leaf is
+    normal/sqrt(fan-in) rounded to ``dtype``, drawn at its stacked shape
+    ``[layers of the stack, ...]`` from ``fold_in(fold_in(k_layers,
+    stack), i)`` with stack 0 = ``dense_layers``, 1 = ``layers`` and ``i``
+    the leaf's place in ``_stack_leaves``; a routed-expert leaf
+    ``[L, n_held, ...]`` draws expert ``e`` (its global number) at
+    ``[L, ...]`` from ``fold_in(that key, e)``; norms are one; embed and
+    lm_head as for every model."""
+    D = cfg.dim
+    params = {
+        "embed": _normal_leaf(k_embed, (cfg.vocab_size, D), D, dtype, None),
+        "final_norm": jnp.ones((D,), dtype),
+    }
+    if not cfg.tie_embeddings:
+        params["lm_head"] = _normal_leaf(k_head, (D, cfg.vocab_size), D,
+                                         dtype, None)
+    n_dense = cfg.n_dense_layers
+    for stack, name, n, experts in ((0, "dense_layers", n_dense, False),
+                                    (1, "layers", cfg.n_layers - n_dense,
+                                     True)):
+        if n == 0:
+            continue
+        ks = jax.random.fold_in(k_layers, stack)
+        leaves = {"attn_norm": jnp.ones((n, D), dtype),
+                  "mlp_norm": jnp.ones((n, D), dtype),
+                  "q_norm": jnp.ones((n, cfg.latent.q_rank), dtype),
+                  "kv_norm": jnp.ones((n, cfg.latent.kv_rank), dtype)}
+        for i, (leaf, shape, fan_in) in enumerate(
+                _stack_leaves(cfg, experts)):
+            k = jax.random.fold_in(ks, i)
+            if leaf.startswith("we_"):
+                leaves[leaf] = _expert_leaf(
+                    k, cfg.moe.held_start, cfg.moe.n_held, (n, *shape),
+                    fan_in, dtype)
+            else:
+                leaves[leaf] = _normal_leaf(k, (n, *shape), fan_in, dtype,
+                                            None)
+        params[name] = leaves
+    return params
+
+
 def rmsnorm(x: jax.Array, w: jax.Array, eps: float, plus_one: bool) -> jax.Array:
     xf = x.astype(jnp.float32)
     normed = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
@@ -187,15 +273,60 @@ def _scale_rope_freqs(freqs: jax.Array, scaling: Optional[tuple]) -> jax.Array:
     raise ValueError(f"unsupported rope scaling {kind!r}")
 
 
+def yarn_correction_range(beta_fast: float, beta_slow: float, dim: int,
+                          theta: float, orig_max: int) -> tuple:
+    """The rotary dimensions between which YaRN blends: where a frequency
+    turns ``beta`` times over ``orig_max`` positions,
+    dim·ln(orig_max / (2π·beta)) / (2·ln theta), floored for beta_fast and
+    ceiled for beta_slow, inside [0, dim/2 - 1] (dim/2 as published)."""
+    def at(beta):
+        return dim * math.log(orig_max / (beta * 2 * math.pi)) \
+            / (2 * math.log(theta))
+    return (max(math.floor(at(beta_fast)), 0),
+            min(math.ceil(at(beta_slow)), dim - 1))
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def attn_softmax_scale(cfg: ModelConfig) -> float:
+    """What a latent model's scores are multiplied by: 1/sqrt(key width),
+    and under YaRN the square of ``yarn_mscale(factor, mscale_all_dim)``."""
+    scale = cfg.latent.qk_dim ** -0.5
+    sc = cfg.rope_scaling
+    if sc is not None and sc[0] == "yarn":
+        scale *= yarn_mscale(sc[1], sc[6]) ** 2
+    return scale
+
+
 def rope(x: jax.Array, positions: jax.Array, theta: float,
          scaling: Optional[tuple] = None) -> jax.Array:
     """Rotary embedding. x: [B, T, heads, hd]; positions: [B, T]."""
     hd = x.shape[-1]
     half = hd // 2
     freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
-    freqs = _scale_rope_freqs(freqs, scaling)
+    yarn = scaling is not None and scaling[0] == "yarn"
+    if yarn:
+        # NTK-by-parts (YaRN): frequency i keeps its value where it turns
+        # more than beta_fast times over the original context, is divided
+        # by ``factor`` where it turns less than beta_slow times, and is
+        # blended linearly between the two dimensions those counts give
+        _, factor, beta_fast, beta_slow, orig_max = scaling[:5]
+        low, high = yarn_correction_range(beta_fast, beta_slow, hd, theta,
+                                          orig_max)
+        ramp = jnp.clip((jnp.arange(half, dtype=jnp.float32) - low)
+                        / max(high - low, 1e-3), 0.0, 1.0)
+        freqs = freqs / factor * ramp + freqs * (1.0 - ramp)
+    else:
+        freqs = _scale_rope_freqs(freqs, scaling)
     angles = positions.astype(jnp.float32)[:, :, None, None] * freqs  # [B,T,1,half]
     cos, sin = jnp.cos(angles), jnp.sin(angles)
+    if yarn:
+        # cos/sin carry mscale / mscale_all_dim (1 where the two agree)
+        ms = yarn_mscale(scaling[1], scaling[5]) \
+            / yarn_mscale(scaling[1], scaling[6])
+        cos, sin = cos * ms, sin * ms
     x1, x2 = x[..., :half].astype(jnp.float32), x[..., half:].astype(jnp.float32)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)
@@ -277,6 +408,169 @@ def _attn_out(x: jax.Array, attn: jax.Array, p: dict,
     return x + jnp.einsum("bthd,hdD->btD", attn, wo)
 
 
+def _latent_qkv(x: jax.Array, p: dict, cfg: ModelConfig,
+                positions: jax.Array) -> tuple[jax.Array, jax.Array]:
+    """Latent attention's inputs in the FOLDED form, for a flat tick
+    ``x [1, Tp, D]``: the row a token stores, ``[c_kv | k_rope | 0]``
+    (``[Tp, lanes]``), and the queries every head puts against such rows,
+    ``[q_nope·W_kb^K | q_rope | 0]`` (``[Tp, H, lanes]``): the key
+    up-projection rides the query, so a cached latent is read as it lies
+    (scope ``qkv`` ⊃ ``latent_proj``, then ``rope``)."""
+    la, H = cfg.latent, cfg.n_heads
+    Tp = x.shape[1]
+    with jax.named_scope("qkv"), jax.named_scope("latent_proj"):
+        h = rmsnorm(x, p["attn_norm"], cfg.norm_eps, cfg.rmsnorm_plus_one)
+        cq = rmsnorm(jnp.einsum("btd,dr->btr", h, p["wq_a"]), p["q_norm"],
+                     cfg.norm_eps, False)
+        q = jnp.einsum("btr,rh->bth", cq, p["wq_b"]).reshape(
+            1, Tp, H, la.qk_dim)
+        ckv = jnp.einsum("btd,dr->btr", h, p["wkv_a"])
+        c_kv = rmsnorm(ckv[..., :la.kv_rank], p["kv_norm"], cfg.norm_eps,
+                       False)
+        # fold: score_i = (q_nope_i W_kb,i^K^T)·c_kv + q_rope_i·k_rope
+        w_k = p["wkv_b"].reshape(la.kv_rank, H,
+                                 la.nope_dim + la.v_dim)[..., :la.nope_dim]
+        q_lat = jnp.einsum("bthn,chn->bthc", q[..., :la.nope_dim], w_k)
+    with jax.named_scope("rope"):
+        q_rope = rope(q[..., la.nope_dim:], positions, cfg.rope_theta,
+                      cfg.rope_scaling)
+        k_rope = rope(ckv[:, :, None, la.kv_rank:], positions,
+                      cfg.rope_theta, cfg.rope_scaling)[:, :, 0]
+    pad = la.lanes - la.kv_rank - la.rope_dim
+    row = jnp.concatenate(
+        [c_kv, k_rope, jnp.zeros((1, Tp, pad), x.dtype)], axis=-1)[0]
+    q_full = jnp.concatenate(
+        [q_lat, q_rope, jnp.zeros((1, Tp, H, pad), x.dtype)], axis=-1)[0]
+    return q_full, row
+
+
+@jax.named_scope("attn_out")
+def _latent_attn_out(x: jax.Array, attn: jax.Array, p: dict,
+                     cfg: ModelConfig) -> jax.Array:
+    """Residual + the value up-projection folded into the output:
+    ``o_i = (p_i·c_kv) W_kb,i^V``, then ``W_o``. attn: [Tp, H, kv_rank]."""
+    la, H = cfg.latent, cfg.n_heads
+    with jax.named_scope("latent_proj"):
+        w_v = p["wkv_b"].reshape(la.kv_rank, H,
+                                 la.nope_dim + la.v_dim)[..., la.nope_dim:]
+        o = jnp.einsum("thc,chv->thv", attn.astype(x.dtype), w_v)
+    wo = p["wo"].reshape(H, la.v_dim, cfg.dim)
+    return x + jnp.einsum("thv,hvD->tD", o, wo)[None]
+
+
+def moe_select(logits: jax.Array, m) -> tuple[jax.Array, jax.Array]:
+    """Router logits [T, n_routed] float32 → (experts [T, k] int32, gates
+    [T, k] float32): sigmoid scores; the experts fall into ``n_group``
+    groups, a group scores the sum of its two largest, the ``topk_group``
+    best groups stay; the ``k`` largest scores inside them are selected;
+    gates are the selected scores over their sum (``norm_topk``) times
+    ``routed_scale``. Ties go to the lower index."""
+    T, E = logits.shape
+    s = jax.nn.sigmoid(logits.astype(jnp.float32))
+    pick = s
+    if m.n_group > 1:
+        g = s.reshape(T, m.n_group, E // m.n_group)
+        group = jax.lax.top_k(g, 2)[0].sum(-1)               # [T, n_group]
+        keep = jax.lax.top_k(group, m.topk_group)[1]         # [T, topk]
+        mask = jnp.zeros((T, m.n_group), bool).at[
+            jnp.arange(T)[:, None], keep].set(True)
+        pick = jnp.where(mask[:, :, None], g, -1.0).reshape(T, E)
+    gates, idx = jax.lax.top_k(pick, m.per_token)
+    if m.norm_topk:
+        gates = gates / (gates.sum(-1, keepdims=True) + 1e-20)
+    return idx.astype(jnp.int32), gates * m.routed_scale
+
+
+def _gated(h: jax.Array, wg: jax.Array, wu: jax.Array, wd: jax.Array,
+           act: str) -> jax.Array:
+    return (_activation(h @ wg, act) * (h @ wu)) @ wd
+
+
+# Rows of one block of the grouped matmul: a block holds assignments to ONE
+# expert (an expert's assignments are padded up to whole blocks).
+MOE_BLOCK = 256
+
+
+def _routed_experts(h: jax.Array, idx: jax.Array, gates: jax.Array,
+                    w: tuple, layer, m, act: str,
+                    valid: jax.Array) -> tuple[jax.Array, jax.Array]:
+    """The HELD experts' part of the routed sum, exact for every routing:
+    ``Σ_{e selected and held} g_e FFN_e(h)`` for each valid token; what an
+    absent expert would add is left out. h [T, D]; idx/gates [T, k];
+    ``w`` the stacked expert weights ``[L, n_held, ...]`` whole, read at
+    ``[layer, e]``. Returns ([T, D] float32, int32 [4]: assignments,
+    assignments to held experts, held experts with a token, 1).
+
+    Grouped by expert without a capacity: the token-expert assignments to
+    held experts are sorted by expert, each expert's run is cut into
+    blocks of ``MOE_BLOCK`` rows, and a loop over the blocks THAT EXIST
+    gathers a block's tokens, multiplies them by that one expert's three
+    matrices and adds the gated result to its tokens' rows. Work and
+    weight traffic follow the real routing (a decode step reads only the
+    experts its rows reached); the worst case, every token choosing held
+    experts, only makes the loop longer."""
+    T, D = h.shape
+    k, E = m.per_token, m.n_held
+    A = T * k
+    blk = min(MOE_BLOCK, -(-T // 8) * 8)
+    local = idx - m.held_start
+    held = (local >= 0) & (local < E) & valid[:, None]
+    local = jnp.where(held, local, E).reshape(A)
+    order = jnp.argsort(local, stable=True).astype(jnp.int32)
+    counts = (local[:, None] == jnp.arange(E, dtype=jnp.int32)).sum(
+        0, dtype=jnp.int32)                                  # [E]
+    starts = jnp.cumsum(counts) - counts
+    n_blk = (counts + blk - 1) // blk
+    blk_end = jnp.cumsum(n_blk)
+    flat_gates = gates.reshape(A)
+    wg, wu, wd = w
+
+    def body(b, out):
+        e = jnp.sum(blk_end <= b).astype(jnp.int32)          # b's expert
+        pos = starts[e] + (b - (blk_end[e] - n_blk[e])) * blk \
+            + jnp.arange(blk, dtype=jnp.int32)
+        ok = pos < starts[e] + counts[e]
+        a = order[jnp.minimum(pos, A - 1)]
+        tok = a // k
+        y = _gated(h[tok], wg[layer, e], wu[layer, e], wd[layer, e], act)
+        y = y.astype(jnp.float32) * jnp.where(ok, flat_gates[a], 0.0)[:, None]
+        # a block's tokens ascend (stable sort), the dropped rows last
+        return out.at[jnp.where(ok, tok, T)].add(y, mode="drop",
+                                                 indices_are_sorted=True)
+
+    out = jax.lax.fori_loop(0, blk_end[-1], body,
+                            jnp.zeros((T, D), jnp.float32))
+    stats = jnp.stack([valid.sum(dtype=jnp.int32) * k, counts.sum(),
+                       (counts > 0).sum(dtype=jnp.int32),
+                       valid.any().astype(jnp.int32)])
+    return out, stats
+
+
+@jax.named_scope("mlp")
+def _moe(x: jax.Array, p: dict, experts: tuple, layer, cfg: ModelConfig,
+         valid: jax.Array) -> tuple[jax.Array, jax.Array]:
+    """The expert layer of a flat tick ``x [1, T, D]``: router over ALL
+    ``n_routed`` experts in float32 (scope ``router``), the held experts'
+    part of the routed sum (``routed_experts``), the shared expert
+    (``shared_expert``), residual. Returns (x, stats of
+    ``_routed_experts``)."""
+    m = cfg.moe
+    h = rmsnorm(x, p["mlp_norm"], cfg.norm_eps, cfg.rmsnorm_plus_one)[0]
+    with jax.named_scope("router"):
+        logits = jnp.dot(h.astype(jnp.float32),
+                         p["router"].astype(jnp.float32),
+                         precision=jax.lax.Precision.HIGHEST)
+        idx, gates = moe_select(logits, m)
+    with jax.named_scope("routed_experts"):
+        routed, stats = _routed_experts(h, idx, gates, experts, layer, m,
+                                        cfg.activation, valid)
+    with jax.named_scope("shared_expert"):
+        shared = _gated(h, p["ws_gate"], p["ws_up"], p["ws_down"],
+                        cfg.activation)
+    y = (routed + shared.astype(jnp.float32)).astype(x.dtype)
+    return x + y[None], stats
+
+
 @jax.named_scope("final_norm")
 def _final_norm(x: jax.Array, params: dict, cfg: ModelConfig) -> jax.Array:
     return rmsnorm(x, params["final_norm"], cfg.norm_eps,
@@ -318,6 +612,7 @@ def forward_hidden(
     The caller advances ``cache.lens`` — keeping length bookkeeping out of
     the traced body lets the same trace serve speculative / chunked prefill.
     """
+    require_plain(cfg, "forward_hidden (the dense-cache forward)")
     B, T = tokens.shape
     if input_embeds is not None:
         x = input_embeds                # prepared by the caller (VLM)
@@ -392,6 +687,7 @@ def forward_hidden_paged(
     of tokens generated this call. The pool is never gathered into a
     contiguous working cache (NOTES_r03 gap 2). Returns (hidden [B, 1, D],
     new tail_k, new tail_v)."""
+    require_plain(cfg, "forward_hidden_paged (the split paged decode)")
     from quoracle_tpu.ops.paged_attention import paged_decode_attend
     B, T = tokens.shape
     x = _embed(params, cfg, tokens)
@@ -447,6 +743,7 @@ def forward_hidden_paged_prefill(
     [B, maxp·page] contiguous working cache the gather path materializes
     never exists (VERDICT r4 item 2; NOTES_r03 gap 1). Returns
     (hidden [B, T, D], k_pool, v_pool) with the chunk KV written."""
+    require_plain(cfg, "forward_hidden_paged_prefill (the split paged prefill)")
     from quoracle_tpu.ops.paged_attention import paged_prefill_merge
     B, T = tokens.shape
     n_tok = k_pool.shape[1] * k_pool.shape[2]
@@ -513,8 +810,11 @@ def forward_hidden_ragged(
     block's real pages (ops/paged_attention.ragged_attend_auto) — the
     [B, maxp·page] working cache, the dense intra-chunk piece, and the
     decode tail buffer all cease to exist. Returns
-    (hidden [1, Tp, D], k_pool, v_pool, k_scale, v_scale) with the chunk
-    KV written (the scales None as they came, on unquantized pools).
+    (hidden [1, Tp, D], k_pool, v_pool, k_scale, v_scale, moe_stats) with
+    the chunk KV written (the scales None as they came, on unquantized
+    pools; ``moe_stats`` None but for a model with expert layers, see
+    ``_forward_hidden_ragged_stacks``, which serves latent attention and
+    expert layers under this same contract).
 
     The pools never move (PR 25): they ride the layer scan as a CARRY,
     whole and in their stored lane-flat layout, each layer writes its Tp
@@ -531,6 +831,12 @@ def forward_hidden_ragged(
     fp32 scales into the page-structured scale pools — carried and
     indexed by layer the same way — and the attention dequantizes inside
     the kernel's streaming loop."""
+    if not cfg.plain:
+        assert shard is None and k_scale is None, \
+            "latent/expert models: no tp shards, no int8 pages"
+        return _forward_hidden_ragged_stacks(
+            params, cfg, tokens, positions, k_pool, v_pool, row_tables,
+            block_meta, flat_dst, tq, interpret)
     from quoracle_tpu.ops.paged_attention import ragged_attend_auto
     B, Tp = tokens.shape       # B == 1: the flat layout is the batch
     L, n_pages, page, lanes = k_pool.shape
@@ -582,7 +888,75 @@ def forward_hidden_ragged(
         (x, k_pool, v_pool, k_scale, v_scale), _ = jax.lax.scan(
             layer_body, (x, k_pool, v_pool, k_scale, v_scale),
             (params["layers"], jnp.arange(L, dtype=jnp.int32)))
-    return _final_norm(x, params, cfg), k_pool, v_pool, k_scale, v_scale
+    return (_final_norm(x, params, cfg), k_pool, v_pool, k_scale, v_scale,
+            None)
+
+
+def _forward_hidden_ragged_stacks(params, cfg, tokens, positions, k_pool,
+                                  v_pool, row_tables, block_meta, flat_dst,
+                                  tq, interpret) -> tuple:
+    """``forward_hidden_ragged`` for a model with latent attention and
+    expert layers: the same contract, with TWO layer stacks — the leading
+    dense layers (``params["dense_layers"]``) and then the expert layers
+    (``params["layers"]``), one ``lax.scan`` each — both carrying the
+    pool in place as the dense model's one scan does. The
+    pool is ONE array ``[L, n_pages, page, latent.lanes]`` (``v_pool`` is
+    None): a token's row is its compressed latent and rotary key, written
+    before attention and read in place by the folded kernel
+    (ops/paged_attention.ragged_attend_latent). Returns the dense
+    function's tuple; its last member is the expert layers' int32 [4]
+    (assignments, of them to held experts, held experts reached summed
+    over layers, expert layers run)."""
+    from quoracle_tpu.ops.paged_attention import ragged_attend_latent_auto
+    L, n_pages, page, _ = k_pool.shape
+    n_tok = n_pages * page
+    x = _embed(params, cfg, tokens)
+    keep = flat_dst < n_tok
+    scale = attn_softmax_scale(cfg)
+    n_dense = cfg.n_dense_layers
+    experts = tuple(params["layers"][k]
+                    for k in ("we_gate", "we_up", "we_down"))
+
+    def attention(x, kp, p, layer):
+        q, row = _latent_qkv(x, p, cfg, positions)
+        with jax.named_scope("kv_write"):
+            dst = jnp.where(keep, layer * n_tok + flat_dst, L * n_tok)
+            kp = kp.reshape(L * n_tok, kp.shape[-1]).at[dst].set(
+                row.astype(kp.dtype), mode="drop").reshape(kp.shape)
+        with jax.named_scope("attn"):
+            attn = ragged_attend_latent_auto(
+                q, kp, row_tables, block_meta, layer, tq=tq,
+                v_lanes=cfg.latent.kv_rank, scale=scale,
+                interpret=interpret)
+        return _latent_attn_out(x, attn, p, cfg), kp
+
+    def dense_body(carry, scanned):
+        x, kp = carry
+        p, layer = scanned
+        x, kp = attention(x, kp, p, layer)
+        return (_mlp(x, p, cfg), kp), None
+
+    def expert_body(carry, scanned):
+        x, kp, stats = carry
+        p, layer = scanned
+        x, kp = attention(x, kp, p, layer)
+        x, st = _moe(x, p, experts, layer - n_dense, cfg, keep)
+        return (x, kp, stats + st), None
+
+    with jax.named_scope("layers"):
+        if n_dense:
+            (x, k_pool), _ = jax.lax.scan(
+                dense_body, (x, k_pool),
+                (params["dense_layers"],
+                 jnp.arange(n_dense, dtype=jnp.int32)))
+        # the routed experts' weights stay out of the scanned slices: a
+        # block reads one expert's matrices at [layer, e]
+        rest = {k: v for k, v in params["layers"].items()
+                if not k.startswith("we_")}
+        (x, k_pool, stats), _ = jax.lax.scan(
+            expert_body, (x, k_pool, jnp.zeros((4,), jnp.int32)),
+            (rest, jnp.arange(n_dense, L, dtype=jnp.int32)))
+    return _final_norm(x, params, cfg), k_pool, v_pool, None, None, stats
 
 
 def project_logits(params: dict, cfg: ModelConfig, hidden: jax.Array) -> jax.Array:

@@ -950,6 +950,186 @@ def ragged_attend_auto(
                              k_scale=k_scale, v_scale=v_scale)
 
 
+# ---------------------------------------------------------------------------
+# LATENT ragged kernel: one shared key per token whose head is the value
+# ---------------------------------------------------------------------------
+#
+# Latent attention (MLA) in its FOLDED form: the key up-projection is folded
+# into the query and the value up-projection into the output, so every one
+# of the H query heads attends to the SAME stored row per token,
+# ``[c_kv | k_rope | 0-pad]`` (``lanes`` wide, models/config.LatentConfig),
+# and the value is the row's first ``v_lanes`` lanes (``c_kv`` again). It is
+# multi-query attention with one kv head, a key wider than the value, and
+# ONE pool: the kernel streams a page once and uses it for both products.
+# Same flat token-major contract, page tables, block meta and in-kernel
+# normalisation as ``ragged_attend``; no window, no int8 pages.
+
+
+def ragged_attend_latent_ref(
+    q: jax.Array,            # [NB·tq, H, lanes] folded queries
+    pool: jax.Array,         # [L, n_pages, page, lanes] — the latent pool
+    row_tables: jax.Array,   # [R, maxp] int32
+    block_meta: jax.Array,   # [4, NB] int32: kv_len, qpos0, nq, row
+    layer,                   # int32 scalar
+    tq: int,
+    v_lanes: int,
+    scale: float,
+) -> jax.Array:
+    """XLA gather reference for the latent kernel (CPU serving path + the
+    kernel's oracle): normalized output [NB·tq, H, v_lanes] f32."""
+    kv_len, qpos0, nq, row = (block_meta[j][:, None, None]
+                              for j in range(4))
+    block_tables = row_tables[row[:, 0, 0]]                  # [NB, maxp]
+    NB, maxp = block_tables.shape
+    _, H, lanes = q.shape
+    page = pool.shape[2]
+    qb = q.astype(jnp.float32).reshape(NB, tq, H, lanes)
+    k = pool[layer, block_tables].reshape(
+        NB, maxp * page, lanes).astype(jnp.float32)
+    scores = jnp.einsum("bthc,bsc->bhts", qb, k) * scale
+    t_idx = jnp.arange(tq, dtype=jnp.int32)[None, :, None]
+    s_idx = jnp.arange(maxp * page, dtype=jnp.int32)[None, None, :]
+    mask = ((s_idx < kv_len) & (s_idx <= qpos0 + t_idx)
+            & (t_idx < nq))[:, None]                         # [NB,1,tq,S]
+    scores = jnp.where(mask, scores, NEG_INF)
+    m = jnp.max(scores, axis=-1, keepdims=True)
+    p = jnp.where(mask, jnp.exp(scores - m), 0.0)
+    l = jnp.sum(p, axis=-1)                                  # [NB,H,tq]
+    acc = jnp.einsum("bhts,bsc->bhtc", p, k[..., :v_lanes])
+    out = acc / jnp.where(l > 0, l, 1.0)[..., None]
+    return out.transpose(0, 2, 1, 3).reshape(NB * tq, H, v_lanes)
+
+
+def _ragged_latent_kernel(tables_ref, meta_ref, layer_ref, q_ref, kv_hbm,
+                          out_ref, kv_scr, sems, *, page: int, tq: int,
+                          v_lanes: int, scale: float):
+    """One tq-token block: stream the owning row's visible latent pages
+    through VMEM double-buffered, ONE DMA a page, and write the normalized
+    output in latent space. The page is the key at its full width and the
+    value at its first ``v_lanes`` lanes. Products take the operands in
+    their stored type with float32 accumulation; softmax is float32."""
+    i = pl.program_id(0)
+    kv_len = meta_ref[0, i]
+    qpos0 = meta_ref[1, i]
+    nq = meta_ref[2, i]
+    row = meta_ref[3, i]
+    layer = layer_ref[0]
+    kv_hi = jnp.minimum(kv_len, qpos0 + nq)
+    n = (kv_hi + page - 1) // page
+    H, lanes = q_ref.shape[2], q_ref.shape[3]
+    q = q_ref[0].reshape(tq * H, lanes)                  # query-major rows
+
+    def dma(j, slot):
+        return pltpu.make_async_copy(
+            kv_hbm.at[layer, tables_ref[row, j]], kv_scr.at[slot],
+            sems.at[slot])
+
+    @pl.when(n > 0)
+    def _():
+        dma(0, 0).start()
+
+    t_of_row = jax.lax.broadcasted_iota(jnp.int32, (tq * H, 1), 0) // H
+    qpos = qpos0 + t_of_row                              # [tq·H, 1]
+    q_ok = t_of_row < nq
+
+    def body(j, carry):
+        m, l, acc = carry
+        slot = jax.lax.rem(j, 2)
+
+        @pl.when(j + 1 < n)
+        def _():
+            dma(j + 1, jax.lax.rem(j + 1, 2)).start()
+
+        dma(j, slot).wait()
+        kv = kv_scr[slot]                                # [page, lanes]
+        s_idx = j * page + jax.lax.broadcasted_iota(
+            jnp.int32, (1, page), 1)
+        valid = (s_idx < kv_len) & (s_idx <= qpos) & q_ok
+        scores = jax.lax.dot_general(                    # [tq·H, page]
+            q, kv, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale
+        scores = jnp.where(valid, scores, NEG_INF)
+        m_new = jnp.maximum(m, jnp.max(scores, axis=1, keepdims=True))
+        p = jnp.where(valid, jnp.exp(scores - m_new), 0.0)
+        corr = jnp.exp(m - m_new)
+        l_new = l * corr + jnp.sum(p, axis=1, keepdims=True)
+        pv = jax.lax.dot_general(                        # [tq·H, v_lanes]
+            p.astype(kv.dtype), kv[:, :v_lanes],
+            (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        return m_new, l_new, acc * corr + pv
+
+    init = (jnp.full((tq * H, 1), NEG_INF, jnp.float32),
+            jnp.zeros((tq * H, 1), jnp.float32),
+            jnp.zeros((tq * H, v_lanes), jnp.float32))
+    _, l, acc = jax.lax.fori_loop(0, n, body, init)
+    norm = acc / jnp.where(l > 0, l, 1.0)
+    out_ref[0] = norm.reshape(tq, H, v_lanes).astype(out_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("tq", "v_lanes", "scale",
+                                             "interpret"))
+def ragged_attend_latent(
+    q: jax.Array,            # [NB·tq, H, lanes] folded queries
+    pool: jax.Array,         # [L, n_pages, page, lanes] — the latent pool
+    row_tables: jax.Array,   # [R, maxp] int32
+    block_meta: jax.Array,   # [4, NB] int32: kv_len, qpos0, nq, row
+    layer,                   # int32 scalar: which layer's pages to stream
+    tq: int,
+    v_lanes: int,
+    scale: float,
+    interpret: bool = False,
+) -> jax.Array:
+    """Pallas latent ragged attention (contract of
+    ``ragged_attend_latent_ref``; output in the queries' type). The pool is
+    passed whole, as stored, and stays in HBM; ``lanes`` and ``v_lanes``
+    are multiples of 128 (config.LatentConfig.lanes pads the stored row)."""
+    Tp, H, lanes = q.shape
+    NB = block_meta.shape[1]
+    page = pool.shape[2]
+    layer = jnp.asarray(layer, jnp.int32).reshape(1)
+    kernel = functools.partial(_ragged_latent_kernel, page=page, tq=tq,
+                               v_lanes=v_lanes, scale=scale)
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,                 # tables, meta, layer
+            grid=(NB,),
+            in_specs=[
+                pl.BlockSpec((1, tq, H, lanes), lambda i, *_: (i, 0, 0, 0)),
+                pl.BlockSpec(memory_space=pl.ANY),      # pool stays in HBM
+            ],
+            out_specs=[
+                pl.BlockSpec((1, tq, H, v_lanes),
+                             lambda i, *_: (i, 0, 0, 0)),
+            ],
+            scratch_shapes=[pltpu.VMEM((2, page, lanes), pool.dtype),
+                            pltpu.SemaphoreType.DMA((2,))],
+        ),
+        out_shape=[jax.ShapeDtypeStruct((NB, tq, H, v_lanes), q.dtype)],
+        interpret=interpret,
+        # pinned: the trace shows `%ragged_attend_latent.<n>`, which the
+        # benchmark's `^%ragged_attend` patterns match
+        name="ragged_attend_latent",
+    )(row_tables.astype(jnp.int32), block_meta.astype(jnp.int32), layer,
+      q.astype(pool.dtype).reshape(NB, tq, H, lanes), pool)[0]
+    return out.reshape(NB * tq, H, v_lanes)
+
+
+def ragged_attend_latent_auto(q, pool, row_tables, block_meta, layer, *,
+                              tq: int, v_lanes: int, scale: float,
+                              interpret: Optional[bool] = None):
+    """Latent attention dispatcher: the Pallas kernel on TPU (or under
+    ``interpret``), the XLA gather reference elsewhere. A pool whose lanes
+    are no multiple of 128 (tiny test models) takes the reference."""
+    aligned = pool.shape[-1] % 128 == 0 and v_lanes % 128 == 0
+    if (_on_tpu() or interpret) and aligned:
+        return ragged_attend_latent(q, pool, row_tables, block_meta, layer,
+                                    tq=tq, v_lanes=v_lanes, scale=scale,
+                                    interpret=bool(interpret))
+    return ragged_attend_latent_ref(q, pool, row_tables, block_meta, layer,
+                                    tq=tq, v_lanes=v_lanes, scale=scale)
+
+
 def _tp_shard_map(inner, shard, q_rank4: bool):
     """Wrap a paged-attention piece in shard_map over the tp axis: every
     head attends independently (GQA groups stay whole per shard — callers

@@ -120,6 +120,8 @@ class KVHandoff:
         # before any pages move — the caller's contract (degrade to a
         # cold re-prefill on the decode side, request still served) is
         # exactly what the scenario harness asserts.
+        from quoracle_tpu.models.config import require_plain
+        require_plain(engine.cfg, "session handoff (--disaggregate)")
         from quoracle_tpu.chaos.faults import CHAOS
         d = CHAOS.fire("handoff.export", model=model_spec)
         if d is not None and d.kind == "fail":

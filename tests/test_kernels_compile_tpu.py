@@ -330,6 +330,68 @@ def test_decode_program_leaves_the_pool_where_it_is_at_mistral_widths(
     assert mem.temp_size_in_bytes < cfg.n_layers * layer_elems * 2
 
 
+# --- the latent kernel and a latent / expert model's decode program ---------
+
+@pytest.mark.parametrize("tq,nb", [(8, 1024), (1, 8)],
+                         ids=["tq8-8k-tick", "tq1-decode"])
+def test_latent_ragged_kernel_compiles(on_v5e, tq, nb):
+    """A.X-K1's latent geometry: 64 query heads against ONE stored row of
+    640 lanes (512 latent, 64 rotary, 64 of pad) whose first 512 lanes are
+    the value; an 8k-token tick and a decode step. The custom call carries
+    the pinned name that the benchmark's ``^%ragged_attend`` matches."""
+    S = on_v5e
+    pool = S((LAYERS, N_PAGES, PAGE, 640), jnp.bfloat16)
+    text = jax.jit(functools.partial(
+        pa.ragged_attend_latent, tq=tq, v_lanes=512, scale=0.13)).lower(
+        S((nb * tq, 64, 640), jnp.bfloat16), pool, S((8, 128), jnp.int32),
+        S((4, nb), jnp.int32), S((), jnp.int32)).compile().as_text()
+    call = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    assert len(call) == 1
+    assert re.match(r"\s*%ragged_attend_latent(\.\d+)? = ", call[0])
+
+
+def test_latent_decode_program_leaves_the_pool_where_it_is(on_v5e,
+                                                           monkeypatch):
+    """A latent, routed-expert model's decode program on the v5e: two
+    layer stacks (one dense layer, two expert layers) and the decode loop
+    carry ONE latent pool in place: two kernels (one a stack's body),
+    nothing that moves a layer's pool, the pool donated into its output."""
+    from quoracle_tpu.models.config import LatentConfig, ModelConfig, MoEConfig
+    from quoracle_tpu.models.generate import GenerateEngine
+    from quoracle_tpu.models.tokenizer import ByteTokenizer
+    from quoracle_tpu.models.transformer import init_params
+    monkeypatch.setattr(pa, "_on_tpu", lambda: True)
+    cfg = ModelConfig(
+        name="narrow-latent-moe", vocab_size=512, dim=256, n_layers=3,
+        n_heads=8, n_kv_heads=8, ffn_dim=512,
+        rope_scaling=("yarn", 32.0, 32.0, 1.0, 4096, 1.0, 1.0),
+        latent=LatentConfig(q_rank=128, kv_rank=512, nope_dim=128,
+                            rope_dim=64, v_dim=128),
+        moe=MoEConfig(n_routed=32, n_held=4, per_token=4, expert_dim=256,
+                      n_group=4, topk_group=2, routed_scale=2.5,
+                      first_dense=1))
+    S = on_v5e
+    params = jax.tree.map(
+        lambda x: S(x.shape, x.dtype),
+        jax.eval_shape(lambda k: init_params(cfg, k), jax.random.PRNGKey(0)))
+    eng = GenerateEngine(cfg, params, ByteTokenizer(), max_seq=1024)
+    st = eng.sessions
+    assert cfg.kv_pools == (640,)
+    pool = S((cfg.n_layers, st.n_pages, st.page, 640), eng.pool_dtype)
+    layer_elems = st.n_pages * st.page * 640
+    R, i32, f32 = 8, jnp.int32, jnp.float32
+    compiled = eng._step_paged_decode_ragged.lower(
+        params, pool, None, None, None, S((R, 8), i32), S((R,), i32),
+        S((R,), i32), S((R, cfg.vocab_size), f32), S((2,), jnp.uint32),
+        S((R,), f32), S((R,), f32), S((R,), jnp.bool_), S((R,), i32),
+        None, None, max_new=32).compile()
+    hlo, mem = compiled.as_text(), compiled.memory_analysis()
+    assert hlo.count("tpu_custom_call") == 2
+    assert pool_moves(hlo, layer_elems) == []
+    assert mem.alias_size_in_bytes >= cfg.n_layers * layer_elems * 2
+    assert mem.temp_size_in_bytes < layer_elems * 2
+
+
 # --- tp wrappers: shard_map around a pallas_call ----------------------------
 
 

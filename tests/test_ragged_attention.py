@@ -623,7 +623,7 @@ def test_dropped_writes_stay_dropped_in_every_layer(n_layers, interpret):
 
     @jax.jit
     def tick(k, v):
-        hidden, k, v, _, _ = tr.forward_hidden_ragged(
+        hidden, k, v, *_ = tr.forward_hidden_ragged(
             params, cfg, jnp.asarray(tok)[None], jnp.asarray(posn)[None],
             k, v, jnp.asarray(tables0), jnp.asarray(meta),
             jnp.asarray(dst), tq=tq, interpret=interpret)
