@@ -453,12 +453,12 @@ def jax_trace_window(logdir: str):
     The window holds, on one clock: each batcher worker's ``qtpu.tick``
     and ``qtpu.tick.<phase>`` spans on its thread's line of the
     ``/host:CPU`` plane (infra/telemetry.TICK_PHASES; the tick carries
-    ``model``, ``rows``, ``admitted``, ``real_tokens``,
-    ``padded_tokens``, ``decode_steps`` and the program key), the
-    runtime's own threads, and on the device planes every operation with
-    the ``jax.named_scope`` path it was traced under (``tf_op``). Python
-    frames are left out (``python_tracer_level`` 0): a window on a live
-    server must not slow its host."""
+    ``model``, ``rows``, ``admitted``, ``nucleus_rows``,
+    ``real_tokens``, ``padded_tokens``, ``decode_steps`` and the program
+    key), the runtime's own threads, and on the device planes every
+    operation with the ``jax.named_scope`` path it was traced under
+    (``tf_op``). Python frames are left out (``python_tracer_level`` 0):
+    a window on a live server must not slow its host."""
     if not _STATE.enabled:
         yield False
         return

@@ -695,6 +695,11 @@ SCHED_PADDED_TOKENS_TOTAL = METRICS.counter(
     "device chunk-token slots processed across generate ticks (real + "
     "padding), per model — [B·T] on the bucketed paths, the flat token "
     "budget on the unified ragged path")
+SCHED_NUCLEUS_ROWS_TOTAL = METRICS.counter(
+    "quoracle_sched_nucleus_rows_total",
+    "rows of a batcher tick that ask for a nucleus (temperature > 0 and "
+    "top_p < 1), summed over ticks, per model: a tick with none sorts no "
+    "vocabulary (models/sampling.py)")
 # -- expert layers (ISSUE 27) ------------------------------------------------
 # Booked once a tick from the int32 [4] the ragged programs return with
 # their outputs (transformer._routed_experts): what the router sent where.
@@ -754,10 +759,11 @@ def _annotation(name: str):
 class TickRecord:
     """One batcher loop iteration: integer-ns time per phase, the
     annotation arguments a trace reader needs (``model``, ``rows``,
-    ``admitted``, ``real_tokens``, ``padded_tokens``, ``decode_steps``,
-    ``program``, ``context_tokens``, an expert model's ``moe_*`` counts),
-    and the prefill fence of the tick (``fence_ns``: the
-    instant ``wait_prefill`` last ended — a row's first-token stamp)."""
+    ``admitted``, ``nucleus_rows``, ``real_tokens``, ``padded_tokens``,
+    ``decode_steps``, ``program``, ``context_tokens``, an expert model's
+    ``moe_*`` counts), and the prefill fence of the tick (``fence_ns``:
+    the instant ``wait_prefill`` last ended — a row's first-token
+    stamp)."""
 
     __slots__ = ("t0_ns", "t1_ns", "phase_ns", "args", "fence_ns",
                  "_phase", "_t_phase", "_ann", "_tick_ann")

@@ -67,9 +67,9 @@ from quoracle_tpu.analysis.lockdep import named_lock
 from quoracle_tpu.infra import costobs, fleetobs, introspect, treeobs
 from quoracle_tpu.infra.flightrec import FLIGHT
 from quoracle_tpu.infra.telemetry import (
-    QOS_ADMIT_WAIT_MS, SCHED_ADMIT_WAIT_MS, SCHED_QUEUE_DEPTH,
-    SCHED_ROWS_TOTAL, SCHED_SLOTS_BUSY, TRACER, tick_close, tick_note,
-    tick_open, tick_phase,
+    QOS_ADMIT_WAIT_MS, SCHED_ADMIT_WAIT_MS, SCHED_NUCLEUS_ROWS_TOTAL,
+    SCHED_QUEUE_DEPTH, SCHED_ROWS_TOTAL, SCHED_SLOTS_BUSY, TRACER,
+    tick_close, tick_note, tick_open, tick_phase,
 )
 from quoracle_tpu.models.generate import GenResult
 from quoracle_tpu.serving.admission import (
@@ -428,13 +428,18 @@ class ContinuousBatcher:
             tick_open(self._model)
             admitted = self._admit()
             n_rows = len(self._live)
-            tick_note(rows=n_rows, admitted=admitted)
+            # the rows that make the sampler sort the vocabulary: with
+            # none, the tick's programs take the branch without the sort
+            nucleus = sum(r.temperature > 0 and r.top_p < 1
+                          for r in self._live)
+            tick_note(rows=n_rows, admitted=admitted, nucleus_rows=nucleus)
             if not self._live:
                 tick_phase("idle")
                 self._wake.wait(timeout=0.2)
                 self._wake.clear()
                 tick_close()
                 continue
+            SCHED_NUCLEUS_ROWS_TOTAL.inc(nucleus, model=self._model)
             tick_phase("prepare")
             try:
                 self._live = self._step(self._live)

@@ -330,6 +330,114 @@ def test_decode_program_leaves_the_pool_where_it_is_at_mistral_widths(
     assert mem.temp_size_in_bytes < cfg.n_layers * layer_elems * 2
 
 
+# --- what the decode program runs on every step, and what only on request ---
+
+_CALLED = re.compile(r"(\w+)=\{?((?:%[\w.\-]+(?:, )?)+)\}?")
+
+
+def on_every_path(hlo: str, opcode: str) -> tuple[list, int]:
+    """(the ``opcode`` instructions of an optimized HLO module that run
+    WHENEVER the program does, how many the module holds in all): those
+    in the entry computation and in every computation it reaches through
+    a call, a fusion, a loop's body or condition — anything but the
+    branch of a ``conditional``, which runs only when its predicate says
+    so."""
+    comps, comp = {}, None
+    for ln in hlo.splitlines():
+        head = re.match(r"(ENTRY )?%(\S+) \(.*\{\s*$", ln)
+        if head:
+            comp = comps[head.group(2)] = {"entry": bool(head.group(1)),
+                                           "ops": [], "calls": []}
+            continue
+        m = re.match(r"\s*(?:ROOT )?%(\S+) = .*?\s([\w\-]+)\(", ln)
+        if comp is None or not m:
+            continue
+        if m.group(2) == opcode:
+            comp["ops"].append(m.group(1))
+        for key, names in _CALLED.findall(ln):
+            if key not in ("branch_computations", "true_computation",
+                           "false_computation"):
+                comp["calls"] += re.findall(r"%([\w.\-]+)", names)
+    todo = [n for n, c in comps.items() if c["entry"]]
+    seen = set(todo)
+    while todo:
+        for n in comps[todo.pop()]["calls"]:
+            if n in comps and n not in seen:
+                seen.add(n)
+                todo.append(n)
+    return (sorted(op for n in seen for op in comps[n]["ops"]),
+            sum(len(c["ops"]) for c in comps.values()))
+
+
+def test_on_every_path_tells_a_branch_from_a_loop_body():
+    """The reader itself: a sort in a loop's body runs on every step, one
+    in a conditional's branch (or in what the branch calls) does not."""
+    hlo = """
+%cmp (a: f32[], b: f32[]) -> pred[] {
+  %a = f32[] parameter(0)
+  %b = f32[] parameter(1)
+  ROOT %lt = pred[] compare(%a, %b), direction=LT
+}
+
+%inner (q: f32[8,512]) -> f32[8,512] {
+  %q = f32[8,512]{1,0} parameter(0)
+  ROOT %sort.3 = f32[8,512]{1,0} sort(%q), dimensions={1}, to_apply=%cmp
+}
+
+%asked (p: (f32[8,512])) -> f32[8,512] {
+  %p = (f32[8,512]{1,0}) parameter(0)
+  %x = f32[8,512]{1,0} get-tuple-element(%p), index=0
+  %sort.2 = f32[8,512]{1,0} sort(%x), dimensions={1}, to_apply=%cmp
+  ROOT %call.1 = f32[8,512]{1,0} call(%sort.2), to_apply=%inner
+}
+
+%not_asked (p: (f32[8,512])) -> f32[8,512] {
+  %p = (f32[8,512]{1,0}) parameter(0)
+  ROOT %x = f32[8,512]{1,0} get-tuple-element(%p), index=0
+}
+
+%body (c: (f32[8,512], pred[])) -> (f32[8,512], pred[]) {
+  %c = (f32[8,512]{1,0}, pred[]) parameter(0)
+  %l = f32[8,512]{1,0} get-tuple-element(%c), index=0
+  %any = pred[] get-tuple-element(%c), index=1
+  %t = (f32[8,512]{1,0}) tuple(%l)
+  %sort.1 = f32[8,512]{1,0} sort(%l), dimensions={1}, to_apply=%cmp
+  %conditional.1 = f32[8,512]{1,0} conditional(%any, %t, %t), branch_computations={%not_asked, %asked}
+  ROOT %r = (f32[8,512]{1,0}, pred[]) tuple(%conditional.1, %any)
+}
+
+%cond (c: (f32[8,512], pred[])) -> pred[] {
+  %c = (f32[8,512]{1,0}, pred[]) parameter(0)
+  ROOT %any = pred[] get-tuple-element(%c), index=1
+}
+
+ENTRY %main (a: (f32[8,512], pred[])) -> (f32[8,512], pred[]) {
+  %a = (f32[8,512]{1,0}, pred[]) parameter(0)
+  ROOT %while.1 = (f32[8,512]{1,0}, pred[]) while(%a), condition=%cond, body=%body
+}
+"""
+    assert on_every_path(hlo, "sort") == (["sort.1"], 3)
+    assert on_every_path(hlo, "conditional") == (["conditional.1"], 1)
+
+
+def test_decode_program_sorts_the_vocabulary_only_in_a_branch(on_v5e,
+                                                              monkeypatch):
+    """The nucleus of ``sample_tokens`` is one branch of a conditional in
+    the compiled program, the first draw's and the loop body's alike: the
+    compiler did not turn it into a select that computes both sides, and
+    no other sort of the vocabulary has come into a step (PERF.md §6,
+    PR 28: 8 x 151,936 logits sorted every step were 15% of Qwen's)."""
+    hlo, _, _ = _decode_program(
+        on_v5e, monkeypatch,
+        _narrow("narrow-kv2-bias", 2, attn_bias=True, tie_embeddings=True),
+        max_seq=512)
+    always, in_all = on_every_path(hlo, "sort")
+    assert always == [] and in_all == 2       # first token, loop body
+    always, in_all = on_every_path(hlo, "conditional")
+    assert len(always) == in_all == 2
+    assert "decode_loop/while/body/sample/top_p/cond/branch_1_fun/" in hlo
+
+
 # --- the latent kernel and a latent / expert model's decode program ---------
 
 @pytest.mark.parametrize("tq,nb", [(8, 1024), (1, 8)],

@@ -511,7 +511,8 @@ def test_the_dense_decode_program_is_the_parents():
     program (`step_paged_decode_ragged`, AOT on the CPU at `tiny` with the
     gather reference in the kernel's place) holds the operations and the
     temporaries it held at the parent commit, where I read them off the
-    same lowering (PR 27: 4fd7b5c)."""
+    same lowering (PR 27: 4fd7b5c), but for what PR 28 added to every
+    model's sampler."""
     from quoracle_tpu.models.generate import GenerateEngine
     from quoracle_tpu.models.tokenizer import ByteTokenizer
     cfg = get_model_config("tiny")
@@ -535,5 +536,8 @@ def test_the_dense_decode_program_is_the_parents():
     assert mem.temp_size_in_bytes == DENSE_DECODE_TEMP_BYTES
 
 
-DENSE_DECODE_OPS = 955               # read off the parent commit (4fd7b5c)
-DENSE_DECODE_TEMP_BYTES = 5_500_376
+# read off the parent commit (4fd7b5c): 955 operations, 5,500,376 bytes.
+# PR 28 put the sampler's nucleus in a conditional, at the first draw and
+# in the loop body: 22 operations and 328 bytes more, for every model.
+DENSE_DECODE_OPS = 977
+DENSE_DECODE_TEMP_BYTES = 5_500_704

@@ -29,6 +29,7 @@ import subprocess
 import sys
 import textwrap
 import threading
+import time
 
 import jax
 import jax.numpy as jnp
@@ -133,13 +134,52 @@ def test_decode_tick_span_is_the_tick_record(served):
         assert sum(phases.values()) == e["wall_ns"]
         assert e["duration_ms"] == pytest.approx(e["wall_ns"] / 1e6, abs=1e-3)
         assert e["model"] == name and e["rows"] >= 1
-        for arg in ("admitted", "real_tokens", "padded_tokens",
-                    "decode_steps", "program", "step"):
+        for arg in ("admitted", "nucleus_rows", "real_tokens",
+                    "padded_tokens", "decode_steps", "program", "step"):
             assert arg in e, arg
         assert "," not in str(e["program"])        # a TraceMe argument
         assert phases["wait_prefill"] > 0 and phases["wait_decode"] > 0
     assert ticks[0]["admitted"] == 1
     assert [e["step"] for e in ticks] == list(range(len(ticks)))
+
+
+def test_tick_books_the_rows_that_ask_for_a_nucleus():
+    """``nucleus_rows`` and ``quoracle_sched_nucleus_rows_total``: rows
+    with ``temperature`` > 0 AND ``top_p`` < 1, the ones the sampler sorts
+    the vocabulary for; a sampled row at 1.0 and a greedy row at 0.5 are
+    not among them."""
+    events: list = []
+    TRACER.add_sink(events.append)
+    b = TPUBackend([MEMBER], continuous=True, continuous_chunk=8)
+    name = b.engines[MEMBER].cfg.name
+    worker = b._cbatchers[MEMBER]
+    booked = telemetry.SCHED_NUCLEUS_ROWS_TOTAL
+
+    def turn(temperature, top_p):
+        before, n = booked.value(model=name), len(events)
+        out = b.query([QueryRequest(
+            MEMBER, [{"role": "user", "content": "nucleus probe"}],
+            temperature=temperature, top_p=top_p, max_tokens=12)])[0]
+        assert out.ok, out.error
+        # the row's future resolves inside its last tick: wait for that
+        # tick's span (every tick is sampled here)
+        deadline = time.monotonic() + 30
+        while (sum(e["name"] == "sched.decode_tick" for e in events)
+               < worker.steps and time.monotonic() < deadline):
+            time.sleep(0.01)
+        ticks = [e for e in events[n:] if e["name"] == "sched.decode_tick"]
+        assert len(ticks) >= 2                     # 12 tokens, chunks of 8
+        return ([e["nucleus_rows"] for e in ticks],
+                booked.value(model=name) - before)
+    try:
+        for temperature, top_p in ((1.0, 1.0), (0.0, 0.5)):
+            per_tick, grown = turn(temperature, top_p)
+            assert set(per_tick) == {0} and grown == 0
+        per_tick, grown = turn(1.0, 0.5)
+        assert set(per_tick) == {1} and grown == len(per_tick)
+    finally:
+        b.close()
+        TRACER.remove_sink(events.append)
 
 
 def test_phase_counter_grows_by_the_ticks_phases(served):
@@ -242,8 +282,9 @@ def test_profiler_trace_holds_tick_and_phases_on_one_thread_line(tmp_path):
               if str(dict(ev.stats).get("rows", "0")) != "0"]
     assert riding, "no tick with rows in the trace"
     for args in riding:
-        for arg in ("model", "rows", "admitted", "real_tokens",
-                    "padded_tokens", "decode_steps", "program"):
+        for arg in ("model", "rows", "admitted", "nucleus_rows",
+                    "real_tokens", "padded_tokens", "decode_steps",
+                    "program"):
             assert arg in args, arg
     names = {ev.name for ev in events if ev.name.startswith("qtpu.tick.")}
     assert names <= {"qtpu.tick." + p for p in TICK_PHASES}
