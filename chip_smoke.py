@@ -290,7 +290,7 @@ def direct_phase(backend, spec: str) -> tuple[Phase, dict]:
 def tick_paths(engine) -> tuple[list, dict]:
     """The CompileRegistry's shape keys (one per compiled program pair) and,
     per attention path, programs and ticks: unified keys start "ragged",
-    anything else is a [B, T, …] rectangle of the gather/direct paths."""
+    anything else is a [B, T, …] rectangle of the gather programs."""
     shapes = engine.compiles.snapshot(max_shapes=1024)["shapes"]
     paths: dict = {}
     for e in shapes:
@@ -441,9 +441,6 @@ async def run(chips: int, compile_log: dict) -> dict:
     if not native_available():
         failures.append("native tokenizer: g++ build of native/bpe.cpp "
                         "failed; the Python fallback would have served")
-    if not engine.paged_gates.source.startswith("default"):
-        failures.append(f"paged gates come from {engine.paged_gates.source}"
-                        f", not the built-in default")
     with tempfile.TemporaryDirectory(prefix="chip-smoke-grove-") as grove:
         with open(os.path.join(grove, "GROVE.md"), "w") as f:
             f.write(GROVE_MD.format(
@@ -494,7 +491,6 @@ async def run(chips: int, compile_log: dict) -> dict:
         "compiled_shapes": [{"shape": e["shape"],
                              "first_call_ms": e["compile_ms"],
                              "later_ticks": e["hits"]} for e in shapes],
-        "paged_gates_source": engine.paged_gates.source,
         "tokenizer": (f"{type(engine.tokenizer).__name__} "
                       f"(native C++: {native_available()})"),
         "memory_after_load": after_load,

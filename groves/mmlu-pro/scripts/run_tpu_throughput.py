@@ -7,8 +7,8 @@ query per question, waiting for each round), this runner submits
 ``--concurrency`` questions' worth of rows AT ONCE from a thread pool —
 the shape of a coordinator fanning out answerer agents — and the
 ContinuousBatcher (models/scheduler.py) admits/retires rows at 32-token
-chunk boundaries. This is the realistic consumer bench config 6 models:
-many agents' forced-choice decodes riding one member's shared decode loop.
+chunk boundaries: many agents' forced-choice decodes riding one member's
+shared decode loop.
 
 Records, per the VERDICT contract: wall-clock per question, aggregate
 tokens/s, and accuracy, in one JSON line.

@@ -19,8 +19,7 @@ schedule — the reproducibility contract that makes a chaos failure
 debuggable instead of anecdotal.
 
 Tier-1 runs every scenario on the mock-device (CPU tiny-engine)
-cluster; bench.py config 17 drives the storm scenario against real
-engines. The registry:
+cluster. The registry:
 
   traffic_storm       multi-tenant storm + admission/router signal loss
   kill_mid_handoff    decode-replica death mid-row + export failure

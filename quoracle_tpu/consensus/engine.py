@@ -358,7 +358,7 @@ class ConsensusEngine:
                      ) -> tuple[list[ActionProposal], list[ModelFailure]]:
         # One round = query + parse + validate; the span parents the
         # backend's per-member generate spans, and quoracle_round_ms is
-        # what bench config 9 reports p50/p95 from.
+        # where a round's p50/p95 are read from.
         t0 = time.monotonic()
         with TRACER.span("consensus.round", round=round_num,
                          agent_id=self.config.session_key):

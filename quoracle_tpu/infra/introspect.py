@@ -301,8 +301,8 @@ class WallProfiler:
     thread's frames (``sys._current_frames``) and folds the stack into
     a collapsed ``file:func;file:func`` string; counts accumulate per
     rotating window. Self-measures its own sampling wall so
-    ``overhead_frac`` is an observation, not a guess — bench config 24
-    gates it at ≤1% for the default rate."""
+    ``overhead_frac`` is an observation, not a guess (DEPLOY §19 alerts
+    above 1% at the default rate)."""
 
     WINDOW_S = 30.0                   # profile window length
     KEEP = 4                          # completed windows retained

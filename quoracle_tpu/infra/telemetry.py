@@ -59,7 +59,7 @@ def quantile(bounds: Sequence[float], counts: Sequence[int],
     overflow). Linear interpolation inside the owning bucket; the overflow
     bucket reports its lower edge (no upper bound to interpolate to).
     Returns None for an empty histogram. Exposed as a module function so
-    bench.py can compute quantiles of COUNT DELTAS (before/after a
+    a reader can compute quantiles of COUNT DELTAS (before/after a
     measured window) without a second histogram instance.
     """
     total = sum(counts)
@@ -207,7 +207,7 @@ class Histogram(_Metric):
 
     def counts(self, **labels: Any) -> tuple[list[int], float, int]:
         """(bucket counts incl. +Inf slot, sum, count). With no labels the
-        counts AGGREGATE across every label set — bench.py diffs these
+        counts AGGREGATE across every label set — a reader diffs these
         around a measured window."""
         with self._lock:
             if labels:
@@ -683,10 +683,10 @@ SCHED_ROWS_TOTAL = METRICS.counter(
 # -- ragged serving kernel (ISSUE 8) ----------------------------------------
 # Padding-waste accounting for the serving hot path: per generate call
 # (one continuous-batcher tick), the chunk-token slots the device actually
-# processed vs the tick's REAL tokens. The bucketed paths pad every tick
-# to a [batch-bucket × prompt-bucket] rectangle; the unified ragged kernel
+# processed vs the tick's REAL tokens. The gather programs pad every tick
+# to a [batch-bucket × prompt-bucket] rectangle; the ragged kernel
 # processes per-row tq-aligned segments — the delta between these two
-# counters is exactly what raggedness reclaims (the bench's headline).
+# counters is exactly what raggedness reclaims.
 SCHED_REAL_TOKENS_TOTAL = METRICS.counter(
     "quoracle_sched_real_tokens_total",
     "real chunk tokens submitted across generate ticks, per model")
@@ -1270,8 +1270,8 @@ INTROSPECT_PROFILE_SAMPLES = METRICS.counter(
 INTROSPECT_OVERHEAD_RATIO = METRICS.gauge(
     "quoracle_introspect_profiler_overhead_ratio",
     "observed fraction of process wall the frame sampler itself "
-    "consumed since start — self-measured, gated at 1 percent for the default "
-    "rate by bench config 24 (DEPLOY §19 ProfilerOverhead)")
+    "consumed since start — self-measured; alert above 1 percent at the "
+    "default rate (DEPLOY §19 ProfilerOverhead)")
 INTROSPECT_WAIT_MS = METRICS.histogram(
     "quoracle_introspect_wait_ms",
     "per-row wait-state decomposition by state (admission | queue | "

@@ -10,10 +10,10 @@ stacks run channels-last on the MXU, shapes are static.
 
 Like the LLM pool, the model serves whatever weights it is given: random
 init produces textured-noise images (the honest no-network analog of the
-bench's generated LLM checkpoints — the serving path, batching, cost
-accounting, and determinism are real; picture quality needs trained
-weights, which need a network). Weights load/store as a flat pytree, so a
-trained checkpoint drops in without code changes.
+generated LLM checkpoints, models/make_checkpoint.py — the serving path,
+batching, cost accounting, and determinism are real; picture quality needs
+trained weights, which need a network). Weights load/store as a flat
+pytree, so a trained checkpoint drops in without code changes.
 """
 
 from __future__ import annotations

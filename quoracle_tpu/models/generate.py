@@ -96,7 +96,7 @@ def prefill(params: dict, cfg: ModelConfig, tokens: jax.Array,
 def grammar_mask(logits: jax.Array, jstate: jax.Array,
                  json_table: jax.Array, eos_id: int) -> jax.Array:
     """THE grammar mask — every constrained decode path (gather decode,
-    direct paged decode, speculative draft + verify) calls this one
+    ragged paged decode, speculative draft + verify) calls this one
     implementation so they can never drift on dead-end or unconstrained
     handling. logits [B, V], jstate [B]; jstate < 0 = unconstrained row;
     a dead-end state (vocab gap: no token allowed) permits eos so the row
@@ -111,10 +111,11 @@ def grammar_mask(logits: jax.Array, jstate: jax.Array,
 
 def _sampling_fns(json_table: Optional[jax.Array], eos_id: int,
                   stop_ids: tuple):
-    """The stop/grammar closures shared by decode() and decode_paged() —
-    one implementation so the gather and direct paged paths can never
+    """The stop/grammar closures shared by decode() and decode_ragged() —
+    one implementation so the gather and ragged paged paths can never
     drift apart on stop handling or grammar dead-end recovery (the two
-    must stay token-exact; tests/test_paged_kv.py equality test)."""
+    must stay token-exact; tests/test_ragged_attention.py equality
+    tests)."""
     stops = jnp.asarray((eos_id,) + tuple(stop_ids), jnp.int32)
     constrained = json_table is not None
 
@@ -248,87 +249,6 @@ def decode(
     return out, n_emitted, cache, jstate
 
 
-def decode_paged(
-    params: dict,
-    cfg: ModelConfig,
-    k_pool: jax.Array,         # [L, n_pages, page, KV·hd] — READ-ONLY
-    v_pool: jax.Array,
-    tables: jax.Array,         # [B, maxp] int32
-    pool_lens: jax.Array,      # [B] int32 valid pool tokens (the prompt)
-    kv_off: jax.Array,         # [B] int32 abs position of pool index 0
-    first_logits: jax.Array,   # [B, V]
-    rng: jax.Array,
-    temperature: jax.Array,
-    top_p: jax.Array,
-    max_new: int,
-    eos_id: int,
-    active: jax.Array,
-    row_limit: jax.Array,
-    pad_id: int = 0,
-    stop_ids: tuple = (),
-    json_table: Optional[jax.Array] = None,
-    json_state: Optional[jax.Array] = None,
-    tail_dtype=jnp.bfloat16,
-    shard: Optional[tuple] = None,
-) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array, jax.Array]:
-    """Autoregressive decode against the PAGED pool: same sampling/grammar
-    semantics as decode(), but attention reads the row's pages directly
-    (ragged — transformer.forward_hidden_paged) and new tokens' KV land in
-    a [L, B, max_new] TAIL buffer instead of a gathered working cache. The
-    memory high-water drops from pool + [B, maxp·page] working cache to
-    pool + tail, and per-step KV reads are proportional to each row's real
-    length (NOTES_r03 gap 2).
-
-    Returns (tokens [B, max_new], n_emitted [B], lens [B], tail_k, tail_v)
-    where lens = pool_lens + valid tail entries per row — the caller
-    scatters tail[:, :lens-pool_lens] into the row's pages (page bookkeeping
-    is host-side, as in the gather path).
-    """
-    from quoracle_tpu.models.transformer import forward_hidden_paged
-    B = first_logits.shape[0]
-    L, KV, HD = cfg.n_layers, cfg.n_kv_heads, cfg.head_dim
-    fns = _sampling_fns(json_table, eos_id, stop_ids)
-    is_stop, mask_logits, advance, _ = fns
-    tok0, n0, done0, jstate0, out0, rng = _first_token(
-        fns, first_logits, rng, temperature, top_p, active, row_limit,
-        json_state, max_new, pad_id)
-    tail_k0 = jnp.zeros((L, B, max_new, KV, HD), tail_dtype)
-    tail_v0 = jnp.zeros((L, B, max_new, KV, HD), tail_dtype)
-    lens0 = pool_lens.astype(jnp.int32)
-
-    def cond(carry):
-        i, done, *_ = carry
-        return (i < max_new) & ~jnp.all(done)
-
-    def body(carry):
-        (i, done, cur, out, n_emitted, lens, tail_k, tail_v, rng,
-         jstate) = carry
-        positions = (lens + kv_off.astype(jnp.int32))[:, None]
-        hidden, tail_k, tail_v = forward_hidden_paged(
-            params, cfg, cur[:, None], positions, k_pool, v_pool, tables,
-            pool_lens, kv_off, tail_k, tail_v, step=i - 1, shard=shard)
-        logits = project_logits(params, cfg, hidden)
-        nxt, rng = _draw(mask_logits, logits[:, 0, :], jstate, rng,
-                         temperature, top_p)
-        with jax.named_scope("row_state"):
-            nxt = jnp.where(done, pad_id, nxt)
-            out = jax.lax.dynamic_update_slice_in_dim(out, nxt[:, None], i,
-                                                      axis=1)
-            n_emitted = n_emitted + jnp.where(done, 0, 1).astype(jnp.int32)
-            lens = lens + jnp.where(done, 0, 1)
-            jstate = advance(jstate, nxt, done)
-            done = done | is_stop(nxt) | (n_emitted >= row_limit)
-        return (i + 1, done, nxt, out, n_emitted, lens, tail_k, tail_v,
-                rng, jstate)
-
-    init = (jnp.asarray(1, jnp.int32), done0, tok0, out0, n0, lens0,
-            tail_k0, tail_v0, rng, jstate0)
-    with jax.named_scope("decode_loop"):
-        (_, done, _, out, n_emitted, lens, tail_k, tail_v, _, jstate) = \
-            jax.lax.while_loop(cond, body, init)
-    return out, n_emitted, lens, tail_k, tail_v, jstate
-
-
 def decode_ragged(
     params: dict,
     cfg: ModelConfig,
@@ -355,7 +275,7 @@ def decode_ragged(
     v_scale: Optional[jax.Array] = None,   # int8 pools (ISSUE 13)
 ) -> tuple:
     """Autoregressive decode through the UNIFIED ragged kernel (ISSUE 8):
-    same sampling/grammar semantics as decode()/decode_paged(), but each
+    same sampling/grammar semantics as decode(), but each
     step's KV scatters STRAIGHT into the row's pages before attention and
     the kernel reads everything — prompt, chunk, and generated tokens —
     off the pages. Neither the [B, maxp·page] working cache nor the
@@ -595,10 +515,10 @@ class SessionStore:
         be satisfied even by evicting every unprotected session.
 
         ``evict=False`` takes only from the free list: TEMP allocations
-        (direct-decode scratch for sessionless rows) must never destroy
+        (a ragged tick's scratch for sessionless rows) must never destroy
         other agents' resident sessions — or thrash the prefix cache — for
         pages that die at call end; the caller falls back to the gather
-        decode instead.
+        programs instead.
 
         Eviction order: RADIX-CACHE LEAVES first (a cached-but-unreferenced
         prefix is recomputable; a resident session is another agent's live
@@ -998,6 +918,10 @@ class GenerateEngine:
     """
 
     BATCH_BUCKETS = (1, 2, 4, 8, 16, 32)
+    # The tests' one seam into the paged path (ragged_fallback): True
+    # routes every paged tick to the gather programs, which the equality
+    # tests compare the ragged path against. Nothing in the program sets it.
+    _force_gather_decode = False
 
     def __init__(self, cfg: ModelConfig, params: dict, tokenizer,
                  max_seq: Optional[int] = None, seed: int = 0,
@@ -1095,46 +1019,7 @@ class GenerateEngine:
         # Order: _paged_lock → _grammar_lock (sessioned path), never
         # reversed.
         self._grammar_lock = named_lock("cache.grammar")
-        # Resident-size thresholds (max prompt tokens in the batch) for the
-        # DIRECT (ragged-kernel) paged decode and paged PREFILL. These are
-        # MEASURED gates, not constants: tools/calibrate_paged.py
-        # measures the gather/direct crossover on the current device and
-        # the engine loads it
-        # (utils/calibration.py; env QUORACLE_PAGED_CALIB). With no
-        # calibration file both paths stay off — a documented absence of
-        # data. Beyond latency the direct paths cap peak HBM (no
-        # [B, maxp·page] working cache), so memory-pressured deployments
-        # may calibrate them on below the latency crossover.
-        from quoracle_tpu.utils.calibration import (
-            load_paged_gates, resolve_unified_gate,
-        )
-        gates = load_paged_gates()
-        self.paged_gates = gates
-        self.direct_decode_min_tokens = gates.decode_min_resident
-        self.direct_prefill_min_tokens = gates.prefill_min_resident
-        self.direct_prefill_max_chunk = gates.prefill_max_chunk
-        # UNIFIED ragged kernel (ISSUE 8): ONE launch per layer for the
-        # whole mixed tick — prefill suffixes, continuations, decode and
-        # verify rows in one token-major grid, KV written straight to
-        # pages. Unlike the direct paths this is ON by default on TPU
-        # (gather becomes the measured fallback): the calibration file
-        # can raise the threshold or disable it, absent key = auto
-        # (0 on TPU, off elsewhere — CPU serving sticks with the fused
-        # gather programs; tests force the unified path explicitly).
-        self.unified_min_tokens = resolve_unified_gate(gates)
-        if not cfg.plain:
-            # the ragged path IS the serving path of a latent / expert
-            # model, on every platform (the CPU runs the kernels' gather
-            # references); where a tick cannot take it, it raises
-            self.unified_min_tokens = 0
         if self.quantize_kv:
-            # Quantized KV serves through the unified ragged path (the
-            # kernel dequantizes in its streaming loop; the gather refs
-            # are the CPU twin) — force it on regardless of platform
-            # calibration; the gather programs stay the structural
-            # fallback (pool exhaustion, partial boundary swaps) with
-            # dequant-on-gather / requant-on-scatter.
-            self.unified_min_tokens = 0
             from quoracle_tpu.infra.telemetry import (
                 QUANT_KV_BYTES_PER_TOKEN,
             )
@@ -1337,20 +1222,15 @@ class GenerateEngine:
             v_scale = v_scale.at[:, dst_pages].set(
                 vs.transpose(0, 1, 2, 4, 3), mode="drop")
             return k_pool, v_pool, k_scale, v_scale
-        # tp-sharded ragged kernels: each tp shard runs the single-device
-        # kernel on its local heads under shard_map (heads independent, no
-        # collective) — mesh engines keep the direct paths instead of
-        # silently falling back to gather (VERDICT r4 item 3). Gated on
-        # whole GQA groups per shard; _run_paged checks the same.
-        paged_shard = (attn_shard if attn_shard is not None
-                       and attn_shard[1] is not None else None)
-        self._paged_shard = paged_shard
-        # Unified ragged kernel sharding: token-major flat layout can't
-        # ride a dp axis (rows interleave in one token axis), so the
-        # unified path runs on single-device engines and tp-only meshes
-        # (heads independent under shard_map); other meshes fall back.
+        # The ragged kernel under a mesh: each tp shard runs the
+        # single-device kernel on its local heads under shard_map (heads
+        # independent, no collective; whole GQA groups per shard). The
+        # token-major flat layout can't ride a dp axis (rows interleave
+        # in one token axis) nor an sp ring, so the ragged programs run
+        # on single-device engines and tp-only meshes; other meshes take
+        # the gather programs (ragged_fallback, condition (a)).
         ragged_shard = None
-        if (paged_shard is not None
+        if (attn_shard is not None and attn_shard[1] is not None
                 and int(mesh.shape.get("sp", 1)) == 1
                 and int(mesh.shape.get("dp", 1)) == 1):
             ragged_shard = (mesh, "tp")
@@ -1517,42 +1397,16 @@ class GenerateEngine:
                 probs = jnp.zeros((1, 1, 1), jnp.float32)
             return ids, probs, cache
 
-        @functools.partial(jax.jit, donate_argnums=(1, 2))
-        def step_paged_prefill_direct(params, k_pool, v_pool, src_tables,
-                                      tokens, prefix_lens, chunk_lens,
-                                      kv_off, flat_dst):
-            # DIRECT paged prefill: the suffix chunk attends to the
-            # resident prefix straight off its pages (one kernel launch
-            # per layer per chunk) and its KV scatters into the dst pages
-            # in place — the [B, maxp·page] working cache never
-            # materializes (VERDICT r4 item 2). Pools donated: the
-            # scatter aliases them.
-            from quoracle_tpu.models.transformer import (
-                forward_hidden_paged_prefill,
-            )
-            B, T = tokens.shape
-            positions = ((prefix_lens + kv_off).astype(jnp.int32)[:, None]
-                         + jnp.arange(T, dtype=jnp.int32)[None, :])
-            hidden, k_pool, v_pool = forward_hidden_paged_prefill(
-                params, cfg, tokens, positions, k_pool, v_pool,
-                src_tables, prefix_lens, chunk_lens, flat_dst,
-                shard=paged_shard)
-            last_h = jnp.take_along_axis(
-                hidden, (chunk_lens - 1)[:, None, None].astype(jnp.int32),
-                axis=1)
-            last = project_logits(params, cfg, last_h)[:, 0, :]
-            return last, k_pool, v_pool
-
         @functools.partial(jax.jit, donate_argnums=(0, 1, 4, 5))
         def step_scatter_prompt(k_pool, v_pool, k_scale, v_scale, k_work,
                                 v_work, dst_pages):
-            # Working cache (prefix gather + suffix prefill) → dst pages,
-            # BEFORE decode: the direct-decode path then reads pages only.
-            # k_work/v_work are donated so the working cache's HBM frees
-            # here (the memory win of the direct path) — XLA warns the
-            # donation isn't aliasable into an output; that's the point,
-            # it's a free, not an alias. Int8 pools requantize on the
-            # scatter (scales beside the pages).
+            # Working cache (prefix gather + verify chunk) → dst pages:
+            # the gather verify's store-back (step_paged_verify has no
+            # decode loop to scatter at its end). k_work/v_work are
+            # donated so the working cache's HBM frees here — XLA warns
+            # the donation isn't aliasable into an output; that's the
+            # point, it's a free, not an alias. Int8 pools requantize on
+            # the scatter (scales beside the pages).
             if quant:
                 return _quant_scatter(k_pool, v_pool, k_scale, v_scale,
                                       k_work, v_work, dst_pages)
@@ -1562,35 +1416,6 @@ class GenerateEngine:
             k_pool = k_pool.at[:, dst_pages].set(kp, mode="drop")
             v_pool = v_pool.at[:, dst_pages].set(vp, mode="drop")
             return k_pool, v_pool, k_scale, v_scale
-
-        @functools.partial(jax.jit, static_argnames=("max_new",))
-        def step_paged_decode_direct(params, k_pool, v_pool, tables,
-                                     pool_lens, kv_off, last_logits, rng,
-                                     temperature, top_p, active, row_limit,
-                                     json_table, json_state, max_new: int):
-            # Pools are READ-ONLY here (not donated): attention streams
-            # pages via ops/paged_attention.py; new KV accumulates in the
-            # tail buffer, scattered into pages by step_scatter_tail.
-            return decode_paged(
-                params, cfg, k_pool, v_pool, tables, pool_lens, kv_off,
-                last_logits, rng, temperature, top_p, max_new,
-                cfg.eos_token_id, active=active, row_limit=row_limit,
-                pad_id=self.tokenizer.pad_id, stop_ids=cfg.stop_token_ids,
-                json_table=json_table, json_state=json_state,
-                tail_dtype=self.cache_dtype, shard=paged_shard)
-
-        @functools.partial(jax.jit, donate_argnums=(0, 1))
-        def step_scatter_tail(k_pool, v_pool, tail_k, tail_v, flat_idx):
-            # tail slot t of row b → pool token slot flat_idx[b, t]
-            # (host-computed; out-of-range = drop for invalid slots)
-            n_tok = k_pool.shape[1] * page
-            kf = k_pool.reshape(L, n_tok, KV * HD)
-            vf = v_pool.reshape(L, n_tok, KV * HD)
-            kf = kf.at[:, flat_idx].set(
-                tail_k.reshape(*tail_k.shape[:3], KV * HD), mode="drop")
-            vf = vf.at[:, flat_idx].set(
-                tail_v.reshape(*tail_v.shape[:3], KV * HD), mode="drop")
-            return (kf.reshape(k_pool.shape), vf.reshape(v_pool.shape))
 
         # the three unified programs donate the pools (and an int8
         # engine's scale pools; None donates nothing): input and output
@@ -1698,11 +1523,8 @@ class GenerateEngine:
         self._step_decode = step_decode
         self._step_paged_prefill = step_paged_prefill
         self._step_paged_verify = step_paged_verify
-        self._step_paged_prefill_direct = step_paged_prefill_direct
         self._step_paged_decode = step_paged_decode
         self._step_scatter_prompt = step_scatter_prompt
-        self._step_paged_decode_direct = step_paged_decode_direct
-        self._step_scatter_tail = step_scatter_tail
 
     def next_rng(self) -> jax.Array:
         with self._rng_lock:
@@ -2285,9 +2107,9 @@ class GenerateEngine:
                     prompts, suffixes, sess_rows, reuse_abs, kv_off_host,
                     store_sids, B, maxp, tokens, pre_arr, off_arr,
                     chunk_arr, limits, rng_key, samp, json_args, max_new,
-                    put, mat, row, t0, verify=vrun,
-                    samp_np=(temp_arr, top_arr, active, limits),
-                    jstate_np=jstate_np)
+                    put, mat, row,
+                    (temp_arr, top_arr, active, limits), jstate_np,
+                    verify=vrun)
         else:
             if images is not None and any(i is not None for i in images):
                 vc = self.cfg.vision
@@ -2558,17 +2380,59 @@ class GenerateEngine:
             st.v_scale = jnp.ones(sshape, jnp.float32)
         st.k, st.v = k, v
 
+    @staticmethod
+    def ragged_fallback(*, mesh_lays_flat: bool, forced: bool,
+                        reuse_without_store: bool,
+                        boundary_page_swapped: bool) -> Optional[str]:
+        """THE rule of the paged path, stated once: a paged tick runs the
+        ragged programs (one token-major launch per layer, KV written
+        straight to the rows' pages) unless THIS tick shows a reason it
+        cannot; the reason comes back as text (None = ragged) and the
+        tick takes the gather programs instead. A function of the tick
+        and the engine's mesh, never of configuration:
+
+        (a) the mesh cannot lay the flat token-major batch — a dp or sp
+            axis, or heads that do not divide over tp (``_ragged_ok``);
+        (b) a row reuses a resident prefix but its store was declined
+            (pool exhausted even after eviction): it would write to
+            temporary pages, and there is no gather to relocate the
+            prefix it reads from its session's own;
+        (c) a shared boundary page was swapped for a fresh one in this
+            allocation (copy-on-write at a partially reused page): the
+            ragged forward writes chunk positions only and would leave
+            the page's reused head unwritten; the gather scatter
+            rewrites every slot.
+
+        A fourth reason is found only by trying, at the site: no free
+        page for a sessionless row's temporaries. ``forced`` is the
+        tests' seam, ``_force_gather_decode``: the gather programs stay
+        reachable on the chip, and the equality tests compare the
+        ragged path against them."""
+        if forced:
+            return "_force_gather_decode"
+        if not mesh_lays_flat:
+            return "the mesh cannot lay a flat token-major batch"
+        if reuse_without_store:
+            return "page pool exhausted: a prefix-reusing row's store " \
+                   "was declined"
+        if boundary_page_swapped:
+            return "a shared boundary page was swapped"
+        return None
+
     def _run_paged(self, prompts, suffixes, sess_rows, reuse_abs,
                    kv_off_host, store_sids, B, maxp, tokens, pre_arr,
                    off_arr, chunk_arr, limits, rng_key, samp, json_args,
-                   max_new, put, mat, row, t0, verify=None, samp_np=None,
-                   jstate_np=None):
-        """The paged-session call: gather resident pages in-device, prefill
-        the suffix, decode, scatter prompt+response KV back to pages, then
-        update session page lists host-side (ints only — no KV bytes move
-        through the host). The CALLER holds self._paged_lock for the whole
-        sessioned generate — lookup, allocation, the pool-donating steps,
-        and the store are one atomic unit."""
+                   max_new, put, mat, row, samp_np, jstate_np,
+                   verify=None):
+        """The paged-session call: the ragged programs write the suffix's
+        and the response's KV straight to the rows' pages; where the
+        tick cannot take them (``ragged_fallback``) the gather programs
+        materialize resident pages in-device, prefill the suffix, decode
+        and scatter prompt+response KV back. Either way the session page
+        lists update host-side afterwards (ints only — no KV bytes move
+        through the host). The CALLER holds self._paged_lock for the
+        whole sessioned generate — lookup, allocation, the pool-donating
+        steps, and the store are one atomic unit."""
         n = len(prompts)
         st = self.sessions
         page = st.page
@@ -2579,51 +2443,9 @@ class GenerateEngine:
         temp_lists: list[Optional[list[int]]] = [None] * n
         spills: list[list[int]] = [[] for _ in range(n)]
         protect = tuple(s for s in store_sids if s)
-        # DIRECT paged decode (ops/paged_attention.py) vs gather decode.
-        # The ragged kernel costs one pallas launch per LAYER per token, so
-        # wherever launch overhead exceeds the gather path's padded KV
-        # reads the fused gather decode is faster (measured: 656 → 1640 ms
-        # per bench config-1 round at ~1k tokens; still 2.3× slower at 16k
-        # resident, batch 1 — tools/bench_longctx.py). The kernel's wins
-        # are peak-HBM (no [B, maxp·page] working cache) and very large
-        # ragged batches; the gate compares the batch's max RESIDENT
-        # (prompt) tokens against direct_decode_min_tokens (measured gate,
-        # utils/calibration.py — see __init__). tp meshes run the kernel
-        # per-shard via shard_map (_paged_shard, whole GQA groups per
-        # shard required); other meshes (sp rings, non-divisible heads)
-        # gather. _force_gather_decode is the equality-test seam
-        # (tests/test_paged_kv.py).
-        mesh_ok = (self.mesh is None
-                   or (self._paged_shard is not None
-                       and int(self.mesh.shape.get("sp", 1)) == 1))
-        use_direct = (mesh_ok
-                      and verify is None      # verify is a chunk forward,
-                                              # not a decode loop
-                      and not getattr(self, "_force_gather_decode", False)
-                      # quantized KV serves through the UNIFIED kernel
-                      # (in-kernel dequant); the split direct kernels
-                      # have no scale stream
-                      and not self.quantize_kv
-                      and max(len(p) for p in prompts)
-                      >= self.direct_decode_min_tokens)
-        # UNIFIED ragged kernel (ISSUE 8) — the default serving path on
-        # TPU: prefill suffixes, continuations, decode steps and verify
-        # windows all dispatch through ONE token-major kernel, KV written
-        # straight to pages. Eligibility mirrors the direct paths' page
-        # discipline (every prefix-reusing row must read/write its OWN dst
-        # pages — there is no gather/scatter to relocate a prefix), plus
-        # the flat layout's mesh constraint (dp can't shard interleaved
-        # rows). _force_gather_decode is the shared equality/fallback
-        # seam; the per-engine threshold comes from the calibration file
-        # (utils/calibration.py resolve_unified_gate).
-        unified_ok = (self._ragged_ok
-                      and not getattr(self, "_force_gather_decode", False)
-                      and samp_np is not None
-                      and max(len(p) for p in prompts)
-                      >= self.unified_min_tokens)
         adopted_release: list[list[int]] = [[] for _ in range(n)]
-        partial_swap = [False]      # a swapped boundary page forces the
-                                    # gather prefill (see below)
+        partial_swap = False        # a swapped boundary page: condition
+                                    # (c) of ragged_fallback
         with st.lock:   # one allocation transaction for the batch
             # Refcount-acquire every adopted donor prefix FIRST: an alloc
             # below may LRU-evict the donor mid-transaction, and the
@@ -2685,13 +2507,12 @@ class GenerateEngine:
                                  if safe_full <= j < need
                                  and st._refs.get(pg, 1) > 1]
                 # Swapping the PARTIALLY-reused boundary page leaves a
-                # dst hole the direct-prefill path would never fill (it
+                # dst hole the ragged forward would never fill (it
                 # writes only chunk positions >= pre_buf; the gather
-                # scatter covers everything) — force the gather prefill
-                # for this batch when that happens.
+                # scatter covers everything): ragged_fallback (c).
                 if any(j == safe_full and pre_buf % page
                        for j in shared_beyond):
-                    partial_swap[0] = True
+                    partial_swap = True
                 if shared_beyond:
                     # copy-on-write: the divergent rewrite lands on fresh
                     # pages; the shared copies (radix cache / adopters)
@@ -2717,10 +2538,17 @@ class GenerateEngine:
                 st._release(tail_shared)        # our refs; adopters keep
                 dst_lists[i] = old
                 dst[i, :len(old)] = old
-            if use_direct or unified_ok:
-                # The direct AND unified paths read every row's prompt
-                # from pages, so rows without a stored session need TEMP
-                # pages for this call. Exhaustion falls back to gather.
+            fallback = self.ragged_fallback(
+                mesh_lays_flat=self._ragged_ok,
+                forced=self._force_gather_decode,
+                reuse_without_store=any(
+                    sess_rows[i] is not None and dst_lists[i] is None
+                    for i in range(n)),
+                boundary_page_swapped=partial_swap)
+            if fallback is None:
+                # The ragged programs read every row's prompt from pages,
+                # so rows without a stored session need TEMP pages for
+                # this call. Exhaustion falls back to gather.
                 for i in range(n):
                     if dst_lists[i] is not None:
                         continue
@@ -2731,53 +2559,19 @@ class GenerateEngine:
                     tmp = st.alloc(-(-need_tokens // page),
                                    protect=protect, evict=False)
                     if tmp is None:
-                        use_direct = False
-                        unified_ok = False
+                        fallback = "no free page for a sessionless " \
+                                   "row's temporaries"
                         break
                     temp_lists[i] = tmp
                     dst[i, :len(tmp)] = tmp
-                if not use_direct and not unified_ok:
+                if fallback is not None:
                     for i, tmp in enumerate(temp_lists):
                         if tmp:
                             st._release(tmp)
                         temp_lists[i] = None
 
-        # DIRECT paged prefill composes with the direct decode only (the
-        # gather decode needs the working cache the direct prefill exists
-        # to skip): suffix chunks attend to resident pages in place, chunk
-        # KV scatters to dst pages, and the decode then reads pages — no
-        # [B, maxp·page] materialization anywhere in the call. Gated by
-        # the measured calibration (utils/calibration.py) + a chunk-size
-        # cap (the intra-chunk piece is dense O(T²)).
-        T = tokens.shape[1]
-        use_direct_pre = (
-            use_direct
-            and not getattr(self, "_force_gather_prefill", False)
-            and max(len(p) for p in prompts) >= self.direct_prefill_min_tokens
-            and T <= self.direct_prefill_max_chunk
-            # every prefix-reusing row must write through its OWN session
-            # pages (dst prefix == src prefix, so the resident KV is
-            # already where the decode will read it). A row whose store
-            # was declined (pool exhaustion) reuses a prefix but targets
-            # TEMP pages — its prefix would never reach dst; gather
-            # handles that batch instead.
-            and all(sess_rows[i] is None or dst_lists[i] is not None
-                    for i in range(n))
-            # a swapped shared BOUNDARY page left a dst hole only the
-            # full gather scatter fills (prefix sharing divergence)
-            and not partial_swap[0])
-
-        # Final unified-kernel eligibility: every prefix-reusing row must
-        # read its prefix from the SAME dst pages the kernel writes (no
-        # gather exists to relocate it), and a swapped shared boundary
-        # page leaves a hole only the gather scatter fills.
-        use_unified = (unified_ok and not partial_swap[0]
-                       and all(sess_rows[i] is None
-                               or dst_lists[i] is not None
-                               for i in range(n)))
-
         vout = None
-        if not use_unified and not self.cfg.plain:
+        if fallback is not None and not self.cfg.plain:
             # give back what this call took, then refuse: the gather
             # programs compute per-head K and V and a dense MLP. A row
             # whose stored session keeps every page it had loses only
@@ -2798,10 +2592,9 @@ class GenerateEngine:
                         if taken:
                             st._release(taken)
             raise RuntimeError(unsupported_path(
-                self.cfg, "the gather fallback of a paged tick (page pool "
-                "exhausted, a shared boundary page swapped, or "
-                "_force_gather_decode)"))
-        if use_unified:
+                self.cfg, f"the gather fallback of a paged tick "
+                          f"({fallback})"))
+        if fallback is None:
             (out, n_emitted, final_lens, jstate_f, vout, t_prefill,
              now) = self._run_unified(
                  n, suffixes, dst, pre_arr, off_arr, chunk_arr,
@@ -2834,24 +2627,6 @@ class GenerateEngine:
             n_emitted = np.zeros((B,), np.int32)
             jstate_f = np.full((B,), -1, np.int32)
             final_lens = pre_arr + chunk_arr
-        elif use_direct_pre:
-            n_tok = st.n_pages * page
-            flat = np.full((B, T), n_tok, np.int32)   # OOB sentinel = drop
-            for i in range(n):
-                n_chunk = min(len(suffixes[i]) or 1,
-                              maxp * page - int(pre_arr[i]))
-                pos = int(pre_arr[i]) + np.arange(max(0, n_chunk))
-                flat[i, :len(pos)] = dst[i, pos // page] * page + pos % page
-            tick_phase("dispatch_prefill")
-            last_logits, st.k, st.v = self._step_paged_prefill_direct(
-                self.params, st.k, st.v, put(src, mat), put(tokens, mat),
-                put(pre_arr, row), put(chunk_arr, row), put(off_arr, row),
-                put(flat, mat))
-            cache = None
-            pool_lens_dev = put(pre_arr + chunk_arr, row)
-            tick_phase("wait_prefill")
-            jax.block_until_ready(last_logits)  # phase fence: prefill done
-            t_prefill = time.monotonic()
         else:
             tick_phase("dispatch_prefill")
             last_logits, cache = self._step_paged_prefill(
@@ -2861,50 +2636,6 @@ class GenerateEngine:
             tick_phase("wait_prefill")
             jax.block_until_ready(last_logits)  # phase fence: prefill done
             t_prefill = time.monotonic()
-
-        if use_unified or verify is not None:
-            pass          # handled above (unified runs its own decode)
-        elif use_direct:
-            tick_phase("dispatch_decode")
-            # prompt KV → pages (unless the direct prefill already wrote
-            # them there), free the working cache, decode straight off the
-            # pool (ragged paged attention), then scatter only the
-            # generated tail back.
-            if not use_direct_pre:
-                pool_lens_dev = cache.lens
-                st.k, st.v, st.k_scale, st.v_scale = \
-                    self._step_scatter_prompt(
-                        st.k, st.v, st.k_scale, st.v_scale, cache.k,
-                        cache.v, put(dst, mat))
-                cache = None  # drop host refs: k/v donated above, HBM freed
-            out, n_emitted, final_lens, tail_k, tail_v, jstate_f = \
-                self._step_paged_decode_direct(
-                    self.params, st.k, st.v, put(dst, mat), pool_lens_dev,
-                    put(off_arr, row), last_logits, rng_key, *samp,
-                    *json_args, max_new=max_new)
-            tick_phase("wait_decode")
-            out = np.asarray(out)
-            n_emitted = np.asarray(n_emitted)
-            jstate_f = np.asarray(jstate_f)
-            lens_host = np.asarray(final_lens)
-            pool_lens_host = np.asarray(pool_lens_dev)
-            flat = np.full((B, tail_k.shape[2]), st.n_pages * page,
-                           np.int32)          # OOB sentinel = dropped
-            for i in range(n):
-                n_tail = int(lens_host[i]) - int(pool_lens_host[i])
-                if n_tail <= 0:
-                    continue
-                pos = int(pool_lens_host[i]) + np.arange(n_tail)
-                pos = pos[pos < maxp * page]
-                flat[i, :len(pos)] = dst[i, pos // page] * page + pos % page
-            st.k, st.v = self._step_scatter_tail(
-                st.k, st.v, tail_k, tail_v, jnp.asarray(flat))
-            # the scatter belongs to this call's decode phase: sync before
-            # stamping, or its device time leaks into the NEXT call's
-            # prefill fence and skews the bench's phase split
-            jax.block_until_ready(st.k)
-            now = time.monotonic()
-        else:
             tick_phase("dispatch_decode")
             (out, n_emitted, final_lens, st.k, st.v, st.k_scale,
              st.v_scale, _, _, jstate_f) = \
@@ -2959,7 +2690,7 @@ class GenerateEngine:
                     and self.cfg.sliding_window is None
                     and self.cfg.vision is None and verify is None):
                 st.insert_prefix(toks, pages)
-        # temp pages (direct decode for sessionless rows) die with the call
+        # temp pages (sessionless rows of a ragged tick) die with the call
         for tmp in temp_lists:
             if tmp:
                 st.release(tmp)

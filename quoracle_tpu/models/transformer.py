@@ -15,8 +15,9 @@ math locally, SURVEY.md §2.8):
 
 Every forward names its device work with ``jax.named_scope`` (ISSUE 24),
 identically: ``embed``, ``layers`` around the scan and inside the layer
-body ``qkv``, ``rope``, ``kv_write``, ``attn`` (within it ``kv_layout``,
-the pools' re-layout for a paged kernel, ops/paged_attention.py),
+body ``qkv``, ``rope``, ``kv_write``, ``attn`` (within it ``kv_layout``:
+a re-layout of the pool for the kernel, which only a test model whose
+head_dim is below the lane width still needs, ops/paged_attention.py),
 ``attn_out``, ``mlp``; then ``final_norm`` and, in project_logits,
 ``head``. The decode loops (models/generate.py) add ``decode_loop`` around
 the while loop, ``sample`` (``grammar_mask``, ``top_p``) and ``row_state``.
@@ -33,9 +34,8 @@ The paged session pool is stored ONCE, ``[L, n_pages, page, KV·hd]``
 (kv-heads flattened into the lane dimension — the layout the ragged
 kernel streams; generate.py ``_ensure_pool``). ``forward_hidden_ragged``,
 the serving path, carries it through the layer scan in place and hands
-it to the kernel whole. The older split paths (``forward_hidden_paged``,
-``forward_hidden_paged_prefill``) still scan it as ``xs`` and take a
-``[n_pages, page, KV, hd]`` view of each layer at their entry.
+it to the kernel whole; the gather programs (generate.py) index its
+pages into the dense cache ``forward_hidden`` attends over.
 """
 
 from __future__ import annotations
@@ -664,123 +664,6 @@ def forward_hidden(
             layer_body, x, (params["layers"], cache.k, cache.v))
     return (_final_norm(x, params, cfg),
             KVCache(k=new_k, v=new_v, lens=cache.lens))
-
-
-def forward_hidden_paged(
-    params: dict,
-    cfg: ModelConfig,
-    tokens: jax.Array,       # [B, 1] int32 (decode step)
-    positions: jax.Array,    # [B, 1] int32 absolute positions
-    k_pool: jax.Array,       # [L, n_pages, page, KV·hd] — read-only
-    v_pool: jax.Array,
-    tables: jax.Array,       # [B, maxp] int32 page table
-    pool_lens: jax.Array,    # [B] int32 valid pool tokens (fixed in decode)
-    kv_off: jax.Array,       # [B] int32 absolute position of pool index 0
-    tail_k: jax.Array,       # [L, B, Tmax, n_kv, hd] generated-token KV
-    tail_v: jax.Array,
-    step: jax.Array,         # scalar int32: tail slot this token writes
-    shard: Optional[tuple] = None,   # (mesh, tp_axis, dp_axis|None)
-) -> tuple[jax.Array, jax.Array, jax.Array]:
-    """Decode-step forward against the PAGED session pool: attention reads
-    the row's pages directly (ops/paged_attention.py — ragged, only
-    ceil(pool_lens/page) pages stream per row) merged with the dense tail
-    of tokens generated this call. The pool is never gathered into a
-    contiguous working cache (NOTES_r03 gap 2). Returns (hidden [B, 1, D],
-    new tail_k, new tail_v)."""
-    require_plain(cfg, "forward_hidden_paged (the split paged decode)")
-    from quoracle_tpu.ops.paged_attention import paged_decode_attend
-    B, T = tokens.shape
-    x = _embed(params, cfg, tokens)
-
-    def layer_body(x, scanned):
-        p, kp, vp, tk, tv = scanned
-        # the split kernels take one layer's [n_pages, page, KV, hd] view
-        kp, vp = (a.reshape(*a.shape[:2], cfg.n_kv_heads, cfg.head_dim)
-                  for a in (kp, vp))
-        q, k, v = _qkv(x, p, cfg, B, T, positions)
-        # all rows write the same tail slot (done rows deposit junk there;
-        # the causal mask excludes it — their frozen q_pos precedes it)
-        with jax.named_scope("kv_write"):
-            tk = jax.lax.dynamic_update_slice_in_dim(tk, k, step, axis=1)
-            tv = jax.lax.dynamic_update_slice_in_dim(tv, v, step, axis=1)
-        with jax.named_scope("attn"):
-            attn = paged_decode_attend(
-                q, kp, vp, tables, pool_lens, kv_off, tk, tv,
-                tail_len=step + 1, q_pos=positions[:, 0],
-                sliding_window=cfg.sliding_window, shard=shard)
-        x = _attn_out(x, attn, p, cfg)
-        x = _mlp(x, p, cfg)
-        return x, (tk, tv)
-
-    with jax.named_scope("layers"):
-        x, (new_tk, new_tv) = jax.lax.scan(
-            layer_body, x,
-            (params["layers"], k_pool, v_pool, tail_k, tail_v))
-    return _final_norm(x, params, cfg), new_tk, new_tv
-
-
-def forward_hidden_paged_prefill(
-    params: dict,
-    cfg: ModelConfig,
-    tokens: jax.Array,       # [B, T] int32 right-padded suffix chunk
-    positions: jax.Array,    # [B, T] int32 absolute positions
-    k_pool: jax.Array,       # [L, n_pages, page, KV·hd] (donated by jit)
-    v_pool: jax.Array,
-    src_tables: jax.Array,   # [B, maxp] pages holding the resident prefix
-    prefix_lens: jax.Array,  # [B] int32 resident pool tokens per row
-    chunk_lens: jax.Array,   # [B] int32 valid chunk tokens per row
-    flat_dst: jax.Array,     # [B, T] int32 flat pool token slot for each
-                             # chunk position (OOB sentinel = drop), from
-                             # the row's DST page table
-    interpret: Optional[bool] = None,
-    shard: Optional[tuple] = None,   # (mesh, tp_axis, dp_axis|None)
-) -> tuple[jax.Array, jax.Array, jax.Array]:
-    """PREFILL against the paged session pool: the suffix chunk attends to
-    the resident prefix by streaming its pages directly
-    (ops/paged_attention.paged_prefill_merge — one kernel launch per layer
-    per CHUNK) merged with dense causal intra-chunk attention, and the
-    chunk's own KV scatters straight into the row's dst pages. The
-    [B, maxp·page] contiguous working cache the gather path materializes
-    never exists (VERDICT r4 item 2; NOTES_r03 gap 1). Returns
-    (hidden [B, T, D], k_pool, v_pool) with the chunk KV written."""
-    require_plain(cfg, "forward_hidden_paged_prefill (the split paged prefill)")
-    from quoracle_tpu.ops.paged_attention import paged_prefill_merge
-    B, T = tokens.shape
-    n_tok = k_pool.shape[1] * k_pool.shape[2]
-    x = _embed(params, cfg, tokens)
-
-    def layer_body(x, scanned):
-        p, kp, vp = scanned          # kp/vp: [n_pages, page, KV·hd]
-        q, k, v = _qkv(x, p, cfg, B, T, positions)
-        with jax.named_scope("attn"):
-            # the split kernel takes the [n_pages, page, KV, hd] view
-            attn = paged_prefill_merge(
-                q, k.astype(kp.dtype), v.astype(vp.dtype),
-                kp.reshape(*kp.shape[:2], *k.shape[2:]),
-                vp.reshape(*vp.shape[:2], *v.shape[2:]),
-                src_tables, prefix_lens, chunk_lens,
-                sliding_window=cfg.sliding_window, interpret=interpret,
-                shard=shard)
-        # chunk KV → dst pages in place (padding/overflow slots carry the
-        # OOB sentinel and drop). The attention above read the pool BEFORE
-        # this write; chunk↔chunk attention used the dense piece, so
-        # nothing this layer needs re-reading.
-        with jax.named_scope("kv_write"):
-            kf = kp.reshape(n_tok, kp.shape[2])
-            vf = vp.reshape(n_tok, vp.shape[2])
-            kf = kf.at[flat_dst].set(
-                k.reshape(B, T, -1).astype(kp.dtype), mode="drop")
-            vf = vf.at[flat_dst].set(
-                v.reshape(B, T, -1).astype(vp.dtype), mode="drop")
-            kp2, vp2 = kf.reshape(kp.shape), vf.reshape(vp.shape)
-        x = _attn_out(x, attn.astype(x.dtype), p, cfg)
-        x = _mlp(x, p, cfg)
-        return x, (kp2, vp2)
-
-    with jax.named_scope("layers"):
-        x, (new_k, new_v) = jax.lax.scan(
-            layer_body, x, (params["layers"], k_pool, v_pool))
-    return _final_norm(x, params, cfg), new_k, new_v
 
 
 def forward_hidden_ragged(

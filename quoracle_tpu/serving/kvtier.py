@@ -578,7 +578,7 @@ class TierManager:
         # restore ladder's last rung (host → disk → FLEET) and the
         # spill writer's second publish target.
         self.prefixd = None
-        # monotonic counters (stats() → /api/kv + bench config 14)
+        # monotonic counters (stats() → /api/kv)
         self.demoted_sessions = 0
         self.demoted_prefix_pages = 0
         self.restored_sessions = 0

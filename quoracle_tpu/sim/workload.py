@@ -26,9 +26,9 @@ Serialization is canonical (sorted keys, no whitespace, ints only in
 event rows), so *byte*-identical traces under the same seed is a
 checkable contract, not an accident of dict ordering.
 
-The ``bench_*`` helpers at the bottom are the single home for the
-prompt mixes bench.py configs 11/20/22 drive — previously duplicated
-hand-rolled loops, now sourced from a simulator trace.
+The ``bench_*`` helpers at the bottom are the single home for small
+fixed-count prompt mixes (interactive rows, sessions, an overload and a
+fleet mix), sourced from a simulator trace instead of hand-rolled loops.
 """
 
 from __future__ import annotations
@@ -513,8 +513,8 @@ CANONICAL = ("diurnal_mix", "storm", "agent_tree", "longtail_ladder")
 def bench_trace(kind: str, n: int, seed: int = 2026,
                 spacing_ms: int = 1_000) -> Trace:
     """A tiny evenly-spaced single-stream trace: the simulator source
-    for bench.py's fixed-count phases (each bench row is one event; the
-    event's stream counter indexes its prompt text)."""
+    for a fixed-count phase (each row is one event; the event's stream
+    counter indexes its prompt text)."""
     cls = {"interactive": "interactive", "session": "agent",
            "batch": "batch"}[kind]
     spec = WorkloadSpec(seed=seed, horizon_ms=(n + 1) * spacing_ms)

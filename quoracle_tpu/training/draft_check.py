@@ -21,7 +21,7 @@ and "small" (the finetune target) and "tiny" (the draft) both use vocab
 tokenizer.json rather than trusting that.
 
     JAX_PLATFORMS=cpu python -m quoracle_tpu.tools.train_draft --steps 400 \
-        --out-artifact SPECULATIVE_r05.json
+        --out-artifact /tmp/speculative.json
 
 Prereq: checkpoints/finetune-format/{base,tuned} from a prior
 `tools/finetune.py --target format` run (the tool errors with the
@@ -360,7 +360,7 @@ def main() -> None:
         "cpu_spec_ms_per_token_p50": round(
             statistics.median(spec_ms), 2) if spec_ms else None,
         "note": ("held-out format tasks, greedy; realized chip speedup = "
-                 "bench config7 ceiling x this acceptance; CPU ms are "
+                 "the draft's ceiling x this acceptance; CPU ms are "
                  "smoke (compute-bound host, see BASELINE.md config 7)"),
     }
     line = json.dumps(payload)

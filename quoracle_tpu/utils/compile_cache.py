@@ -4,7 +4,7 @@ First-touch compiles dominate cold starts: every (prefill, decode) shape
 bucket compiles on first use, and a growing conversation crossing a bucket
 pays again. JAX's persistent cache keys compiled executables by (HLO,
 flags, platform) on disk, so every process after the first reuses them.
-The Runtime's TPU backend, bench.py, chip_smoke.py and the tools enable it
+The Runtime's TPU backend, chip_smoke.py and the tools enable it
 (the mock backend never compiles, so it skips the setup).
 
 Placement: where ``JAX_COMPILATION_CACHE_DIR`` is set JAX already reads

@@ -67,6 +67,20 @@ if os.environ.get("QUORACLE_LOCKDEP", "").strip().lower() not in (
     lockdep.enable()
 
 
+@pytest.fixture(params=["ragged", "gather"])
+def paged_path(request, monkeypatch):
+    """Session-behaviour tests run once per paged path: every engine the
+    test builds serves its sessioned ticks through the ragged programs
+    (the default on every platform) or, pinned by the engine's one test
+    seam, through the gather programs a tick falls back to (a dp/sp mesh,
+    a declined store, a swapped boundary page: generate.ragged_fallback),
+    which stay reachable on the chip."""
+    if request.param == "gather":
+        from quoracle_tpu.models.generate import GenerateEngine
+        monkeypatch.setattr(GenerateEngine, "_force_gather_decode", True)
+    return request.param
+
+
 @pytest.fixture(autouse=True)
 def _lockdep_guard():
     """Fail any test whose execution produced a lock-order inversion.

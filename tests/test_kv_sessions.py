@@ -30,7 +30,7 @@ def test_lcp():
     assert _lcp([1, 2], [1, 2]) == 2
 
 
-def test_session_reuse_matches_fresh_greedy():
+def test_session_reuse_matches_fresh_greedy(paged_path):
     """Round 2 extends round 1's prompt (refinement shape). With session
     reuse the suffix-prefill path must produce identical greedy tokens."""
     fresh = make_engine()
@@ -58,7 +58,7 @@ def test_session_reuse_matches_fresh_greedy():
     assert cached.last_prefill_tokens == len(p2) - len(p1) - n_resp_kv
 
 
-def test_session_divergence_partial_reuse():
+def test_session_divergence_partial_reuse(paged_path):
     """Condensation rewrites history mid-way: only the still-matching
     prefix (system prompt) is reused; output equals fresh."""
     fresh = make_engine()
@@ -88,7 +88,7 @@ def test_identical_reprompt_still_generates():
     assert b[0].n_cached_tokens == len(p) - 1
 
 
-def test_mixed_batch_sessions_and_fresh_rows():
+def test_mixed_batch_sessions_and_fresh_rows(paged_path):
     eng = make_engine()
     pa = enc("user: row a")
     pb = enc("user: row b, no session")
@@ -165,7 +165,7 @@ def test_backend_threads_sessions_through(monkeypatch):
     assert eng.last_prefill_tokens < full
 
 
-def test_mixed_batch_long_fresh_row_does_not_corrupt_resumed_row():
+def test_mixed_batch_long_fresh_row_does_not_corrupt_resumed_row(paged_path):
     """Review r2 repro: a resumed row (large prefix, short suffix) batched
     with a LONG fresh row once made cache_len < prefix + T_padded;
     dynamic_update_slice clamps, scribbling the pad chunk over valid prefix
@@ -349,7 +349,7 @@ def test_drop_session_frees_engine_state():
 SHARED_SYS = "system: " + "policy rules apply here. " * 7   # > 1 page
 
 
-def test_cross_session_prefix_sharing_token_exact():
+def test_cross_session_prefix_sharing_token_exact(paged_path):
     """A NEW session whose prompt starts with another session's
     page-aligned prefix adopts those pages: the first prefill skips the
     shared system prompt, and greedy output is identical to a
@@ -372,7 +372,7 @@ def test_cross_session_prefix_sharing_token_exact():
         "prefix-shared decode diverged from the sharing-disabled engine"
 
 
-def test_prefix_sharing_survives_donor_drop_and_frees_pages():
+def test_prefix_sharing_survives_donor_drop_and_frees_pages(paged_path):
     """Refcounts: dropping the DONOR must not free pages an adopter still
     reads; after dropping everyone the only pages still out are the radix
     prefix cache's (by design — cached prefixes outlive their sessions),
@@ -411,7 +411,7 @@ def test_prefix_sharing_survives_donor_drop_and_frees_pages():
         "prefix-cache clear did not return the pool to baseline"
 
 
-def test_prefix_sharing_donor_divergence_does_not_corrupt_adopter():
+def test_prefix_sharing_donor_divergence_does_not_corrupt_adopter(paged_path):
     """A donor whose conversation diverges (condensation) rewrites its
     dst pages — shared pages beyond the identical-prefix region must be
     swapped for fresh ones so the adopter's KV stays intact."""
@@ -442,56 +442,3 @@ def test_prefix_sharing_donor_divergence_does_not_corrupt_adopter():
         "donor divergence corrupted the adopter's shared prefix"
 
 
-def test_prefix_sharing_divergence_under_direct_paths():
-    """Prefix sharing + FORCED direct paged prefill/decode + donor
-    divergence at a non-page-aligned reuse point: the swapped boundary
-    page leaves a dst hole only the gather scatter fills, so the batch
-    must fall back to gather prefill — output stays token-exact with a
-    sharing-disabled gather engine, and the donor's NEXT round (reading
-    its stored pages) stays intact too."""
-    def forced(eng):
-        eng.direct_decode_min_tokens = 0
-        eng.direct_prefill_min_tokens = 0
-        return eng
-
-    eng = forced(make_engine())
-    plain = make_engine()
-    plain.prefix_sharing = False
-    plain._force_gather_decode = True
-
-    pa = enc(SHARED_SYS + "user: task alpha")
-    pb = enc(SHARED_SYS + "user: task beta")
-    ra = eng.generate([pa], temperature=0.0, max_new_tokens=8,
-                      session_ids=["a"])
-    rb = eng.generate([pb], temperature=0.0, max_new_tokens=8,
-                      session_ids=["b"])
-    assert rb[0].n_cached_tokens >= 128
-    # donor diverges at a MID-PAGE point: common prefix with its resident
-    # tokens ends inside a shared page (reuse % page != 0)
-    pa_div = pa[:150] + enc("user: different continuation")[1:]
-    ra2 = eng.generate([pa_div], temperature=0.0, max_new_tokens=8,
-                       session_ids=["a"])
-    want_div = plain.generate([pa_div], temperature=0.0, max_new_tokens=8,
-                              session_ids=["w1"])
-    assert ra2[0].token_ids == want_div[0].token_ids, \
-        "boundary-page swap corrupted the DONOR's own round"
-    # donor continues on its stored (post-divergence) pages
-    pa3 = pa_div + ra2[0].token_ids + enc(" next")[1:]
-    ra3 = eng.generate([pa3], temperature=0.0, max_new_tokens=8,
-                       session_ids=["a"])
-    pw3 = pa_div + want_div[0].token_ids + enc(" next")[1:]
-    want3 = plain.generate([pw3], temperature=0.0, max_new_tokens=8,
-                           session_ids=["w1"])
-    assert ra3[0].token_ids == want3[0].token_ids, \
-        "donor's stored pages hold wrong KV after the boundary swap"
-    # and the ADOPTER's shared prefix is still intact
-    pb2 = pb + rb[0].token_ids + enc(" more")[1:]
-    rb2 = eng.generate([pb2], temperature=0.0, max_new_tokens=8,
-                       session_ids=["b"])
-    wb = plain.generate([pb], temperature=0.0, max_new_tokens=8,
-                        session_ids=["w2"])
-    pwb2 = pb + wb[0].token_ids + enc(" more")[1:]
-    wb2 = plain.generate([pwb2], temperature=0.0, max_new_tokens=8,
-                         session_ids=["w2"])
-    assert rb2[0].token_ids == wb2[0].token_ids, \
-        "donor divergence corrupted the adopter under direct paths"

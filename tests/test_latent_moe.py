@@ -449,10 +449,6 @@ REFUSALS = {
     "forward_hidden": lambda e: tr.forward_hidden(
         e.params, e.cfg, jnp.zeros((1, 4), jnp.int32),
         jnp.zeros((1, 4), jnp.int32), None, None, None),
-    "forward_hidden_paged": lambda e: tr.forward_hidden_paged(
-        e.params, e.cfg, jnp.zeros((1, 1), jnp.int32), *[None] * 10),
-    "forward_hidden_paged_prefill": lambda e: tr.forward_hidden_paged_prefill(
-        e.params, e.cfg, jnp.zeros((1, 4), jnp.int32), *[None] * 7),
     "host and disk KV tiers": lambda e: e.attach_tier(host_mb=8),
     "handoff": lambda e: __import__(
         "quoracle_tpu.serving.handoff", fromlist=["KVHandoff"]

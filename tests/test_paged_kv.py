@@ -2,11 +2,13 @@
 device-resident pool — resume moves no KV bytes through the host, response
 KV is retained, pages recycle, and sliding-window models keep a
 window-bounded resident footprint (with correct outputs after trimming).
+The session behaviours hold on both paged paths (conftest ``paged_path``):
+the ragged programs every engine serves with, and the gather programs a
+tick falls back to.
 """
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from quoracle_tpu.models.config import ModelConfig, get_model_config, register_model
 from quoracle_tpu.models.generate import PAGE, GenerateEngine, _Session
@@ -34,7 +36,7 @@ TINY_WINDOW = register_model(ModelConfig(
 ))
 
 
-def test_sessions_hold_page_ids_not_kv_copies():
+def test_sessions_hold_page_ids_not_kv_copies(paged_path):
     """The 'no full-buffer copy' criterion: a stored session is host ints
     (tokens + page ids + offset) — zero device arrays per session; the KV
     lives only in the shared pool, and resume prefills only the suffix."""
@@ -56,7 +58,7 @@ def test_sessions_hold_page_ids_not_kv_copies():
     assert eng.last_prefill_tokens == len(p2) - (len(p1) + len(r1.token_ids) - 1)
 
 
-def test_pages_recycle_on_drop_and_divergence():
+def test_pages_recycle_on_drop_and_divergence(paged_path):
     eng = make_engine()
     free0 = None
     for round_trip in range(3):
@@ -71,7 +73,7 @@ def test_pages_recycle_on_drop_and_divergence():
         assert free == free0
 
 
-def test_eviction_recycles_lru_session_pages():
+def test_eviction_recycles_lru_session_pages(paged_path):
     # small pool: 4 usable pages
     eng = make_engine(session_max_bytes=1)  # floor → PAGE tokens minimum
     eng.sessions.__init__(max_tokens=4 * PAGE)
@@ -90,7 +92,7 @@ def test_eviction_recycles_lru_session_pages():
     assert total_pages <= 4
 
 
-def test_sliding_window_bounds_resident_footprint():
+def test_sliding_window_bounds_resident_footprint(paged_path):
     """Mistral-style model: the session's resident KV stays within
     window + one page regardless of conversation length (VERDICT done
     criterion: 'Mistral's KV footprint is window-bounded')."""
@@ -110,7 +112,7 @@ def test_sliding_window_bounds_resident_footprint():
     assert len(s.pages) * eng.sessions.page >= W   # window stays covered
 
 
-def test_sliding_window_resume_matches_fresh():
+def test_sliding_window_resume_matches_fresh(paged_path):
     """Trimmed-session resume (nonzero kv position offset) must produce
     exactly the tokens a fresh full prefill produces."""
     cfg = get_model_config("xla:tiny-window")
@@ -131,7 +133,7 @@ def test_sliding_window_resume_matches_fresh():
     assert got.n_cached_tokens > 0
 
 
-def test_windowed_divergence_discards_reuse():
+def test_windowed_divergence_discards_reuse(paged_path):
     """A divergent prompt on a windowed model cannot reuse the trimmed
     window (hole below the new tokens' attention span) — must fall back to
     full prefill with matching output."""
@@ -152,7 +154,7 @@ def test_windowed_divergence_discards_reuse():
     assert got.n_cached_tokens == 0             # no partial reuse
 
 
-def test_duplicate_session_id_in_batch_stores_once():
+def test_duplicate_session_id_in_batch_stores_once(paged_path):
     eng = make_engine()
     pa, pb = enc("row one"), enc("row two, different")
     res = eng.generate([pa, pb], temperature=0.0, max_new_tokens=4,
@@ -161,73 +163,6 @@ def test_duplicate_session_id_in_batch_stores_once():
     s = eng.sessions.get("dup")
     # first occurrence owns the session
     assert s.tokens[:len(pa)] == list(pa)
-
-
-def test_direct_decode_matches_gather_decode():
-    """The direct paged decode (pool + tail, ops/paged_attention.py) must
-    produce the same greedy tokens as the gather-decode fallback for the
-    same prompts/sessions — including a mixed batch with a sessionless row
-    (temp pages) and a resumed refinement round."""
-    def run(eng):
-        pa = enc("user: compare decode paths please")
-        pb = enc("user: a sessionless neighbor row")
-        r = eng.generate([pa, pb], temperature=0.0, max_new_tokens=10,
-                         session_ids=["s", None])
-        pa2 = pa + r[0].token_ids + enc(" go on")[1:]
-        r2 = eng.generate([pa2, pb], temperature=0.0, max_new_tokens=10,
-                          session_ids=["s", None])
-        return [x.token_ids for x in r + r2]
-
-    direct = make_engine()
-    direct.direct_decode_min_tokens = 0       # force the ragged-kernel path
-    fallback = make_engine()
-    fallback._force_gather_decode = True      # test seam (_run_paged)
-    assert run(direct) == run(fallback)
-
-
-def test_direct_decode_releases_temp_pages():
-    """Sessionless rows borrow pool pages for the direct decode; they must
-    return them after the call."""
-    eng = make_engine()
-    eng.direct_decode_min_tokens = 0          # force the ragged-kernel path
-    free0 = None
-    p = enc("user: temp page bookkeeping")
-    eng.generate([p], temperature=0.0, max_new_tokens=6, session_ids=["a"])
-    free0 = eng.sessions.free_pages()
-    # batch with one sessioned + one sessionless row
-    p2 = enc("user: another prompt entirely")
-    eng.generate([p, p2], temperature=0.0, max_new_tokens=6,
-                 session_ids=["a", None])
-    # session "a" may grow (same prompt → same pages); the temp pages for
-    # the sessionless row are all back
-    assert eng.sessions.free_pages() == free0
-
-
-def test_paged_kernel_matches_reference():
-    """The Pallas kernel (interpret mode off-TPU) agrees with the XLA
-    gather reference on ragged rows, offsets, and sliding windows."""
-    from quoracle_tpu.ops.paged_attention import (
-        paged_attend, paged_attend_ref,
-    )
-    rng = np.random.default_rng(1)
-    B, H, KV, hd, page, n_pages = 3, 8, 2, 32, 16, 12
-    q = jnp.asarray(rng.standard_normal((B, H, hd)), jnp.float32)
-    kp = jnp.asarray(rng.standard_normal((n_pages, page, KV, hd)),
-                     jnp.float32)
-    vp = jnp.asarray(rng.standard_normal((n_pages, page, KV, hd)),
-                     jnp.float32)
-    tables = jnp.asarray([[1, 2, 3, 0], [4, 5, 0, 0], [6, 7, 8, 9]],
-                         jnp.int32)
-    kv_lens = jnp.asarray([40, 17, 64], jnp.int32)
-    kv_off = jnp.asarray([0, 16, 0], jnp.int32)
-    q_pos = kv_off + kv_lens + 3
-    for w in (None, 24):
-        ref = paged_attend_ref(q, kp, vp, tables, kv_lens, kv_off, q_pos, w)
-        krn = paged_attend(q, kp, vp, tables, kv_lens, kv_off, q_pos, w,
-                           interpret=jax.devices()[0].platform != "tpu")
-        for a, b in zip(ref, krn):
-            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                       rtol=2e-4, atol=2e-4)
 
 
 def test_pool_exhaustion_serves_without_storing():
@@ -240,145 +175,3 @@ def test_pool_exhaustion_serves_without_storing():
     assert eng.sessions.get("big") is None      # just not stored
 
 
-def _enable_direct(eng, prefill=False):
-    eng.direct_decode_min_tokens = 0
-    eng.direct_prefill_min_tokens = 0 if prefill else 1 << 30
-
-
-def test_direct_prefill_matches_gather_prefill():
-    """The DIRECT paged prefill (suffix chunk attends to resident pages in
-    place, chunk KV scattered to dst pages; transformer.
-    forward_hidden_paged_prefill) must produce the same greedy tokens as
-    the gather path — fresh call, resumed refinement round, and a mixed
-    batch with a sessionless (temp-page) row."""
-    def run(eng):
-        pa = enc("user: compare prefill paths please, with some length")
-        pb = enc("user: a sessionless neighbor row")
-        r = eng.generate([pa, pb], temperature=0.0, max_new_tokens=10,
-                         session_ids=["s", None])
-        pa2 = pa + r[0].token_ids + enc(" refine that answer")[1:]
-        r2 = eng.generate([pa2, pb], temperature=0.0, max_new_tokens=10,
-                          session_ids=["s", None])
-        return [x.token_ids for x in r + r2]
-
-    direct = make_engine()
-    _enable_direct(direct, prefill=True)
-    fallback = make_engine()
-    fallback._force_gather_decode = True
-    got, want = run(direct), run(fallback)
-    assert got == want
-    # and the direct engine really took the paged-prefill path
-    assert direct.direct_prefill_min_tokens == 0
-
-
-def test_direct_prefill_windowed_resume_matches_fresh():
-    """Sliding-window model: a trimmed-session resume through the direct
-    prefill (nonzero kv_off, window masks inside both kernel pieces) must
-    match a fresh full prefill."""
-    cfg = get_model_config("xla:tiny-window")
-    params = init_params(cfg, jax.random.PRNGKey(1), dtype=jnp.float32)
-    cached = GenerateEngine(cfg, params, ByteTokenizer(), max_seq=1024,
-                            prompt_buckets=(64, 128, 256, 512))
-    _enable_direct(cached, prefill=True)
-    fresh = GenerateEngine(cfg, params, ByteTokenizer(), max_seq=1024,
-                           prompt_buckets=(64, 128, 256, 512))
-    p = enc("u: " + "window test " * 30)
-    r1 = cached.generate([p], temperature=0.0, max_new_tokens=8,
-                         session_ids=["w"])[0]
-    assert cached.sessions.get("w").start_pos > 0
-    p2 = p + r1.token_ids + enc(" continue")[1:]
-    want = fresh.generate([p2], temperature=0.0, max_new_tokens=8)[0]
-    got = cached.generate([p2], temperature=0.0, max_new_tokens=8,
-                          session_ids=["w"])[0]
-    assert got.token_ids == want.token_ids
-    assert got.n_cached_tokens > 0
-
-
-def test_direct_prefill_chunk_cap_falls_back():
-    """Chunks past prefill_max_chunk (the dense O(T²) intra-chunk bound)
-    must fall back to the gather prefill with identical output."""
-    direct = make_engine(max_seq=1024, prompt_buckets=(64, 128, 256, 512))
-    _enable_direct(direct, prefill=True)
-    direct.direct_prefill_max_chunk = 64        # padded T will exceed this
-    fallback = make_engine(max_seq=1024, prompt_buckets=(64, 128, 256, 512))
-    fallback._force_gather_decode = True
-    p = enc("user: " + "a long fresh prompt " * 20)   # chunk > 64
-    want = fallback.generate([p], temperature=0.0, max_new_tokens=8,
-                             session_ids=["s"])[0]
-    got = direct.generate([p], temperature=0.0, max_new_tokens=8,
-                          session_ids=["s"])[0]
-    assert got.token_ids == want.token_ids
-
-
-def test_direct_prefill_releases_temp_pages():
-    eng = make_engine()
-    _enable_direct(eng, prefill=True)
-    p = enc("user: temp page bookkeeping for prefill")
-    eng.generate([p], temperature=0.0, max_new_tokens=6, session_ids=["a"])
-    free0 = eng.sessions.free_pages()
-    p2 = enc("user: another prompt entirely")
-    eng.generate([p, p2], temperature=0.0, max_new_tokens=6,
-                 session_ids=["a", None])
-    assert eng.sessions.free_pages() == free0
-
-
-def test_paged_prefill_kernel_matches_reference():
-    """Interpret-mode prefill kernel vs the XLA gather reference: ragged
-    prefixes (incl. zero), multiple T-blocks, sliding window."""
-    from quoracle_tpu.ops.paged_attention import (
-        paged_prefill_attend, paged_prefill_attend_ref,
-    )
-    rng = np.random.default_rng(2)
-    B, T, H, KV, hd, page, n_pages, maxp = 3, 24, 8, 2, 32, 16, 12, 4
-    q = jnp.asarray(rng.standard_normal((B, T, H, hd)), jnp.float32)
-    kp = jnp.asarray(rng.standard_normal((n_pages, page, KV, hd)),
-                     jnp.float32)
-    vp = jnp.asarray(rng.standard_normal((n_pages, page, KV, hd)),
-                     jnp.float32)
-    tables = jnp.asarray(rng.integers(0, n_pages, (B, maxp)), jnp.int32)
-    prefix = jnp.asarray([40, 0, 61], jnp.int32)
-    for w in (None, 24):
-        ref = paged_prefill_attend_ref(q, kp, vp, tables, prefix, w)
-        krn = paged_prefill_attend(
-            q, kp, vp, tables, prefix, w, t_blk=8,
-            interpret=jax.devices()[0].platform != "tpu")
-        # compare NORMALIZED outputs (raw partials scale with the denom)
-        for (a, ma, la), (b, mb, lb) in ((ref, krn),):
-            na = np.asarray(a) / np.maximum(np.asarray(la), 1e-30)[..., None]
-            nb = np.asarray(b) / np.maximum(np.asarray(lb), 1e-30)[..., None]
-            np.testing.assert_allclose(na, nb, rtol=2e-4, atol=2e-4)
-
-
-def test_paged_gates_calibration_roundtrip(tmp_path, monkeypatch):
-    """Engine gates come from the measured calibration file (VERDICT r3
-    weak #2: config/derived, not hardcoded)."""
-    from quoracle_tpu.utils.calibration import (
-        load_paged_gates, save_paged_gates,
-    )
-    here = getattr(jax.devices()[0], "device_kind", "")
-    path = str(tmp_path / "gates.json")
-    save_paged_gates(path, decode_min_resident=4096,
-                     prefill_min_resident=None, prefill_max_chunk=512,
-                     device_kind=here, note="unit test")
-    monkeypatch.setenv("QUORACLE_PAGED_CALIB", path)
-    g = load_paged_gates()
-    assert g.decode_min_resident == 4096
-    assert g.prefill_min_resident == 1 << 30     # null = off
-    assert g.prefill_max_chunk == 512
-    eng = make_engine()
-    assert eng.direct_decode_min_tokens == 4096
-    assert eng.direct_prefill_min_tokens == 1 << 30
-    # a file measured on a DIFFERENT device kind must not govern this host
-    # (launch-cost regimes differ ~1000× across dispatch setups)
-    other = str(tmp_path / "other.json")
-    save_paged_gates(other, decode_min_resident=0, prefill_min_resident=0,
-                     device_kind="TPU imaginary v9", note="wrong host")
-    monkeypatch.setenv("QUORACLE_PAGED_CALIB", other)
-    g_mismatch = load_paged_gates()
-    assert g_mismatch.decode_min_resident == 1 << 30
-    assert "TPU imaginary v9" in g_mismatch.source
-    # no file → conservative defaults, documented source
-    monkeypatch.setenv("QUORACLE_PAGED_CALIB", str(tmp_path / "absent.json"))
-    g2 = load_paged_gates()
-    assert g2.decode_min_resident == 1 << 30
-    assert "default" in g2.source

@@ -125,10 +125,11 @@ def test_tree_eviction_is_lru():
 
 
 # ---------------------------------------------------------------------------
-# Engine integration
+# Engine integration: the equalities hold on both paged paths (conftest
+# ``paged_path``), the ragged programs and the gather fallback
 # ---------------------------------------------------------------------------
 
-def test_adoption_survives_donor_death():
+def test_adoption_survives_donor_death(paged_path):
     """The cache's own page references keep a prefix adoptable after the
     session that prefilled it is dropped — the old donor-scan sharing
     could not do this."""
@@ -149,7 +150,7 @@ def test_adoption_survives_donor_death():
     assert rb[0].token_ids == want[0].token_ids
 
 
-def test_temperature0_bit_identical_cache_on_vs_off():
+def test_temperature0_bit_identical_cache_on_vs_off(paged_path):
     """Satellite: greedy outputs must be bit-identical with the prefix
     cache enabled vs disabled, across fresh sessions that hit the cache."""
     on = make_engine()
@@ -168,7 +169,7 @@ def test_temperature0_bit_identical_cache_on_vs_off():
     assert off.sessions.prefix_cache.stats()["hits"] == 0
 
 
-def test_cow_shared_page_extension_preserves_sibling():
+def test_cow_shared_page_extension_preserves_sibling(paged_path):
     """Satellite: a session diverging INSIDE a shared page (extending the
     partially reused boundary) must copy-on-write — the swap counter
     moves and the sibling's adopted KV stays byte-intact."""
@@ -206,7 +207,7 @@ def test_cow_shared_page_extension_preserves_sibling():
         "COW failed: sibling read a rewritten shared page"
 
 
-def test_eviction_under_pressure_then_lookup_reprefills():
+def test_eviction_under_pressure_then_lookup_reprefills(paged_path):
     """Satellite: pool pressure evicts the cached prefix; the next lookup
     misses cleanly and re-prefills to the same greedy tokens."""
     # 6 usable pages (768 tokens at 512 B/token for xla:tiny fp32)
@@ -236,7 +237,7 @@ def test_eviction_under_pressure_then_lookup_reprefills():
     assert rb[0].token_ids == want[0].token_ids
 
 
-def test_consensus_fanout_batch_prefills_shared_prompt_once():
+def test_consensus_fanout_batch_prefills_shared_prompt_once(paged_path):
     """Acceptance shape: 3 rows (shared prompt, distinct suffixes, fresh
     sessions) in ONE batched call — rows 2..K prefill only their suffix
     via the intra-batch wave split."""

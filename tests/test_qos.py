@@ -260,13 +260,17 @@ def test_interactive_admit_wait_bounded_under_batch_flood():
 
     floor_s = 3.0
     eng = make_engine()
-    eng.generate([enc("user: warmup")], temperature=0.0,
-                 max_new_tokens=4)                  # pay compiles up front
     cb = ContinuousBatcher(
         eng, chunk=4, max_slots=2,
         policy=WeightedFairPolicy(aging_floor_s=floor_s,
                                   model="xla:tiny"))
     try:
+        # pay compiles up front: the flood's own tick shapes (two slots
+        # of 4-token chunks), through the batcher that will serve it
+        for f in [cb.submit(enc(f"user: bulk warm-up item {i}"),
+                            temperature=0.0, max_new_tokens=32,
+                            priority=Priority.BATCH) for i in range(4)]:
+            f.result(300)
         flood = [cb.submit(enc(f"user: bulk backlog item {i}"),
                            temperature=0.0, max_new_tokens=32,
                            priority=Priority.BATCH)

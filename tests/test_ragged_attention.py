@@ -21,10 +21,11 @@ pages. Tier-1 asserts three things:
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from quoracle_tpu.models.config import get_model_config
 from quoracle_tpu.models.generate import (
-    RAGGED_TQ, GenerateEngine,
+    PAGE, RAGGED_TQ, GenerateEngine,
 )
 from quoracle_tpu.models.tokenizer import ByteTokenizer
 from quoracle_tpu.models.transformer import init_params
@@ -49,11 +50,6 @@ def make_engine(name="xla:tiny", seed=0, **kw):
 
 def enc(text):
     return ByteTokenizer().encode(text, add_bos=True)
-
-
-def _unified(eng):
-    eng.unified_min_tokens = 0          # force the unified kernel path
-    return eng
 
 
 def _gather(eng):
@@ -162,7 +158,7 @@ def test_unified_matches_gather_greedy():
                           session_ids=["s", None])
         return [x.token_ids for x in r + r2]
 
-    got, want = run(_unified(make_engine())), run(_gather(make_engine()))
+    got, want = run(make_engine()), run(_gather(make_engine()))
     assert got == want
 
 
@@ -178,7 +174,7 @@ def test_unified_matches_gather_constrained_json():
                          action_enums=[("walk", "talk"), ("walk", "talk")])
         return [(x.token_ids, x.json_state) for x in r]
 
-    got, want = run(_unified(make_engine())), run(_gather(make_engine()))
+    got, want = run(make_engine()), run(_gather(make_engine()))
     assert got == want
 
 
@@ -196,7 +192,7 @@ def test_unified_matches_gather_speculative_verify():
         return r.token_ids, out["ids"], out["n_cached"], out["probs"]
 
     for need_probs in (False, True):
-        t1, v1, c1, p1 = run(_unified(make_engine()), need_probs)
+        t1, v1, c1, p1 = run(make_engine(), need_probs)
         t2, v2, c2, p2 = run(_gather(make_engine()), need_probs)
         assert (t1, v1, c1) == (t2, v2, c2)
         if need_probs:
@@ -219,7 +215,7 @@ def test_unified_matches_gather_constrained_verify():
                                initial_json_state=[r.json_state])[0]
         return r.token_ids, out["ids"]
 
-    assert run(_unified(make_engine())) == run(_gather(make_engine()))
+    assert run(make_engine()) == run(_gather(make_engine()))
 
 
 def test_unified_windowed_resume_matches_fresh():
@@ -229,9 +225,8 @@ def test_unified_windowed_resume_matches_fresh():
     import tests.test_paged_kv  # noqa: F401 — registers xla:tiny-window
     cfg = get_model_config("xla:tiny-window")
     params = init_params(cfg, jax.random.PRNGKey(1), dtype=jnp.float32)
-    cached = _unified(GenerateEngine(cfg, params, ByteTokenizer(),
-                                     max_seq=1024,
-                                     prompt_buckets=(64, 128, 256, 512)))
+    cached = GenerateEngine(cfg, params, ByteTokenizer(), max_seq=1024,
+                            prompt_buckets=(64, 128, 256, 512))
     fresh = GenerateEngine(cfg, params, ByteTokenizer(), max_seq=1024,
                            prompt_buckets=(64, 128, 256, 512))
     p = enc("u: " + "window test " * 30)
@@ -249,7 +244,7 @@ def test_unified_windowed_resume_matches_fresh():
 def test_unified_releases_temp_pages():
     """Sessionless rows borrow pool pages for the unified tick; every
     page must come back after the call."""
-    eng = _unified(make_engine())
+    eng = make_engine()
     p = enc("user: temp page bookkeeping")
     eng.generate([p], temperature=0.0, max_new_tokens=6,
                  session_ids=["a"])
@@ -260,43 +255,154 @@ def test_unified_releases_temp_pages():
     assert eng.sessions.free_pages() == free0
 
 
-# --- calibration gate + padding telemetry -----------------------------------
+# --- the rule of the paged path (generate.ragged_fallback) -------------------
 
 
-def test_unified_gate_calibration(tmp_path, monkeypatch):
-    """unified_min_resident: explicit value wins, explicit null = off,
-    ABSENT key (old files) = auto — off on CPU, so old calibration files
-    keep exactly their old behavior here."""
-    from quoracle_tpu.utils.calibration import (
-        load_paged_gates, resolve_unified_gate, save_paged_gates,
-    )
-    here = getattr(jax.devices()[0], "device_kind", "")
-    explicit = str(tmp_path / "explicit.json")
-    save_paged_gates(explicit, decode_min_resident=None,
-                     prefill_min_resident=None, unified_min_resident=2048,
-                     device_kind=here)
-    monkeypatch.setenv("QUORACLE_PAGED_CALIB", explicit)
-    g = load_paged_gates()
-    assert g.unified_min_resident == 2048
-    assert resolve_unified_gate(g) == 2048
-    assert make_engine().unified_min_tokens == 2048
+def _decode_paths(eng) -> list:
+    """Record which paged decode program each of ``eng``'s ticks runs."""
+    ran = []
+    for name in ("_step_paged_decode_ragged", "_step_paged_decode"):
+        def step(*a, _step=getattr(eng, name), _name=name, **kw):
+            ran.append("ragged" if _name.endswith("ragged") else "gather")
+            return _step(*a, **kw)
+        setattr(eng, name, step)
+    return ran
 
-    off = str(tmp_path / "off.json")
-    save_paged_gates(off, decode_min_resident=None,
-                     prefill_min_resident=None, unified_min_resident=None,
-                     device_kind=here)
-    monkeypatch.setenv("QUORACLE_PAGED_CALIB", off)
-    assert load_paged_gates().unified_min_resident == 1 << 30
 
-    legacy = str(tmp_path / "legacy.json")
-    save_paged_gates(legacy, decode_min_resident=4096,
-                     prefill_min_resident=None, device_kind=here)
-    monkeypatch.setenv("QUORACLE_PAGED_CALIB", legacy)
-    g = load_paged_gates()
-    assert g.unified_min_resident is None          # AUTO
-    assert g.decode_min_resident == 4096           # old keys still honored
-    on_tpu = jax.devices()[0].platform == "tpu"
-    assert resolve_unified_gate(g) == (0 if on_tpu else 1 << 30)
+def test_every_platform_takes_the_ragged_path(tmp_path, monkeypatch):
+    """A fresh dense engine, on whatever platform the tests run on and
+    with nothing in its environment, serves sessions through the ragged
+    programs: every CompileRegistry key is the ragged program identity.
+    The operator-supplied gate file is gone with the choice it made:
+    whatever the rehearsal configuration still exports (the variable
+    that once named the file) pointed at a file that says OFF changes
+    nothing, because nothing reads it."""
+    import json
+    import os
+
+    def keys():
+        eng = make_engine()
+        p = enc("user: which path serves me")
+        r = eng.generate([p, enc("user: a sessionless neighbour")],
+                         temperature=0.0, max_new_tokens=6,
+                         session_ids=["s", None])
+        eng.generate([p + r[0].token_ids + enc(" and again")[1:]],
+                     temperature=0.0, max_new_tokens=6, session_ids=["s"])
+        return [e["shape"] for e in eng.compiles.snapshot()["shapes"]]
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark/configs/tiny-l2.json")) as f:
+        exported = json.load(f).get("env", {})
+    for var in exported:
+        monkeypatch.delenv(var, raising=False)
+    bare = keys()
+    assert bare and all(k.startswith("ragged") for k in bare), bare
+    off = tmp_path / "gates.json"
+    off.write_text(json.dumps({
+        "decode_min_resident": None, "prefill_min_resident": None,
+        "prefill_max_chunk": 1024, "unified_min_resident": None,
+        "device_kind": getattr(jax.devices()[0], "device_kind", "")}))
+    for var in exported:
+        monkeypatch.setenv(var, str(off))
+    assert keys() == bare
+
+
+LONG = dict(max_seq=1024, prompt_buckets=(64, 128, 256, 512))
+
+
+def _outgrows_the_pool(eng):
+    """Tick 2 resumes session "a" with a suffix the pool cannot hold even
+    after eviction: the row reuses its prefix, its store is declined."""
+    p = enc("user: a session that will outgrow the pool")
+    r1 = eng.generate([p], temperature=0.0, max_new_tokens=4,
+                      session_ids=["a"])[0]
+    big = p + r1.token_ids + enc(" and then " + "x" * 400)[1:]
+    r2 = eng.generate([big], temperature=0.0, max_new_tokens=4,
+                      session_ids=["a"])[0]
+    assert r2.n_cached_tokens > 0
+    r3 = eng.generate([p + r1.token_ids + enc(" go on")[1:]],
+                      temperature=0.0, max_new_tokens=4,
+                      session_ids=["a"])[0]
+    return [r1, r2, r3]
+
+
+def _diverges_inside_a_shared_page(eng):
+    """Tick 2 diverges session "a" in the middle of its second page,
+    which the radix prefix cache holds too: the boundary page is swapped
+    for a fresh one (copy-on-write) with its reused head unwritten."""
+    pa = enc("system: " + "policy rules apply here. " * 12
+             + "user: task alpha")                      # > 2 pages
+    r1 = eng.generate([pa], temperature=0.0, max_new_tokens=8,
+                      session_ids=["a"])[0]
+    div = pa[:150] + enc("user: a different continuation")[1:]
+    r2 = eng.generate([div], temperature=0.0, max_new_tokens=8,
+                      session_ids=["a"])[0]
+    r3 = eng.generate([div + r2.token_ids + enc(" next")[1:]],
+                      temperature=0.0, max_new_tokens=8,
+                      session_ids=["a"])[0]
+    assert r3.n_cached_tokens >= len(div)    # resumes on the swapped page
+    return [r1, r2, r3]
+
+
+def _neighbour_finds_no_scratch(eng):
+    """Tick 2 carries a sessionless row longer than the free list: the
+    one reason found only by trying (no page for its temporaries)."""
+    p = enc("user: a short resident session")
+    r1 = eng.generate([p], temperature=0.0, max_new_tokens=4,
+                      session_ids=["a"])[0]
+    again = p + r1.token_ids + enc(" go on")[1:]
+    r2 = eng.generate([again, enc("x" * 400)], temperature=0.0,
+                      max_new_tokens=4, session_ids=["a", None])
+    r3 = eng.generate([again + r2[0].token_ids + enc(" more")[1:]],
+                      temperature=0.0, max_new_tokens=4,
+                      session_ids=["a"])[0]
+    return [r1, *r2, r3]
+
+
+@pytest.mark.parametrize("drive,pool_tokens", [
+    (_outgrows_the_pool, 2 * PAGE),
+    (_diverges_inside_a_shared_page, None),
+    (_neighbour_finds_no_scratch, 2 * PAGE),
+], ids=["declined_store", "swapped_boundary_page", "no_temporary_page"])
+def test_a_tick_falls_back_for_a_reason_it_can_observe(drive, pool_tokens):
+    """Each condition of ``ragged_fallback`` in turn routes ONE tick, the
+    second of three, to the gather programs: the tokens are those of an
+    engine no condition can arise in (room to spare, no shared pages) on
+    the same prompts, which serves all three ticks ragged; the tick after
+    is ragged again; and no page leaks."""
+    eng, ref = make_engine(**LONG), make_engine(**LONG)
+    if pool_tokens:
+        eng.sessions.__init__(max_tokens=pool_tokens)
+    ref.prefix_sharing = False
+    baseline = eng.sessions.free_pages()
+    ran, ran_ref = _decode_paths(eng), _decode_paths(ref)
+    got, want = drive(eng), drive(ref)
+    assert ran == ["ragged", "gather", "ragged"]
+    assert ran_ref == ["ragged"] * 3
+    assert [r.token_ids for r in got] == [r.token_ids for r in want]
+    eng.drop_session("a")
+    with eng.sessions.lock:
+        eng.sessions.prefix_cache.clear()
+    assert eng.sessions.free_pages() == baseline
+
+
+def test_engine_builds_only_programs_it_can_reach():
+    """The step programs of a plain single-device engine are this list:
+    three ragged, the four gather programs a tick falls back to
+    (``ragged_fallback``), and the dense-cache pair of a sessionless
+    call. A further family of paged programs cannot grow back unseen."""
+    eng = make_engine()
+    built = {k for k, v in vars(eng).items()
+             if k.startswith("_step_") and v is not None}
+    assert built == {
+        "_step_paged_ragged", "_step_paged_decode_ragged",
+        "_step_paged_ragged_verify",
+        "_step_paged_prefill", "_step_paged_decode", "_step_paged_verify",
+        "_step_scatter_prompt",
+        "_step_prefill", "_step_decode"}
+
+
+# --- padding telemetry ------------------------------------------------------
 
 
 def test_padding_telemetry_quantifies_raggedness():
@@ -319,7 +425,7 @@ def test_padding_telemetry_quantifies_raggedness():
         return (SCHED_REAL_TOKENS_TOTAL.value(model=name) - r0,
                 SCHED_PADDED_TOKENS_TOTAL.value(model=name) - p0)
 
-    real_u, padded_u = run(_unified(make_engine()))
+    real_u, padded_u = run(make_engine())
     real_g, padded_g = run(_gather(make_engine()))
     assert real_u == real_g == sum(len(p) for p in prompts)
     assert padded_u >= real_u and padded_g >= real_g
@@ -366,7 +472,7 @@ def test_compile_collapse_vs_bucketed_baseline():
                 eng.drop_session(s)
         return eng.compiles
 
-    uni = run(_unified(make_engine()))
+    uni = run(make_engine())
     gat = run(_gather(make_engine()))
     assert uni.misses <= RAGGED_PROGRAM_BOUND, uni.snapshot()
     assert uni.misses < gat.misses, (uni.snapshot(), gat.snapshot())
@@ -383,8 +489,6 @@ def test_compile_collapse_vs_bucketed_baseline():
 # reference below does none of that: one row at a time, one layer at a
 # time, a pool it indexes as [layer][page][slot][kv-head], dense attention
 # over the row's own tokens.
-
-import pytest                                              # noqa: E402
 
 from quoracle_tpu.models import transformer as tr          # noqa: E402
 from quoracle_tpu.models.config import ModelConfig         # noqa: E402

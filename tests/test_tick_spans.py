@@ -326,10 +326,8 @@ def has_scope(names: set, scope: str) -> bool:
 def make_engine():
     cfg = get_model_config(MEMBER)
     params = init_params(cfg, jax.random.PRNGKey(0), dtype=jnp.float32)
-    eng = GenerateEngine(cfg, params, ByteTokenizer(), max_seq=256,
-                         prompt_buckets=(32, 64, 128))
-    eng.unified_min_tokens = 0          # the unified ragged path
-    return eng
+    return GenerateEngine(cfg, params, ByteTokenizer(), max_seq=256,
+                          prompt_buckets=(32, 64, 128))
 
 
 @pytest.fixture(scope="module")
@@ -392,39 +390,21 @@ def test_constrained_decode_names_the_grammar_mask_beneath_sample():
     assert has_scope(names, "sample/top_p")
 
 
-@pytest.mark.parametrize("forward", ["forward_hidden",
-                                     "forward_hidden_paged",
-                                     "forward_hidden_paged_prefill"])
-def test_the_other_forwards_name_the_same_scopes(forward):
+def test_the_dense_cache_forward_names_the_same_scopes():
+    """``forward_hidden`` (the gather programs, sessionless calls, the
+    trainers) names its device work as the ragged forward does."""
     from quoracle_tpu.models import transformer as tr
     cfg = get_model_config(MEMBER)
     params = init_params(cfg, jax.random.PRNGKey(0), dtype=jnp.float32)
-    L, KV, HD = cfg.n_layers, cfg.n_kv_heads, cfg.head_dim
-    B, T, page, n_pages = 2, 8, 16, 4
-    pool = jnp.zeros((L, n_pages, page, KV * HD))    # as stored
+    B, T = 2, 8
     tok = jnp.zeros((B, T), jnp.int32)
     pos = jnp.zeros((B, T), jnp.int32)
     lens = jnp.full((B,), T, jnp.int32)
-    tables = jnp.zeros((B, 2), jnp.int32)
-    if forward == "forward_hidden":
-        def f(p):
-            return tr.forward_hidden(p, cfg, tok, pos,
-                                     tr.init_cache(cfg, B, 32, jnp.float32),
-                                     jnp.zeros((B,), jnp.int32), lens)
-    elif forward == "forward_hidden_paged":
-        tail = jnp.zeros((L, B, 4, KV, HD))
 
-        def f(p):
-            return tr.forward_hidden_paged(
-                p, cfg, tok[:, :1], pos[:, :1], pool, pool, tables, lens,
-                jnp.zeros((B,), jnp.int32), tail, tail,
-                jnp.asarray(0, jnp.int32))
-    else:
-        def f(p):
-            return tr.forward_hidden_paged_prefill(
-                p, cfg, tok, pos, pool, pool, tables,
-                jnp.zeros((B,), jnp.int32), lens,
-                jnp.zeros((B, T), jnp.int32), interpret=True)
+    def f(p):
+        return tr.forward_hidden(p, cfg, tok, pos,
+                                 tr.init_cache(cfg, B, 32, jnp.float32),
+                                 jnp.zeros((B,), jnp.int32), lens)
     names = op_names(jax.jit(f).lower(params))
     for scope in ("embed", "layers/qkv", "layers/rope", "layers/kv_write",
                   "layers/attn", "layers/attn_out", "layers/mlp",
@@ -464,44 +444,42 @@ def test_every_pallas_call_of_the_ragged_steps_has_its_pinned_name(
         assert names and set(names) == {"ragged_attend"}, (step, names)
 
 
-@pytest.mark.parametrize("kernel", ["ragged_attend", "paged_attend",
-                                    "paged_prefill_attend", "flash_attend"])
+@pytest.mark.parametrize("kernel", ["ragged_attend", "ragged_attend_tiny",
+                                    "ragged_attend_latent", "flash_attend"])
 def test_each_kernel_entry_point_pins_its_name(kernel):
     from quoracle_tpu.ops import flash_attention as fa
     from quoracle_tpu.ops import paged_attention as pa
-    H, KV, HD, page, n_pages, B = 4, 2, 128, 16, 4, 2
-    q3 = jnp.zeros((B, H, HD))
-    pool = jnp.zeros((n_pages, page, KV, HD))
+    H, KV, page, n_pages, B = 4, 2, 16, 4, 2
+    HD = 16 if kernel == "ragged_attend_tiny" else 128
     tables = jnp.zeros((B, 2), jnp.int32)
     lens = jnp.full((B,), 8, jnp.int32)
-    zero = jnp.zeros((B,), jnp.int32)
-    if kernel == "ragged_attend":
+    meta = jnp.zeros((4, 1), jnp.int32)
+    if kernel.startswith("ragged_attend") and kernel != "ragged_attend_latent":
         stored = jnp.zeros((2, n_pages, page, KV * HD))
 
         def f():
             return pa.ragged_attend(
-                jnp.zeros((8, H, HD)), stored, stored, tables,
-                jnp.zeros((4, 1), jnp.int32), 1, tq=8, interpret=True)
-    elif kernel == "paged_attend":
+                jnp.zeros((8, H, HD)), stored, stored, tables, meta, 1,
+                tq=8, interpret=True)
+    elif kernel == "ragged_attend_latent":
+        latent = jnp.zeros((2, n_pages, page, 256))
+
         def f():
-            return pa.paged_attend(q3, pool, pool, tables, lens, zero,
-                                   lens, interpret=True)
-    elif kernel == "paged_prefill_attend":
-        def f():
-            return pa.paged_prefill_attend(
-                jnp.zeros((B, 8, H, HD)), pool, pool, tables, lens,
-                interpret=True)
+            return pa.ragged_attend_latent(
+                jnp.zeros((8, H, 256)), latent, tables, meta, 1, tq=8,
+                v_lanes=128, scale=0.1, interpret=True)
     else:
         def f():
             return fa.flash_attend(
                 jnp.zeros((B, 128, H, HD)), jnp.zeros((B, 128, KV, HD)),
                 jnp.zeros((B, 128, KV, HD)),
                 jnp.zeros((B, 128), jnp.int32), lens, interpret=True)
-    assert pallas_names(jax.make_jaxpr(f)().jaxpr) == [kernel]
+    assert pallas_names(jax.make_jaxpr(f)().jaxpr) == [
+        kernel.removesuffix("_tiny")]
     names = op_names(jax.jit(f).lower())
-    if kernel in ("paged_attend", "paged_prefill_attend"):
-        # the split kernels re-lay their 4-D view of a layer's pool,
-        # under a scope of its own
+    if kernel == "ragged_attend_tiny":
+        # a test model's head_dim below the lane width: the one layer
+        # the call reads is padded, under a scope of its own
         assert has_scope(names, "kv_layout")
     elif kernel == "ragged_attend":
         # the serving kernel reads the pool as stored: at a production
