@@ -358,20 +358,25 @@ def kernels_phase(engine) -> tuple[Phase, dict]:
                            for _ in range(8)]).astype(np.int32)
         nb = 16
         lens = kv_len - np.arange(8) * 517        # ragged decode rows
+        chunk = np.stack([
+            np.full(nb, kv_len), kv_len - (nb - np.arange(nb)) * RAGGED_TQ,
+            np.full(nb, RAGGED_TQ), np.zeros(nb)]).astype(np.int32)
+        tile = engine._ragged_tile
         cases = {
-            "ragged_tq8": (RAGGED_TQ, np.stack([
-                np.full(nb, kv_len),
-                kv_len - (nb - np.arange(nb)) * RAGGED_TQ,
-                np.full(nb, RAGGED_TQ), np.zeros(nb)])),
+            "ragged_tq8": (RAGGED_TQ, chunk, {}),
+            # the chunk forward's call: the same blocks, one walk a tile
+            "ragged_tile": (RAGGED_TQ, chunk, dict(
+                tile=tile, tiles=jnp.asarray(pa.ragged_tiles(
+                    chunk, RAGGED_TQ, tile)))),
             "ragged_tq1": (1, np.stack([lens, lens - 1, np.ones(8),
-                                        np.arange(8)])),
+                                        np.arange(8)]), {}),
         }
-        for name, (tq, meta) in cases.items():
+        for name, (tq, meta, tiled) in cases.items():
             q = jnp.asarray(rng.standard_normal((meta.shape[1] * tq, H,
                                                  hd)), jnp.bfloat16)
             args = (q, kp, vp, jnp.asarray(tables),
                     jnp.asarray(meta.astype(np.int32)), 1)
-            out = pa.ragged_attend(*args, tq=tq,
+            out = pa.ragged_attend(*args, tq=tq, **tiled,
                                    sliding_window=cfg.sliding_window)
             with jax.default_matmul_precision("highest"):
                 ref = pa.ragged_attend_ref(

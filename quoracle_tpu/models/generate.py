@@ -1236,6 +1236,12 @@ class GenerateEngine:
             ragged_shard = (mesh, "tp")
         self._ragged_shard = ragged_shard
         self._ragged_ok = mesh is None or ragged_shard is not None
+        # query tokens of one row that share a walk of its pages in the
+        # chunk forward's attention (ops/paged_attention, the tile kernel);
+        # 0: a latent pool's kernel walks a block at a time, no tile table
+        from quoracle_tpu.ops.paged_attention import ragged_tile
+        self._ragged_tile = ragged_tile(
+            cfg.n_heads, cfg.head_dim, RAGGED_TQ) if cfg.plain else 0
 
         @functools.partial(jax.jit, static_argnames=())
         def step_paged_prefill(params, k_pool, v_pool, k_scale, v_scale,
@@ -1421,11 +1427,12 @@ class GenerateEngine:
         # engine's scale pools; None donates nothing): input and output
         # are one buffer, updated in place
         @functools.partial(jax.jit, donate_argnums=(1, 2, 3, 4),
-                           static_argnames=("tq",))
+                           static_argnames=("tq", "tile"))
         def step_paged_ragged(params, k_pool, v_pool, k_scale, v_scale,
                               tokens_flat,
                               positions_flat, row_tables, block_meta,
-                              flat_dst, last_idx, tq: int):
+                              tiles, flat_dst, last_idx, tq: int,
+                              tile: int):
             # UNIFIED mixed chunk forward (ISSUE 8): one ragged launch
             # per layer over the token-major flattened tick — prefill
             # suffixes, 1-token continuations, any mix of lengths — with
@@ -1437,19 +1444,21 @@ class GenerateEngine:
                     params, cfg, tokens_flat[None], positions_flat[None],
                     k_pool, v_pool, row_tables, block_meta, flat_dst,
                     tq=tq, shard=ragged_shard, k_scale=k_scale,
-                    v_scale=v_scale)
+                    v_scale=v_scale, tiles=tiles, tile=tile)
             last_h = hidden[0][last_idx]                  # [R, D]
             last = project_logits(params, cfg, last_h[:, None])[:, 0, :]
             return last, k_pool, v_pool, k_scale, v_scale, moe
 
         @functools.partial(jax.jit, donate_argnums=(1, 2, 3, 4),
-                           static_argnames=("tq", "kmax", "need_probs"))
+                           static_argnames=("tq", "tile", "kmax",
+                                            "need_probs"))
         def step_paged_ragged_verify(params, k_pool, v_pool, k_scale,
                                      v_scale, tokens_flat,
                                      positions_flat, row_tables,
-                                     block_meta, flat_dst, widx,
+                                     block_meta, tiles, flat_dst, widx,
                                      temperature, json_table, json_state,
-                                     tq: int, kmax: int, need_probs: bool):
+                                     tq: int, tile: int, kmax: int,
+                                     need_probs: bool):
             # Speculative VERIFY through the SAME unified kernel: the
             # teacher-forced chunk rides the ragged forward (KV scattered
             # to pages — committed prefixes resident for the next round,
@@ -1460,7 +1469,7 @@ class GenerateEngine:
                     params, cfg, tokens_flat[None], positions_flat[None],
                     k_pool, v_pool, row_tables, block_meta, flat_dst,
                     tq=tq, shard=ragged_shard, k_scale=k_scale,
-                    v_scale=v_scale)
+                    v_scale=v_scale, tiles=tiles, tile=tile)
             wh = hidden[0][widx]                          # [R, kmax, D]
             logits = project_logits(params, cfg, wh).astype(jnp.float32)
             R = widx.shape[0]
@@ -2717,6 +2726,9 @@ class GenerateEngine:
         collapse assertion. Returns (out, n_emitted, final_lens, jstate_f,
         vout, t_prefill, now) with all row-indexed arrays sized [R] whose
         first ``n`` slots are the batch rows in order."""
+        from quoracle_tpu.ops.paged_attention import (
+            ragged_tile_slots, ragged_tile_walk, ragged_tiles,
+        )
         tick_phase("pack")
         st = self.sessions
         page = st.page
@@ -2786,6 +2798,15 @@ class GenerateEngine:
                     0, s - 1)
             cur += nb * TQ
         self._pending.padded_tokens = TB
+        # the same blocks grouped for the attention kernel's walk: up to
+        # ``tile`` tokens of a row read its pages once between them
+        # (where the kernel walks block by block, the blocks are the walk)
+        tile = self._ragged_tile
+        tiles, walked = None, bmeta
+        if tile:
+            walked = ragged_tiles(bmeta, TQ, tile,
+                                  ragged_tile_slots(NB, R, TQ, tile))
+            tiles = jnp.asarray(walked)
 
         if verify is not None:
             self._pending.shape_key = ("ragged_verify", TB, R, maxp_p2,
@@ -2795,9 +2816,10 @@ class GenerateEngine:
                 self.params, st.k, st.v, st.k_scale, st.v_scale,
                 jnp.asarray(flat_tok),
                 jnp.asarray(flat_pos), jnp.asarray(r_tables),
-                jnp.asarray(bmeta), jnp.asarray(flat_dst),
+                jnp.asarray(bmeta), tiles, jnp.asarray(flat_dst),
                 jnp.asarray(widx), jnp.asarray(r_temp), json_table,
-                js_dev, tq=TQ, kmax=kmax, need_probs=need_probs)
+                js_dev, tq=TQ, tile=tile, kmax=kmax,
+                need_probs=need_probs)
             jax.block_until_ready(vids)  # phase fence: chunk forward done
             t_prefill = time.monotonic()
             vout = (np.asarray(vids),
@@ -2820,8 +2842,8 @@ class GenerateEngine:
                 self.params, st.k, st.v, st.k_scale, st.v_scale,
                 jnp.asarray(flat_tok),
                 jnp.asarray(flat_pos), jnp.asarray(r_tables),
-                jnp.asarray(bmeta),
-                jnp.asarray(flat_dst), jnp.asarray(last_idx), tq=TQ)
+                jnp.asarray(bmeta), tiles, jnp.asarray(flat_dst),
+                jnp.asarray(last_idx), tq=TQ, tile=tile)
         tick_phase("wait_prefill")
         jax.block_until_ready(last_logits)  # phase fence: prefill done
         t_prefill = time.monotonic()
@@ -2852,9 +2874,21 @@ class GenerateEngine:
         ctx = r_pool_lens[:n].astype(np.int64)
         fwd = final_lens[:n].astype(np.int64) - ctx     # decode forwards
         dec = int((fwd * ctx + fwd * (fwd + 1) // 2).sum())
+        # ... and what its programs did bring into VMEM: the pages each
+        # tile of the chunk forward walked, and a row's pages once a
+        # decode step (forward j of a row is a one-token tile that sees
+        # ctx + j tokens). streamed / reads is how many times a needed
+        # token was fetched.
+        steps = np.arange(1, int(fwd.max(initial=0)) + 1)
+        seen = ctx[:, None] + steps
+        streamed, n_tiles = ragged_tile_walk(np.concatenate(
+            [walked[:3], np.stack([seen, seen - 1, steps <= fwd[:, None]]
+                                  ).reshape(3, -1)], axis=1),
+            page, self.cfg.sliding_window)
         tick_note(attn_kv_reads=int(ctx.sum()) + dec,
                   attn_pairs=int((seg * (ctx - seg)
-                                  + seg * (seg + 1) // 2).sum()) + dec)
+                                  + seg * (seg + 1) // 2).sum()) + dec,
+                  attn_kv_streamed=streamed, attn_tiles=n_tiles)
         return out, n_emitted, final_lens, jstate_f, None, t_prefill, now
 
     def _json_table_device(self, enum_set: tuple):

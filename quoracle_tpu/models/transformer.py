@@ -684,6 +684,8 @@ def forward_hidden_ragged(
     shard: Optional[tuple] = None,   # (mesh, tp_axis)
     k_scale: Optional[jax.Array] = None,   # [L, n_pages, KV, page] f32
     v_scale: Optional[jax.Array] = None,   # per-(token, kv-head) scales
+    tiles: Optional[jax.Array] = None,     # [6, NT] int32: the blocks in
+    tile: int = 0,                         # tiles of <= ``tile`` tokens
 ) -> tuple:
     """UNIFIED ragged forward (ISSUE 8): one launch per layer over a
     token-major flattened batch of rows with arbitrary query lengths —
@@ -713,10 +715,15 @@ def forward_hidden_ragged(
     (models/quant.kv_quant), scatters int8 payloads into the pages and
     fp32 scales into the page-structured scale pools — carried and
     indexed by layer the same way — and the attention dequantizes inside
-    the kernel's streaming loop."""
+    the kernel's streaming loop.
+
+    With ``tiles`` (ops/paged_attention.ragged_tiles of ``block_meta``)
+    the dense kernel walks a row's pages once per tile of its queries
+    and not once per block: a schedule, not a layout — nothing else here
+    reads it."""
     if not cfg.plain:
-        assert shard is None and k_scale is None, \
-            "latent/expert models: no tp shards, no int8 pages"
+        assert shard is None and k_scale is None and tiles is None, \
+            "latent/expert models: no tp shards, no int8 pages, no tiles"
         return _forward_hidden_ragged_stacks(
             params, cfg, tokens, positions, k_pool, v_pool, row_tables,
             block_meta, flat_dst, tq, interpret)
@@ -761,7 +768,8 @@ def forward_hidden_ragged(
             attn = ragged_attend_auto(
                 q[0], kp, vp, row_tables, block_meta, layer, tq=tq,
                 sliding_window=cfg.sliding_window, interpret=interpret,
-                shard=shard, k_scale=ks, v_scale=vs)[None]  # [1,Tp,H,hd]
+                shard=shard, k_scale=ks, v_scale=vs, tiles=tiles,
+                tile=tile)[None]                            # [1,Tp,H,hd]
         x = _attn_out(x, attn.astype(x.dtype), p, cfg)
         x = _mlp(x, p, cfg)
         return (x, kp, vp, ks, vs), None

@@ -1,8 +1,10 @@
 """Ragged paged attention (PAPERS.md: Ragged Paged Attention,
 arxiv 2604.15464 — pattern only, the kernels are written here for the
 engine's page-pool layout): ``ragged_attend`` serves a token-major
-flattened batch of mixed prefill+decode rows in ONE launch per layer,
-``ragged_attend_latent`` the same contract over a latent (MLA) pool —
+flattened batch of mixed prefill+decode rows in ONE launch per layer —
+a program per 8-token block, or per TILE of up to 128 of a row's tokens
+that read its pages once between them (the chunk forward's call) —
+``ragged_attend_latent`` the same contract over a latent (MLA) pool:
 see the section comments below and ARCHITECTURE.md §10.
 
 The paged KV session cache (models/generate.py SessionStore) keeps every
@@ -31,6 +33,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -138,6 +141,32 @@ def ragged_attend_ref(
     return out
 
 
+def _attend_page(q, k, v, ks, vs, valid, m, l, acc):
+    """One page of one kv head's online softmax, shared by the block and
+    the tile kernel: ``q`` [rows, hd] scaled float32 queries (query-major,
+    the head's G query heads a token), ``valid`` [rows, page]; ``k``/``v``
+    give the page's [page, hd] float32 blocks and ``ks``/``vs`` an int8
+    page's [1, page] scales (else None), each a function called where its
+    value is used: one made early would live across the softmax. Returns
+    the updated (m, l, acc)."""
+    scores = jax.lax.dot_general(                        # [rows, page]
+        q, k(), (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    if ks is not None:
+        scores = scores * ks()                           # dequant K
+    scores = jnp.where(valid, scores, NEG_INF)
+    m_new = jnp.maximum(m, jnp.max(scores, axis=1, keepdims=True))
+    p = jnp.where(valid, jnp.exp(scores - m_new), 0.0)
+    corr = jnp.exp(m - m_new)
+    l_new = l * corr + jnp.sum(p, axis=1, keepdims=True)
+    if vs is not None:
+        p = p * vs()                                     # dequant V
+    pv = jax.lax.dot_general(                            # [rows, hd]
+        p, v(), (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    return m_new, l_new, acc * corr + pv
+
+
 def _ragged_kernel(tables_ref, meta_ref, layer_ref, q_ref, k_hbm, v_hbm,
                    *refs, page: int, n_kv: int, hd: int, tq: int,
                    scale: float, window: int, quant: bool):
@@ -227,26 +256,13 @@ def _ragged_kernel(tables_ref, meta_ref, layer_ref, q_ref, k_hbm, v_hbm,
             valid = valid & (qpos - s_idx < window)
         out = []
         for kv in range(n_kv):
-            m, l, acc = carry[kv]
-            scores = jax.lax.dot_general(                # [tq·G, page]
+            lanes = slice(kv * hd, (kv + 1) * hd)
+            out.append(_attend_page(
                 q[:, kv * G:(kv + 1) * G].reshape(tq * G, hd),
-                k_blk[:, kv * hd:(kv + 1) * hd],
-                (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            if quant:
-                scores = scores * ks_blk[kv:kv + 1, :]   # dequant K
-            scores = jnp.where(valid, scores, NEG_INF)
-            m_new = jnp.maximum(m, jnp.max(scores, axis=1, keepdims=True))
-            p = jnp.where(valid, jnp.exp(scores - m_new), 0.0)
-            corr = jnp.exp(m - m_new)
-            l_new = l * corr + jnp.sum(p, axis=1, keepdims=True)
-            if quant:
-                p = p * vs_blk[kv:kv + 1, :]             # dequant V
-            pv = jax.lax.dot_general(                    # [tq·G, hd]
-                p, v_blk[:, kv * hd:(kv + 1) * hd],
-                (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            out.append((m_new, l_new, acc * corr + pv))
+                lambda: k_blk[:, lanes], lambda: v_blk[:, lanes],
+                (lambda: ks_blk[kv:kv + 1, :]) if quant else None,
+                (lambda: vs_blk[kv:kv + 1, :]) if quant else None,
+                valid, *carry[kv]))
         return tuple(out)
 
     init = tuple((jnp.full((tq * G, 1), NEG_INF, jnp.float32),
@@ -261,7 +277,7 @@ def _ragged_kernel(tables_ref, meta_ref, layer_ref, q_ref, k_hbm, v_hbm,
 
 
 @functools.partial(jax.jit, static_argnames=("tq", "sliding_window",
-                                             "interpret"))
+                                             "interpret", "tile"))
 def ragged_attend(
     q: jax.Array,            # [NB·tq, H, hd] token-major flattened queries
     k_pool: jax.Array,       # [L, n_pages, page, KV·hd] — the stored pool
@@ -274,10 +290,16 @@ def ragged_attend(
     interpret: bool = False,
     k_scale: Optional[jax.Array] = None,   # [L, n_pages, KV, page] f32
     v_scale: Optional[jax.Array] = None,
+    tiles: Optional[jax.Array] = None,     # [6, NT] int32 (ragged_tiles)
+    tile: int = 0,                         # tokens a tile holds at most
 ) -> jax.Array:
     """Pallas unified ragged attention (same contract as ragged_attend_ref;
     tests/test_ragged_attention.py asserts numerical agreement). Grid is
-    (NB,) — sized by the tick's real tokens / tq, never by batch × max.
+    (NB,) — sized by the tick's real tokens / tq, never by batch × max —
+    or, with the block table grouped into ``tiles`` of up to ``tile``
+    tokens, (NT,): one walk of a row's pages per tile (the tile kernel,
+    section above) where a block a program walks them per tq queries (the
+    decode program's call, tq = 1).
     The pools are passed whole, as stored, and stay in HBM: the kernel
     indexes ``layer`` itself, so nothing of a pool's size is sliced,
     reshaped or copied on the way in. With ``k_scale``/``v_scale`` the
@@ -306,15 +328,44 @@ def ragged_attend(
                              ).reshape(1, n_pages, page, KV * hd_p)
                      for p in one[:2]] + one[2:]
         layer = jnp.zeros((), jnp.int32)
-    qb = q.reshape(NB, tq, H, hd_p)
-    kernel = functools.partial(
-        _ragged_kernel, page=page, n_kv=KV, hd=hd_p, tq=tq,
-        scale=hd ** -0.5, quant=quant,
-        window=-1 if sliding_window is None else int(sliding_window))
     scratch = [pltpu.VMEM((2, page, KV * hd_p), k_pool.dtype),
                pltpu.VMEM((2, page, KV * hd_p), v_pool.dtype)]
     if quant:
         scratch += [pltpu.VMEM((2, KV, page), jnp.float32)] * 2
+    window = -1 if sliding_window is None else int(sliding_window)
+    if tiles is not None:
+        state = (KV, tile * (H // KV))   # kv-head-major, query-major rows
+        out = pl.pallas_call(
+            functools.partial(
+                _ragged_tile_kernel, page=page, n_kv=KV, hd=hd_p, tq=tq,
+                tile=tile, scale=hd ** -0.5, quant=quant, window=window),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=3,             # tables, tiles, layer
+                grid=(tiles.shape[1],),
+                in_specs=[pl.BlockSpec(memory_space=pl.ANY)  # q, the pools
+                          for _ in range(1 + len(pools))],
+                out_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+                scratch_shapes=[
+                    pltpu.VMEM((tile, H, hd_p), q.dtype),
+                    pltpu.VMEM((tile, H, hd_p), jnp.float32),
+                    pltpu.VMEM(state + (hd_p,), jnp.float32),
+                    pltpu.VMEM(state + (1,), jnp.float32),
+                    pltpu.VMEM(state + (1,), jnp.float32),
+                    pltpu.VMEM(state + (hd_p,), jnp.float32),
+                    *scratch,
+                    pltpu.SemaphoreType.DMA((2, len(pools))),
+                    pltpu.SemaphoreType.DMA((1,))],
+            ),
+            out_shape=[jax.ShapeDtypeStruct((Tp, H, hd_p), jnp.float32)],
+            interpret=interpret,
+            name="ragged_attend",                  # pinned, as below
+        )(row_tables.astype(jnp.int32), tiles.astype(jnp.int32),
+          layer.reshape(1), q, *pools)[0]
+        return out[..., :hd]
+    qb = q.reshape(NB, tq, H, hd_p)
+    kernel = functools.partial(
+        _ragged_kernel, page=page, n_kv=KV, hd=hd_p, tq=tq,
+        scale=hd ** -0.5, quant=quant, window=window)
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -343,6 +394,273 @@ def ragged_attend(
     return out.reshape(NB * tq, H, hd_p)[..., :hd]
 
 
+# ---------------------------------------------------------------------------
+# The query TILE (ISSUE 30): a row's pages are read once per tile
+# ---------------------------------------------------------------------------
+#
+# One program per tq-token block re-reads the row's whole visible context
+# for every 8 queries: a cold prompt of n tokens streams n² / 16 resident
+# tokens a layer and multiplies with 8·G-row left operands. The TILE kernel
+# keeps the flat layout (segments padded to tq, the same [Tp, H, hd] arrays,
+# the same program keys) and changes only which queries share a walk: a
+# grid program serves a tile of up to ``tile`` consecutive query tokens of
+# ONE row, brings each visible page into VMEM once, and attends it for all
+# of the tile's queries at once (up to tile·G score rows a kv head), their
+# online-softmax state in VMEM scratch between pages. A tile starts
+# wherever its row's segment puts it (a multiple of tq, not of ``tile``),
+# so q and the output stay in HBM and move by the tile's own start, tq
+# tokens a copy.
+#
+# The host derives the tile table from the block table (``ragged_tiles``):
+#
+#   tiles[:, i] = (kv_len, qpos0, nq, row, tok0, span)
+#     kv_len, qpos0, row   as in block_meta, for the tile's first query;
+#     nq      valid queries of the tile (1..tile; 0 = writes zeros only);
+#     tok0    flat index of the tile's first token;
+#     span    flat tokens the tile owns and writes (nq rounded up to tq;
+#             for nq = 0 a run of padding tokens, written as zeros).
+#
+# A tile's cost follows its nq, which the kernel reads: it attends at the
+# first HEIGHT of tq, 4·tq, … , ``tile`` tokens that holds its queries —
+# a decode row of a mixed tick at tq, a block's cost; a short tool result
+# at 32; a prompt's tiles at ``tile``. Same operands, same page order,
+# same float32 online softmax per query row as the block kernel.
+#
+# The decode program keeps the block kernel at tq = 1: one token a row has
+# no walk to share, and what the tile pays for sharing — q and the output
+# moved by hand, the softmax state through VMEM scratch every page — read
+# 10% (Qwen's widths) to 19% (Mistral's) more a call on the chip at
+# tile = tq = 1, bit-equal outputs (PERF.md §6, PR 30).
+
+RAGGED_TILE = 128            # query tokens a grid program serves at most
+_TILE_VMEM = 12 << 20        # the tile's scratch, of 16 MiB scoped VMEM
+
+
+def ragged_tile(n_heads: int, head_dim: int, tq: int) -> int:
+    """Tokens of a query tile for this head geometry: ``RAGGED_TILE``,
+    halved until the tile's scratch fits ``_TILE_VMEM`` — per token its
+    queries as they arrive (2 bytes), scaled to float32, the accumulator
+    and the output (4 each) at H·hd, and two softmax columns that pad to
+    128 lanes."""
+    hd_p = -(-head_dim // 128) * 128
+    per_token = n_heads * (hd_p * 14 + 2 * 128 * 4)
+    tile = RAGGED_TILE
+    while tile > tq and tile * per_token > _TILE_VMEM:
+        tile //= 2
+    return max(tile, tq)
+
+
+def ragged_tile_slots(n_blocks: int, rows: int, tq: int, tile: int) -> int:
+    """Static length of the tile table for a flat layout of ``n_blocks``
+    tq-token blocks and ``rows`` row slots: each row's last tile may be
+    short, and so may the last padding tile."""
+    return min(n_blocks, n_blocks // (tile // tq) + rows + 1)
+
+
+def ragged_tiles(block_meta, tq: int, tile: int, slots: int = 0):
+    """The tile table [6, max(slots, tiles)] (numpy, host side) of a block
+    table [4, NB]: consecutive live blocks of one row group into tiles of
+    ``tile`` tokens, consecutive inert blocks into padding tiles. Unused
+    slots are all zero: a program that does nothing."""
+    kv_len, qpos0, nq, row = np.asarray(block_meta)
+    nb = nq.shape[0]
+    idx = np.arange(nb)
+    run = np.where(nq > 0, row, -1)            # inert blocks: one run
+    new = np.r_[True, run[1:] != run[:-1]]
+    in_run = idx - np.maximum.accumulate(np.where(new, idx, 0))
+    first = np.flatnonzero(in_run % (tile // tq) == 0)
+    n_blk = np.diff(np.r_[first, nb])
+    tiles = np.zeros((6, max(slots, first.size)), np.int32)
+    tiles[:, :first.size] = (kv_len[first], qpos0[first],
+                             np.add.reduceat(nq, first), row[first],
+                             first * tq, n_blk * tq)
+    return tiles
+
+
+def ragged_tile_walk(tiles, page: int, sliding_window=None) -> tuple:
+    """(resident tokens the kernel's programs bring into VMEM, programs
+    that walk pages) for a tile table: Σ over live tiles of visible pages
+    × page. With the block table as its own tile table (``tile`` = tq) it
+    prices the walk of one program per block."""
+    kv_len, qpos0, nq = np.asarray(tiles, np.int64)[:3]
+    live = nq > 0
+    hi = -(-np.minimum(kv_len, qpos0 + nq) // page)
+    lo = 0 if sliding_window is None else \
+        np.maximum(qpos0 + 1 - sliding_window, 0) // page
+    return (int((np.maximum(hi - lo, 0) * live).sum()) * page,
+            int(live.sum()))
+
+
+def _each(n, fn) -> None:
+    """``fn(i)`` for 0 <= i < n: unrolled where n is a Python int, else a
+    loop on the device."""
+    if isinstance(n, int):
+        for i in range(n):
+            fn(i)
+    else:
+        jax.lax.fori_loop(0, n, lambda i, c: (fn(i), c)[1], 0)
+
+
+def _ragged_tile_kernel(tables_ref, tiles_ref, layer_ref, q_hbm, k_hbm,
+                        v_hbm, *refs, page: int, n_kv: int, hd: int,
+                        tq: int, tile: int, scale: float, window: int,
+                        quant: bool):
+    """One TILE of up to ``tile`` query tokens of one row (see the section
+    comment): q and the output stay in HBM and move tq tokens a copy from
+    and to the tile's own start; the row's visible pages stream through
+    VMEM double-buffered as in the block kernel, ONCE for the whole tile,
+    and each updates the tile's (m, l, acc) in VMEM scratch.
+    Scalar-prefetched: tables_ref [R, maxp], tiles_ref [6, NT] (kv_len,
+    qpos0, nq, row, tok0, span), layer_ref [1].
+
+    Scratch, all [.., tile, ..] tall: q_scr the queries as they arrive,
+    qf_scr the same scaled to float32 and laid kv-head-major, query-major
+    rows ([n_kv, tile·G, hd]: a kv head's left operand is a row slice),
+    m/l/acc_scr the softmax state in that layout, o_scr the normalized
+    output token-major for the copies out."""
+    if quant:
+        (ks_hbm, vs_hbm, out_hbm, q_scr, o_scr, qf_scr, m_scr, l_scr,
+         acc_scr, k_scr, v_scr, ks_scr, vs_scr, sems, io_sem) = refs
+        streams = ((k_hbm, k_scr), (v_hbm, v_scr),
+                   (ks_hbm, ks_scr), (vs_hbm, vs_scr))
+    else:
+        (out_hbm, q_scr, o_scr, qf_scr, m_scr, l_scr, acc_scr, k_scr,
+         v_scr, sems, io_sem) = refs
+        streams = ((k_hbm, k_scr), (v_hbm, v_scr))
+    i = pl.program_id(0)
+    kv_len, qpos0, nq, row, tok0, span = (tiles_ref[j, i] for j in range(6))
+    layer = layer_ref[0]
+    G = q_scr.shape[1] // n_kv
+
+    # q and the output move one tq-token granule a copy: granule g of
+    # the tile's span of the flat array <-> granule g of the scratch
+    def q_in(g):
+        return pltpu.make_async_copy(q_hbm.at[pl.ds(tok0 + g * tq, tq)],
+                                     q_scr.at[pl.ds(g * tq, tq)],
+                                     io_sem.at[0])
+
+    def o_out(g, src=None):
+        return pltpu.make_async_copy(
+            o_scr.at[pl.ds((g if src is None else src) * tq, tq)],
+            out_hbm.at[pl.ds(tok0 + g * tq, tq)], io_sem.at[0])
+
+    def start(n, copy):
+        """Start ``n`` granule copies; returns the wait for them."""
+        _each(n, lambda g: copy(g).start())
+        return lambda: _each(n, lambda g: copy(g).wait())
+
+    @pl.when((nq == 0) & (span > 0))
+    def _():
+        # padding of the flat layout: zeros, as an inert block writes
+        o_scr[0:tq] = jnp.zeros((tq,) + o_scr.shape[1:], o_scr.dtype)
+        start(span // tq, lambda g: o_out(g, src=0))()
+
+    # last visible key + 1: nothing past the tile's last query is visible;
+    # first page: what the tile's FIRST query's window still reaches
+    kv_hi = jnp.minimum(kv_len, qpos0 + nq)
+    if window >= 0:
+        p_lo = jnp.maximum(qpos0 + 1 - window, 0) // page
+    else:
+        p_lo = jnp.int32(0)
+    n = jnp.maximum((kv_hi + page - 1) // page - p_lo, 0)
+
+    def dmas(j, slot):
+        pid = tables_ref[row, p_lo + j]
+        return [pltpu.make_async_copy(hbm.at[layer, pid], scr.at[slot],
+                                      sems.at[slot, s])
+                for s, (hbm, scr) in enumerate(streams)]
+
+    def attend(height: int) -> None:
+        """The tile at ``height`` tokens (>= nq): M = height·G score rows
+        a kv head, rows 0..M of the state scratch."""
+        M = height * G
+        n_g = 1 if height == tq else span // tq          # granules to move
+        wait_q = start(n_g, q_in)
+
+        @pl.when(n > 0)
+        def _():
+            for d in dmas(0, 0):
+                d.start()
+
+        wait_q()
+
+        def granule_rows(g):
+            r0 = g * (tq * G)
+            return pl.ds(r0 if isinstance(g, int)
+                         else pl.multiple_of(r0, tq * G), tq * G)
+
+        def load_q(g):
+            qg = q_scr[pl.ds(g * tq, tq)].astype(jnp.float32) * scale
+            for kv in range(n_kv):
+                qf_scr[kv, granule_rows(g), :] = \
+                    qg[:, kv * G:(kv + 1) * G].reshape(tq * G, hd)
+
+        _each(n_g, load_q)
+        for kv in range(n_kv):
+            m_scr[kv, 0:M, :] = jnp.full((M, 1), NEG_INF, jnp.float32)
+            l_scr[kv, 0:M, :] = jnp.zeros((M, 1), jnp.float32)
+            acc_scr[kv, 0:M, :] = jnp.zeros((M, hd), jnp.float32)
+
+        # per-score-row query index → buffer position and validity, as in
+        # the block kernel; rows past the last granule hold stale queries
+        # and are masked like any row past nq
+        t_of_row = jax.lax.broadcasted_iota(jnp.int32, (M, 1), 0) // G
+        qpos = qpos0 + t_of_row                          # [M, 1]
+        q_ok = t_of_row < nq
+
+        def walk(j):
+            slot = jax.lax.rem(j, 2)
+
+            @pl.when(j + 1 < n)
+            def _():
+                for d in dmas(j + 1, jax.lax.rem(j + 1, 2)):
+                    d.start()
+
+            for d in dmas(j, slot):
+                d.wait()
+            s_idx = (p_lo + j) * page + jax.lax.broadcasted_iota(
+                jnp.int32, (1, page), 1)                 # [1, page]
+            valid = (s_idx < kv_len) & (s_idx <= qpos) & q_ok
+            if window >= 0:
+                valid = valid & (qpos - s_idx < window)
+            for kv in range(n_kv):
+                lanes = slice(kv * hd, (kv + 1) * hd)
+                m, l, acc = _attend_page(
+                    qf_scr[kv, 0:M, :],
+                    lambda: k_scr[slot, :, lanes].astype(jnp.float32),
+                    lambda: v_scr[slot, :, lanes].astype(jnp.float32),
+                    (lambda: ks_scr[slot, kv:kv + 1, :]) if quant else None,
+                    (lambda: vs_scr[slot, kv:kv + 1, :]) if quant else None,
+                    valid, m_scr[kv, 0:M, :], l_scr[kv, 0:M, :],
+                    acc_scr[kv, 0:M, :])
+                m_scr[kv, 0:M, :] = m
+                l_scr[kv, 0:M, :] = l
+                acc_scr[kv, 0:M, :] = acc
+
+        _each(n, walk)
+
+        def store(g):
+            rows = granule_rows(g)
+            for kv in range(n_kv):
+                l = l_scr[kv, rows, :]
+                norm = acc_scr[kv, rows, :] / jnp.where(l > 0, l, 1.0)
+                o_scr[pl.ds(g * tq, tq), kv * G:(kv + 1) * G] = \
+                    norm.reshape(tq, G, hd)
+
+        _each(n_g, store)
+        start(n_g, o_out)()
+
+    heights = [tq]
+    while heights[-1] * 4 < tile:
+        heights.append(heights[-1] * 4)
+    if heights[-1] < tile:
+        heights.append(tile)
+    for below, height in zip([0] + heights, heights):
+        @pl.when((nq > below) & (nq <= height))
+        def _(height=height):
+            attend(height)
+
+
 def ragged_attend_auto(
     q: jax.Array,            # [NB·tq, H, hd]
     k_pool: jax.Array,       # [L, n_pages, page, KV·hd] — the stored pool
@@ -356,6 +674,8 @@ def ragged_attend_auto(
     shard: Optional[tuple] = None,   # (mesh, tp_axis)
     k_scale: Optional[jax.Array] = None,   # [L, n_pages, KV, page] f32 —
     v_scale: Optional[jax.Array] = None,   # int8 pools (ISSUE 13)
+    tiles: Optional[jax.Array] = None,     # [6, NT]: the tile kernel's
+    tile: int = 0,                         # schedule (ragged_tiles)
 ) -> jax.Array:
     """Unified ragged attention dispatcher: Pallas kernel on TPU (or under
     ``interpret``), XLA gather reference elsewhere (CPU tier-1 — same
@@ -377,12 +697,17 @@ def ragged_attend_auto(
         if k_scale is not None:
             ins += [P(None, None, tp_ax, None)] * 2   # [L, n_pages, KV, page]
             args += [k_scale, v_scale]
+        if tiles is not None:
+            ins.append(P(None, None))
+            args.append(tiles)
 
-        def inner(qq, kp, vp, rt, bm, ly, ks=None, vs=None):
+        def inner(qq, kp, vp, rt, bm, ly, *rest):
+            ks, vs = rest[:2] if k_scale is not None else (None, None)
             return ragged_attend_auto(
                 qq, kp, vp, rt, bm, ly, tq=tq,
                 sliding_window=sliding_window, interpret=interpret,
-                k_scale=ks, v_scale=vs)
+                k_scale=ks, v_scale=vs, tile=tile,
+                tiles=rest[-1] if tiles is not None else None)
         # check_vma off: a pallas_call's outputs carry no varying-axes
         # annotation for the checker to verify
         return jax.shard_map(inner, mesh=mesh, in_specs=tuple(ins),
@@ -391,7 +716,8 @@ def ragged_attend_auto(
         return ragged_attend(q, k_pool, v_pool, row_tables, block_meta,
                              layer, tq=tq, sliding_window=sliding_window,
                              interpret=bool(interpret),
-                             k_scale=k_scale, v_scale=v_scale)
+                             k_scale=k_scale, v_scale=v_scale,
+                             tiles=tiles, tile=tile)
     return ragged_attend_ref(q, k_pool, v_pool, row_tables, block_meta,
                              layer, tq=tq, sliding_window=sliding_window,
                              k_scale=k_scale, v_scale=v_scale)

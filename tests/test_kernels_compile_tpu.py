@@ -81,6 +81,65 @@ def test_ragged_kernel_compiles(on_v5e, tq, nb, rows, quant):
     compiles(fn, *args)
 
 
+# the two dense configurations the benchmark serves: (H, KV, window)
+DENSE = {"mistral-h32-kv8": (32, 8, WINDOW), "qwen-h16-kv2": (16, 2, None)}
+
+
+def _tile_args(S, h, kv, tb, width, rows, quant):
+    """Shapes of the chunk forward's attention call at a warm key
+    (token budget ``tb``, table ``width``, ``rows`` slots): the tile
+    kernel's, with the tile table the engine would hand it."""
+    tq = 8
+    tile = pa.ragged_tile(h, HD, tq)
+    pool = S((LAYERS, N_PAGES, PAGE, kv * HD),
+             jnp.int8 if quant else jnp.bfloat16)
+    args = [S((tb, h, HD), jnp.bfloat16), pool, pool,
+            S((rows, width), jnp.int32), S((4, tb // tq), jnp.int32),
+            S((), jnp.int32),
+            S((6, pa.ragged_tile_slots(tb // tq, rows, tq, tile)),
+              jnp.int32)]
+    if quant:
+        args += [S((LAYERS, N_PAGES, kv, PAGE), jnp.float32)] * 2
+    return tq, tile, args
+
+
+@pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8"])
+@pytest.mark.parametrize("tb,width", [(16384, 64), (32768, 128)],
+                         ids=["16k-w64", "32k-w128"])
+@pytest.mark.parametrize("geometry", DENSE.values(), ids=DENSE)
+def test_ragged_tile_kernel_compiles(on_v5e, geometry, tb, width, quant):
+    """The tile kernel (ISSUE 30) at both dense configurations' widths
+    and the largest warm keys, 8 row slots: the tile table in SMEM beside
+    the page tables, and a 128-token tile's queries, softmax state and
+    output in scoped VMEM beside the double-buffered pages — what Mosaic
+    refuses, it refuses here."""
+    h, kv, window = geometry
+    tq, tile, args = _tile_args(on_v5e, h, kv, tb, width, 8, quant)
+    assert tile == pa.RAGGED_TILE
+
+    def fn(q, k, v, tables, meta, layer, tiles, ks=None, vs=None):
+        return pa.ragged_attend(q, k, v, tables, meta, layer, tq=tq,
+                                sliding_window=window, k_scale=ks,
+                                v_scale=vs, tiles=tiles, tile=tile)
+    compiles(fn, *args)
+
+
+def test_compiled_ragged_tile_kernel_carries_the_pinned_name(on_v5e):
+    """The tile kernel is ``%ragged_attend.<n>`` in a trace too: the
+    benchmark's ``^%ragged_attend`` patterns read it as they read the
+    block kernel."""
+    h, kv, window = DENSE["mistral-h32-kv8"]
+    tq, tile, args = _tile_args(on_v5e, h, kv, 4096, 32, 8, False)
+    text = jax.jit(functools.partial(
+        pa.ragged_attend, tq=tq, sliding_window=window, tile=tile)).lower(
+        *args[:6], tiles=args[6]).compile().as_text()
+    call = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    assert len(call) == 1
+    # (alone in its program the call is the ROOT instruction)
+    assert re.match(r"\s*(ROOT )?%ragged_attend(\.\d+)? = ", call[0])
+    assert "/ragged_attend/pallas_call" in call[0]
+
+
 def test_compiled_ragged_kernel_carries_its_pinned_name(on_v5e):
     """A profiler trace shows the kernel as ``%ragged_attend.<n>``, under
     the ``pallas_call``'s explicit ``name`` (ISSUE 24): the benchmark's
@@ -282,6 +341,41 @@ def test_decode_program_leaves_the_pool_where_it_is(on_v5e, monkeypatch,
     pool_bytes = 2 * cfg.n_layers * layer_elems * 2
     assert mem.alias_size_in_bytes >= pool_bytes
     assert mem.temp_size_in_bytes < layer_elems * 2
+
+
+def test_prefill_program_holds_the_tile_kernel(on_v5e, monkeypatch):
+    """``step_paged_ragged`` as the engine serves it (tile table and
+    all, pools donated), compiled for the v5e: its one kernel a layer
+    body is ``%ragged_attend`` fed the tile table, [6, slots] int32, and
+    both pools are donated into their outputs."""
+    from quoracle_tpu.models.generate import RAGGED_TQ, GenerateEngine
+    from quoracle_tpu.models.tokenizer import ByteTokenizer
+    from quoracle_tpu.models.transformer import init_params
+    monkeypatch.setattr(pa, "_on_tpu", lambda: True)
+    cfg = _narrow("narrow-kv2-prefill", 2)
+    S = on_v5e
+    params = jax.tree.map(
+        lambda x: S(x.shape, x.dtype),
+        jax.eval_shape(lambda k: init_params(cfg, k), jax.random.PRNGKey(0)))
+    eng = GenerateEngine(cfg, params, ByteTokenizer(), max_seq=512)
+    st = eng.sessions
+    lanes = cfg.n_kv_heads * cfg.head_dim
+    pool = S((cfg.n_layers, st.n_pages, st.page, lanes), eng.pool_dtype)
+    tb, rows, i32 = 2048, 8, jnp.int32
+    slots = pa.ragged_tile_slots(tb // RAGGED_TQ, rows, RAGGED_TQ,
+                                 eng._ragged_tile)
+    assert (eng._ragged_tile, slots) == (128, 2048 // 128 + 8 + 1)
+    compiled = eng._step_paged_ragged.lower(
+        params, pool, pool, None, None, S((tb,), i32), S((tb,), i32),
+        S((rows, 4), i32), S((4, tb // RAGGED_TQ), i32), S((6, slots), i32),
+        S((tb,), i32), S((rows,), i32), tq=RAGGED_TQ,
+        tile=eng._ragged_tile).compile()
+    hlo = compiled.as_text()
+    call = [ln for ln in hlo.splitlines() if "tpu_custom_call" in ln]
+    assert len(call) == 1 and "%ragged_attend" in call[0]
+    assert f"s32[6,{slots}]" in hlo
+    assert compiled.memory_analysis().alias_size_in_bytes \
+        >= 2 * cfg.n_layers * st.n_pages * st.page * lanes * 2
 
 
 @pytest.mark.slow
@@ -496,15 +590,22 @@ def tp_case(eight_devices):
     }
 
 
-def test_ragged_tp_wrapper_runs_the_kernel_under_shard_map(tp_case):
+@pytest.mark.parametrize("tile", [0, 16], ids=["blocks", "tiles"])
+def test_ragged_tp_wrapper_runs_the_kernel_under_shard_map(tp_case, tile):
+    """Per tp shard under ``shard_map``: one program a block, and (the
+    chunk forward's call) a tile, whose table replicates like the block
+    table beside it."""
     c = tp_case
-    q = c["arr"](16, c["h"], c["hd"])
-    meta = jnp.asarray([[20, 9], [12, 8], [8, 1], [0, 1]], jnp.int32)
+    q = c["arr"](32, c["h"], c["hd"])
+    meta = np.asarray([[28, 28, 28, 9], [4, 12, 20, 8], [8, 8, 8, 1],
+                       [0, 0, 0, 1]], np.int32)
     args = (q, c["stored"](c["kp"]), c["stored"](c["vp"]), c["tables"],
-            meta, jnp.asarray(1, jnp.int32))
+            jnp.asarray(meta), jnp.asarray(1, jnp.int32))
+    tiles = jnp.asarray(pa.ragged_tiles(meta, 8, tile)) if tile else None
     ref = pa.ragged_attend_ref(*args, tq=8)
-    out = jax.jit(lambda *a: pa.ragged_attend_auto(
-        *a, tq=8, interpret=True, shard=(c["mesh"], "tp")))(*args)
+    out = jax.jit(lambda *a, tiles: pa.ragged_attend_auto(
+        *a, tq=8, interpret=True, shard=(c["mesh"], "tp"), tile=tile,
+        tiles=tiles))(*args, tiles=tiles)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
 
 

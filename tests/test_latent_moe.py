@@ -500,6 +500,31 @@ def test_the_gather_fallback_refuses_and_leaks_no_page(engine):
     engine.drop_session("g")
 
 
+def test_a_latent_tick_counts_the_walk_its_kernel_made(engine):
+    """`attn_kv_streamed` / `attn_tiles` on a latent engine: its kernel
+    walks a row's pages once per 8-token BLOCK, so the tick is priced from
+    the block table (the dense engines': from their 128-token tiles), and
+    the engine builds and ships no tile table."""
+    from quoracle_tpu.infra.telemetry import tick_close, tick_open
+    from quoracle_tpu.models.generate import RAGGED_TQ
+    assert engine._ragged_tile == 0
+    rng = np.random.default_rng(11)
+    rows = [[int(t) for t in rng.integers(3, 512, n)] for n in (150, 5)]
+    tick_open("m")
+    try:
+        res = engine.generate(rows, temperature=0.0, max_new_tokens=5)
+    finally:
+        args = tick_close().args
+    pages = lambda n: -(-n // PAGE)         # noqa: E731
+    chunk = [pages(min(len(r), (b + 1) * RAGGED_TQ)) for r in rows
+             for b in range(-(-len(r) // RAGGED_TQ))]
+    dec = [pages(len(r) + j) for r, out in zip(rows, res)
+           for j in range(1, len(out.token_ids))]
+    assert args["attn_tiles"] == len(chunk) + len(dec)
+    assert args["attn_kv_streamed"] == PAGE * (sum(chunk) + sum(dec))
+    assert args["attn_kv_streamed"] > 3 * args["attn_kv_reads"] > 0
+
+
 # -- the dense models keep their programs -----------------------------------
 
 def test_the_dense_decode_program_is_the_parents():

@@ -60,11 +60,14 @@ def _gather(eng):
 # --- kernel vs dense oracle -------------------------------------------------
 
 
-def _random_case(rng, rows, H, KV, hd, page, n_pages, window):
+def _random_case(rng, rows, H, KV, hd, page, n_pages, window, tile=0,
+                 quant=False):
     """Build a flat layout from (prefix, q_len) rows and run kernel
-    (interpret) vs the dense gather oracle."""
+    (interpret) vs the dense gather oracle: one program a block, or with
+    ``tile`` one a tile of that many tokens (``ragged_tiles`` of the same
+    block table, plus two unused slots)."""
     from quoracle_tpu.ops.paged_attention import (
-        ragged_attend, ragged_attend_ref,
+        ragged_attend, ragged_attend_ref, ragged_tiles,
     )
     tq = RAGGED_TQ
     maxp = max(-(-(pre + q) // page) for pre, q in rows if q > 0)
@@ -75,10 +78,17 @@ def _random_case(rng, rows, H, KV, hd, page, n_pages, window):
     # kernel is handed all of them and reads layer 1 of 3 — its
     # neighbours hold other numbers, so a wrong layer cannot agree
     layer = 1
-    kp = jnp.asarray(rng.standard_normal((3, n_pages, page, KV * hd)),
-                     jnp.float32)
-    vp = jnp.asarray(rng.standard_normal((3, n_pages, page, KV * hd)),
-                     jnp.float32)
+    if quant:
+        kp, vp = (jnp.asarray(rng.integers(
+            -127, 128, (3, n_pages, page, KV * hd)), jnp.int8)
+            for _ in range(2))
+        extra = dict(zip(("k_scale", "v_scale"), (jnp.asarray(rng.uniform(
+            0.002, 0.02, (3, n_pages, KV, page)), jnp.float32)
+            for _ in range(2))))
+    else:
+        kp, vp = (jnp.asarray(rng.standard_normal(
+            (3, n_pages, page, KV * hd)), jnp.float32) for _ in range(2))
+        extra = {}
     rtab = np.zeros((len(rows), maxp), np.int32)
     bmeta = np.zeros((4, NB), np.int32)     # kv_len, qpos0, nq, row
     next_page = 1
@@ -94,10 +104,15 @@ def _random_case(rng, rows, H, KV, hd, page, n_pages, window):
         cur_blk += nb
     ref = ragged_attend_ref(q, kp, vp, jnp.asarray(rtab),
                             jnp.asarray(bmeta), layer, tq=tq,
-                            sliding_window=window)
+                            sliding_window=window, **extra)
+    if tile:
+        tiles = ragged_tiles(bmeta, tq, tile)
+        extra.update(tile=tile, tiles=jnp.asarray(np.concatenate(
+            [tiles, np.zeros((6, 2), np.int32)], axis=1)))
     krn = ragged_attend(q, kp, vp, jnp.asarray(rtab), jnp.asarray(bmeta),
                         layer, tq=tq, sliding_window=window,
-                        interpret=jax.devices()[0].platform != "tpu")
+                        interpret=jax.devices()[0].platform != "tpu",
+                        **extra)
     np.testing.assert_allclose(np.asarray(ref), np.asarray(krn),
                                rtol=2e-4, atol=2e-4)
     return np.asarray(krn), bmeta
@@ -140,6 +155,164 @@ def test_ragged_kernel_empty_and_inert_blocks_are_zero():
     # row 0: queries 3..7 of block 0 are padding; row 1's block is inert
     assert np.all(out[3:tq] == 0.0)
     assert np.all(out[tq:] == 0.0)
+
+
+# One row's pages walked once per TILE of its queries (ISSUE 30): the same
+# block table grouped by ``ragged_tiles``. (prefix, q_len) rows; G = H / KV.
+TILE_CASES = {
+    # every length around a tile's edge in ONE launch: a decode row, one
+    # block, a block and a token, a tile less one, a tile, a tile and a
+    # token, and a row of 8 tiles whose last is short (1000 = 7·128 + 104)
+    "lengths-1-to-1000": dict(
+        rows=[(40, 1), (3, 8), (0, 9), (17, 127), (0, 128), (5, 129),
+              (0, 1000)], tile=128, KV=1),
+    # a segment that is no multiple of the tile, after a resident prefix
+    # of several pages; inert blocks between rows
+    "prefix-and-ragged-suffix": dict(
+        rows=[(5 * 16 + 3, 70), (9, 0), (200, 33), (0, 0), (64, 1)],
+        tile=32),
+    "tile-ends-at-the-rows-end": dict(
+        rows=[(0, 64), (16, 128), (7, 32)], tile=32),
+    # the window's first page falls inside a tile (its first and last
+    # query start on different pages) and between two tiles
+    "window-edge-inside-a-tile": dict(
+        rows=[(100, 70), (0, 90), (48, 1)], tile=64, window=20),
+    "window-edge-between-tiles": dict(
+        rows=[(96, 64), (0, 200)], tile=32, window=32),
+    "window-wider-than-a-tile": dict(
+        rows=[(30, 150), (250, 9)], tile=32, window=100),
+    "window-one-page": dict(rows=[(0, 100), (77, 40)], tile=32, window=16),
+    # a 128-token tile at G = 8 (1,024 score rows a kv head) whose window
+    # is a third of it: most of a page's columns are masked for most rows
+    "window-inside-a-tall-tile": dict(
+        rows=[(100, 200), (0, 90), (300, 33)], tile=128, window=40, KV=1),
+    "g1": dict(rows=[(20, 300), (90, 1), (0, 17)], tile=128, H=2, KV=2),
+    "g4": dict(rows=[(20, 300), (90, 1), (0, 17)], tile=128, H=8, KV=2),
+    "g8": dict(rows=[(20, 300), (90, 1), (0, 17)], tile=128, H=8, KV=1),
+    "g3-page8": dict(rows=[(20, 100), (9, 1)], tile=64, H=6, KV=2, page=8),
+    "int8": dict(rows=[(40, 1), (17, 41), (0, 70), (5, 0), (63, 200)],
+                 tile=64, quant=True),
+    "int8-window": dict(rows=[(100, 70), (0, 90), (48, 1)], tile=32,
+                        window=20, quant=True, KV=1),
+    # the smallest tile is a block: the table is the block table's twin
+    "tile-of-one-block": dict(rows=[(40, 1), (17, 11), (0, 19), (5, 0)],
+                              tile=8),
+}
+
+
+@pytest.mark.parametrize("case", TILE_CASES.values(), ids=TILE_CASES)
+def test_tile_kernel_matches_oracle(case):
+    """The tile kernel (interpret mode) against the dense oracle, whole
+    output: padding tokens and inert tiles come out zero as the oracle's
+    do, so one comparison covers them."""
+    rows, page = case["rows"], case.get("page", 16)
+    need = sum(-(-(pre + q) // page) for pre, q in rows if q) + 2
+    out, _ = _random_case(
+        np.random.default_rng(30), rows, case.get("H", 8),
+        case.get("KV", 2), 32, page, need, case.get("window"),
+        tile=case["tile"], quant=case.get("quant", False))
+    assert np.all(np.isfinite(out))
+
+
+@pytest.mark.parametrize("seed", range(4))
+def test_tiles_partition_the_flat_layout(seed):
+    """``ragged_tiles`` of a random block table: the tiles' spans tile
+    the flat tokens without gap or overlap, no tile crosses a row or
+    holds more than ``tile`` tokens, its first query is its first
+    block's, the queries add up, and the engine's static slot count is
+    never short."""
+    from quoracle_tpu.ops.paged_attention import (
+        ragged_tile_slots, ragged_tiles,
+    )
+    rng = np.random.default_rng(seed)
+    tq, tile = RAGGED_TQ, int(rng.choice([8, 32, 128]))
+    n_rows = int(rng.integers(1, 9))
+    segs = rng.integers(1, 600, n_rows)
+    pres = rng.integers(0, 900, n_rows)
+    nb = -(-segs // tq)
+    NB = int(nb.sum()) + int(rng.integers(0, 40))       # tail padding
+    meta = np.zeros((4, NB), np.int32)
+    cur = 0
+    for r in range(n_rows):
+        b = np.arange(nb[r])
+        meta[:, cur + b] = (np.full(nb[r], pres[r] + segs[r]),
+                            pres[r] + b * tq,
+                            np.minimum(tq, segs[r] - b * tq),
+                            np.full(nb[r], r))
+        cur += nb[r]
+    slots = ragged_tile_slots(NB, 8, tq, tile)
+    tiles = ragged_tiles(meta, tq, tile, slots)
+    assert tiles.shape == (6, slots)
+    kv_len, qpos0, nq, row, tok0, span = tiles
+    used = span > 0
+    assert not np.any(tiles[:, ~used])
+    assert np.array_equal(tok0[used],
+                          np.r_[0, np.cumsum(span[used])[:-1]])
+    assert span[used].sum() == NB * tq
+    assert np.all(span <= tile) and np.all(span % tq == 0)
+    live = nq > 0
+    assert np.all(span[live] == -(-nq[live] // tq) * tq)
+    assert nq.sum() == segs.sum()
+    first = tok0[live] // tq
+    assert np.array_equal(tiles[[0, 1, 3]][:, live],
+                          meta[[0, 1, 3]][:, first])
+    for r in range(n_rows):             # a row's tiles: full, then a rest
+        assert nq[live & (row == r)].tolist() == \
+            [tile] * int(segs[r] // tile) + [segs[r] % tile] * int(
+                segs[r] % tile > 0)
+
+
+def test_a_cold_prompt_streams_its_keys_once_per_tile():
+    """``attn_kv_streamed``: a cold 2,048-token row walked a block at a
+    time brings n² / 16 resident tokens into VMEM a layer; walked a
+    128-token tile at a time, under a sixth of that (a sixteenth, and a
+    page for the diagonal)."""
+    from quoracle_tpu.ops.paged_attention import (
+        ragged_tile_walk, ragged_tiles,
+    )
+    tq, n, page = RAGGED_TQ, 2048, 128
+    b = np.arange(n // tq)
+    meta = np.stack([np.full_like(b, n), b * tq, np.full_like(b, tq),
+                     np.zeros_like(b)])
+    by_block, programs = ragged_tile_walk(ragged_tiles(meta, tq, tq), page)
+    assert programs == n // tq
+    # block i sees ceil((i + 1)·8 / 128) pages
+    assert by_block == page * sum(-(-(i + 1) * tq // page) for i in b)
+    by_tile, programs = ragged_tile_walk(ragged_tiles(meta, tq, 128), page)
+    assert programs == n // 128
+    assert by_tile == page * sum(range(1, n // 128 + 1))
+    assert by_tile * 6 < by_block
+    # a window cuts the walk at the tile's FIRST query's reach
+    windowed, _ = ragged_tile_walk(ragged_tiles(meta, tq, 128), page, 256)
+    assert windowed == page * (1 + 2 + 3 * 14)
+
+
+def test_tick_span_counts_what_the_kernel_streamed():
+    """A ragged tick under an open tick record notes, beside
+    ``attn_kv_reads``, the resident tokens its kernel programs brought
+    into VMEM and how many programs walked pages: the chunk forward's
+    tiles, and one one-token tile a row a decode step."""
+    from quoracle_tpu.infra.telemetry import tick_close, tick_open
+    eng = make_engine(max_seq=1024, prompt_buckets=(64, 128, 256, 512))
+    page, tile = eng.sessions.page, eng._ragged_tile
+    long, short = enc("user: " + "a long cold prompt " * 20), enc("u: hi")
+    tick_open("m")
+    try:
+        res = eng.generate([long, short], temperature=0.0,
+                           max_new_tokens=5, session_ids=["a", "b"])
+    finally:
+        args = tick_close().args
+    pages = lambda n: -(-n // page)         # noqa: E731
+    # the chunk forward: row r's tiles end at tile, 2·tile, …, its length
+    chunk = [pages(min(n, (t + 1) * tile)) for n in (len(long), len(short))
+             for t in range(-(-n // tile))]
+    # decode forward j sees the prompt and j sampled tokens; the last
+    # sampled token of a row is never fed back
+    dec = [pages(n + j) for n, r in zip((len(long), len(short)), res)
+           for j in range(1, len(r.token_ids))]
+    assert args["attn_tiles"] == len(chunk) + len(dec)
+    assert args["attn_kv_streamed"] == page * (sum(chunk) + sum(dec))
+    assert args["attn_kv_streamed"] >= args["attn_kv_reads"] > 0
 
 
 # --- engine equality: unified vs gather -------------------------------------
@@ -288,7 +461,9 @@ def test_every_platform_takes_the_ragged_path(tmp_path, monkeypatch):
                          session_ids=["s", None])
         eng.generate([p + r[0].token_ids + enc(" and again")[1:]],
                      temperature=0.0, max_new_tokens=6, session_ids=["s"])
-        return [e["shape"] for e in eng.compiles.snapshot()["shapes"]]
+        # the snapshot lists the dearest compile first: an order that
+        # follows the machine's load and the compile cache, not the path
+        return sorted(e["shape"] for e in eng.compiles.snapshot()["shapes"])
 
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     with open(os.path.join(root, "benchmark/configs/tiny-l2.json")) as f:
@@ -637,7 +812,8 @@ def _assert_pool(got, pool, quant):
             np.testing.assert_allclose(g, w, rtol=2e-5, atol=2e-5)
 
 
-@pytest.mark.parametrize("interpret", [None, True], ids=["xla", "kernel"])
+@pytest.mark.parametrize("interpret", [None, True, "tiles"],
+                         ids=["xla", "kernel", "tile-kernel"])
 @pytest.mark.parametrize("case", [
     dict(), dict(window=6), dict(n_kv_heads=4), dict(quant=True),
 ], ids=["kv2", "kv2-window6", "kv4", "kv2-int8"])
@@ -645,7 +821,11 @@ def test_pool_in_place_matches_plain_per_layer_reference(case, interpret):
     """``forward_hidden_ragged`` then ``decode_ragged`` — the pool a scan
     carry, then a loop carry, written at whole-pool indices and read by
     layer index — against the plain reference: the same greedy tokens and
-    the same pool, every page of every layer."""
+    the same pool, every page of every layer. ``tile-kernel``: the chunk
+    forward's attention walks two blocks a tile (the engine's call)."""
+    from quoracle_tpu.ops.paged_attention import ragged_tiles
+    case_tiles, tiled = interpret == "tiles", {}
+    interpret = None if interpret is None else True
     quant = case.get("quant", False)
     cfg = _tiny(3, case.get("n_kv_heads", 2), case.get("window"))
     params = init_params(cfg, jax.random.PRNGKey(1), dtype=jnp.float32)
@@ -660,13 +840,16 @@ def test_pool_in_place_matches_plain_per_layer_reference(case, interpret):
             ([2, 6], 4, rng.integers(1, 97, 1))]
     tok, posn, dst, meta, tables, last, lens0 = _flat_tick(
         rows, tq, n_pages * PG)
+    if case_tiles:
+        tiled = dict(tile=2 * tq,
+                     tiles=jnp.asarray(ragged_tiles(meta, tq, 2 * tq)))
 
     @jax.jit
     def tick(k, v, ks, vs):
         out = tr.forward_hidden_ragged(
             params, cfg, jnp.asarray(tok)[None], jnp.asarray(posn)[None],
             k, v, jnp.asarray(tables), jnp.asarray(meta), jnp.asarray(dst),
-            tq=tq, interpret=interpret, k_scale=ks, v_scale=vs)
+            tq=tq, interpret=interpret, k_scale=ks, v_scale=vs, **tiled)
         hidden, pools = out[0], out[1:]      # (k, v, k_scale, v_scale)
         first = tr.project_logits(params, cfg,
                                   hidden[0][last][:, None])[:, 0]
