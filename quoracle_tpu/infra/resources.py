@@ -201,7 +201,8 @@ def hbm_attribution(backend) -> dict:
             page_b = _kv_page_bytes(e)
             pool_b = 0
             if st.k is not None:
-                pool_b = int(st.k.nbytes) + int(st.v.nbytes)
+                pool_b = sum(int(a.nbytes) for a in (st.k, st.v)
+                             if a is not None)   # a latent pool has no v
                 if st.k_scale is not None:
                     pool_b += (int(st.k_scale.nbytes)
                                + int(st.v_scale.nbytes))

@@ -716,6 +716,14 @@ MOE_LAYER_STEPS_TOTAL = METRICS.counter(
     "quoracle_moe_layer_steps_total",
     "expert layers run (one per layer per forward step with a valid "
     "token), per model")
+# -- learned sparse attention (ISSUE 31) --------------------------------------
+SPARSE_ATTN_PAIRS_TOTAL = METRICS.counter(
+    "quoracle_sparse_attn_pairs_total",
+    "query-key pairs of a model whose attention selects its keys "
+    "(config.IndexerConfig), summed over layers' queries of chunk forwards "
+    "and decode steps, per model: kind = visible under the causal mask "
+    "(each is scored by the indexer), kind = selected the softmax ran over "
+    "(min(visible, topk) a query)")
 # -- the batcher's tick record (ISSUE 24) -----------------------------------
 # One record per ContinuousBatcher._loop iteration, built on the worker
 # thread where the work happens (models/scheduler.py, models/generate.py).

@@ -51,6 +51,23 @@ class LatentConfig:
 
 
 @dataclasses.dataclass(frozen=True)
+class IndexerConfig:
+    """Learned sparse attention (the DeepSeek-V3.2 form) beside latent
+    attention: every query scores all of its visible keys through a second,
+    cheap attention — ``n_heads`` heads of ``head_dim`` from the query
+    latent against ONE index key a token, weighted per head and summed
+    after a ReLU — and the latent attention's softmax then runs over the
+    ``topk`` best-scoring positions only. A resident token holds its index
+    key beside its latent row, in a pool of its own under the same page
+    ids (``ModelConfig.kv_pools``)."""
+
+    n_heads: int         # index_n_heads
+    head_dim: int        # index_head_dim: the index key's width
+    topk: int            # index_topk: keys a query attends to at most
+    rope_dim: int        # leading values of each query and key that rotate
+
+
+@dataclasses.dataclass(frozen=True)
 class MoEConfig:
     """Routed experts with a shared expert (the DeepSeek-V3 form): the
     router scores ALL ``n_routed`` experts; this process HOLDS experts
@@ -69,6 +86,9 @@ class MoEConfig:
     norm_topk: bool = True
     first_dense: int = 0     # leading layers with the dense MLP
     held_start: int = 0
+    # ``noaux_tc``: one float32 a routed expert is added to its score where
+    # groups and experts are CHOSEN; the gates stay the bare scores
+    router_bias: bool = False
 
     def __post_init__(self):
         assert self.n_routed % self.n_group == 0
@@ -120,6 +140,9 @@ class ModelConfig:
     # paged path only (``require_plain`` at every other path's entry).
     latent: Optional[LatentConfig] = None
     moe: Optional[MoEConfig] = None
+    # Learned top-k selection inside the latent attention (latent models
+    # only): a second pool holds each token's index key.
+    indexer: Optional[IndexerConfig] = None
 
     # --- serving metadata (what the reference pulled from LLMDB) ---
     context_window: int = 8192
@@ -158,6 +181,8 @@ class ModelConfig:
             "latent attention and routed experts are served together only"
         if self.moe is not None:
             assert 0 <= self.moe.first_dense < self.n_layers
+        assert self.indexer is None or self.latent is not None, \
+            "the indexer selects keys for latent attention only"
 
     @property
     def q_per_kv(self) -> int:
@@ -174,9 +199,13 @@ class ModelConfig:
         """THE statement of what a resident token holds in one layer: the
         lane count of each stored pool. Per-head K and V: two pools of
         ``n_kv_heads·head_dim`` lanes; a latent cache: ONE pool of
-        ``latent.lanes``. Pool shapes (generate.py ``_ensure_pool``), the
-        byte rates below, ``kv_signature``, ``quant_stats`` and
-        ``pool_sizing`` all read this."""
+        ``latent.lanes``, and with an indexer a second, narrower one of
+        ``indexer.head_dim`` for the token's index key. Pool shapes
+        (generate.py ``_ensure_pool``), the byte rates below,
+        ``kv_signature``, ``quant_stats`` and ``pool_sizing`` all read
+        this."""
+        if self.indexer is not None:
+            return (self.latent.lanes, self.indexer.head_dim)
         if self.latent is not None:
             return (self.latent.lanes,)
         return (self.n_kv_heads * self.head_dim,) * 2
@@ -188,11 +217,19 @@ class ModelConfig:
     def _attn_params(self) -> int:
         if self.latent is not None:
             la, H = self.latent, self.n_heads
-            return (self.dim * la.q_rank + la.q_rank
-                    + la.q_rank * H * la.qk_dim
-                    + self.dim * (la.kv_rank + la.rope_dim) + la.kv_rank
-                    + la.kv_rank * H * (la.nope_dim + la.v_dim)
-                    + H * la.v_dim * self.dim)
+            n = (self.dim * la.q_rank + la.q_rank
+                 + la.q_rank * H * la.qk_dim
+                 + self.dim * (la.kv_rank + la.rope_dim) + la.kv_rank
+                 + la.kv_rank * H * (la.nope_dim + la.v_dim)
+                 + H * la.v_dim * self.dim)
+            if self.indexer is not None:
+                # query, key and head-weight projections, the key's
+                # LayerNorm (weight and bias)
+                ix = self.indexer
+                n += (la.q_rank * ix.n_heads * ix.head_dim
+                      + self.dim * ix.head_dim + self.dim * ix.n_heads
+                      + 2 * ix.head_dim)
+            return n
         hd = self.head_dim
         q = self.dim * self.n_heads * hd + (self.n_heads * hd
                                             if self.attn_bias else 0)
@@ -209,6 +246,7 @@ class ModelConfig:
         else:
             m = self.moe
             mlp = (self.dim * m.n_routed
+                   + (m.n_routed if m.router_bias else 0)
                    + 3 * self.dim * m.expert_dim * (experts + m.n_shared))
         return self._attn_params() + mlp + norms
 

@@ -1752,7 +1752,7 @@ class GenerateEngine:
         # degrade is a cold re-prefill, exactly the version-skew path.
         # Unquantized engines keep the historic signature unchanged.
         geometry = (f"x{cfg.n_kv_heads}x{cfg.head_dim}" if cfg.latent is None
-                    else f"xlatent{cfg.kv_pools[0]}")
+                    else "xlatent" + "+".join(map(str, cfg.kv_pools)))
         return (f"{cfg.name.replace('/', '_')}-L{cfg.n_layers}"
                 f"{geometry}-p{self.sessions.page}"
                 f"-{jnp.dtype(self.pool_dtype).name}"
@@ -2305,6 +2305,34 @@ class GenerateEngine:
         tick_note(moe_assignments=total, moe_held=held,
                   moe_reached=reached, moe_layer_steps=steps)
 
+    def _note_selection(self, kv_reads: int, pairs: int, ctx, seg,
+                        fwd) -> None:
+        """Book one tick of a model whose attention selects its keys: the
+        indexer streams every index key the attention kernel's walk covers
+        and scores every visible pair (``index_kv_reads`` / ``index_pairs``
+        are the tick's ``attn_kv_reads`` / ``attn_pairs``), and the softmax
+        runs over ``min(visible, topk)`` pairs a query
+        (``attn_selected_pairs``), queries of the chunk forward (positions
+        ``ctx - seg .. ctx``) and of each decode forward alike. Counts are
+        per layer, as the other tick arguments."""
+        from quoracle_tpu.infra.telemetry import SPARSE_ATTN_PAIRS_TOTAL
+        k = self.cfg.indexer.topk
+
+        def selected(lo, hi):
+            """Σ min(v, k) over visible counts v in (lo, hi]."""
+            cut = np.clip(k, lo, hi)
+            return (cut * (cut + 1) - lo * (lo + 1)) // 2 + (hi - cut) * k
+
+        sel = int((selected(ctx - seg, ctx)
+                   + selected(ctx, ctx + fwd)).sum())
+        tick_note(index_kv_reads=kv_reads, index_pairs=pairs,
+                  attn_selected_pairs=sel)
+        L = self.cfg.n_layers
+        SPARSE_ATTN_PAIRS_TOTAL.inc(pairs * L, model=self.cfg.name,
+                                    kind="visible")
+        SPARSE_ATTN_PAIRS_TOTAL.inc(sel * L, model=self.cfg.name,
+                                    kind="selected")
+
     def padding_stats(self) -> dict:
         """Cumulative padding-waste view for /api/resources: what
         raggedness reclaims, quantified per engine."""
@@ -2322,7 +2350,7 @@ class GenerateEngine:
         quantized; plain cache bytes otherwise) — the shared byte rate
         for resources attribution, /api/kv compression and planning."""
         from quoracle_tpu.models.quant import kv_token_bytes
-        if self.cfg.latent is not None:    # one pool, never int8
+        if self.cfg.latent is not None:    # never int8
             return self.cfg.kv_bytes_per_token(
                 dtype_bytes=jnp.dtype(self.pool_dtype).itemsize)
         return kv_token_bytes(
@@ -2355,7 +2383,10 @@ class GenerateEngine:
         (ops/paged_attention.ragged_attend). What a token holds in a
         layer is ``cfg.kv_pools``: a latent model has ONE pool,
         ``[L, n_pages, page, latent.lanes]`` under ``st.k`` (``st.v``
-        stays None, as the scale pools do). The serving programs carry
+        stays None, as the scale pools do), and with an indexer a second,
+        narrower one under ``st.v`` for the tokens' index keys: the same
+        page ids address both, so sessions, the prefix cache and eviction
+        carry a token's two rows together. The serving programs carry
         these two buffers through their layer scan and decode loop and
         update them in place; who wants ``[…, KV, hd]`` takes a view —
         a reshape of the fresh rows on the device, of the pages on the
@@ -2369,7 +2400,7 @@ class GenerateEngine:
         if st.k is not None:
             return
         lanes = self.cfg.kv_pools
-        shape = (self.cfg.n_layers, st.n_pages, st.page, lanes[0])
+        shape = (self.cfg.n_layers, st.n_pages, st.page)
         sh = None
         if self.mesh is not None:
             # created in its sharding: no chip ever holds the whole pool
@@ -2379,8 +2410,8 @@ class GenerateEngine:
             # tp runs is the split of the KV axis, byte for byte
             kv_axis = "tp" if self.cfg.n_kv_heads % tp == 0 else None
             sh = NamedSharding(self.mesh, P(None, None, None, kv_axis))
-        k = jnp.zeros(shape, self.pool_dtype, device=sh)
-        v = (jnp.zeros(shape, self.pool_dtype, device=sh)
+        k = jnp.zeros(shape + lanes[:1], self.pool_dtype, device=sh)
+        v = (jnp.zeros(shape + lanes[1:], self.pool_dtype, device=sh)
              if len(lanes) == 2 else None)
         if self.quantize_kv:
             sshape = (self.cfg.n_layers, st.n_pages,
@@ -2885,10 +2916,12 @@ class GenerateEngine:
             [walked[:3], np.stack([seen, seen - 1, steps <= fwd[:, None]]
                                   ).reshape(3, -1)], axis=1),
             page, self.cfg.sliding_window)
-        tick_note(attn_kv_reads=int(ctx.sum()) + dec,
-                  attn_pairs=int((seg * (ctx - seg)
-                                  + seg * (seg + 1) // 2).sum()) + dec,
+        kv_reads = int(ctx.sum()) + dec
+        pairs = int((seg * (ctx - seg) + seg * (seg + 1) // 2).sum()) + dec
+        tick_note(attn_kv_reads=kv_reads, attn_pairs=pairs,
                   attn_kv_streamed=streamed, attn_tiles=n_tiles)
+        if self.cfg.indexer is not None:
+            self._note_selection(kv_reads, pairs, ctx, seg, fwd)
         return out, n_emitted, final_lens, jstate_f, None, t_prefill, now
 
     def _json_table_device(self, enum_set: tuple):

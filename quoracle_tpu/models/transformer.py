@@ -166,12 +166,22 @@ def _stack_leaves(cfg: ModelConfig, experts: bool) -> list:
             ("wkv_b", (la.kv_rank, H * (la.nope_dim + la.v_dim)),
              la.kv_rank),
             ("wo", (H * la.v_dim, D), H * la.v_dim)]
+    if cfg.indexer is not None:
+        # the indexer's leaves come after the attention's
+        ix = cfg.indexer
+        attn += [("wi_q", (la.q_rank, ix.n_heads * ix.head_dim), la.q_rank),
+                 ("wi_k", (D, ix.head_dim), D),
+                 ("wi_w", (D, ix.n_heads), D)]
     if not experts:
         F = cfg.ffn_dim
         return attn + [("w_gate", (D, F), D), ("w_up", (D, F), D),
                        ("w_down", (F, D), F)]
     m = cfg.moe
     Fe, Fs = m.expert_dim, m.expert_dim * m.n_shared
+    if m.router_bias:
+        # float32, 0.01 × normal (a "fan-in" of 1e4): a checkpoint's is
+        # what balanced its router's load, a seed's only exercises the path
+        attn += [("router_bias", (m.n_routed,), 10_000)]
     return attn + [("router", (D, m.n_routed), D),
                    ("we_gate", (D, Fe), D), ("we_up", (D, Fe), D),
                    ("we_down", (Fe, D), Fe),
@@ -224,10 +234,16 @@ def _init_params_stacks(cfg: ModelConfig, k_embed, k_layers, k_head,
                   "mlp_norm": jnp.ones((n, D), dtype),
                   "q_norm": jnp.ones((n, cfg.latent.q_rank), dtype),
                   "kv_norm": jnp.ones((n, cfg.latent.kv_rank), dtype)}
+        if cfg.indexer is not None:
+            leaves["ik_norm_w"] = jnp.ones((n, cfg.indexer.head_dim), dtype)
+            leaves["ik_norm_b"] = jnp.zeros((n, cfg.indexer.head_dim), dtype)
         for i, (leaf, shape, fan_in) in enumerate(
                 _stack_leaves(cfg, experts)):
             k = jax.random.fold_in(ks, i)
-            if leaf.startswith("we_"):
+            if leaf == "router_bias":
+                leaves[leaf] = _normal_leaf(k, (n, *shape), fan_in,
+                                            jnp.float32, None)
+            elif leaf.startswith("we_"):
                 leaves[leaf] = _expert_leaf(
                     k, cfg.moe.held_start, cfg.moe.n_held, (n, *shape),
                     fan_in, dtype)
@@ -409,13 +425,15 @@ def _attn_out(x: jax.Array, attn: jax.Array, p: dict,
 
 
 def _latent_qkv(x: jax.Array, p: dict, cfg: ModelConfig,
-                positions: jax.Array) -> tuple[jax.Array, jax.Array]:
+                positions: jax.Array) -> tuple:
     """Latent attention's inputs in the FOLDED form, for a flat tick
     ``x [1, Tp, D]``: the row a token stores, ``[c_kv | k_rope | 0]``
     (``[Tp, lanes]``), and the queries every head puts against such rows,
     ``[q_nope·W_kb^K | q_rope | 0]`` (``[Tp, H, lanes]``): the key
     up-projection rides the query, so a cached latent is read as it lies
-    (scope ``qkv`` ⊃ ``latent_proj``, then ``rope``)."""
+    (scope ``qkv`` ⊃ ``latent_proj``, then ``rope``). Also returns what
+    an indexer projects from: the normed input ``h`` and the normed query
+    latent ``c_q`` (``[1, Tp, ·]``)."""
     la, H = cfg.latent, cfg.n_heads
     Tp = x.shape[1]
     with jax.named_scope("qkv"), jax.named_scope("latent_proj"):
@@ -441,7 +459,89 @@ def _latent_qkv(x: jax.Array, p: dict, cfg: ModelConfig,
         [c_kv, k_rope, jnp.zeros((1, Tp, pad), x.dtype)], axis=-1)[0]
     q_full = jnp.concatenate(
         [q_lat, q_rope, jnp.zeros((1, Tp, H, pad), x.dtype)], axis=-1)[0]
-    return q_full, row
+    return q_full, row, h, cq
+
+
+@jax.named_scope("index_proj")
+def _index_inputs(h: jax.Array, cq: jax.Array, p: dict, cfg: ModelConfig,
+                  positions: jax.Array) -> tuple:
+    """The indexer's inputs for a flat tick (``h`` the attention's normed
+    input, ``cq`` its normed query latent, both ``[1, Tp, ·]``): queries
+    ``[Tp, Hi, di]`` from the query latent, ONE key a token ``[Tp, di]``
+    (LayerNorm: mean subtracted, eps 1e-6), rotary on the first
+    ``rope_dim`` values of each (the model's frequencies), and the float32
+    head weights ``[Tp, Hi]`` with the published constants
+    ``Hi^-1/2 · di^-1/2`` in them."""
+    ix = cfg.indexer
+    Tp = h.shape[1]
+    q = jnp.einsum("btr,rh->bth", cq, p["wi_q"]).reshape(
+        1, Tp, ix.n_heads, ix.head_dim)
+    k = jnp.einsum("btd,dk->btk", h, p["wi_k"]).astype(jnp.float32)
+    k = k - jnp.mean(k, axis=-1, keepdims=True)
+    k = k * jax.lax.rsqrt(jnp.mean(k * k, axis=-1, keepdims=True) + 1e-6)
+    k = (k * p["ik_norm_w"].astype(jnp.float32)
+         + p["ik_norm_b"].astype(jnp.float32)).astype(h.dtype)[:, :, None]
+
+    def rotate(x):
+        return jnp.concatenate(
+            [rope(x[..., :ix.rope_dim], positions, cfg.rope_theta,
+                  cfg.rope_scaling), x[..., ix.rope_dim:]], axis=-1)
+
+    w = jnp.einsum("btd,dh->bth", h, p["wi_w"],
+                   preferred_element_type=jnp.float32) \
+        * (ix.n_heads ** -0.5 * ix.head_dim ** -0.5)
+    return rotate(q)[0], rotate(k)[0, :, 0], w[0]
+
+
+@jax.named_scope("index_select")
+def select_keys(scores: jax.Array, block_meta: jax.Array, tq: int,
+                topk: int) -> jax.Array:
+    """The learned selection, exact: ``scores [T, S]`` float32 (a flat
+    tick's queries against every position of their rows' tables) →
+    int32 ``[T, S]``, 1 where the query attends: its visible positions
+    (``block_meta``: ``s <= qpos``, ``s < kv_len``) when there are at most
+    ``topk`` of them, else the ``topk`` of largest score, ties to the
+    lower position.
+
+    No sort: the ``topk``-th largest score is found digit by digit on the
+    scores' bits in an order-preserving unsigned form (two bits a pass,
+    each pass one fused compare-and-count over the scores), and among the
+    positions that tie with it the cut is found the same way on the
+    position's bits. 24 passes at 16k positions; every pass streams what
+    the scoring kernel wrote and nothing of the size is made."""
+    T, S = scores.shape
+    kv_len, qpos0, nq, _ = (jnp.repeat(block_meta[j], tq) for j in range(4))
+    t_in = jnp.arange(T, dtype=jnp.int32) % tq
+    s_idx = jnp.arange(S, dtype=jnp.int32)[None]
+    visible = ((s_idx <= (qpos0 + t_in)[:, None]) & (s_idx < kv_len[:, None])
+               & (t_in < nq)[:, None])
+    # float32 → uint32 of the same order (-0.0 as +0.0); unseen → 0
+    bits = jax.lax.bitcast_convert_type(
+        jnp.where(scores == 0, 0.0, scores).astype(jnp.float32), jnp.uint32)
+    u = jnp.where(bits >> 31 == 1, ~bits, bits | jnp.uint32(1 << 31))
+    u = jnp.where(visible, u, jnp.uint32(0))
+
+    def climb(n_bits, dtype, holds):
+        """The largest value whose ``holds(candidates [T, 3]) -> bool``
+        is true, two bits a pass; ``holds`` is monotone (true up to it)."""
+        v = jnp.zeros((T,), dtype)
+        for shift in range(n_bits + n_bits % 2 - 2, -1, -2):
+            c = v[:, None] | (jnp.arange(1, 4, dtype=dtype) << shift)
+            v = v | (holds(c).sum(-1).astype(dtype) << shift)
+        return v
+
+    # the topk-th largest: the largest v with at least topk scores >= v
+    thr = climb(32, jnp.uint32, lambda c: (
+        u[:, None, :] >= c[:, :, None]).sum(-1) >= topk)[:, None]
+    above = u > thr
+    ties = (u == thr) & visible
+    need = (topk - above.sum(-1))[:, None]
+    # ... and of its ties the first `need`: the largest position p with
+    # fewer than `need` ties below it is the last one taken
+    cut = climb(max(S - 1, 1).bit_length(), jnp.int32, lambda c: (
+        ties[:, None, :] & (s_idx[None] < c[:, :, None])).sum(-1) < need)
+    return (visible & (above | (ties & (s_idx <= cut[:, None])))
+            ).astype(jnp.int32)
 
 
 @jax.named_scope("attn_out")
@@ -458,24 +558,31 @@ def _latent_attn_out(x: jax.Array, attn: jax.Array, p: dict,
     return x + jnp.einsum("thv,hvD->tD", o, wo)[None]
 
 
-def moe_select(logits: jax.Array, m) -> tuple[jax.Array, jax.Array]:
+def moe_select(logits: jax.Array, m,
+               bias: Optional[jax.Array] = None
+               ) -> tuple[jax.Array, jax.Array]:
     """Router logits [T, n_routed] float32 → (experts [T, k] int32, gates
     [T, k] float32): sigmoid scores; the experts fall into ``n_group``
     groups, a group scores the sum of its two largest, the ``topk_group``
     best groups stay; the ``k`` largest scores inside them are selected;
     gates are the selected scores over their sum (``norm_topk``) times
-    ``routed_scale``. Ties go to the lower index."""
+    ``routed_scale``. Ties go to the lower index. With ``bias`` (float32
+    [n_routed], the ``noaux_tc`` correction) groups and experts are
+    chosen by score + bias; the gates are the bare scores of the chosen."""
     T, E = logits.shape
     s = jax.nn.sigmoid(logits.astype(jnp.float32))
-    pick = s
+    pick = s if bias is None else s + bias
     if m.n_group > 1:
-        g = s.reshape(T, m.n_group, E // m.n_group)
+        g = pick.reshape(T, m.n_group, E // m.n_group)
         group = jax.lax.top_k(g, 2)[0].sum(-1)               # [T, n_group]
         keep = jax.lax.top_k(group, m.topk_group)[1]         # [T, topk]
         mask = jnp.zeros((T, m.n_group), bool).at[
             jnp.arange(T)[:, None], keep].set(True)
-        pick = jnp.where(mask[:, :, None], g, -1.0).reshape(T, E)
+        pick = jnp.where(mask[:, :, None], g,
+                         -1.0 if bias is None else -jnp.inf).reshape(T, E)
     gates, idx = jax.lax.top_k(pick, m.per_token)
+    if bias is not None:
+        gates = jnp.take_along_axis(s, idx, axis=-1)
     if m.norm_topk:
         gates = gates / (gates.sum(-1, keepdims=True) + 1e-20)
     return idx.astype(jnp.int32), gates * m.routed_scale
@@ -560,7 +667,7 @@ def _moe(x: jax.Array, p: dict, experts: tuple, layer, cfg: ModelConfig,
         logits = jnp.dot(h.astype(jnp.float32),
                          p["router"].astype(jnp.float32),
                          precision=jax.lax.Precision.HIGHEST)
-        idx, gates = moe_select(logits, m)
+        idx, gates = moe_select(logits, m, p.get("router_bias"))
     with jax.named_scope("routed_experts"):
         routed, stats = _routed_experts(h, idx, gates, experts, layer, m,
                                         cfg.activation, valid)
@@ -783,6 +890,15 @@ def forward_hidden_ragged(
             None)
 
 
+# Query tokens a model with an indexer attends at a time: a tick's index
+# scores are [tokens, table width · page] float32 (64 MiB at 1,024 tokens
+# against 16k positions; a 16k-token tick whole would be 1 GiB, and its
+# folded queries 2.7 GB at 128 heads), so a longer tick runs its attention
+# — projections of the queries, scores, selection, kernel, output fold —
+# chunk by chunk inside the layer.
+INDEX_CHUNK = 1024
+
+
 def _forward_hidden_ragged_stacks(params, cfg, tokens, positions, k_pool,
                                   v_pool, row_tables, block_meta, flat_dst,
                                   tq, interpret) -> tuple:
@@ -797,10 +913,24 @@ def _forward_hidden_ragged_stacks(params, cfg, tokens, positions, k_pool,
     (ops/paged_attention.ragged_attend_latent). Returns the dense
     function's tuple; its last member is the expert layers' int32 [4]
     (assignments, of them to held experts, held experts reached summed
-    over layers, expert layers run)."""
-    from quoracle_tpu.ops.paged_attention import ragged_attend_latent_auto
+    over layers, expert layers run).
+
+    With an indexer (``cfg.indexer``) ``v_pool`` is the index-key pool
+    ``[L, n_pages, page, indexer.head_dim]``, carried and written beside
+    the latent pool under the same slots, and a layer's attention runs
+    over each query's SELECTION (scope ``indexer`` ⊃ ``index_proj``,
+    ``index_scores``, ``index_select``): every query scores its visible
+    positions against their index keys (ops/paged_attention.index_scores),
+    keeps its ``topk`` best (``select_keys``, exact), and the latent
+    kernel walks the row's pages with that per-query mask — the causal
+    walk's cost, the published arithmetic. Ticks longer than
+    ``INDEX_CHUNK`` tokens attend chunk by chunk."""
+    from quoracle_tpu.ops.paged_attention import (
+        index_scores_auto, ragged_attend_latent_auto,
+    )
     L, n_pages, page, _ = k_pool.shape
     n_tok = n_pages * page
+    Tp = tokens.shape[1]
     x = _embed(params, cfg, tokens)
     keep = flat_dst < n_tok
     scale = attn_softmax_scale(cfg)
@@ -808,18 +938,71 @@ def _forward_hidden_ragged_stacks(params, cfg, tokens, positions, k_pool,
     experts = tuple(params["layers"][k]
                     for k in ("we_gate", "we_up", "we_down"))
 
-    def attention(x, kp, p, layer):
-        q, row = _latent_qkv(x, p, cfg, positions)
+    def write(pool, rows, layer, keep, flat_dst):
+        dst = jnp.where(keep, layer * n_tok + flat_dst, L * n_tok)
+        return pool.reshape(L * n_tok, pool.shape[-1]).at[dst].set(
+            rows.astype(pool.dtype), mode="drop").reshape(pool.shape)
+
+    def latent_attention(x, kp, p, layer):
+        q, row, _, _ = _latent_qkv(x, p, cfg, positions)
         with jax.named_scope("kv_write"):
-            dst = jnp.where(keep, layer * n_tok + flat_dst, L * n_tok)
-            kp = kp.reshape(L * n_tok, kp.shape[-1]).at[dst].set(
-                row.astype(kp.dtype), mode="drop").reshape(kp.shape)
+            kp = write(kp, row, layer, keep, flat_dst)
         with jax.named_scope("attn"):
             attn = ragged_attend_latent_auto(
                 q, kp, row_tables, block_meta, layer, tq=tq,
                 v_lanes=cfg.latent.kv_rank, scale=scale,
                 interpret=interpret)
         return _latent_attn_out(x, attn, p, cfg), kp
+
+    def selected_attention(x, pools, p, layer, positions, keep, flat_dst,
+                           block_meta):
+        """``latent_attention`` over each query's selection, for the
+        tokens handed in (the tick, or a chunk of it with its blocks)."""
+        kp, ip = pools
+        q, row, h, cq = _latent_qkv(x, p, cfg, positions)
+        with jax.named_scope("indexer"):
+            qi, ki, w = _index_inputs(h, cq, p, cfg, positions)
+        with jax.named_scope("kv_write"):
+            kp = write(kp, row, layer, keep, flat_dst)
+            ip = write(ip, ki, layer, keep, flat_dst)
+        with jax.named_scope("indexer"):
+            with jax.named_scope("index_scores"):
+                scores = index_scores_auto(qi, w, ip, row_tables,
+                                           block_meta, layer, tq=tq,
+                                           interpret=interpret)
+            select = select_keys(scores, block_meta, tq, cfg.indexer.topk)
+        with jax.named_scope("attn"):
+            attn = ragged_attend_latent_auto(
+                q, kp, row_tables, block_meta, layer, tq=tq,
+                v_lanes=cfg.latent.kv_rank, scale=scale,
+                interpret=interpret, select=select)
+        return _latent_attn_out(x, attn, p, cfg), (kp, ip)
+
+    def chunked_attention(x, pools, p, layer):
+        C = INDEX_CHUNK
+        if Tp <= C:
+            return selected_attention(x, pools, p, layer, positions, keep,
+                                      flat_dst, block_meta)
+        assert Tp % C == 0 and C % tq == 0, (Tp, C, tq)
+        n = Tp // C
+        # a chunk's queries see the chunks before them, written by the
+        # steps before theirs; a block never spans two chunks (C % tq)
+        chunks = (x.reshape(n, 1, C, -1), positions.reshape(n, 1, C),
+                  keep.reshape(n, C), flat_dst.reshape(n, C),
+                  block_meta.reshape(4, n, C // tq).transpose(1, 0, 2))
+
+        def step(pools, chunk):
+            y, pools = selected_attention(chunk[0], pools, p, layer,
+                                          *chunk[1:])
+            return pools, y
+
+        pools, y = jax.lax.scan(step, pools, chunks)
+        return y.reshape(x.shape), pools
+
+    # what the scans carry as "the pool": the latent pool, or the pair
+    attention = latent_attention
+    if cfg.indexer is not None:
+        attention, k_pool = chunked_attention, (k_pool, v_pool)
 
     def dense_body(carry, scanned):
         x, kp = carry
@@ -847,6 +1030,8 @@ def _forward_hidden_ragged_stacks(params, cfg, tokens, positions, k_pool,
         (x, k_pool, stats), _ = jax.lax.scan(
             expert_body, (x, k_pool, jnp.zeros((4,), jnp.int32)),
             (rest, jnp.arange(n_dense, L, dtype=jnp.int32)))
+    if cfg.indexer is not None:
+        k_pool, v_pool = k_pool
     return _final_norm(x, params, cfg), k_pool, v_pool, None, None, stats
 
 

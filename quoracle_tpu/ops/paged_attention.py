@@ -4,8 +4,10 @@ engine's page-pool layout): ``ragged_attend`` serves a token-major
 flattened batch of mixed prefill+decode rows in ONE launch per layer —
 a program per 8-token block, or per TILE of up to 128 of a row's tokens
 that read its pages once between them (the chunk forward's call) —
-``ragged_attend_latent`` the same contract over a latent (MLA) pool:
-see the section comments below and ARCHITECTURE.md §10.
+``ragged_attend_latent`` the same contract over a latent (MLA) pool,
+with a per-query selection where the model has an indexer, whose scores
+``index_scores`` streams from a pool of its own: see the section comments
+below and ARCHITECTURE.md §10.
 
 The paged KV session cache (models/generate.py SessionStore) keeps every
 resident conversation as a PAGE LIST into one device pool. The gather
@@ -747,9 +749,12 @@ def ragged_attend_latent_ref(
     tq: int,
     v_lanes: int,
     scale: float,
+    select: Optional[jax.Array] = None,   # [NB·tq, maxp·page] int32
 ) -> jax.Array:
     """XLA gather reference for the latent kernel (CPU serving path + the
-    kernel's oracle): normalized output [NB·tq, H, v_lanes] f32."""
+    kernel's oracle): normalized output [NB·tq, H, v_lanes] f32. With
+    ``select`` a query attends only where its row of it is nonzero (and
+    the causal mask allows)."""
     kv_len, qpos0, nq, row = (block_meta[j][:, None, None]
                               for j in range(4))
     block_tables = row_tables[row[:, 0, 0]]                  # [NB, maxp]
@@ -763,7 +768,10 @@ def ragged_attend_latent_ref(
     t_idx = jnp.arange(tq, dtype=jnp.int32)[None, :, None]
     s_idx = jnp.arange(maxp * page, dtype=jnp.int32)[None, None, :]
     mask = ((s_idx < kv_len) & (s_idx <= qpos0 + t_idx)
-            & (t_idx < nq))[:, None]                         # [NB,1,tq,S]
+            & (t_idx < nq))
+    if select is not None:
+        mask = mask & (select.reshape(NB, tq, maxp * page) != 0)
+    mask = mask[:, None]                                     # [NB,1,tq,S]
     scores = jnp.where(mask, scores, NEG_INF)
     m = jnp.max(scores, axis=-1, keepdims=True)
     p = jnp.where(mask, jnp.exp(scores - m), 0.0)
@@ -774,13 +782,23 @@ def ragged_attend_latent_ref(
 
 
 def _ragged_latent_kernel(tables_ref, meta_ref, layer_ref, q_ref, kv_hbm,
-                          out_ref, kv_scr, sems, *, page: int, tq: int,
-                          v_lanes: int, scale: float):
+                          *refs, page: int, tq: int, v_lanes: int,
+                          scale: float, selected: bool = False):
     """One tq-token block: stream the owning row's visible latent pages
     through VMEM double-buffered, ONE DMA a page, and write the normalized
     output in latent space. The page is the key at its full width and the
     value at its first ``v_lanes`` lanes. Products take the operands in
-    their stored type with float32 accumulation; softmax is float32."""
+    their stored type with float32 accumulation; softmax is float32.
+
+    ``selected``: one more input, the block's rows of the selection
+    ``[1, tq, maxp·page]`` int32, whole in VMEM; a query attends a key only
+    where its row is nonzero. The walk is the causal one — every visible
+    page is streamed and multiplied, the mask decides what the softmax
+    sees — so it costs the dense walk's time."""
+    if selected:
+        sel_ref, out_ref, kv_scr, sems = refs
+    else:
+        out_ref, kv_scr, sems = refs
     i = pl.program_id(0)
     kv_len = meta_ref[0, i]
     qpos0 = meta_ref[1, i]
@@ -818,6 +836,12 @@ def _ragged_latent_kernel(tables_ref, meta_ref, layer_ref, q_ref, kv_hbm,
         s_idx = j * page + jax.lax.broadcasted_iota(
             jnp.int32, (1, page), 1)
         valid = (s_idx < kv_len) & (s_idx <= qpos) & q_ok
+        if selected:
+            # query t's row of the selection, for each of its H score rows
+            sel = sel_ref[0, :, pl.ds(pl.multiple_of(j * page, page), page)]
+            valid = valid & jnp.concatenate(
+                [jnp.broadcast_to(sel[t:t + 1] != 0, (H, page))
+                 for t in range(tq)], axis=0)
         scores = jax.lax.dot_general(                    # [tq·H, page]
             q, kv, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale
@@ -851,6 +875,7 @@ def ragged_attend_latent(
     v_lanes: int,
     scale: float,
     interpret: bool = False,
+    select: Optional[jax.Array] = None,   # [NB·tq, maxp·page] int32
 ) -> jax.Array:
     """Pallas latent ragged attention (contract of
     ``ragged_attend_latent_ref``; output in the queries' type). The pool is
@@ -861,7 +886,18 @@ def ragged_attend_latent(
     page = pool.shape[2]
     layer = jnp.asarray(layer, jnp.int32).reshape(1)
     kernel = functools.partial(_ragged_latent_kernel, page=page, tq=tq,
-                               v_lanes=v_lanes, scale=scale)
+                               v_lanes=v_lanes, scale=scale,
+                               selected=select is not None)
+    more_specs, more, params = [], [], {}
+    if select is not None:
+        S = select.shape[1]
+        more_specs = [pl.BlockSpec((1, tq, S), lambda i, *_: (i, 0, 0))]
+        more = [select.astype(jnp.int32).reshape(NB, tq, S)]
+        # 128 heads of 8 queries and the block's rows of the selection
+        # (0.5 MiB at 16k positions, twice) pass the 16 MiB that Mosaic
+        # scopes by default, by 68 KiB at DeepSeek-V3.2's widths
+        params = {"compiler_params": pltpu.CompilerParams(
+            vmem_limit_bytes=32 << 20)}
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -870,6 +906,7 @@ def ragged_attend_latent(
             in_specs=[
                 pl.BlockSpec((1, tq, H, lanes), lambda i, *_: (i, 0, 0, 0)),
                 pl.BlockSpec(memory_space=pl.ANY),      # pool stays in HBM
+                *more_specs,
             ],
             out_specs=[
                 pl.BlockSpec((1, tq, H, v_lanes),
@@ -883,14 +920,16 @@ def ragged_attend_latent(
         # pinned: the trace shows `%ragged_attend_latent.<n>`, which the
         # benchmark's `^%ragged_attend` patterns match
         name="ragged_attend_latent",
+        **params,
     )(row_tables.astype(jnp.int32), block_meta.astype(jnp.int32), layer,
-      q.astype(pool.dtype).reshape(NB, tq, H, lanes), pool)[0]
+      q.astype(pool.dtype).reshape(NB, tq, H, lanes), pool, *more)[0]
     return out.reshape(NB * tq, H, v_lanes)
 
 
 def ragged_attend_latent_auto(q, pool, row_tables, block_meta, layer, *,
                               tq: int, v_lanes: int, scale: float,
-                              interpret: Optional[bool] = None):
+                              interpret: Optional[bool] = None,
+                              select: Optional[jax.Array] = None):
     """Latent attention dispatcher: the Pallas kernel on TPU (or under
     ``interpret``), the XLA gather reference elsewhere. A pool whose lanes
     are no multiple of 128 (tiny test models) takes the reference."""
@@ -898,6 +937,140 @@ def ragged_attend_latent_auto(q, pool, row_tables, block_meta, layer, *,
     if (_on_tpu() or interpret) and aligned:
         return ragged_attend_latent(q, pool, row_tables, block_meta, layer,
                                     tq=tq, v_lanes=v_lanes, scale=scale,
-                                    interpret=bool(interpret))
+                                    interpret=bool(interpret), select=select)
     return ragged_attend_latent_ref(q, pool, row_tables, block_meta, layer,
-                                    tq=tq, v_lanes=v_lanes, scale=scale)
+                                    tq=tq, v_lanes=v_lanes, scale=scale,
+                                    select=select)
+
+
+# ---------------------------------------------------------------------------
+# INDEX scores: the cheap attention that selects a query's keys
+# ---------------------------------------------------------------------------
+#
+# A model with an indexer (models/config.IndexerConfig) keeps ONE index key
+# a token in a pool of its own, ``[L, n_pages, page, head_dim]`` under the
+# latent pool's page ids. A query's score of a visible position is
+# ``Σ_j w_j · ReLU(q_j · k)`` over the indexer's heads: the kernel below
+# walks a block's row exactly as the attention kernels do (same tables,
+# same block meta, one DMA a page of index keys — a fifth of a latent
+# page's bytes) and writes the float32 scores of the block's queries
+# against every position it walked, ``[NB·tq, maxp·page]``. Positions it
+# did not walk (past the block's last query) hold whatever the buffer
+# held: the caller masks by visibility before it selects.
+
+
+def index_scores_ref(
+    q: jax.Array,            # [NB·tq, Hi, di] the indexer's queries
+    w: jax.Array,            # [NB·tq, Hi] float32 head weights
+    pool: jax.Array,         # [L, n_pages, page, di] — the index-key pool
+    row_tables: jax.Array,   # [R, maxp] int32
+    block_meta: jax.Array,   # [4, NB] int32: kv_len, qpos0, nq, row
+    layer,                   # int32 scalar
+    tq: int,
+) -> jax.Array:
+    """XLA gather reference for ``index_scores`` (CPU serving path + the
+    kernel's oracle): [NB·tq, maxp·page] float32, every position of the
+    row's table scored (the kernel leaves those past the causal walk
+    unwritten)."""
+    tables = row_tables[block_meta[3]]                       # [NB, maxp]
+    NB, maxp = tables.shape
+    _, Hi, di = q.shape
+    page = pool.shape[2]
+    k = pool[layer, tables].reshape(NB, maxp * page, di)
+    dots = jnp.einsum("bthd,bsd->bths",
+                      q.astype(pool.dtype).reshape(NB, tq, Hi, di), k,
+                      preferred_element_type=jnp.float32)
+    sc = jnp.einsum("bths,bth->bts", jnp.maximum(dots, 0.0),
+                    w.astype(jnp.float32).reshape(NB, tq, Hi))
+    return sc.reshape(NB * tq, maxp * page)
+
+
+def _index_scores_kernel(tables_ref, meta_ref, layer_ref, q_ref, w_ref,
+                         k_hbm, out_ref, k_scr, sems, *, page: int,
+                         tq: int):
+    """One tq-token block's index scores over its row's visible pages."""
+    i = pl.program_id(0)
+    kv_len = meta_ref[0, i]
+    qpos0 = meta_ref[1, i]
+    nq = meta_ref[2, i]
+    row = meta_ref[3, i]
+    layer = layer_ref[0]
+    n = (jnp.minimum(kv_len, qpos0 + nq) + page - 1) // page
+    Hi, di = q_ref.shape[2], q_ref.shape[3]
+    q = q_ref[0].reshape(tq * Hi, di)                    # query-major rows
+    w = w_ref[0]                                         # [tq·Hi, 1]
+
+    def dma(j, slot):
+        return pltpu.make_async_copy(
+            k_hbm.at[layer, tables_ref[row, j]], k_scr.at[slot],
+            sems.at[slot])
+
+    @pl.when(n > 0)
+    def _():
+        dma(0, 0).start()
+
+    def body(j, carry):
+        slot = jax.lax.rem(j, 2)
+
+        @pl.when(j + 1 < n)
+        def _():
+            dma(j + 1, jax.lax.rem(j + 1, 2)).start()
+
+        dma(j, slot).wait()
+        dots = jax.lax.dot_general(                      # [tq·Hi, page]
+            q, k_scr[slot], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        heads = jnp.maximum(dots, 0.0) * w
+        out_ref[0, :, pl.ds(pl.multiple_of(j * page, page), page)] = \
+            jnp.concatenate(
+                [heads[t * Hi:(t + 1) * Hi].sum(axis=0, keepdims=True)
+                 for t in range(tq)], axis=0)            # [tq, page]
+        return carry
+
+    jax.lax.fori_loop(0, n, body, 0)
+
+
+@functools.partial(jax.jit, static_argnames=("tq", "interpret"))
+def index_scores(q, w, pool, row_tables, block_meta, layer, tq: int,
+                 interpret: bool = False) -> jax.Array:
+    """Pallas index scores (contract of ``index_scores_ref`` on the
+    positions a block can see). The pool stays in HBM, whole."""
+    Tp, Hi, di = q.shape
+    NB = block_meta.shape[1]
+    page = pool.shape[2]
+    S = row_tables.shape[1] * page
+    out = pl.pallas_call(
+        functools.partial(_index_scores_kernel, page=page, tq=tq),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,                 # tables, meta, layer
+            grid=(NB,),
+            in_specs=[
+                pl.BlockSpec((1, tq, Hi, di), lambda i, *_: (i, 0, 0, 0)),
+                pl.BlockSpec((1, tq * Hi, 1), lambda i, *_: (i, 0, 0)),
+                pl.BlockSpec(memory_space=pl.ANY),      # pool stays in HBM
+            ],
+            out_specs=[pl.BlockSpec((1, tq, S), lambda i, *_: (i, 0, 0))],
+            scratch_shapes=[pltpu.VMEM((2, page, di), pool.dtype),
+                            pltpu.SemaphoreType.DMA((2,))],
+        ),
+        out_shape=[jax.ShapeDtypeStruct((NB, tq, S), jnp.float32)],
+        interpret=interpret,
+        # a name `^%ragged_attend` does not match: the attention kernel
+        # stays the one such call a layer (the benchmark's step mark)
+        name="index_scores",
+    )(row_tables.astype(jnp.int32), block_meta.astype(jnp.int32),
+      jnp.asarray(layer, jnp.int32).reshape(1),
+      q.astype(pool.dtype).reshape(NB, tq, Hi, di),
+      w.astype(jnp.float32).reshape(NB, tq * Hi, 1), pool)[0]
+    return out.reshape(NB * tq, S)
+
+
+def index_scores_auto(q, w, pool, row_tables, block_meta, layer, *,
+                      tq: int, interpret: Optional[bool] = None):
+    """Index-score dispatcher: the Pallas kernel on TPU (or under
+    ``interpret``) where the key is whole lanes wide, else the reference."""
+    if (_on_tpu() or interpret) and pool.shape[-1] % 128 == 0:
+        return index_scores(q, w, pool, row_tables, block_meta, layer,
+                            tq=tq, interpret=bool(interpret))
+    return index_scores_ref(q, w, pool, row_tables, block_meta, layer,
+                            tq=tq)

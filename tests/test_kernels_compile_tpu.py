@@ -568,6 +568,87 @@ def test_latent_decode_program_leaves_the_pool_where_it_is(on_v5e,
     assert mem.temp_size_in_bytes < layer_elems * 2
 
 
+# --- a learned selection inside the latent attention (ISSUE 31) -------------
+
+@pytest.mark.parametrize("tq,nb", [(8, 128), (1, 8)],
+                         ids=["tq8-1k-chunk", "tq1-decode"])
+def test_selection_kernels_compile_at_v32_widths(on_v5e, tq, nb):
+    """DeepSeek-V3.2's geometry against a 128-page table (16k positions):
+    the scoring kernel (64 heads of 128 against ONE index key a token)
+    and the latent kernel with a per-query selection at 128 heads, which
+    needs more than Mosaic's default 16 MiB of scoped VMEM. One custom
+    call each; only the attention's name matches ``^%ragged_attend``."""
+    S = on_v5e
+    tables, meta = S((8, 128), jnp.int32), S((4, nb), jnp.int32)
+    scores = jax.jit(functools.partial(pa.index_scores, tq=tq)).lower(
+        S((nb * tq, 64, 128), jnp.bfloat16), S((nb * tq, 64), jnp.float32),
+        S((LAYERS, N_PAGES, PAGE, 128), jnp.bfloat16), tables, meta,
+        S((), jnp.int32)).compile().as_text()
+    attn = jax.jit(functools.partial(
+        pa.ragged_attend_latent, tq=tq, v_lanes=512, scale=0.13)).lower(
+        S((nb * tq, 128, 640), jnp.bfloat16),
+        S((LAYERS, N_PAGES, PAGE, 640), jnp.bfloat16), tables, meta,
+        S((), jnp.int32),
+        select=S((nb * tq, 128 * PAGE), jnp.int32)).compile().as_text()
+    for text, name in ((scores, "index_scores"),
+                       (attn, "ragged_attend_latent")):
+        call = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+        assert len(call) == 1
+        assert re.match(rf"\s*%{name}(\.\d+)? = ", call[0])
+    assert not re.match(r"\s*%ragged_attend", [
+        ln for ln in scores.splitlines() if "tpu_custom_call" in ln][0])
+
+
+def test_selecting_decode_program_leaves_both_pools_where_they_are(
+        on_v5e, monkeypatch):
+    """A model with an indexer on the v5e: the decode program's two layer
+    stacks and its loop carry TWO pools of unequal width in place (latent
+    rows, index keys): four kernels (a scoring and an attention call a
+    stack's body), nothing that moves a layer of either pool, both
+    donated into their outputs."""
+    from quoracle_tpu.models.config import (
+        IndexerConfig, LatentConfig, ModelConfig, MoEConfig,
+    )
+    from quoracle_tpu.models.generate import GenerateEngine
+    from quoracle_tpu.models.tokenizer import ByteTokenizer
+    from quoracle_tpu.models.transformer import init_params
+    monkeypatch.setattr(pa, "_on_tpu", lambda: True)
+    cfg = ModelConfig(
+        name="narrow-sparse-latent-moe", vocab_size=512, dim=256,
+        n_layers=3, n_heads=8, n_kv_heads=8, ffn_dim=512,
+        rope_scaling=("yarn", 40.0, 32.0, 1.0, 4096, 1.0, 1.0),
+        latent=LatentConfig(q_rank=128, kv_rank=512, nope_dim=128,
+                            rope_dim=64, v_dim=128),
+        moe=MoEConfig(n_routed=32, n_held=4, per_token=4, expert_dim=256,
+                      n_group=4, topk_group=2, routed_scale=2.5,
+                      first_dense=1, router_bias=True),
+        indexer=IndexerConfig(n_heads=8, head_dim=128, topk=256,
+                              rope_dim=64))
+    S = on_v5e
+    params = jax.tree.map(
+        lambda x: S(x.shape, x.dtype),
+        jax.eval_shape(lambda k: init_params(cfg, k), jax.random.PRNGKey(0)))
+    eng = GenerateEngine(cfg, params, ByteTokenizer(), max_seq=1024)
+    st = eng.sessions
+    assert cfg.kv_pools == (640, 128)
+    pools = [S((cfg.n_layers, st.n_pages, st.page, w), eng.pool_dtype)
+             for w in cfg.kv_pools]
+    tokens = st.n_pages * st.page
+    R, i32, f32 = 8, jnp.int32, jnp.float32
+    compiled = eng._step_paged_decode_ragged.lower(
+        params, *pools, None, None, S((R, 8), i32), S((R,), i32),
+        S((R,), i32), S((R, cfg.vocab_size), f32), S((2,), jnp.uint32),
+        S((R,), f32), S((R,), f32), S((R,), jnp.bool_), S((R,), i32),
+        None, None, max_new=32).compile()
+    hlo, mem = compiled.as_text(), compiled.memory_analysis()
+    assert hlo.count("tpu_custom_call") == 4
+    assert len(re.findall(r"%index_scores(\.\d+)? = ", hlo)) == 2
+    assert pool_moves(hlo, tokens * 640) == []
+    assert pool_moves(hlo, tokens * 128) == []
+    assert mem.alias_size_in_bytes >= cfg.n_layers * tokens * (640 + 128) * 2
+    assert mem.temp_size_in_bytes < tokens * 640 * 2
+
+
 # --- tp wrappers: shard_map around a pallas_call ----------------------------
 
 

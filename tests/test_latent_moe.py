@@ -184,7 +184,7 @@ def test_folded_attention_is_the_unfolded_one(toy):
     x = jnp.asarray(np.random.default_rng(3).normal(size=(1, T, cfg.dim)),
                     jnp.float32)
     with jax.default_matmul_precision("highest"):
-        q, row = tr._latent_qkv(x, p, cfg, jnp.arange(T)[None])
+        q, row, _, _ = tr._latent_qkv(x, p, cfg, jnp.arange(T)[None])
         assert row.shape == (T, 128) and q.shape == (T, 4, 128)
         assert bool(jnp.all(row[:, 40:] == 0)) and bool(jnp.all(
             q[..., 40:] == 0))                       # 32 + 8, then the pad
