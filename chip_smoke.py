@@ -308,7 +308,8 @@ def kernels_phase(engine) -> tuple[Phase, dict]:
     each device's kernel instance sees) and pool dtype, on seeded random
     K/V. The unified ragged kernel (every serving tick): the last 128
     query positions of one row against its whole 8k context (tq=8), and
-    one decode step of a full slot set at ragged lengths (tq=1). The flash
+    one decode step of a full slot set at ragged lengths (tq=1), alone and
+    with a quarter of each row's pages walked once for all. The flash
     kernel (the plain forward of ``reference_gap`` above 256 tokens, and
     the embedder): a 256-token chunk against a 2k cache.
 
@@ -362,25 +363,35 @@ def kernels_phase(engine) -> tuple[Phase, dict]:
             np.full(nb, kv_len), kv_len - (nb - np.arange(nb)) * RAGGED_TQ,
             np.full(nb, RAGGED_TQ), np.zeros(nb)]).astype(np.int32)
         tile = engine._ragged_tile
+        decode = np.stack([lens, lens - 1, np.ones(8), np.arange(8)])
+        # a shared prompt: every row's first 32 pages are row 0's
+        common = tables.copy()
+        common[:, :32] = tables[0, :32]
+        window = cfg.sliding_window
         cases = {
-            "ragged_tq8": (RAGGED_TQ, chunk, {}),
+            "ragged_tq8": (RAGGED_TQ, chunk, tables, window, {}),
             # the chunk forward's call: the same blocks, one walk a tile
-            "ragged_tile": (RAGGED_TQ, chunk, dict(
+            "ragged_tile": (RAGGED_TQ, chunk, tables, window, dict(
                 tile=tile, tiles=jnp.asarray(pa.ragged_tiles(
                     chunk, RAGGED_TQ, tile)))),
-            "ragged_tq1": (1, np.stack([lens, lens - 1, np.ones(8),
-                                        np.arange(8)]), {}),
+            "ragged_tq1": (1, decode, tables, window, {}),
+            # the decode program's call on those rows: the common pages
+            # walked once for all eight (without the window, under which
+            # nothing is shared)
+            "ragged_tq1_shared": (1, decode, common, None, dict(
+                shared=jnp.asarray(pa.shared_walks(common, lens - 1,
+                                                   page)))),
         }
-        for name, (tq, meta, tiled) in cases.items():
+        for name, (tq, meta, tabs, window, walk) in cases.items():
             q = jnp.asarray(rng.standard_normal((meta.shape[1] * tq, H,
                                                  hd)), jnp.bfloat16)
-            args = (q, kp, vp, jnp.asarray(tables),
+            args = (q, kp, vp, jnp.asarray(tabs),
                     jnp.asarray(meta.astype(np.int32)), 1)
-            out = pa.ragged_attend(*args, tq=tq, **tiled,
-                                   sliding_window=cfg.sliding_window)
+            out = pa.ragged_attend(*args, tq=tq, **walk,
+                                   sliding_window=window)
             with jax.default_matmul_precision("highest"):
                 ref = pa.ragged_attend_ref(
-                    *args, tq=tq, sliding_window=cfg.sliding_window)
+                    *args, tq=tq, sliding_window=window)
             compare(name, out, ref)
 
     B, T, S = 2, 256, 2048

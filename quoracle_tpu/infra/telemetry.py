@@ -724,6 +724,13 @@ SPARSE_ATTN_PAIRS_TOTAL = METRICS.counter(
     "and decode steps, per model: kind = visible under the causal mask "
     "(each is scored by the indexer), kind = selected the softmax ran over "
     "(min(visible, topk) a query)")
+# -- the decode program's shared walk (ISSUE 32) ------------------------------
+ATTN_SHARED_KV_TOKENS_TOTAL = METRICS.counter(
+    "quoracle_attn_shared_kv_tokens_total",
+    "resident tokens of pages that rows of a decode tick have in common "
+    "(ops/paged_attention.shared_walks), a layer, per model: kind = needed "
+    "what the rows needed of them (rows x pages x page x steps), kind = "
+    "walked what the shared walks brought into VMEM (once a group a step)")
 # -- the batcher's tick record (ISSUE 24) -----------------------------------
 # One record per ContinuousBatcher._loop iteration, built on the worker
 # thread where the work happens (models/scheduler.py, models/generate.py).

@@ -273,6 +273,7 @@ def decode_ragged(
     interpret: Optional[bool] = None,
     k_scale: Optional[jax.Array] = None,   # [L, n_pages, KV, page] f32 —
     v_scale: Optional[jax.Array] = None,   # int8 pools (ISSUE 13)
+    shared: Optional[jax.Array] = None,    # [2 + SHARED_ROWS, R] int32
 ) -> tuple:
     """Autoregressive decode through the UNIFIED ragged kernel (ISSUE 8):
     same sampling/grammar semantics as decode(), but each
@@ -281,7 +282,12 @@ def decode_ragged(
     off the pages. Neither the [B, maxp·page] working cache nor the
     [L, B, max_new] tail buffer exists; decode HBM high-water is the pool
     itself. Every step is one tq=1-block-per-row launch per layer of the
-    same kernel that served the mixed prefill chunk.
+    same kernel that served the mixed prefill chunk. ``shared``
+    (ops/paged_attention.shared_walks of ``tables`` and ``pool_lens``)
+    tells that kernel which rows' tables begin with the same pages: it
+    reads those once a step for all of them. The table holds for the whole
+    loop — a shared page is full before the first step, and every step
+    writes behind it.
 
     The pools are loop-carried and every step updates them IN PLACE: the
     forward scatters the step's R rows into the carried buffers and gives
@@ -335,7 +341,7 @@ def decode_ragged(
         hidden, kp, vp, ks, vs, st = forward_hidden_ragged(
             params, cfg, cur[None], positions[None], kp, vp, tables,
             meta, flat, tq=1, interpret=interpret, shard=shard,
-            k_scale=ks, v_scale=vs)
+            k_scale=ks, v_scale=vs, shared=shared)
         if st is not None:
             moe = moe + st
         logits = project_logits(params, cfg, hidden)[0]      # [R, V]
@@ -1507,7 +1513,7 @@ class GenerateEngine:
         @functools.partial(jax.jit, donate_argnums=(1, 2, 3, 4),
                            static_argnames=("max_new",))
         def step_paged_decode_ragged(params, k_pool, v_pool, k_scale,
-                                     v_scale, tables,
+                                     v_scale, tables, shared,
                                      pool_lens, kv_off, last_logits, rng,
                                      temperature, top_p, active,
                                      row_limit, json_table, json_state,
@@ -1515,14 +1521,16 @@ class GenerateEngine:
             # Decode continuation of the unified tick: KV written straight
             # to pages inside the loop (no tail buffer, no tail scatter);
             # attention is the same ragged kernel at tq=1 (int8 pools
-            # quantize each step's token on write).
+            # quantize each step's token on write), told by ``shared``
+            # which rows' leading pages to read once for all of them.
             return decode_ragged(
                 params, cfg, k_pool, v_pool, tables, pool_lens, kv_off,
                 last_logits, rng, temperature, top_p, max_new,
                 cfg.eos_token_id, active=active, row_limit=row_limit,
                 pad_id=self.tokenizer.pad_id, stop_ids=cfg.stop_token_ids,
                 json_table=json_table, json_state=json_state,
-                shard=ragged_shard, k_scale=k_scale, v_scale=v_scale)
+                shard=ragged_shard, k_scale=k_scale, v_scale=v_scale,
+                shared=shared)
 
         self._step_paged_ragged = step_paged_ragged
         self._step_paged_ragged_verify = step_paged_ragged_verify
@@ -2759,6 +2767,7 @@ class GenerateEngine:
         first ``n`` slots are the batch rows in order."""
         from quoracle_tpu.ops.paged_attention import (
             ragged_tile_slots, ragged_tile_walk, ragged_tiles,
+            shared_walk_tokens, shared_walks,
         )
         tick_phase("pack")
         st = self.sessions
@@ -2838,6 +2847,12 @@ class GenerateEngine:
             walked = ragged_tiles(bmeta, TQ, tile,
                                   ragged_tile_slots(NB, R, TQ, tile))
             tiles = jnp.asarray(walked)
+        # ... and the decode steps' one-token rows: which of them have
+        # leading pages in common, read once a step for all of them
+        # (a latent pool's kernel has no such walk)
+        shared = shared_walks(r_tables, r_pool_lens, page,
+                              self.cfg.sliding_window) \
+            if self.cfg.plain and verify is None else None
 
         if verify is not None:
             self._pending.shape_key = ("ragged_verify", TB, R, maxp_p2,
@@ -2884,6 +2899,7 @@ class GenerateEngine:
             self._step_paged_decode_ragged(
                 self.params, st.k, st.v, st.k_scale, st.v_scale,
                 jnp.asarray(r_tables),
+                None if shared is None else jnp.asarray(shared),
                 jnp.asarray(r_pool_lens), jnp.asarray(r_off), last_logits,
                 rng_key, jnp.asarray(r_temp), jnp.asarray(r_top),
                 jnp.asarray(r_active), jnp.asarray(r_limits), json_table,
@@ -2908,16 +2924,33 @@ class GenerateEngine:
         # ... and what its programs did bring into VMEM: the pages each
         # tile of the chunk forward walked, and a row's pages once a
         # decode step (forward j of a row is a one-token tile that sees
-        # ctx + j tokens). streamed / reads is how many times a needed
-        # token was fetched.
+        # ctx + j tokens) — but for the leading pages a shared walk
+        # covers, which come in once a step for all of its rows.
+        # streamed / reads is how many times a needed token was fetched.
         steps = np.arange(1, int(fwd.max(initial=0)) + 1)
         seen = ctx[:, None] + steps
-        streamed, n_tiles = ragged_tile_walk(np.concatenate(
-            [walked[:3], np.stack([seen, seen - 1, steps <= fwd[:, None]]
-                                  ).reshape(3, -1)], axis=1),
-            page, self.cfg.sliding_window)
+        decode = np.stack([seen, seen - 1, steps <= fwd[:, None]])
+        skip = np.zeros((n,), np.int64) if shared is None else shared[0, :n]
+        streamed, n_tiles = (a + b for a, b in zip(
+            ragged_tile_walk(walked, page, self.cfg.sliding_window),
+            ragged_tile_walk(decode, page, self.cfg.sliding_window,
+                             skip=skip[:, None])))
         kv_reads = int(ctx.sum()) + dec
         pairs = int((seg * (ctx - seg) + seg * (seg + 1) // 2).sum()) + dec
+        if shared is not None:
+            from quoracle_tpu.infra.telemetry import (
+                ATTN_SHARED_KV_TOKENS_TOTAL,
+            )
+            needed, shared_in = shared_walk_tokens(shared, fwd, page)
+            streamed += shared_in
+            tick_note(attn_shared_rows=int((skip > 0).sum()),
+                      attn_shared_pages=int(skip[shared[1, :n] > 0].sum()))
+            L = self.cfg.n_layers
+            ATTN_SHARED_KV_TOKENS_TOTAL.inc(needed * L, model=self.cfg.name,
+                                            kind="needed")
+            ATTN_SHARED_KV_TOKENS_TOTAL.inc(shared_in * L,
+                                            model=self.cfg.name,
+                                            kind="walked")
         tick_note(attn_kv_reads=kv_reads, attn_pairs=pairs,
                   attn_kv_streamed=streamed, attn_tiles=n_tiles)
         if self.cfg.indexer is not None:

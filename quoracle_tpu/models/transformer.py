@@ -793,6 +793,7 @@ def forward_hidden_ragged(
     v_scale: Optional[jax.Array] = None,   # per-(token, kv-head) scales
     tiles: Optional[jax.Array] = None,     # [6, NT] int32: the blocks in
     tile: int = 0,                         # tiles of <= ``tile`` tokens
+    shared: Optional[jax.Array] = None,    # [2 + SHARED_ROWS, R] int32
 ) -> tuple:
     """UNIFIED ragged forward (ISSUE 8): one launch per layer over a
     token-major flattened batch of rows with arbitrary query lengths —
@@ -826,11 +827,14 @@ def forward_hidden_ragged(
 
     With ``tiles`` (ops/paged_attention.ragged_tiles of ``block_meta``)
     the dense kernel walks a row's pages once per tile of its queries
-    and not once per block: a schedule, not a layout — nothing else here
-    reads it."""
+    and not once per block; with ``shared`` (``shared_walks`` of
+    ``row_tables``; the decode step, one token a row) it walks the pages
+    that rows have in common once for all of them: schedules, not layouts
+    — nothing else here reads either."""
     if not cfg.plain:
-        assert shard is None and k_scale is None and tiles is None, \
-            "latent/expert models: no tp shards, no int8 pages, no tiles"
+        assert shard is None and k_scale is None and tiles is None \
+            and shared is None, "latent/expert models: no tp shards, " \
+            "no int8 pages, no tiles, no shared walk"
         return _forward_hidden_ragged_stacks(
             params, cfg, tokens, positions, k_pool, v_pool, row_tables,
             block_meta, flat_dst, tq, interpret)
@@ -876,7 +880,7 @@ def forward_hidden_ragged(
                 q[0], kp, vp, row_tables, block_meta, layer, tq=tq,
                 sliding_window=cfg.sliding_window, interpret=interpret,
                 shard=shard, k_scale=ks, v_scale=vs, tiles=tiles,
-                tile=tile)[None]                            # [1,Tp,H,hd]
+                tile=tile, shared=shared)[None]             # [1,Tp,H,hd]
         x = _attn_out(x, attn.astype(x.dtype), p, cfg)
         x = _mlp(x, p, cfg)
         return (x, kp, vp, ks, vs), None

@@ -149,16 +149,20 @@ def _attend_page(q, k, v, ks, vs, valid, m, l, acc):
     the head's G query heads a token), ``valid`` [rows, page]; ``k``/``v``
     give the page's [page, hd] float32 blocks and ``ks``/``vs`` an int8
     page's [1, page] scales (else None), each a function called where its
-    value is used: one made early would live across the softmax. Returns
+    value is used: one made early would live across the softmax. ``valid``
+    None: every row sees the whole page (a shared walk's pages). Returns
     the updated (m, l, acc)."""
     scores = jax.lax.dot_general(                        # [rows, page]
         q, k(), (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32)
     if ks is not None:
         scores = scores * ks()                           # dequant K
-    scores = jnp.where(valid, scores, NEG_INF)
+    if valid is not None:
+        scores = jnp.where(valid, scores, NEG_INF)
     m_new = jnp.maximum(m, jnp.max(scores, axis=1, keepdims=True))
-    p = jnp.where(valid, jnp.exp(scores - m_new), 0.0)
+    p = jnp.exp(scores - m_new)
+    if valid is not None:
+        p = jnp.where(valid, p, 0.0)
     corr = jnp.exp(m - m_new)
     l_new = l * corr + jnp.sum(p, axis=1, keepdims=True)
     if vs is not None:
@@ -169,9 +173,9 @@ def _attend_page(q, k, v, ks, vs, valid, m, l, acc):
     return m_new, l_new, acc * corr + pv
 
 
-def _ragged_kernel(tables_ref, meta_ref, layer_ref, q_ref, k_hbm, v_hbm,
-                   *refs, page: int, n_kv: int, hd: int, tq: int,
-                   scale: float, window: int, quant: bool):
+def _ragged_kernel(tables_ref, meta_ref, layer_ref, *refs, page: int,
+                   n_kv: int, hd: int, tq: int, scale: float, window: int,
+                   quant: bool, shared: bool = False):
     """One tq-token block of the flattened batch: stream the owning row's
     VISIBLE pages through VMEM double-buffered (kv heads flattened into
     the lane dim) and write the NORMALIZED attention output for the
@@ -192,13 +196,23 @@ def _ragged_kernel(tables_ref, meta_ref, layer_ref, q_ref, k_hbm, v_hbm,
     dequant happens inside the streaming loop with zero lane transposes:
     K's per-token scale multiplies the score columns
     (``q·(k·s) = (q·k)·s``) and V's multiplies the probability columns
-    (``(p·s)·v = p·(v·s)``), both as a ``[1, page]`` lane broadcast."""
+    (``(p·s)·v = p·(v·s)``), both as a ``[1, page]`` lane broadcast.
+
+    ``shared`` (the decode program, tq = 1, block i = row i): one more
+    prefetched table, ``shared_ref [2 + SHARED_ROWS, R]`` (``shared_walks``),
+    q a second time whole in HBM, and the shared walk's scratch; see the
+    section "The SHARED walk" below."""
+    if shared:
+        shared_ref, q_ref, q_hbm, k_hbm, v_hbm, *refs = refs
+    else:
+        q_ref, k_hbm, v_hbm, *refs = refs
     if quant:
-        ks_hbm, vs_hbm, out_ref, k_scr, v_scr, ks_scr, vs_scr, sems = refs
+        ks_hbm, vs_hbm, out_ref, k_scr, v_scr, ks_scr, vs_scr, sems, \
+            *walk_scr = refs
         streams = ((k_hbm, k_scr), (v_hbm, v_scr),
                    (ks_hbm, ks_scr), (vs_hbm, vs_scr))
     else:
-        out_ref, k_scr, v_scr, sems = refs
+        out_ref, k_scr, v_scr, sems, *walk_scr = refs
         streams = ((k_hbm, k_scr), (v_hbm, v_scr))
     i = pl.program_id(0)
     kv_len = meta_ref[0, i]
@@ -206,28 +220,62 @@ def _ragged_kernel(tables_ref, meta_ref, layer_ref, q_ref, k_hbm, v_hbm,
     nq = meta_ref[2, i]
     row = meta_ref[3, i]
     layer = layer_ref[0]
+    H = q_ref.shape[2]
+    G = H // n_kv
+
+    def page_dmas(row, j, slot):
+        pid = tables_ref[row, j]
+        return [pltpu.make_async_copy(hbm.at[layer, pid], scr.at[slot],
+                                      sems.at[slot, s])
+                for s, (hbm, scr) in enumerate(streams)]
+
     # last visible key + 1: nothing past the block's last query is visible
     kv_hi = jnp.minimum(kv_len, qpos0 + nq)
     if window >= 0:
         p_lo = jnp.maximum(qpos0 + 1 - window, 0) // page
     else:
         p_lo = jnp.int32(0)
-    n = jnp.maximum((kv_hi + page - 1) // page - p_lo, 0)
+    carried = None
+    if shared and window < 0:
+        # pages a shared walk covers for this row: its own walk starts
+        # behind them, from the state the walk left for it. (A window's
+        # first page differs by row: nothing is shared, whatever the table.)
+        p_lo = shared_ref[0, i]
+        m_st, l_st, acc_st = walk_scr[5:8]       # the rows' parked state
+        members = [shared_ref[2 + k, i] for k in range(SHARED_ROWS)]
+        live = meta_ref[2, members[0]]
+        for r in members[1:]:
+            live = jnp.maximum(live, meta_ref[2, r])
 
-    q = q_ref[0].astype(jnp.float32) * scale             # [tq, H, hd]
-    H = q.shape[1]
-    G = H // n_kv
+        def kv_blocks(slot, kv):
+            """What ``_attend_page`` reads of the page in ``slot`` for one
+            kv head: (k, v, k's scales, v's scales)."""
+            lanes = slice(kv * hd, (kv + 1) * hd)
+            return (lambda: k_scr[slot, :, lanes].astype(jnp.float32),
+                    lambda: v_scr[slot, :, lanes].astype(jnp.float32),
+                    (lambda: ks_scr[slot, kv:kv + 1, :]) if quant else None,
+                    (lambda: vs_scr[slot, kv:kv + 1, :]) if quant else None)
+
+        @pl.when((shared_ref[1, i] > 0) & (live > 0))
+        def _():
+            _shared_walk(members, p_lo, functools.partial(page_dmas, row),
+                         kv_blocks, q_hbm, walk_scr, n_kv=n_kv, G=G,
+                         scale=scale)
+
+        carried = (p_lo > 0) & (nq > 0)
+    # a block with no query (padding; a row that is done) walks nothing
+    n = jnp.where(nq > 0,
+                  jnp.maximum((kv_hi + page - 1) // page - p_lo, 0), 0)
 
     def dmas(j, slot):
-        pid = tables_ref[row, p_lo + j]
-        return [pltpu.make_async_copy(hbm.at[layer, pid], scr.at[slot],
-                                      sems.at[slot, s])
-                for s, (hbm, scr) in enumerate(streams)]
+        return page_dmas(row, p_lo + j, slot)
 
     @pl.when(n > 0)
     def _():
         for d in dmas(0, 0):
             d.start()
+
+    q = q_ref[0].astype(jnp.float32) * scale             # [tq, H, hd]
 
     # per-score-row query index (tq·G rows, query-major like the prefill
     # kernel) → buffer position and validity shared by every kv head.
@@ -267,11 +315,26 @@ def _ragged_kernel(tables_ref, meta_ref, layer_ref, q_ref, k_hbm, v_hbm,
                 valid, *carry[kv]))
         return tuple(out)
 
-    init = tuple((jnp.full((tq * G, 1), NEG_INF, jnp.float32),
-                  jnp.zeros((tq * G, 1), jnp.float32),
-                  jnp.zeros((tq * G, hd), jnp.float32))
-                 for _ in range(n_kv))
-    final = jax.lax.fori_loop(0, n, body, init)
+    def fresh():
+        return (jnp.full((tq * G, 1), NEG_INF, jnp.float32),
+                jnp.zeros((tq * G, 1), jnp.float32),
+                jnp.zeros((tq * G, hd), jnp.float32))
+
+    def init(kv):
+        if carried is None:
+            return fresh()
+        # m and l are parked one value a row in all 128 lanes and come
+        # back through a lane reduction, as the loop's own row maxima and
+        # sums do: a column loaded as such would be re-laid every page
+        # (+10% a call at Qwen's widths, +19% at Mistral's, on the chip)
+        at = i * n_kv + kv
+        parked = (jnp.max(m_st[at], axis=1, keepdims=True),
+                  jnp.max(l_st[at], axis=1, keepdims=True), acc_st[at])
+        return tuple(jnp.where(carried, st, new)
+                     for st, new in zip(parked, fresh()))
+
+    final = jax.lax.fori_loop(0, n, body,
+                              tuple(init(kv) for kv in range(n_kv)))
     for kv in range(n_kv):
         _, l, acc = final[kv]
         norm = acc / jnp.where(l > 0, l, 1.0)
@@ -294,6 +357,7 @@ def ragged_attend(
     v_scale: Optional[jax.Array] = None,
     tiles: Optional[jax.Array] = None,     # [6, NT] int32 (ragged_tiles)
     tile: int = 0,                         # tokens a tile holds at most
+    shared: Optional[jax.Array] = None,    # [2 + SHARED_ROWS, R] int32
 ) -> jax.Array:
     """Pallas unified ragged attention (same contract as ragged_attend_ref;
     tests/test_ragged_attention.py asserts numerical agreement). Grid is
@@ -306,7 +370,10 @@ def ragged_attend(
     indexes ``layer`` itself, so nothing of a pool's size is sliced,
     reshaped or copied on the way in. With ``k_scale``/``v_scale`` the
     kernel streams each int8 page's scale block alongside its payload and
-    dequantizes in-loop."""
+    dequantizes in-loop. With ``shared`` (``shared_walks`` of the tables;
+    the decode program's call, tq = 1 and block i row i's) rows whose
+    tables begin alike have their common pages walked once between them
+    (section "The SHARED walk"): the same output, fewer page reads."""
     Tp, H, hd = q.shape
     NB = block_meta.shape[1]
     _, n_pages, page, lanes = k_pool.shape
@@ -336,6 +403,7 @@ def ragged_attend(
         scratch += [pltpu.VMEM((2, KV, page), jnp.float32)] * 2
     window = -1 if sliding_window is None else int(sliding_window)
     if tiles is not None:
+        assert shared is None, "a tile's queries are one row's"
         state = (KV, tile * (H // KV))   # kv-head-major, query-major rows
         out = pl.pallas_call(
             functools.partial(
@@ -367,22 +435,42 @@ def ragged_attend(
     qb = q.reshape(NB, tq, H, hd_p)
     kernel = functools.partial(
         _ragged_kernel, page=page, n_kv=KV, hd=hd_p, tq=tq,
-        scale=hd ** -0.5, quant=quant, window=window)
+        scale=hd ** -0.5, quant=quant, window=window,
+        shared=shared is not None)
+    prefetch = [row_tables.astype(jnp.int32), block_meta.astype(jnp.int32),
+                layer.reshape(1)]
+    more_in, more_scr = [], []
+    if shared is not None:
+        R, G = row_tables.shape[0], H // KV
+        assert tq == 1 and NB == R and shared.shape == (2 + SHARED_ROWS, R)
+        prefetch.append(shared.astype(jnp.int32))
+        more_in = [qb]                             # whole, for the gather
+        walk = (KV, SHARED_ROWS * G)               # member-major score rows
+        more_scr = [pltpu.VMEM((SHARED_ROWS, tq, H, hd_p), q.dtype),
+                    pltpu.VMEM(walk + (hd_p,), jnp.float32),
+                    pltpu.VMEM(walk + (1,), jnp.float32),
+                    pltpu.VMEM(walk + (1,), jnp.float32),
+                    pltpu.VMEM(walk + (hd_p,), jnp.float32),
+                    pltpu.VMEM((R * KV, G, 128), jnp.float32),
+                    pltpu.VMEM((R * KV, G, 128), jnp.float32),
+                    pltpu.VMEM((R * KV, G, hd_p), jnp.float32),
+                    pltpu.SemaphoreType.DMA((1,))]
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,                 # tables, meta, layer
+            num_scalar_prefetch=len(prefetch),     # tables, meta, layer …
             grid=(NB,),
             in_specs=[
                 pl.BlockSpec((1, tq, H, hd_p), lambda i, *_: (i, 0, 0, 0)),
                 *[pl.BlockSpec(memory_space=pl.ANY)       # pools stay in HBM
-                  for _ in pools],
+                  for _ in more_in + pools],
             ],
             out_specs=[
                 pl.BlockSpec((1, tq, H, hd_p), lambda i, *_: (i, 0, 0, 0)),
             ],
             scratch_shapes=[*scratch,
-                            pltpu.SemaphoreType.DMA((2, len(pools)))],
+                            pltpu.SemaphoreType.DMA((2, len(pools))),
+                            *more_scr],
         ),
         out_shape=[
             jax.ShapeDtypeStruct((NB, tq, H, hd_p), jnp.float32),
@@ -391,8 +479,7 @@ def ragged_attend(
         # pinned: the trace shows the kernel as `%ragged_attend.<n>`, and
         # the benchmark's metric files match on that name
         name="ragged_attend",
-    )(row_tables.astype(jnp.int32), block_meta.astype(jnp.int32),
-      layer.reshape(1), qb, *pools)[0]
+    )(*prefetch, qb, *more_in, *pools)[0]
     return out.reshape(NB * tq, H, hd_p)[..., :hd]
 
 
@@ -479,15 +566,17 @@ def ragged_tiles(block_meta, tq: int, tile: int, slots: int = 0):
     return tiles
 
 
-def ragged_tile_walk(tiles, page: int, sliding_window=None) -> tuple:
+def ragged_tile_walk(tiles, page: int, sliding_window=None,
+                     skip=0) -> tuple:
     """(resident tokens the kernel's programs bring into VMEM, programs
     that walk pages) for a tile table: Σ over live tiles of visible pages
     × page. With the block table as its own tile table (``tile`` = tq) it
-    prices the walk of one program per block."""
+    prices the walk of one program per block. ``skip``: leading pages a
+    tile's own walk leaves to a shared one (a count, or one a tile)."""
     kv_len, qpos0, nq = np.asarray(tiles, np.int64)[:3]
     live = nq > 0
     hi = -(-np.minimum(kv_len, qpos0 + nq) // page)
-    lo = 0 if sliding_window is None else \
+    lo = skip if sliding_window is None else \
         np.maximum(qpos0 + 1 - sliding_window, 0) // page
     return (int((np.maximum(hi - lo, 0) * live).sum()) * page,
             int(live.sum()))
@@ -663,6 +752,179 @@ def _ragged_tile_kernel(tables_ref, tiles_ref, layer_ref, q_hbm, k_hbm,
             attend(height)
 
 
+# ---------------------------------------------------------------------------
+# The SHARED walk (ISSUE 32): common leading pages are read once a step
+# ---------------------------------------------------------------------------
+#
+# The decode program's call is one program a row (tq = 1), each walking ITS
+# row's table. Rows that adopted one prompt from the prefix cache hold the
+# same page ids at the head of their tables, so 8 agents on a 10k-token
+# prompt brought the same 80 pages into VMEM 8 times a layer a step. The
+# host finds such rows from the tick's tables alone (``shared_walks``):
+#
+#   shared[0, r]      pages of r's table that a shared walk covers (0: none)
+#   shared[1, r]      1 where r LEADS a walk: the lowest row of its group,
+#                     so the grid reaches it before the others
+#   shared[2 + k, r]  the rows of the walk r leads, SHARED_ROWS of them
+#                     (short groups repeat the leader, which costs a
+#                     duplicate score row and writes the same state twice)
+#
+# The leader's program gathers its group's queries from HBM, streams the
+# common pages through the kernel's own double buffer ONCE and multiplies
+# each against all of them (SHARED_ROWS·G score rows a kv head), every
+# query row with its own float32 online-softmax state, as the tile kernel
+# does for the queries of one row; it leaves each member's (m, l, acc) in
+# VMEM scratch that outlives the program. Every row's program — the
+# leader's too — then walks only the pages behind the shared ones and
+# starts from that state instead of (−inf, 0, 0): the same pages in the same
+# order through the same arithmetic for every query, the state merely
+# parked between two programs. Every shared page is full and wholly
+# visible for every member (``shared_walks`` caps the count at the rows'
+# whole pages before the loop), so the walk masks nothing.
+#
+# A row in no group reads shared[0, r] = 0 and runs the program it always
+# ran; a tick with no group runs no walk. One compiled kernel either way.
+
+SHARED_ROWS = 8              # rows one shared walk serves at most
+# Common pages below which a walk does not pay: what it adds (the gathered
+# queries' DMA, a pipeline start of its own, the parked state) is 2–3 µs a
+# call on the chip, and a PAIR of rows gets that back between 4 common
+# pages (20.1 µs a call for 19.2 unshared at Qwen's widths, 30.6 for 29.7 at
+# Mistral's) and 8 (21.3 for 22.7; 34.7 for 37.1): PERF.md §6, PR 32.
+SHARED_MIN_PAGES = 6
+
+
+def shared_walks(tables, pool_lens, page: int, sliding_window=None):
+    """The decode program's shared-walk table [2 + SHARED_ROWS, R] (numpy,
+    host side; layout in the section comment) from the tick's row tables
+    [R, maxp] and the rows' resident tokens [R] alone. Rows whose tables
+    BEGIN with the same page ids form a group of 2..SHARED_ROWS rows; its
+    walk covers their common leading pages, at most the fewest WHOLE pages
+    any of them holds now — the decode loop writes behind those. Groups are
+    runs of the rows sorted by table (neighbours share the most), cut where
+    the pages saved, Σ (rows − 1) × common, are most. A row slot that holds
+    nothing (a zero table) joins no group; under a sliding window nothing
+    is shared."""
+    tables = np.asarray(tables)
+    full = np.asarray(pool_lens) // page
+    R = full.shape[0]
+    out = np.zeros((2 + SHARED_ROWS, R), np.int32)
+    out[2:] = np.arange(R)
+    if sliding_window is not None:
+        return out
+    rows = np.flatnonzero(full >= SHARED_MIN_PAGES)
+    if rows.size < 2:
+        return out
+    rows = rows[np.lexsort(tables[rows].T[::-1])]
+    t, f = tables[rows], full[rows]
+    differ = t[1:] != t[:-1]
+    lcp = np.where(differ.any(axis=1), differ.argmax(axis=1), t.shape[1])
+    lcp = np.minimum(lcp, np.minimum(f[1:], f[:-1])).tolist()
+    if max(lcp) < SHARED_MIN_PAGES:
+        return out
+    # best[j]: most page reads saved among the first j sorted rows;
+    # cut[j]: the group that ends there, (rows, common pages), if one does
+    n = rows.size
+    best, cut = [0] * (n + 1), [None] * (n + 1)
+    for j in range(2, n + 1):
+        best[j] = best[j - 1]
+        common = lcp[j - 2]
+        for size in range(2, min(SHARED_ROWS, j) + 1):
+            common = min(common, lcp[j - size])
+            if common < SHARED_MIN_PAGES:
+                break
+            saved = best[j - size] + (size - 1) * common
+            if saved > best[j]:
+                best[j], cut[j] = saved, (size, common)
+    j = n
+    while j > 0:
+        if cut[j] is None:
+            j -= 1
+            continue
+        size, common = cut[j]
+        members = np.sort(rows[j - size:j])
+        out[0, members] = common
+        out[1, members[0]] = 1
+        out[2:2 + size, members[0]] = members
+        j -= size
+    return out
+
+
+def shared_walk_tokens(shared, forwards, page: int) -> tuple:
+    """(resident tokens the rows of a tick needed from shared pages, tokens
+    the shared walks brought into VMEM for them) over a decode loop in
+    which row r ran ``forwards[r]`` steps: a walk runs in every step that
+    one of its rows does."""
+    shared = np.asarray(shared)
+    forwards = np.asarray(forwards, np.int64)
+    n = forwards.shape[0]
+    needed = int((shared[0, :n] * forwards).sum()) * page
+    walked = sum(int(shared[0, r]) * int(forwards[shared[2:, r]].max())
+                 for r in np.flatnonzero(shared[1, :n])) * page
+    return needed, walked
+
+
+def _shared_walk(members, n_pages, dmas, kv_blocks, q_hbm, scratch, *,
+                 n_kv: int, G: int, scale: float):
+    """The walk a leader's program makes for its group (section comment):
+    ``members`` SHARED_ROWS row indices (scalars), ``n_pages`` > 0 common
+    pages, ``dmas(j, slot)`` the copies of page j, ``kv_blocks(slot, kv)``
+    what ``_attend_page`` reads of it. ``scratch``, as ``ragged_attend``
+    lists it: qg_scr [SHARED_ROWS, 1, H, hd] the members' queries as they
+    arrive, qf_scr [n_kv, SHARED_ROWS·G, hd] the same scaled to float32,
+    member-major rows a kv head; (m, l, acc) in that layout while the walk
+    runs; the same PARKED by (row, kv head), [R·n_kv, G, ·], where each
+    member's program finds it (m and l in every lane of 128); the
+    queries' DMA semaphore."""
+    qg_scr, qf_scr, *state, q_sem = scratch
+    state, parked = state[:3], state[3:]
+    M = len(members) * G
+
+    def q_in(k):
+        return pltpu.make_async_copy(q_hbm.at[members[k]], qg_scr.at[k],
+                                     q_sem.at[0])
+
+    for k in range(len(members)):
+        q_in(k).start()
+    for d in dmas(0, 0):
+        d.start()
+    for k in range(len(members)):
+        q_in(k).wait()
+    for k in range(len(members)):
+        qk = qg_scr[k].astype(jnp.float32) * scale       # [1, H, hd]
+        for kv in range(n_kv):
+            qf_scr[kv, k * G:(k + 1) * G, :] = \
+                qk[:, kv * G:(kv + 1) * G].reshape(G, qk.shape[-1])
+    m_scr, l_scr, acc_scr = state
+    for kv in range(n_kv):
+        m_scr[kv] = jnp.full((M, 1), NEG_INF, jnp.float32)
+        l_scr[kv] = jnp.zeros((M, 1), jnp.float32)
+        acc_scr[kv] = jnp.zeros(acc_scr.shape[1:], jnp.float32)
+
+    def walk(j, carry):
+        slot = jax.lax.rem(j, 2)
+
+        @pl.when(j + 1 < n_pages)
+        def _():
+            for d in dmas(j + 1, jax.lax.rem(j + 1, 2)):
+                d.start()
+
+        for d in dmas(j, slot):
+            d.wait()
+        for kv in range(n_kv):
+            m_scr[kv], l_scr[kv], acc_scr[kv] = _attend_page(
+                qf_scr[kv], *kv_blocks(slot, kv), None,
+                m_scr[kv], l_scr[kv], acc_scr[kv])
+        return carry
+
+    jax.lax.fori_loop(0, n_pages, walk, 0)
+    for k, r in enumerate(members):
+        for kv in range(n_kv):
+            for scr, st in zip(state, parked):
+                rows = scr[kv, k * G:(k + 1) * G, :]
+                st[r * n_kv + kv] = jnp.broadcast_to(rows, st.shape[1:])
+
+
 def ragged_attend_auto(
     q: jax.Array,            # [NB·tq, H, hd]
     k_pool: jax.Array,       # [L, n_pages, page, KV·hd] — the stored pool
@@ -678,6 +940,8 @@ def ragged_attend_auto(
     v_scale: Optional[jax.Array] = None,   # int8 pools (ISSUE 13)
     tiles: Optional[jax.Array] = None,     # [6, NT]: the tile kernel's
     tile: int = 0,                         # schedule (ragged_tiles)
+    shared: Optional[jax.Array] = None,    # [2 + SHARED_ROWS, R]: the
+                                           # decode call's (shared_walks)
 ) -> jax.Array:
     """Unified ragged attention dispatcher: Pallas kernel on TPU (or under
     ``interpret``), XLA gather reference elsewhere (CPU tier-1 — same
@@ -687,7 +951,9 @@ def ragged_attend_auto(
     collective; the pools' KV·hd lanes split into tp runs of whole
     kv-heads, and int8 scale pools shard on their KV axis beside them.
     ``k_scale``/``v_scale`` mark int8 pools and route to the in-kernel
-    dequant / dequantizing reference."""
+    dequant / dequantizing reference. ``tiles`` and ``shared`` are
+    schedules of the kernel's walk (replicated under ``shard``); the
+    reference, which gathers, has no use for either."""
     if shard is not None:
         from jax.sharding import PartitionSpec as P
         mesh, tp_ax = shard
@@ -699,9 +965,11 @@ def ragged_attend_auto(
         if k_scale is not None:
             ins += [P(None, None, tp_ax, None)] * 2   # [L, n_pages, KV, page]
             args += [k_scale, v_scale]
-        if tiles is not None:
-            ins.append(P(None, None))
-            args.append(tiles)
+        # the walk's schedule, whichever the call has: replicated
+        plan = {"tiles": tiles, "shared": shared}
+        plan = {k: v for k, v in plan.items() if v is not None}
+        ins += [P(None, None)] * len(plan)
+        args += list(plan.values())
 
         def inner(qq, kp, vp, rt, bm, ly, *rest):
             ks, vs = rest[:2] if k_scale is not None else (None, None)
@@ -709,7 +977,7 @@ def ragged_attend_auto(
                 qq, kp, vp, rt, bm, ly, tq=tq,
                 sliding_window=sliding_window, interpret=interpret,
                 k_scale=ks, v_scale=vs, tile=tile,
-                tiles=rest[-1] if tiles is not None else None)
+                **dict(zip(plan, rest[len(rest) - len(plan):])))
         # check_vma off: a pallas_call's outputs carry no varying-axes
         # annotation for the checker to verify
         return jax.shard_map(inner, mesh=mesh, in_specs=tuple(ins),
@@ -719,7 +987,7 @@ def ragged_attend_auto(
                              layer, tq=tq, sliding_window=sliding_window,
                              interpret=bool(interpret),
                              k_scale=k_scale, v_scale=v_scale,
-                             tiles=tiles, tile=tile)
+                             tiles=tiles, tile=tile, shared=shared)
     return ragged_attend_ref(q, k_pool, v_pool, row_tables, block_meta,
                              layer, tq=tq, sliding_window=sliding_window,
                              k_scale=k_scale, v_scale=v_scale)

@@ -81,6 +81,32 @@ def test_ragged_kernel_compiles(on_v5e, tq, nb, rows, quant):
     compiles(fn, *args)
 
 
+@pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8"])
+@pytest.mark.parametrize("rows", [8, 64], ids=["r8", "r64"])
+@pytest.mark.parametrize("h,kv", [(32, 8), (16, 2)],
+                         ids=["h32-kv8", "h16-kv2"])
+def test_shared_walk_kernel_compiles(on_v5e, h, kv, rows, quant):
+    """The decode call with a shared-walk table (ISSUE 32) at Mistral's and
+    Qwen's head shapes, a table 128 wide, the batcher's 8 row slots and the
+    largest row bucket: the walk table in SMEM beside the page tables; the
+    gathered queries, the walk's softmax state and every row's parked
+    state in scoped VMEM beside the double-buffered pages (at 64 rows of 8
+    kv heads the parked state alone is 6 MiB)."""
+    S = on_v5e
+    pool = S((LAYERS, N_PAGES, PAGE, kv * HD),
+             jnp.int8 if quant else jnp.bfloat16)
+    args = [S((rows, h, HD), jnp.bfloat16), pool, pool,
+            S((rows, 128), jnp.int32), S((4, rows), jnp.int32),
+            S((), jnp.int32), S((2 + pa.SHARED_ROWS, rows), jnp.int32)]
+    if quant:
+        args += [S((LAYERS, N_PAGES, kv, PAGE), jnp.float32)] * 2
+
+    def fn(q, k, v, tables, meta, layer, shared, ks=None, vs=None):
+        return pa.ragged_attend(q, k, v, tables, meta, layer, tq=1,
+                                k_scale=ks, v_scale=vs, shared=shared)
+    compiles(fn, *args)
+
+
 # the two dense configurations the benchmark serves: (H, KV, window)
 DENSE = {"mistral-h32-kv8": (32, 8, WINDOW), "qwen-h16-kv2": (16, 2, None)}
 
@@ -301,7 +327,8 @@ def _decode_program(on_v5e, monkeypatch, cfg, max_seq, rows=8, width=4):
     pool = S((cfg.n_layers, st.n_pages, st.page, lanes), eng.pool_dtype)
     R, i32, f32 = rows, jnp.int32, jnp.float32
     compiled = eng._step_paged_decode_ragged.lower(
-        params, pool, pool, None, None, S((R, width), i32), S((R,), i32),
+        params, pool, pool, None, None, S((R, width), i32),
+        S((2 + pa.SHARED_ROWS, R), i32), S((R,), i32),
         S((R,), i32), S((R, cfg.vocab_size), f32), S((2,), jnp.uint32),
         S((R,), f32), S((R,), f32), S((R,), jnp.bool_), S((R,), i32),
         None, None, max_new=32).compile()
@@ -317,26 +344,37 @@ def _narrow(name, n_kv_heads, **kw):
     much smaller than these 26 MB the compiler would prefetch into fast
     memory whole, which no serving pool fits."""
     from quoracle_tpu.models.config import ModelConfig
+    kw.setdefault("n_heads", 8)
     return ModelConfig(name=name, vocab_size=512, dim=256, n_layers=3,
-                       n_heads=8, n_kv_heads=n_kv_heads, ffn_dim=512,
-                       head_dim=128, **kw)
+                       n_kv_heads=n_kv_heads, ffn_dim=512, head_dim=128,
+                       **kw)
 
 
-@pytest.mark.parametrize("cfg,max_seq", [
-    (_narrow("narrow-kv8-window", 8, sliding_window=4096), 128),
+@pytest.mark.parametrize("cfg,max_seq,width", [
+    (_narrow("narrow-kv8-window", 8, sliding_window=4096), 128, 4),
     (_narrow("narrow-kv2-bias", 2, attn_bias=True, tie_embeddings=True),
-     512),
+     512, 4),
+    # the shared walk (ISSUE 32) at the two dense configurations' head
+    # shapes, a table 128 wide: Mistral's without its window, under which
+    # the kernel holds no walk
+    (_narrow("narrow-h32-kv8-w128", 8, n_heads=32), 128, 128),
+    (_narrow("narrow-h16-kv2-w128", 2, n_heads=16), 512, 128),
 ], ids=lambda c: getattr(c, "name", None))
 def test_decode_program_leaves_the_pool_where_it_is(on_v5e, monkeypatch,
-                                                    cfg, max_seq):
+                                                    cfg, max_seq, width):
     """The optimized HLO of the decode program holds no operation that
     moves a layer's pool or more, its temporaries stay under one layer's
     pool, and both pools are donated into their outputs. This is what
-    keeps the copies from coming back unnoticed (PERF.md §6, PR 25)."""
+    keeps the copies from coming back unnoticed (PERF.md §6, PR 25). The
+    program takes the tick's shared-walk table, [2 + SHARED_ROWS, R]
+    int32, and its one kernel a layer body reads it."""
     hlo, mem, layer_elems = _decode_program(on_v5e, monkeypatch, cfg,
-                                            max_seq=max_seq)
+                                            max_seq=max_seq, width=width)
     assert layer_elems == (max_seq // 4 + 1) * 128 * cfg.n_kv_heads * 128
     assert hlo.count("tpu_custom_call") == 1     # one kernel a layer body
+    call = next(ln for ln in hlo.splitlines() if "tpu_custom_call" in ln)
+    assert "%ragged_attend" in call
+    assert f"s32[{2 + pa.SHARED_ROWS},8]" in call
     assert pool_moves(hlo, layer_elems) == []
     pool_bytes = 2 * cfg.n_layers * layer_elems * 2
     assert mem.alias_size_in_bytes >= pool_bytes
@@ -557,7 +595,7 @@ def test_latent_decode_program_leaves_the_pool_where_it_is(on_v5e,
     layer_elems = st.n_pages * st.page * 640
     R, i32, f32 = 8, jnp.int32, jnp.float32
     compiled = eng._step_paged_decode_ragged.lower(
-        params, pool, None, None, None, S((R, 8), i32), S((R,), i32),
+        params, pool, None, None, None, S((R, 8), i32), None, S((R,), i32),
         S((R,), i32), S((R, cfg.vocab_size), f32), S((2,), jnp.uint32),
         S((R,), f32), S((R,), f32), S((R,), jnp.bool_), S((R,), i32),
         None, None, max_new=32).compile()
@@ -636,7 +674,7 @@ def test_selecting_decode_program_leaves_both_pools_where_they_are(
     tokens = st.n_pages * st.page
     R, i32, f32 = 8, jnp.int32, jnp.float32
     compiled = eng._step_paged_decode_ragged.lower(
-        params, *pools, None, None, S((R, 8), i32), S((R,), i32),
+        params, *pools, None, None, S((R, 8), i32), None, S((R,), i32),
         S((R,), i32), S((R, cfg.vocab_size), f32), S((2,), jnp.uint32),
         S((R,), f32), S((R,), f32), S((R,), jnp.bool_), S((R,), i32),
         None, None, max_new=32).compile()
@@ -687,6 +725,30 @@ def test_ragged_tp_wrapper_runs_the_kernel_under_shard_map(tp_case, tile):
     out = jax.jit(lambda *a, tiles: pa.ragged_attend_auto(
         *a, tq=8, interpret=True, shard=(c["mesh"], "tp"), tile=tile,
         tiles=tiles))(*args, tiles=tiles)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
+
+
+def test_shared_walk_replicates_under_shard_map(tp_case):
+    """The decode call per tp shard: the shared-walk table replicates like
+    the page tables, and each shard walks the common pages once for its
+    own heads of both rows."""
+    c = tp_case
+    n = pa.SHARED_MIN_PAGES
+    assert n + 2 <= 8                       # the case's pool: pages 1..8
+    tables = np.zeros((2, n + 1), np.int32)
+    tables[:, :n] = np.arange(1, n + 1)
+    tables[:, n] = (n + 1, n + 2)
+    lens = np.asarray([n * 8 + 3, n * 8 + 7], np.int32)
+    shared = pa.shared_walks(tables, lens, 8)
+    assert shared[:2].tolist() == [[n, n], [1, 0]]
+    meta = np.stack([lens + 1, lens, [1, 1], [0, 1]]).astype(np.int32)
+    args = (c["arr"](2, c["h"], c["hd"]), c["stored"](c["kp"]),
+            c["stored"](c["vp"]), jnp.asarray(tables), jnp.asarray(meta),
+            jnp.asarray(1, jnp.int32))
+    ref = pa.ragged_attend_ref(*args, tq=1)
+    out = jax.jit(lambda *a, shared: pa.ragged_attend_auto(
+        *a, tq=1, interpret=True, shard=(c["mesh"], "tp"),
+        shared=shared))(*args, shared=jnp.asarray(shared))
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
 
 

@@ -546,8 +546,9 @@ def test_the_dense_decode_program_is_the_parents():
               cfg.n_kv_heads * cfg.head_dim), eng.pool_dtype)
     R, i32, f = 8, jnp.int32, jnp.float32
     lowered = eng._step_paged_decode_ragged.lower(
-        params, pool, pool, None, None, S((R, 4), i32), S((R,), i32),
-        S((R,), i32), S((R, cfg.vocab_size), f), S((2,), jnp.uint32),
+        params, pool, pool, None, None, S((R, 4), i32), S((10, R), i32),
+        S((R,), i32), S((R,), i32), S((R, cfg.vocab_size), f),
+        S((2,), jnp.uint32),
         S((R,), f), S((R,), f), S((R,), jnp.bool_), S((R,), i32), None,
         None, max_new=32)
     ops = [ln for ln in lowered.as_text().splitlines()
