@@ -201,8 +201,9 @@ def hbm_attribution(backend) -> dict:
             page_b = _kv_page_bytes(e)
             pool_b = 0
             if st.k is not None:
-                pool_b = sum(int(a.nbytes) for a in (st.k, st.v)
-                             if a is not None)   # a latent pool has no v
+                pool_b = sum(int(a.nbytes) for a in (st.k, st.v, st.state)
+                             if a is not None)   # a latent pool has no v;
+                                                 # conv layers: state
                 if st.k_scale is not None:
                     pool_b += (int(st.k_scale.nbytes)
                                + int(st.v_scale.nbytes))

@@ -731,6 +731,21 @@ ATTN_SHARED_KV_TOKENS_TOTAL = METRICS.counter(
     "(ops/paged_attention.shared_walks), a layer, per model: kind = needed "
     "what the rows needed of them (rows x pages x page x steps), kind = "
     "walked what the shared walks brought into VMEM (once a group a step)")
+# -- state that is not keys and values (ISSUE 33) -----------------------------
+# A model with conv layers holds, beside its K/V pages, one state record a
+# page (generate.py ``_ensure_pool``); booked once a tick by the engine.
+CONV_STATE_ROWS_TOTAL = METRICS.counter(
+    "quoracle_conv_state_rows_total",
+    "rows of a tick of a model with conv layers, per model, by where the "
+    "row's chunk took its conv state from: source = carried (the "
+    "session's own record at its end), adopted (a cached page's record, "
+    "the prefix cache's or an earlier boundary of the session's own), "
+    "zero (a sequence's start)")
+CONV_STATE_REPREFILL_TOKENS_TOTAL = METRICS.counter(
+    "quoracle_conv_state_reprefill_tokens_total",
+    "prompt tokens whose K/V was resident and matched but which ran "
+    "through the chunk forward again because no conv state is held at the "
+    "match's end (reuse is rounded down to a page boundary), per model")
 # -- the batcher's tick record (ISSUE 24) -----------------------------------
 # One record per ContinuousBatcher._loop iteration, built on the worker
 # thread where the work happens (models/scheduler.py, models/generate.py).

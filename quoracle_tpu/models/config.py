@@ -69,11 +69,12 @@ class IndexerConfig:
 
 @dataclasses.dataclass(frozen=True)
 class MoEConfig:
-    """Routed experts with a shared expert (the DeepSeek-V3 form): the
-    router scores ALL ``n_routed`` experts; this process HOLDS experts
-    ``held_start .. held_start + n_held`` (its share of an expert-parallel
-    deployment; the whole set when ``n_held == n_routed``) and computes
-    only their part of a token's result plus the shared expert."""
+    """Routed experts, with or without a shared expert (the DeepSeek-V3
+    form; ``n_shared`` 0: LFM2's): the router scores ALL ``n_routed``
+    experts; this process HOLDS experts ``held_start .. held_start +
+    n_held`` (its share of an expert-parallel deployment; the whole set
+    when ``n_held == n_routed``) and computes only their part of a token's
+    result plus the shared expert."""
 
     n_routed: int            # the router's width, as published
     n_held: int              # experts held here
@@ -89,6 +90,9 @@ class MoEConfig:
     # ``noaux_tc``: one float32 a routed expert is added to its score where
     # groups and experts are CHOSEN; the gates stay the bare scores
     router_bias: bool = False
+    # what the selected scores' sum takes before the gates are divided by
+    # it (``norm_topk``): 1e-20 as DeepSeek-V3 publishes it, 1e-6 at LFM2
+    gate_eps: float = 1e-20
 
     def __post_init__(self):
         assert self.n_routed % self.n_group == 0
@@ -98,11 +102,16 @@ class MoEConfig:
 
 @dataclasses.dataclass(frozen=True)
 class ModelConfig:
-    """Architecture + serving config for one decoder-only transformer.
+    """Architecture + serving config for one decoder-only model.
 
-    Covers the Llama/Mistral/Gemma/Qwen families (RMSNorm, RoPE, GQA/MQA,
-    gated MLP). Per-family quirks are expressed as data, not subclasses, so a
-    single traced forward function serves every family.
+    Covers the dense Llama/Mistral/Gemma/Qwen families (RMSNorm, RoPE,
+    GQA/MQA, gated MLP), latent attention with routed experts and an
+    optional learned key selection (the DeepSeek-V2/V3/V3.2 form), and
+    hybrids whose layers differ in KIND (LFM2: gated short convolutions
+    among per-head attention layers, a dense feed-forward first and routed
+    experts after). Per-family quirks are data, not subclasses; which
+    forward serves a configuration follows from that data alone
+    (``plain``, ``latent``, ``layer_plan``).
     """
 
     name: str
@@ -134,15 +143,26 @@ class ModelConfig:
     # takes mscale squared (transformer.attn_softmax_scale).
     # None = unscaled. (Kept a tuple so ModelConfig stays hashable for jit.)
     rope_scaling: Optional[tuple] = None
-    # Latent attention with routed experts (frozen sub-records, so the
-    # config stays hashable; the two come together: no configuration asks
-    # for one without the other). Such a model is served on the ragged
-    # paged path only (``require_plain`` at every other path's entry).
+    # Latent attention and routed experts (frozen sub-records, so the
+    # config stays hashable). Latent attention comes with experts; experts
+    # also come beside per-head attention. Such a model is served on the
+    # ragged paged path only (``require_plain`` at every other path's
+    # entry).
     latent: Optional[LatentConfig] = None
     moe: Optional[MoEConfig] = None
     # Learned top-k selection inside the latent attention (latent models
     # only): a second pool holds each token's index key.
     indexer: Optional[IndexerConfig] = None
+    # The token mixer of each layer, "attention" or "conv" (None: attention
+    # everywhere). A "conv" layer is LFM2's gated short convolution: what
+    # a session holds for it is the last ``conv_cache - 1`` conv inputs, a
+    # fixed block whatever the session's length (``state_lanes``), not
+    # per-token rows.
+    layer_types: Optional[tuple] = None
+    conv_cache: int = 3              # conv_L_cache: taps of the convolution
+    # RMSNorm over each head's values of q and k, before the rotary (one
+    # weight vector for all heads)
+    qk_norm: bool = False
 
     # --- serving metadata (what the reference pulled from LLMDB) ---
     context_window: int = 8192
@@ -177,12 +197,20 @@ class ModelConfig:
         if self.head_dim is None:
             object.__setattr__(self, "head_dim", self.dim // self.n_heads)
         assert self.n_heads % self.n_kv_heads == 0, "GQA requires n_heads % n_kv_heads == 0"
-        assert (self.latent is None) == (self.moe is None), \
-            "latent attention and routed experts are served together only"
+        assert self.latent is None or self.moe is not None, \
+            "latent attention is served with routed experts only"
         if self.moe is not None:
             assert 0 <= self.moe.first_dense < self.n_layers
         assert self.indexer is None or self.latent is not None, \
             "the indexer selects keys for latent attention only"
+        if self.layer_types is not None:
+            assert len(self.layer_types) == self.n_layers \
+                and set(self.layer_types) <= {"attention", "conv"}, \
+                self.layer_types
+            assert self.latent is None or "conv" not in self.layer_types, \
+                "short-conv layers stand among per-head attention layers"
+            assert self.conv_cache >= 2
+        assert not self.qk_norm or self.latent is None
 
     @property
     def q_per_kv(self) -> int:
@@ -190,9 +218,58 @@ class ModelConfig:
 
     @property
     def plain(self) -> bool:
-        """Dense decoder with per-head K and V: every path serves it. A
-        latent, routed-expert model runs on the ragged paged path alone."""
-        return self.latent is None
+        """Dense decoder with per-head K and V in every layer: every path
+        serves it. A model with latent attention, routed experts, conv
+        layers or a q/k norm runs on the ragged paged path alone."""
+        return (self.latent is None and self.moe is None
+                and self.n_conv_layers == 0 and not self.qk_norm)
+
+    @property
+    def mixers(self) -> tuple:
+        return self.layer_types or ("attention",) * self.n_layers
+
+    @property
+    def n_attn_layers(self) -> int:
+        """Layers that hold per-token rows in pages (``kv_pools``)."""
+        return self.mixers.count("attention")
+
+    @property
+    def n_conv_layers(self) -> int:
+        """Layers that hold a fixed block of state (``state_lanes``)."""
+        return self.mixers.count("conv")
+
+    @property
+    def state_lanes(self) -> int:
+        """What a session holds in ONE conv layer, whatever its length:
+        the last ``conv_cache - 1`` conv inputs of ``dim`` values, side by
+        side. One such record a conv layer rides every page of the pool
+        (generate.py ``_ensure_pool``): the state at the end of the page's
+        tokens."""
+        return (self.conv_cache - 1) * self.dim if self.n_conv_layers else 0
+
+    def state_bytes_per_record(self, dtype_bytes: int = 2) -> int:
+        """Bytes of one state record over all conv layers."""
+        return self.n_conv_layers * self.state_lanes * dtype_bytes
+
+    @property
+    def layer_plan(self) -> tuple:
+        """The layers as the pattern forward runs them: three segments
+        ``(kinds, repeats)`` — the leading dense-feed-forward layers once,
+        the shortest PERIOD of the layers after them as often as it fits
+        whole (one ``lax.scan``: program size does not follow the depth),
+        and what is left of a last period once. ``kinds`` is a tuple of
+        (mixer, feed-forward) pairs, "attention" | "conv" and "dense" |
+        "experts"."""
+        n_dense = self.n_dense_layers
+        kinds = tuple((m, "dense" if i < n_dense else "experts")
+                      for i, m in enumerate(self.mixers))
+        lead, rest = kinds[:n_dense], kinds[n_dense:]
+        p = next((p for p in range(1, len(rest) + 1)
+                  if all(rest[i] == rest[i + p]
+                         for i in range(len(rest) - p))), 0)
+        n = len(rest) // p if p else 0
+        return ((lead, 1 if lead else 0), (rest[:p], n),
+                (rest[n * p:], 1 if rest[n * p:] else 0))
 
     @property
     def kv_pools(self) -> tuple:
@@ -237,9 +314,15 @@ class ModelConfig:
                   + (self.n_kv_heads * hd if self.attn_bias else 0))
         return q + kv + self.n_heads * hd * self.dim
 
-    def _layer_params(self, experts: Optional[int]) -> int:
+    def _conv_params(self) -> int:
+        """A short-conv operator: in (to B, C, x), the taps, out."""
+        return (self.dim * 3 * self.dim + self.conv_cache * self.dim
+                + self.dim * self.dim)
+
+    def _layer_params(self, experts: Optional[int],
+                      mixer: str = "attention") -> int:
         """One layer's parameters; ``experts`` None = the dense MLP, else
-        that many routed experts beside the router and the shared one."""
+        that many routed experts beside the router and the shared ones."""
         norms = 2 * self.dim
         if experts is None:
             mlp = 3 * self.dim * self.ffn_dim      # gate + up + down
@@ -248,7 +331,9 @@ class ModelConfig:
             mlp = (self.dim * m.n_routed
                    + (m.n_routed if m.router_bias else 0)
                    + 3 * self.dim * m.expert_dim * (experts + m.n_shared))
-        return self._attn_params() + mlp + norms
+        op = self._conv_params() if mixer == "conv" else \
+            self._attn_params() + (2 * self.head_dim if self.qk_norm else 0)
+        return op + mlp + norms
 
     @property
     def n_params(self) -> int:
@@ -257,11 +342,10 @@ class ModelConfig:
         experts) — the input to the HBM budget."""
         embed = self.vocab_size * self.dim
         head = 0 if self.tie_embeddings else self.vocab_size * self.dim
-        total = (embed + self.dim + head
-                 + self.n_dense_layers * self._layer_params(None))
-        if self.moe is not None:
-            total += (self.n_layers - self.n_dense_layers) \
-                * self._layer_params(self.moe.n_held)
+        total = embed + self.dim + head + sum(
+            self._layer_params(
+                None if i < self.n_dense_layers else self.moe.n_held, m)
+            for i, m in enumerate(self.mixers))
         if self.vision is not None:
             # ViT tower + projector come out of the same HBM budget
             # (models/vision.py init_vision_params structure)
@@ -294,15 +378,20 @@ class ModelConfig:
         lanes = sum(self.kv_pools)
         if self.latent is None:
             lanes //= tp
-        return lanes * self.n_layers * dtype_bytes
+        return lanes * self.n_attn_layers * dtype_bytes
 
 
 def unsupported_path(cfg: ModelConfig, what: str) -> str:
-    """The one error text for a path that cannot serve a model with
-    latent attention or expert layers."""
-    return (f"model {cfg.name} (latent attention and routed experts) is "
-            f"served on the ragged paged path of one device only; {what} "
-            f"cannot run it")
+    """The one error text for a path that cannot serve a model, naming
+    what of the model the path cannot carry."""
+    has = [name for name, on in (
+        ("latent attention", cfg.latent is not None),
+        ("a learned key selection", cfg.indexer is not None),
+        ("short-conv state beside the paged KV", cfg.n_conv_layers > 0),
+        ("a q/k norm", cfg.qk_norm),
+        ("routed experts", cfg.moe is not None)) if on]
+    return (f"model {cfg.name} ({', '.join(has)}) is served on the ragged "
+            f"paged path of one device only; {what} cannot run it")
 
 
 def require_plain(cfg: ModelConfig, what: str) -> None:

@@ -29,7 +29,7 @@ from quoracle_tpu.models.config import (
 )
 from quoracle_tpu.models.sampling import sample_tokens
 from quoracle_tpu.models.transformer import (
-    KVCache, forward_hidden, forward_hidden_ragged, init_cache,
+    ConvTick, KVCache, forward_hidden, forward_hidden_ragged, init_cache,
     project_logits,
 )
 
@@ -274,6 +274,7 @@ def decode_ragged(
     k_scale: Optional[jax.Array] = None,   # [L, n_pages, KV, page] f32 —
     v_scale: Optional[jax.Array] = None,   # int8 pools (ISSUE 13)
     shared: Optional[jax.Array] = None,    # [2 + SHARED_ROWS, R] int32
+    state: Optional[jax.Array] = None,     # conv layers' state pool
 ) -> tuple:
     """Autoregressive decode through the UNIFIED ragged kernel (ISSUE 8):
     same sampling/grammar semantics as decode(), but each
@@ -295,8 +296,15 @@ def decode_ragged(
     ``while`` has nothing to copy — tests/test_kernels_compile_tpu.py
     holds the compiled program to that.
 
+    With ``state`` (a model with conv layers: one record a page a conv
+    layer, transformer.ConvTick) the loop carries that pool the same way:
+    a step reads each live row's record under the page of its last
+    resident token and writes the new one under the page of the token it
+    forwards, so a page that fills keeps the state at its end and a row
+    that is done leaves its record alone.
+
     Returns (tokens [R, max_new], n_emitted [R], lens [R], k_pool,
-    v_pool, k_scale, v_scale, jstate, moe_stats) where lens counts the
+    v_pool, k_scale, v_scale, jstate, moe_stats, state) where lens counts the
     row's valid pool tokens (prompt + chunk + emitted-and-forwarded) and
     ``moe_stats`` is the expert layers' int32 [4] summed over the steps
     (transformer.forward_hidden_ragged; None without experts). With
@@ -320,7 +328,7 @@ def decode_ragged(
 
     def body(carry):
         (i, done, cur, out, n_emitted, lens, kp, vp, ks, vs, rng,
-         jstate, moe) = carry
+         jstate, moe, sp) = carry
         with jax.named_scope("row_state"):
             live = (~done).astype(jnp.int32)
             # this step's token writes at buffer slot lens; done rows (and
@@ -338,10 +346,21 @@ def decode_ragged(
                 jnp.arange(R, dtype=jnp.int32),   # one tq=1 block per row
             ])
             positions = lens + kv_off.astype(jnp.int32)
-        hidden, kp, vp, ks, vs, st = forward_hidden_ragged(
+            conv = None
+            if sp is not None:
+                # one token a row: it follows the record under the page of
+                # the token before it and is recorded under its own page
+                last = jnp.take_along_axis(
+                    tables, jnp.clip((lens - 1) // page, 0, maxp - 1)[:, None],
+                    axis=1)[:, 0]
+                conv = ConvTick(sp, last, None, None,
+                                jnp.where(flat < n_tok, pg, n_pages))
+        hidden, kp, vp, ks, vs, st, *rest = forward_hidden_ragged(
             params, cfg, cur[None], positions[None], kp, vp, tables,
             meta, flat, tq=1, interpret=interpret, shard=shard,
-            k_scale=ks, v_scale=vs, shared=shared)
+            k_scale=ks, v_scale=vs, shared=shared, conv=conv)
+        if sp is not None:
+            sp = rest[0]
         if st is not None:
             moe = moe + st
         logits = project_logits(params, cfg, hidden)[0]      # [R, V]
@@ -356,20 +375,36 @@ def decode_ragged(
             jstate = advance(jstate, nxt, done)
             done = done | is_stop(nxt) | (n_emitted >= row_limit)
         return (i + 1, done, nxt, out, n_emitted, lens, kp, vp, ks, vs,
-                rng, jstate, moe)
+                rng, jstate, moe, sp)
 
     # unquantized loops carry scale placeholders as empty pytrees (None
     # is a valid while_loop carry leaf-less node)
     init = (jnp.asarray(1, jnp.int32), done0, tok0, out0, n0, lens0,
             k_pool, v_pool, k_scale, v_scale, rng, jstate0,
-            None if cfg.moe is None else jnp.zeros((4,), jnp.int32))
+            None if cfg.moe is None else jnp.zeros((4,), jnp.int32), state)
     # what the loop itself emits carries ``decode_loop`` and no sub-scope
     # (until PR 25: a copy of each loop-carried pool every step)
     with jax.named_scope("decode_loop"):
         (_, done, _, out, n_emitted, lens, k_pool, v_pool, k_scale,
-         v_scale, _, jstate, moe) = jax.lax.while_loop(cond, body, init)
+         v_scale, _, jstate, moe, state) = jax.lax.while_loop(cond, body,
+                                                              init)
     return (out, n_emitted, lens, k_pool, v_pool, k_scale, v_scale, jstate,
-            moe)
+            moe, state)
+
+
+def conv_past(row: np.ndarray, idx: np.ndarray, taps: int) -> np.ndarray:
+    """``ConvTick.past`` from each flat token's row slot and its place in
+    its row's chunk (``[Tp]`` each; a padded slot may say anything): entry
+    ``[t, j-1]`` is ``t - j`` where the chunk reaches back ``j`` tokens,
+    else the record's entry that holds that token (the record's last
+    entry is the token before the chunk's first)."""
+    n = len(row)
+    j = np.arange(1, taps)[None]
+    idx = idx[:, None]
+    return np.where(
+        idx >= j, np.maximum(np.arange(n)[:, None] - j, 0),
+        n + row[:, None] * (taps - 1)
+        + np.clip(taps - 1 - j + idx, 0, taps - 2)).astype(np.int32)
 
 
 def _round_up(n: int, buckets: Sequence[int]) -> int:
@@ -499,6 +534,11 @@ class SessionStore:
         self.v: Optional[jax.Array] = None
         self.k_scale: Optional[jax.Array] = None
         self.v_scale: Optional[jax.Array] = None
+        # A model with conv layers: one state record a page a conv layer,
+        # addressed by the same page ids (GenerateEngine._ensure_pool), so
+        # adoption, copy-on-write, reference counts and eviction carry a
+        # page's record with no bookkeeping of its own.
+        self.state: Optional[jax.Array] = None
         # Tiered KV (ISSUE 7, serving/kvtier.py): when attached, alloc's
         # eviction ladder DEMOTES victims to the host tier instead of
         # destroying them, and the engine's session lookup restores
@@ -1247,7 +1287,8 @@ class GenerateEngine:
         # 0: a latent pool's kernel walks a block at a time, no tile table
         from quoracle_tpu.ops.paged_attention import ragged_tile
         self._ragged_tile = ragged_tile(
-            cfg.n_heads, cfg.head_dim, RAGGED_TQ) if cfg.plain else 0
+            cfg.n_heads, cfg.head_dim, RAGGED_TQ) if cfg.latent is None \
+            else 0
 
         @functools.partial(jax.jit, static_argnames=())
         def step_paged_prefill(params, k_pool, v_pool, k_scale, v_scale,
@@ -1432,28 +1473,34 @@ class GenerateEngine:
         # the three unified programs donate the pools (and an int8
         # engine's scale pools; None donates nothing): input and output
         # are one buffer, updated in place
-        @functools.partial(jax.jit, donate_argnums=(1, 2, 3, 4),
+        @functools.partial(jax.jit, donate_argnums=(1, 2, 3, 4, 12),
                            static_argnames=("tq", "tile"))
         def step_paged_ragged(params, k_pool, v_pool, k_scale, v_scale,
                               tokens_flat,
                               positions_flat, row_tables, block_meta,
-                              tiles, flat_dst, last_idx, tq: int,
-                              tile: int):
+                              tiles, flat_dst, last_idx, state=None,
+                              conv=None, *, tq: int, tile: int):
             # UNIFIED mixed chunk forward (ISSUE 8): one ragged launch
             # per layer over the token-major flattened tick — prefill
             # suffixes, 1-token continuations, any mix of lengths — with
             # chunk KV scattered to the rows' pages inside the forward.
             # Shapes key on (flat token budget, page-table width) only:
             # the batch-bucket × prompt-bucket program matrix collapses.
-            hidden, k_pool, v_pool, k_scale, v_scale, moe = \
+            # ``state`` / ``conv``: a model with conv layers hands in its
+            # state pool (donated like the others) and where the tick's
+            # rows read and write it (ConvTick's fields after the pool).
+            if state is not None:
+                conv = ConvTick(state, *conv)
+            hidden, k_pool, v_pool, k_scale, v_scale, moe, *rest = \
                 forward_hidden_ragged(
                     params, cfg, tokens_flat[None], positions_flat[None],
                     k_pool, v_pool, row_tables, block_meta, flat_dst,
                     tq=tq, shard=ragged_shard, k_scale=k_scale,
-                    v_scale=v_scale, tiles=tiles, tile=tile)
+                    v_scale=v_scale, tiles=tiles, tile=tile, conv=conv)
             last_h = hidden[0][last_idx]                  # [R, D]
             last = project_logits(params, cfg, last_h[:, None])[:, 0, :]
-            return last, k_pool, v_pool, k_scale, v_scale, moe
+            return (last, k_pool, v_pool, k_scale, v_scale, moe,
+                    rest[0] if rest else None)
 
         @functools.partial(jax.jit, donate_argnums=(1, 2, 3, 4),
                            static_argnames=("tq", "tile", "kmax",
@@ -1510,14 +1557,14 @@ class GenerateEngine:
                 probs = jnp.zeros((1, 1, 1), jnp.float32)
             return ids, probs, k_pool, v_pool, k_scale, v_scale
 
-        @functools.partial(jax.jit, donate_argnums=(1, 2, 3, 4),
+        @functools.partial(jax.jit, donate_argnums=(1, 2, 3, 4, 17),
                            static_argnames=("max_new",))
         def step_paged_decode_ragged(params, k_pool, v_pool, k_scale,
                                      v_scale, tables, shared,
                                      pool_lens, kv_off, last_logits, rng,
                                      temperature, top_p, active,
                                      row_limit, json_table, json_state,
-                                     max_new: int):
+                                     state=None, *, max_new: int):
             # Decode continuation of the unified tick: KV written straight
             # to pages inside the loop (no tail buffer, no tail scatter);
             # attention is the same ragged kernel at tq=1 (int8 pools
@@ -1530,7 +1577,7 @@ class GenerateEngine:
                 pad_id=self.tokenizer.pad_id, stop_ids=cfg.stop_token_ids,
                 json_table=json_table, json_state=json_state,
                 shard=ragged_shard, k_scale=k_scale, v_scale=v_scale,
-                shared=shared)
+                shared=shared, state=state)
 
         self._step_paged_ragged = step_paged_ragged
         self._step_paged_ragged_verify = step_paged_ragged_verify
@@ -1761,6 +1808,9 @@ class GenerateEngine:
         # Unquantized engines keep the historic signature unchanged.
         geometry = (f"x{cfg.n_kv_heads}x{cfg.head_dim}" if cfg.latent is None
                     else "xlatent" + "+".join(map(str, cfg.kv_pools)))
+        if cfg.n_conv_layers:
+            geometry += (f"-A{cfg.n_attn_layers}"
+                         f"-conv{cfg.n_conv_layers}x{cfg.state_lanes}")
         return (f"{cfg.name.replace('/', '_')}-L{cfg.n_layers}"
                 f"{geometry}-p{self.sessions.page}"
                 f"-{jnp.dtype(self.pool_dtype).name}"
@@ -1868,6 +1918,11 @@ class GenerateEngine:
         (the scheduler's relative-state convention). Every row must be
         sessioned; speculative serving never runs on sliding-window or
         vision engines (the BatchedSpeculator enforces eligibility)."""
+        if self.cfg.n_conv_layers:
+            # a rejected draft's tokens would have advanced the conv
+            # state, and no record is kept to roll it back to
+            raise ValueError(unsupported_path(
+                self.cfg, "verify_chunk (speculative drafts)"))
         assert session_ids is not None and all(session_ids), \
             "verify_chunk requires a session per row"
         assert len(verify_k) == len(prompts)
@@ -1924,6 +1979,7 @@ class GenerateEngine:
 
         sess_rows: list[Optional[_Session]] = [None] * n
         reuse_abs = [0] * n
+        reprefill = 0        # matched tokens that hold no conv state
         kv_off_host = [0] * n
         store_sids: list[Optional[str]] = [None] * n
         paged = False
@@ -1994,6 +2050,14 @@ class GenerateEngine:
                     # a divergence the resident window [start_pos, p) would
                     # leave a hole below the new tokens' attention windows.
                     continue
+                if self.cfg.n_conv_layers and p < len(s.tokens):
+                    # conv state is held at the session's end and at page
+                    # boundaries only (_ensure_pool): a match that ends
+                    # elsewhere is reused up to the last boundary at or
+                    # below it, and the rest runs again
+                    held = p // self.sessions.page * self.sessions.page
+                    reprefill += p - held
+                    p = held
                 if p > s.start_pos:
                     sess_rows[i] = s
                     reuse_abs[i] = p
@@ -2006,6 +2070,8 @@ class GenerateEngine:
                 require_plain(self.cfg,
                               "the sequence-parallel ring / image rows")
             paged = True
+        if self.cfg.n_conv_layers:
+            self._note_state(sess_rows, reuse_abs, reprefill)
         prefixes = [r - o for r, o in zip(reuse_abs, kv_off_host)]  # buffer
         suffixes = [list(p[r:]) for p, r in zip(prompts, reuse_abs)]
         max_chunk = max(len(s) for s in suffixes)
@@ -2313,6 +2379,30 @@ class GenerateEngine:
         tick_note(moe_assignments=total, moe_held=held,
                   moe_reached=reached, moe_layer_steps=steps)
 
+    def _note_state(self, sess_rows, reuse_abs, reprefill: int) -> None:
+        """Book where each row of a tick of a model with conv layers takes
+        its conv state from: its session's own end record (``carried``), a
+        page boundary's record (``adopted``: a cached prefix's, or the
+        session's own below a match that ended inside a page), or zeros
+        (``cold``: a sequence's start); and the matched tokens that ran
+        again for want of a record where the match ended."""
+        from quoracle_tpu.infra.telemetry import (
+            CONV_STATE_REPREFILL_TOKENS_TOTAL, CONV_STATE_ROWS_TOTAL,
+        )
+        rows = {"carried": 0, "adopted": 0, "zero": 0}
+        for s, r in zip(sess_rows, reuse_abs):
+            rows["zero" if not r else
+                 "carried" if r == len(s.tokens) and not s.shared_prefix
+                 else "adopted"] += 1
+        name = self.cfg.name
+        for source, k in rows.items():
+            CONV_STATE_ROWS_TOTAL.inc(k, model=name, source=source)
+        CONV_STATE_REPREFILL_TOKENS_TOTAL.inc(reprefill, model=name)
+        tick_note(state_rows_carried=rows["carried"],
+                  state_rows_adopted=rows["adopted"],
+                  state_rows_cold=rows["zero"],
+                  state_reprefill_tokens=reprefill)
+
     def _note_selection(self, kv_reads: int, pairs: int, ctx, seg,
                         fwd) -> None:
         """Book one tick of a model whose attention selects its keys: the
@@ -2358,7 +2448,7 @@ class GenerateEngine:
         quantized; plain cache bytes otherwise) — the shared byte rate
         for resources attribution, /api/kv compression and planning."""
         from quoracle_tpu.models.quant import kv_token_bytes
-        if self.cfg.latent is not None:    # never int8
+        if not self.cfg.plain:             # never int8
             return self.cfg.kv_bytes_per_token(
                 dtype_bytes=jnp.dtype(self.pool_dtype).itemsize)
         return kv_token_bytes(
@@ -2379,6 +2469,10 @@ class GenerateEngine:
             "kv_bytes_per_token_bf16": bf16_rate,
             "kv_compression": round(bf16_rate / rate, 3) if rate else None,
             "resident_kv_tokens": self.sessions.max_tokens,
+            # what rides every page beside its tokens' rows: the conv
+            # layers' state at the page's end (0 without conv layers)
+            "state_bytes_per_record": self.cfg.state_bytes_per_record(
+                jnp.dtype(self.pool_dtype).itemsize),
         }
 
     def _ensure_pool(self) -> None:
@@ -2394,7 +2488,22 @@ class GenerateEngine:
         stays None, as the scale pools do), and with an indexer a second,
         narrower one under ``st.v`` for the tokens' index keys: the same
         page ids address both, so sessions, the prefix cache and eviction
-        carry a token's two rows together. The serving programs carry
+        carry a token's two rows together. Only ATTENTION layers have
+        pages (``cfg.n_attn_layers``; a pool is indexed by a layer's
+        place among them). A model with conv layers holds, under
+        ``st.state``, ``[n_conv_layers · n_pages, state_lanes]`` (row
+        ``layer · n_pages + page``; stored flat, because ``n_pages`` is no
+        multiple of a tile's rows and merging the two dimensions inside a
+        program would copy the pool): ONE
+        record a page a conv layer, the layer's state (its last
+        ``conv_cache - 1`` conv inputs) after the LAST token written to
+        that page. A full page's record is therefore the state at the
+        page's end, which is what a session that adopts the page from the
+        prefix cache must start from; a session's last page's record is
+        the state at the session's end, which is what its next turn
+        continues from. The page id addresses both, so reference counts,
+        copy-on-write, the radix cache and eviction carry a page's record
+        with the page. The serving programs carry
         these two buffers through their layer scan and decode loop and
         update them in place; who wants ``[…, KV, hd]`` takes a view —
         a reshape of the fresh rows on the device, of the pages on the
@@ -2408,7 +2517,7 @@ class GenerateEngine:
         if st.k is not None:
             return
         lanes = self.cfg.kv_pools
-        shape = (self.cfg.n_layers, st.n_pages, st.page)
+        shape = (self.cfg.n_attn_layers, st.n_pages, st.page)
         sh = None
         if self.mesh is not None:
             # created in its sharding: no chip ever holds the whole pool
@@ -2426,6 +2535,9 @@ class GenerateEngine:
                       self.cfg.n_kv_heads, st.page)
             st.k_scale = jnp.ones(sshape, jnp.float32)
             st.v_scale = jnp.ones(sshape, jnp.float32)
+        if self.cfg.n_conv_layers:
+            st.state = jnp.zeros((self.cfg.n_conv_layers * st.n_pages,
+                                  self.cfg.state_lanes), self.pool_dtype)
         st.k, st.v = k, v
 
     @staticmethod
@@ -2813,6 +2925,20 @@ class GenerateEngine:
         if verify is not None:
             k_arr, kmax, need_probs = verify
             widx = np.zeros((R, kmax), np.int32)
+        conv = None
+        if st.state is not None:
+            # transformer.ConvTick's fields after the pool: the record each
+            # row starts from, where each flat token's predecessors lie
+            # (from its row and its place in its chunk; padding: its own
+            # neighbours, which no one reads), and the tokens after which
+            # the state is recorded — every one that ends a page and each
+            # row's last — with their pages
+            c_src = np.full((R,), -1, np.int32)
+            c_row = np.zeros((TB,), np.int32)
+            c_idx = np.full((TB,), TB, np.int32)
+            rec_src = np.zeros((TB // page + 2 * R,), np.int32)
+            rec_dst = np.full(rec_src.shape, st.n_pages, np.int32)
+            n_rec = 0
         cur = 0
         for i in range(n):
             s, nb = segs[i], nb_rows[i]
@@ -2829,6 +2955,16 @@ class GenerateEngine:
             bmeta[2, blk] = np.minimum(TQ, s - np.arange(nb) * TQ)
             bmeta[3, blk] = i
             last_idx[i] = cur + s - 1
+            if st.state is not None:
+                if pre:
+                    c_src[i] = dst[i, (pre - 1) // page]
+                c_row[cur:cur + s] = i
+                c_idx[cur:cur + s] = np.arange(s)
+                ends = np.flatnonzero((pos + 1) % page == 0)
+                ends = np.union1d(ends, [s - 1])
+                rec_src[n_rec:n_rec + len(ends)] = cur + ends
+                rec_dst[n_rec:n_rec + len(ends)] = dst[i, pos[ends] // page]
+                n_rec += len(ends)
             r_tables[i, :maxp] = dst[i]
             r_pool_lens[i] = kv_len
             r_off[i] = int(off_arr[i])
@@ -2838,6 +2974,10 @@ class GenerateEngine:
                     0, s - 1)
             cur += nb * TQ
         self._pending.padded_tokens = TB
+        if st.state is not None:
+            conv = tuple(jnp.asarray(a) for a in (
+                c_src, conv_past(c_row, c_idx, self.cfg.conv_cache),
+                rec_src, rec_dst))
         # the same blocks grouped for the attention kernel's walk: up to
         # ``tile`` tokens of a row read its pages once between them
         # (where the kernel walks block by block, the blocks are the walk)
@@ -2852,7 +2992,7 @@ class GenerateEngine:
         # (a latent pool's kernel has no such walk)
         shared = shared_walks(r_tables, r_pool_lens, page,
                               self.cfg.sliding_window) \
-            if self.cfg.plain and verify is None else None
+            if self.cfg.latent is None and verify is None else None
 
         if verify is not None:
             self._pending.shape_key = ("ragged_verify", TB, R, maxp_p2,
@@ -2883,19 +3023,19 @@ class GenerateEngine:
         # the chunk's end): what the attention kernel streams each step
         tick_note(context_tokens=int(r_pool_lens.sum()))
         tick_phase("dispatch_prefill")
-        last_logits, st.k, st.v, st.k_scale, st.v_scale, moe_pre = \
-            self._step_paged_ragged(
+        (last_logits, st.k, st.v, st.k_scale, st.v_scale, moe_pre,
+         st.state) = self._step_paged_ragged(
                 self.params, st.k, st.v, st.k_scale, st.v_scale,
                 jnp.asarray(flat_tok),
                 jnp.asarray(flat_pos), jnp.asarray(r_tables),
                 jnp.asarray(bmeta), tiles, jnp.asarray(flat_dst),
-                jnp.asarray(last_idx), tq=TQ, tile=tile)
+                jnp.asarray(last_idx), st.state, conv, tq=TQ, tile=tile)
         tick_phase("wait_prefill")
         jax.block_until_ready(last_logits)  # phase fence: prefill done
         t_prefill = time.monotonic()
         tick_phase("dispatch_decode")
         (out, n_emitted, final_lens, st.k, st.v, st.k_scale, st.v_scale,
-         jstate_f, moe_dec) = \
+         jstate_f, moe_dec, st.state) = \
             self._step_paged_decode_ragged(
                 self.params, st.k, st.v, st.k_scale, st.v_scale,
                 jnp.asarray(r_tables),
@@ -2903,7 +3043,7 @@ class GenerateEngine:
                 jnp.asarray(r_pool_lens), jnp.asarray(r_off), last_logits,
                 rng_key, jnp.asarray(r_temp), jnp.asarray(r_top),
                 jnp.asarray(r_active), jnp.asarray(r_limits), json_table,
-                js_dev, max_new=max_new)
+                js_dev, st.state, max_new=max_new)
         tick_phase("wait_decode")
         out = np.asarray(out)
         n_emitted = np.asarray(n_emitted)
@@ -2945,7 +3085,7 @@ class GenerateEngine:
             streamed += shared_in
             tick_note(attn_shared_rows=int((skip > 0).sum()),
                       attn_shared_pages=int(skip[shared[1, :n] > 0].sum()))
-            L = self.cfg.n_layers
+            L = self.cfg.n_attn_layers
             ATTN_SHARED_KV_TOKENS_TOTAL.inc(needed * L, model=self.cfg.name,
                                             kind="needed")
             ATTN_SHARED_KV_TOKENS_TOTAL.inc(shared_in * L,

@@ -33,7 +33,11 @@ Invariants (asserted by tests/test_prefix_cache.py):
   I2  tree page content is immutable: writers either rewrite a shared page
       byte-identically (the gather scatter inside the identical-prefix
       region) or COW-swap it — a cached block's KV never changes under a
-      reader;
+      reader; nor does what rides the page beside its rows (a model with
+      conv layers keeps the layers' state at the page's end under the same
+      page id, GenerateEngine._ensure_pool: a full page is never written
+      again, so its record stands, and a COW swap leaves it with the
+      donor; tests/test_shortconv_moe.py);
   I3  sessions hold contiguous root-path references, so iterative
       unreferenced-LEAF eviction reaches exactly the reclaimable nodes.
 

@@ -103,8 +103,10 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16,
     L, D, F = cfg.n_layers, cfg.dim, cfg.ffn_dim
     H, KV, HD = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     if not cfg.plain:
-        assert mesh is None, "latent/expert models are not sharded yet"
-        return _init_params_stacks(cfg, k_embed, k_layers, k_head, dtype)
+        assert mesh is None, "latent/expert/hybrid models are not sharded yet"
+        init = _init_params_stacks if cfg.latent is not None \
+            else _init_params_pattern
+        return init(cfg, k_embed, k_layers, k_head, dtype)
     if mesh is not None:
         from jax.sharding import NamedSharding
         from quoracle_tpu.parallel.mesh import param_specs
@@ -254,6 +256,86 @@ def _init_params_stacks(cfg: ModelConfig, k_embed, k_layers, k_head,
     return params
 
 
+# Leaves of one layer of a PATTERN model (per-head attention or short conv,
+# dense feed-forward or experts), in the order that numbers their keys
+# (``_init_params_pattern``): (name, shape, fan-in).
+def _pattern_leaves(cfg: ModelConfig, mixer: str, ff: str) -> list:
+    D, H, KV, HD = cfg.dim, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+    if mixer == "conv":
+        K = cfg.conv_cache
+        leaves = [("w_in", (D, 3 * D), D), ("w_conv", (K, D), K),
+                  ("w_out", (D, D), D)]
+    else:
+        leaves = [("wq", (D, H * HD), D), ("wk", (D, KV * HD), D),
+                  ("wv", (D, KV * HD), D), ("wo", (H * HD, D), H * HD)]
+    if ff == "dense":
+        F = cfg.ffn_dim
+        return leaves + [("w_gate", (D, F), D), ("w_up", (D, F), D),
+                         ("w_down", (F, D), F)]
+    m = cfg.moe
+    Fe, Fs = m.expert_dim, m.expert_dim * m.n_shared
+    if m.router_bias:
+        leaves += [("router_bias", (m.n_routed,), 10_000)]
+    leaves += [("router", (D, m.n_routed), D),
+               ("we_gate", (D, Fe), D), ("we_up", (D, Fe), D),
+               ("we_down", (Fe, D), Fe)]
+    if m.n_shared:
+        leaves += [("ws_gate", (D, Fs), D), ("ws_up", (D, Fs), D),
+                   ("ws_down", (Fs, D), Fs)]
+    return leaves
+
+
+def _init_params_pattern(cfg: ModelConfig, k_embed, k_layers, k_head,
+                         dtype) -> dict:
+    """Random init of a model whose layers differ in kind
+    (``cfg.layer_plan``): ``params["segments"]`` holds, for each of the
+    plan's three segments (lead, period, tail), a tuple over the segment's
+    layer positions of that position's leaves, stacked over the segment's
+    repeats. THE RULE (the benchmark's reference draws the same bits from
+    it): the leaf numbered ``i`` in ``_pattern_leaves`` of position ``q``
+    of segment ``s`` is normal/sqrt(fan-in) rounded to ``dtype``, drawn at
+    its stacked shape ``[repeats, ...]`` from ``fold_in(fold_in(fold_in(
+    k_layers, s), q), i)``; a routed-expert leaf ``[repeats, n_held, ...]``
+    draws expert ``e`` (its global number) at ``[repeats, ...]`` from
+    ``fold_in(that key, e)``; ``router_bias`` is float32, 0.01 × normal;
+    norms are one; embed and lm_head as for every model."""
+    D = cfg.dim
+    params = {
+        "embed": _normal_leaf(k_embed, (cfg.vocab_size, D), D, dtype, None),
+        "final_norm": jnp.ones((D,), dtype),
+    }
+    if not cfg.tie_embeddings:
+        params["lm_head"] = _normal_leaf(k_head, (D, cfg.vocab_size), D,
+                                         dtype, None)
+    segments = []
+    for s, (kinds, n) in enumerate(cfg.layer_plan):
+        positions = []
+        for q, (mixer, ff) in enumerate(kinds if n else ()):
+            kq = jax.random.fold_in(jax.random.fold_in(k_layers, s), q)
+            leaves = {"attn_norm": jnp.ones((n, D), dtype),
+                      "mlp_norm": jnp.ones((n, D), dtype)}
+            if mixer == "attention" and cfg.qk_norm:
+                leaves["q_norm"] = jnp.ones((n, cfg.head_dim), dtype)
+                leaves["k_norm"] = jnp.ones((n, cfg.head_dim), dtype)
+            for i, (leaf, shape, fan_in) in enumerate(
+                    _pattern_leaves(cfg, mixer, ff)):
+                k = jax.random.fold_in(kq, i)
+                if leaf == "router_bias":
+                    leaves[leaf] = _normal_leaf(k, (n, *shape), fan_in,
+                                                jnp.float32, None)
+                elif leaf.startswith("we_"):
+                    leaves[leaf] = _expert_leaf(
+                        k, cfg.moe.held_start, cfg.moe.n_held, (n, *shape),
+                        fan_in, dtype)
+                else:
+                    leaves[leaf] = _normal_leaf(k, (n, *shape), fan_in,
+                                                dtype, None)
+            positions.append(leaves)
+        segments.append(tuple(positions))
+    params["segments"] = tuple(segments)
+    return params
+
+
 def rmsnorm(x: jax.Array, w: jax.Array, eps: float, plus_one: bool) -> jax.Array:
     xf = x.astype(jnp.float32)
     normed = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
@@ -348,12 +430,14 @@ def rope(x: jax.Array, positions: jax.Array, theta: float,
     return out.astype(x.dtype)
 
 
+_ACTIVATIONS = {"silu": jax.nn.silu,
+                "gelu": functools.partial(jax.nn.gelu, approximate=True)}
+
+
 def _activation(x: jax.Array, kind: str) -> jax.Array:
-    if kind == "silu":
-        return jax.nn.silu(x)
-    if kind == "gelu":
-        return jax.nn.gelu(x, approximate=True)
-    raise ValueError(f"unknown activation {kind!r}")
+    if kind not in _ACTIVATIONS:
+        raise ValueError(f"unknown activation {kind!r}")
+    return _ACTIVATIONS[kind](x)
 
 
 def _embed_lookup(params: dict, tokens: jax.Array) -> jax.Array:
@@ -397,8 +481,9 @@ def _qkv(x: jax.Array, p: dict, cfg: ModelConfig, B: int, T: int,
          positions: jax.Array) -> tuple[jax.Array, jax.Array, jax.Array]:
     """The shared attention-input block: rmsnorm → q/k/v projections
     (+ optional bias) reshaped to head layout, weights dequantized on
-    the fly when quantized (scope ``qkv``), then rotary embedding of q
-    and k at ``positions`` (scope ``rope``)."""
+    the fly when quantized, with ``cfg.qk_norm`` an RMSNorm over each
+    head's values of q and k (scope ``qkv`` ⊃ ``qk_norm``), then rotary
+    embedding of q and k at ``positions`` (scope ``rope``)."""
     with jax.named_scope("qkv"):
         h = rmsnorm(x, p["attn_norm"], cfg.norm_eps, cfg.rmsnorm_plus_one)
         q = jnp.einsum("btd,dh->bth", h, dequant_weight(p["wq"], h.dtype))
@@ -409,6 +494,12 @@ def _qkv(x: jax.Array, p: dict, cfg: ModelConfig, B: int, T: int,
         q = q.reshape(B, T, cfg.n_heads, cfg.head_dim)
         k = k.reshape(B, T, cfg.n_kv_heads, cfg.head_dim)
         v = v.reshape(B, T, cfg.n_kv_heads, cfg.head_dim)
+        if cfg.qk_norm:
+            with jax.named_scope("qk_norm"):
+                q = rmsnorm(q, p["q_norm"], cfg.norm_eps,
+                            cfg.rmsnorm_plus_one)
+                k = rmsnorm(k, p["k_norm"], cfg.norm_eps,
+                            cfg.rmsnorm_plus_one)
     with jax.named_scope("rope"):
         q = rope(q, positions, cfg.rope_theta, cfg.rope_scaling)
         k = rope(k, positions, cfg.rope_theta, cfg.rope_scaling)
@@ -565,10 +656,11 @@ def moe_select(logits: jax.Array, m,
     [T, k] float32): sigmoid scores; the experts fall into ``n_group``
     groups, a group scores the sum of its two largest, the ``topk_group``
     best groups stay; the ``k`` largest scores inside them are selected;
-    gates are the selected scores over their sum (``norm_topk``) times
-    ``routed_scale``. Ties go to the lower index. With ``bias`` (float32
-    [n_routed], the ``noaux_tc`` correction) groups and experts are
-    chosen by score + bias; the gates are the bare scores of the chosen."""
+    gates are the selected scores over their sum plus ``gate_eps``
+    (``norm_topk``) times ``routed_scale``. Ties go to the lower index.
+    With ``bias`` (float32 [n_routed], the ``noaux_tc`` correction) groups
+    and experts are chosen by score + bias; the gates are the bare scores
+    of the chosen."""
     T, E = logits.shape
     s = jax.nn.sigmoid(logits.astype(jnp.float32))
     pick = s if bias is None else s + bias
@@ -584,7 +676,7 @@ def moe_select(logits: jax.Array, m,
     if bias is not None:
         gates = jnp.take_along_axis(s, idx, axis=-1)
     if m.norm_topk:
-        gates = gates / (gates.sum(-1, keepdims=True) + 1e-20)
+        gates = gates / (gates.sum(-1, keepdims=True) + m.gate_eps)
     return idx.astype(jnp.int32), gates * m.routed_scale
 
 
@@ -653,14 +745,78 @@ def _routed_experts(h: jax.Array, idx: jax.Array, gates: jax.Array,
     return out, stats
 
 
+def _routed_experts_grouped(h: jax.Array, idx: jax.Array, gates: jax.Array,
+                            w: tuple, layer, m, act: str, valid: jax.Array,
+                            interpret: bool = False
+                            ) -> tuple[jax.Array, jax.Array]:
+    """``_routed_experts`` with the blocks' work in ONE kernel
+    (ops/grouped_experts.py): the same grouping — an expert's assignments
+    in their own order, cut into blocks of one expert's rows — laid out
+    for all blocks at once and without a sort: an assignment's row is its
+    expert's first block plus its place among that expert's assignments (a
+    running count down a one-hot table). Then a gather of the blocks'
+    tokens, the kernel over the blocks THAT EXIST (a scalar table names
+    each block's expert, so the weights read are those the routing
+    reached), and each token's sum over its own assignments' rows, gated,
+    in float32. A score of device operations a layer where the loop runs
+    eighteen a block. Returns ([T, D] float32, int32 [2, n_held]: each
+    held expert's assignments, and 1 where it has any — what of
+    ``_routed_experts``' four counts differs from layer to layer; the
+    caller sums them and adds the tick's own, ``moe_counts``)."""
+    from quoracle_tpu.ops.grouped_experts import grouped_ffn
+    T, D = h.shape
+    k, E = m.per_token, m.n_held
+    A = T * k
+    blk = min(MOE_BLOCK, -(-T // 16) * 16)
+    NB = min(E, A) + A // blk            # Σ ceil(c_e / blk) is at most this
+    local = idx - m.held_start
+    held = (local >= 0) & (local < E) & valid[:, None]
+    hot = (jnp.where(held, local, E).reshape(A, 1)
+           == jnp.arange(E, dtype=jnp.int32)).astype(jnp.int32)   # [A, E]
+    ahead = jnp.cumsum(hot, axis=0) - hot    # of its expert's, before it
+    counts = hot.sum(0)                                           # [E]
+    n_blk = (counts + blk - 1) // blk
+    blk_end = jnp.cumsum(n_blk)
+    first = blk_end - n_blk                  # an expert's first block
+    e_b = jnp.minimum((blk_end[None, :] <= jnp.arange(
+        NB, dtype=jnp.int32)[:, None]).sum(1, dtype=jnp.int32), E - 1)
+    row = jnp.where(held.reshape(A),
+                    (hot * (first * blk + ahead)).sum(1), NB * blk)
+    tok = jnp.zeros((NB * blk,), jnp.int32).at[row].set(
+        jnp.arange(A, dtype=jnp.int32) // k, mode="drop",
+        unique_indices=True)                 # rows no one has: token 0
+    y = grouped_ffn(h[tok].reshape(NB, blk, D), *w, layer, e_b, blk_end[-1],
+                    act=_ACTIVATIONS[act], interpret=interpret)
+    mine = y.reshape(NB * blk, D)[jnp.minimum(row, NB * blk - 1)]
+    out = jnp.where(held[..., None], gates[..., None]
+                    * mine.reshape(T, k, D).astype(jnp.float32), 0.0).sum(1)
+    return out, jnp.stack([counts, (counts > 0).astype(jnp.int32)])
+
+
+def moe_counts(per_expert: jax.Array, valid: jax.Array, m,
+               n_layers: int) -> jax.Array:
+    """``_routed_experts``' int32 [4] summed over ``n_layers`` expert
+    layers of one forward, from the sum of ``_routed_experts_grouped``'s
+    [2, n_held] over them and the tick's valid tokens."""
+    return jnp.stack([
+        valid.sum(dtype=jnp.int32) * (m.per_token * n_layers),
+        per_expert[0].sum(), per_expert[1].sum(),
+        valid.any().astype(jnp.int32) * n_layers])
+
+
 @jax.named_scope("mlp")
 def _moe(x: jax.Array, p: dict, experts: tuple, layer, cfg: ModelConfig,
-         valid: jax.Array) -> tuple[jax.Array, jax.Array]:
+         valid: jax.Array, grouped: bool = False,
+         interpret: bool = False) -> tuple[jax.Array, jax.Array]:
     """The expert layer of a flat tick ``x [1, T, D]``: router over ALL
     ``n_routed`` experts in float32 (scope ``router``), the held experts'
-    part of the routed sum (``routed_experts``), the shared expert
-    (``shared_expert``), residual. Returns (x, stats of
-    ``_routed_experts``)."""
+    part of the routed sum (``routed_experts``), the shared expert where
+    the model has one (``shared_expert``), residual. Returns (x, the counts
+    of ``_routed_experts``, or of ``_routed_experts_grouped``).
+    ``grouped``: the blocks in one kernel a layer
+    (``_routed_experts_grouped``) in place of the loop over them; the
+    pattern forward asks for it on the TPU — the latent models keep the
+    loop, and with it their accepted programs."""
     m = cfg.moe
     h = rmsnorm(x, p["mlp_norm"], cfg.norm_eps, cfg.rmsnorm_plus_one)[0]
     with jax.named_scope("router"):
@@ -669,13 +825,19 @@ def _moe(x: jax.Array, p: dict, experts: tuple, layer, cfg: ModelConfig,
                          precision=jax.lax.Precision.HIGHEST)
         idx, gates = moe_select(logits, m, p.get("router_bias"))
     with jax.named_scope("routed_experts"):
-        routed, stats = _routed_experts(h, idx, gates, experts, layer, m,
-                                        cfg.activation, valid)
-    with jax.named_scope("shared_expert"):
-        shared = _gated(h, p["ws_gate"], p["ws_up"], p["ws_down"],
-                        cfg.activation)
-    y = (routed + shared.astype(jnp.float32)).astype(x.dtype)
-    return x + y[None], stats
+        if grouped:
+            routed, stats = _routed_experts_grouped(
+                h, idx, gates, experts, layer, m, cfg.activation, valid,
+                interpret)
+        else:
+            routed, stats = _routed_experts(h, idx, gates, experts, layer,
+                                            m, cfg.activation, valid)
+    if m.n_shared:
+        with jax.named_scope("shared_expert"):
+            shared = _gated(h, p["ws_gate"], p["ws_up"], p["ws_down"],
+                            cfg.activation)
+        routed = routed + shared.astype(jnp.float32)
+    return x + routed.astype(x.dtype)[None], stats
 
 
 @jax.named_scope("final_norm")
@@ -794,6 +956,8 @@ def forward_hidden_ragged(
     tiles: Optional[jax.Array] = None,     # [6, NT] int32: the blocks in
     tile: int = 0,                         # tiles of <= ``tile`` tokens
     shared: Optional[jax.Array] = None,    # [2 + SHARED_ROWS, R] int32
+    conv: Optional["ConvTick"] = None,     # a model with conv layers: its
+                                           # state pool and the tick's use
 ) -> tuple:
     """UNIFIED ragged forward (ISSUE 8): one launch per layer over a
     token-major flattened batch of rows with arbitrary query lengths —
@@ -807,7 +971,10 @@ def forward_hidden_ragged(
     the chunk KV written (the scales None as they came, on unquantized
     pools; ``moe_stats`` None but for a model with expert layers, see
     ``_forward_hidden_ragged_stacks``, which serves latent attention and
-    expert layers under this same contract).
+    expert layers under this same contract, as
+    ``_forward_hidden_ragged_pattern`` serves a model whose layers differ
+    in kind; with ``conv``, what a model with conv layers hands in, the
+    tuple has a seventh member, the state pool updated).
 
     The pools never move (PR 25): they ride the layer scan as a CARRY,
     whole and in their stored lane-flat layout, each layer writes its Tp
@@ -831,13 +998,19 @@ def forward_hidden_ragged(
     ``row_tables``; the decode step, one token a row) it walks the pages
     that rows have in common once for all of them: schedules, not layouts
     — nothing else here reads either."""
-    if not cfg.plain:
+    if cfg.latent is not None:
         assert shard is None and k_scale is None and tiles is None \
             and shared is None, "latent/expert models: no tp shards, " \
             "no int8 pages, no tiles, no shared walk"
         return _forward_hidden_ragged_stacks(
             params, cfg, tokens, positions, k_pool, v_pool, row_tables,
             block_meta, flat_dst, tq, interpret)
+    if not cfg.plain:
+        assert shard is None and k_scale is None, \
+            "expert/hybrid models: no tp shards, no int8 pages"
+        return _forward_hidden_ragged_pattern(
+            params, cfg, tokens, positions, k_pool, v_pool, row_tables,
+            block_meta, flat_dst, tq, interpret, tiles, tile, shared, conv)
     from quoracle_tpu.ops.paged_attention import ragged_attend_auto
     B, Tp = tokens.shape       # B == 1: the flat layout is the batch
     L, n_pages, page, lanes = k_pool.shape
@@ -1037,6 +1210,203 @@ def _forward_hidden_ragged_stacks(params, cfg, tokens, positions, k_pool,
     if cfg.indexer is not None:
         k_pool, v_pool = k_pool
     return _final_norm(x, params, cfg), k_pool, v_pool, None, None, stats
+
+
+class ConvTick(NamedTuple):
+    """What a tick needs of the conv layers' state (generate.py
+    ``_ensure_pool``: one record a page a conv layer, the state at the end
+    of the page's tokens) — the pool, updated in place as the K/V pools
+    are, and where this tick's rows read and write it. A forward reads
+    every conv layer's records ONCE, before its layers, and writes them
+    once, behind them (``_forward_hidden_ragged_pattern``):
+
+    ``src [R]``        the page whose record each row's chunk starts from
+                       (the page of its last resident token); negative: the
+                       row starts a sequence, from zeros
+    ``past [Tp, K-1]`` where the token ``j + 1`` places before each flat
+                       token in ITS row lies (generate.py ``conv_past``): a
+                       flat
+                       position, or ``Tp + row · (K-1) + i`` for entry ``i``
+                       of the row's record (a chunk's first ``conv_cache -
+                       1`` tokens take predecessors from the record, never
+                       from the flat layout's neighbours). None: one token
+                       a row, flat token ``r`` row ``r``'s (a decode step):
+                       every predecessor is the record's
+    ``rec_src [NR]``   flat tokens after which the state is recorded: a
+                       row's last, and every token that ends a page (None
+                       with ``past``: every row's one token)
+    ``rec_dst [NR]``   the page each is recorded under; ``n_pages`` or more
+                       drops the write (unused slots, rows that are done)"""
+
+    pool: jax.Array    # [n_conv_layers · n_pages, (conv_cache - 1) · dim]:
+                       # flat over (layer, page) as STORED — n_pages is no
+                       # multiple of a tile's rows, so merging the two
+                       # dimensions in the program would copy the pool
+    src: jax.Array
+    past: Optional[jax.Array]
+    rec_src: Optional[jax.Array]
+    rec_dst: jax.Array
+
+
+@jax.named_scope("conv")
+def _short_conv(x: jax.Array, p: dict, cfg: ModelConfig, prev: jax.Array,
+                tick: ConvTick) -> tuple[jax.Array, jax.Array]:
+    """The gated short convolution of a flat tick ``x [1, Tp, D]`` (LFM2):
+    ``[B ‖ C ‖ x̃] = norm(x) W_in``; ``z = B ⊙ x̃``; a depthwise causal
+    convolution of ``conv_cache`` taps over ``z`` along each ROW's tokens,
+    the taps before a chunk's first token read from the row's record
+    ``prev [R, (K-1)·D]`` (scope ``conv_in``, ``conv_taps``); ``y = C ⊙
+    conv``; residual + ``y W_out`` (``conv_out``). ``z`` is rounded to the
+    activation type before the taps, so a value read back from a record
+    is the value a longer chunk would have had in hand. Returns (x, the
+    state after the tick's recorded tokens ``[NR, (K-1)·D]``: their last
+    ``conv_cache - 1`` values of ``z``, the oldest first)."""
+    K, D = cfg.conv_cache, cfg.dim
+    with jax.named_scope("conv_in"):
+        u = rmsnorm(x, p["attn_norm"], cfg.norm_eps, cfg.rmsnorm_plus_one)
+        bcx = jnp.einsum("btd,df->btf", u, p["w_in"])[0]        # [Tp, 3D]
+        z = bcx[:, :D] * bcx[:, 2 * D:]
+    with jax.named_scope("conv_taps"):
+        # back[j-1][t] = z of the token j places before t in t's row
+        if tick.past is None:
+            back = [prev[:, (K - 1 - j) * D:(K - j) * D].astype(z.dtype)
+                    for j in range(1, K)]
+        else:
+            past = jnp.concatenate([z, prev.reshape(-1, D).astype(
+                z.dtype)])[tick.past]
+            back = [past[:, j - 1] for j in range(1, K)]
+        w = p["w_conv"].astype(jnp.float32)                     # [K, D]
+        c = w[K - 1] * z.astype(jnp.float32) + sum(
+            w[K - 1 - j] * back[j - 1].astype(jnp.float32)
+            for j in range(1, K))
+        y = (bcx[:, D:2 * D].astype(jnp.float32) * c).astype(x.dtype)
+        last = jnp.concatenate(
+            [back[j - 1] for j in range(K - 2, 0, -1)] + [z], axis=-1)
+        if tick.rec_src is not None:
+            last = last[tick.rec_src]
+    with jax.named_scope("conv_out"):
+        x = x + jnp.einsum("td,dD->tD", y, p["w_out"])[None]
+    return x, last
+
+
+def _forward_hidden_ragged_pattern(params, cfg, tokens, positions, k_pool,
+                                   v_pool, row_tables, block_meta, flat_dst,
+                                   tq, interpret, tiles, tile, shared,
+                                   conv) -> tuple:
+    """``forward_hidden_ragged`` for a model whose layers differ in KIND
+    (``cfg.layer_plan``): per-head attention or a gated short convolution,
+    a dense feed-forward or routed experts. The same contract and the
+    same in-place pools, with two differences. The K/V pools hold the
+    ATTENTION layers only, ``[n_attn_layers, n_pages, page, KV·hd]``,
+    indexed by a layer's place among them. And the conv layers' state
+    lies beside them (``conv``, a ``ConvTick``): every conv layer's
+    records are read from its pool once, before the layers, the layers'
+    new records ride the scans in a buffer of the tick's size, and one
+    write behind the layers puts them under their pages, in place (scopes
+    ``conv_taps`` and ``state_write``, as ``_short_conv``'s). Each segment
+    of the plan is one ``lax.scan`` over its repeats with the segment's
+    layers unrolled in the body: the leading dense layers once, the period
+    as often as it fits, the rest of a last period once — so program size
+    follows the period, not the depth. The routed experts' weights stay
+    out of the scanned slices. Returns the dense function's tuple with the
+    expert layers' int32 [4] (None without experts) and, seventh, the
+    state pool (None without conv layers)."""
+    from quoracle_tpu.ops.paged_attention import _on_tpu, ragged_attend_auto
+    # the experts' blocks in one kernel a layer on the TPU (interpreted
+    # where a test asks for the kernels), the loop over blocks elsewhere
+    grouped = bool(interpret) or _on_tpu()
+    A, n_pages, page, lanes = k_pool.shape
+    n_tok = n_pages * page
+    Tp = tokens.shape[1]
+    x = _embed(params, cfg, tokens)
+    keep = flat_dst < n_tok
+    n_conv = cfg.n_conv_layers
+    assert (conv is not None) == (n_conv > 0)
+    prev = recs = None
+    if conv is not None:
+        of_layer = jnp.arange(n_conv, dtype=jnp.int32)[:, None] \
+            * (conv.pool.shape[0] // n_conv)
+        with jax.named_scope("conv"), jax.named_scope("conv_taps"):
+            prev = jnp.where((conv.src >= 0)[None, :, None], conv.pool[
+                of_layer + jnp.maximum(conv.src, 0)], 0)  # [n_conv, R, ·]
+        recs = jnp.zeros((n_conv, conv.rec_dst.shape[0],
+                          conv.pool.shape[1]), conv.pool.dtype)
+
+    def attention(x, kp, vp, p, a):
+        q, k, v = _qkv(x, p, cfg, 1, Tp, positions)
+        with jax.named_scope("kv_write"):
+            dst = jnp.where(keep, a * n_tok + flat_dst, A * n_tok)
+            kp = kp.reshape(A * n_tok, lanes).at[dst].set(
+                k[0].reshape(Tp, lanes).astype(kp.dtype),
+                mode="drop").reshape(kp.shape)
+            vp = vp.reshape(A * n_tok, lanes).at[dst].set(
+                v[0].reshape(Tp, lanes).astype(vp.dtype),
+                mode="drop").reshape(vp.shape)
+        with jax.named_scope("attn"):
+            attn = ragged_attend_auto(
+                q[0], kp, vp, row_tables, block_meta, a, tq=tq,
+                interpret=interpret, tiles=tiles, tile=tile,
+                shared=shared)[None]
+        return _attn_out(x, attn.astype(x.dtype), p, cfg), kp, vp
+
+    def segment(carry, stacked, kinds, n, a0, c0):
+        """``n`` repeats of ``kinds``, whose first attention layer is the
+        ``a0``-th of the model's and first conv layer the ``c0``-th."""
+        a_per = sum(m == "attention" for m, _ in kinds)
+        c_per = len(kinds) - a_per
+        experts = [tuple(p[k] for k in ("we_gate", "we_up", "we_down"))
+                   if ff == "experts" else None
+                   for p, (_, ff) in zip(stacked, kinds)]
+        rest = tuple({k: v for k, v in p.items() if not k.startswith("we_")}
+                     for p in stacked)
+
+        def body(carry, scanned):
+            x, kp, vp, recs, stats = carry
+            ps, rep = scanned
+            a, c = a0 + rep * a_per, c0 + rep * c_per
+            for p, w, (mixer, ff) in zip(ps, experts, kinds):
+                if mixer == "attention":
+                    x, kp, vp = attention(x, kp, vp, p, a)
+                    a = a + 1
+                else:
+                    x, last = _short_conv(x, p, cfg, prev[c], conv)
+                    recs = jax.lax.dynamic_update_index_in_dim(
+                        recs, last.astype(recs.dtype), c, 0)
+                    c = c + 1
+                if ff == "dense":
+                    x = _mlp(x, p, cfg)
+                else:
+                    x, st = _moe(x, p, w, rep, cfg, keep, grouped,
+                                 bool(interpret))
+                    stats = stats + st
+            return (x, kp, vp, recs, stats), None
+
+        carry, _ = jax.lax.scan(body, carry,
+                                (rest, jnp.arange(n, dtype=jnp.int32)))
+        return carry, a0 + n * a_per, c0 + n * c_per
+
+    stats = None
+    if cfg.moe is not None:
+        stats = jnp.zeros((2, cfg.moe.n_held) if grouped else (4,), jnp.int32)
+    carry = (x, k_pool, v_pool, recs, stats)
+    a0 = c0 = 0
+    with jax.named_scope("layers"):
+        for stacked, (kinds, n) in zip(params["segments"], cfg.layer_plan):
+            if n:
+                carry, a0, c0 = segment(carry, stacked, kinds, n, a0, c0)
+    x, k_pool, v_pool, recs, stats = carry
+    state = None
+    if conv is not None:
+        with jax.named_scope("conv"), jax.named_scope("state_write"):
+            dst = jnp.where(conv.rec_dst < n_pages, of_layer + conv.rec_dst,
+                            conv.pool.shape[0])
+            state = conv.pool.at[dst.reshape(-1)].set(
+                recs.reshape(-1, recs.shape[-1]), mode="drop")
+    if grouped and stats is not None:
+        stats = moe_counts(stats, keep, cfg.moe,
+                           cfg.n_layers - cfg.n_dense_layers)
+    return (_final_norm(x, params, cfg), k_pool, v_pool, None, None, stats,
+            state)
 
 
 def project_logits(params: dict, cfg: ModelConfig, hidden: jax.Array) -> jax.Array:

@@ -1,0 +1,131 @@
+"""The routed experts' gated feed-forward over expert-aligned blocks as ONE
+kernel a layer (``grouped_ffn``), for a model whose layer reaches tens of
+small experts a decode step (LFM2: 64 experts of 18.9 MB, 4 a token, so 8
+rows reach about 21 of them in each of 8 layers).
+
+The loop over blocks in ``transformer._routed_experts`` computes the same
+thing with some 18 device operations a block: at 170 blocks a step that
+is 3,000 operations a step, which the chip runs well enough but which a
+profiler session cannot carry (a million events in five traced seconds
+take it four minutes to hand over; PERF.md §6, PR 33). Here a grid
+program per (block, slice of the expert's width) reads that block's
+expert straight from the stacked weights — its number comes from a
+scalar-prefetched table, so a step streams the experts its rows reached
+and no others — and the three matmuls and the activation between them
+happen in VMEM: one operation a layer.
+
+The loop stays the oracle (tests/test_shortconv_moe.py) and what the CPU
+and the latent models run.
+
+No reference counterpart: the reference never executes a model
+(SURVEY.md §2.8 — all inference was remote HTTPS).
+"""
+
+from __future__ import annotations
+
+import functools
+from typing import Callable
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+# Lanes of an expert's width one grid program takes: three weight slices
+# of [D, 512] / [512, D], twice each for the pipeline, are 12 MiB at
+# D = 2048.
+WIDTH_SLICES = (512, 384, 256, 128)
+
+
+def width_slice(expert_dim: int) -> int:
+    """The widest of ``WIDTH_SLICES`` that divides the expert's width,
+    else the width whole (toy sizes)."""
+    return next((s for s in WIDTH_SLICES if expert_dim % s == 0),
+                expert_dim)
+
+
+def _ffn_kernel(expert_ref, meta_ref, x_ref, wg_ref, wu_ref, wd_ref,
+                out_ref, acc_ref, *, act: Callable, n_slices: int):
+    b, f = pl.program_id(0), pl.program_id(1)
+
+    @pl.when(b < meta_ref[0])               # a block that exists
+    def _():
+        @pl.when(f == 0)
+        def _():
+            acc_ref[...] = jnp.zeros_like(acc_ref)
+
+        x = x_ref[...]                                        # [blk, D]
+        g = jnp.dot(x, wg_ref[...], preferred_element_type=jnp.float32)
+        u = jnp.dot(x, wu_ref[...], preferred_element_type=jnp.float32)
+        a = (act(g) * u).astype(x.dtype)                      # [blk, tf]
+        acc_ref[...] += jnp.dot(a, wd_ref[...],
+                                preferred_element_type=jnp.float32)
+
+        @pl.when(f == n_slices - 1)
+        def _():
+            out_ref[...] = acc_ref[...].astype(out_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("act", "interpret"))
+def grouped_ffn(
+    x: jax.Array,          # [NB, blk, D]: each block's tokens, gathered
+    wg: jax.Array,         # [L, E, D, F]  the experts' stacked weights,
+    wu: jax.Array,         # [L, E, D, F]  whole: they stay in HBM and a
+    wd: jax.Array,         # [L, E, F, D]  program reads its slice
+    layer,                 # int32 scalar: which of the L layers
+    block_expert: jax.Array,   # [NB] int32: each block's expert
+    n_blocks,              # int32 scalar: blocks that exist (the first)
+    act: Callable,
+    interpret: bool = False,
+) -> jax.Array:
+    """``act(x_b W_g[e_b]) ⊙ (x_b W_u[e_b])) W_d[e_b]`` for every block
+    ``b < n_blocks``, [NB, blk, D] in ``x``'s type; the blocks behind
+    them are left unwritten (no program of theirs moves or multiplies
+    anything: their index maps stay on the last block that exists, so the
+    pipeline has nothing to fetch). float32 between the matmuls and in the
+    sum over the width's slices, rounded once at the end."""
+    NB, blk, D = x.shape
+    F = wg.shape[-1]
+    tf = width_slice(F)
+    n_slices = F // tf
+    meta = jnp.stack([jnp.asarray(n_blocks, jnp.int32),
+                      jnp.asarray(layer, jnp.int32)])
+
+    def block(b, meta):
+        return jnp.maximum(jnp.minimum(b, meta[0] - 1), 0)
+
+    def slice_(b, f, meta):
+        return jnp.where(b < meta[0], f, n_slices - 1)
+
+    def x_map(b, f, expert, meta):
+        return block(b, meta), 0, 0
+
+    def up_map(b, f, expert, meta):
+        return meta[1], expert[block(b, meta)], 0, slice_(b, f, meta)
+
+    def down_map(b, f, expert, meta):
+        return meta[1], expert[block(b, meta)], slice_(b, f, meta), 0
+
+    return pl.pallas_call(
+        functools.partial(_ffn_kernel, act=act, n_slices=n_slices),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,                  # block_expert, meta
+            grid=(NB, n_slices),
+            in_specs=[
+                pl.BlockSpec((None, blk, D), x_map),
+                pl.BlockSpec((None, None, D, tf), up_map),
+                pl.BlockSpec((None, None, D, tf), up_map),
+                pl.BlockSpec((None, None, tf, D), down_map),
+            ],
+            out_specs=pl.BlockSpec((None, blk, D), x_map),
+            scratch_shapes=[pltpu.VMEM((blk, D), jnp.float32)],
+        ),
+        out_shape=jax.ShapeDtypeStruct((NB, blk, D), x.dtype),
+        # a block's result is summed over the width's slices in order, and
+        # the blocks that do not exist rest on the last one's buffers
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=48 << 20),
+        interpret=interpret,
+        name="routed_experts_ffn",
+    )(block_expert.astype(jnp.int32), meta, x, wg, wu, wd)

@@ -384,10 +384,21 @@ def ragged_attend(
     if quant:
         pools += [k_scale.astype(jnp.float32), v_scale.astype(jnp.float32)]
     hd_p = max(128, ((hd + 127) // 128) * 128)
-    if hd_p != hd:
+    pack = hd_p // hd if hd_p % hd == 0 and KV % (hd_p // hd) == 0 else 1
+    if pack > 1:
+        # heads narrower than a lane tile (LFM2: 64): ``pack`` kv heads
+        # that lie side by side in the stored row are read as ONE head of
+        # 128 lanes, and a query keeps its own head's lanes and zeros in
+        # its neighbours' (``_pack_queries``), so its scores are its own
+        # head's and the pool is streamed as it lies, unpadded. The
+        # kernel multiplies zeros in place of moving the pool.
+        q = _pack_queries(q, KV, pack)
+        KV //= pack
+    elif hd_p != hd:
         # tiny test models only (every production head_dim is a lane
-        # multiple and never comes here): the one layer the call reads,
-        # its head_dim zero-padded to the lane width, read as layer 0
+        # multiple or packs, and never comes here): the one layer the
+        # call reads, its head_dim zero-padded to the lane width, read as
+        # layer 0
         q = jnp.pad(q, [(0, 0), (0, 0), (0, hd_p - hd)])
         one = [jax.lax.dynamic_index_in_dim(p, layer, 0, keepdims=True)
                for p in pools]
@@ -431,7 +442,7 @@ def ragged_attend(
             name="ragged_attend",                  # pinned, as below
         )(row_tables.astype(jnp.int32), tiles.astype(jnp.int32),
           layer.reshape(1), q, *pools)[0]
-        return out[..., :hd]
+        return _own_lanes(out, KV, pack, hd)
     qb = q.reshape(NB, tq, H, hd_p)
     kernel = functools.partial(
         _ragged_kernel, page=page, n_kv=KV, hd=hd_p, tq=tq,
@@ -480,7 +491,32 @@ def ragged_attend(
         # the benchmark's metric files match on that name
         name="ragged_attend",
     )(*prefetch, qb, *more_in, *pools)[0]
-    return out.reshape(NB * tq, H, hd_p)[..., :hd]
+    return _own_lanes(out.reshape(NB * tq, H, hd_p), KV, pack, hd)
+
+
+def _pack_queries(q: jax.Array, n_kv: int, pack: int) -> jax.Array:
+    """[T, H, hd] -> [T, H, pack·hd]: query head h of kv head g keeps its
+    values in lanes ``(g % pack)·hd ..`` of the packed head ``g // pack``
+    and holds zeros elsewhere."""
+    T, H, hd = q.shape
+    G = H // n_kv
+    q = q.reshape(T, n_kv // pack, pack, G, 1, hd)
+    own = jnp.eye(pack, dtype=q.dtype)[None, None, :, None, :, None]
+    return (q * own).reshape(T, H, pack * hd)
+
+
+def _own_lanes(out: jax.Array, n_kv: int, pack: int, hd: int) -> jax.Array:
+    """The kernel's [T, H, hd_p] output as [T, H, hd]: a packed head's
+    output holds all ``pack`` heads' values side by side, and a query
+    head takes its own kv head's (``n_kv`` counts the PACKED heads);
+    unpacked, the lanes before the zero padding."""
+    if pack == 1:
+        return out[..., :hd]
+    T, H, _ = out.shape
+    G = H // (n_kv * pack)
+    out = out.reshape(T, n_kv, pack, G, pack, hd)
+    return jnp.stack([out[:, :, a, :, a] for a in range(pack)],
+                     axis=2).reshape(T, H, hd)
 
 
 # ---------------------------------------------------------------------------

@@ -687,6 +687,128 @@ def test_selecting_decode_program_leaves_both_pools_where_they_are(
     assert mem.temp_size_in_bytes < tokens * 640 * 2
 
 
+# --- conv layers' state beside the paged K/V (ISSUE 33) ----------------------
+
+def _hybrid_programs(S, monkeypatch, cfg, tb=256, width=8, rows=8):
+    """Both serving programs of a model with conv layers, compiled for the
+    v5e with donation as served: [(optimized HLO, memory analysis)] of the
+    chunk forward and the decode loop, and the engine's store."""
+    from quoracle_tpu.models.generate import RAGGED_TQ, GenerateEngine
+    from quoracle_tpu.models.tokenizer import ByteTokenizer
+    from quoracle_tpu.models.transformer import init_params
+    monkeypatch.setattr(pa, "_on_tpu", lambda: True)
+    params = jax.tree.map(
+        lambda x: S(x.shape, x.dtype),
+        jax.eval_shape(lambda k: init_params(cfg, k), jax.random.PRNGKey(0)))
+    eng = GenerateEngine(cfg, params, ByteTokenizer(),
+                         max_seq=min(cfg.context_window, 131072))
+    st = eng.sessions
+    kv = S((cfg.n_attn_layers, st.n_pages, st.page, cfg.kv_pools[0]),
+           eng.pool_dtype)
+    state = S((cfg.n_conv_layers * st.n_pages, cfg.state_lanes),
+              eng.pool_dtype)
+    R, i32, f32 = rows, jnp.int32, jnp.float32
+    slots = pa.ragged_tile_slots(tb // RAGGED_TQ, R, RAGGED_TQ,
+                                 eng._ragged_tile)
+    n_rec = tb // st.page + 2 * R
+    chunk = eng._step_paged_ragged.lower(
+        params, kv, kv, None, None, S((tb,), i32), S((tb,), i32),
+        S((R, width), i32), S((4, tb // RAGGED_TQ), i32),
+        S((6, slots), i32), S((tb,), i32), S((R,), i32), state,
+        (S((R,), i32), S((tb, cfg.conv_cache - 1), i32), S((n_rec,), i32),
+         S((n_rec,), i32)), tq=RAGGED_TQ, tile=eng._ragged_tile).compile()
+    decode = eng._step_paged_decode_ragged.lower(
+        params, kv, kv, None, None, S((R, width), i32),
+        S((2 + pa.SHARED_ROWS, R), i32), S((R,), i32), S((R,), i32),
+        S((R, cfg.vocab_size), f32), S((2,), jnp.uint32), S((R,), f32),
+        S((R,), f32), S((R,), jnp.bool_), S((R,), i32), None, None, state,
+        max_new=32).compile()
+    return [(c.as_text(), c.memory_analysis()) for c in (chunk, decode)], st
+
+
+def _narrow_hybrid(periods):
+    """LFM2's layer pattern and head geometry (heads of 64: two kv heads
+    a lane tile, 512 lanes a token) under narrow weights, with thousands
+    of pages as the benchmark has: a state pool of a few megabytes the
+    compiler would prefetch into fast memory whole, which no serving pool
+    fits."""
+    from quoracle_tpu.models.config import ModelConfig, MoEConfig
+    return ModelConfig(
+        name=f"narrow-shortconv-moe-{periods}", vocab_size=512, dim=512,
+        n_layers=1 + 4 * periods, n_heads=32, n_kv_heads=8, head_dim=64,
+        ffn_dim=512, rope_theta=1e6, tie_embeddings=True, qk_norm=True,
+        layer_types=("conv",) + ("attention", "conv", "conv", "conv")
+        * periods,
+        moe=MoEConfig(n_routed=16, n_held=16, per_token=4, expert_dim=128,
+                      n_shared=0, first_dense=1, router_bias=True,
+                      gate_eps=1e-6), context_window=16384)
+
+
+def test_hybrid_programs_carry_pools_and_state_in_place(on_v5e, monkeypatch):
+    """A model with conv layers on the v5e: both programs carry the two
+    K/V pools (attention layers only) AND the state pool through the
+    segment scans — and the decode loop — in place: one attention kernel
+    (the period's one attention layer; heads of 64 packed two a lane
+    tile, so no re-layout of the pool) and one kernel for each expert
+    layer's blocks, nothing that moves a layer of either pool, all three
+    donated into their outputs. And the scan over whole
+    periods holds: at two periods (the benchmark's cut) and at three the
+    programs have the same kernels and the same fusions, so program size
+    and compile time do not follow the depth."""
+    counts = []
+    for periods in (2, 3):
+        cfg = _narrow_hybrid(periods)
+        programs, st = _hybrid_programs(on_v5e, monkeypatch, cfg)
+        kv_elems = st.n_pages * st.page * 512
+        state_elems = st.n_pages * cfg.state_lanes
+        assert cfg.n_conv_layers == 1 + 3 * periods and st.n_pages > 2048
+        for hlo, mem in programs:
+            calls = [ln for ln in hlo.splitlines()
+                     if "tpu_custom_call" in ln]
+            # the period's attention layer, and its four expert layers'
+            # blocks in one kernel each (ops/grouped_experts.py)
+            assert sum("%ragged_attend" in c for c in calls) == 1
+            assert sum("%routed_experts_ffn" in c for c in calls) == 4
+            assert len(calls) == 5
+            assert "kv_layout" not in hlo
+            assert pool_moves(hlo, kv_elems) == []
+            # what consumes the state pool and makes a few rows of it is
+            # a row's record being read, not the pool being moved
+            assert [m for m in pool_moves(hlo, state_elems)
+                    if not (m[0] == "fusion" and m[2] < state_elems)] == []
+            assert mem.alias_size_in_bytes >= 2 * (
+                2 * cfg.n_attn_layers * kv_elems
+                + cfg.n_conv_layers * state_elems)
+        counts.append([len(re.findall(r" fusion\(", hlo))
+                       for hlo, _ in programs])
+    assert counts[0] == counts[1]
+
+
+@pytest.mark.slow
+def test_hybrid_programs_at_lfm2_widths(on_v5e, monkeypatch):
+    """The benchmark's `lfm2-24b-a2b-l9` at its published widths (`-m
+    slow`: a minute of every core, which the suite's timed tests cannot
+    spare; run by hand before chip time): both programs compile with pools and state in place, and
+    arguments plus temporaries stay under 13 GiB of the chip's 16."""
+    from benchmark import configs
+    from benchmark.families import shortconv_moe
+    from quoracle_tpu.models.config import get_model_config
+    cfg = get_model_config(shortconv_moe.register(
+        configs.load_config("lfm2-24b-a2b-l9")))
+    programs, st = _hybrid_programs(on_v5e, monkeypatch, cfg, tb=2048,
+                                    width=32)
+    assert st.n_pages == 4097 and cfg.state_lanes == 4096
+    for hlo, mem in programs:
+        assert hlo.count("tpu_custom_call") == 5
+        assert pool_moves(hlo, st.n_pages * st.page * 512) == []
+        assert [m for m in pool_moves(hlo, st.n_pages * 4096)
+                if not (m[0] == "fusion" and m[2] < st.n_pages * 4096)] == []
+        assert mem.alias_size_in_bytes >= 2 * st.n_pages * (
+            2 * 2 * st.page * 512 + 7 * 4096)
+        assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+                < 13 * 2 ** 30)
+
+
 # --- tp wrappers: shard_map around a pallas_call ----------------------------
 
 
