@@ -645,8 +645,8 @@ def _latent_attn_out(x: jax.Array, attn: jax.Array, p: dict,
         w_v = p["wkv_b"].reshape(la.kv_rank, H,
                                  la.nope_dim + la.v_dim)[..., la.nope_dim:]
         o = jnp.einsum("thc,chv->thv", attn.astype(x.dtype), w_v)
-    wo = p["wo"].reshape(H, la.v_dim, cfg.dim)
-    return x + jnp.einsum("thv,hvD->tD", o, wo)[None]
+    # flat over H·v: the layer scan's slice of wo fuses INTO this matmul
+    return x + jnp.einsum("tk,kD->tD", o.reshape(len(o), -1), p["wo"])[None]
 
 
 def moe_select(logits: jax.Array, m,

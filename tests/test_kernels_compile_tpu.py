@@ -21,7 +21,10 @@ installed ``shard_map`` rejected at trace time.
 Between the two: the compiled decode PROGRAM (``step_paged_decode_ragged``,
 donation and all) is read for anything that moves the page pool — the
 copies, slices and relayouts that were two thirds of a decode step until
-PR 25 carried the pool in place (PERF.md §6).
+PR 25 carried the pool in place (PERF.md §6) — and both serving programs
+of a latent model for a layer scan's slice of a stacked WEIGHT that does
+not fit fast memory and is copied through HBM instead (``weight_moves``;
+a fifth of a decode step at 128 heads until PR 36).
 """
 
 import functools
@@ -436,9 +439,206 @@ def test_decode_program_leaves_the_pool_where_it_is_at_mistral_widths(
     assert mem.temp_size_in_bytes < cfg.n_layers * layer_elems * 2
 
 
-# --- what the decode program runs on every step, and what only on request ---
+# --- a layer's slice of a stacked weight, inside a layer scan (ISSUE 36) -----
 
 _CALLED = re.compile(r"(\w+)=\{?((?:%[\w.\-]+(?:, )?)+)\}?")
+_RESULT = re.compile(
+    r"\s*(ROOT )?%(\S+) = (\w+)\[([\d,]*)\](\S*) ([\w\-]+)\((?:%([\w.\-]+))?")
+_WIDTH = {"pred": 1, "s8": 1, "u8": 1, "bf16": 2, "f16": 2, "s16": 2,
+          "u16": 2, "f32": 4, "s32": 4, "u32": 4}
+
+
+def weight_moves(hlo: str, min_bytes: int) -> list:
+    """The instructions INSIDE a ``while`` body of an optimized HLO module
+    (or in what a body calls: an inner loop, a branch) whose result is a
+    ``dynamic-slice`` or a ``copy`` — bare, or as the root of a fusion,
+    behind bitcasts — of at least ``min_bytes`` of an array the loop
+    carries, and does NOT lie in fast memory (no ``S(1)`` in its layout):
+    [(opcode, name, bytes)]. A layer scan's slice of a stacked weight is
+    such a result, and so is a re-laid copy of one. One that fits fast
+    memory is the read of that weight; one that does not is written back
+    to HBM and read again by its consumer, a copy that no arithmetic asks
+    for (PERF.md §6, PR 36: 224 MiB of ``wo`` in every expert layer of
+    every decode step at 128 heads). The entry computation is not looked
+    at: what it copies, it copies once a call."""
+    comps, comp = {}, None
+    for ln in hlo.splitlines():
+        head = re.match(r"(?:ENTRY )?%(\S+) \(.*\{\s*$", ln)
+        if head:
+            comp = comps[head.group(1)] = {"insts": {}, "root": None,
+                                           "calls": [], "fuses": {},
+                                           "bodies": []}
+            continue
+        if comp is None or " = " not in ln:
+            continue
+        # a loop's or a branch's result may be a tuple, which _RESULT does
+        # not match: what a line calls is read off the line itself
+        m = _RESULT.match(ln)
+        for key, names in _CALLED.findall(ln):
+            called = re.findall(r"%([\w.\-]+)", names)
+            if key == "calls" and " fusion(" in ln:
+                if m:                   # a fused body is no computation
+                    comp["fuses"][m.group(2)] = called[0]   # of the loop's
+            elif key == "body":
+                comp["bodies"] += called
+            else:
+                comp["calls"] += called
+        if not m:
+            continue
+        root, name, dtype, dims, layout, op, first = m.groups()
+        n = _WIDTH.get(dtype, 4)
+        for d in filter(None, dims.split(",")):
+            n *= int(d)
+        comp["insts"][name] = (op, n, layout, first)
+        if root:
+            comp["root"] = name
+    todo = [b for c in comps.values() for b in c["bodies"]]
+    in_loop = set(todo)
+    while todo:
+        c = comps[todo.pop()]
+        for n in c["calls"] + c["bodies"]:
+            if n in comps and n not in in_loop:
+                in_loop.add(n)
+                todo.append(n)
+
+    def maker(c, name):
+        """The opcode that makes ``name``'s bytes: a fusion's is its
+        root's, behind bitcasts."""
+        op, body = c["insts"][name][0], comps.get(c["fuses"].get(name))
+        if op != "fusion" or body is None:
+            return op
+        r = body["root"]
+        while r in body["insts"] and body["insts"][r][0] == "bitcast":
+            r = body["insts"][r][3]
+        return body["insts"][r][0] if r in body["insts"] else op
+
+    def moved(cn, name):
+        """Does ``name`` hold bytes the loop CARRIES (an element of the
+        body's parameter: a stacked weight, a pool), moved by slices and
+        copies alone? What the body computed and then copied is an
+        activation, not looked at."""
+        c, moves = comps[cn], False
+        while name in c["insts"]:
+            op = maker(c, name)
+            if op in ("get-tuple-element", "parameter"):
+                return moves
+            if op not in ("bitcast", "dynamic-slice", "copy"):
+                return False
+            moves = moves or op != "bitcast"
+            name = c["insts"][name][3]
+        return False
+    return [(comps[cn]["insts"][name][0], name, n)
+            for cn in sorted(in_loop)
+            for name, (op, n, layout, _) in comps[cn]["insts"].items()
+            if n >= min_bytes and "S(1)" not in layout and op != "bitcast"
+            and moved(cn, name)]
+
+
+def test_weight_moves_tells_a_copy_through_hbm_from_a_read():
+    """The reader itself, on the parent's lines (PR 33's decode program at
+    `deepseek-v3.2-ep16-l5`, layouts as the compiler printed them): the
+    layer scan's slice of `wo`, 224 MiB, has no `S(1)`: it is the
+    `constant_dynamic-slice_fusion.13` that was the largest device
+    operation of the cell; `wq_b`'s, 72 MiB, lies in fast memory. The
+    entry's copy of a whole stacked weight is once a call, not a layer's;
+    a slice inside another fusion is nothing of its own; an inner loop's
+    body counts; a slice re-laid into HBM counts (the chunk forward's
+    `copy.163` of `wq_b`), a copy of what the body computed does not; a
+    small slice does not."""
+    hlo = """
+%fused_computation.276 (param_0.3675: bf16[4,16384,7168], param_1.4086: s32[]) -> bf16[1,16384,7168] {
+  %param_0.3675 = bf16[4,16384,7168]{2,1,0:T(8,128)(2,1)} parameter(0)
+  %param_1.4086 = s32[]{:T(128)} parameter(1)
+  %constant.4341 = s32[]{:T(128)} constant(0)
+  ROOT %dynamic_slice.350 = bf16[1,16384,7168]{2,1,0:T(8,128)(2,1)} dynamic-slice(%param_0.3675, %param_1.4086, %constant.4341, %constant.4341), dynamic_slice_sizes={1,16384,7168}
+}
+
+%fused_computation.284 (param_0.1: bf16[4,1536,24576], param_1.1: s32[]) -> bf16[1,1536,24576] {
+  %param_0.1 = bf16[4,1536,24576]{1,2,0:T(8,128)(2,1)} parameter(0)
+  %param_1.1 = s32[]{:T(128)} parameter(1)
+  %constant.1 = s32[]{:T(128)} constant(0)
+  ROOT %dynamic_slice.348 = bf16[1,1536,24576]{1,2,0:T(8,128)(2,1)S(1)} dynamic-slice(%param_0.1, %param_1.1, %constant.1, %constant.1), dynamic_slice_sizes={1,1536,24576}
+}
+
+%fused_computation.624 (param_0.2: bf16[4,16384,7168], param_1.2: s32[]) -> bf16[16384,7168] {
+  %param_0.2 = bf16[4,16384,7168]{2,1,0:T(8,128)(2,1)} parameter(0)
+  %param_1.2 = s32[]{:T(128)} parameter(1)
+  %constant.2 = s32[]{:T(128)} constant(0)
+  %dynamic_slice.514 = bf16[1,16384,7168]{2,1,0:T(8,128)(2,1)} dynamic-slice(%param_0.2, %param_1.2, %constant.2, %constant.2), dynamic_slice_sizes={1,16384,7168}
+  ROOT %bitcast.9 = bf16[16384,7168]{1,0:T(8,128)(2,1)} bitcast(%dynamic_slice.514)
+}
+
+%fused_computation.700 (param_0.3: bf16[8,16384], param_1.3: bf16[4,16384,7168], param_2.3: s32[]) -> bf16[8,7168] {
+  %param_0.3 = bf16[8,16384]{1,0:T(8,128)(2,1)} parameter(0)
+  %param_1.3 = bf16[4,16384,7168]{2,1,0:T(8,128)(2,1)} parameter(1)
+  %param_2.3 = s32[]{:T(128)} parameter(2)
+  %constant.3 = s32[]{:T(128)} constant(0)
+  %dynamic_slice.7 = bf16[1,16384,7168]{2,1,0:T(8,128)(2,1)} dynamic-slice(%param_1.3, %param_2.3, %constant.3, %constant.3), dynamic_slice_sizes={1,16384,7168}
+  %bitcast.7 = bf16[16384,7168]{1,0:T(8,128)(2,1)} bitcast(%dynamic_slice.7)
+  ROOT %convolution.7 = bf16[8,7168]{1,0:T(8,128)(2,1)} convolution(%param_0.3, %bitcast.7), dim_labels=bf_io->bf
+}
+
+%chunk_body (c: (s32[], bf16[4,16384,7168])) -> (s32[], bf16[4,16384,7168]) {
+  %c = (s32[]{:T(128)}, bf16[4,16384,7168]{2,1,0:T(8,128)(2,1)}) parameter(0)
+  %i.1 = s32[]{:T(128)} get-tuple-element(%c), index=0
+  %w.1 = bf16[4,16384,7168]{2,1,0:T(8,128)(2,1)} get-tuple-element(%c), index=1
+  %dynamic-slice_bitcast_fusion.11 = bf16[16384,7168]{1,0:T(8,128)(2,1)} fusion(%w.1, %i.1), kind=kLoop, calls=%fused_computation.624
+  ROOT %t.1 = (s32[]{:T(128)}, bf16[4,16384,7168]{2,1,0:T(8,128)(2,1)}) tuple(%i.1, %w.1)
+}
+
+%chunk_cond (c: (s32[], bf16[4,16384,7168])) -> pred[] {
+  %c = (s32[]{:T(128)}, bf16[4,16384,7168]{2,1,0:T(8,128)(2,1)}) parameter(0)
+  ROOT %p = pred[]{:T(512)} constant(false)
+}
+
+%layer_body (c: (s32[], bf16[4,16384,7168], bf16[4,1536,24576], bf16[8,16384])) -> (s32[], bf16[4,16384,7168], bf16[4,1536,24576], bf16[8,16384]) {
+  %c = (s32[]{:T(128)}, bf16[4,16384,7168]{2,1,0:T(8,128)(2,1)}, bf16[4,1536,24576]{1,2,0:T(8,128)(2,1)}, bf16[8,16384]{1,0:T(8,128)(2,1)}) parameter(0)
+  %i = s32[]{:T(128)} get-tuple-element(%c), index=0
+  %wo = bf16[4,16384,7168]{2,1,0:T(8,128)(2,1)} get-tuple-element(%c), index=1
+  %wq = bf16[4,1536,24576]{1,2,0:T(8,128)(2,1)} get-tuple-element(%c), index=2
+  %o = bf16[8,16384]{1,0:T(8,128)(2,1)} get-tuple-element(%c), index=3
+  %constant_dynamic-slice_fusion.15 = bf16[1,1536,24576]{1,2,0:T(8,128)(2,1)S(1)} fusion(%wq, %i), kind=kLoop, calls=%fused_computation.284
+  %constant_dynamic-slice_fusion.13 = bf16[1,16384,7168]{2,1,0:T(8,128)(2,1)} fusion(%wo, %i), kind=kLoop, calls=%fused_computation.276
+  %bitcast.13 = bf16[16384,7168]{1,0:T(8,128)(2,1)} bitcast(%constant_dynamic-slice_fusion.13)
+  %fusion.700 = bf16[8,7168]{1,0:T(8,128)(2,1)} fusion(%o, %wo, %i), kind=kOutput, calls=%fused_computation.700
+  %dynamic-slice.3 = s32[1]{0:T(128)} dynamic-slice(%i, %i), dynamic_slice_sizes={1}
+  %copy.163 = bf16[1,1536,24576]{2,1,0:T(8,128)(2,1)} copy(%constant_dynamic-slice_fusion.15)
+  %add.1 = bf16[8,16384]{1,0:T(8,128)(2,1)} add(%o, %o)
+  %copy.168 = bf16[8,16384]{0,1:T(8,128)(2,1)} copy(%add.1)
+  %t.2 = (s32[]{:T(128)}, bf16[4,16384,7168]{2,1,0:T(8,128)(2,1)}) tuple(%i, %wo)
+  %while.2 = (s32[]{:T(128)}, bf16[4,16384,7168]{2,1,0:T(8,128)(2,1)}) while(%t.2), condition=%chunk_cond, body=%chunk_body
+  ROOT %t = (s32[]{:T(128)}, bf16[4,16384,7168]{2,1,0:T(8,128)(2,1)}, bf16[4,1536,24576]{1,2,0:T(8,128)(2,1)}, bf16[8,16384]{1,0:T(8,128)(2,1)}) tuple(%i, %wo, %wq, %o)
+}
+
+%layer_cond (c: (s32[], bf16[4,16384,7168], bf16[4,1536,24576], bf16[8,16384])) -> pred[] {
+  %c = (s32[]{:T(128)}, bf16[4,16384,7168]{2,1,0:T(8,128)(2,1)}, bf16[4,1536,24576]{1,2,0:T(8,128)(2,1)}, bf16[8,16384]{1,0:T(8,128)(2,1)}) parameter(0)
+  ROOT %p = pred[]{:T(512)} constant(false)
+}
+
+ENTRY %main (wo: bf16[4,16384,7168], wq: bf16[4,1536,24576], o: bf16[8,16384]) -> bf16[8,16384] {
+  %wo = bf16[4,16384,7168]{2,1,0:T(8,128)(2,1)} parameter(0)
+  %wq = bf16[4,1536,24576]{2,1,0:T(8,128)(2,1)} parameter(1)
+  %o = bf16[8,16384]{1,0:T(8,128)(2,1)} parameter(2)
+  %copy.154 = bf16[4,1536,24576]{1,2,0:T(8,128)(2,1)} copy(%wq)
+  %z = s32[]{:T(128)} constant(0)
+  %t = (s32[]{:T(128)}, bf16[4,16384,7168]{2,1,0:T(8,128)(2,1)}, bf16[4,1536,24576]{1,2,0:T(8,128)(2,1)}, bf16[8,16384]{1,0:T(8,128)(2,1)}) tuple(%z, %wo, %copy.154, %o)
+  %while.1 = (s32[]{:T(128)}, bf16[4,16384,7168]{2,1,0:T(8,128)(2,1)}, bf16[4,1536,24576]{1,2,0:T(8,128)(2,1)}, bf16[8,16384]{1,0:T(8,128)(2,1)}) while(%t), condition=%layer_cond, body=%layer_body
+  ROOT %r = bf16[8,16384]{1,0:T(8,128)(2,1)} get-tuple-element(%while.1), index=3
+}
+"""
+    assert weight_moves(hlo, 64 << 20) == [
+        ("fusion", "dynamic-slice_bitcast_fusion.11", 234881024),
+        ("fusion", "constant_dynamic-slice_fusion.13", 234881024),
+        ("copy", "copy.163", 75497472)]
+    assert weight_moves(hlo, 128 << 20) == weight_moves(hlo, 64 << 20)[:2]
+    # with no threshold to speak of: still nothing that lies in fast
+    # memory, and nothing the body made itself
+    assert [n for _, n, _ in weight_moves(hlo, 1)] == [
+        "dynamic-slice_bitcast_fusion.11",
+        "constant_dynamic-slice_fusion.13", "dynamic-slice.3", "copy.163"]
+
+
+# --- what the decode program runs on every step, and what only on request ---
 
 
 def on_every_path(hlo: str, opcode: str) -> tuple[list, int]:
@@ -685,6 +885,114 @@ def test_selecting_decode_program_leaves_both_pools_where_they_are(
     assert pool_moves(hlo, tokens * 128) == []
     assert mem.alias_size_in_bytes >= cfg.n_layers * tokens * (640 + 128) * 2
     assert mem.temp_size_in_bytes < tokens * 640 * 2
+
+
+# --- a latent model's output projection reads wo[layer] where it lies -------
+
+def _latent_programs(S, monkeypatch, cfg, tb, width, rows=8, max_seq=1024):
+    """Both serving programs of a latent, routed-expert model (with or
+    without an indexer), compiled for the v5e with donation as served:
+    [(optimized HLO, memory analysis)] of the chunk forward (``tb`` flat
+    tokens) and the decode loop."""
+    from quoracle_tpu.models.generate import RAGGED_TQ, GenerateEngine
+    from quoracle_tpu.models.tokenizer import ByteTokenizer
+    from quoracle_tpu.models.transformer import init_params
+    monkeypatch.setattr(pa, "_on_tpu", lambda: True)
+    params = jax.tree.map(
+        lambda x: S(x.shape, x.dtype),
+        jax.eval_shape(lambda k: init_params(cfg, k), jax.random.PRNGKey(0)))
+    eng = GenerateEngine(cfg, params, ByteTokenizer(), max_seq=max_seq)
+    st = eng.sessions
+    pools = [S((cfg.n_layers, st.n_pages, st.page, w), eng.pool_dtype)
+             for w in cfg.kv_pools] + [None] * (2 - len(cfg.kv_pools))
+    R, i32, f32 = rows, jnp.int32, jnp.float32
+    chunk = eng._step_paged_ragged.lower(
+        params, *pools, None, None, S((tb,), i32), S((tb,), i32),
+        S((R, width), i32), S((4, tb // RAGGED_TQ), i32), None,
+        S((tb,), i32), S((R,), i32), tq=RAGGED_TQ, tile=0).compile()
+    decode = eng._step_paged_decode_ragged.lower(
+        params, *pools, None, None, S((R, width), i32), None, S((R,), i32),
+        S((R,), i32), S((R, cfg.vocab_size), f32), S((2,), jnp.uint32),
+        S((R,), f32), S((R,), f32), S((R,), jnp.bool_), S((R,), i32),
+        None, None, max_new=32).compile()
+    return [(c.as_text(), c.memory_analysis()) for c in (chunk, decode)]
+
+
+WO_LAYER_BYTES = 128 * 128 * 7168 * 2     # 224 MiB: no fast memory holds it
+
+
+@pytest.mark.parametrize("indexer", [False, True],
+                         ids=["latent", "sparse-latent"])
+def test_latent_programs_read_a_layer_of_wo_where_it_lies(on_v5e,
+                                                          monkeypatch,
+                                                          indexer):
+    """DeepSeek-V3.2's output projection — 128 heads of 128 into 7,168:
+    a layer of `wo` is 224 MiB, which fast memory cannot hold — under a
+    model whose every other weight is small: neither serving program
+    makes a layer's slice of the stacked weight (3 expert layers) a
+    result of its own in HBM. `_latent_attn_out` contracts `wo` over one
+    flat dimension, so the scan's slice is part of the matmul's fusion,
+    which takes the stacked array and the layer number; contracted over
+    (heads, v) apart, the slice was its own fusion in front of it, 224
+    MiB written and read again in every layer of every decode step
+    (PERF.md §6, PR 36). Temporaries stay under one such slice."""
+    from quoracle_tpu.models.config import (
+        IndexerConfig, LatentConfig, ModelConfig, MoEConfig,
+    )
+    cfg = ModelConfig(
+        name="narrow-but-wo" + ("-indexed" if indexer else ""),
+        vocab_size=512, dim=7168, n_layers=4, n_heads=128, n_kv_heads=128,
+        ffn_dim=256, rope_scaling=("yarn", 40.0, 32.0, 1.0, 4096, 1.0, 1.0),
+        latent=LatentConfig(q_rank=128, kv_rank=128, nope_dim=128,
+                            rope_dim=64, v_dim=128),
+        moe=MoEConfig(n_routed=16, n_held=2, per_token=2, expert_dim=128,
+                      n_group=4, topk_group=2, routed_scale=2.5,
+                      first_dense=1, router_bias=indexer),
+        indexer=IndexerConfig(n_heads=8, head_dim=128, topk=256,
+                              rope_dim=64) if indexer else None)
+    programs = _latent_programs(on_v5e, monkeypatch, cfg, tb=256, width=8)
+    for hlo, mem in programs:
+        assert f"bf16[3,{128 * 128},7168]" in hlo   # the stack, an operand
+        assert weight_moves(hlo, 64 << 20) == []
+        assert mem.temp_size_in_bytes < WO_LAYER_BYTES
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("name,wo_bytes,decode_temp_max,chunk_moves", [
+    # parent (PR 33): decode 953,849,344 with the slice of `wo` in it
+    ("deepseek-v3.2-ep16-l5", WO_LAYER_BYTES, 730_000_000,
+     # the chunk forward re-lays its layer of `wq_b` (contraction minor):
+     # 72 MiB, the parent's too; ROADMAP S13's item, not this reader's
+     [("copy", 1536 * 24576 * 2)]),
+    ("ax-k1-ep16-l7", WO_LAYER_BYTES // 2, None, []),
+])
+def test_latent_programs_at_benchmark_widths(on_v5e, monkeypatch, name,
+                                             wo_bytes, decode_temp_max,
+                                             chunk_moves):
+    """The benchmark's two latent configurations at their published
+    widths (`-m slow`: a minute each; run by hand before chip time), 8
+    rows, tables 128 wide, a 512-token chunk: the decode program moves
+    no weight slice of 64 MiB or more through HBM, the chunk forward
+    none of a layer of `wo`; prints each program's temporaries."""
+    from benchmark import configs
+    from benchmark.families import latent_moe, sparse_latent_moe
+    raw = configs.load_config(name)
+    family = {"latent_moe": latent_moe,
+              "sparse_latent_moe": sparse_latent_moe}[raw["family"]]
+    cfg = get_model_config(family.register(raw))
+    assert cfg.n_heads * cfg.latent.v_dim * cfg.dim * 2 == wo_bytes
+    (chunk, cmem), (decode, dmem) = _latent_programs(
+        on_v5e, monkeypatch, cfg, tb=512, width=128,
+        max_seq=min(cfg.context_window, 16384))
+    print(name, "temp_size_in_bytes: decode", dmem.temp_size_in_bytes,
+          "chunk", cmem.temp_size_in_bytes,
+          "moves: decode", weight_moves(decode, 16 << 20),
+          "chunk", weight_moves(chunk, 16 << 20))
+    assert weight_moves(decode, 64 << 20) == []
+    assert [(op, n) for op, _, n in weight_moves(chunk, 64 << 20)] \
+        == chunk_moves
+    if decode_temp_max:
+        assert dmem.temp_size_in_bytes <= decode_temp_max
 
 
 # --- conv layers' state beside the paged K/V (ISSUE 33) ----------------------

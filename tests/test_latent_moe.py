@@ -199,6 +199,47 @@ def test_folded_attention_is_the_unfolded_one(toy):
     assert np.abs(np.asarray(got - want)).max() < 1e-4
 
 
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("heads", [8, 64])
+def test_the_output_projection_is_the_contraction_over_heads_and_v(toy,
+                                                                   heads,
+                                                                   dtype):
+    """`_latent_attn_out` contracts `wo` over ONE flat dimension of H·v
+    (so that a layer scan's slice of the stacked weight fuses into the
+    matmul, PERF.md §6, PR 36): the same dot products as the contraction
+    over (heads, v) apart, written out here as the function had it, at a
+    ragged number of tokens. float32: the order of the sums alone
+    differs; bfloat16 (products summed in float32, rounded once): at most
+    one rounding of the projection, carried into the rounded sum."""
+    cfg = dataclasses.replace(toy[0], n_heads=heads, n_kv_heads=heads)
+    la = cfg.latent
+    assert (la.kv_rank, la.nope_dim, la.v_dim, cfg.dim) == (32, 16, 16, 64)
+    rng = np.random.default_rng(heads)
+    T = 37
+    x = jnp.asarray(rng.normal(size=(1, T, 64)), dtype)
+    attn = jnp.asarray(rng.normal(size=(T, heads, 32)), jnp.float32)
+    p = {"wkv_b": jnp.asarray(rng.normal(size=(32, heads * 32)) / 6, dtype),
+         "wo": jnp.asarray(rng.normal(size=(heads * 16, 64))
+                           / np.sqrt(heads * 16), dtype)}
+    with jax.default_matmul_precision("highest"):
+        got = tr._latent_attn_out(x, attn, p, cfg)
+        w_v = p["wkv_b"].reshape(32, heads, 32)[..., 16:]
+        o = jnp.einsum("thc,chv->thv", attn.astype(dtype), w_v)
+        y = jnp.einsum("thv,hvD->tD", o, p["wo"].reshape(heads, 16, 64))
+        want = x + y[None]
+    assert got.dtype == dtype and got.shape == (1, T, 64)
+    got, want, y = (np.asarray(a, np.float32) for a in (got, want, y))
+    assert np.abs(y).max() > 1.0
+    if dtype == jnp.float32:
+        assert np.abs(got - want).max() < 1e-6
+    else:
+        def ulp(a):     # a bfloat16 keeps 8 bits: below 2^k, 2^(k-8) apart
+            return 2.0 ** (np.ceil(np.log2(np.maximum(np.abs(a), 1e-30)))
+                           - 8)
+        assert np.all(np.abs(got - want) <= ulp(y)[None] + ulp(want))
+
+
 @pytest.mark.parametrize("tq", [8, 1])
 def test_latent_kernel_is_its_reference(tq):
     """The Pallas kernel (interpret mode) against the gather reference:
@@ -443,6 +484,42 @@ def test_engine_serves_it_on_the_ragged_path(engine, toy):
     q = engine.quant_stats()
     assert q["kv_bytes_per_token"] == 3 * 128 * 4 and not q["quantize_kv"]
     assert "xlatent128-" in engine.kv_signature()
+
+
+# read off the parent commit (PR 33: d58388d) through this fixture's engine
+PARENT_GREEDY = [
+    [358, 161, 448, 120, 20, 439, 372, 335, 178, 509, 214, 488, 321, 296,
+     94, 441, 461, 223, 94, 278, 358, 315, 192, 374],
+    [13, 272, 268, 399, 58, 511, 153, 240, 294, 399, 147, 321, 340, 174,
+     194, 436, 147, 268, 324, 436, 448, 195, 141, 263]]
+PARENT_RESUMED = [321, 294, 104, 10, 401, 142, 385, 9]
+
+
+def serves_the_parents_tokens(engine, greedy, resumed):
+    """Two sessionless rows in one tick — a prompt past a page and a
+    short one (rng 36) — then a session's resumed turn: the tokens
+    recorded at the parent commit."""
+    rng = np.random.default_rng(36)
+    long = [int(t) for t in rng.integers(3, 512, 150)]
+    short = [int(t) for t in rng.integers(3, 512, 21)]
+    res = engine.generate([long, short], temperature=0.0,
+                          max_new_tokens=24)
+    assert [r.token_ids for r in res] == greedy
+    r1 = engine.generate([long], temperature=0.0, max_new_tokens=8,
+                         session_ids=["p"])[0]
+    r2 = engine.generate([long + r1.token_ids + [5, 6, 7]],
+                         temperature=0.0, max_new_tokens=8,
+                         session_ids=["p"])[0]
+    engine.drop_session("p")
+    assert r1.token_ids == greedy[0][:8]
+    assert (r2.token_ids, r2.n_cached_tokens) == (resumed, 157)
+
+
+def test_greedy_tokens_are_the_parents(engine):
+    """PR 36 changed the FORM of the output projection, not what it
+    computes: the toy's greedy tokens through the engine are the ones
+    the parent commit served."""
+    serves_the_parents_tokens(engine, PARENT_GREEDY, PARENT_RESUMED)
 
 
 REFUSALS = {

@@ -257,3 +257,45 @@ def test_an_expert_model_without_conv_layers_is_a_pattern_too():
                          prompt_buckets=(32, 64, 128, 256, 512))
     _, gap = served(eng, ref, [int(t) for t in tokens_of(19, 200)], "a")
     assert gap < TOL and eng.sessions.state is None
+
+
+# -- a change to another family's forward leaves these programs alone -------
+
+def test_both_serving_programs_are_the_parents(engine, toy):
+    """As `tests/test_latent_moe.py` holds the dense decode program to the
+    parent's operation count: this family's two serving programs, lowered
+    at the toy's widths (8 rows, a 256-token chunk), hold the operations
+    they held before the latent models' output projection changed its
+    form (PR 36; read off PR 33's commit d58388d, where both programs
+    also hash to the same StableHLO text)."""
+    import jax
+    from quoracle_tpu.models.generate import RAGGED_TQ
+    from quoracle_tpu.ops import paged_attention as pa
+    cfg, st = toy[0], engine.sessions
+    S, i32, f = jax.ShapeDtypeStruct, jnp.int32, jnp.float32
+    R, W, tb = 8, 4, 256
+    kv = S((cfg.n_attn_layers, st.n_pages, st.page, cfg.kv_pools[0]),
+           engine.pool_dtype)
+    state = S((cfg.n_conv_layers * st.n_pages, cfg.state_lanes),
+              engine.pool_dtype)
+    slots = pa.ragged_tile_slots(tb // RAGGED_TQ, R, RAGGED_TQ,
+                                 engine._ragged_tile)
+    n_rec = tb // st.page + 2 * R
+    chunk = engine._step_paged_ragged.lower(
+        engine.params, kv, kv, None, None, S((tb,), i32), S((tb,), i32),
+        S((R, W), i32), S((4, tb // RAGGED_TQ), i32), S((6, slots), i32),
+        S((tb,), i32), S((R,), i32), state,
+        (S((R,), i32), S((tb, cfg.conv_cache - 1), i32), S((n_rec,), i32),
+         S((n_rec,), i32)), tq=RAGGED_TQ, tile=engine._ragged_tile)
+    decode = engine._step_paged_decode_ragged.lower(
+        engine.params, kv, kv, None, None, S((R, W), i32),
+        S((2 + pa.SHARED_ROWS, R), i32), S((R,), i32), S((R,), i32),
+        S((R, cfg.vocab_size), f), S((2,), jnp.uint32), S((R,), f),
+        S((R,), f), S((R,), jnp.bool_), S((R,), i32), None, None, state,
+        max_new=32)
+    assert [len([ln for ln in low.as_text().splitlines()
+                 if " = " in ln and "stablehlo." in ln])
+            for low in (chunk, decode)] == SHORTCONV_OPS
+
+
+SHORTCONV_OPS = [2031, 2537]        # chunk forward, decode loop

@@ -22,7 +22,9 @@ from benchmark.families import sparse_latent_moe as fam
 from quoracle_tpu.models import transformer as tr
 from quoracle_tpu.models.config import MoEConfig, get_model_config
 from quoracle_tpu.ops import paged_attention as pa
-from tests.test_latent_moe import RAW as AXK1, f32
+from tests.test_latent_moe import (
+    RAW as AXK1, f32, serves_the_parents_tokens,
+)
 
 TOL = 2e-4
 PAGE = 128
@@ -460,6 +462,23 @@ def test_engine_serves_it_and_an_adopted_prefix_is_a_cold_one(engine, toy):
     engine.drop_session("a")
     engine.drop_session("b")
     assert st.free_pages() >= free
+
+
+# read off the parent commit (PR 33: d58388d) through this fixture's engine
+PARENT_GREEDY = [
+    [454, 26, 431, 79, 373, 185, 13, 30, 434, 17, 479, 387, 154, 262, 252,
+     46, 431, 477, 73, 311, 442, 217, 345, 471],
+    [88, 373, 373, 314, 432, 311, 505, 454, 367, 205, 328, 86, 211, 504,
+     122, 331, 52, 495, 322, 225, 84, 86, 115, 181]]
+PARENT_RESUMED = [42, 382, 503, 20, 485, 497, 293, 278]
+
+
+def test_greedy_tokens_are_the_parents(engine):
+    """PR 36 changed the FORM of the output projection (`wo` contracted
+    over one flat dimension, tests/test_latent_moe.py), not what it
+    computes: this toy's greedy tokens through the engine — every query
+    selecting its 16 keys — are the ones the parent commit served."""
+    serves_the_parents_tokens(engine, PARENT_GREEDY, PARENT_RESUMED)
 
 
 def test_a_context_past_the_last_prompt_bucket_lands_on_a_bounded_key(
