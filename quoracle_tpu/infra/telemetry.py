@@ -33,6 +33,7 @@ bit-identical with tracing on or off (ISSUE 2 acceptance).
 from __future__ import annotations
 
 import bisect
+import contextlib
 import itertools
 import threading
 import time
@@ -775,19 +776,82 @@ TICK_PHASE_MS_TOTAL = METRICS.counter(
     "wait_* phases are the device, idle is an empty loop, the rest is "
     "host work between device programs")
 _TICK_NAMES = {p: "qtpu.tick." + p for p in TICK_PHASES}
+# Named operations INSIDE the phases (ISSUE 37): a phase is a lump (the
+# worker's whole path from the splice to the flat layout is ``prepare``),
+# and the chip idles under single operations of it. ``tick_op(name)`` times
+# one: a child annotation ``qtpu.op.<name>`` — NOT ``qtpu.tick.<…>``, which
+# every reader takes for a phase — and integer ns of SELF time on the
+# record (an operation opened inside another is taken out of it), so a
+# phase's own time is its span less the operations inside it. The phases,
+# their tiling and their counter stay as they were. Classes: ``schedule``
+# the batcher's and the layout's own work, ``session`` the session store
+# and the prefix cache, ``transfer`` what crosses to the device,
+# ``observe`` what the instruments cost on the worker's path.
+TICK_OPS: tuple = (
+    # -- schedule
+    "splice",           # _plain_step: r.prompt + r.emitted, budgets, arguments
+    "wave_split",       # generate._prefix_wave_split
+    "layout",           # _generate_impl's row arrays, _run_unified's flat tick
+    "tiles",            # ragged_tiles: the blocks grouped for the kernel walk
+    "shared_walks",     # shared_walks: decode rows whose leading pages agree
+    "results",          # _generate_impl: ids out of the fetched array, decode
+    "retire_rows",      # _plain_step after the engine call, _finish_row
+    # -- session
+    "lock",             # the wait for _paged_lock (generate, verify_chunk)
+    "session_lookup",   # sessions.get, the common prefix with the held tokens
+    "tier_restore",     # tier.restore_session: a hibernated session paged in
+    "prefix_match",     # sessions.match_prefix: the radix match and adoption
+    "page_alloc",       # _run_paged's allocation: alloc, eviction, COW
+    "state_adopt",      # a conv model's tables: the record a row starts from,
+                        # each token's predecessors, the records to write
+    "session_put",      # the stored tokens, put_raw, page release
+    "prefix_insert",    # sessions.insert_prefix: the radix insert
+    # -- transfer
+    "rng",              # next_rng: the split and its unpacking (2 programs)
+    "h2d",              # the jnp.asarray arguments of a program
+    "enqueue",          # the jitted call itself
+    "device",           # wait_*: until the program's first output is there
+                        # (wait_decode: on the host, the first np.asarray)
+    "fetch",            # the other np.asarray copies behind it
+    # -- observe
+    "observe",          # calls into an observe-only plane: introspect, costobs
+                        # row keys, _book_step_waits, chaos, the sampled span
+    "account",          # what the instruments compute on the hot path: padding
+                        # chip ledger, telemetry, attention/expert/state counts
+)
+TICK_OP_MS_TOTAL = METRICS.counter(
+    "quoracle_tick_op_ms_total",
+    "continuous-batcher worker time by named operation inside the tick's "
+    "phases (ms of self time), per model (infra/telemetry.TICK_OPS): a "
+    "phase's own time is quoracle_tick_phase_ms_total less the operations "
+    "opened inside it")
+_OP_NAMES = {o: "qtpu.op." + o for o in TICK_OPS}
+# -- the session drop, from inside (ISSUE 37) --------------------------------
+SESSION_DROP_WAIT_MS = METRICS.histogram(
+    "quoracle_session_drop_wait_ms",
+    "engine.drop_session's wait for the engine's paged lock (ms), per "
+    "model: a sessioned tick holds the lock from end to end, so a caller "
+    "that drops a session waits out the tick under way")
+
+
+_TRACE_ANNOTATION: Any = None
 
 
 def _annotation(name: str):
     """An entered TraceAnnotation (jax imported at first use: the mock
     backend's processes never open a tick)."""
-    from jax.profiler import TraceAnnotation
-    ann = TraceAnnotation(name)
+    global _TRACE_ANNOTATION
+    if _TRACE_ANNOTATION is None:
+        from jax.profiler import TraceAnnotation
+        _TRACE_ANNOTATION = TraceAnnotation
+    ann = _TRACE_ANNOTATION(name)
     ann.__enter__()
     return ann
 
 
 class TickRecord:
-    """One batcher loop iteration: integer-ns time per phase, the
+    """One batcher loop iteration: integer-ns time per phase and per named
+    operation inside the phases (``op_ns``, self time: ``tick_op``), the
     annotation arguments a trace reader needs (``model``, ``rows``,
     ``admitted``, ``nucleus_rows``, ``real_tokens``, ``padded_tokens``,
     ``decode_steps``, ``program``, ``context_tokens``, an expert model's
@@ -795,12 +859,14 @@ class TickRecord:
     the instant ``wait_prefill`` last ended — a row's first-token
     stamp)."""
 
-    __slots__ = ("t0_ns", "t1_ns", "phase_ns", "args", "fence_ns",
-                 "_phase", "_t_phase", "_ann", "_tick_ann")
+    __slots__ = ("t0_ns", "t1_ns", "phase_ns", "op_ns", "args", "fence_ns",
+                 "_phase", "_t_phase", "_ann", "_tick_ann", "_op")
 
     def __init__(self, model: str):
         self.args: dict = {"model": model}
         self.phase_ns = dict.fromkeys(TICK_PHASES, 0)
+        self.op_ns: dict = {}             # operation -> ns of self time
+        self._op: Optional[_TickOp] = None    # the innermost one open
         self.fence_ns = 0
         self.t1_ns = 0
         self._tick_ann = _annotation("qtpu.tick")
@@ -839,11 +905,45 @@ class TickRecord:
         for name, ns in self.phase_ns.items():
             if ns:
                 TICK_PHASE_MS_TOTAL.inc(ns / 1e6, model=model, phase=name)
+        for name, ns in self.op_ns.items():
+            TICK_OP_MS_TOTAL.inc(ns / 1e6, model=model, op=name)
 
     def as_attrs(self) -> dict:
         """The record as span attributes (``sched.decode_tick``)."""
         return {**self.args, "wall_ns": self.t1_ns - self.t0_ns,
-                "phases_ns": {k: v for k, v in self.phase_ns.items() if v}}
+                "phases_ns": {k: v for k, v in self.phase_ns.items() if v},
+                "ops_ns": dict(self.op_ns)}
+
+
+class _TickOp:
+    """One named operation of a tick, open: ``qtpu.op.<name>`` on the
+    worker's line and, at its end, its SELF time on the record."""
+
+    __slots__ = ("rec", "name", "outer", "inner_ns", "ann", "t0")
+
+    def __init__(self, rec: TickRecord, name: str):
+        self.rec = rec
+        self.name = name
+
+    def __enter__(self) -> None:
+        rec = self.rec
+        self.outer = rec._op
+        rec._op = self
+        self.inner_ns = 0
+        self.ann = _annotation(_OP_NAMES[self.name])
+        self.t0 = time.monotonic_ns()
+
+    def __exit__(self, *exc) -> None:
+        ns = time.monotonic_ns() - self.t0
+        self.ann.__exit__(None, None, None)
+        rec, outer, name = self.rec, self.outer, self.name
+        rec.op_ns[name] = rec.op_ns.get(name, 0) + ns - self.inner_ns
+        rec._op = outer
+        if outer is not None:
+            outer.inner_ns += ns
+
+
+_NO_OP = contextlib.nullcontext()    # ``tick_op`` with no open tick
 
 
 class _TickLocal(threading.local):
@@ -869,6 +969,15 @@ def tick_phase(name: str) -> Optional[TickRecord]:
     if rec is not None:
         rec.phase(name)
     return rec
+
+
+def tick_op(name: str):
+    """THE operation helper: ``with tick_op(name):`` around one named
+    operation (one of TICK_OPS) inside the phase under way. On a thread
+    with no open tick — the engine driven directly, a client that drops a
+    session — one thread-local read and nothing else."""
+    rec = _TICK.record
+    return _NO_OP if rec is None else _TickOp(rec, name)
 
 
 def tick_note(**args: Any) -> None:

@@ -12,6 +12,7 @@ only in trip count, exactly what XLA wants.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 import time
@@ -22,7 +23,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from quoracle_tpu.infra.telemetry import (
-    DECODE_MS, DECODE_STEP_MS, PREFILL_MS, TRACER, tick_note, tick_phase,
+    DECODE_MS, DECODE_STEP_MS, PREFILL_MS, SESSION_DROP_WAIT_MS, TRACER,
+    tick_note, tick_op, tick_phase,
 )
 from quoracle_tpu.models.config import (
     ModelConfig, require_plain, unsupported_path,
@@ -1590,6 +1592,17 @@ class GenerateEngine:
         self._step_paged_decode = step_paged_decode
         self._step_scatter_prompt = step_scatter_prompt
 
+    @contextlib.contextmanager
+    def _paged_locked(self):
+        """Hold ``_paged_lock``; on the batcher's thread the wait for it is
+        the tick's operation ``lock``."""
+        with tick_op("lock"):
+            self._paged_lock.acquire()
+        try:
+            yield
+        finally:
+            self._paged_lock.release()
+
     def next_rng(self) -> jax.Array:
         with self._rng_lock:
             self._rng, k = jax.random.split(self._rng)
@@ -1688,8 +1701,9 @@ class GenerateEngine:
             # allocation/eviction, the pool-donating steps, and the store
             # must be one atomic unit, or a concurrent call could evict and
             # recycle pages this batch still references.
-            with self._paged_lock:
-                later = self._prefix_wave_split(prompts, session_ids)
+            with self._paged_locked():
+                with tick_op("wave_split"):
+                    later = self._prefix_wave_split(prompts, session_ids)
                 if later:
                     return self._generate_waves(
                         later, prompts, temperature, top_p, max_new_tokens,
@@ -1864,21 +1878,36 @@ class GenerateEngine:
         """Release a session's pages — including any image-digest-qualified
         variants ("<sid>|img:<sha>", models/runtime.py VLM sessions).
         Serialized with sessioned generate calls so an in-flight batch
-        never loses pages it references."""
-        with self._paged_lock:
-            self.sessions.drop(session_id)
-            prefix = session_id + "|img:"
-            for key in [k for k in self.sessions._sessions
-                        if k.startswith(prefix)]:
-                self.sessions.drop(key)
-            tier = self.sessions.tier
-            if tier is not None:
-                # digest-keyed variants may live ONLY in the host tier
-                # (hibernated) — discard those too, or a dead agent's
-                # image sessions linger until host-LRU
-                for key in [k for k in tier.host.sessions
+        never loses pages it references: a sessioned tick holds the lock
+        from end to end, and what the caller waited for it is booked here
+        (``quoracle_session_drop_wait_ms``, and ``qtpu.session_drop`` on
+        the caller's line of a profiler trace)."""
+        with jax.profiler.TraceAnnotation("qtpu.session_drop") as span:
+            t0 = time.monotonic_ns()
+            self._paged_lock.acquire()
+            t1 = time.monotonic_ns()
+            try:
+                self.sessions.drop(session_id)
+                prefix = session_id + "|img:"
+                for key in [k for k in self.sessions._sessions
                             if k.startswith(prefix)]:
-                    tier.discard_session(key)
+                    self.sessions.drop(key)
+                tier = self.sessions.tier
+                if tier is not None:
+                    # digest-keyed variants may live ONLY in the host tier
+                    # (hibernated) — discard those too, or a dead agent's
+                    # image sessions linger until host-LRU
+                    for key in [k for k in tier.host.sessions
+                                if k.startswith(prefix)]:
+                        tier.discard_session(key)
+            finally:
+                self._paged_lock.release()
+                t2 = time.monotonic_ns()
+                SESSION_DROP_WAIT_MS.observe((t1 - t0) / 1e6,
+                                             model=self.cfg.name)
+                span.set_metadata(model=self.cfg.name,
+                                  lock_wait_us=(t1 - t0) // 1000,
+                                  held_us=(t2 - t1) // 1000)
 
     def session_tokens(self, session_id: str) -> Optional[list[int]]:
         """The session's resident conversation ids (host ints, prompt +
@@ -1928,7 +1957,7 @@ class GenerateEngine:
         assert len(verify_k) == len(prompts)
         assert all(1 <= int(k) <= len(p)
                    for k, p in zip(verify_k, prompts))
-        with self._paged_lock:
+        with self._paged_locked():
             return self._generate_impl(
                 prompts, temperature, 1.0, 1, None, session_ids,
                 constrain_json, action_enums, None, initial_json_state,
@@ -1984,84 +2013,89 @@ class GenerateEngine:
         store_sids: list[Optional[str]] = [None] * n
         paged = False
         if session_ids is not None and not use_ring:
-            seen: set[str] = set()
-            for i, sid in enumerate(session_ids):
-                if not sid or sid in seen:
-                    continue
-                seen.add(sid)
-                store_sids[i] = sid
-                paged = True
-                s = self.sessions.get(sid)
-                if s is None and self.sessions.tier is not None \
-                        and self.sessions.tier.has_session(sid):
-                    # hibernated session: restore by page-in instead of
-                    # re-prefill (ISSUE 7; the caller holds _paged_lock,
-                    # so the pool scatter cannot race a paged step). A
-                    # restore failure of ANY kind degrades to re-prefill
-                    # — the tier is never a correctness dependency.
-                    try:
-                        self._ensure_pool()
-                        s = self.sessions.tier.restore_session(sid)
-                    except Exception:     # noqa: BLE001 — fall back
-                        import logging
-                        logging.getLogger(__name__).exception(
-                            "kv restore failed for %s; re-prefilling",
-                            sid)
-                        s = None
-                if s is None:
-                    # Cross-session prefix sharing: a NEW session whose
-                    # prompt starts with a RADIX-CACHED page-aligned
-                    # prefix (same system prompt across the tree's
-                    # agents; models/prefix_cache.py) adopts those pages
-                    # read-only — _run_paged refcount-acquires them and
-                    # uses them as this row's dst prefix, so only the
-                    # suffix prefills.
-                    if (self.prefix_sharing
-                            and self.cfg.sliding_window is None
-                            # VLM engines: identical placeholder token
-                            # ids can front DIFFERENT images — adopting
-                            # another session's prefix KV would condition
-                            # on the wrong image (the digest-keyed
-                            # session safeguard, models/runtime.py)
-                            and self.cfg.vision is None):
-                        # verify mode: the last K_i positions are the
-                        # verify window and must run through the chunk
-                        # forward — never be served from reused KV
-                        cap = (len(prompts[i]) - 1 if vk is None
-                               else len(prompts[i]) - vk[i])
-                        if self.sessions.tier is not None:
-                            # tiered lookup may page disk/host blocks
-                            # into the pool — it must exist first
-                            self._ensure_pool()
-                        d = (self.sessions.match_prefix(prompts[i], cap)
-                             if cap > 0 else None)
-                        if d is not None:
-                            sess_rows[i] = d
-                            reuse_abs[i] = len(d.tokens)
-                            kv_off_host[i] = 0
-                    continue
-                # ≥1 suffix token must run to produce last-position logits
-                # (verify mode: the whole K_i window must run — see above)
-                p = min(_lcp(s.tokens, prompts[i]),
-                        len(prompts[i]) - 1 if vk is None
-                        else len(prompts[i]) - vk[i])
-                if self.cfg.sliding_window is not None and p < len(s.tokens):
-                    # Windowed models resume only on clean extension: after
-                    # a divergence the resident window [start_pos, p) would
-                    # leave a hole below the new tokens' attention windows.
-                    continue
-                if self.cfg.n_conv_layers and p < len(s.tokens):
-                    # conv state is held at the session's end and at page
-                    # boundaries only (_ensure_pool): a match that ends
-                    # elsewhere is reused up to the last boundary at or
-                    # below it, and the rest runs again
-                    held = p // self.sessions.page * self.sessions.page
-                    reprefill += p - held
-                    p = held
-                if p > s.start_pos:
-                    sess_rows[i] = s
-                    reuse_abs[i] = p
-                    kv_off_host[i] = s.start_pos
+            with tick_op("session_lookup"):
+                seen: set[str] = set()
+                for i, sid in enumerate(session_ids):
+                    if not sid or sid in seen:
+                        continue
+                    seen.add(sid)
+                    store_sids[i] = sid
+                    paged = True
+                    s = self.sessions.get(sid)
+                    if s is None and self.sessions.tier is not None \
+                            and self.sessions.tier.has_session(sid):
+                        # hibernated session: restore by page-in instead of
+                        # re-prefill (ISSUE 7; the caller holds _paged_lock,
+                        # so the pool scatter cannot race a paged step). A
+                        # restore failure of ANY kind degrades to re-prefill
+                        # — the tier is never a correctness dependency.
+                        try:
+                            with tick_op("tier_restore"):
+                                self._ensure_pool()
+                                s = self.sessions.tier.restore_session(sid)
+                        except Exception:     # noqa: BLE001 — fall back
+                            import logging
+                            logging.getLogger(__name__).exception(
+                                "kv restore failed for %s; re-prefilling",
+                                sid)
+                            s = None
+                    if s is None:
+                        # Cross-session prefix sharing: a NEW session whose
+                        # prompt starts with a RADIX-CACHED page-aligned
+                        # prefix (same system prompt across the tree's
+                        # agents; models/prefix_cache.py) adopts those pages
+                        # read-only — _run_paged refcount-acquires them and
+                        # uses them as this row's dst prefix, so only the
+                        # suffix prefills.
+                        if (self.prefix_sharing
+                                and self.cfg.sliding_window is None
+                                # VLM engines: identical placeholder token
+                                # ids can front DIFFERENT images — adopting
+                                # another session's prefix KV would condition
+                                # on the wrong image (the digest-keyed
+                                # session safeguard, models/runtime.py)
+                                and self.cfg.vision is None):
+                            # verify mode: the last K_i positions are the
+                            # verify window and must run through the chunk
+                            # forward — never be served from reused KV
+                            cap = (len(prompts[i]) - 1 if vk is None
+                                   else len(prompts[i]) - vk[i])
+                            if self.sessions.tier is not None:
+                                # tiered lookup may page disk/host blocks
+                                # into the pool — it must exist first
+                                self._ensure_pool()
+                            with tick_op("prefix_match"):
+                                d = (self.sessions.match_prefix(
+                                    prompts[i], cap) if cap > 0 else None)
+                            if d is not None:
+                                sess_rows[i] = d
+                                reuse_abs[i] = len(d.tokens)
+                                kv_off_host[i] = 0
+                        continue
+                    # ≥1 suffix token must run to produce last-position
+                    # logits (verify mode: the whole K_i window must run —
+                    # see above)
+                    p = min(_lcp(s.tokens, prompts[i]),
+                            len(prompts[i]) - 1 if vk is None
+                            else len(prompts[i]) - vk[i])
+                    if (self.cfg.sliding_window is not None
+                            and p < len(s.tokens)):
+                        # Windowed models resume only on clean extension: after
+                        # a divergence the resident window [start_pos, p) would
+                        # leave a hole below the new tokens' attention windows.
+                        continue
+                    if self.cfg.n_conv_layers and p < len(s.tokens):
+                        # conv state is held at the session's end and at page
+                        # boundaries only (_ensure_pool): a match that ends
+                        # elsewhere is reused up to the last boundary at or
+                        # below it, and the rest runs again
+                        held = p // self.sessions.page * self.sessions.page
+                        reprefill += p - held
+                        p = held
+                    if p > s.start_pos:
+                        sess_rows[i] = s
+                        reuse_abs[i] = p
+                        kv_off_host[i] = s.start_pos
 
         if not self.cfg.plain:
             # no dense-cache forward for this family: rows without a
@@ -2071,7 +2105,8 @@ class GenerateEngine:
                               "the sequence-parallel ring / image rows")
             paged = True
         if self.cfg.n_conv_layers:
-            self._note_state(sess_rows, reuse_abs, reprefill)
+            with tick_op("account"):
+                self._note_state(sess_rows, reuse_abs, reprefill)
         prefixes = [r - o for r, o in zip(reuse_abs, kv_off_host)]  # buffer
         suffixes = [list(p[r:]) for p, r in zip(prompts, reuse_abs)]
         max_chunk = max(len(s) for s in suffixes)
@@ -2110,24 +2145,26 @@ class GenerateEngine:
         if paged:
             cache_len = maxp * page
 
-        tokens = np.full((B, T), self.tokenizer.pad_id, np.int32)
-        pre_arr = np.zeros((B,), np.int32)
-        off_arr = np.zeros((B,), np.int32)
-        chunk_arr = np.ones((B,), np.int32)  # padded rows: 1 (harmless)
-        limits = np.ones((B,), np.int32)
-        for i, s in enumerate(suffixes):
-            tokens[i, :len(s)] = s
-            pre_arr[i] = prefixes[i]
-            off_arr[i] = kv_off_host[i]
-            chunk_arr[i] = max(1, len(s))
-            total = max(1, len(prompts[i]))
-            limits[i] = max(1, min(row_budgets[i], self.max_seq - total))
-        temp_arr = np.zeros((B,), np.float32)
-        temp_arr[:n] = temps
-        top_arr = np.ones((B,), np.float32)
-        top_arr[:n] = tops
-        active = np.zeros((B,), bool)
-        active[:n] = True
+        with tick_op("layout"):
+            tokens = np.full((B, T), self.tokenizer.pad_id, np.int32)
+            pre_arr = np.zeros((B,), np.int32)
+            off_arr = np.zeros((B,), np.int32)
+            chunk_arr = np.ones((B,), np.int32)  # padded rows: 1 (harmless)
+            limits = np.ones((B,), np.int32)
+            for i, s in enumerate(suffixes):
+                tokens[i, :len(s)] = s
+                pre_arr[i] = prefixes[i]
+                off_arr[i] = kv_off_host[i]
+                chunk_arr[i] = max(1, len(s))
+                total = max(1, len(prompts[i]))
+                limits[i] = max(1, min(row_budgets[i],
+                                       self.max_seq - total))
+            temp_arr = np.zeros((B,), np.float32)
+            temp_arr[:n] = temps
+            top_arr = np.ones((B,), np.float32)
+            top_arr[:n] = tops
+            active = np.zeros((B,), bool)
+            active[:n] = True
 
         if self.mesh is not None:
             from jax.sharding import NamedSharding, PartitionSpec as P
@@ -2137,9 +2174,11 @@ class GenerateEngine:
         else:
             row = mat = None
             put = lambda a, s: jnp.asarray(a)
-        rng_key = rng if rng is not None else self.next_rng()
-        samp = (put(temp_arr, row), put(top_arr, row),
-                put(active, row), put(limits, row))
+        with tick_op("rng"):
+            rng_key = rng if rng is not None else self.next_rng()
+        with tick_op("h2d"):
+            samp = (put(temp_arr, row), put(top_arr, row),
+                    put(active, row), put(limits, row))
 
         # JSON grammar constraint: rows flagged True start in their
         # grammar's start state; -1 rows sample unconstrained. Rows may
@@ -2229,36 +2268,38 @@ class GenerateEngine:
         # budget (_run_unified sets the thread-local).
         padded_toks = getattr(self._pending, "padded_tokens", None)
         self._pending.padded_tokens = None
-        self._note_padding(sum(max(1, len(s)) for s in suffixes),
-                           B * T if padded_toks is None else padded_toks)
-        # Chip-economics charge (ISSUE 17): split each phase's measured
-        # wall across the live rows by real tokens; padding waste lands
-        # on the overhead pseudo-tenant. Read-only — consumes the row
-        # keys the batcher declared on this thread, touches no RNG or
-        # device state.
-        from quoracle_tpu.infra import costobs
-        chip_ms_rows = costobs.charge_step(
-            self, n=n,
-            prefill_weights=([max(1, len(s)) for s in suffixes[:n]]
-                             if vrun is None else [int(k) for k in vk]),
-            decode_weights=[int(n_emitted[i]) for i in range(n)],
-            padded_prefill=(B * T if padded_toks is None
-                            else padded_toks),
-            padded_decode=(B * vrun[1] if vrun is not None
-                           else B * max_new),
-            cache_len=cache_len, verify=vrun is not None,
-            prefill_bucket=vrun[1] if vrun is not None else T,
-            decode_bucket=max_new)
+        from quoracle_tpu.infra import costobs, introspect
+        with tick_op("account"):
+            self._note_padding(
+                sum(max(1, len(s)) for s in suffixes),
+                B * T if padded_toks is None else padded_toks)
+            # Chip-economics charge (ISSUE 17): split each phase's measured
+            # wall across the live rows by real tokens; padding waste lands
+            # on the overhead pseudo-tenant. Read-only — consumes the row
+            # keys the batcher declared on this thread, touches no RNG or
+            # device state.
+            chip_ms_rows = costobs.charge_step(
+                self, n=n,
+                prefill_weights=([max(1, len(s)) for s in suffixes[:n]]
+                                 if vrun is None else [int(k) for k in vk]),
+                decode_weights=[int(n_emitted[i]) for i in range(n)],
+                padded_prefill=(B * T if padded_toks is None
+                                else padded_toks),
+                padded_decode=(B * vrun[1] if vrun is not None
+                               else B * max_new),
+                cache_len=cache_len, verify=vrun is not None,
+                prefill_bucket=vrun[1] if vrun is not None else T,
+                decode_bucket=max_new)
+            self._record_telemetry(n, B, T, cache_len,
+                                   vrun[1] if vrun is not None else max_new,
+                                   "verify" if vrun is not None else paged,
+                                   n_emitted, latency)
         # Liveness heartbeat (ISSUE 18): tokens the device actually
         # produced this call — a frozen counter under live rows is the
         # stall detector's engine-level signal.
-        from quoracle_tpu.infra import introspect
-        introspect.beat(f"engine.tokens:{self.cfg.name}",
-                        sum(int(n_emitted[i]) for i in range(n)))
-        self._record_telemetry(n, B, T, cache_len,
-                               vrun[1] if vrun is not None else max_new,
-                               "verify" if vrun is not None else paged,
-                               n_emitted, latency)
+        with tick_op("observe"):
+            introspect.beat(f"engine.tokens:{self.cfg.name}",
+                            sum(int(n_emitted[i]) for i in range(n)))
 
         if verify is not None:
             vids, vprobs = vout
@@ -2272,30 +2313,32 @@ class GenerateEngine:
                 "chip_ms": chip_ms_rows[i],
             } for i in range(n)]
 
-        results = []
-        for i in range(n):
-            # Extract by emitted COUNT, not by sentinel scan: pad_id may be a
-            # real vocab token in HF checkpoints.
-            k = min(int(n_emitted[i]), row_budgets[i])
-            ids = [int(t) for t in out[i, :k]]
-            finish = "length"
-            stop_set = {self.cfg.eos_token_id, *self.cfg.stop_token_ids}
-            if ids and ids[-1] in stop_set:
-                ids.pop()
-                finish = "stop"
-            results.append(GenResult(
-                token_ids=ids,
-                text=self.tokenizer.decode(ids),
-                n_prompt_tokens=len(prompts[i]),
-                n_gen_tokens=len(ids),
-                latency_s=latency,
-                finish_reason=finish,
-                n_cached_tokens=reuse_abs[i],
-                json_state=(int(jstate_f[i]) - grammar_bases[i]
-                            if constrain_json is not None
-                            and constrain_json[i] else -1),
-                chip_ms=chip_ms_rows[i],
-            ))
+        with tick_op("results"):
+            results = []
+            for i in range(n):
+                # Extract by emitted COUNT, not by sentinel scan: pad_id
+                # may be a real vocab token in HF checkpoints.
+                k = min(int(n_emitted[i]), row_budgets[i])
+                ids = [int(t) for t in out[i, :k]]
+                finish = "length"
+                stop_set = {self.cfg.eos_token_id,
+                            *self.cfg.stop_token_ids}
+                if ids and ids[-1] in stop_set:
+                    ids.pop()
+                    finish = "stop"
+                results.append(GenResult(
+                    token_ids=ids,
+                    text=self.tokenizer.decode(ids),
+                    n_prompt_tokens=len(prompts[i]),
+                    n_gen_tokens=len(ids),
+                    latency_s=latency,
+                    finish_reason=finish,
+                    n_cached_tokens=reuse_abs[i],
+                    json_state=(int(jstate_f[i]) - grammar_bases[i]
+                                if constrain_json is not None
+                                and constrain_json[i] else -1),
+                    chip_ms=chip_ms_rows[i],
+                ))
         return results
 
     def _record_telemetry(self, n: int, B: int, T: int, cache_len: int,
@@ -2606,7 +2649,8 @@ class GenerateEngine:
         adopted_release: list[list[int]] = [[] for _ in range(n)]
         partial_swap = False        # a swapped boundary page: condition
                                     # (c) of ragged_fallback
-        with st.lock:   # one allocation transaction for the batch
+        # one allocation transaction for the batch
+        with tick_op("page_alloc"), st.lock:
             # Refcount-acquire every adopted donor prefix FIRST: an alloc
             # below may LRU-evict the donor mid-transaction, and the
             # adopted pages must survive until this call's steps have
@@ -2811,55 +2855,57 @@ class GenerateEngine:
             now = time.monotonic()
 
         tick_phase("commit")
-        lens_host = np.asarray(final_lens)
-        for i in range(n):
-            sid, pages = store_sids[i], dst_lists[i]
-            if sid is None or pages is None:
-                continue
-            valid = int(lens_host[i])            # buffer tokens with KV
-            used = max(1, -(-valid // page))
-            st.release(spills[i])
-            st.release(pages[used:])
-            pages = pages[:used]
-            start = kv_off_host[i]
-            abs_valid = start + valid
-            plen = len(prompts[i])
-            toks = list(prompts[i]) + [
-                int(t) for t in out[i, :abs_valid - plen]]
-            W = self.cfg.sliding_window
-            if W is not None and valid - W >= page:
-                # bound the resident footprint to the attention window
-                drop = (valid - W) // page
-                st.release(pages[:drop])
-                pages = pages[drop:]
-                start += drop * page
-            # put_raw: page lifecycle handled explicitly above (the old
-            # session's pages are all in dst_lists + spills, so the
-            # releases above cover exactly the no-longer-referenced ones)
-            st.put_raw(sid, _Session(tokens=toks, pages=pages,
-                                     start_pos=start))
-            # Radix prefix cache insert: every FULL page of the stored
-            # conversation (prompt + retained response KV) becomes
-            # adoptable by future sessions. Windowed/trimmed sessions are
-            # excluded (their pages don't start at position 0) and VLM
-            # engines never share (image hazard, see the lookup site).
-            # verify-mode store-backs carry unverified DRAFT tokens at the
-            # tail — correct to resume from (token-keyed LCP) but not
-            # worth polluting the shared prefix cache with
-            if (self.prefix_sharing and start == 0
-                    and self.cfg.sliding_window is None
-                    and self.cfg.vision is None and verify is None):
-                st.insert_prefix(toks, pages)
-        # temp pages (sessionless rows of a ragged tick) die with the call
-        for tmp in temp_lists:
-            if tmp:
-                st.release(tmp)
-        # adopted-prefix references that no stored session took over
-        # (read-only adoption, or a declined store) release now — the
-        # steps above have consumed the pages
-        for pages in adopted_release:
-            if pages:
-                st.release(pages)
+        with tick_op("session_put"):
+            lens_host = np.asarray(final_lens)
+            for i in range(n):
+                sid, pages = store_sids[i], dst_lists[i]
+                if sid is None or pages is None:
+                    continue
+                valid = int(lens_host[i])            # buffer tokens with KV
+                used = max(1, -(-valid // page))
+                st.release(spills[i])
+                st.release(pages[used:])
+                pages = pages[:used]
+                start = kv_off_host[i]
+                abs_valid = start + valid
+                plen = len(prompts[i])
+                toks = list(prompts[i]) + [
+                    int(t) for t in out[i, :abs_valid - plen]]
+                W = self.cfg.sliding_window
+                if W is not None and valid - W >= page:
+                    # bound the resident footprint to the attention window
+                    drop = (valid - W) // page
+                    st.release(pages[:drop])
+                    pages = pages[drop:]
+                    start += drop * page
+                # put_raw: page lifecycle handled explicitly above (the old
+                # session's pages are all in dst_lists + spills, so the
+                # releases above cover exactly the no-longer-referenced ones)
+                st.put_raw(sid, _Session(tokens=toks, pages=pages,
+                                         start_pos=start))
+                # Radix prefix cache insert: every FULL page of the stored
+                # conversation (prompt + retained response KV) becomes
+                # adoptable by future sessions. Windowed/trimmed sessions are
+                # excluded (their pages don't start at position 0) and VLM
+                # engines never share (image hazard, see the lookup site).
+                # verify-mode store-backs carry unverified DRAFT tokens at the
+                # tail — correct to resume from (token-keyed LCP) but not
+                # worth polluting the shared prefix cache with
+                if (self.prefix_sharing and start == 0
+                        and self.cfg.sliding_window is None
+                        and self.cfg.vision is None and verify is None):
+                    with tick_op("prefix_insert"):
+                        st.insert_prefix(toks, pages)
+            # temp pages (sessionless rows of a ragged tick) die with the call
+            for tmp in temp_lists:
+                if tmp:
+                    st.release(tmp)
+            # adopted-prefix references that no stored session took over
+            # (read-only adoption, or a declined store) release now — the
+            # steps above have consumed the pages
+            for pages in adopted_release:
+                if pages:
+                    st.release(pages)
         return out, n_emitted, jstate_f, t_prefill, now, vout
 
     def _run_unified(self, n, suffixes, dst, pre_arr, off_arr, chunk_arr,
@@ -2878,8 +2924,7 @@ class GenerateEngine:
         vout, t_prefill, now) with all row-indexed arrays sized [R] whose
         first ``n`` slots are the batch rows in order."""
         from quoracle_tpu.ops.paged_attention import (
-            ragged_tile_slots, ragged_tile_walk, ragged_tiles,
-            shared_walk_tokens, shared_walks,
+            ragged_tile_slots, ragged_tiles, shared_walks,
         )
         tick_phase("pack")
         st = self.sessions
@@ -2887,44 +2932,72 @@ class GenerateEngine:
         page_cap = maxp * page
         n_tok = st.n_pages * page
         TQ = RAGGED_TQ
-        segs, nb_rows = [], []
-        for i in range(n):
-            s = max(1, min(int(chunk_arr[i]), page_cap - int(pre_arr[i])))
-            segs.append(s)
-            nb_rows.append(-(-s // TQ))
-        raw = sum(b * TQ for b in nb_rows)
-        TB = _round_up(raw, RAGGED_TOKEN_BUCKETS)
-        if TB == raw and raw > RAGGED_TOKEN_BUCKETS[-1]:
-            TB = -(-raw // 4096) * 4096     # beyond the ladder: 4k steps
-        NB = TB // TQ                       # blocks
-        R = _round_up(n, RAGGED_ROW_BUCKETS)   # row slots
-        maxp_p2 = 1 << max(0, maxp - 1).bit_length()   # pow2 table width
-        pad_id = self.tokenizer.pad_id
-        flat_tok = np.full((TB,), pad_id, np.int32)
-        flat_pos = np.zeros((TB,), np.int32)
-        flat_dst = np.full((TB,), n_tok, np.int32)     # OOB = drop
-        bmeta = np.zeros((4, NB), np.int32)     # kv_len, qpos0, nq, row
-        last_idx = np.zeros((R,), np.int32)
-        r_tables = np.zeros((R, maxp_p2), np.int32)
-        r_pool_lens = np.zeros((R,), np.int32)
-        r_off = np.zeros((R,), np.int32)
-        temp_arr, top_arr, active, limits_np = samp_np
-        r_temp = np.zeros((R,), np.float32)
-        r_top = np.ones((R,), np.float32)
-        r_active = np.zeros((R,), bool)
-        r_limits = np.ones((R,), np.int32)
-        r_temp[:n] = temp_arr[:n]
-        r_top[:n] = top_arr[:n]
-        r_active[:n] = active[:n]
-        r_limits[:n] = limits_np[:n]
-        js_dev = None
-        if json_table is not None:
-            r_jstate = np.full((R,), -1, np.int32)
-            r_jstate[:n] = jstate_np[:n]
-            js_dev = jnp.asarray(r_jstate)
-        if verify is not None:
-            k_arr, kmax, need_probs = verify
-            widx = np.zeros((R, kmax), np.int32)
+        with tick_op("layout"):
+            segs, nb_rows = [], []
+            for i in range(n):
+                s = max(1, min(int(chunk_arr[i]),
+                               page_cap - int(pre_arr[i])))
+                segs.append(s)
+                nb_rows.append(-(-s // TQ))
+            raw = sum(b * TQ for b in nb_rows)
+            TB = _round_up(raw, RAGGED_TOKEN_BUCKETS)
+            if TB == raw and raw > RAGGED_TOKEN_BUCKETS[-1]:
+                TB = -(-raw // 4096) * 4096     # beyond the ladder: 4k steps
+            NB = TB // TQ                       # blocks
+            R = _round_up(n, RAGGED_ROW_BUCKETS)   # row slots
+            maxp_p2 = 1 << max(0, maxp - 1).bit_length()   # pow2 table width
+            pad_id = self.tokenizer.pad_id
+            flat_tok = np.full((TB,), pad_id, np.int32)
+            flat_pos = np.zeros((TB,), np.int32)
+            flat_dst = np.full((TB,), n_tok, np.int32)     # OOB = drop
+            bmeta = np.zeros((4, NB), np.int32)   # kv_len, qpos0, nq, row
+            last_idx = np.zeros((R,), np.int32)
+            r_tables = np.zeros((R, maxp_p2), np.int32)
+            r_pool_lens = np.zeros((R,), np.int32)
+            r_off = np.zeros((R,), np.int32)
+            temp_arr, top_arr, active, limits_np = samp_np
+            r_temp = np.zeros((R,), np.float32)
+            r_top = np.ones((R,), np.float32)
+            r_active = np.zeros((R,), bool)
+            r_limits = np.ones((R,), np.int32)
+            r_temp[:n] = temp_arr[:n]
+            r_top[:n] = top_arr[:n]
+            r_active[:n] = active[:n]
+            r_limits[:n] = limits_np[:n]
+            if json_table is not None:
+                r_jstate = np.full((R,), -1, np.int32)
+                r_jstate[:n] = jstate_np[:n]
+            if verify is not None:
+                k_arr, kmax, need_probs = verify
+                widx = np.zeros((R, kmax), np.int32)
+            cur = 0
+            starts = []                 # each row's first flat slot
+            for i in range(n):
+                s, nb = segs[i], nb_rows[i]
+                pre = int(pre_arr[i])
+                toks = suffixes[i][:s]
+                flat_tok[cur:cur + len(toks)] = toks
+                pos = pre + np.arange(s, dtype=np.int32)
+                flat_pos[cur:cur + s] = int(off_arr[i]) + pos
+                flat_dst[cur:cur + s] = (dst[i, pos // page] * page
+                                         + pos % page)
+                kv_len = pre + s
+                blk = cur // TQ + np.arange(nb)
+                bmeta[0, blk] = kv_len
+                bmeta[1, blk] = pre + np.arange(nb) * TQ
+                bmeta[2, blk] = np.minimum(TQ, s - np.arange(nb) * TQ)
+                bmeta[3, blk] = i
+                last_idx[i] = cur + s - 1
+                r_tables[i, :maxp] = dst[i]
+                r_pool_lens[i] = kv_len
+                r_off[i] = int(off_arr[i])
+                if verify is not None:
+                    widx[i] = cur + np.clip(
+                        s - int(k_arr[i]) + np.arange(kmax, dtype=np.int32),
+                        0, s - 1)
+                starts.append(cur)
+                cur += nb * TQ
+            self._pending.padded_tokens = TB
         conv = None
         if st.state is not None:
             # transformer.ConvTick's fields after the pool: the record each
@@ -2933,84 +3006,74 @@ class GenerateEngine:
             # neighbours, which no one reads), and the tokens after which
             # the state is recorded — every one that ends a page and each
             # row's last — with their pages
-            c_src = np.full((R,), -1, np.int32)
-            c_row = np.zeros((TB,), np.int32)
-            c_idx = np.full((TB,), TB, np.int32)
-            rec_src = np.zeros((TB // page + 2 * R,), np.int32)
-            rec_dst = np.full(rec_src.shape, st.n_pages, np.int32)
-            n_rec = 0
-        cur = 0
-        for i in range(n):
-            s, nb = segs[i], nb_rows[i]
-            pre = int(pre_arr[i])
-            toks = suffixes[i][:s]
-            flat_tok[cur:cur + len(toks)] = toks
-            pos = pre + np.arange(s, dtype=np.int32)
-            flat_pos[cur:cur + s] = int(off_arr[i]) + pos
-            flat_dst[cur:cur + s] = dst[i, pos // page] * page + pos % page
-            kv_len = pre + s
-            blk = cur // TQ + np.arange(nb)
-            bmeta[0, blk] = kv_len
-            bmeta[1, blk] = pre + np.arange(nb) * TQ
-            bmeta[2, blk] = np.minimum(TQ, s - np.arange(nb) * TQ)
-            bmeta[3, blk] = i
-            last_idx[i] = cur + s - 1
-            if st.state is not None:
-                if pre:
-                    c_src[i] = dst[i, (pre - 1) // page]
-                c_row[cur:cur + s] = i
-                c_idx[cur:cur + s] = np.arange(s)
-                ends = np.flatnonzero((pos + 1) % page == 0)
-                ends = np.union1d(ends, [s - 1])
-                rec_src[n_rec:n_rec + len(ends)] = cur + ends
-                rec_dst[n_rec:n_rec + len(ends)] = dst[i, pos[ends] // page]
-                n_rec += len(ends)
-            r_tables[i, :maxp] = dst[i]
-            r_pool_lens[i] = kv_len
-            r_off[i] = int(off_arr[i])
-            if verify is not None:
-                widx[i] = cur + np.clip(
-                    s - int(k_arr[i]) + np.arange(kmax, dtype=np.int32),
-                    0, s - 1)
-            cur += nb * TQ
-        self._pending.padded_tokens = TB
-        if st.state is not None:
-            conv = tuple(jnp.asarray(a) for a in (
-                c_src, conv_past(c_row, c_idx, self.cfg.conv_cache),
-                rec_src, rec_dst))
+            with tick_op("state_adopt"):
+                c_src = np.full((R,), -1, np.int32)
+                c_row = np.zeros((TB,), np.int32)
+                c_idx = np.full((TB,), TB, np.int32)
+                rec_src = np.zeros((TB // page + 2 * R,), np.int32)
+                rec_dst = np.full(rec_src.shape, st.n_pages, np.int32)
+                n_rec = 0
+                for i, cur in enumerate(starts):
+                    s, pre = segs[i], int(pre_arr[i])
+                    pos = pre + np.arange(s, dtype=np.int32)
+                    if pre:
+                        c_src[i] = dst[i, (pre - 1) // page]
+                    c_row[cur:cur + s] = i
+                    c_idx[cur:cur + s] = np.arange(s)
+                    ends = np.flatnonzero((pos + 1) % page == 0)
+                    ends = np.union1d(ends, [s - 1])
+                    rec_src[n_rec:n_rec + len(ends)] = cur + ends
+                    rec_dst[n_rec:n_rec + len(ends)] = \
+                        dst[i, pos[ends] // page]
+                    n_rec += len(ends)
+                past = conv_past(c_row, c_idx, self.cfg.conv_cache)
+            with tick_op("h2d"):
+                conv = tuple(jnp.asarray(a) for a in (
+                    c_src, past, rec_src, rec_dst))
         # the same blocks grouped for the attention kernel's walk: up to
         # ``tile`` tokens of a row read its pages once between them
         # (where the kernel walks block by block, the blocks are the walk)
         tile = self._ragged_tile
         tiles, walked = None, bmeta
         if tile:
-            walked = ragged_tiles(bmeta, TQ, tile,
-                                  ragged_tile_slots(NB, R, TQ, tile))
-            tiles = jnp.asarray(walked)
+            with tick_op("tiles"):
+                walked = ragged_tiles(bmeta, TQ, tile,
+                                      ragged_tile_slots(NB, R, TQ, tile))
         # ... and the decode steps' one-token rows: which of them have
         # leading pages in common, read once a step for all of them
         # (a latent pool's kernel has no such walk)
-        shared = shared_walks(r_tables, r_pool_lens, page,
-                              self.cfg.sliding_window) \
-            if self.cfg.latent is None and verify is None else None
+        shared = None
+        if self.cfg.latent is None and verify is None:
+            with tick_op("shared_walks"):
+                shared = shared_walks(r_tables, r_pool_lens, page,
+                                      self.cfg.sliding_window)
+        with tick_op("h2d"):
+            js_dev = (None if json_table is None
+                      else jnp.asarray(r_jstate))
+            if tile:
+                tiles = jnp.asarray(walked)
 
         if verify is not None:
             self._pending.shape_key = ("ragged_verify", TB, R, maxp_p2,
                                        kmax)
-            (vids, vprobs, st.k, st.v, st.k_scale,
-             st.v_scale) = self._step_paged_ragged_verify(
-                self.params, st.k, st.v, st.k_scale, st.v_scale,
-                jnp.asarray(flat_tok),
-                jnp.asarray(flat_pos), jnp.asarray(r_tables),
-                jnp.asarray(bmeta), tiles, jnp.asarray(flat_dst),
-                jnp.asarray(widx), jnp.asarray(r_temp), json_table,
-                js_dev, tq=TQ, tile=tile, kmax=kmax,
-                need_probs=need_probs)
-            jax.block_until_ready(vids)  # phase fence: chunk forward done
+            with tick_op("h2d"):
+                args = (jnp.asarray(flat_tok), jnp.asarray(flat_pos),
+                        jnp.asarray(r_tables), jnp.asarray(bmeta), tiles,
+                        jnp.asarray(flat_dst), jnp.asarray(widx),
+                        jnp.asarray(r_temp))
+            with tick_op("enqueue"):
+                (vids, vprobs, st.k, st.v, st.k_scale,
+                 st.v_scale) = self._step_paged_ragged_verify(
+                    self.params, st.k, st.v, st.k_scale, st.v_scale, *args,
+                    json_table, js_dev, tq=TQ, tile=tile, kmax=kmax,
+                    need_probs=need_probs)
+            with tick_op("device"):
+                jax.block_until_ready(vids)  # phase fence: chunk forward
             t_prefill = time.monotonic()
-            vout = (np.asarray(vids),
-                    np.asarray(vprobs) if need_probs else None)
-            jax.block_until_ready(st.k)
+            with tick_op("fetch"):
+                vout = (np.asarray(vids),
+                        np.asarray(vprobs) if need_probs else None)
+                jax.block_until_ready(st.k)
             now = time.monotonic()
             out = np.zeros((R, 0), np.int32)
             n_emitted = np.zeros((R,), np.int32)
@@ -3023,36 +3086,65 @@ class GenerateEngine:
         # the chunk's end): what the attention kernel streams each step
         tick_note(context_tokens=int(r_pool_lens.sum()))
         tick_phase("dispatch_prefill")
-        (last_logits, st.k, st.v, st.k_scale, st.v_scale, moe_pre,
-         st.state) = self._step_paged_ragged(
-                self.params, st.k, st.v, st.k_scale, st.v_scale,
-                jnp.asarray(flat_tok),
-                jnp.asarray(flat_pos), jnp.asarray(r_tables),
-                jnp.asarray(bmeta), tiles, jnp.asarray(flat_dst),
-                jnp.asarray(last_idx), st.state, conv, tq=TQ, tile=tile)
+        with tick_op("h2d"):
+            args = (jnp.asarray(flat_tok), jnp.asarray(flat_pos),
+                    jnp.asarray(r_tables), jnp.asarray(bmeta), tiles,
+                    jnp.asarray(flat_dst), jnp.asarray(last_idx))
+        with tick_op("enqueue"):
+            (last_logits, st.k, st.v, st.k_scale, st.v_scale, moe_pre,
+             st.state) = self._step_paged_ragged(
+                    self.params, st.k, st.v, st.k_scale, st.v_scale, *args,
+                    st.state, conv, tq=TQ, tile=tile)
         tick_phase("wait_prefill")
-        jax.block_until_ready(last_logits)  # phase fence: prefill done
+        with tick_op("device"):
+            jax.block_until_ready(last_logits)  # phase fence: prefill done
         t_prefill = time.monotonic()
         tick_phase("dispatch_decode")
-        (out, n_emitted, final_lens, st.k, st.v, st.k_scale, st.v_scale,
-         jstate_f, moe_dec, st.state) = \
-            self._step_paged_decode_ragged(
-                self.params, st.k, st.v, st.k_scale, st.v_scale,
-                jnp.asarray(r_tables),
-                None if shared is None else jnp.asarray(shared),
-                jnp.asarray(r_pool_lens), jnp.asarray(r_off), last_logits,
-                rng_key, jnp.asarray(r_temp), jnp.asarray(r_top),
-                jnp.asarray(r_active), jnp.asarray(r_limits), json_table,
-                js_dev, st.state, max_new=max_new)
+        with tick_op("h2d"):
+            tables = (jnp.asarray(r_tables),
+                      None if shared is None else jnp.asarray(shared),
+                      jnp.asarray(r_pool_lens), jnp.asarray(r_off))
+            samp = (jnp.asarray(r_temp), jnp.asarray(r_top),
+                    jnp.asarray(r_active), jnp.asarray(r_limits))
+        with tick_op("enqueue"):
+            (out, n_emitted, final_lens, st.k, st.v, st.k_scale,
+             st.v_scale, jstate_f, moe_dec, st.state) = \
+                self._step_paged_decode_ragged(
+                    self.params, st.k, st.v, st.k_scale, st.v_scale,
+                    *tables, last_logits, rng_key, *samp, json_table,
+                    js_dev, st.state, max_new=max_new)
         tick_phase("wait_decode")
-        out = np.asarray(out)
-        n_emitted = np.asarray(n_emitted)
-        jstate_f = np.asarray(jstate_f)
-        final_lens = np.asarray(final_lens)
-        jax.block_until_ready(st.k)
+        # the first fetch blocks until the program has ended (its copy is
+        # queued behind the program: a fence before it would put the
+        # host's wake-up between the two); what follows in this phase is
+        # the host's, with the chip idle: the other copies, then the
+        # instruments
+        with tick_op("device"):
+            out = np.asarray(out)
+        with tick_op("fetch"):
+            n_emitted = np.asarray(n_emitted)
+            jstate_f = np.asarray(jstate_f)
+            final_lens = np.asarray(final_lens)
+            jax.block_until_ready(st.k)
         now = time.monotonic()
         if moe_pre is not None:
-            self._note_moe(np.asarray(moe_pre) + np.asarray(moe_dec))
+            with tick_op("fetch"):
+                moe = np.asarray(moe_pre) + np.asarray(moe_dec)
+        with tick_op("account"):
+            if moe_pre is not None:
+                self._note_moe(moe)
+            self._note_attention(n, segs, r_pool_lens, final_lens, walked,
+                                 shared, page)
+        return out, n_emitted, final_lens, jstate_f, None, t_prefill, now
+
+    def _note_attention(self, n: int, segs, r_pool_lens, final_lens, walked,
+                        shared, page: int) -> None:
+        """Book what the attention kernel had to do in one ragged tick and
+        what its programs brought in, on the tick span (and, for a model
+        that selects its keys, ``_note_selection``)."""
+        from quoracle_tpu.ops.paged_attention import (
+            ragged_tile_walk, shared_walk_tokens,
+        )
         # what the attention kernel had to do this tick, for its roofline
         # (a reader's lower bounds): resident tokens streamed — each row's
         # context once for its chunk and once per decode step — and
@@ -3095,7 +3187,6 @@ class GenerateEngine:
                   attn_kv_streamed=streamed, attn_tiles=n_tiles)
         if self.cfg.indexer is not None:
             self._note_selection(kv_reads, pairs, ctx, seg, fwd)
-        return out, n_emitted, final_lens, jstate_f, None, t_prefill, now
 
     def _json_table_device(self, enum_set: tuple):
         """Lazily build + cache grammar tables for this tokenizer (one

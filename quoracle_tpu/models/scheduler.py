@@ -69,7 +69,7 @@ from quoracle_tpu.infra.flightrec import FLIGHT
 from quoracle_tpu.infra.telemetry import (
     QOS_ADMIT_WAIT_MS, SCHED_ADMIT_WAIT_MS, SCHED_NUCLEUS_ROWS_TOTAL,
     SCHED_QUEUE_DEPTH, SCHED_ROWS_TOTAL, SCHED_SLOTS_BUSY, TRACER,
-    tick_close, tick_note, tick_open, tick_phase,
+    tick_close, tick_note, tick_op, tick_open, tick_phase,
 )
 from quoracle_tpu.models.generate import GenResult
 from quoracle_tpu.serving.admission import (
@@ -421,11 +421,16 @@ class ContinuousBatcher:
         return admitted
 
     def _loop(self) -> None:
+        sampled = None                    # (step, closed record) to emit
         while not self._stop:
             # One tick record per iteration (ISSUE 24): the phases the
             # worker passes through from here to tick_close() tile the
             # iteration, each a TraceAnnotation on this thread's line.
             tick_open(self._model)
+            if sampled is not None:
+                with tick_op("observe"):
+                    self._emit_tick_span(*sampled)
+                sampled = None
             admitted = self._admit()
             n_rows = len(self._live)
             # the rows that make the sampler sort the vocabulary: with
@@ -447,20 +452,21 @@ class ContinuousBatcher:
                 self._live = self._isolate_failure(self._live)  # nuke all
             step = self.steps
             self.steps += 1               # watchdog progress signal
-            introspect.beat(f"sched.tick:{self._model}")
-            self._chaos_tick()
+            with tick_op("observe"):
+                introspect.beat(f"sched.tick:{self._model}")
+                self._chaos_tick()
             rec = tick_close()
             # Sampled decode-tick span (ISSUE 15 satellite): 1-in-N
             # ticks (QUORACLE_TRACE_DECODE_SAMPLE, keyed on the
             # monotonic step counter — deterministic, no RNG) so
             # serving decode traffic cannot starve consensus traces
-            # out of the bounded span rings. The span IS the tick
-            # record: its phases and arguments ride as attributes.
+            # out of the bounded span rings. The span IS the closed tick
+            # record, so it is emitted at the head of the NEXT
+            # iteration, where its cost has a record to be booked on.
             if TRACER.active() and fleetobs.sample_tick(step):
-                attrs = rec.as_attrs()
-                TRACER.emit("sched.decode_tick", attrs["wall_ns"] / 1e6,
-                            ts=time.time() - attrs["wall_ns"] / 1e9,
-                            step=step, **attrs)
+                sampled = (step, rec)
+        if sampled is not None:
+            self._emit_tick_span(*sampled)
         # worker exit (close()): the worker owns _live, so it fails any
         # remaining rows itself — close() only takes over when this
         # thread is confirmed dead
@@ -475,6 +481,14 @@ class ContinuousBatcher:
         # gauge reset on the worker-exit path too (ISSUE 4 satellite):
         # whichever of close()/worker runs last, the scrape reads zero
         SCHED_SLOTS_BUSY.set(0, model=self._model)
+
+    def _emit_tick_span(self, step: int, rec) -> None:
+        """``sched.decode_tick``: the closed tick record, its phases, named
+        operations and arguments as attributes."""
+        attrs = rec.as_attrs()
+        TRACER.emit("sched.decode_tick", attrs["wall_ns"] / 1e6,
+                    ts=time.time() - attrs["wall_ns"] / 1e9,
+                    step=step, **attrs)
 
     def _chaos_tick(self) -> None:
         """Chaos seam (ISSUE 11): per-tick fault hook in the decode
@@ -739,33 +753,41 @@ class ContinuousBatcher:
             r.waits.note("lock", lock_ns)
 
     def _plain_step(self, rows: list) -> list:
-        prompts = [r.prompt + r.emitted for r in rows]
-        budgets = [min(self.chunk, r.max_new - len(r.emitted))
-                   for r in rows]
+        with tick_op("splice"):
+            prompts = [r.prompt + r.emitted for r in rows]
+            budgets = [min(self.chunk, r.max_new - len(r.emitted))
+                       for r in rows]
+            sampling = dict(
+                temperature=[r.temperature for r in rows],
+                top_p=[r.top_p for r in rows],
+                max_new_tokens=budgets,
+                session_ids=[r.session_id for r in rows],
+                constrain_json=[r.constrain for r in rows],
+                action_enums=[r.action_enum for r in rows],
+                initial_json_state=[r.json_state for r in rows])
         tick = tick_phase("prepare")      # always inside _loop's tick
         before = tick.snapshot()
-        introspect.drain_inner_waits()
-        # declare this chunk's attribution keys on the worker thread —
-        # the engine's charge site consumes them (one call, one set)
-        costobs.set_row_keys([self._row_key(r) for r in rows])
-        results = self.engine.generate(
-            prompts,
-            temperature=[r.temperature for r in rows],
-            top_p=[r.top_p for r in rows],
-            max_new_tokens=budgets,
-            session_ids=[r.session_id for r in rows],
-            constrain_json=[r.constrain for r in rows],
-            action_enums=[r.action_enum for r in rows],
-            initial_json_state=[r.json_state for r in rows],
-        )
+        with tick_op("observe"):
+            introspect.drain_inner_waits()
+            # declare this chunk's attribution keys on the worker thread —
+            # the engine's charge site consumes them (one call, one set)
+            costobs.set_row_keys([self._row_key(r) for r in rows])
+        results = self.engine.generate(prompts, **sampling)
         # the engine call's wall, split by the tick record: two
         # snapshots differ by exactly the time between them
         after = tick.snapshot()
-        self._book_step_waits(
-            rows, sum(after.values()) - sum(before.values()),
-            (after["wait_prefill"] - before["wait_prefill"],
-             after["wait_decode"] - before["wait_decode"]))
+        with tick_op("observe"):
+            self._book_step_waits(
+                rows, sum(after.values()) - sum(before.values()),
+                (after["wait_prefill"] - before["wait_prefill"],
+                 after["wait_decode"] - before["wait_decode"]))
         tick_phase("retire")
+        with tick_op("retire_rows"):
+            return self._retire_rows(tick, rows, results, budgets)
+
+    def _retire_rows(self, tick, rows, results, budgets) -> list:
+        """The engine call's results onto their rows; rows that ended
+        retire through _finish_row, the rest ride the next tick."""
         # a path with no prefill fence of its own: the call's end
         fence_ns = tick.fence_ns or time.monotonic_ns()
         still = []

@@ -15,6 +15,12 @@ record, and the names on the device side.
     ``pallas_call`` carries its pinned ``name``;
   * read-only: temp-0 output is bit-identical with a profiler session
     open and closed;
+  * inside the phases, named operations (ISSUE 37): ``tick_op`` opens
+    ``qtpu.op.<name>`` from one fixed tuple, each inside one phase, self
+    time on the record, the counter and the sampled span; every name is
+    reached by some tick and nothing outside the tuple is; a session drop
+    books its wait for the paged lock on a histogram and on
+    ``qtpu.session_drop``;
   * scope names are part of the persistent compile cache's key, the
     checkout's path is not (utils/compile_cache.py).
 
@@ -22,6 +28,7 @@ No timing thresholds anywhere: times are compared with each other, never
 with a constant.
 """
 
+import collections
 import glob
 import json
 import os
@@ -37,7 +44,8 @@ import pytest
 
 from quoracle_tpu.infra import introspect, telemetry
 from quoracle_tpu.infra.telemetry import (
-    TICK_PHASES, TRACER, tick_close, tick_note, tick_open, tick_phase,
+    TICK_OPS, TICK_PHASES, TRACER, tick_close, tick_note, tick_op,
+    tick_open, tick_phase,
 )
 from quoracle_tpu.models.config import get_model_config
 from quoracle_tpu.models.generate import GenerateEngine
@@ -297,6 +305,282 @@ def test_profiler_trace_holds_tick_and_phases_on_one_thread_line(tmp_path):
               and t.start_ns <= ev.start_ns and ev.end_ns <= t.end_ns]
     assert sum(ev.duration_ns for ev in inside) <= t.duration_ns
     assert sum(ev.duration_ns for ev in inside) > 0
+
+
+# ---------------------------------------------------------------------------
+# Named operations inside the phases (ISSUE 37)
+# ---------------------------------------------------------------------------
+
+SYSTEM = "system: " + "policy rules apply here. " * 8     # over one page
+
+
+def sessioned(backend, sid, text, max_tokens=12):
+    out = backend.query([QueryRequest(
+        MEMBER, [{"role": "system", "content": SYSTEM},
+                 {"role": "user", "content": text}],
+        temperature=0.0, max_tokens=max_tokens, session_id=sid)])[0]
+    assert out.ok, out.error
+    return out
+
+
+Event = collections.namedtuple(
+    "Event", "name start_ns end_ns duration_ns stats")
+
+
+def host_events(trace_dir) -> list:
+    """[[Event]]: the lines of the `/host:CPU` plane of the trace."""
+    from jax.profiler import ProfileData
+    path = glob.glob(os.path.join(
+        str(trace_dir), "plugins", "profile", "*", "*.xplane.pb"))[-1]
+    return [[Event(ev.name, ev.start_ns, ev.end_ns, ev.duration_ns,
+                   dict(ev.stats)) for ev in line.events]
+            for plane in ProfileData.from_file(path).planes
+            if plane.name == "/host:CPU" for line in plane.lines]
+
+
+def start_trace(trace_dir) -> None:
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1
+    jax.profiler.start_trace(str(trace_dir), profiler_options=opts)
+
+
+@pytest.fixture(scope="module")
+def reached(tmp_path_factory):
+    """What three kinds of tick open: {operation: ns} summed over (a) the
+    ticks of a continuous backend that serves two sessions with one system
+    prompt, under a profiler session (the radix cache, the batcher's own
+    operations); (b) a tick of an engine with conv state; (c) a tick that
+    restores a hibernated session. With (a)'s trace and span events."""
+    env = pytest.MonkeyPatch()
+    env.setenv("QUORACLE_TRACE_DECODE_SAMPLE", "1")
+    introspect.enable()
+    ops: dict = {}
+
+    def add(op_ns):
+        for name, ns in op_ns.items():
+            ops[name] = ops.get(name, 0) + ns
+
+    events: list = []
+    TRACER.add_sink(events.append)
+    trace_dir = tmp_path_factory.mktemp("ops")
+    b = TPUBackend([MEMBER], continuous=True, continuous_chunk=8)
+    try:
+        start_trace(trace_dir)
+        try:
+            for sid in ("a", "b", "a"):
+                sessioned(b, sid, f"turn of {sid}")
+        finally:
+            jax.profiler.stop_trace()
+    finally:
+        b.close()
+        TRACER.remove_sink(events.append)
+        env.undo()
+    ticks = [e for e in events if e["name"] == "sched.decode_tick"]
+    for e in ticks:
+        add(e["ops_ns"])
+
+    def one_tick(eng, prompt, sid):
+        rec = tick_open(eng.cfg.name)
+        try:
+            eng.generate([prompt], temperature=0.0, max_new_tokens=4,
+                         session_ids=[sid])
+        finally:
+            tick_close()
+        add(rec.op_ns)
+
+    from tests.test_shortconv_moe import RAW, f32, model
+    cfg, params, _ = model(RAW)
+    conv = GenerateEngine(cfg, f32(params), ByteTokenizer(), max_seq=1024,
+                          prompt_buckets=(32, 64, 128, 256, 512))
+    one_tick(conv, list(range(3, 40)), "c")
+
+    tiny = get_model_config(MEMBER)
+    eng = GenerateEngine(
+        tiny, init_params(tiny, jax.random.PRNGKey(0), dtype=jnp.float32),
+        ByteTokenizer(), max_seq=512, prompt_buckets=(32, 64, 128, 256))
+    tier = eng.attach_tier(host_mb=64)
+    prompt = ByteTokenizer().encode(SYSTEM + " task", add_bos=True)
+    res = eng.generate([prompt], temperature=0.0, max_new_tokens=4,
+                       session_ids=["t"])[0]
+    st = eng.sessions
+    with eng._paged_lock, st.lock:          # the ladder: hibernate it
+        st._release(st.alloc(st.n_pages - 1))
+    assert tier.has_session("t") and st.get("t") is None
+    one_tick(eng, prompt + res.token_ids + [5, 6, 7], "t")
+    assert tier.restored_sessions == 1
+    return {"ops": ops, "ticks": ticks, "host": host_events(trace_dir)}
+
+
+@pytest.mark.parametrize("op", TICK_OPS)
+def test_every_named_operation_is_reached_by_some_tick(reached, op):
+    assert reached["ops"].get(op, 0) > 0, f"no tick opened {op}"
+
+
+def test_ticks_open_no_operation_outside_the_fixed_list(reached):
+    assert set(reached["ops"]) <= set(TICK_OPS)
+    assert len(set(TICK_OPS)) == len(TICK_OPS)
+    assert not set(TICK_OPS) & set(TICK_PHASES)
+    named = {ev.name for evs in reached["host"] for ev in evs
+             if ev.name.startswith("qtpu.")}
+    assert {n for n in named if n.startswith("qtpu.op.")} \
+        <= {"qtpu.op." + o for o in TICK_OPS}
+    # a child of a phase is NOT named like a phase: every reader takes
+    # `qtpu.tick.<x>` for one of the ten
+    assert {n for n in named if n.startswith("qtpu.tick.")} \
+        <= {"qtpu.tick." + p for p in TICK_PHASES}
+    with pytest.raises(KeyError):
+        rec = tick_open("m")
+        try:
+            with tick_op("not-an-operation"):
+                pass
+        finally:
+            tick_close()
+    assert rec.op_ns == {}
+
+
+def test_an_operation_lies_inside_one_phase(reached):
+    """On the worker's line every `qtpu.op.*` span lies inside ONE
+    `qtpu.tick.<phase>` span, and the operations inside a phase take no
+    more than the phase (an operation inside another counts once)."""
+    worker = [evs for evs in reached["host"]
+              if any(ev.name == "qtpu.tick" for ev in evs)]
+    assert len(worker) == 1
+    phases = sorted((ev for ev in worker[0]
+                     if ev.name.startswith("qtpu.tick.")),
+                    key=lambda ev: ev.start_ns)
+    ops = [ev for ev in worker[0] if ev.name.startswith("qtpu.op.")]
+    assert ops and phases
+    inside: dict = {}
+    for ev in ops:
+        holds = [i for i, ph in enumerate(phases)
+                 if ph.start_ns <= ev.start_ns and ev.end_ns <= ph.end_ns]
+        assert len(holds) == 1, (ev.name, len(holds))
+        inside.setdefault(holds[0], []).append(ev)
+    for i, evs in inside.items():
+        outer = [ev for ev in evs if not any(
+            o is not ev and o.start_ns <= ev.start_ns
+            and ev.end_ns <= o.end_ns for o in evs)]
+        assert sum(ev.duration_ns for ev in outer) <= phases[i].duration_ns
+
+
+def test_tick_span_carries_the_operations_self_time(reached):
+    assert reached["ticks"]
+    for e in reached["ticks"]:
+        assert set(e["ops_ns"]) <= set(TICK_OPS)
+        assert all(ns >= 0 for ns in e["ops_ns"].values())
+        # self time: nested operations count once, so all of them together
+        # fit the tick's phases other than the empty loop's wait
+        assert sum(e["ops_ns"].values()) <= e["wall_ns"]
+    # the waits for the device are named too, inside their fences
+    e = max(reached["ticks"], key=lambda e: e["wall_ns"])
+    assert 0 < e["ops_ns"]["device"] <= (
+        e["phases_ns"]["wait_prefill"] + e["phases_ns"]["wait_decode"])
+
+
+def test_operations_nest_by_self_time_and_feed_the_counter_exactly():
+    model = "ops-counter-probe"
+    rec = tick_open(model)
+    tick_phase("commit")
+    with tick_op("session_put"):
+        time.sleep(0.002)
+        with tick_op("prefix_insert"):
+            time.sleep(0.004)
+        with tick_op("prefix_insert"):           # a name may come again
+            pass
+    with tick_op("account"):
+        pass
+    assert tick_close() is rec
+    assert set(rec.op_ns) == {"session_put", "prefix_insert", "account"}
+    assert rec.op_ns["prefix_insert"] > rec.op_ns["session_put"] > 0
+    assert sum(rec.op_ns.values()) <= rec.phase_ns["commit"]
+    assert rec.as_attrs()["ops_ns"] == rec.op_ns
+    for name, ns in rec.op_ns.items():
+        assert telemetry.TICK_OP_MS_TOTAL.value(model=model, op=name) \
+            == ns / 1e6
+    assert telemetry.TICK_OP_MS_TOTAL.value(model=model, op="layout") == 0
+    assert "quoracle_tick_op_ms_total{" in \
+        telemetry.METRICS.render_prometheus()
+
+
+def test_outside_a_tick_the_operation_helper_records_nothing():
+    before = telemetry.TICK_OP_MS_TOTAL.total()
+    with tick_op("layout") as nothing:
+        assert nothing is None
+    assert tick_op("layout") is tick_op("fetch")     # the shared no-op
+    seen = []
+    rec = tick_open("m")                             # this thread's only
+
+    def elsewhere():
+        with tick_op("layout"):
+            seen.append(tick_phase("pack"))
+    t = threading.Thread(target=elsewhere)
+    t.start()
+    t.join()
+    assert tick_close() is rec
+    assert seen == [None] and rec.op_ns == {}
+    assert telemetry.TICK_OP_MS_TOTAL.total() == before
+
+
+class SeenLock:
+    """The engine's paged lock, noting when a caller starts to wait."""
+
+    def __init__(self, base):
+        self.base = base
+        self.waiting = threading.Event()
+        self.t_wait_ns = 0
+
+    def acquire(self, *a):
+        self.t_wait_ns = time.monotonic_ns()
+        self.waiting.set()
+        return self.base.acquire(*a)
+
+    def release(self):
+        self.base.release()
+
+
+def test_a_session_drop_books_its_wait_for_the_paged_lock(tmp_path):
+    cfg = get_model_config(MEMBER)
+    eng = GenerateEngine(
+        cfg, init_params(cfg, jax.random.PRNGKey(0), dtype=jnp.float32),
+        ByteTokenizer(), max_seq=256, prompt_buckets=(32, 64, 128))
+    eng.generate([[5, 6, 7, 8]], temperature=0.0, max_new_tokens=2,
+                 session_ids=["gone"])
+    assert eng.sessions.get("gone") is not None
+    _, sum0, n0 = telemetry.SESSION_DROP_WAIT_MS.counts(model=cfg.name)
+    lock = eng._paged_lock
+    eng._paged_lock = seen = SeenLock(lock)
+    start_trace(tmp_path)
+    try:
+        lock.acquire()                     # a sessioned tick under way
+        t = threading.Thread(target=eng.drop_session, args=("gone",))
+        t.start()
+        assert seen.waiting.wait(30)
+        time.sleep(0.05)
+        held_until_ns = time.monotonic_ns()
+        lock.release()
+        t.join(30)
+    finally:
+        jax.profiler.stop_trace()
+        eng._paged_lock = lock
+    assert eng.sessions.get("gone") is None
+    # the drop read its clock before it asked for the lock and after it
+    # got it: it waited at least from the asking to the release
+    known_ns = held_until_ns - seen.t_wait_ns
+    assert known_ns > 0
+    _, sum1, n1 = telemetry.SESSION_DROP_WAIT_MS.counts(model=cfg.name)
+    assert n1 == n0 + 1 and (sum1 - sum0) * 1e6 >= known_ns
+    drops = [ev for evs in host_events(tmp_path) for ev in evs
+             if ev.name == "qtpu.session_drop"]
+    assert len(drops) == 1
+    args = drops[0].stats
+    assert args["model"] == cfg.name
+    assert int(args["lock_wait_us"]) >= known_ns // 1000
+    assert int(args["held_us"]) >= 0
+    assert drops[0].duration_ns >= known_ns
+    # on the caller's line, not the batcher's: no tick is open there
+    assert "quoracle_session_drop_wait_ms" in \
+        telemetry.METRICS.render_prometheus()
 
 
 # ---------------------------------------------------------------------------
