@@ -1287,10 +1287,21 @@ class GenerateEngine:
         # query tokens of one row that share a walk of its pages in the
         # chunk forward's attention (ops/paged_attention, the tile kernel);
         # 0: a latent pool's kernel walks a block at a time, no tile table
-        from quoracle_tpu.ops.paged_attention import ragged_tile
+        from quoracle_tpu.ops.paged_attention import (
+            decode_walk_pages, ragged_tile,
+        )
         self._ragged_tile = ragged_tile(
             cfg.n_heads, cfg.head_dim, RAGGED_TQ) if cfg.latent is None \
             else 0
+        # pages a loop iteration of the block kernel's walks carries, as
+        # the kernel reckons it from a page's bytes on one shard (the
+        # decode program's call; 1: a latent pool's kernel walks page by
+        # page). Only the tick span's ``attn_walk_steps`` reads it.
+        self._walk_block = decode_walk_pages(
+            self.sessions.page,
+            cfg.n_kv_heads // (int(mesh.shape["tp"]) if ragged_shard else 1),
+            cfg.head_dim, jnp.dtype(self.pool_dtype).itemsize) \
+            if cfg.latent is None else 1
 
         @functools.partial(jax.jit, static_argnames=())
         def step_paged_prefill(params, k_pool, v_pool, k_scale, v_scale,
@@ -3143,7 +3154,8 @@ class GenerateEngine:
         what its programs brought in, on the tick span (and, for a model
         that selects its keys, ``_note_selection``)."""
         from quoracle_tpu.ops.paged_attention import (
-            ragged_tile_walk, shared_walk_tokens,
+            ragged_tile_walk, ragged_walk_steps, shared_walk_steps,
+            shared_walk_tokens,
         )
         # what the attention kernel had to do this tick, for its roofline
         # (a reader's lower bounds): resident tokens streamed — each row's
@@ -3163,10 +3175,19 @@ class GenerateEngine:
         seen = ctx[:, None] + steps
         decode = np.stack([seen, seen - 1, steps <= fwd[:, None]])
         skip = np.zeros((n,), np.int64) if shared is None else shared[0, :n]
+        window = self.cfg.sliding_window
         streamed, n_tiles = (a + b for a, b in zip(
-            ragged_tile_walk(walked, page, self.cfg.sliding_window),
-            ragged_tile_walk(decode, page, self.cfg.sliding_window,
-                             skip=skip[:, None])))
+            ragged_tile_walk(walked, page, window),
+            ragged_tile_walk(decode, page, window, skip=skip[:, None])))
+        # ... and the loop iterations those walks made: a page each in
+        # the tile kernel, a block of pages in the block kernel (every
+        # decode step; the chunk forward where the engine builds no
+        # tiles), so streamed / page / walk_steps is how full they ran
+        block = self._walk_block
+        walk_steps = ragged_walk_steps(
+            walked, page, 1 if self._ragged_tile else block, window) \
+            + ragged_walk_steps(decode, page, block, window,
+                                skip=skip[:, None])
         kv_reads = int(ctx.sum()) + dec
         pairs = int((seg * (ctx - seg) + seg * (seg + 1) // 2).sum()) + dec
         if shared is not None:
@@ -3175,6 +3196,7 @@ class GenerateEngine:
             )
             needed, shared_in = shared_walk_tokens(shared, fwd, page)
             streamed += shared_in
+            walk_steps += shared_walk_steps(shared, fwd, block)
             tick_note(attn_shared_rows=int((skip > 0).sum()),
                       attn_shared_pages=int(skip[shared[1, :n] > 0].sum()))
             L = self.cfg.n_attn_layers
@@ -3184,7 +3206,8 @@ class GenerateEngine:
                                             model=self.cfg.name,
                                             kind="walked")
         tick_note(attn_kv_reads=kv_reads, attn_pairs=pairs,
-                  attn_kv_streamed=streamed, attn_tiles=n_tiles)
+                  attn_kv_streamed=streamed, attn_tiles=n_tiles,
+                  attn_walk_steps=walk_steps)
         if self.cfg.indexer is not None:
             self._note_selection(kv_reads, pairs, ctx, seg, fwd)
 

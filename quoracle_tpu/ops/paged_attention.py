@@ -15,7 +15,7 @@ programs index each batch row's pages into a contiguous working cache
 ([B, maxp·page, ...] materialized in HBM) and attend over the PADDED
 length. Here attention reads the pool directly: the Pallas kernel walks
 each row's page table and streams only ceil(kv_len/page) pages through
-VMEM (double-buffered HBM DMA) — work is RAGGED, proportional to each
+VMEM (HBM DMA, copies running ahead) — work is RAGGED, proportional to each
 row's real length, not the batch max — and since the forward scatters a
 chunk's KV to its pages BEFORE attention, every key a query can see is
 already there: no tail buffer, no dense intra-chunk piece, no partials
@@ -74,7 +74,7 @@ def _on_tpu() -> bool:
 # Because every key the block can see — resident prefix, earlier chunk
 # tokens, its own tokens — already sits in the pages, there is no
 # tail/chunk partial to merge: the kernel streams only the row's real
-# ceil(visible/page) pages through VMEM (double-buffered, kv heads
+# ceil(visible/page) pages through VMEM (copies running ahead, kv heads
 # flattened into lanes so every Mosaic memref slice stays (8, 128)-tiled
 # for ANY head count; KV = 14 is not sublane-tileable) and normalizes the
 # online-softmax accumulator in-kernel. T=1 decode rows, T=chunk
@@ -144,8 +144,9 @@ def ragged_attend_ref(
 
 
 def _attend_page(q, k, v, ks, vs, valid, m, l, acc):
-    """One page of one kv head's online softmax, shared by the block and
-    the tile kernel: ``q`` [rows, hd] scaled float32 queries (query-major,
+    """One page of one kv head's online softmax in the tile kernel (the
+    block kernel updates the same state once a BLOCK of pages,
+    ``_attend_block``): ``q`` [rows, hd] scaled float32 queries (query-major,
     the head's G query heads a token), ``valid`` [rows, page]; ``k``/``v``
     give the page's [page, hd] float32 blocks and ``ks``/``vs`` an int8
     page's [1, page] scales (else None), each a function called where its
@@ -175,13 +176,22 @@ def _attend_page(q, k, v, ks, vs, valid, m, l, acc):
 
 def _ragged_kernel(tables_ref, meta_ref, layer_ref, *refs, page: int,
                    n_kv: int, hd: int, tq: int, scale: float, window: int,
-                   quant: bool, shared: bool = False):
+                   quant: bool, block: int, shared: bool = False):
     """One tq-token block of the flattened batch: stream the owning row's
-    VISIBLE pages through VMEM double-buffered (kv heads flattened into
-    the lane dim) and write the NORMALIZED attention output for the
-    block. With the chunk KV already scattered into the pages there is
-    no second partial to merge, so the online-softmax accumulator
-    normalizes in-kernel.
+    VISIBLE pages through VMEM (kv heads flattened into the lane dim) and
+    write the NORMALIZED attention output for the block. With the chunk KV
+    already scattered into the pages there is no second partial to merge,
+    so the online-softmax accumulator normalizes in-kernel.
+
+    The walk advances ``block`` PAGES a loop iteration (``_walk_blocks``,
+    section "The BLOCK walk" below): the scratch of a stream holds two
+    blocks, ``[2·block, page, KV·hd]`` with a DMA semaphore a slot, and an
+    iteration starts the next block's copies before it attends its own —
+    every page's scores first, then ONE update of the float32 softmax
+    state for the block (``_attend_block``; its score tiles wait in the
+    last scratch, ``[KV, block, rows, page]``). Same operands, same
+    products, float32 throughout; ``block`` = 1 is the walk of one page
+    an iteration and one state update a page.
 
     Scalar-prefetched (SMEM): tables_ref [R, maxp] one page table per ROW,
     meta_ref [4, NB] per-block (kv_len, qpos0, nq, row), layer_ref [1] the
@@ -192,7 +202,7 @@ def _ragged_kernel(tables_ref, meta_ref, layer_ref, *refs, page: int,
     1 MiB of SMEM at an 8k-token tick.
 
     ``quant`` (int8 pools, ISSUE 13): each page's fp32 scale block
-    ``[KV, page]`` rides the SAME double-buffered DMA stream, and the
+    ``[KV, page]`` rides the SAME stream of copies, slot for slot, and the
     dequant happens inside the streaming loop with zero lane transposes:
     K's per-token scale multiplies the score columns
     (``q·(k·s) = (q·k)·s``) and V's multiplies the probability columns
@@ -214,6 +224,7 @@ def _ragged_kernel(tables_ref, meta_ref, layer_ref, *refs, page: int,
     else:
         out_ref, k_scr, v_scr, sems, *walk_scr = refs
         streams = ((k_hbm, k_scr), (v_hbm, v_scr))
+    s_scr = walk_scr[-1]                 # a block's score tiles
     i = pl.program_id(0)
     kv_len = meta_ref[0, i]
     qpos0 = meta_ref[1, i]
@@ -235,6 +246,19 @@ def _ragged_kernel(tables_ref, meta_ref, layer_ref, *refs, page: int,
         p_lo = jnp.maximum(qpos0 + 1 - window, 0) // page
     else:
         p_lo = jnp.int32(0)
+
+    def page_blocks(half, kv):
+        """What ``_attend_block`` reads of a block's i-th page, in scratch
+        slot ``half + i``, for one kv head: (k, v, k's scales, v's
+        scales), each a function of i."""
+        lanes = slice(kv * hd, (kv + 1) * hd)
+        return (lambda i: k_scr[half + i, :, lanes].astype(jnp.float32),
+                lambda i: v_scr[half + i, :, lanes].astype(jnp.float32),
+                (lambda i: ks_scr[half + i, kv:kv + 1, :]) if quant
+                else None,
+                (lambda i: vs_scr[half + i, kv:kv + 1, :]) if quant
+                else None)
+
     carried = None
     if shared and window < 0:
         # pages a shared walk covers for this row: its own walk starts
@@ -247,20 +271,11 @@ def _ragged_kernel(tables_ref, meta_ref, layer_ref, *refs, page: int,
         for r in members[1:]:
             live = jnp.maximum(live, meta_ref[2, r])
 
-        def kv_blocks(slot, kv):
-            """What ``_attend_page`` reads of the page in ``slot`` for one
-            kv head: (k, v, k's scales, v's scales)."""
-            lanes = slice(kv * hd, (kv + 1) * hd)
-            return (lambda: k_scr[slot, :, lanes].astype(jnp.float32),
-                    lambda: v_scr[slot, :, lanes].astype(jnp.float32),
-                    (lambda: ks_scr[slot, kv:kv + 1, :]) if quant else None,
-                    (lambda: vs_scr[slot, kv:kv + 1, :]) if quant else None)
-
         @pl.when((shared_ref[1, i] > 0) & (live > 0))
         def _():
             _shared_walk(members, p_lo, functools.partial(page_dmas, row),
-                         kv_blocks, q_hbm, walk_scr, n_kv=n_kv, G=G,
-                         scale=scale)
+                         page_blocks, q_hbm, walk_scr[:-1], s_scr,
+                         n_kv=n_kv, G=G, scale=scale, block=block)
 
         carried = (p_lo > 0) & (nq > 0)
     # a block with no query (padding; a row that is done) walks nothing
@@ -270,10 +285,7 @@ def _ragged_kernel(tables_ref, meta_ref, layer_ref, *refs, page: int,
     def dmas(j, slot):
         return page_dmas(row, p_lo + j, slot)
 
-    @pl.when(n > 0)
-    def _():
-        for d in dmas(0, 0):
-            d.start()
+    _start_block(n, block, 0, dmas)
 
     q = q_ref[0].astype(jnp.float32) * scale             # [tq, H, hd]
 
@@ -284,36 +296,18 @@ def _ragged_kernel(tables_ref, meta_ref, layer_ref, *refs, page: int,
     qpos = qpos0 + t_of_row                              # [tq·G, 1]
     q_ok = t_of_row < nq
 
-    def body(j, carry):
-        slot = jax.lax.rem(j, 2)
+    def attend(first, half, left, wait, carry):
+        def valid(i):
+            s_idx = (p_lo + first + i) * page + jax.lax.broadcasted_iota(
+                jnp.int32, (1, page), 1)                 # [1, page]
+            ok = (s_idx < kv_len) & (s_idx <= qpos) & q_ok
+            if window >= 0:
+                ok = ok & (qpos - s_idx < window)
+            return ok
 
-        @pl.when(j + 1 < n)
-        def _():
-            for d in dmas(j + 1, jax.lax.rem(j + 1, 2)):
-                d.start()
-
-        for d in dmas(j, slot):
-            d.wait()
-        k_blk = k_scr[slot].astype(jnp.float32)          # [page, KV·hd]
-        v_blk = v_scr[slot].astype(jnp.float32)
-        if quant:
-            ks_blk = ks_scr[slot]                        # [KV, page] f32
-            vs_blk = vs_scr[slot]
-        s_idx = (p_lo + j) * page + jax.lax.broadcasted_iota(
-            jnp.int32, (1, page), 1)                     # [1, page]
-        valid = (s_idx < kv_len) & (s_idx <= qpos) & q_ok
-        if window >= 0:
-            valid = valid & (qpos - s_idx < window)
-        out = []
-        for kv in range(n_kv):
-            lanes = slice(kv * hd, (kv + 1) * hd)
-            out.append(_attend_page(
-                q[:, kv * G:(kv + 1) * G].reshape(tq * G, hd),
-                lambda: k_blk[:, lanes], lambda: v_blk[:, lanes],
-                (lambda: ks_blk[kv:kv + 1, :]) if quant else None,
-                (lambda: vs_blk[kv:kv + 1, :]) if quant else None,
-                valid, *carry[kv]))
-        return tuple(out)
+        heads = [(q[:, kv * G:(kv + 1) * G].reshape(tq * G, hd),
+                  *page_blocks(half, kv)) for kv in range(n_kv)]
+        return _attend_block(left, wait, heads, valid, s_scr, carry)
 
     def fresh():
         return (jnp.full((tq * G, 1), NEG_INF, jnp.float32),
@@ -333,8 +327,8 @@ def _ragged_kernel(tables_ref, meta_ref, layer_ref, *refs, page: int,
         return tuple(jnp.where(carried, st, new)
                      for st, new in zip(parked, fresh()))
 
-    final = jax.lax.fori_loop(0, n, body,
-                              tuple(init(kv) for kv in range(n_kv)))
+    final = _walk_blocks(n, block, dmas, attend,
+                         tuple(init(kv) for kv in range(n_kv)))
     for kv in range(n_kv):
         _, l, acc = final[kv]
         norm = acc / jnp.where(l > 0, l, 1.0)
@@ -342,7 +336,8 @@ def _ragged_kernel(tables_ref, meta_ref, layer_ref, *refs, page: int,
 
 
 @functools.partial(jax.jit, static_argnames=("tq", "sliding_window",
-                                             "interpret", "tile"))
+                                             "interpret", "tile",
+                                             "walk_block"))
 def ragged_attend(
     q: jax.Array,            # [NB·tq, H, hd] token-major flattened queries
     k_pool: jax.Array,       # [L, n_pages, page, KV·hd] — the stored pool
@@ -358,6 +353,7 @@ def ragged_attend(
     tiles: Optional[jax.Array] = None,     # [6, NT] int32 (ragged_tiles)
     tile: int = 0,                         # tokens a tile holds at most
     shared: Optional[jax.Array] = None,    # [2 + SHARED_ROWS, R] int32
+    walk_block: Optional[int] = None,      # tests: pages a loop iteration
 ) -> jax.Array:
     """Pallas unified ragged attention (same contract as ragged_attend_ref;
     tests/test_ragged_attention.py asserts numerical agreement). Grid is
@@ -373,7 +369,10 @@ def ragged_attend(
     dequantizes in-loop. With ``shared`` (``shared_walks`` of the tables;
     the decode program's call, tq = 1 and block i row i's) rows whose
     tables begin alike have their common pages walked once between them
-    (section "The SHARED walk"): the same output, fewer page reads."""
+    (section "The SHARED walk"): the same output, fewer page reads. The
+    block kernel's walk carries ``walk_pages`` of the page's bytes a loop
+    iteration (section "The BLOCK walk"); ``walk_block`` is the tests' way
+    to the walk of one page an iteration, which nothing else asks for."""
     Tp, H, hd = q.shape
     NB = block_meta.shape[1]
     _, n_pages, page, lanes = k_pool.shape
@@ -383,8 +382,7 @@ def ragged_attend(
     pools = [k_pool, v_pool]
     if quant:
         pools += [k_scale.astype(jnp.float32), v_scale.astype(jnp.float32)]
-    hd_p = max(128, ((hd + 127) // 128) * 128)
-    pack = hd_p // hd if hd_p % hd == 0 and KV % (hd_p // hd) == 0 else 1
+    hd_p, pack = _lane_geometry(KV, hd)
     if pack > 1:
         # heads narrower than a lane tile (LFM2: 64): ``pack`` kv heads
         # that lie side by side in the stored row are read as ONE head of
@@ -408,11 +406,14 @@ def ragged_attend(
                              ).reshape(1, n_pages, page, KV * hd_p)
                      for p in one[:2]] + one[2:]
         layer = jnp.zeros((), jnp.int32)
-    scratch = [pltpu.VMEM((2, page, KV * hd_p), k_pool.dtype),
-               pltpu.VMEM((2, page, KV * hd_p), v_pool.dtype)]
-    if quant:
-        scratch += [pltpu.VMEM((2, KV, page), jnp.float32)] * 2
     window = -1 if sliding_window is None else int(sliding_window)
+    # the tile kernel walks a page an iteration (its multiplies bind it)
+    block = 1 if tiles is not None else walk_block or walk_pages(
+        page * KV * hd_p * k_pool.dtype.itemsize)
+    scratch = [pltpu.VMEM((2 * block, page, KV * hd_p), k_pool.dtype),
+               pltpu.VMEM((2 * block, page, KV * hd_p), v_pool.dtype)]
+    if quant:
+        scratch += [pltpu.VMEM((2 * block, KV, page), jnp.float32)] * 2
     if tiles is not None:
         assert shared is None, "a tile's queries are one row's"
         state = (KV, tile * (H // KV))   # kv-head-major, query-major rows
@@ -446,7 +447,7 @@ def ragged_attend(
     qb = q.reshape(NB, tq, H, hd_p)
     kernel = functools.partial(
         _ragged_kernel, page=page, n_kv=KV, hd=hd_p, tq=tq,
-        scale=hd ** -0.5, quant=quant, window=window,
+        scale=hd ** -0.5, quant=quant, window=window, block=block,
         shared=shared is not None)
     prefetch = [row_tables.astype(jnp.int32), block_meta.astype(jnp.int32),
                 layer.reshape(1)]
@@ -480,8 +481,11 @@ def ragged_attend(
                 pl.BlockSpec((1, tq, H, hd_p), lambda i, *_: (i, 0, 0, 0)),
             ],
             scratch_shapes=[*scratch,
-                            pltpu.SemaphoreType.DMA((2, len(pools))),
-                            *more_scr],
+                            pltpu.SemaphoreType.DMA((2 * block, len(pools))),
+                            *more_scr,
+                            pltpu.VMEM((KV, block, max(
+                                tq, SHARED_ROWS if shared is not None else 0)
+                                * (H // KV), page), jnp.float32)],
         ),
         out_shape=[
             jax.ShapeDtypeStruct((NB, tq, H, hd_p), jnp.float32),
@@ -492,6 +496,190 @@ def ragged_attend(
         name="ragged_attend",
     )(*prefetch, qb, *more_in, *pools)[0]
     return _own_lanes(out.reshape(NB * tq, H, hd_p), KV, pack, hd)
+
+
+def _lane_geometry(n_kv: int, head_dim: int) -> tuple:
+    """(lanes a head takes in the kernel, kv heads read as one): a head
+    narrower than a lane tile packs with its neighbours where the heads
+    divide evenly, else it is padded to the tile."""
+    hd_p = max(128, -(-head_dim // 128) * 128)
+    pack = hd_p // head_dim
+    if hd_p % head_dim or n_kv % pack:
+        pack = 1
+    return hd_p, pack
+
+
+# ---------------------------------------------------------------------------
+# The BLOCK walk (ISSUE 38): several pages in flight, one loop turn a block
+# ---------------------------------------------------------------------------
+#
+# Two things made a page of a decode walk cost its bytes + 0.30–0.36 µs on
+# the chip whatever its size (34% of the bandwidth at 2 kv heads, 62% at 8;
+# PERF.md §6, PR 38, has the split). A walk that starts page j + 1 while it
+# attends page j has one page's arithmetic to hide a DMA's start-to-arrival
+# latency behind; and with the copies far enough ahead the walk is bound by
+# the online softmax's own chain, run once a page for 4–64 score rows: a
+# row maximum and a row sum (lane reductions), exp(m − m_new) and a rescale
+# of acc a kv head a page, each waiting for the one before.
+#
+# The block kernel's walks (a row's own pages, and a group's shared ones)
+# therefore move a BLOCK of B pages a loop iteration. A stream's scratch
+# is two blocks, [2·B, page, KV·hd], with a DMA semaphore a slot; iteration
+# b starts block b + 1's copies — up to B a stream, all in flight at once —
+# into the half block b − 1 left, then attends block b (``_attend_block``):
+#
+#   1. every page's score tile, q·kᵀ (masked where the walk masks), is
+#      written to scratch, a running ELEMENTWISE maximum beside it;
+#   2. one row maximum for the block, one m_new, one exp(m − m_new);
+#   3. every page's p = exp(s − m_new) is summed elementwise and multiplied
+#      into the block's p·v;
+#   4. one row sum, one rescale of l and acc.
+#
+# The B score tiles of a block share a maximum and a rescale, as a page's
+# 128 columns always did: the online softmax with a tile of B·page keys
+# (boom_attention_tricks §9–11, ``pages_per_compute_block``). Operands do
+# not change — float32 q, pages upcast exactly, float32 state — so this is
+# no lower precision; it reorders float32 additions and takes exp against
+# the block's maximum, not the page's (tests/test_ragged_attention.py holds
+# B against B = 1 and the dense oracle within the file's float32 limit).
+# A walk's last block is partial: pages past the walk's end are neither
+# copied nor attended (every loop is bounded by the pages left, none over
+# a masked page of stale scratch), so a walk of fewer than B pages costs
+# what it did. The loops' bodies are a page each — the kernel holds two
+# matmuls a kv head as it always did, not B of them.
+#
+# B is a function of what the kernel can see (``walk_pages``): the bytes of
+# a page in one stream, so that a block in flight is half a MiB a stream —
+# what covers the latency at the bandwidth; on the chip larger blocks read
+# level or worse (PERF.md §6) — and the two blocks of K and V stay within
+# 2 MiB of the 16 MiB of scoped VMEM.
+
+_WALK_BLOCK_BYTES = 512 << 10    # a block in flight, a stream, at most
+_WALK_BLOCK_PAGES = 8            # ... and in pages (the score scratch)
+
+
+def walk_pages(page_bytes: int) -> int:
+    """Pages a loop iteration of the block kernel's walks carries (B of
+    the section above) for pages of ``page_bytes`` in one stream (page ·
+    KV·hd · itemsize, as the kernel lays a head out): the largest power of
+    two that keeps a block within ``_WALK_BLOCK_BYTES``, at most
+    ``_WALK_BLOCK_PAGES`` — 8 at 64 KiB a page (qwen2.5-3b), 4 at 128 KiB
+    (lfm2-24b-a2b-l9, heads packed), 2 at 256 KiB (mistral-7b-l16)."""
+    block = _WALK_BLOCK_PAGES
+    while block > 1 and block * page_bytes > _WALK_BLOCK_BYTES:
+        block //= 2
+    return block
+
+
+def decode_walk_pages(page: int, n_kv: int, head_dim: int,
+                      itemsize: int) -> int:
+    """``walk_pages`` for a pool of this geometry, as ``ragged_attend``
+    reckons it (host side: the engine's count of loop iterations)."""
+    hd_p, pack = _lane_geometry(n_kv, head_dim)
+    return walk_pages(page * (n_kv // pack) * hd_p * itemsize)
+
+
+def _start_block(n, block: int, b, dmas) -> None:
+    """Start the copies of block ``b`` of a walk of ``n`` pages: pages
+    b·block .. into the scratch half of b's parity, a slot a page; none
+    past the walk's end."""
+    first = b * block
+    half = jax.lax.rem(b, 2) * block
+
+    def start(i):
+        for d in dmas(first + i, half + i):
+            d.start()
+
+    _each(jnp.minimum(block, n - first), start)
+
+
+def _walk_blocks(n, block: int, dmas, attend, carry):
+    """The walk of ``n`` pages (a traced count) ``block`` pages a loop
+    iteration, block 0's copies already started (``_start_block``: the
+    caller starts them as early as it can). ``dmas(j, slot)`` are page j's
+    copies into scratch slot ``slot``; ``attend(first, half, left, wait,
+    carry)`` attends one block — pages ``first`` .., in slots ``half`` ..,
+    ``left`` of them (1..block), ``wait(i)`` the wait for its i-th page's
+    copies — and returns the carry. Returns the last carry."""
+    def turn(b, carry):
+        _start_block(n, block, b + 1, dmas)
+        first = b * block
+        half = jax.lax.rem(b, 2) * block
+
+        def wait(i):
+            for d in dmas(first + i, half + i):
+                d.wait()
+
+        return attend(first, half, jnp.minimum(block, n - first), wait,
+                      carry)
+
+    return jax.lax.fori_loop(0, (n + block - 1) // block, turn, carry)
+
+
+_MASKED = NEG_INF / 2        # a stored score under this was masked
+
+
+def _attend_block(left, wait, heads, valid, s_scr, state):
+    """One block of a walk for every kv head (section comment): ``left``
+    pages (traced, 1..B); ``wait(i)`` waits for the copies of the block's
+    i-th page; ``heads`` a kv head each (q [rows, hd] scaled float32
+    queries, then k, v and an int8 page's k and v scales or None, each a
+    function of i: the page's [page, hd] float32 blocks, [1, page]
+    scales); ``valid(i)`` the page's [rows, page] mask, or ``valid`` None
+    where every row sees every page whole (a shared walk); ``s_scr``
+    [n_kv, B, >= rows, page] the block's score tiles; ``state`` a kv head
+    each (m, l, acc). Returns the updated state."""
+    rows, hd = heads[0][0].shape
+    page = s_scr.shape[-1]
+
+    def scores(i, tops):
+        wait(i)
+        ok = None if valid is None else valid(i)
+        out = []
+        for kv, (q, k, _, ks, _) in enumerate(heads):
+            s = jax.lax.dot_general(                     # [rows, page]
+                q, k(i), (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            if ks is not None:
+                s = s * ks(i)                            # dequant K
+            if ok is not None:
+                s = jnp.where(ok, s, NEG_INF)
+            s_scr[kv, i, 0:rows, :] = s
+            out.append(jnp.maximum(tops[kv], s))
+        return tuple(out)
+
+    tops = jax.lax.fori_loop(
+        0, left, scores,
+        tuple(jnp.full((rows, page), NEG_INF, jnp.float32) for _ in heads))
+    m_new = [jnp.maximum(m, jnp.max(top, axis=1, keepdims=True))
+             for (m, _, _), top in zip(state, tops)]
+
+    def values(i, sums):
+        out = []
+        for kv, (_, _, v, _, vs) in enumerate(heads):
+            s = s_scr[kv, i, 0:rows, :]
+            p = jnp.exp(s - m_new[kv])
+            if valid is not None:
+                p = jnp.where(s > _MASKED, p, 0.0)
+            lt, pv = sums[kv]
+            lt = lt + p
+            if vs is not None:
+                p = p * vs(i)                            # dequant V
+            out.append((lt, pv + jax.lax.dot_general(    # [rows, hd]
+                p, v(i), (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)))
+        return tuple(out)
+
+    sums = jax.lax.fori_loop(
+        0, left, values,
+        tuple((jnp.zeros((rows, page), jnp.float32),
+               jnp.zeros((rows, hd), jnp.float32)) for _ in heads))
+    out = []
+    for (m, l, acc), top, (lt, pv) in zip(state, m_new, sums):
+        corr = jnp.exp(m - top)
+        out.append((top, l * corr + jnp.sum(lt, axis=1, keepdims=True),
+                    acc * corr + pv))
+    return tuple(out)
 
 
 def _pack_queries(q: jax.Array, n_kv: int, pack: int) -> jax.Array:
@@ -602,6 +790,16 @@ def ragged_tiles(block_meta, tq: int, tile: int, slots: int = 0):
     return tiles
 
 
+def _tile_pages(tiles, page: int, sliding_window, skip) -> tuple:
+    """(pages each tile's program walks, which tiles are live)."""
+    kv_len, qpos0, nq = np.asarray(tiles, np.int64)[:3]
+    live = nq > 0
+    hi = -(-np.minimum(kv_len, qpos0 + nq) // page)
+    lo = skip if sliding_window is None else \
+        np.maximum(qpos0 + 1 - sliding_window, 0) // page
+    return np.maximum(hi - lo, 0) * live, live
+
+
 def ragged_tile_walk(tiles, page: int, sliding_window=None,
                      skip=0) -> tuple:
     """(resident tokens the kernel's programs bring into VMEM, programs
@@ -609,13 +807,17 @@ def ragged_tile_walk(tiles, page: int, sliding_window=None,
     × page. With the block table as its own tile table (``tile`` = tq) it
     prices the walk of one program per block. ``skip``: leading pages a
     tile's own walk leaves to a shared one (a count, or one a tile)."""
-    kv_len, qpos0, nq = np.asarray(tiles, np.int64)[:3]
-    live = nq > 0
-    hi = -(-np.minimum(kv_len, qpos0 + nq) // page)
-    lo = skip if sliding_window is None else \
-        np.maximum(qpos0 + 1 - sliding_window, 0) // page
-    return (int((np.maximum(hi - lo, 0) * live).sum()) * page,
-            int(live.sum()))
+    pages, live = _tile_pages(tiles, page, sliding_window, skip)
+    return int(pages.sum()) * page, int(live.sum())
+
+
+def ragged_walk_steps(tiles, page: int, block: int = 1,
+                      sliding_window=None, skip=0) -> int:
+    """Loop iterations the programs of a tile table make walking their
+    pages ``block`` pages an iteration (``walk_pages``; the tile kernel
+    and a latent pool's walk one), each walk's last block partial."""
+    pages, _ = _tile_pages(tiles, page, sliding_window, skip)
+    return int((-(-pages // block)).sum())
 
 
 def _each(n, fn) -> None:
@@ -806,17 +1008,20 @@ def _ragged_tile_kernel(tables_ref, tiles_ref, layer_ref, q_hbm, k_hbm,
 #                     duplicate score row and writes the same state twice)
 #
 # The leader's program gathers its group's queries from HBM, streams the
-# common pages through the kernel's own double buffer ONCE and multiplies
-# each against all of them (SHARED_ROWS·G score rows a kv head), every
-# query row with its own float32 online-softmax state, as the tile kernel
-# does for the queries of one row; it leaves each member's (m, l, acc) in
+# common pages through the kernel's own scratch ONCE, a block of pages a
+# loop iteration as every walk of the kernel goes (section "The BLOCK
+# walk"), and multiplies each against all of them (SHARED_ROWS·G score
+# rows a kv head), every query row with its own float32 online-softmax
+# state, updated once a block; it leaves each member's (m, l, acc) in
 # VMEM scratch that outlives the program. Every row's program — the
 # leader's too — then walks only the pages behind the shared ones and
 # starts from that state instead of (−inf, 0, 0): the same pages in the same
-# order through the same arithmetic for every query, the state merely
-# parked between two programs. Every shared page is full and wholly
-# visible for every member (``shared_walks`` caps the count at the rows'
-# whole pages before the loop), so the walk masks nothing.
+# order for every query, the state merely parked between two programs (a
+# block's maximum is taken where the two walks cut the pages, so a row's
+# output is its unshared walk's to float32 rounding). Every shared page
+# is full and wholly visible for every member (``shared_walks`` caps the
+# count at the rows' whole pages before the loop), so the walk masks
+# nothing.
 #
 # A row in no group reads shared[0, r] = 0 and runs the program it always
 # ran; a tick with no group runs no walk. One compiled kernel either way.
@@ -893,19 +1098,36 @@ def shared_walk_tokens(shared, forwards, page: int) -> tuple:
     one of its rows does."""
     shared = np.asarray(shared)
     forwards = np.asarray(forwards, np.int64)
-    n = forwards.shape[0]
-    needed = int((shared[0, :n] * forwards).sum()) * page
-    walked = sum(int(shared[0, r]) * int(forwards[shared[2:, r]].max())
-                 for r in np.flatnonzero(shared[1, :n])) * page
+    needed = int((shared[0, :forwards.shape[0]] * forwards).sum()) * page
+    walked = sum(common * steps
+                 for common, steps in _shared_runs(shared, forwards)) * page
     return needed, walked
 
 
-def _shared_walk(members, n_pages, dmas, kv_blocks, q_hbm, scratch, *,
-                 n_kv: int, G: int, scale: float):
+def _shared_runs(shared, forwards) -> list:
+    """[(common pages, decode steps it ran in)] a shared walk of the loop:
+    a walk runs in every step that one of its rows does."""
+    return [(int(shared[0, r]), int(forwards[shared[2:, r]].max()))
+            for r in np.flatnonzero(shared[1, :forwards.shape[0]])]
+
+
+def shared_walk_steps(shared, forwards, block: int) -> int:
+    """Loop iterations the shared walks of a decode loop made, ``block``
+    pages an iteration (``forwards`` as in ``shared_walk_tokens``)."""
+    return sum(-(-common // block) * steps for common, steps in
+               _shared_runs(np.asarray(shared),
+                            np.asarray(forwards, np.int64)))
+
+
+def _shared_walk(members, n_pages, dmas, page_blocks, q_hbm, scratch, s_scr,
+                 *, n_kv: int, G: int, scale: float, block: int):
     """The walk a leader's program makes for its group (section comment):
     ``members`` SHARED_ROWS row indices (scalars), ``n_pages`` > 0 common
-    pages, ``dmas(j, slot)`` the copies of page j, ``kv_blocks(slot, kv)``
-    what ``_attend_page`` reads of it. ``scratch``, as ``ragged_attend``
+    pages walked ``block`` a loop iteration as every walk of the kernel is
+    (``_walk_blocks``), ``dmas(j, slot)`` the copies of page j,
+    ``page_blocks(half, kv)`` what ``_attend_block`` reads of a block's
+    pages in the kernel's own scratch, ``s_scr`` its score tiles.
+    ``scratch``, as ``ragged_attend``
     lists it: qg_scr [SHARED_ROWS, 1, H, hd] the members' queries as they
     arrive, qf_scr [n_kv, SHARED_ROWS·G, hd] the same scaled to float32,
     member-major rows a kv head; (m, l, acc) in that layout while the walk
@@ -922,8 +1144,7 @@ def _shared_walk(members, n_pages, dmas, kv_blocks, q_hbm, scratch, *,
 
     for k in range(len(members)):
         q_in(k).start()
-    for d in dmas(0, 0):
-        d.start()
+    _start_block(n_pages, block, 0, dmas)
     for k in range(len(members)):
         q_in(k).wait()
     for k in range(len(members)):
@@ -937,23 +1158,16 @@ def _shared_walk(members, n_pages, dmas, kv_blocks, q_hbm, scratch, *,
         l_scr[kv] = jnp.zeros((M, 1), jnp.float32)
         acc_scr[kv] = jnp.zeros(acc_scr.shape[1:], jnp.float32)
 
-    def walk(j, carry):
-        slot = jax.lax.rem(j, 2)
-
-        @pl.when(j + 1 < n_pages)
-        def _():
-            for d in dmas(j + 1, jax.lax.rem(j + 1, 2)):
-                d.start()
-
-        for d in dmas(j, slot):
-            d.wait()
+    def attend(first, half, left, wait, carry):
+        heads = [(qf_scr[kv], *page_blocks(half, kv)) for kv in range(n_kv)]
+        new = _attend_block(
+            left, wait, heads, None, s_scr,
+            tuple((m_scr[kv], l_scr[kv], acc_scr[kv]) for kv in range(n_kv)))
         for kv in range(n_kv):
-            m_scr[kv], l_scr[kv], acc_scr[kv] = _attend_page(
-                qf_scr[kv], *kv_blocks(slot, kv), None,
-                m_scr[kv], l_scr[kv], acc_scr[kv])
+            m_scr[kv], l_scr[kv], acc_scr[kv] = new[kv]
         return carry
 
-    jax.lax.fori_loop(0, n_pages, walk, 0)
+    _walk_blocks(n_pages, block, dmas, attend, 0)
     for k, r in enumerate(members):
         for kv in range(n_kv):
             for scr, st in zip(state, parked):

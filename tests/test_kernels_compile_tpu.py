@@ -68,6 +68,10 @@ def compiles(fn, *args) -> None:
 @pytest.mark.parametrize("tq,nb,rows", [(8, 1024, 8), (1, 8, 8)],
                          ids=["tq8-8k-tick", "tq1-decode"])
 def test_ragged_kernel_compiles(on_v5e, tq, nb, rows, quant):
+    """The block kernel at Mistral's widths with the scratch of its block
+    walk (ISSUE 38): two blocks of ``walk_pages`` pages a stream and a
+    DMA semaphore a slot in scoped VMEM; the prefetched tables in SMEM
+    are the ones it always had."""
     S = on_v5e
     pool = S((LAYERS, N_PAGES, PAGE, KV * HD),
              jnp.int8 if quant else jnp.bfloat16)
@@ -84,21 +88,24 @@ def test_ragged_kernel_compiles(on_v5e, tq, nb, rows, quant):
     compiles(fn, *args)
 
 
-@pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8"])
 @pytest.mark.parametrize("rows", [8, 64], ids=["r8", "r64"])
-@pytest.mark.parametrize("h,kv", [(32, 8), (16, 2)],
-                         ids=["h32-kv8", "h16-kv2"])
-def test_shared_walk_kernel_compiles(on_v5e, h, kv, rows, quant):
-    """The decode call with a shared-walk table (ISSUE 32) at Mistral's and
-    Qwen's head shapes, a table 128 wide, the batcher's 8 row slots and the
-    largest row bucket: the walk table in SMEM beside the page tables; the
-    gathered queries, the walk's softmax state and every row's parked
-    state in scoped VMEM beside the double-buffered pages (at 64 rows of 8
-    kv heads the parked state alone is 6 MiB)."""
+@pytest.mark.parametrize("h,kv,hd,quant", [
+    (32, 8, 128, False), (32, 8, 128, True), (16, 2, 128, False),
+    (16, 2, 128, True), (32, 8, 64, False),     # packed: no int8 pool
+], ids=["h32-kv8-bf16", "h32-kv8-int8", "h16-kv2-bf16", "h16-kv2-int8",
+        "h32-kv8-hd64-packed-bf16"])
+def test_shared_walk_kernel_compiles(on_v5e, h, kv, hd, quant, rows):
+    """The decode call with a shared-walk table (ISSUE 32) at Mistral's,
+    Qwen's and LFM2's head shapes (the last two heads to a lane tile), a
+    table 128 wide, the batcher's 8 row slots and the largest row bucket:
+    the walk table in SMEM beside the page tables; the gathered queries,
+    the walk's softmax state and every row's parked state in scoped VMEM
+    beside the two blocks of pages a stream, ``walk_pages`` each (ISSUE
+    38; at 64 rows of 8 kv heads the parked state alone is 6 MiB)."""
     S = on_v5e
-    pool = S((LAYERS, N_PAGES, PAGE, kv * HD),
+    pool = S((LAYERS, N_PAGES, PAGE, kv * hd),
              jnp.int8 if quant else jnp.bfloat16)
-    args = [S((rows, h, HD), jnp.bfloat16), pool, pool,
+    args = [S((rows, h, hd), jnp.bfloat16), pool, pool,
             S((rows, 128), jnp.int32), S((4, rows), jnp.int32),
             S((), jnp.int32), S((2 + pa.SHARED_ROWS, rows), jnp.int32)]
     if quant:
@@ -196,6 +203,12 @@ def test_ragged_kernel_compiles_at_every_catalog_geometry(on_v5e):
                                ("llama-3-8b", "mistral-7b", "gemma-7b",
                                 "llama-1b", "mistral-1b", "gemma-1b"))}
     for h, kv, hd, window in sorted(geometries, key=str):
+        # each at its own block of pages: both blocks of K and V within
+        # an eighth of the 16 MiB of scoped VMEM (a page of a MiB, gemma-7b's,
+        # is walked alone: 4 MiB)
+        block = pa.decode_walk_pages(PAGE, kv, hd, 2)
+        assert 1 <= block <= 8 and (block == 1 or
+                                    4 * block * PAGE * kv * hd * 2 <= 2 << 20)
         pool = S((LAYERS, N_PAGES, PAGE, kv * hd), jnp.bfloat16)
         for tq, nb in ((8, 64), (1, 8)):
             compiles(functools.partial(pa.ragged_attend, tq=tq,
@@ -203,6 +216,72 @@ def test_ragged_kernel_compiles_at_every_catalog_geometry(on_v5e):
                      S((nb * tq, h, hd), jnp.bfloat16), pool, pool,
                      S((8, 64), jnp.int32), S((4, nb), jnp.int32),
                      S((), jnp.int32))
+
+
+@pytest.mark.parametrize("name,n_kv,hd,itemsize,page_bytes,block", [
+    ("qwen2.5-3b", 2, 128, 2, 64 << 10, 8),
+    ("mistral-7b-l16", 8, 128, 2, 256 << 10, 2),
+    ("lfm2-24b-a2b-l9", 8, 64, 2, 128 << 10, 4),    # 4 packed heads of 128
+    ("mistral-7b-l16-int8", 8, 128, 1, 128 << 10, 4),
+    ("mistral-7b-tp4-shard", 2, 128, 2, 64 << 10, 8),
+    ("gemma-7b", 16, 256, 2, 1 << 20, 1),           # a page an iteration
+    ("tiny-f32", 2, 16, 4, 128 << 10, 4),           # 16 lanes padded to 128
+], ids=lambda v: v if isinstance(v, str) else None)
+def test_walk_block_follows_the_pages_bytes(name, n_kv, hd, itemsize,
+                                            page_bytes, block):
+    """B of the block walk is a function of what the kernel sees — the
+    bytes of a page in one stream, as it lays the heads out — at the three
+    benchmark widths and around them: a block in flight of half a MiB a
+    stream, at most 8 pages."""
+    hd_p, pack = pa._lane_geometry(n_kv, hd)
+    assert PAGE * (n_kv // pack) * hd_p * itemsize == page_bytes
+    assert pa.walk_pages(page_bytes) == block
+    assert pa.decode_walk_pages(PAGE, n_kv, hd, itemsize) == block
+    assert block == 1 or block * page_bytes == 512 << 10
+
+
+def test_walk_steps_count_what_a_hand_walked_table_gives():
+    """``attn_walk_steps`` is counted from the tables as the other tick
+    arguments are: per decode step a row's own walk turns once a block of
+    the pages BEHIND its shared ones (the last block partial), and a
+    group's shared walk once a block of its common pages in every step
+    one of its members runs. Walked by hand here, loop for loop."""
+    page, block = 128, 4
+    #       resident tokens, decode forwards, leading pages a walk covers
+    rows = [(1000, 3, 0), (9 * page - 1, 2, 6), (6 * page + 5, 4, 6),
+            (17 * page + 60, 5, 0)]
+    ctx, fwd, skip = (np.asarray(c, np.int64) for c in zip(*rows))
+    shared = np.zeros((2 + pa.SHARED_ROWS, 4), np.int32)
+    shared[2:] = np.arange(4)
+    shared[0, [1, 2]], shared[1, 1] = 6, 1
+    shared[2:, 1] = [1, 2] + [1] * 6
+    private = walks = 0
+    for step in range(1, fwd.max() + 1):
+        for r in np.flatnonzero(fwd >= step):
+            n = -(-(ctx[r] + step) // page) - skip[r]    # the kernel's n
+            private += len(range(0, n, block))           # its turns
+        if (fwd[[1, 2]] >= step).any():
+            walks += len(range(0, 6, block))
+    steps = np.arange(1, fwd.max() + 1)
+    seen = ctx[:, None] + steps
+    decode = np.stack([seen, seen - 1, steps <= fwd[:, None]])
+    assert pa.ragged_walk_steps(decode, page, block,
+                                skip=skip[:, None]) == private
+    assert pa.shared_walk_steps(shared, fwd, block) == walks
+    # row 0: 8 pages, 2 turns a step; row 3: 18 pages, 5 turns a step,
+    # the last of 2 pages; the group's 6 common pages: 2 turns, 4 steps
+    assert private == 3 * 2 + (1 + 1) + 4 * 1 + 5 * 5 and walks == 2 * 4
+    # a page an iteration: the tile kernel's count, and the pages streamed
+    assert pa.ragged_walk_steps(decode, page, 1, skip=skip[:, None]) * page \
+        == pa.ragged_tile_walk(decode, page, skip=skip[:, None])[0]
+    # a window's first page is the row's own: nothing is skipped, and a
+    # walk is the 4 or 5 pages the window touches (1 turn or 2)
+    window = 4 * page
+    by_hand = sum(len(range((ctx[r] + step - window) // page,
+                            -(-(ctx[r] + step) // page), block))
+                  for r in range(4) for step in range(1, fwd[r] + 1))
+    assert pa.ragged_walk_steps(decode, page, block, window) == by_hand
+    assert int(fwd.sum()) < by_hand < 2 * int(fwd.sum())
 
 
 @pytest.mark.parametrize("b,t,s", [(3, 512, 1024), (1, 1024, 32768 + 1024)],

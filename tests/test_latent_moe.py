@@ -600,6 +600,8 @@ def test_a_latent_tick_counts_the_walk_its_kernel_made(engine):
     assert args["attn_tiles"] == len(chunk) + len(dec)
     assert args["attn_kv_streamed"] == PAGE * (sum(chunk) + sum(dec))
     assert args["attn_kv_streamed"] > 3 * args["attn_kv_reads"] > 0
+    # a latent pool's walk turns once a page
+    assert args["attn_walk_steps"] * PAGE == args["attn_kv_streamed"]
 
 
 # -- the dense models keep their programs -----------------------------------
