@@ -747,6 +747,25 @@ CONV_STATE_REPREFILL_TOKENS_TOTAL = METRICS.counter(
     "prompt tokens whose K/V was resident and matched but which ran "
     "through the chunk forward again because no conv state is held at the "
     "match's end (reuse is rounded down to a page boundary), per model")
+# -- retention groups of attention layers (ISSUE 39) -------------------------
+# A model whose window and full attention layers are mixed holds a session's
+# pages in two groups of pools (config.kv_groups; generate.py ``_run_paged``):
+# the full group keeps every token, the window group what a window reaches.
+KV_GROUP_PAGES_TOTAL = METRICS.counter(
+    "quoracle_kv_group_pages_total",
+    "pages of a model with a window group of attention layers, per model "
+    "and group (full | window), by event: allocated (fresh pages a storing "
+    "row took for a tick), adopted (pages of a cached prefix a new session "
+    "took by reference: the full group's whole, the window group's for the "
+    "last window), released_behind_window (window-group pages a session "
+    "let go at store-back because no position it can still query reaches "
+    "them)")
+KV_SESSION_HELD_TOKENS_TOTAL = METRICS.counter(
+    "quoracle_kv_session_held_tokens_total",
+    "tokens a session holds after a store-back, summed over store-backs, "
+    "per model and group (full | window): window over full is the share of "
+    "its length a session still holds in the window group (near 1: nothing "
+    "is released)")
 # -- the batcher's tick record (ISSUE 24) -----------------------------------
 # One record per ContinuousBatcher._loop iteration, built on the worker
 # thread where the work happens (models/scheduler.py, models/generate.py).
@@ -801,10 +820,12 @@ TICK_OPS: tuple = (
     "session_lookup",   # sessions.get, the common prefix with the held tokens
     "tier_restore",     # tier.restore_session: a hibernated session paged in
     "prefix_match",     # sessions.match_prefix: the radix match and adoption
-    "page_alloc",       # _run_paged's allocation: alloc, eviction, COW
+    "page_alloc",       # _run_paged's allocation: alloc, eviction, COW (and a
+                        # window group's pages for the tick: _window_row)
     "state_adopt",      # a conv model's tables: the record a row starts from,
                         # each token's predecessors, the records to write
-    "session_put",      # the stored tokens, put_raw, page release
+    "session_put",      # the stored tokens, put_raw, page release (and a
+                        # window group's pages behind the window let go)
     "prefix_insert",    # sessions.insert_prefix: the radix insert
     # -- transfer
     "rng",              # next_rng: the split and its unpacking (2 programs)

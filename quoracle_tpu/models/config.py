@@ -101,6 +101,25 @@ class MoEConfig:
 
 
 @dataclasses.dataclass(frozen=True)
+class AttnKind:
+    """One KIND of per-head attention layer. A model whose attention layers
+    differ (Laguna: a full-attention layer, then three with a window of 512
+    and more query heads) names its kinds in ``ModelConfig.attn_kinds`` and
+    a layer's kind in ``layer_types``; every other model has the one kind
+    its own fields spell (``ModelConfig.attn_kind``). K and V are the same
+    ``n_kv_heads`` of ``head_dim`` in every kind: kinds share a page's
+    layout, and differ in what a session must KEEP (``kv_groups``)."""
+
+    n_heads: int                       # query heads
+    window: Optional[int] = None       # keys a query reaches back; None: all
+    rope_theta: float = 10000.0
+    rope_scaling: Optional[tuple] = None    # as ModelConfig.rope_scaling
+    # leading values of each head that rotate (pairs (i, i + rotary_dim/2));
+    # the rest pass through. None: the whole head
+    rotary_dim: Optional[int] = None
+
+
+@dataclasses.dataclass(frozen=True)
 class ModelConfig:
     """Architecture + serving config for one decoder-only model.
 
@@ -109,7 +128,8 @@ class ModelConfig:
     optional learned key selection (the DeepSeek-V2/V3/V3.2 form), and
     hybrids whose layers differ in KIND (LFM2: gated short convolutions
     among per-head attention layers, a dense feed-forward first and routed
-    experts after). Per-family quirks are data, not subclasses; which
+    experts after; Laguna: window and full attention layers with their own
+    head counts and rotary). Per-family quirks are data, not subclasses; which
     forward serves a configuration follows from that data alone
     (``plain``, ``latent``, ``layer_plan``).
     """
@@ -153,12 +173,21 @@ class ModelConfig:
     # Learned top-k selection inside the latent attention (latent models
     # only): a second pool holds each token's index key.
     indexer: Optional[IndexerConfig] = None
-    # The token mixer of each layer, "attention" or "conv" (None: attention
-    # everywhere). A "conv" layer is LFM2's gated short convolution: what
-    # a session holds for it is the last ``conv_cache - 1`` conv inputs, a
-    # fixed block whatever the session's length (``state_lanes``), not
-    # per-token rows.
+    # The token mixer of each layer, "attention", "conv" or the name of one
+    # of ``attn_kinds`` (None: attention everywhere). A "conv" layer is
+    # LFM2's gated short convolution: what a session holds for it is the
+    # last ``conv_cache - 1`` conv inputs, a fixed block whatever the
+    # session's length (``state_lanes``), not per-token rows.
     layer_types: Optional[tuple] = None
+    # ((name, AttnKind), ...): the kinds of attention layer of a model that
+    # has several, by the name ``layer_types`` gives a layer (a tuple of
+    # pairs, so the config stays hashable). None: one kind, from the
+    # fields above (n_heads, sliding_window, rope_theta, rope_scaling).
+    attn_kinds: Optional[tuple] = None
+    # a gate a head on the attention's output, before the output
+    # projection: sigmoid of the layer's normed input through ``wg``
+    # [dim, heads] ("Gated Attention for LLMs", the head-wise form)
+    attn_gate: bool = False
     conv_cache: int = 3              # conv_L_cache: taps of the convolution
     # RMSNorm over each head's values of q and k, before the rotary (one
     # weight vector for all heads)
@@ -203,10 +232,17 @@ class ModelConfig:
             assert 0 <= self.moe.first_dense < self.n_layers
         assert self.indexer is None or self.latent is not None, \
             "the indexer selects keys for latent attention only"
+        if self.attn_kinds is not None:
+            assert self.latent is None and self.layer_types is not None \
+                and "attention" not in self.layer_types, \
+                "a model with kinds of attention names each layer's"
+            assert all(k.n_heads % self.n_kv_heads == 0
+                       for _, k in self.attn_kinds)
         if self.layer_types is not None:
             assert len(self.layer_types) == self.n_layers \
-                and set(self.layer_types) <= {"attention", "conv"}, \
-                self.layer_types
+                and set(self.layer_types) <= {
+                    "conv", *(dict(self.attn_kinds) if self.attn_kinds
+                              else ("attention",))}, self.layer_types
             assert self.latent is None or "conv" not in self.layer_types, \
                 "short-conv layers stand among per-head attention layers"
             assert self.conv_cache >= 2
@@ -220,18 +256,60 @@ class ModelConfig:
     def plain(self) -> bool:
         """Dense decoder with per-head K and V in every layer: every path
         serves it. A model with latent attention, routed experts, conv
-        layers or a q/k norm runs on the ragged paged path alone."""
+        layers, a q/k norm, kinds of attention layer or a gate on its
+        heads runs on the ragged paged path alone."""
         return (self.latent is None and self.moe is None
-                and self.n_conv_layers == 0 and not self.qk_norm)
+                and self.n_conv_layers == 0 and not self.qk_norm
+                and self.attn_kinds is None and not self.attn_gate)
 
     @property
     def mixers(self) -> tuple:
         return self.layer_types or ("attention",) * self.n_layers
 
+    def attn_kind(self, mixer: str = "attention") -> AttnKind:
+        """The attention a layer of type ``mixer`` runs: the kind of that
+        name, or the model's one kind, spelled by its own fields."""
+        if self.attn_kinds is not None:
+            return dict(self.attn_kinds)[mixer]
+        return AttnKind(self.n_heads, self.sliding_window, self.rope_theta,
+                        self.rope_scaling)
+
+    @property
+    def max_heads(self) -> int:
+        """The most query heads an attention layer has."""
+        return max((k.n_heads for _, k in self.attn_kinds or ()),
+                   default=self.n_heads)
+
     @property
     def n_attn_layers(self) -> int:
         """Layers that hold per-token rows in pages (``kv_pools``)."""
-        return self.mixers.count("attention")
+        return self.n_layers - self.n_conv_layers
+
+    @property
+    def kv_groups(self) -> tuple:
+        """The attention layers by what a session must KEEP of them, one
+        ``(window, layers)`` a retention GROUP: a group's layers hold a
+        token's rows for as long as a query can still reach them — for
+        ever (``window`` None) or for ``window`` positions. Every group has
+        its own pools and its own page ids (generate.py ``_ensure_pool``,
+        ``SessionStore``): a session's pages behind a window go back to
+        that group's free list while the full group keeps every token. One
+        group for every model but one with kinds of attention; there the
+        group that keeps everything comes first, then windows ascending."""
+        if self.attn_kinds is None:
+            return ((self.sliding_window, self.n_attn_layers),)
+        count: dict = {}
+        for m in self.mixers:
+            if m != "conv":
+                w = self.attn_kind(m).window
+                count[w] = count.get(w, 0) + 1
+        return tuple(sorted(count.items(),
+                            key=lambda g: (g[0] is not None, g[0] or 0)))
+
+    def kv_group_of(self, mixer: str) -> int:
+        """The retention group (its place in ``kv_groups``) of a layer."""
+        w = self.attn_kind(mixer).window
+        return [g[0] for g in self.kv_groups].index(w)
 
     @property
     def n_conv_layers(self) -> int:
@@ -280,7 +358,7 @@ class ModelConfig:
         ``indexer.head_dim`` for the token's index key. Pool shapes
         (generate.py ``_ensure_pool``), the byte rates below,
         ``kv_signature``, ``quant_stats`` and ``pool_sizing`` all read
-        this."""
+        this, beside ``kv_groups``: which layers share a set of pools."""
         if self.indexer is not None:
             return (self.latent.lanes, self.indexer.head_dim)
         if self.latent is not None:
@@ -291,7 +369,7 @@ class ModelConfig:
     def n_dense_layers(self) -> int:
         return self.n_layers if self.moe is None else self.moe.first_dense
 
-    def _attn_params(self) -> int:
+    def _attn_params(self, mixer: str = "attention") -> int:
         if self.latent is not None:
             la, H = self.latent, self.n_heads
             n = (self.dim * la.q_rank + la.q_rank
@@ -307,12 +385,12 @@ class ModelConfig:
                       + self.dim * ix.head_dim + self.dim * ix.n_heads
                       + 2 * ix.head_dim)
             return n
-        hd = self.head_dim
-        q = self.dim * self.n_heads * hd + (self.n_heads * hd
-                                            if self.attn_bias else 0)
+        hd, H = self.head_dim, self.attn_kind(mixer).n_heads
+        q = self.dim * H * hd + (H * hd if self.attn_bias else 0)
         kv = 2 * (self.dim * self.n_kv_heads * hd
                   + (self.n_kv_heads * hd if self.attn_bias else 0))
-        return q + kv + self.n_heads * hd * self.dim
+        gate = self.dim * H if self.attn_gate else 0
+        return q + kv + H * hd * self.dim + gate
 
     def _conv_params(self) -> int:
         """A short-conv operator: in (to B, C, x), the taps, out."""
@@ -332,7 +410,8 @@ class ModelConfig:
                    + (m.n_routed if m.router_bias else 0)
                    + 3 * self.dim * m.expert_dim * (experts + m.n_shared))
         op = self._conv_params() if mixer == "conv" else \
-            self._attn_params() + (2 * self.head_dim if self.qk_norm else 0)
+            self._attn_params(mixer) + (2 * self.head_dim
+                                        if self.qk_norm else 0)
         return op + mlp + norms
 
     @property
@@ -371,14 +450,19 @@ class ModelConfig:
         return self.n_params - (self.n_layers - m.first_dense) \
             * 3 * self.dim * m.expert_dim * (m.n_held - m.per_token)
 
-    def kv_bytes_per_token(self, tp: int = 1, dtype_bytes: int = 2) -> int:
+    def kv_bytes_per_token(self, tp: int = 1, dtype_bytes: int = 2,
+                           group: Optional[int] = None) -> int:
         """KV cache bytes per resident token PER TP SHARD (whole GQA
         groups per shard: kv heads divide across tp; a latent cache has
-        no heads to divide and is whole on every shard)."""
+        no heads to divide and is whole on every shard): over every
+        attention layer, or over the layers of one retention ``group``
+        (``kv_groups``) — what a token costs while that group keeps it."""
         lanes = sum(self.kv_pools)
         if self.latent is None:
             lanes //= tp
-        return lanes * self.n_attn_layers * dtype_bytes
+        layers = self.n_attn_layers if group is None \
+            else self.kv_groups[group][1]
+        return lanes * layers * dtype_bytes
 
 
 def unsupported_path(cfg: ModelConfig, what: str) -> str:
@@ -389,6 +473,8 @@ def unsupported_path(cfg: ModelConfig, what: str) -> str:
         ("a learned key selection", cfg.indexer is not None),
         ("short-conv state beside the paged KV", cfg.n_conv_layers > 0),
         ("a q/k norm", cfg.qk_norm),
+        ("window and full attention layers mixed", len(cfg.kv_groups) > 1),
+        ("a gate on the attention's heads", cfg.attn_gate),
         ("routed experts", cfg.moe is not None)) if on]
     return (f"model {cfg.name} ({', '.join(has)}) is served on the ragged "
             f"paged path of one device only; {what} cannot run it")

@@ -305,6 +305,11 @@ def decode_ragged(
     forwards, so a page that fills keeps the state at its end and a row
     that is done leaves its record alone.
 
+    A model with several retention groups of attention layers
+    (config.kv_groups) hands in ``k_pool``, ``v_pool`` and ``tables`` as
+    TUPLES, a member a group, and gets the pools back so: a step's token
+    has a slot in every group's pages, and ``shared`` is the full group's.
+
     Returns (tokens [R, max_new], n_emitted [R], lens [R], k_pool,
     v_pool, k_scale, v_scale, jstate, moe_stats, state) where lens counts the
     row's valid pool tokens (prompt + chunk + emitted-and-forwarded) and
@@ -314,9 +319,14 @@ def decode_ragged(
     returned as they came) each step's token quantizes on write inside
     the forward."""
     R = first_logits.shape[0]
-    _, n_pages, page, _ = k_pool.shape
-    n_tok = n_pages * page
-    maxp = tables.shape[1]
+    # a model with several retention groups of attention layers
+    # (config.kv_groups) hands in a tuple of pools and of tables, a member
+    # a group: each step's token has a slot in every group's pages
+    multi = isinstance(tables, tuple)
+    group_pools, group_tables = (k_pool, tables) if multi \
+        else ((k_pool,), (tables,))
+    _, n_pages, page, _ = group_pools[0].shape
+    maxp = group_tables[0].shape[1]
     fns = _sampling_fns(json_table, eos_id, stop_ids)
     is_stop, mask_logits, advance, _ = fns
     tok0, n0, done0, jstate0, out0, rng = _first_token(
@@ -336,11 +346,17 @@ def decode_ragged(
             # this step's token writes at buffer slot lens; done rows (and
             # any row at its page-table edge) drop via the sentinel n_tok,
             # which the forward turns into a drop in every layer
-            pg = jnp.take_along_axis(
-                tables, jnp.minimum(lens // page, maxp - 1)[:, None],
-                axis=1)[:, 0]
-            flat = jnp.where(done | (lens // page >= maxp), n_tok,
-                             pg * page + lens % page)
+            pgs, flats = [], []
+            for pool, table in zip(group_pools, group_tables):
+                pg = jnp.take_along_axis(
+                    table, jnp.minimum(lens // page, maxp - 1)[:, None],
+                    axis=1)[:, 0]
+                pgs.append(pg)
+                flats.append(jnp.where(
+                    done | (lens // page >= maxp), pool.shape[1] * page,
+                    pg * page + lens % page))
+            pg = pgs[0]
+            flat = tuple(flats) if multi else flats[0]
             meta = jnp.stack([
                 lens + live,          # kv_len incl. the token just written
                 lens - (1 - live),    # qpos0 (done rows: inert block)
@@ -353,10 +369,12 @@ def decode_ragged(
                 # one token a row: it follows the record under the page of
                 # the token before it and is recorded under its own page
                 last = jnp.take_along_axis(
-                    tables, jnp.clip((lens - 1) // page, 0, maxp - 1)[:, None],
+                    group_tables[0],
+                    jnp.clip((lens - 1) // page, 0, maxp - 1)[:, None],
                     axis=1)[:, 0]
                 conv = ConvTick(sp, last, None, None,
-                                jnp.where(flat < n_tok, pg, n_pages))
+                                jnp.where(flats[0] < n_pages * page, pg,
+                                          n_pages))
         hidden, kp, vp, ks, vs, st, *rest = forward_hidden_ragged(
             params, cfg, cur[None], positions[None], kp, vp, tables,
             meta, flat, tq=1, interpret=interpret, shard=shard,
@@ -495,10 +513,60 @@ class _Session:
     # pages belong to ANOTHER session; _run_paged refcount-acquires them
     # before using them as this row's dst prefix
     shared_prefix: bool = False
+    # A model with a WINDOW group of attention layers beside the full one
+    # (config.kv_groups, SessionStore.window): the session's pages in that
+    # group's pools, ``wpages[j]`` for the same positions as ``pages[j]``
+    # and 0 where the session holds none — the pages behind the window,
+    # which it lets go at every store-back, and of an adopted prefix all
+    # but the last window's. None: the model has one group.
+    wpages: Optional[list[int]] = None
 
     @property
     def resident_len(self) -> int:
         return len(self.tokens) - self.start_pos
+
+
+class _WindowPages:
+    """The page ids of a WINDOW group's pools (config.kv_groups): a second
+    id space beside the store's own, with its own free list and reference
+    counts (absent key = 1, as the store's). Page 0 is scratch here too: a
+    table entry of 0 is a page the session does not hold. The store's lock
+    guards it."""
+
+    def __init__(self, n_pages: int, window: int):
+        self.n_pages = n_pages
+        self.tokens = window          # positions a query reaches back
+        self._free: list[int] = list(range(n_pages - 1, 0, -1))
+        self._refs: dict[int, int] = {}
+
+    def first_page(self, pos: int, page: int) -> int:
+        """The first page a query at position ``pos`` still reaches."""
+        return max(pos - self.tokens + 1, 0) // page
+
+    def take(self, n: int) -> Optional[list[int]]:
+        if n > len(self._free):
+            return None
+        return [self._free.pop() for _ in range(n)]
+
+    def acquire(self, pages) -> None:
+        for p in pages:
+            if p:
+                self._refs[p] = self._refs.get(p, 1) + 1
+
+    def release(self, pages) -> int:
+        """Give up one reference a page; returns how many went free."""
+        freed = 0
+        for p in pages:
+            if not p:
+                continue
+            c = self._refs.get(p, 1) - 1
+            if c <= 0:
+                self._refs.pop(p, None)
+                self._free.append(p)
+                freed += 1
+            else:
+                self._refs[p] = c
+        return freed
 
 
 class SessionStore:
@@ -510,11 +578,22 @@ class SessionStore:
     the free list runs dry. Thread-safe; the ENGINE additionally serializes
     paged steps (the pool buffers are donated through them)."""
 
-    def __init__(self, max_tokens: int = 262_144, page: int = PAGE):
+    def __init__(self, max_tokens: int = 262_144, page: int = PAGE,
+                 window: Optional[tuple] = None):
         from quoracle_tpu.analysis.lockdep import named_lock
         self.page = page
         self.n_pages = max(3, -(-max_tokens // page) + 1)   # +1 scratch
         self.max_tokens = (self.n_pages - 1) * page
+        # ``window`` (tokens the group's pools hold, the window): the page
+        # ids of a model's WINDOW group of attention layers, which a
+        # session holds only as far back as the window reaches
+        # (``alloc_window``; the engine's ``_run_paged`` lets go of the
+        # rest at every store-back). The ids above are then the FULL
+        # group's, which keeps every token.
+        self.window: Optional[_WindowPages] = None
+        if window is not None:
+            self.window = _WindowPages(
+                max(3, -(-window[0] // page) + 1), window[1])
         self.lock = named_lock("session.store", rlock=True)
         self._sessions: dict[str, _Session] = {}
         self._free: list[int] = list(range(self.n_pages - 1, 0, -1))
@@ -597,7 +676,7 @@ class SessionStore:
                     # release below drops only the victim's own refs, so
                     # shared/COW pages other holders read stay resident
                     self.tier.demote_session(lru, sess)
-                self._release(sess.pages)
+                self._release_session(sess)
             if len(self._free) < n:
                 # defensive: accounting drift — _attainable promised pages
                 # the ladder could not deliver. Formerly a silent None;
@@ -629,6 +708,57 @@ class SessionStore:
                     if not self.prefix_cache.holds(p)
                     and c >= self._refs.get(p, 1))
         return len(self._free) + n_tree + extra
+
+    def alloc_window(self, n: int, protect: tuple = (),
+                     evict: bool = True) -> Optional[list[int]]:
+        """``alloc`` for the WINDOW group's pages. Its ladder, when the
+        free list runs dry: first the radix cache lets go of window pages
+        no session reads, least recently matched first (a node keeps its
+        full-group page; a prefix stays adoptable wherever its last
+        window is still cached, prefix_cache.py), then LRU sessions go
+        (never the ``protect`` keys), whole, as in ``alloc``. None —
+        with nothing evicted — when even that cannot make ``n`` pages."""
+        w = self.window
+        with self.lock:
+            if n <= len(w._free) or not evict:
+                return w.take(n)
+            victims = [k for k in self._sessions if k not in protect]
+            if n > self._attainable_window(victims):
+                return None
+            while len(w._free) < n:
+                if self.prefix_cache.strip_window(n - len(w._free)):
+                    continue
+                if not victims:
+                    break
+                lru = min(victims, key=lambda k: self._sessions[k].last_used)
+                victims.remove(lru)
+                self._release_session(self._sessions.pop(lru))
+            return w.take(n)
+
+    def _attainable_window(self, victims: list) -> int:
+        """Window-group pages reachable by evicting ``victims`` and
+        stripping the radix cache's window pages: the free list, cached
+        pages whose every other reference a victim would release, and
+        victims' pages outside the cache that only victims hold."""
+        import collections
+        w = self.window
+        released: collections.Counter = collections.Counter()
+        for k in victims:
+            for p in self._sessions[k].wpages or ():
+                if p:
+                    released[p] += 1
+        cached = self.prefix_cache.window_pages()
+        n_tree = sum(1 for p in cached
+                     if w._refs.get(p, 1) - released.get(p, 0) <= 1)
+        extra = sum(1 for p, c in released.items()
+                    if p not in cached and c >= w._refs.get(p, 1))
+        return len(w._free) + n_tree + extra
+
+    def _release_session(self, sess: "_Session") -> None:
+        """Give up a session's references in every group."""
+        self._release(sess.pages)
+        if sess.wpages:
+            self.window.release(sess.wpages)
 
     def _release(self, pages: list[int]) -> None:
         for p in pages:
@@ -679,19 +809,28 @@ class SessionStore:
             pages, matched = self.prefix_cache.match(tokens, max_reuse)
             if matched < self.page:
                 return None
-            return _Session(tokens=list(tokens[:matched]),
-                            pages=pages, start_pos=0, shared_prefix=True)
+            wpages = None
+            if self.window is not None:
+                # the full group's pages whole; of the window group's what
+                # a query at the prefix's end still reaches (the match ends
+                # where those are cached, prefix_cache._walk)
+                first = self.window.first_page(matched, self.page)
+                wpages = [0] * first + self.prefix_cache.window_pages_of(
+                    pages[first:])
+            return _Session(tokens=list(tokens[:matched]), pages=pages,
+                            start_pos=0, shared_prefix=True, wpages=wpages)
 
-    def insert_prefix(self, tokens: Sequence[int],
-                      pages: Sequence[int]) -> int:
+    def insert_prefix(self, tokens: Sequence[int], pages: Sequence[int],
+                      wpages: Optional[Sequence[int]] = None) -> int:
         """Feed a freshly stored session's full pages into the radix
         cache (the engine calls this at store-back for full-attention,
-        non-VLM sessions with start_pos == 0). With a disk-backed tier
+        non-VLM sessions with start_pos == 0; ``wpages``: the session's
+        pages in a window group, for the same blocks). With a disk-backed tier
         attached, each full block also writes through to the checksummed
         prefix store (content-addressed — re-inserts cost one stat), so
         a restarted process warm-starts from these prefixes."""
         with self.lock:
-            added = self.prefix_cache.insert(tokens, pages)
+            added = self.prefix_cache.insert(tokens, pages, wpages)
             # durable targets: the local disk store and/or the fleet
             # prefix service (ISSUE 12) — persist_block fans out to both
             if (self.tier is not None
@@ -712,6 +851,9 @@ class SessionStore:
             old = self._sessions.get(key)
             if old is not None and old is not sess:
                 self._release([p for p in old.pages if p not in sess.pages])
+                if old.wpages:
+                    self.window.release([p for p in old.wpages
+                                         if p not in (sess.wpages or ())])
             self._sessions[key] = sess
             if self.tier is not None:
                 self.tier.discard_session(key)   # host copy now stale
@@ -739,7 +881,7 @@ class SessionStore:
         with self.lock:
             s = self._sessions.pop(key, None)
             if s is not None:
-                self._release(s.pages)
+                self._release_session(s)
             if self.tier is not None:
                 # a dropped conversation must not resurrect from the
                 # host tier under a reused id
@@ -1046,9 +1188,27 @@ class GenerateEngine:
         # per-(token, head) scales, so resident_kv_tokens lands at ~2x
         # the bf16 figure at the same byte budget (ISSUE 13).
         token_bytes = self.kv_token_pool_bytes()
+        window = None
+        if len(cfg.kv_groups) > 1:
+            # A window group beside the full one: each gets HALF of the
+            # byte budget, at its own byte rate (a token costs the full
+            # group its layers' rows for as long as the session lives, the
+            # window group its layers' for a window). The split is a
+            # statement, not a tuning: how many tokens either group ends
+            # up holding follows the traffic — the full group every
+            # session's whole length, the window group a window and a
+            # tick's new tokens a live row plus what the radix cache keeps
+            # for adoption (docs/DEPLOY.md §19 has the operator's view).
+            assert len(cfg.kv_groups) == 2, cfg.kv_groups
+            session_max_bytes //= 2
+            wbytes = cfg.kv_bytes_per_token(
+                dtype_bytes=jnp.dtype(self.pool_dtype).itemsize, group=1)
+            window = (max(PAGE, min(session_max_bytes // wbytes,
+                                    32 * self.max_seq)),
+                      cfg.kv_groups[1][0])
         self.sessions = SessionStore(
             max_tokens=max(PAGE, min(session_max_bytes // token_bytes,
-                                     32 * self.max_seq)))
+                                     32 * self.max_seq)), window=window)
         self.sessions.model = cfg.name     # metric label (alloc drift,
                                            # tier counters)
         # The paged steps donate the pool buffers; calls that touch the pool
@@ -1290,8 +1450,9 @@ class GenerateEngine:
         from quoracle_tpu.ops.paged_attention import (
             decode_walk_pages, ragged_tile,
         )
+        # (kinds of attention layer share the one tile table: the widest's)
         self._ragged_tile = ragged_tile(
-            cfg.n_heads, cfg.head_dim, RAGGED_TQ) if cfg.latent is None \
+            cfg.max_heads, cfg.head_dim, RAGGED_TQ) if cfg.latent is None \
             else 0
         # pages a loop iteration of the block kernel's walks carries, as
         # the kernel reckons it from a page's bytes on one shard (the
@@ -1836,6 +1997,9 @@ class GenerateEngine:
         if cfg.n_conv_layers:
             geometry += (f"-A{cfg.n_attn_layers}"
                          f"-conv{cfg.n_conv_layers}x{cfg.state_lanes}")
+        if len(cfg.kv_groups) > 1:
+            geometry += "-G" + "+".join(
+                f"{layers}w{window or 0}" for window, layers in cfg.kv_groups)
         return (f"{cfg.name.replace('/', '_')}-L{cfg.n_layers}"
                 f"{geometry}-p{self.sessions.page}"
                 f"-{jnp.dtype(self.pool_dtype).name}"
@@ -1958,9 +2122,10 @@ class GenerateEngine:
         (the scheduler's relative-state convention). Every row must be
         sessioned; speculative serving never runs on sliding-window or
         vision engines (the BatchedSpeculator enforces eligibility)."""
-        if self.cfg.n_conv_layers:
+        if self.cfg.n_conv_layers or self.sessions.window is not None:
             # a rejected draft's tokens would have advanced the conv
-            # state, and no record is kept to roll it back to
+            # state, and no record is kept to roll it back to; a window
+            # group's pages behind a rejected draft are gone by then
             raise ValueError(unsupported_path(
                 self.cfg, "verify_chunk (speculative drafts)"))
         assert session_ids is not None and all(session_ids), \
@@ -2050,6 +2215,16 @@ class GenerateEngine:
                                 "kv restore failed for %s; re-prefilling",
                                 sid)
                             s = None
+                    p_win = 0
+                    if s is not None and self.sessions.window is not None:
+                        p_win = self._window_match(s, prompts[i])
+                        if not p_win:
+                            # what the window layers would need behind the
+                            # match is gone: the session is forgotten, and
+                            # the row starts over as a new one (from the
+                            # radix cache, where that still holds its prompt)
+                            self.sessions.drop(sid)
+                            s = None
                     if s is None:
                         # Cross-session prefix sharing: a NEW session whose
                         # prompt starts with a RADIX-CACHED page-aligned
@@ -2086,9 +2261,9 @@ class GenerateEngine:
                     # ≥1 suffix token must run to produce last-position
                     # logits (verify mode: the whole K_i window must run —
                     # see above)
-                    p = min(_lcp(s.tokens, prompts[i]),
-                            len(prompts[i]) - 1 if vk is None
-                            else len(prompts[i]) - vk[i])
+                    p = p_win or min(_lcp(s.tokens, prompts[i]),
+                                     len(prompts[i]) - 1 if vk is None
+                                     else len(prompts[i]) - vk[i])
                     if (self.cfg.sliding_window is not None
                             and p < len(s.tokens)):
                         # Windowed models resume only on clean extension: after
@@ -2352,6 +2527,25 @@ class GenerateEngine:
                 ))
         return results
 
+    def _window_match(self, s: _Session, prompt) -> int:
+        """How much of a resident session a prompt reuses in a model with
+        a window group: the common prefix (a token short of the prompt:
+        one has to run), cut back to a page boundary where it ends inside
+        the session's tokens — the pages behind a divergence are shared
+        with the radix cache in both groups, and a tick writes whole
+        fresh pages, never into a shared one. 0 where the session no
+        longer holds, in the window group, every page a query behind the
+        match reaches: it does after a clean extension (store-back keeps
+        the last window); after a divergence further back than that the
+        window layers' rows are gone, and nothing short of the prompt's
+        start could make them again."""
+        st = self.sessions
+        p = min(_lcp(s.tokens, prompt), len(prompt) - 1)
+        if p < len(s.tokens):
+            p = p // st.page * st.page
+        held = s.wpages[st.window.first_page(p, st.page):-(-p // st.page)]
+        return p if all(held) else 0
+
     def _record_telemetry(self, n: int, B: int, T: int, cache_len: int,
                           max_new: int, paged: bool, n_emitted,
                           latency: float) -> None:
@@ -2503,8 +2697,11 @@ class GenerateEngine:
         for resources attribution, /api/kv compression and planning."""
         from quoracle_tpu.models.quant import kv_token_bytes
         if not self.cfg.plain:             # never int8
+            # a model with a window group: the FULL group's rate, what a
+            # token costs for as long as its session lives
             return self.cfg.kv_bytes_per_token(
-                dtype_bytes=jnp.dtype(self.pool_dtype).itemsize)
+                dtype_bytes=jnp.dtype(self.pool_dtype).itemsize,
+                group=0 if len(self.cfg.kv_groups) > 1 else None)
         return kv_token_bytes(
             self.cfg.n_layers, self.cfg.n_kv_heads, self.cfg.head_dim,
             jnp.dtype(self.pool_dtype).itemsize, self.quantize_kv)
@@ -2513,8 +2710,10 @@ class GenerateEngine:
         """The member's quantization posture for /api/kv and bench
         config 19: mode flags, the per-token KV byte rate vs the bf16
         rate, and the resulting compression ratio."""
+        multi = len(self.cfg.kv_groups) > 1
         bf16_rate = self.cfg.kv_bytes_per_token(
-            dtype_bytes=jnp.dtype(self.cache_dtype).itemsize)
+            dtype_bytes=jnp.dtype(self.cache_dtype).itemsize,
+            group=0 if multi else None)
         rate = self.kv_token_pool_bytes()
         return {
             "quantize_weights": self.quantize_weights,
@@ -2527,6 +2726,16 @@ class GenerateEngine:
             # layers' state at the page's end (0 without conv layers)
             "state_bytes_per_record": self.cfg.state_bytes_per_record(
                 jnp.dtype(self.pool_dtype).itemsize),
+            # what a token holds in a WINDOW group of attention layers,
+            # for at most a window and a page (0: the model has none; the
+            # rate above is then every attention layer's, else the full
+            # group's alone), and the tokens that group's pools hold
+            "window_kv_bytes_per_token": self.cfg.kv_bytes_per_token(
+                dtype_bytes=jnp.dtype(self.pool_dtype).itemsize, group=1)
+            if multi else 0,
+            "resident_window_kv_tokens":
+            (self.sessions.window.n_pages - 1) * self.sessions.page
+            if multi else 0,
         }
 
     def _ensure_pool(self) -> None:
@@ -2571,6 +2780,19 @@ class GenerateEngine:
         if st.k is not None:
             return
         lanes = self.cfg.kv_pools
+        if st.window is not None:
+            # a window group beside the full one (config.kv_groups): a
+            # PAIR of pools a stream, ``[layers of the group, the group's
+            # n_pages, page, KV·hd]``, each under its own page ids —
+            # ``st.k = (full, window)`` — which the serving programs carry
+            # and update in place as they do one
+            st.k, st.v = (tuple(
+                jnp.zeros((layers, n_pages, st.page, lanes[0]),
+                          self.pool_dtype)
+                for (_, layers), n_pages in zip(
+                    self.cfg.kv_groups, (st.n_pages, st.window.n_pages)))
+                for _ in range(2))
+            return
         shape = (self.cfg.n_attn_layers, st.n_pages, st.page)
         sh = None
         if self.mesh is not None:
@@ -2593,6 +2815,66 @@ class GenerateEngine:
             st.state = jnp.zeros((self.cfg.n_conv_layers * st.n_pages,
                                   self.cfg.state_lanes), self.pool_dtype)
         st.k, st.v = k, v
+
+    def _window_row(self, s: Optional[_Session], pre: int, need: int,
+                    protect: tuple) -> Optional[list[int]]:
+        """A storing row's pages in the WINDOW group for one tick
+        (SessionStore.window; the caller holds the store's lock): entry j
+        the page of positions ``[j·page, (j+1)·page)``, for ``need`` pages.
+        The row keeps what it holds (its own, or an adopted prefix's)
+        from the first page a query of this tick still reaches up to the
+        ``pre`` tokens it reuses — the page a clean extension goes on
+        filling among them — takes a FRESH page for every page behind
+        that, the tick's own tokens and the decode loop's (a chunk's
+        tokens attend each other across it, so all of them are held for
+        the length of the tick), and lets go of the rest of what it held:
+        what lies behind the window by now, and the tail a divergence
+        left. Pages before the first reachable one stay 0 — the kernel's
+        walk starts behind them. None, with nothing taken, where the pool
+        cannot make the fresh pages."""
+        st = self.sessions
+        win, page = st.window, st.page
+        first = win.first_page(pre, page)
+        fresh_from = -(-pre // page)
+        fresh = st.alloc_window(need - fresh_from, protect=protect)
+        if fresh is None:
+            return None
+        held = list(s.wpages) if s is not None else []
+        assert all(held[first:fresh_from]) and \
+            len(held) >= fresh_from, (pre, held)
+        win.release(held[:first] + held[fresh_from:])
+        from quoracle_tpu.infra.telemetry import KV_GROUP_PAGES_TOTAL
+        KV_GROUP_PAGES_TOTAL.inc(len(fresh), model=self.cfg.name,
+                                 group="window", event="allocated")
+        if s is not None and s.shared_prefix:
+            KV_GROUP_PAGES_TOTAL.inc(fresh_from - first, model=self.cfg.name,
+                                     group="window", event="adopted")
+            KV_GROUP_PAGES_TOTAL.inc(len(s.pages), model=self.cfg.name,
+                                     group="full", event="adopted")
+        return [0] * first + held[first:fresh_from] + fresh
+
+    def _note_window(self, behind: list[int], valid: int,
+                     kept: int) -> int:
+        """Let go of a stored session's window-group pages ``behind`` the
+        window and book it: the pages released, and what the session now
+        holds in either group (``quoracle_kv_session_held_tokens_total``:
+        tokens, summed over store-backs — window over full is the share
+        of its length a session still holds in the window group).
+        Returns the count released."""
+        from quoracle_tpu.infra.telemetry import (
+            KV_GROUP_PAGES_TOTAL, KV_SESSION_HELD_TOKENS_TOTAL,
+        )
+        st = self.sessions
+        with st.lock:
+            st.window.release(behind)
+        n = sum(1 for pg in behind if pg)
+        name = self.cfg.name
+        KV_GROUP_PAGES_TOTAL.inc(n, model=name, group="window",
+                                 event="released_behind_window")
+        KV_SESSION_HELD_TOKENS_TOTAL.inc(valid, model=name, group="full")
+        KV_SESSION_HELD_TOKENS_TOTAL.inc(
+            min(valid, kept * st.page), model=name, group="window")
+        return n
 
     @staticmethod
     def ragged_fallback(*, mesh_lays_flat: bool, forced: bool,
@@ -2660,6 +2942,15 @@ class GenerateEngine:
         adopted_release: list[list[int]] = [[] for _ in range(n)]
         partial_swap = False        # a swapped boundary page: condition
                                     # (c) of ragged_fallback
+        # A model with a window group (config.kv_groups): each row's pages
+        # in THAT group's pools, for the same positions as ``dst`` and 0
+        # where the row holds none (``_window_row``); what it took for the
+        # tick alone, and the references it took on an adopted prefix
+        win = st.window
+        wdst = np.zeros((B, maxp), np.int32) if win is not None else None
+        wrows: list[Optional[list[int]]] = [None] * n
+        wtemps: list[list[int]] = [[] for _ in range(n)]
+        wadopted: list[list[int]] = [[] for _ in range(n)]
         # one allocation transaction for the batch
         with tick_op("page_alloc"), st.lock:
             # Refcount-acquire every adopted donor prefix FIRST: an alloc
@@ -2671,6 +2962,9 @@ class GenerateEngine:
                 if s is not None and s.shared_prefix:
                     st.acquire(s.pages)
                     adopted_release[i] = list(s.pages)
+                    if win is not None:
+                        win.acquire(s.wpages)
+                        wadopted[i] = list(s.wpages)
             for i in range(n):
                 s = sess_rows[i]
                 if s is not None:
@@ -2734,22 +3028,30 @@ class GenerateEngine:
                     # keep their content (prefix_cache.py invariant I2)
                     st.prefix_cache.note_cow(len(shared_beyond))
                 n_extra = max(0, need - len(old)) + len(shared_beyond)
-                if n_extra:
-                    extra = st.alloc(n_extra, protect=protect)
-                    if extra is None:
-                        # pool exhausted even after eviction: serve the
-                        # row without storing (old session stays valid).
-                        # An adopted prefix reverts to read-only use: its
-                        # reference releases after the steps run.
-                        store_sids[i] = None
-                        spills[i] = []
-                        if s is not None and s.shared_prefix:
-                            adopted_release[i] = list(s.pages)
-                        continue
-                    for j in shared_beyond:
-                        st._release([old[j]])   # our ref; adopters keep
-                        old[j] = extra.pop()
-                    old = old + extra
+                extra = st.alloc(n_extra, protect=protect) if n_extra else []
+                if extra is not None and win is not None:
+                    wrows[i] = self._window_row(s, reuse_abs[i], need,
+                                                protect)
+                    if wrows[i] is None:
+                        st._release(extra)
+                        extra = None
+                    else:
+                        wadopted[i] = []    # the stored session owns them
+                        wdst[i, :need] = wrows[i]
+                if extra is None:
+                    # pool exhausted even after eviction: serve the
+                    # row without storing (old session stays valid).
+                    # An adopted prefix reverts to read-only use: its
+                    # reference releases after the steps run.
+                    store_sids[i] = None
+                    spills[i] = []
+                    if s is not None and s.shared_prefix:
+                        adopted_release[i] = list(s.pages)
+                    continue
+                for j in shared_beyond:
+                    st._release([old[j]])       # our ref; adopters keep
+                    old[j] = extra.pop()
+                old = old + extra
                 st._release(tail_shared)        # our refs; adopters keep
                 dst_lists[i] = old
                 dst[i, :len(old)] = old
@@ -2773,17 +3075,28 @@ class GenerateEngine:
                     # must not evict other agents' resident sessions
                     tmp = st.alloc(-(-need_tokens // page),
                                    protect=protect, evict=False)
-                    if tmp is None:
+                    wtmp = [] if win is None or tmp is None else \
+                        st.alloc_window(len(tmp), evict=False)
+                    if tmp is None or wtmp is None:
+                        if tmp is not None:
+                            st._release(tmp)
                         fallback = "no free page for a sessionless " \
                                    "row's temporaries"
                         break
                     temp_lists[i] = tmp
                     dst[i, :len(tmp)] = tmp
+                    if win is not None:
+                        wtemps[i] = wtmp
+                        wdst[i, :len(wtmp)] = wtmp
                 if fallback is not None:
                     for i, tmp in enumerate(temp_lists):
                         if tmp:
                             st._release(tmp)
                         temp_lists[i] = None
+                    for wtmp in wtemps:
+                        if wtmp:
+                            win.release(wtmp)
+                    wtemps = [[] for _ in range(n)]
 
         vout = None
         if fallback is not None and not self.cfg.plain:
@@ -2806,6 +3119,15 @@ class GenerateEngine:
                     for taken in (temp_lists[i], adopted_release[i]):
                         if taken:
                             st._release(taken)
+                    if win is not None:
+                        if wrows[i] is not None:
+                            # _window_row re-dealt the session's window
+                            # pages: it is forgotten with the row's
+                            kept = st._sessions.pop(store_sids[i], None)
+                            if kept is not None:
+                                st._release(kept.pages)
+                            win.release(wrows[i])
+                        win.release(wtemps[i] + wadopted[i])
             raise RuntimeError(unsupported_path(
                 self.cfg, f"the gather fallback of a paged tick "
                           f"({fallback})"))
@@ -2814,7 +3136,7 @@ class GenerateEngine:
              now) = self._run_unified(
                  n, suffixes, dst, pre_arr, off_arr, chunk_arr,
                  samp_np, jstate_np, json_args[0], rng_key, max_new,
-                 maxp, verify)
+                 maxp, verify, wdst)
         elif verify is not None:
             # Speculative verify: ONE teacher-forced chunk forward with
             # window logits (no decode loop). The chunk KV scatters back
@@ -2868,6 +3190,7 @@ class GenerateEngine:
         tick_phase("commit")
         with tick_op("session_put"):
             lens_host = np.asarray(final_lens)
+            released = 0        # window-group pages let go behind windows
             for i in range(n):
                 sid, pages = store_sids[i], dst_lists[i]
                 if sid is None or pages is None:
@@ -2889,11 +3212,15 @@ class GenerateEngine:
                     st.release(pages[:drop])
                     pages = pages[drop:]
                     start += drop * page
+                wpages = None
+                if win is not None:
+                    win.release(wrows[i][used:])
+                    wpages = wrows[i][:used]
                 # put_raw: page lifecycle handled explicitly above (the old
                 # session's pages are all in dst_lists + spills, so the
                 # releases above cover exactly the no-longer-referenced ones)
                 st.put_raw(sid, _Session(tokens=toks, pages=pages,
-                                         start_pos=start))
+                                         start_pos=start, wpages=wpages))
                 # Radix prefix cache insert: every FULL page of the stored
                 # conversation (prompt + retained response KV) becomes
                 # adoptable by future sessions. Windowed/trimmed sessions are
@@ -2906,7 +3233,22 @@ class GenerateEngine:
                         and self.cfg.sliding_window is None
                         and self.cfg.vision is None and verify is None):
                     with tick_op("prefix_insert"):
-                        st.insert_prefix(toks, pages)
+                        st.insert_prefix(toks, pages, wpages)
+                if win is not None:
+                    # ... and only then does the session let go of the
+                    # window group's pages that no position it can still
+                    # query reaches (the next is ``valid``): the radix
+                    # cache has taken its own references first, so a
+                    # prefix stays adoptable at every boundary
+                    behind = win.first_page(valid, page)
+                    released += self._note_window(
+                        wpages[:behind], valid, used - behind)
+                    wpages[:behind] = [0] * behind
+            if win is not None:
+                tick_note(window_pages_released=released)
+                for i in range(n):
+                    with st.lock:
+                        win.release(wtemps[i] + wadopted[i])
             # temp pages (sessionless rows of a ragged tick) die with the call
             for tmp in temp_lists:
                 if tmp:
@@ -2921,7 +3263,7 @@ class GenerateEngine:
 
     def _run_unified(self, n, suffixes, dst, pre_arr, off_arr, chunk_arr,
                      samp_np, jstate_np, json_table, rng_key,
-                     max_new, maxp, verify):
+                     max_new, maxp, verify, wdst=None):
         """One UNIFIED ragged tick (ISSUE 8): lay every row's suffix out
         token-major (segments padded to RAGGED_TQ blocks so a block never
         spans rows), run ONE mixed chunk forward through the ragged
@@ -2933,7 +3275,15 @@ class GenerateEngine:
         width, decode bound), which CompileRegistry ledgers for the
         collapse assertion. Returns (out, n_emitted, final_lens, jstate_f,
         vout, t_prefill, now) with all row-indexed arrays sized [R] whose
-        first ``n`` slots are the batch rows in order."""
+        first ``n`` slots are the batch rows in order.
+
+        ``wdst`` (a model with a window group, config.kv_groups): the
+        rows' pages in that group's pools, beside ``dst`` the full
+        group's. The tick then lays a SECOND table and a second set of
+        write slots over the same positions (``window_tables``) and hands
+        the programs pairs — pools, tables, slots — where they take one;
+        the program's key does not change: both tables are ``maxp_p2``
+        wide."""
         from quoracle_tpu.ops.paged_attention import (
             ragged_tile_slots, ragged_tiles, shared_walks,
         )
@@ -2961,6 +3311,10 @@ class GenerateEngine:
             flat_tok = np.full((TB,), pad_id, np.int32)
             flat_pos = np.zeros((TB,), np.int32)
             flat_dst = np.full((TB,), n_tok, np.int32)     # OOB = drop
+            if wdst is not None:
+                w_tok = st.window.n_pages * page
+                flat_wdst = np.full((TB,), w_tok, np.int32)
+                w_tables = np.zeros((R, maxp_p2), np.int32)
             bmeta = np.zeros((4, NB), np.int32)   # kv_len, qpos0, nq, row
             last_idx = np.zeros((R,), np.int32)
             r_tables = np.zeros((R, maxp_p2), np.int32)
@@ -2992,6 +3346,10 @@ class GenerateEngine:
                 flat_pos[cur:cur + s] = int(off_arr[i]) + pos
                 flat_dst[cur:cur + s] = (dst[i, pos // page] * page
                                          + pos % page)
+                if wdst is not None:
+                    flat_wdst[cur:cur + s] = (wdst[i, pos // page] * page
+                                              + pos % page)
+                    w_tables[i, :maxp] = wdst[i]
                 kv_len = pre + s
                 blk = cur // TQ + np.arange(nb)
                 bmeta[0, blk] = kv_len
@@ -3058,6 +3416,12 @@ class GenerateEngine:
             with tick_op("shared_walks"):
                 shared = shared_walks(r_tables, r_pool_lens, page,
                                       self.cfg.sliding_window)
+        flat_dsts, tables_np = flat_dst, r_tables
+        if wdst is not None:
+            # the window group's table and slots ride beside the full
+            # group's, a pair where a one-group model has an array
+            flat_dsts, tables_np = (flat_dst, flat_wdst), (r_tables,
+                                                           w_tables)
         with tick_op("h2d"):
             js_dev = (None if json_table is None
                       else jnp.asarray(r_jstate))
@@ -3098,9 +3462,10 @@ class GenerateEngine:
         tick_note(context_tokens=int(r_pool_lens.sum()))
         tick_phase("dispatch_prefill")
         with tick_op("h2d"):
+            as_dev = functools.partial(jax.tree.map, jnp.asarray)
             args = (jnp.asarray(flat_tok), jnp.asarray(flat_pos),
-                    jnp.asarray(r_tables), jnp.asarray(bmeta), tiles,
-                    jnp.asarray(flat_dst), jnp.asarray(last_idx))
+                    as_dev(tables_np), jnp.asarray(bmeta), tiles,
+                    as_dev(flat_dsts), jnp.asarray(last_idx))
         with tick_op("enqueue"):
             (last_logits, st.k, st.v, st.k_scale, st.v_scale, moe_pre,
              st.state) = self._step_paged_ragged(
@@ -3112,7 +3477,7 @@ class GenerateEngine:
         t_prefill = time.monotonic()
         tick_phase("dispatch_decode")
         with tick_op("h2d"):
-            tables = (jnp.asarray(r_tables),
+            tables = (as_dev(tables_np),
                       None if shared is None else jnp.asarray(shared),
                       jnp.asarray(r_pool_lens), jnp.asarray(r_off))
             samp = (jnp.asarray(r_temp), jnp.asarray(r_top),
@@ -3152,7 +3517,34 @@ class GenerateEngine:
                         shared, page: int) -> None:
         """Book what the attention kernel had to do in one ragged tick and
         what its programs brought in, on the tick span (and, for a model
-        that selects its keys, ``_note_selection``)."""
+        that selects its keys, ``_note_selection``): a LAYER's numbers.
+        A model with a window group beside the full one
+        (config.kv_groups) books the full group's layer under these
+        names and the window group's, reckoned under its window and
+        with no shared walk, under the same names with ``_window``
+        behind them."""
+        work = self._attention_work(n, segs, r_pool_lens, final_lens,
+                                    walked, shared, page,
+                                    *self.cfg.kv_groups[0])
+        tick_note(**work)
+        for group in self.cfg.kv_groups[1:]:
+            tick_note(**{f"{k}_window": v for k, v in self._attention_work(
+                n, segs, r_pool_lens, final_lens, walked, None, page,
+                *group).items()})
+        if self.cfg.indexer is not None:
+            seg = np.asarray(segs, np.int64)
+            ctx = r_pool_lens[:n].astype(np.int64)
+            self._note_selection(work["attn_kv_reads"], work["attn_pairs"],
+                                 ctx, seg, final_lens[:n].astype(np.int64)
+                                 - ctx)
+
+    def _attention_work(self, n: int, segs, r_pool_lens, final_lens, walked,
+                        shared, page: int, window: Optional[int],
+                        layers: int) -> dict:
+        """One attention layer's work in a ragged tick, as tick-span
+        arguments: for a group of ``layers`` layers with ``window`` (None:
+        none) and the decode program's ``shared`` walk table (None:
+        none)."""
         from quoracle_tpu.ops.paged_attention import (
             ragged_tile_walk, ragged_walk_steps, shared_walk_steps,
             shared_walk_tokens,
@@ -3160,11 +3552,12 @@ class GenerateEngine:
         # what the attention kernel had to do this tick, for its roofline
         # (a reader's lower bounds): resident tokens streamed — each row's
         # context once for its chunk and once per decode step — and
-        # query-key pairs attended under the causal mask
+        # query-key pairs attended under the causal mask (and the window:
+        # a query reaches ``window`` keys at most, a chunk's queries
+        # between them the chunk and a window before it)
         seg = np.asarray(segs, np.int64)
         ctx = r_pool_lens[:n].astype(np.int64)
         fwd = final_lens[:n].astype(np.int64) - ctx     # decode forwards
-        dec = int((fwd * ctx + fwd * (fwd + 1) // 2).sum())
         # ... and what its programs did bring into VMEM: the pages each
         # tile of the chunk forward walked, and a row's pages once a
         # decode step (forward j of a row is a one-token tile that sees
@@ -3173,9 +3566,23 @@ class GenerateEngine:
         # streamed / reads is how many times a needed token was fetched.
         steps = np.arange(1, int(fwd.max(initial=0)) + 1)
         seen = ctx[:, None] + steps
-        decode = np.stack([seen, seen - 1, steps <= fwd[:, None]])
+        live = steps <= fwd[:, None]
+        decode = np.stack([seen, seen - 1, live])
+        if window is None:
+            dec = int((fwd * ctx + fwd * (fwd + 1) // 2).sum())
+            kv_reads = int(ctx.sum()) + dec
+            pairs = int((seg * (ctx - seg) + seg * (seg + 1) // 2).sum()) \
+                + dec
+        else:
+            dec = int((np.minimum(seen, window) * live).sum())
+            kv_reads = int(np.minimum(ctx, seg + window - 1).sum()) + dec
+            # query i of a chunk (0-based) sits at position ctx - seg + i
+            # and reaches min(position + 1, window) keys
+            first = ctx - seg + 1
+            full = np.clip(window - first, 0, seg)   # queries below window
+            pairs = int((full * first + full * (full - 1) // 2
+                         + (seg - full) * window).sum()) + dec
         skip = np.zeros((n,), np.int64) if shared is None else shared[0, :n]
-        window = self.cfg.sliding_window
         streamed, n_tiles = (a + b for a, b in zip(
             ragged_tile_walk(walked, page, window),
             ragged_tile_walk(decode, page, window, skip=skip[:, None])))
@@ -3188,8 +3595,7 @@ class GenerateEngine:
             walked, page, 1 if self._ragged_tile else block, window) \
             + ragged_walk_steps(decode, page, block, window,
                                 skip=skip[:, None])
-        kv_reads = int(ctx.sum()) + dec
-        pairs = int((seg * (ctx - seg) + seg * (seg + 1) // 2).sum()) + dec
+        work = {}
         if shared is not None:
             from quoracle_tpu.infra.telemetry import (
                 ATTN_SHARED_KV_TOKENS_TOTAL,
@@ -3197,19 +3603,17 @@ class GenerateEngine:
             needed, shared_in = shared_walk_tokens(shared, fwd, page)
             streamed += shared_in
             walk_steps += shared_walk_steps(shared, fwd, block)
-            tick_note(attn_shared_rows=int((skip > 0).sum()),
-                      attn_shared_pages=int(skip[shared[1, :n] > 0].sum()))
-            L = self.cfg.n_attn_layers
-            ATTN_SHARED_KV_TOKENS_TOTAL.inc(needed * L, model=self.cfg.name,
-                                            kind="needed")
-            ATTN_SHARED_KV_TOKENS_TOTAL.inc(shared_in * L,
-                                            model=self.cfg.name,
-                                            kind="walked")
-        tick_note(attn_kv_reads=kv_reads, attn_pairs=pairs,
-                  attn_kv_streamed=streamed, attn_tiles=n_tiles,
-                  attn_walk_steps=walk_steps)
-        if self.cfg.indexer is not None:
-            self._note_selection(kv_reads, pairs, ctx, seg, fwd)
+            work.update(
+                attn_shared_rows=int((skip > 0).sum()),
+                attn_shared_pages=int(skip[shared[1, :n] > 0].sum()))
+            ATTN_SHARED_KV_TOKENS_TOTAL.inc(
+                needed * layers, model=self.cfg.name, kind="needed")
+            ATTN_SHARED_KV_TOKENS_TOTAL.inc(
+                shared_in * layers, model=self.cfg.name, kind="walked")
+        work.update(attn_kv_reads=kv_reads, attn_pairs=pairs,
+                    attn_kv_streamed=streamed, attn_tiles=n_tiles,
+                    attn_walk_steps=walk_steps)
+        return work
 
     def _json_table_device(self, enum_set: tuple):
         """Lazily build + cache grammar tables for this tokenizer (one

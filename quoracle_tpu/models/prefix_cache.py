@@ -41,6 +41,21 @@ Invariants (asserted by tests/test_prefix_cache.py):
   I3  sessions hold contiguous root-path references, so iterative
       unreferenced-LEAF eviction reaches exactly the reclaimable nodes.
 
+A model with a WINDOW group of attention layers beside the full one
+(config.kv_groups; the store's ``window`` ids) keeps a cached block in both:
+a node holds the block's full-group page and, as ``wpage``, its window-group
+page, with the tree's own reference on each, so a new session can adopt a
+prefix at ANY page boundary — the full group's pages whole and the window
+group's for the last window (SessionStore.match_prefix). When the window
+group runs out of pages the tree lets go of window pages nobody else reads,
+least recently matched first (``strip_window``): the node stays, with its
+full-group page, and a match ends at the longest boundary whose last window
+is still cached (``_walk``) — the hot shared prompt is touched at every
+match and keeps its pages, a finished conversation's blocks lose theirs
+first. A session holding a node's window page holds its full-group page
+too, so leaf eviction's rule (no reference but the tree's on the full-group
+page) covers both.
+
 Locking: all mutating/inspecting methods assume the owning SessionStore's
 RLock is held (the store re-enters it freely); the store's public wrappers
 (`match_prefix`, `insert_prefix`, `alloc`) take it.
@@ -57,11 +72,13 @@ class _Node:
     """One cached page: edge label ``block`` (page-length token tuple,
     relative to the parent path), pool page id, LRU stamp."""
 
-    __slots__ = ("block", "page", "children", "parent", "last_used")
+    __slots__ = ("block", "page", "wpage", "children", "parent",
+                 "last_used")
 
     def __init__(self, block: tuple, page: int, parent: "Optional[_Node]"):
         self.block = block
         self.page = page
+        self.wpage = 0      # its page in the store's window group, if any
         self.children: dict[tuple, _Node] = {}
         self.parent = parent
         self.last_used = time.monotonic()
@@ -75,6 +92,7 @@ class RadixPrefixCache:
         self.page = store.page
         self._root = _Node((), 0, None)      # sentinel; page 0 is scratch
         self._pages: dict[int, _Node] = {}   # page id -> its node
+        self._wpages: dict[int, _Node] = {}  # window-group page id -> node
         # counters (monotonic; exposed via stats() -> web API + bench)
         self.hits = 0
         self.misses = 0
@@ -83,6 +101,7 @@ class RadixPrefixCache:
         self.inserted_pages = 0
         self.evicted_pages = 0
         self.cow_copies = 0
+        self.stripped_window_pages = 0
 
     # -- lookup ------------------------------------------------------------
 
@@ -101,6 +120,13 @@ class RadixPrefixCache:
                 break
             path.append(child)
             node = child
+        w = self.store.window
+        if w is not None:
+            # a match can end only where the window group's pages that a
+            # query behind it reaches are all cached
+            while path and not all(n.wpage for n in path[
+                    w.first_page(len(path) * page, page):]):
+                path.pop()
         return path
 
     def match(self, tokens: Sequence[int],
@@ -127,12 +153,16 @@ class RadixPrefixCache:
 
     # -- insert ------------------------------------------------------------
 
-    def insert(self, tokens: Sequence[int], pages: Sequence[int]) -> int:
+    def insert(self, tokens: Sequence[int], pages: Sequence[int],
+               wpages: Optional[Sequence[int]] = None) -> int:
         """Record a prefilled prefix: every FULL page of ``tokens`` whose
         block is not yet cached gets a node holding ``pages[j]`` and a tree
         reference on it. Blocks already cached keep their existing node
         (dedupe — the caller's duplicate page stays the session's own).
-        Returns the number of new nodes."""
+        ``wpages`` (a store with a window group): the caller's window-group
+        pages for the same blocks, 0 where it holds none; a node that has
+        no window page — new, or stripped since — takes the caller's, with
+        a tree reference of its own. Returns the number of new nodes."""
         page = self.page
         node = self._root
         added = 0
@@ -151,6 +181,11 @@ class RadixPrefixCache:
                 # the tree's own reference: absent refcount key == 1
                 self.store._refs[pg] = self.store._refs.get(pg, 1) + 1
                 added += 1
+            wp = wpages[j] if wpages is not None and j < len(wpages) else 0
+            if wp and not child.wpage and wp not in self._wpages:
+                child.wpage = wp
+                self._wpages[wp] = child
+                self.store.window.acquire([wp])
             child.last_used = time.monotonic()
             node = child
         self.inserted_pages += added
@@ -172,7 +207,9 @@ class RadixPrefixCache:
             stack.extend(node.children.values())
             if node is self._root or node.children:
                 continue
-            if self.store._refs.get(node.page, 1) != 1:
+            if self.store._refs.get(node.page, 1) != 1 or (
+                    node.wpage
+                    and self.store.window._refs.get(node.wpage, 1) != 1):
                 continue       # a session/adopter still reads it
             if best is None or node.last_used < best.last_used:
                 best = node
@@ -181,6 +218,37 @@ class RadixPrefixCache:
     def _remove(self, node: _Node) -> None:
         del node.parent.children[node.block]
         self._pages.pop(node.page, None)
+        self._drop_window(node)
+
+    def _drop_window(self, node: _Node) -> int:
+        """Give up the tree's reference on a node's window-group page;
+        returns 1 where that freed it."""
+        if not node.wpage:
+            return 0
+        wp, node.wpage = node.wpage, 0
+        del self._wpages[wp]
+        return self.store.window.release([wp])
+
+    def strip_window(self, n: int) -> int:
+        """Free up to ``n`` WINDOW-group pages that only the tree still
+        references, least recently matched nodes first; the nodes stay,
+        with their full-group pages (module docstring). Returns the pages
+        freed."""
+        refs = self.store.window._refs
+        idle = sorted((node for wp, node in self._wpages.items()
+                       if refs.get(wp, 1) == 1),
+                      key=lambda node: node.last_used)[:n]
+        freed = sum(self._drop_window(node) for node in idle)
+        self.stripped_window_pages += freed
+        return freed
+
+    def window_pages(self) -> set:
+        """Every window-group page the tree holds a reference on."""
+        return set(self._wpages)
+
+    def window_pages_of(self, pages: Sequence[int]) -> list[int]:
+        """The window-group pages of the nodes that hold ``pages``."""
+        return [self._pages[p].wpage for p in pages]
 
     def _node_tokens(self, node: _Node) -> list:
         """The full token prefix a node's page caches (root-path blocks
@@ -223,6 +291,7 @@ class RadixPrefixCache:
             node = stack.pop()
             stack.extend(node.children.values())
             self.store._release([node.page])
+            self._drop_window(node)
             dropped += 1
         self._root.children.clear()
         self._pages.clear()
@@ -272,6 +341,10 @@ class RadixPrefixCache:
             "evicted_pages": self.evicted_pages,
             "cow_copies": self.cow_copies,
             "cached_pages": len(self._pages),
+            # a store with a window group: that group's pages held, and
+            # those given back under its pressure
+            "cached_window_pages": len(self._wpages),
+            "stripped_window_pages": self.stripped_window_pages,
         }
 
     def occupancy(self) -> dict:

@@ -40,6 +40,7 @@ pages into the dense cache ``forward_hidden`` attends over.
 
 from __future__ import annotations
 
+import contextlib
 import functools
 import math
 from typing import NamedTuple, Optional
@@ -260,14 +261,17 @@ def _init_params_stacks(cfg: ModelConfig, k_embed, k_layers, k_head,
 # dense feed-forward or experts), in the order that numbers their keys
 # (``_init_params_pattern``): (name, shape, fan-in).
 def _pattern_leaves(cfg: ModelConfig, mixer: str, ff: str) -> list:
-    D, H, KV, HD = cfg.dim, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+    D, KV, HD = cfg.dim, cfg.n_kv_heads, cfg.head_dim
     if mixer == "conv":
         K = cfg.conv_cache
         leaves = [("w_in", (D, 3 * D), D), ("w_conv", (K, D), K),
                   ("w_out", (D, D), D)]
     else:
+        H = cfg.attn_kind(mixer).n_heads
         leaves = [("wq", (D, H * HD), D), ("wk", (D, KV * HD), D),
                   ("wv", (D, KV * HD), D), ("wo", (H * HD, D), H * HD)]
+        if cfg.attn_gate:
+            leaves += [("wg", (D, H), D)]
     if ff == "dense":
         F = cfg.ffn_dim
         return leaves + [("w_gate", (D, F), D), ("w_up", (D, F), D),
@@ -314,7 +318,7 @@ def _init_params_pattern(cfg: ModelConfig, k_embed, k_layers, k_head,
             kq = jax.random.fold_in(jax.random.fold_in(k_layers, s), q)
             leaves = {"attn_norm": jnp.ones((n, D), dtype),
                       "mlp_norm": jnp.ones((n, D), dtype)}
-            if mixer == "attention" and cfg.qk_norm:
+            if mixer != "conv" and cfg.qk_norm:
                 leaves["q_norm"] = jnp.ones((n, cfg.head_dim), dtype)
                 leaves["k_norm"] = jnp.ones((n, cfg.head_dim), dtype)
             for i, (leaf, shape, fan_in) in enumerate(
@@ -399,8 +403,16 @@ def attn_softmax_scale(cfg: ModelConfig) -> float:
 
 
 def rope(x: jax.Array, positions: jax.Array, theta: float,
-         scaling: Optional[tuple] = None) -> jax.Array:
-    """Rotary embedding. x: [B, T, heads, hd]; positions: [B, T]."""
+         scaling: Optional[tuple] = None,
+         rotary_dim: Optional[int] = None) -> jax.Array:
+    """Rotary embedding. x: [B, T, heads, hd]; positions: [B, T]. With
+    ``rotary_dim`` below hd only each head's first ``rotary_dim`` values
+    rotate (pairs (i, i + rotary_dim/2), frequencies and a YaRN blend
+    reckoned over ``rotary_dim``); the rest pass through."""
+    if rotary_dim is not None and rotary_dim < x.shape[-1]:
+        return jnp.concatenate(
+            [rope(x[..., :rotary_dim], positions, theta, scaling),
+             x[..., rotary_dim:]], axis=-1)
     hd = x.shape[-1]
     half = hd // 2
     freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
@@ -478,12 +490,16 @@ def _mlp(x: jax.Array, p: dict, cfg: ModelConfig) -> jax.Array:
 
 
 def _qkv(x: jax.Array, p: dict, cfg: ModelConfig, B: int, T: int,
-         positions: jax.Array) -> tuple[jax.Array, jax.Array, jax.Array]:
+         positions: jax.Array, kind=None
+         ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """The shared attention-input block: rmsnorm → q/k/v projections
     (+ optional bias) reshaped to head layout, weights dequantized on
     the fly when quantized, with ``cfg.qk_norm`` an RMSNorm over each
     head's values of q and k (scope ``qkv`` ⊃ ``qk_norm``), then rotary
-    embedding of q and k at ``positions`` (scope ``rope``)."""
+    embedding of q and k at ``positions`` (scope ``rope``). ``kind``
+    (config.AttnKind; None: the model's one kind) gives the layer's query
+    heads and rotary."""
+    kind = kind or cfg.attn_kind()
     with jax.named_scope("qkv"):
         h = rmsnorm(x, p["attn_norm"], cfg.norm_eps, cfg.rmsnorm_plus_one)
         q = jnp.einsum("btd,dh->bth", h, dequant_weight(p["wq"], h.dtype))
@@ -491,7 +507,7 @@ def _qkv(x: jax.Array, p: dict, cfg: ModelConfig, B: int, T: int,
         v = jnp.einsum("btd,dh->bth", h, dequant_weight(p["wv"], h.dtype))
         if cfg.attn_bias:               # Qwen2-style QKV biases
             q, k, v = q + p["bq"], k + p["bk"], v + p["bv"]
-        q = q.reshape(B, T, cfg.n_heads, cfg.head_dim)
+        q = q.reshape(B, T, kind.n_heads, cfg.head_dim)
         k = k.reshape(B, T, cfg.n_kv_heads, cfg.head_dim)
         v = v.reshape(B, T, cfg.n_kv_heads, cfg.head_dim)
         if cfg.qk_norm:
@@ -501,17 +517,35 @@ def _qkv(x: jax.Array, p: dict, cfg: ModelConfig, B: int, T: int,
                 k = rmsnorm(k, p["k_norm"], cfg.norm_eps,
                             cfg.rmsnorm_plus_one)
     with jax.named_scope("rope"):
-        q = rope(q, positions, cfg.rope_theta, cfg.rope_scaling)
-        k = rope(k, positions, cfg.rope_theta, cfg.rope_scaling)
+        q = rope(q, positions, kind.rope_theta, kind.rope_scaling,
+                 kind.rotary_dim)
+        k = rope(k, positions, kind.rope_theta, kind.rope_scaling,
+                 kind.rotary_dim)
     return q, k, v
+
+
+@jax.named_scope("attn_gate")
+def _attn_gate(x: jax.Array, attn: jax.Array, p: dict,
+               cfg: ModelConfig) -> jax.Array:
+    """``cfg.attn_gate``: head n's output times sigmoid((norm(x) W_g)_n),
+    the layer's normed input through ``wg`` [dim, heads], in float32.
+    attn: [1, T, H, hd] float32."""
+    h = rmsnorm(x, p["attn_norm"], cfg.norm_eps, cfg.rmsnorm_plus_one)
+    g = jnp.einsum("btd,dh->bth", h, p["wg"],
+                   preferred_element_type=jnp.float32)
+    return attn * jax.nn.sigmoid(g)[..., None]
 
 
 @jax.named_scope("attn_out")
 def _attn_out(x: jax.Array, attn: jax.Array, p: dict,
               cfg: ModelConfig) -> jax.Array:
-    """Residual + output projection of the attended heads."""
+    """Residual + output projection of the attended heads (as many as
+    ``attn`` has: a layer's kind decides), each times its gate first where
+    the model has one (scope ``attn_out`` ⊃ ``attn_gate``)."""
+    if cfg.attn_gate:
+        attn = _attn_gate(x, attn, p, cfg).astype(x.dtype)
     wo = dequant_weight(p["wo"], x.dtype).reshape(
-        cfg.n_heads, cfg.head_dim, cfg.dim)
+        attn.shape[2], cfg.head_dim, cfg.dim)
     return x + jnp.einsum("bthd,hdD->btD", attn, wo)
 
 
@@ -688,6 +722,11 @@ def _gated(h: jax.Array, wg: jax.Array, wu: jax.Array, wd: jax.Array,
 # Rows of one block of the grouped matmul: a block holds assignments to ONE
 # expert (an expert's assignments are padded up to whole blocks).
 MOE_BLOCK = 256
+# Tokens of a tick that go through the grouped experts at a time: the layout
+# holds a row for EVERY assignment (any routing is exact), 10 a token at
+# 3,072 wide twice over (in and out) — 3 GiB at a 16,384-token tick whole,
+# under 1 GiB at 4,096, the longest tick any accepted cell has.
+MOE_TICK = 4096
 
 
 def _routed_experts(h: jax.Array, idx: jax.Array, gates: jax.Array,
@@ -825,7 +864,20 @@ def _moe(x: jax.Array, p: dict, experts: tuple, layer, cfg: ModelConfig,
                          precision=jax.lax.Precision.HIGHEST)
         idx, gates = moe_select(logits, m, p.get("router_bias"))
     with jax.named_scope("routed_experts"):
-        if grouped:
+        T = h.shape[0]
+        if grouped and T > MOE_TICK and T % MOE_TICK == 0:
+            # a long tick's tokens MOE_TICK at a time: the grouped layout
+            # is sized for every assignment falling on a held expert
+            def part(c):
+                return _routed_experts_grouped(
+                    *c[:3], experts, layer, m, cfg.activation, c[3],
+                    interpret)
+            routed, stats = jax.lax.map(part, tuple(
+                a.reshape(T // MOE_TICK, MOE_TICK, *a.shape[1:])
+                for a in (h, idx, gates, valid)))
+            routed = routed.reshape(T, -1)
+            stats = jnp.stack([stats[:, 0].sum(0), stats[:, 1].max(0)])
+        elif grouped:
             routed, stats = _routed_experts_grouped(
                 h, idx, gates, experts, layer, m, cfg.activation, valid,
                 interpret)
@@ -1294,12 +1346,24 @@ def _forward_hidden_ragged_pattern(params, cfg, tokens, positions, k_pool,
                                    tq, interpret, tiles, tile, shared,
                                    conv) -> tuple:
     """``forward_hidden_ragged`` for a model whose layers differ in KIND
-    (``cfg.layer_plan``): per-head attention or a gated short convolution,
-    a dense feed-forward or routed experts. The same contract and the
-    same in-place pools, with two differences. The K/V pools hold the
-    ATTENTION layers only, ``[n_attn_layers, n_pages, page, KV·hd]``,
-    indexed by a layer's place among them. And the conv layers' state
-    lies beside them (``conv``, a ``ConvTick``): every conv layer's
+    (``cfg.layer_plan``): per-head attention (of one kind or of several,
+    ``cfg.attn_kinds``) or a gated short convolution, a dense feed-forward
+    or routed experts. The same contract and the same in-place pools, with
+    these differences. The K/V pools hold the ATTENTION layers only,
+    ``[n_attn_layers, n_pages, page, KV·hd]``, indexed by a layer's place
+    among them. A model whose attention layers fall into several retention
+    GROUPS (``cfg.kv_groups``: window and full layers mixed) hands in a
+    TUPLE, one member a group, of each of ``k_pool``, ``v_pool``,
+    ``row_tables`` and ``flat_dst`` — a group has its own pools
+    ``[layers of the group, its n_pages, page, KV·hd]``, its own page ids
+    and so its own tables and slots, all over the same positions (a window
+    group's table holds page 0 where the session let a page behind the
+    window go: the kernel's walk starts at the first page the window
+    reaches and never reads those entries) — and gets the tuples of pools
+    back; a layer reads and writes its group's, at its place among the
+    group's layers, with its kind's window; ``shared`` is the full group's
+    walk (a window's first page differs by row). And the conv layers'
+    state lies beside them (``conv``, a ``ConvTick``): every conv layer's
     records are read from its pool once, before the layers, the layers'
     new records ride the scans in a buffer of the tick's size, and one
     write behind the layers puts them under their pages, in place (scopes
@@ -1308,18 +1372,27 @@ def _forward_hidden_ragged_pattern(params, cfg, tokens, positions, k_pool,
     layers unrolled in the body: the leading dense layers once, the period
     as often as it fits, the rest of a last period once — so program size
     follows the period, not the depth. The routed experts' weights stay
-    out of the scanned slices. Returns the dense function's tuple with the
-    expert layers' int32 [4] (None without experts) and, seventh, the
-    state pool (None without conv layers)."""
+    out of the scanned slices. A layer's scopes are the dense forward's;
+    where the model names kinds of attention, a layer's are inside one
+    named for its kind (``full_attention`` ⊃ ``qkv`` …). Returns the dense
+    function's tuple with the expert layers' int32 [4] (None without
+    experts) and, seventh, the state pool (None without conv layers)."""
     from quoracle_tpu.ops.paged_attention import _on_tpu, ragged_attend_auto
     # the experts' blocks in one kernel a layer on the TPU (interpreted
     # where a test asks for the kernels), the loop over blocks elsewhere
     grouped = bool(interpret) or _on_tpu()
-    A, n_pages, page, lanes = k_pool.shape
-    n_tok = n_pages * page
+    n_groups = len(cfg.kv_groups)
+    multi = n_groups > 1
+    if not multi:
+        k_pool, v_pool, row_tables, flat_dst = (
+            (k_pool,), (v_pool,), (row_tables,), (flat_dst,))
+    n_pages, page, lanes = k_pool[0].shape[1:]
     Tp = tokens.shape[1]
     x = _embed(params, cfg, tokens)
-    keep = flat_dst < n_tok
+    # a group's sentinel is ITS pool's end; a token dropped in one group
+    # (padding, a row that is done) is dropped in every group
+    keeps = tuple(d < kp.shape[1] * page for d, kp in zip(flat_dst, k_pool))
+    keep = keeps[0]
     n_conv = cfg.n_conv_layers
     assert (conv is not None) == (n_conv > 0)
     prev = recs = None
@@ -1332,10 +1405,13 @@ def _forward_hidden_ragged_pattern(params, cfg, tokens, positions, k_pool,
         recs = jnp.zeros((n_conv, conv.rec_dst.shape[0],
                           conv.pool.shape[1]), conv.pool.dtype)
 
-    def attention(x, kp, vp, p, a):
-        q, k, v = _qkv(x, p, cfg, 1, Tp, positions)
+    def attention(x, kp, vp, p, a, kind, g):
+        """One attention layer of ``kind``, the ``a``-th of group ``g``:
+        ``kp``/``vp`` that group's pools."""
+        A, n_tok = kp.shape[0], kp.shape[1] * page
+        q, k, v = _qkv(x, p, cfg, 1, Tp, positions, kind)
         with jax.named_scope("kv_write"):
-            dst = jnp.where(keep, a * n_tok + flat_dst, A * n_tok)
+            dst = jnp.where(keeps[g], a * n_tok + flat_dst[g], A * n_tok)
             kp = kp.reshape(A * n_tok, lanes).at[dst].set(
                 k[0].reshape(Tp, lanes).astype(kp.dtype),
                 mode="drop").reshape(kp.shape)
@@ -1344,16 +1420,22 @@ def _forward_hidden_ragged_pattern(params, cfg, tokens, positions, k_pool,
                 mode="drop").reshape(vp.shape)
         with jax.named_scope("attn"):
             attn = ragged_attend_auto(
-                q[0], kp, vp, row_tables, block_meta, a, tq=tq,
-                interpret=interpret, tiles=tiles, tile=tile,
-                shared=shared)[None]
-        return _attn_out(x, attn.astype(x.dtype), p, cfg), kp, vp
+                q[0], kp, vp, row_tables[g], block_meta, a, tq=tq,
+                sliding_window=kind.window, interpret=interpret,
+                tiles=tiles, tile=tile,
+                shared=shared if kind.window is None else None)[None]
+        if not cfg.attn_gate:
+            attn = attn.astype(x.dtype)     # gated in float32, then cast
+        return _attn_out(x, attn, p, cfg), kp, vp
 
     def segment(carry, stacked, kinds, n, a0, c0):
-        """``n`` repeats of ``kinds``, whose first attention layer is the
-        ``a0``-th of the model's and first conv layer the ``c0``-th."""
-        a_per = sum(m == "attention" for m, _ in kinds)
-        c_per = len(kinds) - a_per
+        """``n`` repeats of ``kinds``, whose first attention layer of
+        group g is the ``a0[g]``-th of that group's and first conv layer
+        the ``c0``-th of the model's."""
+        group = [None if m == "conv" else cfg.kv_group_of(m)
+                 for m, _ in kinds]
+        a_per = [group.count(g) for g in range(n_groups)]
+        c_per = group.count(None)
         experts = [tuple(p[k] for k in ("we_gate", "we_up", "we_down"))
                    if ff == "experts" else None
                    for p, (_, ff) in zip(stacked, kinds)]
@@ -1361,13 +1443,19 @@ def _forward_hidden_ragged_pattern(params, cfg, tokens, positions, k_pool,
                      for p in stacked)
 
         def body(carry, scanned):
-            x, kp, vp, recs, stats = carry
+            x, kps, vps, recs, stats = carry
             ps, rep = scanned
-            a, c = a0 + rep * a_per, c0 + rep * c_per
-            for p, w, (mixer, ff) in zip(ps, experts, kinds):
-                if mixer == "attention":
-                    x, kp, vp = attention(x, kp, vp, p, a)
-                    a = a + 1
+            a = [a0[g] + rep * a_per[g] for g in range(n_groups)]
+            c = c0 + rep * c_per
+            kps, vps = list(kps), list(vps)
+            for p, w, (mixer, ff), g in zip(ps, experts, kinds, group):
+                if g is not None:
+                    with jax.named_scope(mixer) if cfg.attn_kinds \
+                            else contextlib.nullcontext():
+                        x, kps[g], vps[g] = attention(
+                            x, kps[g], vps[g], p, a[g],
+                            cfg.attn_kind(mixer), g)
+                    a[g] = a[g] + 1
                 else:
                     x, last = _short_conv(x, p, cfg, prev[c], conv)
                     recs = jax.lax.dynamic_update_index_in_dim(
@@ -1379,22 +1467,25 @@ def _forward_hidden_ragged_pattern(params, cfg, tokens, positions, k_pool,
                     x, st = _moe(x, p, w, rep, cfg, keep, grouped,
                                  bool(interpret))
                     stats = stats + st
-            return (x, kp, vp, recs, stats), None
+            return (x, tuple(kps), tuple(vps), recs, stats), None
 
         carry, _ = jax.lax.scan(body, carry,
                                 (rest, jnp.arange(n, dtype=jnp.int32)))
-        return carry, a0 + n * a_per, c0 + n * c_per
+        return carry, [a0[g] + n * a_per[g] for g in range(n_groups)], \
+            c0 + n * c_per
 
     stats = None
     if cfg.moe is not None:
         stats = jnp.zeros((2, cfg.moe.n_held) if grouped else (4,), jnp.int32)
-    carry = (x, k_pool, v_pool, recs, stats)
-    a0 = c0 = 0
+    carry = (x, tuple(k_pool), tuple(v_pool), recs, stats)
+    a0, c0 = [0] * n_groups, 0
     with jax.named_scope("layers"):
         for stacked, (kinds, n) in zip(params["segments"], cfg.layer_plan):
             if n:
                 carry, a0, c0 = segment(carry, stacked, kinds, n, a0, c0)
     x, k_pool, v_pool, recs, stats = carry
+    if not multi:
+        k_pool, v_pool = k_pool[0], v_pool[0]
     state = None
     if conv is not None:
         with jax.named_scope("conv"), jax.named_scope("state_write"):

@@ -265,6 +265,16 @@ def pool_sizing(pool: Sequence[str], n_devices: int = 8,
         else:
             kv_tok = cfg.kv_bytes_per_token(tp, dtype_bytes)
         resident = int(page_pool // kv_tok) if page_pool > 0 else 0
+        window_resident = 0
+        if len(cfg.kv_groups) > 1 and not quantize_kv:
+            # window and full attention layers mixed: the engine gives
+            # each retention group half of the pool at the group's own
+            # rate (generate.py GenerateEngine.__init__)
+            kv_tok = cfg.kv_bytes_per_token(tp, dtype_bytes, group=0)
+            resident, window_resident = (
+                int(max(0.0, page_pool) / 2
+                    // cfg.kv_bytes_per_token(tp, dtype_bytes, group=g))
+                for g in (0, 1))
         m_fits = page_pool > 0
         fits = fits and m_fits
         used += tp
@@ -288,6 +298,9 @@ def pool_sizing(pool: Sequence[str], n_devices: int = 8,
             "weights_dtype": "int8" if quantize_weights else "bf16",
             "kv_dtype": "int8+scales" if quantize_kv else "bf16",
             "resident_kv_tokens": resident,
+            # tokens a WINDOW group's pools hold (0: the model has none;
+            # the figure above is then the full group's)
+            "resident_window_kv_tokens": window_resident,
             "tiers": {
                 "hbm_pages": resident // page,
                 "hbm_tokens": resident,

@@ -1196,6 +1196,152 @@ def test_hybrid_programs_at_lfm2_widths(on_v5e, monkeypatch):
                 < 13 * 2 ** 30)
 
 
+# --- window and full attention layers mixed (ISSUE 39) ----------------------
+
+@pytest.mark.parametrize("walk", ["tq8-blocks", "tq8-tiles", "tq1-decode"])
+@pytest.mark.parametrize("h,window", [(48, None), (72, 512)],
+                         ids=["full-g6", "sliding-g9"])
+def test_ragged_kernels_compile_at_groups_of_six_and_nine(on_v5e, h, window,
+                                                          walk):
+    """Laguna's two kinds of attention layer: 6 and 9 query heads to a kv
+    head, neither a multiple of the 8-row sublane tile every accepted
+    configuration's group is (4, 8) or divides (2) — the block kernel at a
+    16,384-token tick, the tile kernel with the tile 72 heads leave room
+    for (32 tokens: 288 and 192 score rows a kv head), and the decode call
+    (one query a row: 6 and 9 score rows a kv head), with the shared walk
+    in the full layers and the window's first page in the sliding ones."""
+    S = on_v5e
+    tq = 1 if walk == "tq1-decode" else 8
+    tb = 8 if tq == 1 else 16384
+    pool = S((LAYERS, N_PAGES, PAGE, 8 * HD), jnp.bfloat16)
+    args = [S((tb, h, HD), jnp.bfloat16), pool, pool,
+            S((8, 128), jnp.int32), S((4, tb // tq), jnp.int32),
+            S((), jnp.int32)]
+    kw = {}
+    tile = pa.ragged_tile(72, HD, 8)
+    assert tile == 32
+    if walk == "tq8-tiles":
+        args.append(S((6, pa.ragged_tile_slots(tb // tq, 8, tq, tile)),
+                      jnp.int32))
+        kw = dict(tile=tile)
+    elif tq == 1 and window is None:
+        args.append(S((2 + pa.SHARED_ROWS, 8), jnp.int32))
+
+    def fn(q, k, v, tables, meta, layer, plan=None):
+        name = "tiles" if walk == "tq8-tiles" else "shared"
+        return pa.ragged_attend(q, k, v, tables, meta, layer, tq=tq,
+                                sliding_window=window, **{name: plan}, **kw)
+    compiles(fn, *args)
+
+
+def _window_programs(S, monkeypatch, cfg, tb=256, width=8, rows=8,
+                     max_seq=16384):
+    """``_hybrid_programs`` for a model with a window group beside the
+    full one: pools, tables and write slots are PAIRS."""
+    from quoracle_tpu.models.generate import GenerateEngine, RAGGED_TQ
+    from quoracle_tpu.models.tokenizer import ByteTokenizer
+    from quoracle_tpu.models.transformer import init_params
+    monkeypatch.setattr(pa, "_on_tpu", lambda: True)
+    params = jax.tree.map(
+        lambda x: S(x.shape, x.dtype),
+        jax.eval_shape(lambda k: init_params(cfg, k), jax.random.PRNGKey(0)))
+    eng = GenerateEngine(cfg, params, ByteTokenizer(), max_seq=max_seq)
+    st = eng.sessions
+    pools = tuple(S((layers, n, st.page, cfg.kv_pools[0]), eng.pool_dtype)
+                  for (_, layers), n in zip(
+                      cfg.kv_groups, (st.n_pages, st.window.n_pages)))
+    R, i32, f32 = rows, jnp.int32, jnp.float32
+    slots = pa.ragged_tile_slots(tb // RAGGED_TQ, R, RAGGED_TQ,
+                                 eng._ragged_tile)
+    chunk = eng._step_paged_ragged.lower(
+        params, pools, pools, None, None, S((tb,), i32), S((tb,), i32),
+        (S((R, width), i32),) * 2, S((4, tb // RAGGED_TQ), i32),
+        S((6, slots), i32), (S((tb,), i32),) * 2, S((R,), i32), None, None,
+        tq=RAGGED_TQ, tile=eng._ragged_tile).compile()
+    decode = eng._step_paged_decode_ragged.lower(
+        params, pools, pools, None, None, (S((R, width), i32),) * 2,
+        S((2 + pa.SHARED_ROWS, R), i32), S((R,), i32), S((R,), i32),
+        S((R, cfg.vocab_size), f32), S((2,), jnp.uint32), S((R,), f32),
+        S((R,), f32), S((R,), jnp.bool_), S((R,), i32), None, None, None,
+        max_new=32).compile()
+    return [(c.as_text(), c.memory_analysis()) for c in (chunk, decode)], st
+
+
+def _narrow_window_moe(periods):
+    """Laguna's layer pattern, head geometry (8 kv heads of 128, 6 and 9
+    query heads to each) and window under narrow weights."""
+    from quoracle_tpu.models.config import AttnKind, ModelConfig, MoEConfig
+    return ModelConfig(
+        name=f"narrow-window-moe-{periods}", vocab_size=512, dim=256,
+        n_layers=1 + 4 * periods, n_heads=48, n_kv_heads=8, head_dim=128,
+        ffn_dim=512, norm_eps=1e-6,
+        layer_types=("full_attention",) + (
+            ("sliding_attention",) * 3 + ("full_attention",)) * periods,
+        attn_kinds=(("full_attention", AttnKind(
+            48, None, 500000.0, ("yarn", 128.0, 32.0, 1.0, 8192, 1.0, 0.0),
+            64)), ("sliding_attention", AttnKind(72, 512, 10000.0))),
+        attn_gate=True,
+        moe=MoEConfig(n_routed=64, n_held=8, per_token=10, expert_dim=128,
+                      n_shared=1, routed_scale=2.5, first_dense=1),
+        context_window=16384)
+
+
+def test_window_programs_carry_both_groups_in_place(on_v5e, monkeypatch):
+    """A model with a window group beside the full one on the v5e: both
+    programs carry BOTH groups' K/V pools through the segment scans — and
+    the decode loop — in place, donated into their outputs: an attention
+    kernel a layer of the period (three sliding, one full) and the leading
+    layer's, a grouped-experts kernel an expert layer, nothing that moves a
+    layer of any pool. The scan over whole periods holds: two periods and
+    three give the same kernels and fusions."""
+    counts = []
+    for periods in (2, 3):
+        cfg = _narrow_window_moe(periods)
+        assert cfg.kv_groups == ((None, 1 + periods), (512, 3 * periods))
+        programs, st = _window_programs(on_v5e, monkeypatch, cfg)
+        elems = [n * st.page * 1024 for n in (st.n_pages, st.window.n_pages)]
+        assert elems[0] != elems[1]
+        for hlo, mem in programs:
+            calls = [ln for ln in hlo.splitlines()
+                     if "tpu_custom_call" in ln]
+            assert sum("%ragged_attend" in c for c in calls) == 5
+            assert sum("%routed_experts_ffn" in c for c in calls) == 4
+            assert len(calls) == 9 and "kv_layout" not in hlo
+            for e in elems:
+                assert pool_moves(hlo, e) == []
+            assert mem.alias_size_in_bytes >= 2 * 2 * (
+                (1 + periods) * elems[0] + 3 * periods * elems[1])
+        counts.append([len(re.findall(r" fusion\(", hlo))
+                       for hlo, _ in programs])
+    assert counts[0] == counts[1]
+
+
+@pytest.mark.slow
+def test_window_programs_at_laguna_widths(on_v5e, monkeypatch):
+    """The benchmark's `laguna-s-2.1-ep8-l13` at its published widths (`-m
+    slow`: a minute; run by hand before chip time): the chunk forward at
+    the first agent's 16,384-token tick and the decode program compile,
+    both groups' pools in place, no layer's weight copied through HBM in
+    the decode loop, and arguments plus temporaries under 13 GiB of the
+    chip's 16 (a long tick's experts go MOE_TICK tokens at a time)."""
+    from benchmark import configs
+    from benchmark.families import window_moe
+    cfg = get_model_config(window_moe.register(
+        configs.load_config("laguna-s-2.1-ep8-l13")))
+    programs, st = _window_programs(on_v5e, monkeypatch, cfg, tb=16384,
+                                    width=128, max_seq=131072)
+    assert (st.n_pages, st.window.n_pages) == (513, 229)
+    for hlo, mem in programs:
+        assert hlo.count("tpu_custom_call") == 9
+        for n in (513, 229):
+            assert pool_moves(hlo, n * st.page * 1024) == []
+        assert mem.alias_size_in_bytes >= 2 * st.page * 1024 * 2 * (
+            4 * 513 + 9 * 229)
+        assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+                < 13 * 2 ** 30)
+    assert weight_moves(programs[1][0], 1 << 20) == []
+
+
 # --- tp wrappers: shard_map around a pallas_call ----------------------------
 
 

@@ -347,11 +347,12 @@ def start_trace(trace_dir) -> None:
 
 @pytest.fixture(scope="module")
 def reached(tmp_path_factory):
-    """What three kinds of tick open: {operation: ns} summed over (a) the
+    """What four kinds of tick open: {operation: ns} summed over (a) the
     ticks of a continuous backend that serves two sessions with one system
     prompt, under a profiler session (the radix cache, the batcher's own
     operations); (b) a tick of an engine with conv state; (c) a tick that
-    restores a hibernated session. With (a)'s trace and span events."""
+    restores a hibernated session; (d) a tick of an engine with a window
+    group of attention layers. With (a)'s trace and span events."""
     env = pytest.MonkeyPatch()
     env.setenv("QUORACLE_TRACE_DECODE_SAMPLE", "1")
     introspect.enable()
@@ -394,6 +395,14 @@ def reached(tmp_path_factory):
     conv = GenerateEngine(cfg, f32(params), ByteTokenizer(), max_seq=1024,
                           prompt_buckets=(32, 64, 128, 256, 512))
     one_tick(conv, list(range(3, 40)), "c")
+
+    # (d) a tick of a model with a window group beside the full one
+    from tests import test_window_moe as wm
+    cfg, params, _ = wm.model(wm.RAW)
+    mixed = GenerateEngine(cfg, wm.f32(params), ByteTokenizer(),
+                           max_seq=1024,
+                           prompt_buckets=(32, 64, 128, 256, 512))
+    one_tick(mixed, list(range(3, 40)), "w")
 
     tiny = get_model_config(MEMBER)
     eng = GenerateEngine(
