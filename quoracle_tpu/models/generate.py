@@ -1448,21 +1448,22 @@ class GenerateEngine:
         # chunk forward's attention (ops/paged_attention, the tile kernel);
         # 0: a latent pool's kernel walks a block at a time, no tile table
         from quoracle_tpu.ops.paged_attention import (
-            decode_walk_pages, ragged_tile,
+            decode_walk_pages, latent_walk_pages, ragged_tile,
         )
         # (kinds of attention layer share the one tile table: the widest's)
         self._ragged_tile = ragged_tile(
             cfg.max_heads, cfg.head_dim, RAGGED_TQ) if cfg.latent is None \
             else 0
-        # pages a loop iteration of the block kernel's walks carries, as
-        # the kernel reckons it from a page's bytes on one shard (the
-        # decode program's call; 1: a latent pool's kernel walks page by
-        # page). Only the tick span's ``attn_walk_steps`` reads it.
+        # pages a loop iteration of the decode program's walks carries, as
+        # the kernel reckons it: from a page's bytes on one shard, or for a
+        # latent pool from the keys a block scores at once (its chunk
+        # forward walks page by page, as the tile kernel does). Only the
+        # tick span's ``attn_walk_steps`` reads it.
         self._walk_block = decode_walk_pages(
             self.sessions.page,
             cfg.n_kv_heads // (int(mesh.shape["tp"]) if ragged_shard else 1),
             cfg.head_dim, jnp.dtype(self.pool_dtype).itemsize) \
-            if cfg.latent is None else 1
+            if cfg.latent is None else latent_walk_pages(self.sessions.page)
 
         @functools.partial(jax.jit, static_argnames=())
         def step_paged_prefill(params, k_pool, v_pool, k_scale, v_scale,
@@ -3409,10 +3410,10 @@ class GenerateEngine:
                 walked = ragged_tiles(bmeta, TQ, tile,
                                       ragged_tile_slots(NB, R, TQ, tile))
         # ... and the decode steps' one-token rows: which of them have
-        # leading pages in common, read once a step for all of them
-        # (a latent pool's kernel has no such walk)
+        # leading pages in common, read once a step for all of them (the
+        # dense kernel's walk and a latent pool's alike)
         shared = None
-        if self.cfg.latent is None and verify is None:
+        if verify is None:
             with tick_op("shared_walks"):
                 shared = shared_walks(r_tables, r_pool_lens, page,
                                       self.cfg.sliding_window)
@@ -3592,7 +3593,8 @@ class GenerateEngine:
         # tiles), so streamed / page / walk_steps is how full they ran
         block = self._walk_block
         walk_steps = ragged_walk_steps(
-            walked, page, 1 if self._ragged_tile else block, window) \
+            walked, page, 1 if self._ragged_tile or self.cfg.latent
+            else block, window) \
             + ragged_walk_steps(decode, page, block, window,
                                 skip=skip[:, None])
         work = {}

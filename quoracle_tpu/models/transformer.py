@@ -1051,12 +1051,11 @@ def forward_hidden_ragged(
     that rows have in common once for all of them: schedules, not layouts
     — nothing else here reads either."""
     if cfg.latent is not None:
-        assert shard is None and k_scale is None and tiles is None \
-            and shared is None, "latent/expert models: no tp shards, " \
-            "no int8 pages, no tiles, no shared walk"
+        assert shard is None and k_scale is None and tiles is None, \
+            "latent/expert models: no tp shards, no int8 pages, no tiles"
         return _forward_hidden_ragged_stacks(
             params, cfg, tokens, positions, k_pool, v_pool, row_tables,
-            block_meta, flat_dst, tq, interpret)
+            block_meta, flat_dst, tq, interpret, shared)
     if not cfg.plain:
         assert shard is None and k_scale is None, \
             "expert/hybrid models: no tp shards, no int8 pages"
@@ -1130,7 +1129,7 @@ INDEX_CHUNK = 1024
 
 def _forward_hidden_ragged_stacks(params, cfg, tokens, positions, k_pool,
                                   v_pool, row_tables, block_meta, flat_dst,
-                                  tq, interpret) -> tuple:
+                                  tq, interpret, shared=None) -> tuple:
     """``forward_hidden_ragged`` for a model with latent attention and
     expert layers: the same contract, with TWO layer stacks — the leading
     dense layers (``params["dense_layers"]``) and then the expert layers
@@ -1153,10 +1152,16 @@ def _forward_hidden_ragged_stacks(params, cfg, tokens, positions, k_pool,
     keeps its ``topk`` best (``select_keys``, exact), and the latent
     kernel walks the row's pages with that per-query mask — the causal
     walk's cost, the published arithmetic. Ticks longer than
-    ``INDEX_CHUNK`` tokens attend chunk by chunk."""
+    ``INDEX_CHUNK`` tokens attend chunk by chunk.
+
+    ``shared`` (the decode step, one token a row): the latent kernel walks
+    the pages that rows have in common once for all of them, as the dense
+    kernel does; a chunk forward's call (tq > 1) has no such walk."""
     from quoracle_tpu.ops.paged_attention import (
         index_scores_auto, ragged_attend_latent_auto,
     )
+    if tq != 1:
+        shared = None
     L, n_pages, page, _ = k_pool.shape
     n_tok = n_pages * page
     Tp = tokens.shape[1]
@@ -1180,7 +1185,7 @@ def _forward_hidden_ragged_stacks(params, cfg, tokens, positions, k_pool,
             attn = ragged_attend_latent_auto(
                 q, kp, row_tables, block_meta, layer, tq=tq,
                 v_lanes=cfg.latent.kv_rank, scale=scale,
-                interpret=interpret)
+                interpret=interpret, shared=shared)
         return _latent_attn_out(x, attn, p, cfg), kp
 
     def selected_attention(x, pools, p, layer, positions, keep, flat_dst,
@@ -1198,13 +1203,14 @@ def _forward_hidden_ragged_stacks(params, cfg, tokens, positions, k_pool,
             with jax.named_scope("index_scores"):
                 scores = index_scores_auto(qi, w, ip, row_tables,
                                            block_meta, layer, tq=tq,
-                                           interpret=interpret)
+                                           interpret=interpret,
+                                           shared=shared)
             select = select_keys(scores, block_meta, tq, cfg.indexer.topk)
         with jax.named_scope("attn"):
             attn = ragged_attend_latent_auto(
                 q, kp, row_tables, block_meta, layer, tq=tq,
                 v_lanes=cfg.latent.kv_rank, scale=scale,
-                interpret=interpret, select=select)
+                interpret=interpret, select=select, shared=shared)
         return _latent_attn_out(x, attn, p, cfg), (kp, ip)
 
     def chunked_attention(x, pools, p, layer):

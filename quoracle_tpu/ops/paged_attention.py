@@ -6,8 +6,9 @@ a program per 8-token block, or per TILE of up to 128 of a row's tokens
 that read its pages once between them (the chunk forward's call) —
 ``ragged_attend_latent`` the same contract over a latent (MLA) pool,
 with a per-query selection where the model has an indexer, whose scores
-``index_scores`` streams from a pool of its own: see the section comments
-below and ARCHITECTURE.md §10.
+``index_scores`` streams from a pool of its own; in the decode program
+both take the dense kernel's shared-walk table and read a group's common
+pages once: see the section comments below and ARCHITECTURE.md §10.
 
 The paged KV session cache (models/generate.py SessionStore) keeps every
 resident conversation as a PAGE LIST into one device pool. The gather
@@ -1382,7 +1383,7 @@ def _ragged_latent_kernel(tables_ref, meta_ref, layer_ref, q_ref, kv_hbm,
 
 
 @functools.partial(jax.jit, static_argnames=("tq", "v_lanes", "scale",
-                                             "interpret"))
+                                             "interpret", "walk_block"))
 def ragged_attend_latent(
     q: jax.Array,            # [NB·tq, H, lanes] folded queries
     pool: jax.Array,         # [L, n_pages, page, lanes] — the latent pool
@@ -1394,11 +1395,22 @@ def ragged_attend_latent(
     scale: float,
     interpret: bool = False,
     select: Optional[jax.Array] = None,   # [NB·tq, maxp·page] int32
+    shared: Optional[jax.Array] = None,   # [2 + SHARED_ROWS, R] int32
+    walk_block: Optional[int] = None,     # tests: pages a loop iteration
 ) -> jax.Array:
     """Pallas latent ragged attention (contract of
     ``ragged_attend_latent_ref``; output in the queries' type). The pool is
     passed whole, as stored, and stays in HBM; ``lanes`` and ``v_lanes``
-    are multiples of 128 (config.LatentConfig.lanes pads the stored row)."""
+    are multiples of 128 (config.LatentConfig.lanes pads the stored row).
+    With ``shared`` (``shared_walks`` of the tables; the decode program's
+    call, tq = 1 and block i row i's) the call is the DECODE walk of the
+    section below: rows whose tables begin alike have their common pages
+    streamed and multiplied once between them."""
+    if shared is not None:
+        return _latent_decode_walk(q, pool, row_tables, block_meta, layer,
+                                   shared, tq=tq, v_lanes=v_lanes,
+                                   scale=scale, interpret=interpret,
+                                   select=select, walk_block=walk_block)
     Tp, H, lanes = q.shape
     NB = block_meta.shape[1]
     page = pool.shape[2]
@@ -1447,18 +1459,347 @@ def ragged_attend_latent(
 def ragged_attend_latent_auto(q, pool, row_tables, block_meta, layer, *,
                               tq: int, v_lanes: int, scale: float,
                               interpret: Optional[bool] = None,
-                              select: Optional[jax.Array] = None):
+                              select: Optional[jax.Array] = None,
+                              shared: Optional[jax.Array] = None):
     """Latent attention dispatcher: the Pallas kernel on TPU (or under
     ``interpret``), the XLA gather reference elsewhere. A pool whose lanes
-    are no multiple of 128 (tiny test models) takes the reference."""
+    are no multiple of 128 (tiny test models) takes the reference.
+    ``shared`` is a schedule of the decode call's walk (``shared_walks``):
+    the reference, which gathers, has no use for it."""
     aligned = pool.shape[-1] % 128 == 0 and v_lanes % 128 == 0
     if (_on_tpu() or interpret) and aligned:
         return ragged_attend_latent(q, pool, row_tables, block_meta, layer,
                                     tq=tq, v_lanes=v_lanes, scale=scale,
-                                    interpret=bool(interpret), select=select)
+                                    interpret=bool(interpret), select=select,
+                                    shared=shared)
     return ragged_attend_latent_ref(q, pool, row_tables, block_meta, layer,
                                     tq=tq, v_lanes=v_lanes, scale=scale,
                                     select=select)
+
+
+# ---------------------------------------------------------------------------
+# The latent DECODE walk (ISSUE 40): a group's pages once, a block a turn
+# ---------------------------------------------------------------------------
+#
+# The decode program's call of the latent kernel is one program a one-token
+# row, H score rows each (64 at A.X-K1, 128 at DeepSeek-V3.2), and rows that
+# adopted one prompt walked its pages one after another, a page a turn: 5
+# rows × 80 pages of 160 KiB a layer a step, 0.66 µs a page a row for 0.2 of
+# bytes. With ``shared`` (the table of ``shared_walks``, section "The SHARED
+# walk": the same contract, the same host half) the call is this kernel:
+#
+#   the group  the lowest row of a group streams the common pages ONCE and
+#              multiplies each against all of the group's queries at once, a
+#              left operand of members × H rows, every score row with its
+#              own float32 online-softmax state; it parks each member's
+#              (m, l, acc) in VMEM scratch that outlives the program, and
+#              every row's program walks only the pages behind the shared
+#              ones, from that state. A member is H score rows × ``lanes``
+#              of multiplies a page, and a walk's cost follows its rows
+#              (0.64 / 1.0 / 1.9 µs a page at 2 / 5 / 8 members of 128
+#              heads), so a short group does not repeat its leader up to
+#              SHARED_ROWS as the dense walk does: the walk is compiled at
+#              ``LATENT_WALK_SIZES`` members and a group takes the first
+#              that holds it (a table's tail repeats the leader: a group's
+#              size is the members that differ from it).
+#   a block    both walks move ``latent_walk_pages`` pages a loop turn
+#              through ``_walk_blocks`` (the next block's copies in flight)
+#              and attend a block as ONE run of keys: one q·kᵀ, one row
+#              maximum, one exp, one p·v and one rescale of the [rows,
+#              v_lanes] accumulator a block, where the walk of a page a
+#              turn paid each a page (0.66 → 0.35 µs a page a row at 128
+#              heads, 0.55 → 0.29 at 64). Operands as before — the stored
+#              type with float32 accumulation, a float32 softmax — so a
+#              row's output is its old walk's to the rounding of where the
+#              maxima are taken. A walk's last block may be partial: its
+#              tail is masked, never copied into (the scratch is zeroed
+#              once a call so that it holds numbers).
+#   select     a shared page is NOT wholly visible to every member: each
+#              member's row of the selection is gathered beside its query
+#              and masks its H score rows, as in the row's own walk.
+#
+# A row in no group reads shared[0, r] = 0 and walks all of its pages; a
+# zero table is the walk with nothing shared. The chunk forward's call
+# (tq = 8) keeps ``_ragged_latent_kernel``. PERF.md §6, PR 40, has the
+# readings.
+
+# Members a group's walk is compiled at: a group takes the first that
+# holds it, so an odd one multiplies one member's rows for nothing (0.2 µs
+# a page at 128 heads). Every size compiled in cost 3.4 MB of kernel code a
+# call against 0.17 before, 13 MiB of HBM and 11 s of a warm start at
+# DeepSeek-V3.2's widths (PERF.md §6, PR 40); the scoring kernel, whose
+# cost hardly follows its rows, takes two.
+LATENT_WALK_SIZES = (2, 4, 6, 8)
+INDEX_WALK_SIZES = (4, 8)
+# Keys a block of the latent walks scores at once. The float32 score tile
+# of a full group is members × H × keys × 4 bytes (2 MiB at 1,024 rows):
+# 512 keys read 20% under 256 on the chip, 1,024 read 1.9–4.6× OVER.
+_LATENT_WALK_KEYS = 512
+
+
+def latent_walk_pages(page: int) -> int:
+    """Pages a loop turn of the latent decode walks carries (4 at pages of
+    128 tokens); the engine's count of loop turns reads it too."""
+    return max(1, _LATENT_WALK_KEYS // page)
+
+
+def _group_walks(shared_ref, meta_ref, i, sizes: tuple, walk) -> None:
+    """Run ``walk(members)`` where row i LEADS a group of the shared-walk
+    table with a live member, ``members`` the group's rows padded to the
+    first of ``sizes`` that holds them (a table's tail repeats the leader:
+    a group's size is the members that differ from it)."""
+    members = [shared_ref[2 + k, i] for k in range(SHARED_ROWS)]
+    live, size = meta_ref[2, members[0]], jnp.int32(1)
+    for r in members[1:]:
+        live = jnp.maximum(live, meta_ref[2, r])
+        size = size + (r != members[0]).astype(jnp.int32)
+    leads = (shared_ref[1, i] > 0) & (live > 0)
+    for below, held in zip((0,) + sizes, sizes):
+        @pl.when(leads & (size > below) & (size <= held))
+        def _(held=held):
+            walk(members[:held])
+
+
+def _latent_block(q, kv, valid, m, l, acc, *, scale: float, v_lanes: int):
+    """One block of a latent walk, its pages end to end as ONE run of keys:
+    ``q`` [rows, lanes] in the stored type, ``kv`` [keys, lanes] (the key
+    at full width, the value its first ``v_lanes`` lanes), ``valid`` [rows
+    or 1, keys]. One row maximum, one exp and one rescale of acc a block;
+    ``l`` stays a sum a LANE, [rows, 128], until the walk ends (a lane
+    reduction a block less). Returns the updated (m, l, acc)."""
+    rows, keys = q.shape[0], kv.shape[0]
+    valid = jnp.broadcast_to(valid, (rows, keys))
+    scores = jax.lax.dot_general(                        # [rows, keys]
+        q, kv, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32) * scale
+    scores = jnp.where(valid, scores, NEG_INF)
+    m_new = jnp.maximum(m, jnp.max(scores, axis=1, keepdims=True))
+    p = jnp.where(valid, jnp.exp(scores - m_new), 0.0)
+    corr = jnp.exp(m - m_new)
+    l_new = l * corr + sum(p[:, at:at + 128] for at in range(0, keys, 128))
+    pv = jax.lax.dot_general(                            # [rows, v_lanes]
+        p.astype(kv.dtype), kv[:, :v_lanes],
+        (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+    return m_new, l_new, acc * corr + pv
+
+
+def _latent_decode_kernel(tables_ref, meta_ref, layer_ref, shared_ref,
+                          q_ref, q_hbm, kv_hbm, *refs, page: int,
+                          v_lanes: int, scale: float, selected: bool,
+                          block: int, sizes: tuple):
+    """Row i's program of the latent decode walk (section comment): the
+    group's walk first where i leads one, then i's own pages behind the
+    shared ones. Scalar-prefetched: tables_ref [R, maxp], meta_ref [4, R],
+    layer_ref [1], shared_ref [2 + SHARED_ROWS, R]. q arrives twice, the
+    row's block in VMEM and whole in HBM for the group's gather; so does
+    the selection where the model has one.
+
+    Scratch: kv_scr [2·block·page, lanes], two blocks of pages end to end,
+    and a DMA semaphore a page (both walks); qg_scr [members·H, lanes] the
+    group's queries, member-major; (m, l, acc)_scr the group's state in
+    that layout while its walk runs; (m, l, acc)_st the same PARKED by row,
+    [R, H, ·] (m in every lane of 128: a column loaded as such would be
+    re-laid every block; l a sum a lane, as the walks carry it); the
+    gather's semaphore; selg_scr [members, 1, maxp·page] the members' rows
+    of the selection."""
+    if selected:
+        (sel_ref, sel_hbm, out_ref, kv_scr, sems, qg_scr, m_scr, l_scr,
+         acc_scr, m_st, l_st, acc_st, g_sem, selg_scr) = refs
+    else:
+        (out_ref, kv_scr, sems, qg_scr, m_scr, l_scr, acc_scr, m_st, l_st,
+         acc_st, g_sem) = refs
+    i = pl.program_id(0)
+    kv_len = meta_ref[0, i]
+    qpos0 = meta_ref[1, i]
+    nq = meta_ref[2, i]
+    row = meta_ref[3, i]
+    layer = layer_ref[0]
+    H, lanes = q_ref.shape[2], q_ref.shape[3]
+    keys = block * page
+    p_lo = shared_ref[0, i]
+
+    @pl.when(i == 0)
+    def _():
+        # a walk's last block may be partial: the slots behind it are
+        # masked, never copied into, and must hold numbers
+        kv_scr[...] = jnp.zeros(kv_scr.shape, kv_scr.dtype)
+
+    def page_dmas(j, slot):
+        return [pltpu.make_async_copy(
+            kv_hbm.at[layer, tables_ref[row, j]],
+            kv_scr.at[pl.ds(pl.multiple_of(slot * page, page), page)],
+            sems.at[slot])]
+
+    def block_of(half):
+        return kv_scr[pl.ds(pl.multiple_of(half * page, keys), keys)]
+
+    def kept(sel, at, j):
+        """[1, keys]: where row ``at`` of ``sel`` keeps the keys of the
+        block that begins with page j."""
+        return sel[at, :, pl.ds(pl.multiple_of(j * page, page), keys)] != 0
+
+    def group_walk(group):
+        """The common pages once for ``group`` (a static count of member
+        rows; a table's tail repeats the leader)."""
+        rows = len(group) * H
+
+        def gather(k):
+            out = [pltpu.make_async_copy(q_hbm.at[group[k], 0],
+                                         qg_scr.at[pl.ds(k * H, H)],
+                                         g_sem.at[0])]
+            if selected:
+                out.append(pltpu.make_async_copy(
+                    sel_hbm.at[group[k]], selg_scr.at[k], g_sem.at[0]))
+            return out
+
+        for k in range(len(group)):
+            for d in gather(k):
+                d.start()
+        _start_block(p_lo, block, 0, page_dmas)
+        m_scr[0:rows] = jnp.full((rows, 1), NEG_INF, jnp.float32)
+        l_scr[0:rows] = jnp.zeros((rows, 128), jnp.float32)
+        acc_scr[0:rows] = jnp.zeros((rows, v_lanes), jnp.float32)
+        for k in range(len(group)):
+            for d in gather(k):
+                d.wait()
+
+        def attend(first, half, left, wait, carry):
+            _each(left, wait)
+            # every shared page is whole and visible to every member, but
+            # for what a member's selection drops; the last block's tail
+            s_idx = first * page + jax.lax.broadcasted_iota(
+                jnp.int32, (1, keys), 1)
+            valid = s_idx < p_lo * page
+            if selected:
+                valid = jnp.concatenate(
+                    [jnp.broadcast_to(valid & kept(selg_scr, k, first),
+                                      (H, keys))
+                     for k in range(len(group))], axis=0)
+            m_scr[0:rows], l_scr[0:rows], acc_scr[0:rows] = _latent_block(
+                qg_scr[0:rows], block_of(half), valid, m_scr[0:rows],
+                l_scr[0:rows], acc_scr[0:rows], scale=scale,
+                v_lanes=v_lanes)
+            return carry
+
+        _walk_blocks(p_lo, block, page_dmas, attend, 0)
+        for k, r in enumerate(group):
+            at = slice(k * H, (k + 1) * H)
+            m_st[r] = jnp.broadcast_to(m_scr[at], m_st.shape[1:])
+            l_st[r] = l_scr[at]
+            acc_st[r] = acc_scr[at]
+
+    _group_walks(shared_ref, meta_ref, i, sizes, group_walk)
+
+    # the row's own walk: the pages behind the shared ones, from the state
+    # the group's walk parked; a row that is done walks nothing
+    kv_hi = jnp.minimum(kv_len, qpos0 + nq)
+    n = jnp.where(nq > 0,
+                  jnp.maximum((kv_hi + page - 1) // page - p_lo, 0), 0)
+
+    def dmas(j, slot):
+        return page_dmas(p_lo + j, slot)
+
+    _start_block(n, block, 0, dmas)
+    q = q_ref[0].reshape(H, lanes)
+    carried = (p_lo > 0) & (nq > 0)
+    init = tuple(
+        jnp.where(carried, st, new) for st, new in zip(
+            (jnp.max(m_st[i], axis=1, keepdims=True), l_st[i], acc_st[i]),
+            (jnp.full((H, 1), NEG_INF, jnp.float32),
+             jnp.zeros((H, 128), jnp.float32),
+             jnp.zeros((H, v_lanes), jnp.float32))))
+
+    def attend(first, half, left, wait, carry):
+        _each(left, wait)
+        s_idx = (p_lo + first) * page + jax.lax.broadcasted_iota(
+            jnp.int32, (1, keys), 1)
+        valid = (s_idx < kv_len) & (s_idx <= qpos0) & (nq > 0)
+        if selected:
+            valid = valid & kept(sel_ref, 0, p_lo + first)
+        return _latent_block(q, block_of(half), valid, *carry, scale=scale,
+                             v_lanes=v_lanes)
+
+    _, l, acc = _walk_blocks(n, block, dmas, attend, init)
+    l = jnp.sum(l, axis=1, keepdims=True)
+    norm = acc / jnp.where(l > 0, l, 1.0)
+    out_ref[0] = norm.reshape(1, H, v_lanes).astype(out_ref.dtype)
+
+
+def _latent_decode_walk(q, pool, row_tables, block_meta, layer, shared, *,
+                        tq: int, v_lanes: int, scale: float,
+                        interpret: bool, select, walk_block):
+    """``ragged_attend_latent`` with a shared-walk table: the pallas_call
+    of ``_latent_decode_kernel`` (traced inside the caller's jit)."""
+    R, H, lanes = q.shape
+    page = pool.shape[2]
+    assert tq == 1 and block_meta.shape[1] == R \
+        and shared.shape == (2 + SHARED_ROWS, R), (tq, shared.shape, R)
+    sizes = LATENT_WALK_SIZES
+    assert sizes[-1] == SHARED_ROWS, sizes
+    block = walk_block or latent_walk_pages(page)
+    kernel = functools.partial(
+        _latent_decode_kernel, page=page, v_lanes=v_lanes, scale=scale,
+        selected=select is not None, block=block, sizes=sizes)
+    qb = q.astype(pool.dtype).reshape(R, 1, H, lanes)
+    wide = SHARED_ROWS * H
+    more_specs, more, more_scr = [], [], []
+    # what the scratch and the blocks in flight take of VMEM: the default
+    # scope is 16 MiB, which 128 heads of parked state and eight members'
+    # selections at 16k positions pass
+    need = (2 * block * page * lanes * pool.dtype.itemsize
+            + wide * lanes * pool.dtype.itemsize
+            + (wide + R * H) * (v_lanes + 2 * 128) * 4
+            + 3 * wide * block * page * 4 + 4 * H * (lanes + v_lanes) * 4)
+    if select is not None:
+        # a walk's last block may begin at the table's last page: the
+        # selection is read a block at a time, so it ends a block's tail on
+        S = select.shape[1] + (block - 1) * page
+        sel = jnp.pad(select.astype(jnp.int32),
+                      ((0, 0), (0, (block - 1) * page))).reshape(R, 1, S)
+        more_specs = [pl.BlockSpec((1, 1, S), lambda i, *_: (i, 0, 0)),
+                      pl.BlockSpec(memory_space=pl.ANY)]
+        more = [sel, sel]
+        more_scr = [pltpu.VMEM((SHARED_ROWS, 1, S), jnp.int32)]
+        need += (SHARED_ROWS + 2) * 8 * S * 4    # a row pads to 8 sublanes
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,          # tables, meta, layer, shared
+            grid=(R,),
+            in_specs=[
+                pl.BlockSpec((1, 1, H, lanes), lambda i, *_: (i, 0, 0, 0)),
+                pl.BlockSpec(memory_space=pl.ANY),      # q, for the gather
+                pl.BlockSpec(memory_space=pl.ANY),      # pool stays in HBM
+                *more_specs,
+            ],
+            out_specs=[
+                pl.BlockSpec((1, 1, H, v_lanes),
+                             lambda i, *_: (i, 0, 0, 0)),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((2 * block * page, lanes), pool.dtype),
+                pltpu.SemaphoreType.DMA((2 * block,)),
+                pltpu.VMEM((wide, lanes), pool.dtype),
+                pltpu.VMEM((wide, 1), jnp.float32),
+                pltpu.VMEM((wide, 128), jnp.float32),
+                pltpu.VMEM((wide, v_lanes), jnp.float32),
+                pltpu.VMEM((R, H, 128), jnp.float32),
+                pltpu.VMEM((R, H, 128), jnp.float32),
+                pltpu.VMEM((R, H, v_lanes), jnp.float32),
+                pltpu.SemaphoreType.DMA((1,)),
+                *more_scr],
+        ),
+        out_shape=[jax.ShapeDtypeStruct((R, 1, H, v_lanes), q.dtype)],
+        interpret=interpret,
+        # pinned, as the walk without a table: `%ragged_attend_latent.<n>`
+        name="ragged_attend_latent",
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=max(32 << 20, need + (8 << 20))
+            if select is not None or need > (12 << 20) else None),
+    )(row_tables.astype(jnp.int32), block_meta.astype(jnp.int32),
+      jnp.asarray(layer, jnp.int32).reshape(1), shared.astype(jnp.int32),
+      qb, qb, pool, *more)[0]
+    return out.reshape(R, H, v_lanes)
 
 
 # ---------------------------------------------------------------------------
@@ -1474,7 +1815,10 @@ def ragged_attend_latent_auto(q, pool, row_tables, block_meta, layer, *,
 # page's bytes) and writes the float32 scores of the block's queries
 # against every position it walked, ``[NB·tq, maxp·page]``. Positions it
 # did not walk (past the block's last query) hold whatever the buffer
-# held: the caller masks by visibility before it selects.
+# held: the caller masks by visibility before it selects. The decode
+# program's call (tq = 1, with the shared-walk table) is
+# ``_index_decode_kernel``: eight pages a turn scored as one run of keys,
+# a group's common pages once for all of its members.
 
 
 def index_scores_ref(
@@ -1548,11 +1892,171 @@ def _index_scores_kernel(tables_ref, meta_ref, layer_ref, q_ref, w_ref,
     jax.lax.fori_loop(0, n, body, 0)
 
 
-@functools.partial(jax.jit, static_argnames=("tq", "interpret"))
+def _index_decode_kernel(tables_ref, meta_ref, layer_ref, shared_ref, q_ref,
+                         w_ref, q_hbm, k_hbm, out_ref, k_scr, sems, qg_scr,
+                         wg_scr, sc_st, g_sem, *, page: int, block: int,
+                         sizes: tuple):
+    """Row i's index scores in the decode program, with the shared walk of
+    the latent decode kernel (section "The latent DECODE walk": the same
+    table, the same groups): where i leads a group, the common pages of
+    index keys are streamed once and scored against every member's heads
+    at once, each member's row of scores PARKED in ``sc_st [R, 1, S]``;
+    every row then copies its parked scores out and scores the pages
+    behind them. A score is a sum of independent products — no state
+    crosses a page — so a row's scores are those of its walk alone.
+    Both walks carry ``block`` pages a turn (``walk_pages`` of a page of
+    index keys), scored as one run of keys. ``w_ref`` is every row's head
+    weights, whole in VMEM ([R, Hi, 1]: a column a row cannot be copied
+    out of HBM by itself)."""
+    i = pl.program_id(0)
+    kv_len = meta_ref[0, i]
+    qpos0 = meta_ref[1, i]
+    nq = meta_ref[2, i]
+    row = meta_ref[3, i]
+    layer = layer_ref[0]
+    Hi = q_ref.shape[2]
+    keys = block * page
+    p_lo = shared_ref[0, i]
+
+    def page_dmas(j, slot):
+        return [pltpu.make_async_copy(
+            k_hbm.at[layer, tables_ref[row, j]],
+            k_scr.at[pl.ds(pl.multiple_of(slot * page, page), page)],
+            sems.at[slot])]
+
+    def score(q, w, half, left, wait, stores):
+        """A block's scores for ``len(stores)`` members: ``stores[k](b,
+        [1, page])`` takes member k's scores of the block's page b."""
+        _each(left, wait)
+        dots = jax.lax.dot_general(                      # [rows, keys]
+            q, k_scr[pl.ds(pl.multiple_of(half * page, keys), keys)],
+            (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+        heads = jnp.maximum(dots, 0.0) * w
+        for k, store in enumerate(stores):
+            mine = heads[k * Hi:(k + 1) * Hi].sum(axis=0, keepdims=True)
+            for b in range(block):
+                @pl.when(b < left)
+                def _(b=b, mine=mine, store=store):
+                    store(b, mine[:, b * page:(b + 1) * page])
+
+    def group_walk(group):
+        rows = len(group) * Hi
+
+        def gather(k):
+            return pltpu.make_async_copy(q_hbm.at[group[k], 0],
+                                         qg_scr.at[pl.ds(k * Hi, Hi)],
+                                         g_sem.at[0])
+
+        for k in range(len(group)):
+            gather(k).start()
+        _start_block(p_lo, block, 0, page_dmas)
+        for k, r in enumerate(group):
+            wg_scr[k * Hi:(k + 1) * Hi] = w_ref[r]
+        for k in range(len(group)):
+            gather(k).wait()
+
+        def attend(first, half, left, wait, carry):
+            def park(r):
+                def store(b, sc):
+                    sc_st[r, :, pl.ds(pl.multiple_of(
+                        (first + b) * page, page), page)] = sc
+                return store
+
+            score(qg_scr[0:rows], wg_scr[0:rows], half, left, wait,
+                  [park(r) for r in group])
+            return carry
+
+        _walk_blocks(p_lo, block, page_dmas, attend, 0)
+
+    _group_walks(shared_ref, meta_ref, i, sizes, group_walk)
+
+    n = jnp.where(nq > 0, jnp.maximum(
+        (jnp.minimum(kv_len, qpos0 + nq) + page - 1) // page - p_lo, 0), 0)
+
+    def dmas(j, slot):
+        return page_dmas(p_lo + j, slot)
+
+    _start_block(n, block, 0, dmas)
+
+    def out_page(j):
+        return (0, slice(None), pl.ds(pl.multiple_of(j * page, page), page))
+
+    def adopt(j):
+        out_ref[out_page(j)] = sc_st[i, :, pl.ds(
+            pl.multiple_of(j * page, page), page)]
+
+    _each(jnp.where(nq > 0, p_lo, 0), adopt)
+    q = q_ref[0].reshape(Hi, q_ref.shape[3])
+    w = w_ref[i]                                         # [Hi, 1]
+
+    def attend(first, half, left, wait, carry):
+        def store(b, sc):
+            out_ref[out_page(p_lo + first + b)] = sc
+
+        score(q, w, half, left, wait, [store])
+        return carry
+
+    _walk_blocks(n, block, dmas, attend, 0)
+
+
+def _index_decode_walk(q, w, pool, row_tables, block_meta, layer, shared, *,
+                       tq: int, interpret: bool, walk_block):
+    """``index_scores`` with a shared-walk table: the pallas_call of
+    ``_index_decode_kernel`` (traced inside the caller's jit)."""
+    R, Hi, di = q.shape
+    page = pool.shape[2]
+    assert tq == 1 and block_meta.shape[1] == R \
+        and shared.shape == (2 + SHARED_ROWS, R), (tq, shared.shape, R)
+    S = row_tables.shape[1] * page
+    block = walk_block or walk_pages(page * di * pool.dtype.itemsize)
+    wide = SHARED_ROWS * Hi
+    qb = q.astype(pool.dtype).reshape(R, 1, Hi, di)
+    wb = w.astype(jnp.float32).reshape(R, Hi, 1)
+    out = pl.pallas_call(
+        functools.partial(_index_decode_kernel, page=page, block=block,
+                          sizes=INDEX_WALK_SIZES),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,      # tables, meta, layer, shared
+            grid=(R,),
+            in_specs=[
+                pl.BlockSpec((1, 1, Hi, di), lambda i, *_: (i, 0, 0, 0)),
+                pl.BlockSpec((R, Hi, 1), lambda i, *_: (0, 0, 0)),
+                pl.BlockSpec(memory_space=pl.ANY),   # q, for the gather
+                pl.BlockSpec(memory_space=pl.ANY),   # pool stays in HBM
+            ],
+            out_specs=[pl.BlockSpec((1, 1, S), lambda i, *_: (i, 0, 0))],
+            scratch_shapes=[
+                pltpu.VMEM((2 * block * page, di), pool.dtype),
+                pltpu.SemaphoreType.DMA((2 * block,)),
+                pltpu.VMEM((wide, di), pool.dtype),
+                pltpu.VMEM((wide, 1), jnp.float32),
+                pltpu.VMEM((R, 1, S), jnp.float32),
+                pltpu.SemaphoreType.DMA((1,))],
+        ),
+        out_shape=[jax.ShapeDtypeStruct((R, 1, S), jnp.float32)],
+        interpret=interpret,
+        name="index_scores",               # pinned, as below
+    )(row_tables.astype(jnp.int32), block_meta.astype(jnp.int32),
+      jnp.asarray(layer, jnp.int32).reshape(1), shared.astype(jnp.int32),
+      qb, wb, qb, pool)[0]
+    return out.reshape(R, S)
+
+
+@functools.partial(jax.jit, static_argnames=("tq", "interpret",
+                                             "walk_block"))
 def index_scores(q, w, pool, row_tables, block_meta, layer, tq: int,
-                 interpret: bool = False) -> jax.Array:
+                 interpret: bool = False,
+                 shared: Optional[jax.Array] = None,
+                 walk_block: Optional[int] = None) -> jax.Array:
     """Pallas index scores (contract of ``index_scores_ref`` on the
-    positions a block can see). The pool stays in HBM, whole."""
+    positions a block can see). The pool stays in HBM, whole. With
+    ``shared`` (``shared_walks`` of the tables; the decode program's call,
+    tq = 1 and block i row i's) rows of a group have their common pages
+    scored in one walk (``_index_decode_kernel``): the same scores."""
+    if shared is not None:
+        return _index_decode_walk(q, w, pool, row_tables, block_meta, layer,
+                                  shared, tq=tq, interpret=interpret,
+                                  walk_block=walk_block)
     Tp, Hi, di = q.shape
     NB = block_meta.shape[1]
     page = pool.shape[2]
@@ -1584,11 +2088,13 @@ def index_scores(q, w, pool, row_tables, block_meta, layer, tq: int,
 
 
 def index_scores_auto(q, w, pool, row_tables, block_meta, layer, *,
-                      tq: int, interpret: Optional[bool] = None):
+                      tq: int, interpret: Optional[bool] = None,
+                      shared: Optional[jax.Array] = None):
     """Index-score dispatcher: the Pallas kernel on TPU (or under
-    ``interpret``) where the key is whole lanes wide, else the reference."""
+    ``interpret``) where the key is whole lanes wide, else the reference
+    (which gathers, and has no use for ``shared``)."""
     if (_on_tpu() or interpret) and pool.shape[-1] % 128 == 0:
         return index_scores(q, w, pool, row_tables, block_meta, layer,
-                            tq=tq, interpret=bool(interpret))
+                            tq=tq, interpret=bool(interpret), shared=shared)
     return index_scores_ref(q, w, pool, row_tables, block_meta, layer,
                             tq=tq)

@@ -874,7 +874,8 @@ def test_latent_decode_program_leaves_the_pool_where_it_is(on_v5e,
     layer_elems = st.n_pages * st.page * 640
     R, i32, f32 = 8, jnp.int32, jnp.float32
     compiled = eng._step_paged_decode_ragged.lower(
-        params, pool, None, None, None, S((R, 8), i32), None, S((R,), i32),
+        params, pool, None, None, None, S((R, 8), i32),
+        S((2 + pa.SHARED_ROWS, R), i32), S((R,), i32),
         S((R,), i32), S((R, cfg.vocab_size), f32), S((2,), jnp.uint32),
         S((R,), f32), S((R,), f32), S((R,), jnp.bool_), S((R,), i32),
         None, None, max_new=32).compile()
@@ -953,7 +954,8 @@ def test_selecting_decode_program_leaves_both_pools_where_they_are(
     tokens = st.n_pages * st.page
     R, i32, f32 = 8, jnp.int32, jnp.float32
     compiled = eng._step_paged_decode_ragged.lower(
-        params, *pools, None, None, S((R, 8), i32), None, S((R,), i32),
+        params, *pools, None, None, S((R, 8), i32),
+        S((2 + pa.SHARED_ROWS, R), i32), S((R,), i32),
         S((R,), i32), S((R, cfg.vocab_size), f32), S((2,), jnp.uint32),
         S((R,), f32), S((R,), f32), S((R,), jnp.bool_), S((R,), i32),
         None, None, max_new=32).compile()
@@ -964,6 +966,108 @@ def test_selecting_decode_program_leaves_both_pools_where_they_are(
     assert pool_moves(hlo, tokens * 128) == []
     assert mem.alias_size_in_bytes >= cfg.n_layers * tokens * (640 + 128) * 2
     assert mem.temp_size_in_bytes < tokens * 640 * 2
+
+
+# --- the latent decode walk: a group's pages once (ISSUE 40) ----------------
+
+def _custom_calls(text: str) -> list:
+    return [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+
+
+@pytest.mark.parametrize("rows", [8, 64], ids=["r8", "r64"])
+@pytest.mark.parametrize("heads,selected", [(128, True), (64, False)],
+                         ids=["deepseek-v3.2", "ax-k1"])
+def test_latent_decode_walk_compiles(on_v5e, heads, selected, rows):
+    """The decode call with a shared-walk table at both latent
+    configurations' widths (128 heads under a selection over 16k positions;
+    64 heads without), tables 128 wide: the group's walk at every size of
+    ``LATENT_WALK_SIZES``, the members' gathered queries and selections,
+    the parked state of ``rows`` rows and a block of four pages in flight
+    fit the scoped VMEM the call asks for, the four prefetched tables the
+    1 MiB of SMEM; the custom call keeps the pinned name."""
+    S = on_v5e
+    kw = {"select": S((rows, 128 * PAGE), jnp.int32)} if selected else {}
+    text = jax.jit(functools.partial(
+        pa.ragged_attend_latent, tq=1, v_lanes=512, scale=0.13)).lower(
+        S((rows, heads, 640), jnp.bfloat16),
+        S((LAYERS, N_PAGES, PAGE, 640), jnp.bfloat16),
+        S((rows, 128), jnp.int32), S((4, rows), jnp.int32), S((), jnp.int32),
+        shared=S((2 + pa.SHARED_ROWS, rows), jnp.int32),
+        **kw).compile().as_text()
+    call = _custom_calls(text)
+    assert len(call) == 1
+    assert re.match(r"\s*%ragged_attend_latent(\.\d+)? = ", call[0])
+
+
+@pytest.mark.parametrize("rows", [8, 64], ids=["r8", "r64"])
+def test_index_scores_decode_walk_compiles(on_v5e, rows):
+    """DeepSeek-V3.2's scoring call in the decode program, with the table:
+    64 heads of 128 a member, eight pages of index keys a turn, every row's
+    scores of the shared pages parked in VMEM; the name `^%ragged_attend`
+    does not match."""
+    S = on_v5e
+    text = jax.jit(functools.partial(pa.index_scores, tq=1)).lower(
+        S((rows, 64, 128), jnp.bfloat16), S((rows, 64), jnp.float32),
+        S((LAYERS, N_PAGES, PAGE, 128), jnp.bfloat16),
+        S((rows, 128), jnp.int32), S((4, rows), jnp.int32), S((), jnp.int32),
+        shared=S((2 + pa.SHARED_ROWS, rows), jnp.int32)).compile().as_text()
+    call = _custom_calls(text)
+    assert len(call) == 1
+    assert re.match(r"\s*%index_scores(\.\d+)? = ", call[0])
+
+
+def mosaic_bodies(lowered) -> list:
+    """The Mosaic modules of a lowering's kernels as text WITHOUT source
+    locations (the serialized body in ``backend_config`` holds them: an
+    edit that moves the file's lines changes the raw text, not this)."""
+    import base64
+
+    from jax._src.interpreters import mlir as jax_mlir
+    from jax._src.lib.mlir import ir
+    out = []
+    for body in re.findall(r'\\22body\\22: \\22([A-Za-z0-9+/=]+)\\22',
+                           lowered.as_text()):
+        with jax_mlir.make_ir_context() as ctx:
+            ctx.allow_unregistered_dialects = True
+            out.append(ir.Module.parse(base64.b64decode(body)).operation
+                       .get_asm(enable_debug_info=False))
+    return out
+
+
+# sha256 of the chunk forward's kernels at DeepSeek-V3.2's widths, read off
+# the parent commit (829012b) with this same reader: the decode walk is a
+# kernel of its own and leaves these three as they were
+CHUNK_KERNELS = {
+    "latent-selected": "0955089bf7cccd6c",
+    "latent": "31338baab7ec2708",
+    "index-scores": "af1c4f58cd154a0e",
+}
+
+
+@pytest.mark.parametrize("kernel", sorted(CHUNK_KERNELS))
+def test_the_chunk_forwards_kernels_are_the_text_they_were(on_v5e, kernel):
+    """The chunk forward's calls (tq = 8, no table) lower to the Mosaic
+    text they had before the decode walk got its own kernel."""
+    import hashlib
+    S = on_v5e
+    nb = 128
+    tables, meta = S((8, 128), jnp.int32), S((4, nb), jnp.int32)
+    if kernel == "index-scores":
+        lowered = jax.jit(functools.partial(pa.index_scores, tq=8)).lower(
+            S((nb * 8, 64, 128), jnp.bfloat16), S((nb * 8, 64), jnp.float32),
+            S((5, 512, PAGE, 128), jnp.bfloat16), tables, meta,
+            S((), jnp.int32))
+    else:
+        heads, kw = (128, {"select": S((nb * 8, 128 * PAGE), jnp.int32)}) \
+            if kernel == "latent-selected" else (64, {})
+        lowered = jax.jit(functools.partial(
+            pa.ragged_attend_latent, tq=8, v_lanes=512, scale=0.13)).lower(
+            S((nb * 8, heads, 640), jnp.bfloat16),
+            S((5, 512, PAGE, 640), jnp.bfloat16), tables, meta,
+            S((), jnp.int32), **kw)
+    (body,) = mosaic_bodies(lowered)
+    assert hashlib.sha256(body.encode()).hexdigest()[:16] \
+        == CHUNK_KERNELS[kernel]
 
 
 # --- a latent model's output projection reads wo[layer] where it lies -------
@@ -990,7 +1094,8 @@ def _latent_programs(S, monkeypatch, cfg, tb, width, rows=8, max_seq=1024):
         S((R, width), i32), S((4, tb // RAGGED_TQ), i32), None,
         S((tb,), i32), S((R,), i32), tq=RAGGED_TQ, tile=0).compile()
     decode = eng._step_paged_decode_ragged.lower(
-        params, *pools, None, None, S((R, width), i32), None, S((R,), i32),
+        params, *pools, None, None, S((R, width), i32),
+        S((2 + pa.SHARED_ROWS, R), i32), S((R,), i32),
         S((R,), i32), S((R, cfg.vocab_size), f32), S((2,), jnp.uint32),
         S((R,), f32), S((R,), f32), S((R,), jnp.bool_), S((R,), i32),
         None, None, max_new=32).compile()

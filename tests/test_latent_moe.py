@@ -265,6 +265,109 @@ def test_latent_kernel_is_its_reference(tq):
     assert np.abs(np.asarray(got - want)).max() < 1e-5
 
 
+# -- the decode call's shared walk (ISSUE 40) --------------------------------
+#
+# A case: 8 one-token rows; ``groups`` [(rows, common pages)]: those rows'
+# tables begin with the same ``common`` pages; ``own`` pages a row holds
+# behind them (0: the row's new token lands in the last common page's last
+# slot, so that page is not whole and the walk covers one page less for
+# every member); ``walked`` {row: pages} where that differs from
+# ``groups``; ``done`` rows have no query (nq = 0).
+MIN = pa.SHARED_MIN_PAGES
+SHARED_WALK_CASES = {
+    "a-pair": dict(groups=[((1, 4), MIN + 1)], own=[1, 2, 1, 1, 3, 1, 2, 1]),
+    "five-beside-rows-in-no-group": dict(
+        groups=[((0, 2, 3, 5, 6), MIN + 2)], own=[1, 2, 1, 1, 3, 2, 2, 1]),
+    "all-eight": dict(groups=[(tuple(range(8)), MIN)],
+                      own=[1, 2, 1, 1, 3, 1, 2, 1]),
+    "two-groups-of-three-and-four": dict(
+        groups=[((0, 1, 2), MIN + 3), ((3, 4, 6, 7), MIN)],
+        own=[2, 1, 1, 1, 2, 3, 1, 1]),
+    # its last token lies in the last common page: nothing of its own
+    # behind the shared pages, and the walk ends a page earlier for all
+    "a-member-with-no-page-of-its-own": dict(
+        groups=[((0, 3, 5), MIN + 1)], own=[1, 2, 1, 0, 3, 2, 2, 1],
+        walked={0: MIN, 3: MIN, 5: MIN}),
+    "common-pages-at-the-break-even": dict(
+        groups=[((2, 6), MIN)], own=[1, 2, 1, 1, 3, 1, 2, 1]),
+    "one-under-the-break-even": dict(
+        groups=[((2, 6), MIN - 1)], own=[1, 2, 1, 1, 3, 1, 2, 1],
+        walked={}),
+    "a-done-row-inside-a-group": dict(
+        groups=[((1, 2, 4, 7), MIN + 1)], own=[1, 2, 1, 1, 3, 1, 2, 1],
+        done=(2,)),
+    "a-done-leader": dict(
+        groups=[((1, 2, 4), MIN + 1)], own=[1, 2, 1, 1, 3, 1, 2, 1],
+        done=(1,)),
+}
+
+
+def shared_walk_case(case, rng, lanes=256):
+    """(pool, tables, lens, block meta, the pages a shared walk should cover
+    a row) of a ``SHARED_WALK_CASES`` entry."""
+    R = len(case["own"])
+    ids = iter(range(1, 10_000))
+    tables = np.zeros((R, 16), np.int32)
+    lens = np.zeros((R,), np.int32)
+    want = np.zeros((R,), np.int32)
+    heads = {}
+    for rows, common in case["groups"]:
+        run = [next(ids) for _ in range(common)]
+        for r in rows:
+            heads[r] = run
+            want[r] = common
+    for r in range(R):
+        pages = list(heads.get(r, [])) + [next(ids)
+                                          for _ in range(case["own"][r])]
+        tables[r, :len(pages)] = pages
+        # resident tokens: the last page partly filled; a member with no
+        # page of its own holds its last common page but for one slot
+        lens[r] = len(pages) * PAGE - (1 if not case["own"][r]
+                                       else int(rng.integers(1, PAGE)))
+    if "walked" in case:
+        want[:] = 0
+        for r, n in case["walked"].items():
+            want[r] = n
+    live = np.array([r not in case.get("done", ()) for r in range(R)],
+                    np.int32)
+    meta = np.stack([lens + live, lens - (1 - live), live, np.arange(R)])
+    pool = jnp.asarray(rng.normal(size=(2, next(ids), PAGE, lanes)),
+                       jnp.float32)
+    return pool, tables, lens, meta.astype(np.int32), want
+
+
+@pytest.mark.parametrize("walk_block", [None, 1],
+                         ids=["block-as-served", "a-page-a-turn"])
+@pytest.mark.parametrize("case", sorted(SHARED_WALK_CASES))
+def test_latent_shared_walk_is_its_reference(case, walk_block):
+    """The latent decode call with ``shared_walks``' table of its rows
+    (interpret mode) against the gather reference, which knows no walk,
+    and against the same kernel with a zero table: rows of a group have
+    their common pages multiplied once between them and come out as they
+    do alone."""
+    rng = np.random.default_rng(40)
+    pool, tables, lens, meta, want = shared_walk_case(
+        SHARED_WALK_CASES[case], rng)
+    shared = pa.shared_walks(tables, lens, PAGE)
+    assert shared[0].tolist() == want.tolist()
+    R, H = len(lens), 8
+    q = jnp.asarray(rng.normal(size=(R, H, pool.shape[-1])), jnp.float32)
+    args = (q, pool, jnp.asarray(tables), jnp.asarray(meta), 1)
+    kw = dict(tq=1, v_lanes=128, scale=0.07)
+    ref = pa.ragged_attend_latent_ref(*args, **kw)
+    got = pa.ragged_attend_latent(*args, interpret=True, walk_block=walk_block,
+                                  shared=jnp.asarray(shared), **kw)
+    alone = pa.ragged_attend_latent(
+        *args, interpret=True, walk_block=walk_block,
+        shared=jnp.zeros_like(jnp.asarray(shared)).at[2:].set(
+            jnp.arange(R)), **kw)
+    assert np.abs(np.asarray(got - ref)).max() < 1e-5
+    assert np.abs(np.asarray(alone - ref)).max() < 1e-5
+    assert np.abs(np.asarray(got - alone)).max() < 1e-5
+    done = list(SHARED_WALK_CASES[case].get("done", ()))
+    assert not np.asarray(got)[done].any()
+
+
 # -- the expert layer -------------------------------------------------------
 
 def moe_layer(cfg, params, x, valid=None):
@@ -600,8 +703,12 @@ def test_a_latent_tick_counts_the_walk_its_kernel_made(engine):
     assert args["attn_tiles"] == len(chunk) + len(dec)
     assert args["attn_kv_streamed"] == PAGE * (sum(chunk) + sum(dec))
     assert args["attn_kv_streamed"] > 3 * args["attn_kv_reads"] > 0
-    # a latent pool's walk turns once a page
-    assert args["attn_walk_steps"] * PAGE == args["attn_kv_streamed"]
+    # a latent pool's chunk forward turns once a page, its decode walk
+    # once a block of ``latent_walk_pages`` (every row's pages fit one)
+    assert engine._walk_block >= max(dec)
+    assert args["attn_walk_steps"] == sum(chunk) + len(dec)
+    # nothing in common: no row in a group, no page walked for two
+    assert args["attn_shared_rows"] == args["attn_shared_pages"] == 0
 
 
 # -- the dense models keep their programs -----------------------------------

@@ -23,7 +23,8 @@ from quoracle_tpu.models import transformer as tr
 from quoracle_tpu.models.config import MoEConfig, get_model_config
 from quoracle_tpu.ops import paged_attention as pa
 from tests.test_latent_moe import (
-    RAW as AXK1, f32, serves_the_parents_tokens,
+    RAW as AXK1, SHARED_WALK_CASES, f32, serves_the_parents_tokens,
+    shared_walk_case,
 )
 
 TOL = 2e-4
@@ -386,6 +387,67 @@ def test_latent_kernel_honours_a_selection_as_its_reference(tq):
     p = np.exp(sc - sc.max(-1, keepdims=True))
     hand = (p / p.sum(-1, keepdims=True)) @ rows[:, :128]
     assert np.abs(hand - np.asarray(want[i])).max() < 1e-4
+
+
+@pytest.mark.parametrize("case", sorted(SHARED_WALK_CASES))
+def test_latent_shared_walk_honours_each_members_selection(case):
+    """The decode call's shared walk under a selection (interpret mode)
+    against the gather reference: a shared page is multiplied once for the
+    group and masked a member at a time — the first member of every group
+    keeps NOTHING on its second page, its neighbours about a third."""
+    rng = np.random.default_rng(41)
+    spec = SHARED_WALK_CASES[case]
+    pool, tables, lens, meta, want = shared_walk_case(spec, rng)
+    shared = pa.shared_walks(tables, lens, PAGE)
+    assert shared[0].tolist() == want.tolist()
+    R, H = len(lens), 8
+    select = (rng.random((R, tables.shape[1] * PAGE)) < 0.3).astype(np.int32)
+    for rows, _ in spec["groups"]:
+        select[rows[0], PAGE:2 * PAGE] = 0
+    q = jnp.asarray(rng.normal(size=(R, H, pool.shape[-1])), jnp.float32)
+    args = (q, pool, jnp.asarray(tables), jnp.asarray(meta), 1)
+    kw = dict(tq=1, v_lanes=128, scale=0.07, select=jnp.asarray(select))
+    ref = pa.ragged_attend_latent_ref(*args, **kw)
+    got = pa.ragged_attend_latent(*args, interpret=True,
+                                  shared=jnp.asarray(shared), **kw)
+    assert np.abs(np.asarray(got - ref)).max() < 1e-5
+    dense = pa.ragged_attend_latent_ref(*args, **{**kw, "select": None})
+    live = np.asarray(meta[2]) > 0
+    assert np.abs(np.asarray(dense - ref))[live].max() > 0.05
+
+
+@pytest.mark.parametrize("case", sorted(SHARED_WALK_CASES))
+def test_index_scores_with_the_shared_walk_are_the_walk_alones(case):
+    """The decode call's index scores with ``shared_walks``' table
+    (interpret mode): a group's common pages are scored in one walk for all
+    of its members, and every visible score is BIT-equal to the walk with
+    a zero table, to the kernel the chunk forward keeps, and to the
+    reference — a score is a sum of independent products."""
+    rng = np.random.default_rng(42)
+    pool, tables, lens, meta, want = shared_walk_case(
+        SHARED_WALK_CASES[case], rng, lanes=128)
+    shared = pa.shared_walks(tables, lens, PAGE)
+    assert shared[0].tolist() == want.tolist()
+    R, Hi = len(lens), 8
+    q = jnp.asarray(rng.normal(size=(R, Hi, 128)), jnp.float32)
+    w = jnp.asarray(rng.normal(size=(R, Hi)), jnp.float32)
+    args = (q, w, pool, jnp.asarray(tables), jnp.asarray(meta), 1)
+    zero = np.zeros_like(shared)
+    zero[2:] = np.arange(R)
+    vis = seen(jnp.asarray(meta), 1, tables.shape[1] * PAGE)
+    assert vis.sum() > 2000
+
+    def scores(**kw):
+        return np.where(vis, np.asarray(pa.index_scores(
+            *args, tq=1, interpret=True, **kw)), 0)
+
+    got = scores(shared=jnp.asarray(shared))
+    assert np.array_equal(got, scores(shared=jnp.asarray(zero)))
+    assert np.array_equal(got, scores(shared=jnp.asarray(shared),
+                                      walk_block=1))
+    assert np.array_equal(got, scores())
+    ref = np.where(vis, np.asarray(pa.index_scores_ref(*args, tq=1)), 0)
+    assert np.abs(got - ref).max() < 1e-4
 
 
 # -- through the engine -----------------------------------------------------

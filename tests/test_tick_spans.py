@@ -592,6 +592,80 @@ def test_a_session_drop_books_its_wait_for_the_paged_lock(tmp_path):
         telemetry.METRICS.render_prometheus()
 
 
+# --- a latent engine's decode ticks carry the shared walk (ISSUE 40) ---------
+
+def test_latent_decode_tick_books_the_shared_walk(monkeypatch):
+    """Three sessions of a latent, routed-expert toy on one system prompt,
+    decoded in one tick: the tick span carries the rows and pages the
+    kernel's shared walk served, streams fewer resident tokens than its
+    rows needed, and counts the loop turns of a walk that carries
+    ``latent_walk_pages`` a turn; with nothing in common (no group forms)
+    the same tick reads as a latent tick always did, but for the pages a
+    turn of the decode steps' walks."""
+    import numpy as np
+
+    from benchmark.families import latent_moe
+    from quoracle_tpu.models.tokenizer import get_tokenizer
+    from quoracle_tpu.ops import paged_attention as pa
+    from tests.test_latent_moe import RAW
+    raw = {**RAW, "name": "toy-axk1-long",
+           "serving": dict(context_window=2048, output_limit=128)}
+    cfg = get_model_config(latent_moe.register(raw))
+    params = init_params(cfg, jax.random.PRNGKey(3), dtype=jnp.float32)
+    page, rng = 128, np.random.default_rng(40)
+    n_shared = pa.SHARED_MIN_PAGES + 1
+    system = [int(t) for t in rng.integers(3, 512, n_shared * page + 1)]
+    # suffixes of one 8-token block: a latent chunk forward walks a row's
+    # pages once a block, which is not this test's matter
+    asks = [system + [int(t) for t in rng.integers(3, 512, n)]
+            for n in (3, 7, 5)]
+    steps = 5                    # forwards a row: the sixth token stays
+
+    def run():
+        eng = GenerateEngine(cfg, params, get_tokenizer("tiny"),
+                             max_seq=2048,
+                             prompt_buckets=(256, 512, 1024, 2048))
+        eng.generate([system + [7, 8, 9]], temperature=0.0,
+                     max_new_tokens=2, session_ids=["donor"])
+        telemetry.tick_open("m")
+        try:
+            res = eng.generate(asks, temperature=0.0,
+                               max_new_tokens=steps + 1,
+                               session_ids=["a", "b", "c"])
+        finally:
+            args = telemetry.tick_close().args
+        assert all(r.n_cached_tokens == n_shared * page for r in res)
+        return [r.token_ids for r in res], args, eng._walk_block
+
+    with monkeypatch.context() as patch:
+        patch.setattr(pa, "SHARED_MIN_PAGES", 10 ** 6)
+        want, off, _ = run()
+    got, on, block = run()
+    assert got == want
+    assert block == pa.latent_walk_pages(page) == 4
+    assert off["attn_shared_rows"] == 0 and off["attn_shared_pages"] == 0
+    assert off["attn_kv_streamed"] >= off["attn_kv_reads"]
+    assert on["attn_shared_rows"] == 3
+    assert on["attn_shared_pages"] == n_shared
+    assert on["attn_kv_streamed"] < on["attn_kv_reads"] \
+        == off["attn_kv_reads"]
+    assert off["attn_kv_streamed"] - on["attn_kv_streamed"] == \
+        2 * n_shared * page * steps
+    # a decode step's walks by hand: a row alone walks all of its pages,
+    # ``block`` a turn; with the walk the group's pages are one walk and a
+    # row's own begin behind them
+    pages = [[-(-(len(a) + j) // page) for j in range(1, steps + 1)]
+             for a in asks]
+    turns = lambda n: -(-n // block)                    # noqa: E731
+    alone = sum(turns(n) for row in pages for n in row)
+    walked = steps * turns(n_shared) + sum(
+        turns(n - n_shared) for row in pages for n in row)
+    assert off["attn_walk_steps"] - on["attn_walk_steps"] == alone - walked
+    # the chunk forward's walks are a page a turn in both
+    chunk = off["attn_walk_steps"] - alone
+    assert chunk > 0 and chunk == on["attn_walk_steps"] - walked
+
+
 # ---------------------------------------------------------------------------
 # Names on the device side
 # ---------------------------------------------------------------------------
