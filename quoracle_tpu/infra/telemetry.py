@@ -717,6 +717,15 @@ MOE_LAYER_STEPS_TOTAL = METRICS.counter(
     "quoracle_moe_layer_steps_total",
     "expert layers run (one per layer per forward step with a valid "
     "token), per model")
+MOE_BLOCK_ROWS_TOTAL = METRICS.counter(
+    "quoracle_moe_block_rows_total",
+    "rows of the grouped experts' kernel (ops/grouped_experts.py: a block "
+    "holds ONE expert's assignments, padded up to the tick's block "
+    "height), summed over expert layers and steps, per model: kind = "
+    "assigned for the rows that hold an assignment to a held expert, run "
+    "for the rows of the blocks the kernel ran; assigned / run is how full "
+    "its blocks were. Not booked where the loop over blocks serves (the "
+    "CPU, the latent models)")
 # -- learned sparse attention (ISSUE 31) --------------------------------------
 SPARSE_ATTN_PAIRS_TOTAL = METRICS.counter(
     "quoracle_sparse_attn_pairs_total",

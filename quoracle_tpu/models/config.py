@@ -70,11 +70,11 @@ class IndexerConfig:
 @dataclasses.dataclass(frozen=True)
 class MoEConfig:
     """Routed experts, with or without a shared expert (the DeepSeek-V3
-    form; ``n_shared`` 0: LFM2's): the router scores ALL ``n_routed``
-    experts; this process HOLDS experts ``held_start .. held_start +
-    n_held`` (its share of an expert-parallel deployment; the whole set
-    when ``n_held == n_routed``) and computes only their part of a token's
-    result plus the shared expert."""
+    form; ``n_shared`` 0: LFM2's, Mellum's): the router scores ALL
+    ``n_routed`` experts; this process HOLDS experts ``held_start ..
+    held_start + n_held`` (its share of an expert-parallel deployment; the
+    whole set when ``n_held == n_routed``) and computes only their part of
+    a token's result plus the shared expert."""
 
     n_routed: int            # the router's width, as published
     n_held: int              # experts held here
@@ -93,9 +93,14 @@ class MoEConfig:
     # what the selected scores' sum takes before the gates are divided by
     # it (``norm_topk``): 1e-20 as DeepSeek-V3 publishes it, 1e-6 at LFM2
     gate_eps: float = 1e-20
+    # what turns a router's logits into scores: "sigmoid", an expert at a
+    # time (DeepSeek-V3, LFM2, Laguna), or "softmax" over all ``n_routed``
+    # (Mellum: softmax, then the top k, then ``norm_topk``)
+    score: str = "sigmoid"
 
     def __post_init__(self):
         assert self.n_routed % self.n_group == 0
+        assert self.score in ("sigmoid", "softmax"), self.score
         assert 0 <= self.held_start \
             and self.held_start + self.n_held <= self.n_routed
 

@@ -32,7 +32,7 @@ from quoracle_tpu.models.config import (
 from quoracle_tpu.models.sampling import sample_tokens
 from quoracle_tpu.models.transformer import (
     ConvTick, KVCache, forward_hidden, forward_hidden_ragged, init_cache,
-    project_logits,
+    moe_stats_len, project_logits,
 )
 
 # Finite mask value: a whole-row -inf would NaN the sampling softmax; the
@@ -313,8 +313,8 @@ def decode_ragged(
     Returns (tokens [R, max_new], n_emitted [R], lens [R], k_pool,
     v_pool, k_scale, v_scale, jstate, moe_stats, state) where lens counts the
     row's valid pool tokens (prompt + chunk + emitted-and-forwarded) and
-    ``moe_stats`` is the expert layers' int32 [4] summed over the steps
-    (transformer.forward_hidden_ragged; None without experts). With
+    ``moe_stats`` is the expert layers' counts summed over the steps
+    (transformer.moe_counts; None without experts). With
     ``k_scale``/``v_scale`` (int8 pools, ISSUE 13; None otherwise, and
     returned as they came) each step's token quantizes on write inside
     the forward."""
@@ -401,7 +401,8 @@ def decode_ragged(
     # is a valid while_loop carry leaf-less node)
     init = (jnp.asarray(1, jnp.int32), done0, tok0, out0, n0, lens0,
             k_pool, v_pool, k_scale, v_scale, rng, jstate0,
-            None if cfg.moe is None else jnp.zeros((4,), jnp.int32), state)
+            None if cfg.moe is None else jnp.zeros(
+                (moe_stats_len(cfg, interpret),), jnp.int32), state)
     # what the loop itself emits carries ``decode_loop`` and no sub-scope
     # (until PR 25: a copy of each loop-carried pool every step)
     with jax.named_scope("decode_loop"):
@@ -2610,17 +2611,23 @@ class GenerateEngine:
         tick_note(real_tokens=int(real), padded_tokens=int(padded))
 
     def _note_moe(self, stats) -> None:
-        """Book one tick's expert-layer counts (the int32 [4] the two
+        """Book one tick's expert-layer counts (the int32 vector the two
         programs return with their outputs: assignments, of them to held
         experts, held experts reached summed over layers and steps,
-        expert layers run), once a tick, on the counters and the tick
-        span."""
+        expert layers run; where the grouped kernel ran them, the blocks
+        it ran and the rows those hold), once a tick, on the counters and
+        the tick span."""
         from quoracle_tpu.infra.telemetry import (
-            MOE_ASSIGNMENTS_TOTAL, MOE_EXPERTS_REACHED_TOTAL,
-            MOE_LAYER_STEPS_TOTAL,
+            MOE_ASSIGNMENTS_TOTAL, MOE_BLOCK_ROWS_TOTAL,
+            MOE_EXPERTS_REACHED_TOTAL, MOE_LAYER_STEPS_TOTAL,
         )
-        total, held, reached, steps = (int(v) for v in stats)
+        total, held, reached, steps, *grouped = (int(v) for v in stats)
         name = self.cfg.name
+        if grouped:
+            blocks, rows = grouped
+            MOE_BLOCK_ROWS_TOTAL.inc(held, model=name, kind="assigned")
+            MOE_BLOCK_ROWS_TOTAL.inc(rows, model=name, kind="run")
+            tick_note(moe_blocks=blocks, moe_block_rows=rows)
         MOE_ASSIGNMENTS_TOTAL.inc(held, model=name, held="true")
         MOE_ASSIGNMENTS_TOTAL.inc(total - held, model=name, held="false")
         MOE_EXPERTS_REACHED_TOTAL.inc(reached, model=name)

@@ -687,7 +687,8 @@ def moe_select(logits: jax.Array, m,
                bias: Optional[jax.Array] = None
                ) -> tuple[jax.Array, jax.Array]:
     """Router logits [T, n_routed] float32 → (experts [T, k] int32, gates
-    [T, k] float32): sigmoid scores; the experts fall into ``n_group``
+    [T, k] float32): scores by ``m.score`` (sigmoid, an expert at a time,
+    or softmax over all ``n_routed``); the experts fall into ``n_group``
     groups, a group scores the sum of its two largest, the ``topk_group``
     best groups stay; the ``k`` largest scores inside them are selected;
     gates are the selected scores over their sum plus ``gate_eps``
@@ -696,7 +697,10 @@ def moe_select(logits: jax.Array, m,
     and experts are chosen by score + bias; the gates are the bare scores
     of the chosen."""
     T, E = logits.shape
-    s = jax.nn.sigmoid(logits.astype(jnp.float32))
+    if m.score == "softmax":
+        s = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+    else:
+        s = jax.nn.sigmoid(logits.astype(jnp.float32))
     pick = s if bias is None else s + bias
     if m.n_group > 1:
         g = pick.reshape(T, m.n_group, E // m.n_group)
@@ -798,15 +802,17 @@ def _routed_experts_grouped(h: jax.Array, idx: jax.Array, gates: jax.Array,
     each block's expert, so the weights read are those the routing
     reached), and each token's sum over its own assignments' rows, gated,
     in float32. A score of device operations a layer where the loop runs
-    eighteen a block. Returns ([T, D] float32, int32 [2, n_held]: each
-    held expert's assignments, and 1 where it has any — what of
-    ``_routed_experts``' four counts differs from layer to layer; the
-    caller sums them and adds the tick's own, ``moe_counts``)."""
+    eighteen a block. Returns ([T, D] float32, int32 [3, n_held]: each
+    held expert's assignments, 1 where it has any, and the blocks of
+    ``moe_block_rows(T)`` rows the kernel ran for it — what of
+    ``_routed_experts``' four counts differs from layer to layer, and what
+    the layout cost; the caller sums them and adds the tick's own,
+    ``moe_counts``)."""
     from quoracle_tpu.ops.grouped_experts import grouped_ffn
     T, D = h.shape
     k, E = m.per_token, m.n_held
     A = T * k
-    blk = min(MOE_BLOCK, -(-T // 16) * 16)
+    blk = moe_block_rows(T)
     NB = min(E, A) + A // blk            # Σ ceil(c_e / blk) is at most this
     local = idx - m.held_start
     held = (local >= 0) & (local < E) & valid[:, None]
@@ -829,18 +835,49 @@ def _routed_experts_grouped(h: jax.Array, idx: jax.Array, gates: jax.Array,
     mine = y.reshape(NB * blk, D)[jnp.minimum(row, NB * blk - 1)]
     out = jnp.where(held[..., None], gates[..., None]
                     * mine.reshape(T, k, D).astype(jnp.float32), 0.0).sum(1)
-    return out, jnp.stack([counts, (counts > 0).astype(jnp.int32)])
+    return out, jnp.stack([counts, (counts > 0).astype(jnp.int32), n_blk])
+
+
+def moe_block_rows(n_tokens: int) -> int:
+    """Rows of a block of the grouped kernel in a forward of ``n_tokens``
+    (a long tick's pieces of ``MOE_TICK`` have ``MOE_BLOCK``)."""
+    return min(MOE_BLOCK, -(-n_tokens // 16) * 16)
+
+
+# What a forward's expert layers report: ``_routed_experts``' four counts
+# and, where the grouped kernel ran them, two more (``moe_counts``).
+MOE_STATS, MOE_STATS_GROUPED = 4, 6
+
+
+def experts_grouped(cfg: ModelConfig, interpret) -> bool:
+    """Whether ``cfg``'s expert layers run as one kernel a layer
+    (``_routed_experts_grouped``): the pattern forward's, on the TPU and
+    where a test asks for the kernels; the loop over blocks elsewhere, and
+    in the latent models, which keep their accepted programs."""
+    from quoracle_tpu.ops.paged_attention import _on_tpu
+    return cfg.latent is None and (bool(interpret) or _on_tpu())
+
+
+def moe_stats_len(cfg: ModelConfig, interpret) -> int:
+    """How many counts ``forward_hidden_ragged`` returns for ``cfg``."""
+    return MOE_STATS_GROUPED if experts_grouped(cfg, interpret) \
+        else MOE_STATS
 
 
 def moe_counts(per_expert: jax.Array, valid: jax.Array, m,
                n_layers: int) -> jax.Array:
     """``_routed_experts``' int32 [4] summed over ``n_layers`` expert
     layers of one forward, from the sum of ``_routed_experts_grouped``'s
-    [2, n_held] over them and the tick's valid tokens."""
+    [3, n_held] over them and the tick's valid tokens, and behind them
+    what the grouped layout cost: the blocks the kernel ran and the rows
+    they hold (a block is one expert's, so 64 experts with a token each
+    are 64 blocks of ``moe_block_rows`` rows whatever the tokens)."""
+    blocks = per_expert[2].sum()
     return jnp.stack([
         valid.sum(dtype=jnp.int32) * (m.per_token * n_layers),
         per_expert[0].sum(), per_expert[1].sum(),
-        valid.any().astype(jnp.int32) * n_layers])
+        valid.any().astype(jnp.int32) * n_layers,
+        blocks, blocks * moe_block_rows(valid.shape[0])])
 
 
 @jax.named_scope("mlp")
@@ -876,7 +913,8 @@ def _moe(x: jax.Array, p: dict, experts: tuple, layer, cfg: ModelConfig,
                 a.reshape(T // MOE_TICK, MOE_TICK, *a.shape[1:])
                 for a in (h, idx, gates, valid)))
             routed = routed.reshape(T, -1)
-            stats = jnp.stack([stats[:, 0].sum(0), stats[:, 1].max(0)])
+            stats = jnp.stack([stats[:, 0].sum(0), stats[:, 1].max(0),
+                               stats[:, 2].sum(0)])
         elif grouped:
             routed, stats = _routed_experts_grouped(
                 h, idx, gates, experts, layer, m, cfg.activation, valid,
@@ -1381,12 +1419,12 @@ def _forward_hidden_ragged_pattern(params, cfg, tokens, positions, k_pool,
     out of the scanned slices. A layer's scopes are the dense forward's;
     where the model names kinds of attention, a layer's are inside one
     named for its kind (``full_attention`` ⊃ ``qkv`` …). Returns the dense
-    function's tuple with the expert layers' int32 [4] (None without
-    experts) and, seventh, the state pool (None without conv layers)."""
-    from quoracle_tpu.ops.paged_attention import _on_tpu, ragged_attend_auto
-    # the experts' blocks in one kernel a layer on the TPU (interpreted
-    # where a test asks for the kernels), the loop over blocks elsewhere
-    grouped = bool(interpret) or _on_tpu()
+    function's tuple with the expert layers' counts (``moe_counts``' six
+    where the grouped kernel ran them, else ``_routed_experts``' four; None
+    without experts) and, seventh, the state pool (None without conv
+    layers)."""
+    from quoracle_tpu.ops.paged_attention import ragged_attend_auto
+    grouped = experts_grouped(cfg, interpret)
     n_groups = len(cfg.kv_groups)
     multi = n_groups > 1
     if not multi:
@@ -1482,7 +1520,8 @@ def _forward_hidden_ragged_pattern(params, cfg, tokens, positions, k_pool,
 
     stats = None
     if cfg.moe is not None:
-        stats = jnp.zeros((2, cfg.moe.n_held) if grouped else (4,), jnp.int32)
+        stats = jnp.zeros((3, cfg.moe.n_held) if grouped else (MOE_STATS,),
+                          jnp.int32)
     carry = (x, tuple(k_pool), tuple(v_pool), recs, stats)
     a0, c0 = [0] * n_groups, 0
     with jax.named_scope("layers"):

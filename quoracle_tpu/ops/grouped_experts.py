@@ -35,13 +35,24 @@ from jax.experimental.pallas import tpu as pltpu
 # of [D, 512] / [512, D], twice each for the pipeline, are 12 MiB at
 # D = 2048.
 WIDTH_SLICES = (512, 384, 256, 128)
+# What an expert's three matrices WHOLE may take of the kernel's 48 MiB,
+# twice each for the pipeline (24.8 MB at 2,304 x 896), beside the block,
+# its float32 sum and the float32 activations between the matmuls.
+WHOLE_BYTES = 32 << 20
 
 
-def width_slice(expert_dim: int) -> int:
+def width_slice(expert_dim: int, dim: int = 0, itemsize: int = 2) -> int:
     """The widest of ``WIDTH_SLICES`` that divides the expert's width,
-    else the width whole (toy sizes)."""
-    return next((s for s in WIDTH_SLICES if expert_dim % s == 0),
-                expert_dim)
+    else the width whole (toy sizes) — and the width whole, too, where
+    only the NARROWEST slice divides it and the expert fits (896 = 7 x 128
+    at a model 2,304 wide): a 128-lane slice of ``[D, F]`` is D runs of
+    256 bytes, seven grid programs a block (PERF.md section 6, PR 41, has
+    both readings)."""
+    s = next((s for s in WIDTH_SLICES if expert_dim % s == 0), expert_dim)
+    if s == WIDTH_SLICES[-1] < expert_dim \
+            and 2 * 3 * dim * expert_dim * itemsize <= WHOLE_BYTES:
+        return expert_dim
+    return s
 
 
 def _ffn_kernel(expert_ref, meta_ref, x_ref, wg_ref, wu_ref, wd_ref,
@@ -86,7 +97,7 @@ def grouped_ffn(
     sum over the width's slices, rounded once at the end."""
     NB, blk, D = x.shape
     F = wg.shape[-1]
-    tf = width_slice(F)
+    tf = width_slice(F, D, wg.dtype.itemsize)
     n_slices = F // tf
     meta = jnp.stack([jnp.asarray(n_blocks, jnp.int32),
                       jnp.asarray(layer, jnp.int32)])

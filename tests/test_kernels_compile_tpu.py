@@ -1447,6 +1447,111 @@ def test_window_programs_at_laguna_widths(on_v5e, monkeypatch):
     assert weight_moves(programs[1][0], 1 << 20) == []
 
 
+# --- every layer's experts behind a softmax router (ISSUE 41) -----------------
+
+@pytest.mark.parametrize("blk,nb", [(16, 72), (256, 192)],
+                         ids=["decode-16", "prefill-256"])
+def test_grouped_experts_compile_at_mellum_widths(on_v5e, blk, nb):
+    """``grouped_ffn`` at a model 2,304 wide and experts 896 wide — the
+    first ``dim`` that is no multiple of 512, the first width no slice of
+    ``WIDTH_SLICES`` but the narrowest divides — at a decode step's block
+    height and a long tick's, with the slice ``width_slice`` keeps for it;
+    the accepted widths keep theirs (512 lanes: ``lfm2``'s 1,536 and
+    ``laguna``'s 1,024 lower the programs they had)."""
+    from quoracle_tpu.ops import grouped_experts as ge
+    assert ge.width_slice(1536) == 512 and ge.width_slice(1024) == 512
+    assert ge.width_slice(896, 2304) == 896 and ge.width_slice(2048) == 512
+    # ... and a model too wide for an expert whole keeps the slices
+    assert ge.width_slice(896, 7168) == 128 and ge.width_slice(128) == 128
+    S, bf = on_v5e, jnp.bfloat16
+    D, F, E, L = 2304, 896, 64, 3
+    compiled = ge.grouped_ffn.lower(
+        S((nb, blk, D), bf), S((L, E, D, F), bf), S((L, E, D, F), bf),
+        S((L, E, F, D), bf), S((), jnp.int32), S((nb,), jnp.int32),
+        S((), jnp.int32), act=jax.nn.silu).compile()
+    assert "routed_experts_ffn" in compiled.as_text()
+
+
+def _narrow_softmax_moe(periods):
+    """Mellum's layer pattern (sliding x 3 then full, no leading layer),
+    head geometry (4 kv heads of 128, 8 query heads to each), window and
+    router under narrow weights."""
+    from quoracle_tpu.models.config import AttnKind, ModelConfig, MoEConfig
+    return ModelConfig(
+        name=f"narrow-softmax-moe-{periods}", vocab_size=512, dim=256,
+        n_layers=4 * periods, n_heads=32, n_kv_heads=4, head_dim=128,
+        ffn_dim=512, norm_eps=1e-6,
+        layer_types=(("sliding_attention",) * 3 + ("full_attention",))
+        * periods,
+        attn_kinds=(("full_attention", AttnKind(
+            32, None, 500000.0, ("yarn", 16.0, 32.0, 1.0, 8192, 1.0, 0.0))),
+            ("sliding_attention", AttnKind(32, 1024, 500000.0))),
+        moe=MoEConfig(n_routed=64, n_held=64, per_token=8, expert_dim=128,
+                      n_shared=0, first_dense=0, score="softmax"),
+        context_window=16384)
+
+
+def test_softmax_moe_programs_carry_both_groups_in_place(on_v5e,
+                                                         monkeypatch):
+    """The Mellum form on the v5e: no leading segment, the period's full
+    layer last. Both programs carry both groups' pools in place through
+    the one scan, with an attention kernel and a grouped-experts kernel a
+    layer of the period and nothing else; two periods and three give the
+    same kernels and fusions."""
+    counts = []
+    for periods in (2, 3):
+        cfg = _narrow_softmax_moe(periods)
+        assert cfg.kv_groups == ((None, periods), (1024, 3 * periods))
+        assert cfg.layer_plan[0] == ((), 0) and cfg.layer_plan[2] == ((), 0)
+        programs, st = _window_programs(on_v5e, monkeypatch, cfg)
+        elems = [n * st.page * 512 for n in (st.n_pages, st.window.n_pages)]
+        for hlo, mem in programs:
+            calls = [ln for ln in hlo.splitlines()
+                     if "tpu_custom_call" in ln]
+            assert sum("%ragged_attend" in c for c in calls) == 4
+            assert sum("%routed_experts_ffn" in c for c in calls) == 4
+            assert len(calls) == 8 and "kv_layout" not in hlo
+            for e in elems:
+                assert pool_moves(hlo, e) == []
+            assert mem.alias_size_in_bytes >= 2 * 2 * (
+                periods * elems[0] + 3 * periods * elems[1])
+        # a layer's expert stack (64 x 3 matrices, 12 MiB here) is read at
+        # [layer, expert] by the kernel, never copied through HBM
+        assert weight_moves(programs[1][0], 1 << 20) == []
+        counts.append([len(re.findall(r" fusion\(", hlo))
+                       for hlo, _ in programs])
+    assert counts[0] == counts[1]
+
+
+@pytest.mark.slow
+def test_softmax_moe_programs_at_mellum_widths(on_v5e, monkeypatch):
+    """The benchmark's `mellum2-12b-a2.5b-l12` at its published widths (`-m
+    slow`: a minute; run by hand before chip time): the chunk forward at
+    the 4,096-token tick and the decode program compile, both groups'
+    pools in place, NO copy of a layer's expert stack (64 x 12.4 MB a
+    matrix triple) or of any other layer's weight through HBM in the
+    decode loop, and arguments plus temporaries under 14 GiB of the
+    chip's 16."""
+    from benchmark import configs
+    from benchmark.families import window_moe_softmax
+    cfg = get_model_config(window_moe_softmax.register(
+        configs.load_config("mellum2-12b-a2.5b-l12")))
+    programs, st = _window_programs(on_v5e, monkeypatch, cfg, tb=4096,
+                                    width=128, max_seq=131072)
+    assert (st.n_pages, st.window.n_pages) == (1367, 457)
+    for hlo, mem in programs:
+        assert hlo.count("tpu_custom_call") == 8
+        for n in (1367, 457):
+            assert pool_moves(hlo, n * st.page * 512) == []
+        assert mem.alias_size_in_bytes >= 2 * st.page * 512 * 2 * (
+            3 * 1367 + 9 * 457)
+        print("mellum AOT: arguments", mem.argument_size_in_bytes,
+              "temporaries", mem.temp_size_in_bytes)
+        assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+                < 14 * 2 ** 30)
+    assert weight_moves(programs[1][0], 1 << 20) == []
+
+
 # --- tp wrappers: shard_map around a pallas_call ----------------------------
 
 

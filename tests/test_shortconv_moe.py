@@ -368,8 +368,14 @@ def test_the_blocks_in_one_kernel_give_the_loops_sum(T, E, held, dtype, tol):
         want, n = tr._routed_experts(h, idx, gates, w, 1, m, "silu", rows)
         got, per_expert = tr._routed_experts_grouped(h, idx, gates, w, 1, m,
                                                      "silu", rows, True)
-        assert [int(v) for v in n] == [int(v) for v in tr.moe_counts(
-            per_expert, rows, m, 1)]
+        counts = [int(v) for v in tr.moe_counts(per_expert, rows, m, 1)]
+        assert [int(v) for v in n] == counts[:4]
+        # and behind them what the layout cost: an expert's assignments
+        # padded up to whole blocks of the tick's height
+        blk = tr.moe_block_rows(T)
+        assert counts[4:] == [sum(-(-int(c) // blk) for c in per_expert[0]),
+                              blk * int(per_expert[2].sum())]
+        assert counts[2] <= counts[4] and counts[1] <= counts[5]
         assert float(jnp.abs(got - want).max()) < tol
         assert not bool(jnp.any(got[~np.asarray(rows)]))
     assert float(jnp.abs(want).max()) == 0 and int(n[2]) == 0
@@ -393,8 +399,11 @@ def test_the_forward_with_its_kernels_is_the_forward_without(toy):
         jnp.asarray(np.arange(1, 5)[None].repeat(8, 0), jnp.int32), meta,
         PAGE + pos, tq=8, conv=conv, interpret=kernels)
         for kernels in (None, True)]
-    for a, b in zip(*[(o[0], o[1][:, 1], o[6], o[5]) for o in outs]):
+    for a, b in zip(*[(o[0], o[1][:, 1], o[6], o[5][:4]) for o in outs]):
         assert float(jnp.abs(a - b).max()) < 1e-4
+    # the kernels' forward says what its blocks cost besides: 48-row blocks
+    blocks, rows = (int(v) for v in outs[1][5][4:])
+    assert blocks >= int(outs[1][5][2]) and rows == 48 * blocks
     assert [int(v) for v in outs[0][5]] == [40 * 2 * 8, 40 * 2 * 8,
                                             int(outs[1][5][2]), 8]
 
