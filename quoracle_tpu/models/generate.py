@@ -1458,8 +1458,8 @@ class GenerateEngine:
         # pages a loop iteration of the decode program's walks carries, as
         # the kernel reckons it: from a page's bytes on one shard, or for a
         # latent pool from the keys a block scores at once (its chunk
-        # forward walks page by page, as the tile kernel does). Only the
-        # tick span's ``attn_walk_steps`` reads it.
+        # forward's walks, a block of 8 queries each, carry the same).
+        # Only the tick span's ``attn_walk_steps`` reads it.
         self._walk_block = decode_walk_pages(
             self.sessions.page,
             cfg.n_kv_heads // (int(mesh.shape["tp"]) if ragged_shard else 1),
@@ -3595,13 +3595,13 @@ class GenerateEngine:
             ragged_tile_walk(walked, page, window),
             ragged_tile_walk(decode, page, window, skip=skip[:, None])))
         # ... and the loop iterations those walks made: a page each in
-        # the tile kernel, a block of pages in the block kernel (every
-        # decode step; the chunk forward where the engine builds no
-        # tiles), so streamed / page / walk_steps is how full they ran
+        # the tile kernel, a block of pages in the block kernel and the
+        # latent kernels (every decode step; the chunk forward where the
+        # engine builds no tiles), so streamed / page / walk_steps is how
+        # full they ran
         block = self._walk_block
         walk_steps = ragged_walk_steps(
-            walked, page, 1 if self._ragged_tile or self.cfg.latent
-            else block, window) \
+            walked, page, 1 if self._ragged_tile else block, window) \
             + ragged_walk_steps(decode, page, block, window,
                                 skip=skip[:, None])
         work = {}

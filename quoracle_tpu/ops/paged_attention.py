@@ -815,8 +815,9 @@ def ragged_tile_walk(tiles, page: int, sliding_window=None,
 def ragged_walk_steps(tiles, page: int, block: int = 1,
                       sliding_window=None, skip=0) -> int:
     """Loop iterations the programs of a tile table make walking their
-    pages ``block`` pages an iteration (``walk_pages``; the tile kernel
-    and a latent pool's walk one), each walk's last block partial."""
+    pages ``block`` pages an iteration (``walk_pages``, or for a latent
+    pool ``latent_walk_pages``; the tile kernel one), each walk's last
+    block partial."""
     pages, _ = _tile_pages(tiles, page, sliding_window, skip)
     return int((-(-pages // block)).sum())
 
@@ -1256,7 +1257,10 @@ def ragged_attend_auto(
 # multi-query attention with one kv head, a key wider than the value, and
 # ONE pool: the kernel streams a page once and uses it for both products.
 # Same flat token-major contract, page tables, block meta and in-kernel
-# normalisation as ``ragged_attend``; no window, no int8 pages.
+# normalisation as ``ragged_attend``; no window, no int8 pages. Both of its
+# calls — the chunk forward's (tq = 8, below) and the decode program's
+# (section "The latent DECODE walk") — move a block of pages a loop turn
+# and attend it as one run of keys.
 
 
 def ragged_attend_latent_ref(
@@ -1302,83 +1306,84 @@ def ragged_attend_latent_ref(
 
 def _ragged_latent_kernel(tables_ref, meta_ref, layer_ref, q_ref, kv_hbm,
                           *refs, page: int, tq: int, v_lanes: int,
-                          scale: float, selected: bool = False):
-    """One tq-token block: stream the owning row's visible latent pages
-    through VMEM double-buffered, ONE DMA a page, and write the normalized
-    output in latent space. The page is the key at its full width and the
-    value at its first ``v_lanes`` lanes. Products take the operands in
-    their stored type with float32 accumulation; softmax is float32.
+                          scale: float, selected: bool, block: int):
+    """One tq-token block of the chunk forward: the owning row's visible
+    latent pages ``block`` a loop turn through ``_walk_blocks`` (the next
+    block's copies in flight, ONE DMA a page), each block attended as one
+    run of ``block·page`` keys (``_latent_block``, section "The latent
+    DECODE walk": the block's tq queries are a group whose every page is
+    common), and the normalized output written in latent space. The page
+    is the key at its full width and the value at its first ``v_lanes``
+    lanes. Products take the operands in their stored type with float32
+    accumulation; softmax is float32.
 
     ``selected``: one more input, the block's rows of the selection
-    ``[1, tq, maxp·page]`` int32, whole in VMEM; a query attends a key only
-    where its row is nonzero. The walk is the causal one — every visible
-    page is streamed and multiplied, the mask decides what the softmax
-    sees — so it costs the dense walk's time."""
+    ``[1, tq, S]`` int32 (S a whole number of blocks), whole in VMEM; a
+    query attends a key only where its row is nonzero. The walk is the
+    causal one — every visible page is streamed and multiplied, the mask
+    decides what the softmax sees — so it costs the dense walk's time.
+
+    Scratch: kv_scr [2·block·page, lanes], two blocks of pages end to end,
+    and a DMA semaphore a page; (m, l, acc)_scr the block's softmax state,
+    query-major [tq·H, ·] (l a sum a lane, as the walks carry it): a
+    carry that size is the register file, and lives here."""
     if selected:
-        sel_ref, out_ref, kv_scr, sems = refs
+        sel_ref, out_ref, kv_scr, sems, m_scr, l_scr, acc_scr = refs
     else:
-        out_ref, kv_scr, sems = refs
+        out_ref, kv_scr, sems, m_scr, l_scr, acc_scr = refs
     i = pl.program_id(0)
     kv_len = meta_ref[0, i]
     qpos0 = meta_ref[1, i]
     nq = meta_ref[2, i]
     row = meta_ref[3, i]
     layer = layer_ref[0]
-    kv_hi = jnp.minimum(kv_len, qpos0 + nq)
-    n = (kv_hi + page - 1) // page
+    keys = block * page
+    # a block with no query walks nothing and writes zeros
+    n = jnp.where(
+        nq > 0, (jnp.minimum(kv_len, qpos0 + nq) + page - 1) // page, 0)
     H, lanes = q_ref.shape[2], q_ref.shape[3]
-    q = q_ref[0].reshape(tq * H, lanes)                  # query-major rows
 
-    def dma(j, slot):
-        return pltpu.make_async_copy(
-            kv_hbm.at[layer, tables_ref[row, j]], kv_scr.at[slot],
-            sems.at[slot])
+    def dmas(j, slot):
+        return [pltpu.make_async_copy(
+            kv_hbm.at[layer, tables_ref[row, j]],
+            kv_scr.at[pl.ds(pl.multiple_of(slot * page, page), page)],
+            sems.at[slot])]
 
-    @pl.when(n > 0)
+    @pl.when(i == 0)
     def _():
-        dma(0, 0).start()
+        # a walk's last block may be partial: the slots behind it are
+        # masked, never copied into, and must hold numbers
+        kv_scr[...] = jnp.zeros(kv_scr.shape, kv_scr.dtype)
 
-    t_of_row = jax.lax.broadcasted_iota(jnp.int32, (tq * H, 1), 0) // H
-    qpos = qpos0 + t_of_row                              # [tq·H, 1]
-    q_ok = t_of_row < nq
+    _start_block(n, block, 0, dmas)
+    m_scr[...] = jnp.full(m_scr.shape, NEG_INF, jnp.float32)
+    l_scr[...] = jnp.zeros(l_scr.shape, jnp.float32)
+    acc_scr[...] = jnp.zeros(acc_scr.shape, jnp.float32)
+    q = q_ref[0].reshape(tq * H, lanes)                  # query-major rows
+    t = jax.lax.broadcasted_iota(jnp.int32, (tq, 1), 0)
 
-    def body(j, carry):
-        m, l, acc = carry
-        slot = jax.lax.rem(j, 2)
-
-        @pl.when(j + 1 < n)
-        def _():
-            dma(j + 1, jax.lax.rem(j + 1, 2)).start()
-
-        dma(j, slot).wait()
-        kv = kv_scr[slot]                                # [page, lanes]
-        s_idx = j * page + jax.lax.broadcasted_iota(
-            jnp.int32, (1, page), 1)
-        valid = (s_idx < kv_len) & (s_idx <= qpos) & q_ok
+    def attend(first, half, left, wait, carry):
+        _each(left, wait)
+        # what the block's tq queries see of its keys, built ONCE at
+        # [tq, keys], then a query's row for each of its H score rows
+        s_idx = first * page + jax.lax.broadcasted_iota(
+            jnp.int32, (1, keys), 1)
+        seen = (s_idx < kv_len) & (s_idx <= qpos0 + t) & (t < nq)
         if selected:
-            # query t's row of the selection, for each of its H score rows
-            sel = sel_ref[0, :, pl.ds(pl.multiple_of(j * page, page), page)]
-            valid = valid & jnp.concatenate(
-                [jnp.broadcast_to(sel[t:t + 1] != 0, (H, page))
-                 for t in range(tq)], axis=0)
-        scores = jax.lax.dot_general(                    # [tq·H, page]
-            q, kv, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
-        scores = jnp.where(valid, scores, NEG_INF)
-        m_new = jnp.maximum(m, jnp.max(scores, axis=1, keepdims=True))
-        p = jnp.where(valid, jnp.exp(scores - m_new), 0.0)
-        corr = jnp.exp(m - m_new)
-        l_new = l * corr + jnp.sum(p, axis=1, keepdims=True)
-        pv = jax.lax.dot_general(                        # [tq·H, v_lanes]
-            p.astype(kv.dtype), kv[:, :v_lanes],
-            (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-        return m_new, l_new, acc * corr + pv
+            seen = seen & (sel_ref[0, :, pl.ds(
+                pl.multiple_of(first * page, keys), keys)] != 0)
+        valid = jnp.concatenate(
+            [jnp.broadcast_to(seen[k:k + 1], (H, keys))
+             for k in range(tq)], axis=0)
+        m_scr[...], l_scr[...], acc_scr[...] = _latent_block(
+            q, kv_scr[pl.ds(pl.multiple_of(half * page, keys), keys)],
+            valid, m_scr[...], l_scr[...], acc_scr[...], scale=scale,
+            v_lanes=v_lanes)
+        return carry
 
-    init = (jnp.full((tq * H, 1), NEG_INF, jnp.float32),
-            jnp.zeros((tq * H, 1), jnp.float32),
-            jnp.zeros((tq * H, v_lanes), jnp.float32))
-    _, l, acc = jax.lax.fori_loop(0, n, body, init)
-    norm = acc / jnp.where(l > 0, l, 1.0)
+    _walk_blocks(n, block, dmas, attend, 0)
+    l = jnp.sum(l_scr[...], axis=1, keepdims=True)
+    norm = acc_scr[...] / jnp.where(l > 0, l, 1.0)
     out_ref[0] = norm.reshape(tq, H, v_lanes).astype(out_ref.dtype)
 
 
@@ -1415,19 +1420,27 @@ def ragged_attend_latent(
     NB = block_meta.shape[1]
     page = pool.shape[2]
     layer = jnp.asarray(layer, jnp.int32).reshape(1)
+    block = walk_block or latent_walk_pages(page)
+    keys, rows, itemsize = block * page, tq * H, pool.dtype.itemsize
     kernel = functools.partial(_ragged_latent_kernel, page=page, tq=tq,
                                v_lanes=v_lanes, scale=scale,
-                               selected=select is not None)
-    more_specs, more, params = [], [], {}
+                               selected=select is not None, block=block)
+    # what the scratch, the blocks in flight, the pipelined q and output
+    # blocks and a block's score tiles take of VMEM (the default scope is
+    # 16 MiB, which 128 heads of 8 queries pass), as the decode walk does
+    need = (2 * keys * lanes * itemsize
+            + 2 * rows * (lanes + v_lanes) * itemsize
+            + rows * (v_lanes + 2 * 128) * 4 + 3 * rows * keys * 4)
+    more_specs, more = [], []
     if select is not None:
-        S = select.shape[1]
+        # a walk's blocks begin at whole blocks of pages and the selection
+        # is read a block at a time: it ends a whole block on
+        S = -(-select.shape[1] // keys) * keys
         more_specs = [pl.BlockSpec((1, tq, S), lambda i, *_: (i, 0, 0))]
-        more = [select.astype(jnp.int32).reshape(NB, tq, S)]
-        # 128 heads of 8 queries and the block's rows of the selection
-        # (0.5 MiB at 16k positions, twice) pass the 16 MiB that Mosaic
-        # scopes by default, by 68 KiB at DeepSeek-V3.2's widths
-        params = {"compiler_params": pltpu.CompilerParams(
-            vmem_limit_bytes=32 << 20)}
+        more = [jnp.pad(select.astype(jnp.int32),
+                        ((0, 0), (0, S - select.shape[1]))
+                        ).reshape(NB, tq, S)]
+        need += 2 * max(tq, 8) * S * 4           # a block pads to 8 sublanes
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -1442,15 +1455,20 @@ def ragged_attend_latent(
                 pl.BlockSpec((1, tq, H, v_lanes),
                              lambda i, *_: (i, 0, 0, 0)),
             ],
-            scratch_shapes=[pltpu.VMEM((2, page, lanes), pool.dtype),
-                            pltpu.SemaphoreType.DMA((2,))],
+            scratch_shapes=[pltpu.VMEM((2 * keys, lanes), pool.dtype),
+                            pltpu.SemaphoreType.DMA((2 * block,)),
+                            pltpu.VMEM((rows, 1), jnp.float32),
+                            pltpu.VMEM((rows, 128), jnp.float32),
+                            pltpu.VMEM((rows, v_lanes), jnp.float32)],
         ),
         out_shape=[jax.ShapeDtypeStruct((NB, tq, H, v_lanes), q.dtype)],
         interpret=interpret,
         # pinned: the trace shows `%ragged_attend_latent.<n>`, which the
         # benchmark's `^%ragged_attend` patterns match
         name="ragged_attend_latent",
-        **params,
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=max(32 << 20, need + (8 << 20))
+            if need > (12 << 20) else None),
     )(row_tables.astype(jnp.int32), block_meta.astype(jnp.int32), layer,
       q.astype(pool.dtype).reshape(NB, tq, H, lanes), pool, *more)[0]
     return out.reshape(NB * tq, H, v_lanes)
@@ -1519,9 +1537,22 @@ def ragged_attend_latent_auto(q, pool, row_tables, block_meta, layer, *,
 #              and masks its H score rows, as in the row's own walk.
 #
 # A row in no group reads shared[0, r] = 0 and walks all of its pages; a
-# zero table is the walk with nothing shared. The chunk forward's call
-# (tq = 8) keeps ``_ragged_latent_kernel``. PERF.md §6, PR 40, has the
+# zero table is the walk with nothing shared. PERF.md §6, PR 40, has the
 # readings.
+#
+# The chunk forward's call (tq = 8, no table: ``_ragged_latent_kernel``)
+# walks the same way since ISSUE 44. Its block of 8 queries IS such a group
+# — 8 members whose every page is common, tq·H score rows (512 at A.X-K1,
+# 1,024 at DeepSeek-V3.2) — and it walked a page a turn with the old
+# arithmetic: a [1,024, 128] score tile scaled, masked twice, one row
+# maximum, one lane reduction of l and one rescale of the [1,024, 512]
+# float32 accumulator a PAGE, each waiting on the last (PERF.md §6, PR 44,
+# has the reading against the MXU's time for the page's multiplies). It now
+# moves ``latent_walk_pages`` pages a turn through ``_walk_blocks`` and
+# attends them as one run of keys through ``_latent_block``, its (m, l, acc)
+# in VMEM scratch as a group's; a block's visibility is built once at
+# [tq, keys] (kv_len, the causal mask, nq, the queries' rows of the
+# selection) and a query's row of it broadcast to its H score rows.
 
 # Members a group's walk is compiled at: a group takes the first that
 # holds it, so an odd one multiplies one member's rows for nothing (0.2 µs

@@ -1034,40 +1034,94 @@ def mosaic_bodies(lowered) -> list:
     return out
 
 
-# sha256 of the chunk forward's kernels at DeepSeek-V3.2's widths, read off
-# the parent commit (829012b) with this same reader: the decode walk is a
-# kernel of its own and leaves these three as they were
-CHUNK_KERNELS = {
-    "latent-selected": "0955089bf7cccd6c",
-    "latent": "31338baab7ec2708",
+# sha256 of the DECODE program's latent kernels at the two latent cells'
+# widths (and of the scoring kernel's chunk-forward call), read off the
+# parent commit (79fda53) with this same reader: the chunk forward's latent
+# kernel was rewritten to walk a block of pages a turn (ISSUE 44) and
+# leaves these as they were
+UNTOUCHED_KERNELS = {
+    "latent-decode-selected": "142631240b228f79",
+    "latent-decode": "ca973008759f4348",
+    "index-decode": "bf86b017ce0c2348",
     "index-scores": "af1c4f58cd154a0e",
 }
 
 
-@pytest.mark.parametrize("kernel", sorted(CHUNK_KERNELS))
-def test_the_chunk_forwards_kernels_are_the_text_they_were(on_v5e, kernel):
-    """The chunk forward's calls (tq = 8, no table) lower to the Mosaic
-    text they had before the decode walk got its own kernel."""
+@pytest.mark.parametrize("kernel", sorted(UNTOUCHED_KERNELS))
+def test_the_kernels_beside_the_chunk_walk_are_the_text_they_were(on_v5e,
+                                                                  kernel):
+    """The decode calls (tq = 1, with the shared-walk table: the latent
+    walk with and without a selection, the scoring walk) and the scoring
+    kernel's chunk-forward call (tq = 8) lower to the Mosaic text they had
+    before the chunk forward's latent kernel got the block walk."""
     import hashlib
     S = on_v5e
-    nb = 128
-    tables, meta = S((8, 128), jnp.int32), S((4, nb), jnp.int32)
     if kernel == "index-scores":
+        nb = 128
         lowered = jax.jit(functools.partial(pa.index_scores, tq=8)).lower(
             S((nb * 8, 64, 128), jnp.bfloat16), S((nb * 8, 64), jnp.float32),
-            S((5, 512, PAGE, 128), jnp.bfloat16), tables, meta,
-            S((), jnp.int32))
+            S((5, 512, PAGE, 128), jnp.bfloat16), S((8, 128), jnp.int32),
+            S((4, nb), jnp.int32), S((), jnp.int32))
     else:
-        heads, kw = (128, {"select": S((nb * 8, 128 * PAGE), jnp.int32)}) \
-            if kernel == "latent-selected" else (64, {})
-        lowered = jax.jit(functools.partial(
-            pa.ragged_attend_latent, tq=8, v_lanes=512, scale=0.13)).lower(
-            S((nb * 8, heads, 640), jnp.bfloat16),
-            S((5, 512, PAGE, 640), jnp.bfloat16), tables, meta,
-            S((), jnp.int32), **kw)
+        rows = 8
+        tables, meta = S((rows, 128), jnp.int32), S((4, rows), jnp.int32)
+        shared = S((2 + pa.SHARED_ROWS, rows), jnp.int32)
+        if kernel == "index-decode":
+            lowered = jax.jit(functools.partial(
+                pa.index_scores, tq=1)).lower(
+                S((rows, 64, 128), jnp.bfloat16), S((rows, 64), jnp.float32),
+                S((5, 512, PAGE, 128), jnp.bfloat16), tables, meta,
+                S((), jnp.int32), shared=shared)
+        else:
+            heads, kw = (128, {"select": S((rows, 128 * PAGE), jnp.int32)}) \
+                if kernel == "latent-decode-selected" else (64, {})
+            lowered = jax.jit(functools.partial(
+                pa.ragged_attend_latent, tq=1, v_lanes=512,
+                scale=0.13)).lower(
+                S((rows, heads, 640), jnp.bfloat16),
+                S((5, 512, PAGE, 640), jnp.bfloat16), tables, meta,
+                S((), jnp.int32), shared=shared, **kw)
     (body,) = mosaic_bodies(lowered)
     assert hashlib.sha256(body.encode()).hexdigest()[:16] \
-        == CHUNK_KERNELS[kernel]
+        == UNTOUCHED_KERNELS[kernel]
+
+
+# --- the latent chunk forward's block walk (ISSUE 44) ------------------------
+
+@pytest.mark.parametrize("heads,selected,code_max,vmem_max", [
+    # the parent's page-a-turn bodies were 557,056 and 285,696 bytes (AOT,
+    # PR 44); a prefill program holds two instances, and the PR keeps the
+    # growth under 0.5 MB a program
+    (128, True, 820_000, 32 << 20),
+    (64, False, 460_000, None),
+], ids=["deepseek-v3.2", "ax-k1"])
+def test_latent_chunk_walk_compiles_within_its_code_and_vmem(
+        on_v5e, heads, selected, code_max, vmem_max):
+    """The chunk forward's latent call (tq = 8, no table) at both latent
+    configurations' widths, 128 blocks against tables 128 wide: four pages
+    a turn as one run of 512 keys, the block's (m, l, acc) in scratch.
+    ONE body a program: its code stays within a quarter of a MB of the
+    page-a-turn body's, and it asks for 32 MiB of scoped VMEM at 1,024
+    score rows under a selection over 16k positions and for no more than
+    the default 16 MiB at 512."""
+    S = on_v5e
+    nb = 128
+    kw = {"select": S((nb * 8, 128 * PAGE), jnp.int32)} if selected else {}
+    lowered = jax.jit(functools.partial(
+        pa.ragged_attend_latent, tq=8, v_lanes=512, scale=0.13)).lower(
+        S((nb * 8, heads, 640), jnp.bfloat16),
+        S((5, 512, PAGE, 640), jnp.bfloat16), S((8, 128), jnp.int32),
+        S((4, nb), jnp.int32), S((), jnp.int32), **kw)
+    assert pa.latent_walk_pages(PAGE) == 4
+    scoped = re.findall(r'scoped_memory_configs\\22: \[\{[^}]*'
+                        r'\\22size\\22: (\d+)', lowered.as_text())
+    assert [int(x) for x in scoped] == ([vmem_max] if vmem_max else [])
+    compiled = lowered.compile()
+    call = _custom_calls(compiled.as_text())
+    assert len(call) == 1
+    assert re.match(r"\s*%ragged_attend_latent(\.\d+)? = ", call[0])
+    assert compiled.memory_analysis().generated_code_size_in_bytes \
+        < code_max
 
 
 # --- a latent model's output projection reads wo[layer] where it lies -------

@@ -265,6 +265,73 @@ def test_latent_kernel_is_its_reference(tq):
     assert np.abs(np.asarray(got - want)).max() < 1e-5
 
 
+# -- the chunk forward's walk, a block of pages a turn (ISSUE 44) -------------
+#
+# A case: rows of (resident tokens with the chunk written, the chunk's first
+# position, its queries), cut into blocks of 8 queries as the engine cuts
+# them, over tables 8 pages wide; ``blocks`` are block-meta columns added as
+# they stand (kv_len, qpos0, nq, row).
+CHUNK_WALK_CASES = {
+    # 8 pages: whole blocks at 1, 2 and 4 pages a turn
+    "whole-blocks": dict(rows=[(1024, 1008, 16)]),
+    # 6, 3 and 5 pages: the last block of a walk holds 2, 1 or 3 of 4
+    "a-partial-last-block": dict(
+        rows=[(768, 744, 24), (384, 376, 8), (640, 600, 40)]),
+    # the last page holds 17 tokens; another row's whole context is 5
+    "a-kv-len-inside-a-page": dict(rows=[(401, 393, 8), (5, 0, 5)]),
+    # 11 queries: a block of 8 and one of 3; a row of one query
+    "fewer-queries-than-the-block": dict(
+        rows=[(300, 289, 11), (700, 699, 1)]),
+    # a block with resident tokens and no query, and an inert one
+    "a-block-with-no-query": dict(
+        rows=[(520, 512, 8)], blocks=[(520, 512, 0, 0), (0, 0, 0, 0)]),
+    # a chunk that begins and ends anywhere: its blocks straddle a page
+    # boundary (queries 125..132 see one page, then two), the chunk's own
+    # tokens past a block's last query are written and hidden
+    "a-chunk-cut-anywhere": dict(
+        rows=[(650, 125, 77), (1000, 509, 11)]),
+}
+
+
+def chunk_walk_case(case, rng, tq=8, lanes=256, width=8):
+    """(pool, tables, block meta) of a ``CHUNK_WALK_CASES`` entry."""
+    meta = []
+    for r, (kv, first, nq) in enumerate(case["rows"]):
+        meta += [(kv, first + b * tq, min(tq, nq - b * tq), r)
+                 for b in range(-(-nq // tq))]
+    meta += case.get("blocks", [])
+    R = len(case["rows"])
+    tables = rng.permutation(R * width + 1)[:R * width].reshape(R, width)
+    pool = jnp.asarray(rng.normal(size=(2, R * width + 1, PAGE, lanes)),
+                       jnp.float32)
+    return (pool, jnp.asarray(tables, jnp.int32),
+            jnp.asarray(np.array(meta).T, jnp.int32))
+
+
+@pytest.mark.parametrize("walk_block", [1, 2, None],
+                         ids=["a-page-a-turn", "two-pages", "as-served"])
+@pytest.mark.parametrize("case", sorted(CHUNK_WALK_CASES))
+def test_latent_chunk_walk_is_its_reference(case, walk_block):
+    """The chunk forward's latent call (tq = 8, interpret mode) at A.X-K1's
+    64 heads against the gather reference, which knows no walk: a block of
+    pages attended as one run of keys gives what the reference gives,
+    whatever the pages a turn; a block with no query writes zeros."""
+    rng = np.random.default_rng(44)
+    pool, tables, bm = chunk_walk_case(CHUNK_WALK_CASES[case], rng)
+    H = 64
+    q = jnp.asarray(rng.normal(size=(bm.shape[1] * 8, H, pool.shape[-1])),
+                    jnp.float32)
+    kw = dict(tq=8, v_lanes=128, scale=0.07)
+    got = pa.ragged_attend_latent(q, pool, tables, bm, 1, interpret=True,
+                                  walk_block=walk_block, **kw)
+    want = pa.ragged_attend_latent_ref(q, pool, tables, bm, 1, **kw)
+    assert np.abs(np.asarray(got - want)).max() < 1e-5
+    idle = np.repeat(np.asarray(bm[2]), 8) <= np.tile(np.arange(8),
+                                                      bm.shape[1])
+    assert not np.asarray(got)[idle].any()
+    assert np.asarray(got)[~idle].any(axis=(1, 2)).all()
+
+
 # -- the decode call's shared walk (ISSUE 40) --------------------------------
 #
 # A case: 8 one-token rows; ``groups`` [(rows, common pages)]: those rows'
@@ -703,10 +770,10 @@ def test_a_latent_tick_counts_the_walk_its_kernel_made(engine):
     assert args["attn_tiles"] == len(chunk) + len(dec)
     assert args["attn_kv_streamed"] == PAGE * (sum(chunk) + sum(dec))
     assert args["attn_kv_streamed"] > 3 * args["attn_kv_reads"] > 0
-    # a latent pool's chunk forward turns once a page, its decode walk
-    # once a block of ``latent_walk_pages`` (every row's pages fit one)
-    assert engine._walk_block >= max(dec)
-    assert args["attn_walk_steps"] == sum(chunk) + len(dec)
+    # a latent pool's walks, the chunk forward's and the decode steps',
+    # turn once a block of ``latent_walk_pages`` (every walk here fits one)
+    assert engine._walk_block >= max(dec + chunk)
+    assert args["attn_walk_steps"] == len(chunk) + len(dec)
     # nothing in common: no row in a group, no page walked for two
     assert args["attn_shared_rows"] == args["attn_shared_pages"] == 0
 

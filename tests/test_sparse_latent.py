@@ -23,8 +23,8 @@ from quoracle_tpu.models import transformer as tr
 from quoracle_tpu.models.config import MoEConfig, get_model_config
 from quoracle_tpu.ops import paged_attention as pa
 from tests.test_latent_moe import (
-    RAW as AXK1, SHARED_WALK_CASES, f32, serves_the_parents_tokens,
-    shared_walk_case,
+    CHUNK_WALK_CASES, RAW as AXK1, SHARED_WALK_CASES, chunk_walk_case, f32,
+    serves_the_parents_tokens, shared_walk_case,
 )
 
 TOL = 2e-4
@@ -387,6 +387,41 @@ def test_latent_kernel_honours_a_selection_as_its_reference(tq):
     p = np.exp(sc - sc.max(-1, keepdims=True))
     hand = (p / p.sum(-1, keepdims=True)) @ rows[:, :128]
     assert np.abs(hand - np.asarray(want[i])).max() < 1e-4
+
+
+@pytest.mark.parametrize("walk_block", [1, 2, None],
+                         ids=["a-page-a-turn", "two-pages", "as-served"])
+@pytest.mark.parametrize("case", sorted(CHUNK_WALK_CASES))
+def test_latent_chunk_walk_honours_each_querys_selection(case, walk_block):
+    """The chunk forward's latent call under a selection at DeepSeek-V3.2's
+    128 heads (tq = 8, interpret mode) against the gather reference: a
+    block's visibility is built once for its 8 queries and each query's
+    row of it masks its own 128 score rows — query 1 of every block keeps
+    NOTHING of the first four pages (a whole block of keys, as served),
+    its neighbours about a third of them."""
+    rng = np.random.default_rng(45)
+    pool, tables, bm = chunk_walk_case(CHUNK_WALK_CASES[case], rng)
+    NB, H = bm.shape[1], 128
+    q = jnp.asarray(rng.normal(size=(NB * 8, H, pool.shape[-1])),
+                    jnp.float32)
+    select = (rng.random((NB * 8, tables.shape[1] * PAGE)) < 0.3
+              ).astype(np.int32)
+    select[1::8, :4 * PAGE] = 0
+    kw = dict(tq=8, v_lanes=128, scale=0.07, select=jnp.asarray(select))
+    got = pa.ragged_attend_latent(q, pool, tables, bm, 1, interpret=True,
+                                  walk_block=walk_block, **kw)
+    want = pa.ragged_attend_latent_ref(q, pool, tables, bm, 1, **kw)
+    assert np.abs(np.asarray(got - want)).max() < 1e-5
+    dense = pa.ragged_attend_latent_ref(q, pool, tables, bm, 1,
+                                        **{**kw, "select": None})
+    vis = seen(bm, 8, tables.shape[1] * PAGE)
+    kept = (vis & (select != 0)).sum(axis=1)
+    some = (kept > 0) & (kept < vis.sum(axis=1))
+    assert some.sum() >= 4
+    assert np.abs(np.asarray(dense - want))[some].max() > 0.05
+    # a query whose every visible key was dropped attends nothing
+    none = vis.any(axis=1) & (kept == 0)
+    assert not np.asarray(got)[none].any()
 
 
 @pytest.mark.parametrize("case", sorted(SHARED_WALK_CASES))

@@ -661,9 +661,46 @@ def test_latent_decode_tick_books_the_shared_walk(monkeypatch):
     walked = steps * turns(n_shared) + sum(
         turns(n - n_shared) for row in pages for n in row)
     assert off["attn_walk_steps"] - on["attn_walk_steps"] == alone - walked
-    # the chunk forward's walks are a page a turn in both
+    # the chunk forward's walks are the same in both
     chunk = off["attn_walk_steps"] - alone
     assert chunk > 0 and chunk == on["attn_walk_steps"] - walked
+
+
+def test_latent_chunk_forward_counts_a_block_of_pages_a_walk_step():
+    """A latent chunk forward's ``attn_walk_steps`` (ISSUE 44): its kernel
+    walks a row's visible pages once per block of 8 queries,
+    ``latent_walk_pages`` pages a loop turn, so a tick with no decode step
+    counts Σ ⌈pages / 4⌉ over its blocks where it streams Σ pages."""
+    import numpy as np
+
+    from benchmark.families import latent_moe
+    from quoracle_tpu.models.generate import RAGGED_TQ
+    from quoracle_tpu.models.tokenizer import get_tokenizer
+    from quoracle_tpu.ops import paged_attention as pa
+    from tests.test_latent_moe import RAW
+    raw = {**RAW, "name": "toy-axk1-chunks",
+           "serving": dict(context_window=2048, output_limit=128)}
+    cfg = get_model_config(latent_moe.register(raw))
+    params = init_params(cfg, jax.random.PRNGKey(3), dtype=jnp.float32)
+    eng = GenerateEngine(cfg, params, get_tokenizer("tiny"), max_seq=2048,
+                         prompt_buckets=(256, 512, 1024, 2048))
+    page, rng = 128, np.random.default_rng(44)
+    block = eng._walk_block
+    assert block == pa.latent_walk_pages(page) == 4
+    # 6 pages + 3 tokens, a page and a half, under a block of queries
+    rows = [[int(t) for t in rng.integers(3, 512, n)] for n in (771, 200, 5)]
+    telemetry.tick_open("m")
+    try:
+        eng.generate(rows, temperature=0.0, max_new_tokens=1)
+    finally:
+        args = telemetry.tick_close().args
+    pages = [-(-min(len(r), (b + 1) * RAGGED_TQ) // page) for r in rows
+             for b in range(-(-len(r) // RAGGED_TQ))]
+    assert max(pages) == 7 and min(pages) == 1
+    assert args["attn_tiles"] == len(pages)
+    assert args["attn_kv_streamed"] == page * sum(pages)
+    assert args["attn_walk_steps"] == sum(-(-n // block) for n in pages) \
+        < sum(pages)
 
 
 # ---------------------------------------------------------------------------
