@@ -3554,8 +3554,8 @@ class GenerateEngine:
         none) and the decode program's ``shared`` walk table (None:
         none)."""
         from quoracle_tpu.ops.paged_attention import (
-            ragged_tile_walk, ragged_walk_steps, shared_walk_steps,
-            shared_walk_tokens,
+            decode_walks, ragged_tile_walk, ragged_walk_steps,
+            shared_walk_steps, shared_walk_tokens,
         )
         # what the attention kernel had to do this tick, for its roofline
         # (a reader's lower bounds): resident tokens streamed — each row's
@@ -3604,6 +3604,15 @@ class GenerateEngine:
             walked, page, 1 if self._ragged_tile else block, window) \
             + ragged_walk_steps(decode, page, block, window,
                                 skip=skip[:, None])
+        # ... and the walks themselves, a decode step's: a row's own and
+        # a group's shared one; the dense block kernel starts the first
+        # block of every walk of a call but its first while the walk
+        # before it attends its last (a latent pool's kernels start each
+        # cold)
+        n_walks, n_ahead = decode_walks(decode, page, window,
+                                        skip[:, None], shared)
+        if self.cfg.latent is not None:
+            n_ahead = 0
         work = {}
         if shared is not None:
             from quoracle_tpu.infra.telemetry import (
@@ -3621,7 +3630,8 @@ class GenerateEngine:
                 shared_in * layers, model=self.cfg.name, kind="walked")
         work.update(attn_kv_reads=kv_reads, attn_pairs=pairs,
                     attn_kv_streamed=streamed, attn_tiles=n_tiles,
-                    attn_walk_steps=walk_steps)
+                    attn_walk_steps=walk_steps, attn_walks=n_walks,
+                    attn_walks_started_ahead=n_ahead)
         return work
 
     def _json_table_device(self, enum_set: tuple):

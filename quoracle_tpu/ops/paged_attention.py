@@ -212,28 +212,36 @@ def _ragged_kernel(tables_ref, meta_ref, layer_ref, *refs, page: int,
     ``shared`` (the decode program, tq = 1, block i = row i): one more
     prefetched table, ``shared_ref [2 + SHARED_ROWS, R]`` (``shared_walks``),
     q a second time whole in HBM, and the shared walk's scratch; see the
-    section "The SHARED walk" below."""
+    section "The SHARED walk" below.
+
+    A walk's FIRST block is started by the walk before it (section "The
+    walk started ahead"): ``ahead_ref`` (SMEM scratch, [2]) carries from
+    one grid program to the next which half of the scratch the next walk
+    begins in and which program's first walk is in flight."""
     if shared:
         shared_ref, q_ref, q_hbm, k_hbm, v_hbm, *refs = refs
     else:
         q_ref, k_hbm, v_hbm, *refs = refs
     if quant:
         ks_hbm, vs_hbm, out_ref, k_scr, v_scr, ks_scr, vs_scr, sems, \
-            *walk_scr = refs
+            ahead_ref, *walk_scr = refs
         streams = ((k_hbm, k_scr), (v_hbm, v_scr),
                    (ks_hbm, ks_scr), (vs_hbm, vs_scr))
     else:
-        out_ref, k_scr, v_scr, sems, *walk_scr = refs
+        out_ref, k_scr, v_scr, sems, ahead_ref, *walk_scr = refs
         streams = ((k_hbm, k_scr), (v_hbm, v_scr))
     s_scr = walk_scr[-1]                 # a block's score tiles
     i = pl.program_id(0)
+    last = meta_ref.shape[1] - 1
     kv_len = meta_ref[0, i]
     qpos0 = meta_ref[1, i]
     nq = meta_ref[2, i]
-    row = meta_ref[3, i]
     layer = layer_ref[0]
     H = q_ref.shape[2]
     G = H // n_kv
+    # a shared walk's pages are a row's FIRST: under a window, whose first
+    # page differs by row, nothing is shared, whatever the table
+    sharing = shared and window < 0
 
     def page_dmas(row, j, slot):
         pid = tables_ref[row, j]
@@ -241,12 +249,62 @@ def _ragged_kernel(tables_ref, meta_ref, layer_ref, *refs, page: int,
                                       sems.at[slot, s])
                 for s, (hbm, scr) in enumerate(streams)]
 
-    # last visible key + 1: nothing past the block's last query is visible
-    kv_hi = jnp.minimum(kv_len, qpos0 + nq)
-    if window >= 0:
-        p_lo = jnp.maximum(qpos0 + 1 - window, 0) // page
-    else:
-        p_lo = jnp.int32(0)
+    # Every walk of the call is known to every program: the tables are
+    # scalars in SMEM, program j's as readable from program i as its own.
+    def own_walk(j):
+        """(row, first page, pages) of program j's walk of ITS row's
+        pages: those its last query sees, behind the pages a shared walk
+        covers for it; a program with no query (padding; a row that is
+        done) walks nothing."""
+        kv_len, qpos0, nq, row = (meta_ref[a, j] for a in range(4))
+        # last visible key + 1: nothing past the block's last query is
+        kv_hi = jnp.minimum(kv_len, qpos0 + nq)
+        if window >= 0:
+            p_lo = jnp.maximum(qpos0 + 1 - window, 0) // page
+        elif shared:
+            p_lo = shared_ref[0, j]
+        else:
+            p_lo = jnp.int32(0)
+        n = jnp.where(nq > 0,
+                      jnp.maximum((kv_hi + page - 1) // page - p_lo, 0), 0)
+        return row, p_lo, n
+
+    def shared_pages(j):
+        """(members, pages) of the shared walk program j makes: 0 pages
+        where it leads no group, or none of its group has a query."""
+        members = [shared_ref[2 + k, j] for k in range(SHARED_ROWS)]
+        live = meta_ref[2, members[0]]
+        for r in members[1:]:
+            live = jnp.maximum(live, meta_ref[2, r])
+        return members, jnp.where((shared_ref[1, j] > 0) & (live > 0),
+                                  shared_ref[0, j], 0)
+
+    def first_of(own, common):
+        """(row, first page, pages) of the walk a program makes FIRST:
+        its group's ``common`` pages where it walks them, else its own."""
+        row, p_lo, n = own
+        if not sharing:
+            return own
+        return (row, jnp.where(common > 0, 0, p_lo),
+                jnp.where(common > 0, common, n))
+
+    def first_walk(j):
+        return first_of(own_walk(j), shared_pages(j)[1] if sharing else 0)
+
+    def walk_dmas(walk, after):
+        """``dmas(j, slot)`` of a walk of this program that starts the
+        first block of the walk ``after`` it behind its own last block
+        (``_walk_blocks``' ``n_after``): page j of the walk, and from the
+        end of its whole blocks on the pages of ``after``."""
+        row, p_lo, n = walk
+        end = (n + block - 1) // block * block
+
+        def dmas(j, slot):
+            own = j < end
+            return page_dmas(jnp.where(own, row, after[0]),
+                             jnp.where(own, p_lo, after[1] - end) + j, slot)
+
+        return dmas
 
     def page_blocks(half, kv):
         """What ``_attend_block`` reads of a block's i-th page, in scratch
@@ -260,33 +318,53 @@ def _ragged_kernel(tables_ref, meta_ref, layer_ref, *refs, page: int,
                 (lambda i: vs_scr[half + i, kv:kv + 1, :]) if quant
                 else None)
 
+    row, p_lo, n = own = own_walk(i)
+    members, common = shared_pages(i) if sharing else (None, 0)
+    walks = (common > 0) | (n > 0)
+    # what runs after this program's walks: the first walk of the next
+    # program that has one (section "The walk started ahead"; a program
+    # that walks nothing starts nothing, and does not look)
+    nxt, *after = jax.lax.while_loop(
+        lambda c: walks & (c[3] == 0) & (c[0] < last),
+        lambda c: (c[0] + 1, *first_walk(c[0] + 1)),
+        (i, jnp.int32(0), jnp.int32(0), jnp.int32(0)))
+    # ... and which half of the scratch each of its own begins in. The
+    # first program with a walk starts cold, as every program used to.
+    ahead = (i > 0) & (ahead_ref[1] == i)
+    half_sh = jnp.where(ahead, ahead_ref[0], 0)
+    half_own = jax.lax.rem(half_sh + (common + block - 1) // block, 2)
+    half_after = jax.lax.rem(half_own + (n + block - 1) // block, 2)
+
+    first_row, first_lo, first_n = first_of(own, common)
+    _start_block(jnp.where(walks & ~ahead, first_n, 0), block, 0,
+                 lambda j, slot: page_dmas(first_row, first_lo + j, slot),
+                 half_sh)
+    # (SMEM scratch holds whatever the last call left: the first program
+    # reads none of it, and leaves "none started" where it starts none)
+    ahead_ref[0] = jnp.where(walks, half_after, ahead_ref[0])
+    ahead_ref[1] = jnp.where(walks & (after[2] > 0), nxt,
+                             jnp.where(walks | (i == 0), -1, ahead_ref[1]))
+
     carried = None
-    if shared and window < 0:
-        # pages a shared walk covers for this row: its own walk starts
-        # behind them, from the state the walk left for it. (A window's
-        # first page differs by row: nothing is shared, whatever the table.)
-        p_lo = shared_ref[0, i]
+    if sharing:
         m_st, l_st, acc_st = walk_scr[5:8]       # the rows' parked state
-        members = [shared_ref[2 + k, i] for k in range(SHARED_ROWS)]
-        live = meta_ref[2, members[0]]
-        for r in members[1:]:
-            live = jnp.maximum(live, meta_ref[2, r])
 
-        @pl.when((shared_ref[1, i] > 0) & (live > 0))
+        @pl.when(common > 0)
         def _():
-            _shared_walk(members, p_lo, functools.partial(page_dmas, row),
-                         page_blocks, q_hbm, walk_scr[:-1], s_scr,
-                         n_kv=n_kv, G=G, scale=scale, block=block)
+            # behind its last block the leader's own walk is next, or
+            # where it has none the next program's
+            then = tuple(jnp.where(n > 0, a, b) for a, b in zip(own, after))
+            _shared_walk(members, common,
+                         walk_dmas((row, 0, common), then), page_blocks,
+                         q_hbm, walk_scr[:-1], s_scr, n_kv=n_kv, G=G,
+                         scale=scale, block=block, half0=half_sh,
+                         n_after=then[2])
 
+        # pages a shared walk covers for this row: its own walk starts
+        # behind them, from the state the walk left for it
         carried = (p_lo > 0) & (nq > 0)
-    # a block with no query (padding; a row that is done) walks nothing
-    n = jnp.where(nq > 0,
-                  jnp.maximum((kv_hi + page - 1) // page - p_lo, 0), 0)
 
-    def dmas(j, slot):
-        return page_dmas(row, p_lo + j, slot)
-
-    _start_block(n, block, 0, dmas)
+    dmas = walk_dmas(own, after)
 
     q = q_ref[0].astype(jnp.float32) * scale             # [tq, H, hd]
 
@@ -329,7 +407,8 @@ def _ragged_kernel(tables_ref, meta_ref, layer_ref, *refs, page: int,
                      for st, new in zip(parked, fresh()))
 
     final = _walk_blocks(n, block, dmas, attend,
-                         tuple(init(kv) for kv in range(n_kv)))
+                         tuple(init(kv) for kv in range(n_kv)),
+                         half0=half_own, n_after=after[2])
     for kv in range(n_kv):
         _, l, acc = final[kv]
         norm = acc / jnp.where(l > 0, l, 1.0)
@@ -372,8 +451,10 @@ def ragged_attend(
     tables begin alike have their common pages walked once between them
     (section "The SHARED walk"): the same output, fewer page reads. The
     block kernel's walk carries ``walk_pages`` of the page's bytes a loop
-    iteration (section "The BLOCK walk"); ``walk_block`` is the tests' way
-    to the walk of one page an iteration, which nothing else asks for."""
+    iteration (section "The BLOCK walk"), and each of its walks starts the
+    first block of the walk that runs after it (section "The walk started
+    ahead"); ``walk_block`` is the tests' way to the walk of one page an
+    iteration, which nothing else asks for."""
     Tp, H, hd = q.shape
     NB = block_meta.shape[1]
     _, n_pages, page, lanes = k_pool.shape
@@ -483,6 +564,7 @@ def ragged_attend(
             ],
             scratch_shapes=[*scratch,
                             pltpu.SemaphoreType.DMA((2 * block, len(pools))),
+                            pltpu.SMEM((2,), jnp.int32),   # started ahead
                             *more_scr,
                             pltpu.VMEM((KV, block, max(
                                 tq, SHARED_ROWS if shared is not None else 0)
@@ -546,14 +628,63 @@ def _lane_geometry(n_kv: int, head_dim: int) -> tuple:
 # A walk's last block is partial: pages past the walk's end are neither
 # copied nor attended (every loop is bounded by the pages left, none over
 # a masked page of stale scratch), so a walk of fewer than B pages costs
-# what it did. The loops' bodies are a page each — the kernel holds two
-# matmuls a kv head as it always did, not B of them.
+# what it did (less, since ISSUE 45, the wait for its first block: the
+# next section says what a grid program costs now). The loops' bodies are
+# a page each — the kernel holds two matmuls a kv head as it always did,
+# not B of them.
 #
 # B is a function of what the kernel can see (``walk_pages``): the bytes of
 # a page in one stream, so that a block in flight is half a MiB a stream —
 # what covers the latency at the bandwidth; on the chip larger blocks read
 # level or worse (PERF.md §6) — and the two blocks of K and V stay within
 # 2 MiB of the 16 MiB of scoped VMEM.
+
+# ---------------------------------------------------------------------------
+# The walk started ahead (ISSUE 45): no walk waits for its first block cold
+# ---------------------------------------------------------------------------
+#
+# Inside a walk block b + 1 is in flight while block b is attended, but a
+# walk's FIRST block used to be started by the walk itself and waited for
+# with nothing to hide it behind: once a grid program, twice in a leader's
+# (its shared walk, then its own). The decode call is 8 programs of walks of
+# 1–3 blocks, so most blocks of a call were first blocks.
+#
+# In the block kernel (``_ragged_kernel``) every walk's first block is
+# started by the walk that runs BEFORE it, as that walk's "next block": the
+# block behind a walk's last is the first block of what runs next — in a
+# leader's program its own walk behind its shared one, else the first walk
+# of the next program that walks anything (its shared walk if it leads a
+# live group, else its own; programs with no query are passed over). The
+# copies start where a next block's always did, before the last block is
+# attended and into the half of the two-block scratch it does not occupy:
+# the same ``_start_block`` of the same loop turn, with a row, a first page
+# and a count chosen by scalars (``walk_dmas``, ``_walk_blocks``'
+# ``n_after``), so the loop holds no branch more than it did. Every
+# program can work out every other's walks: tables, block meta and the
+# shared-walk table are scalars in SMEM. Two more scalars, in SMEM scratch
+# that outlives a grid step (the grid's one dimension is sequential),
+# carry what a program cannot know from the tables alone: which half the
+# next walk begins in (the parity of the blocks attended so far), and
+# which program's first walk is in flight. The first program of a call
+# with a walk finds none in flight and starts its own cold, as every
+# program used to; the last starts nothing, so a call ends with no copy in
+# flight. The same copies into the same scratch and the same products in
+# the same order, only started earlier: a row's output is bit-equal to the
+# parent's (tests/test_ragged_attention.py holds a row in a full call
+# against the same row alone in its call, where its walks start cold).
+#
+# What a grid program costs now (PERF.md §6, PR 45; the decode call alone on
+# the chip, 8 row slots, parent → this): a walk started ahead is 0.4–0.5 µs
+# cheaper at Qwen's widths and 0.7–1.0 at Mistral's, lfm2's, laguna's and
+# mellum2's — the copy's latency and the first pages' bytes. A call of 8
+# rows of 1–3 pages is 12.8 → 9.7 µs (Qwen), 20.8 → 14.2 (Mistral), 16.9 →
+# 12.0 (lfm2): 1.2–1.8 µs a program; the agent cells' call (14 shared pages
+# + tails of 2–10) 28.0 → 24.7. What is left of PR 38's "2.8 µs a grid
+# program" is not a wait: a block's own arithmetic (scores, one softmax
+# update, values: a chain with nothing to overlap in a walk of one block),
+# the copies' descriptors (2 a page, issued from the scalar core) and the
+# grid step. Walks of 90–100 pages read level (234.7 → 236.7 µs for 763
+# pages at Qwen's widths).
 
 _WALK_BLOCK_BYTES = 512 << 10    # a block in flight, a stream, at most
 _WALK_BLOCK_PAGES = 8            # ... and in pages (the score scratch)
@@ -580,12 +711,14 @@ def decode_walk_pages(page: int, n_kv: int, head_dim: int,
     return walk_pages(page * (n_kv // pack) * hd_p * itemsize)
 
 
-def _start_block(n, block: int, b, dmas) -> None:
+def _start_block(n, block: int, b, dmas, half0=None) -> None:
     """Start the copies of block ``b`` of a walk of ``n`` pages: pages
     b·block .. into the scratch half of b's parity, a slot a page; none
-    past the walk's end."""
+    past the walk's end. ``half0`` (0 or 1): the half the walk's block 0
+    lies in where that is not the first (a walk STARTED AHEAD, section
+    "The walk started ahead")."""
     first = b * block
-    half = jax.lax.rem(b, 2) * block
+    half = jax.lax.rem(b if half0 is None else b + half0, 2) * block
 
     def start(i):
         for d in dmas(first + i, half + i):
@@ -594,18 +727,31 @@ def _start_block(n, block: int, b, dmas) -> None:
     _each(jnp.minimum(block, n - first), start)
 
 
-def _walk_blocks(n, block: int, dmas, attend, carry):
+def _walk_blocks(n, block: int, dmas, attend, carry, half0=None,
+                 n_after=None):
     """The walk of ``n`` pages (a traced count) ``block`` pages a loop
     iteration, block 0's copies already started (``_start_block``: the
     caller starts them as early as it can). ``dmas(j, slot)`` are page j's
     copies into scratch slot ``slot``; ``attend(first, half, left, wait,
     carry)`` attends one block — pages ``first`` .., in slots ``half`` ..,
     ``left`` of them (1..block), ``wait(i)`` the wait for its i-th page's
-    copies — and returns the carry. Returns the last carry."""
+    copies — and returns the carry. Returns the last carry. A caller whose
+    walks are started ahead gives ``half0``, the half block 0 lies in, and
+    ``n_after``, the pages of the first block of whatever walk runs NEXT:
+    to this walk they are pages ``turns·block ..``, the block behind its
+    last, which ``dmas`` maps to the next walk's, and their copies start
+    where a next block's always do — before the last block is attended,
+    into the half it does not occupy."""
+    turns = (n + block - 1) // block
+
     def turn(b, carry):
-        _start_block(n, block, b + 1, dmas)
+        if n_after is None:
+            _start_block(n, block, b + 1, dmas)
+        else:
+            _start_block(jnp.where(b + 1 == turns, turns * block + n_after,
+                                   n), block, b + 1, dmas, half0)
         first = b * block
-        half = jax.lax.rem(b, 2) * block
+        half = jax.lax.rem(b if half0 is None else b + half0, 2) * block
 
         def wait(i):
             for d in dmas(first + i, half + i):
@@ -614,7 +760,7 @@ def _walk_blocks(n, block: int, dmas, attend, carry):
         return attend(first, half, jnp.minimum(block, n - first), wait,
                       carry)
 
-    return jax.lax.fori_loop(0, (n + block - 1) // block, turn, carry)
+    return jax.lax.fori_loop(0, turns, turn, carry)
 
 
 _MASKED = NEG_INF / 2        # a stored score under this was masked
@@ -820,6 +966,24 @@ def ragged_walk_steps(tiles, page: int, block: int = 1,
     block partial."""
     pages, _ = _tile_pages(tiles, page, sliding_window, skip)
     return int((-(-pages // block)).sum())
+
+
+def decode_walks(steps, page: int, sliding_window=None, skip=0,
+                 shared=None) -> tuple:
+    """(walks of at least one page the block kernel's programs make over a
+    decode loop, those whose first block an EARLIER walk of the same call
+    started: all but a call's first; section "The walk started ahead").
+    ``steps`` [3, rows, steps]: the one-token tiles of the loop, (kv_len,
+    qpos0, nq) a row a step; ``skip`` as in ``ragged_tile_walk``; with
+    ``shared`` a group's walk is one more in every step that one of its
+    rows runs."""
+    pages, live = _tile_pages(steps, page, sliding_window, skip)
+    calls = (pages > 0).sum(axis=0)              # a row's own walk, a step
+    if shared is not None and sliding_window is None:
+        shared = np.asarray(shared)[:, :live.shape[0]]
+        for r in np.flatnonzero((shared[1] > 0) & (shared[0] > 0)):
+            calls = calls + live[shared[2:, r]].any(axis=0)
+    return int(calls.sum()), int(np.maximum(calls - 1, 0).sum())
 
 
 def _each(n, fn) -> None:
@@ -1122,13 +1286,17 @@ def shared_walk_steps(shared, forwards, block: int) -> int:
 
 
 def _shared_walk(members, n_pages, dmas, page_blocks, q_hbm, scratch, s_scr,
-                 *, n_kv: int, G: int, scale: float, block: int):
+                 *, n_kv: int, G: int, scale: float, block: int, half0,
+                 n_after):
     """The walk a leader's program makes for its group (section comment):
     ``members`` SHARED_ROWS row indices (scalars), ``n_pages`` > 0 common
     pages walked ``block`` a loop iteration as every walk of the kernel is
     (``_walk_blocks``), ``dmas(j, slot)`` the copies of page j,
     ``page_blocks(half, kv)`` what ``_attend_block`` reads of a block's
     pages in the kernel's own scratch, ``s_scr`` its score tiles.
+    The walk's first block is in flight when it begins, in half ``half0``
+    of the scratch, and behind its last it starts ``n_after`` pages of the
+    walk that runs next (``dmas`` knows them: ``_walk_blocks``).
     ``scratch``, as ``ragged_attend``
     lists it: qg_scr [SHARED_ROWS, 1, H, hd] the members' queries as they
     arrive, qf_scr [n_kv, SHARED_ROWS·G, hd] the same scaled to float32,
@@ -1146,7 +1314,6 @@ def _shared_walk(members, n_pages, dmas, page_blocks, q_hbm, scratch, s_scr,
 
     for k in range(len(members)):
         q_in(k).start()
-    _start_block(n_pages, block, 0, dmas)
     for k in range(len(members)):
         q_in(k).wait()
     for k in range(len(members)):
@@ -1169,7 +1336,7 @@ def _shared_walk(members, n_pages, dmas, page_blocks, q_hbm, scratch, s_scr,
             m_scr[kv], l_scr[kv], acc_scr[kv] = new[kv]
         return carry
 
-    _walk_blocks(n_pages, block, dmas, attend, 0)
+    _walk_blocks(n_pages, block, dmas, attend, 0, half0, n_after)
     for k, r in enumerate(members):
         for kv in range(n_kv):
             for scr, st in zip(state, parked):

@@ -117,6 +117,47 @@ def test_shared_walk_kernel_compiles(on_v5e, h, kv, hd, quant, rows):
     compiles(fn, *args)
 
 
+# the decode call of every configuration the dense block kernel serves:
+# (query heads, kv heads, head_dim, window, whether the call has the
+# shared-walk table, B, the parent's kernel in bytes of code: AOT, PR 45)
+DECODE_CALLS = {
+    "qwen2.5-3b": (16, 2, 128, None, True, 8, 130048),
+    "mistral-7b-l16": (32, 8, 128, 4096, True, 2, 76800),
+    "lfm2-24b-a2b-l9": (32, 8, 64, None, True, 4, 264192),
+    "laguna-s-2.1-full": (48, 8, 128, None, True, 2, 229376),
+    "laguna-s-2.1-window": (72, 8, 128, 512, False, 2, 89600),
+    "mellum2-full": (32, 4, 128, None, True, 4, 166400),
+    "mellum2-window": (32, 4, 128, 1024, False, 4, 66048),
+}
+
+
+@pytest.mark.parametrize("geometry", DECODE_CALLS.values(), ids=DECODE_CALLS)
+def test_decode_call_compiles_with_its_walks_started_ahead(on_v5e, geometry):
+    """The decode call at each dense configuration's widths (ISSUE 45):
+    the two scalars that carry a walk started ahead from one grid program
+    to the next lie in SMEM beside the prefetched tables, the search for
+    the next program with a walk is a scalar loop Mosaic accepts, and the
+    kernel grew by the copies' descriptors, not by a second walk body: it
+    stays within 32 KiB of the parent's (a walk body is 40–200)."""
+    h, kv, hd, window, table, block, parent_bytes = geometry
+    S = on_v5e
+    hd_p, pack = pa._lane_geometry(kv, hd)
+    assert pa.walk_pages(PAGE * (kv // pack) * hd_p * 2) == block
+    pool = S((LAYERS, N_PAGES, PAGE, kv * hd), jnp.bfloat16)
+    args = [S((8, h, hd), jnp.bfloat16), pool, pool, S((8, 128), jnp.int32),
+            S((4, 8), jnp.int32), S((), jnp.int32)]
+    if table:
+        args.append(S((2 + pa.SHARED_ROWS, 8), jnp.int32))
+
+    def fn(q, k, v, tables, meta, layer, shared=None):
+        return pa.ragged_attend(q, k, v, tables, meta, layer, tq=1,
+                                sliding_window=window, shared=shared)
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert len(_custom_calls(compiled.as_text())) == 1
+    code = compiled.memory_analysis().generated_code_size_in_bytes
+    assert parent_bytes < code < parent_bytes + (32 << 10), code
+
+
 # the two dense configurations the benchmark serves: (H, KV, window)
 DENSE = {"mistral-h32-kv8": (32, 8, WINDOW), "qwen-h16-kv2": (16, 2, None)}
 
@@ -461,6 +502,11 @@ def test_decode_program_leaves_the_pool_where_it_is(on_v5e, monkeypatch,
     pool_bytes = 2 * cfg.n_layers * layer_elems * 2
     assert mem.alias_size_in_bytes >= pool_bytes
     assert mem.temp_size_in_bytes < layer_elems * 2
+    # with its walks started ahead (ISSUE 45) the program still copies no
+    # layer's weight through HBM, and holds ONE walk body a kernel: 1.22 to
+    # 1.45 MB of code on the parent, 5.6 to 7.7 KB more now (AOT, PR 45)
+    assert weight_moves(hlo, 1 << 20) == []
+    assert mem.generated_code_size_in_bytes < 1_500_000
 
 
 def test_prefill_program_holds_the_tile_kernel(on_v5e, monkeypatch):
@@ -516,6 +562,29 @@ def test_decode_program_leaves_the_pool_where_it_is_at_mistral_widths(
           "alias bytes:", mem.alias_size_in_bytes)
     assert moves == []
     assert mem.temp_size_in_bytes < cfg.n_layers * layer_elems * 2
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("name,max_seq,pool_pages", [
+    ("mistral-7b-l16", 8192, 257), ("qwen2.5-3b", 32768, 457)])
+def test_dense_decode_programs_at_benchmark_widths(on_v5e, monkeypatch, name,
+                                                   max_seq, pool_pages):
+    """The decode program of the two dense configurations the benchmark
+    serves, as their files state them, with every walk of its one kernel a
+    layer started ahead (ISSUE 45; `-m slow`, half a minute each, by hand
+    before chip time): the pools stay where they are and no layer's slice
+    of a stacked weight is copied through HBM (PR 36's reading stands)."""
+    from benchmark import configs
+    from benchmark.families import dense
+    cfg = get_model_config(dense.register(configs.load_config(name)))
+    hlo, mem, layer_elems = _decode_program(on_v5e, monkeypatch, cfg,
+                                            max_seq=max_seq, width=128)
+    assert layer_elems == pool_pages * 128 * cfg.n_kv_heads * 128
+    assert hlo.count("tpu_custom_call") == 1
+    print(name, "code bytes:", mem.generated_code_size_in_bytes,
+          "temp bytes:", mem.temp_size_in_bytes)
+    assert pool_moves(hlo, layer_elems) == []
+    assert weight_moves(hlo, 1 << 20) == []
 
 
 # --- a layer's slice of a stacked weight, inside a layer scan (ISSUE 36) -----
@@ -1038,12 +1107,20 @@ def mosaic_bodies(lowered) -> list:
 # widths (and of the scoring kernel's chunk-forward call), read off the
 # parent commit (79fda53) with this same reader: the chunk forward's latent
 # kernel was rewritten to walk a block of pages a turn (ISSUE 44) and
-# leaves these as they were
+# leaves these as they were. Since ISSUE 45 the dense block kernel starts
+# every walk's first block ahead, through ``_start_block`` and
+# ``_walk_blocks``, which these kernels call too: the chunk forward's
+# latent kernel (both forms) and the TILE kernel at the two dense widths,
+# read off ITS parent (1a20234), are pinned beside them.
 UNTOUCHED_KERNELS = {
     "latent-decode-selected": "142631240b228f79",
     "latent-decode": "ca973008759f4348",
     "index-decode": "bf86b017ce0c2348",
     "index-scores": "af1c4f58cd154a0e",
+    "latent-chunk-selected": "783aaeaa3a619770",
+    "latent-chunk": "a06f4ba4eb680402",
+    "tile-mistral-h32-kv8": "8eb5c6256b4fb10e",
+    "tile-qwen-h16-kv2": "7f4639fe9e10da16",
 }
 
 
@@ -1053,10 +1130,29 @@ def test_the_kernels_beside_the_chunk_walk_are_the_text_they_were(on_v5e,
     """The decode calls (tq = 1, with the shared-walk table: the latent
     walk with and without a selection, the scoring walk) and the scoring
     kernel's chunk-forward call (tq = 8) lower to the Mosaic text they had
-    before the chunk forward's latent kernel got the block walk."""
+    before the chunk forward's latent kernel got the block walk; that
+    kernel and the tile kernel to the text they had before the dense
+    decode call's walks were started ahead."""
     import hashlib
     S = on_v5e
-    if kernel == "index-scores":
+    if kernel.startswith("tile-"):
+        h, kv, window = DENSE[kernel[5:]]
+        tq, tile, args = _tile_args(S, h, kv, 16384, 64, 8, False)
+        lowered = jax.jit(lambda q, k, v, tables, meta, layer, tiles:
+                          pa.ragged_attend(q, k, v, tables, meta, layer,
+                                           tq=tq, sliding_window=window,
+                                           tiles=tiles, tile=tile)
+                          ).lower(*args)
+    elif kernel.startswith("latent-chunk"):
+        nb = 128
+        heads, kw = (128, {"select": S((nb * 8, 128 * PAGE), jnp.int32)}) \
+            if kernel.endswith("selected") else (64, {})
+        lowered = jax.jit(functools.partial(
+            pa.ragged_attend_latent, tq=8, v_lanes=512, scale=0.13)).lower(
+            S((nb * 8, heads, 640), jnp.bfloat16),
+            S((5, 512, PAGE, 640), jnp.bfloat16), S((8, 128), jnp.int32),
+            S((4, nb), jnp.int32), S((), jnp.int32), **kw)
+    elif kernel == "index-scores":
         nb = 128
         lowered = jax.jit(functools.partial(pa.index_scores, tq=8)).lower(
             S((nb * 8, 64, 128), jnp.bfloat16), S((nb * 8, 64), jnp.float32),

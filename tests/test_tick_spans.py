@@ -664,6 +664,12 @@ def test_latent_decode_tick_books_the_shared_walk(monkeypatch):
     # the chunk forward's walks are the same in both
     chunk = off["attn_walk_steps"] - alone
     assert chunk > 0 and chunk == on["attn_walk_steps"] - walked
+    # a step's walks: each row's own, and with the table the group's; the
+    # latent kernels start every walk cold (ISSUE 45 changed the dense
+    # block kernel alone), so none counts as started ahead
+    assert (off["attn_walks"], on["attn_walks"]) == (3 * steps, 4 * steps)
+    assert off["attn_walks_started_ahead"] == 0 \
+        == on["attn_walks_started_ahead"]
 
 
 def test_latent_chunk_forward_counts_a_block_of_pages_a_walk_step():
