@@ -3,7 +3,7 @@
 
 One process — server and client together, because a chip belongs to one
 process at a time — starts the server the way a user does
-(``quoracle_tpu.cli serve --backend tpu --continuous --pool …``, through
+(``quoracle_tpu.cli serve --backend tpu --pool …``, through
 ``cli.start_server``), serving ONE member at Mistral-7B's published widths
 with seeded random weights:
 
@@ -440,7 +440,7 @@ async def run(chips: int, compile_log: dict) -> dict:
     from quoracle_tpu.models.config import get_model_config
     from quoracle_tpu.native.tokenizer import native_available
     spec = SPECS[chips]
-    argv = ["serve", "--backend", "tpu", "--continuous", "--pool", spec,
+    argv = ["serve", "--backend", "tpu", "--pool", spec,
             "--port", "0"] + (["--tp", str(chips)] if chips > 1 else [])
     print("starting: python -m quoracle_tpu.cli " + " ".join(argv),
           flush=True)

@@ -72,8 +72,8 @@ def main() -> int:
         from quoracle_tpu.models.config import BENCH_POOL
         pool = [BENCH_POOL[0]]
     spec = pool[0]
-    backend = TPUBackend([spec], continuous=True,
-                        continuous_slots=max(8, args.concurrency))
+    backend = TPUBackend(
+        [spec], continuous_slots=max(8, args.concurrency))
 
     tasks = load(args.data)[: args.limit]
     per_cat: dict[str, list[int]] = {}

@@ -95,8 +95,8 @@ def main() -> int:
     if not pool:
         from quoracle_tpu.models.config import BENCH_POOL
         pool = list(BENCH_POOL)
-    backend = TPUBackend(pool, continuous=True,
-                        continuous_slots=max(8, args.concurrency))
+    backend = TPUBackend(
+        pool, continuous_slots=max(8, args.concurrency))
 
     questions = load(args.data)[: args.limit]
     per_subject: dict[str, list[int]] = {}

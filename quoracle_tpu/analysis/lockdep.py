@@ -18,13 +18,13 @@ Design rules (mirroring kernel lockdep):
 
 * **Try-acquires are exempt.** ``acquire(blocking=False)`` cannot
   deadlock — backing off on contention is the sanctioned way to take a
-  lock against the declared order (GenerateEngine.prefetch_session,
-  the baton batcher's serve lock). Successful try-acquires still enter
-  the held stack and the observed-edge graph.
+  lock against the declared order (GenerateEngine.prefetch_session).
+  Successful try-acquires still enter the held stack and the
+  observed-edge graph.
 * **Re-entrant re-acquisition is exempt.** Taking a lock the thread
   already holds (RLocks) blocks on nothing.
 * **Coarse locks** (``coarse=True``) serialize device work by design —
-  the engine's paged lock, the baton serve lock, the native build lock.
+  the engine's paged lock, the native build lock.
   The flag is metadata for the STATIC pass (blocking calls under them
   are their purpose, not a finding); ranks still apply at runtime.
 * **Disabled is near-free.** ``named_lock`` always returns a
@@ -96,8 +96,6 @@ HIERARCHY: tuple = (
     ("qos.slo",        18, False),  # SLOTracker EWMA tail state
     ("qos.bucket",     19, False),  # per-tenant TokenBucket
     # -- pool-member serialization --------------------------------------
-    ("member.serve",   20, True),   # baton batcher: device work under it
-    ("member.pending", 21, False),  # baton pending-submission queue
     ("spec.decoder",   22, True),   # v1 batch-1 speculative decoder
     ("spec.adaptive",  23, False),  # BatchedSpeculator adaptive-K state
     # -- session plane --------------------------------------------------

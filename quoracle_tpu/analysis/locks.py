@@ -14,10 +14,10 @@ three ways:
 * ``lock-blocking`` — a blocking operation (device transfer, file I/O,
   sleep, subprocess, bus broadcast, queue/thread waits) performed while
   a BOOKKEEPING lock is held. Locks marked ``coarse`` in the hierarchy
-  (the engine's paged lock, the baton serve lock, the native build
-  lock) serialize device work by design and are exempt; everything else
-  holding up a blocking call stalls every thread contending for pure
-  bookkeeping — exactly the PR 7 async-spill bug class.
+  (the engine's paged lock, the native build lock) serialize device
+  work by design and are exempt; everything else holding up a blocking
+  call stalls every thread contending for pure bookkeeping — exactly
+  the PR 7 async-spill bug class.
 
 How lock identity is resolved (repo-native, heuristic on purpose):
 

@@ -229,8 +229,7 @@ class TrafficStorm(Scenario):
         # replicas=3 → 1 prefill + 2 decode: the router has a real
         # placement choice, so the router.signals drop path is live
         cl = ClusterPlane.build([MEMBER], replicas=3, disaggregate=True,
-                                continuous=True, continuous_chunk=8,
-                                qos=True)
+                                continuous_chunk=8, qos=True)
         for rep in cl.replicas:
             ctrl = getattr(rep.backend, "qos_controller", None)
             if ctrl is not None:
@@ -301,7 +300,7 @@ class KillMidHandoff(Scenario):
     def build(self, ctx: dict) -> None:
         from quoracle_tpu.serving.cluster import ClusterPlane
         cl = ClusterPlane.build([MEMBER], replicas=3, disaggregate=True,
-                                continuous=True, continuous_chunk=8)
+                                continuous_chunk=8)
         ctx["cluster"] = cl
         ctx["backends"] = [cl]
 
@@ -535,7 +534,7 @@ class HbmPressureChurn(Scenario):
 
     def build(self, ctx: dict) -> None:
         from quoracle_tpu.models.runtime import TPUBackend
-        b = TPUBackend([MEMBER], continuous=True, continuous_chunk=8,
+        b = TPUBackend([MEMBER], continuous_chunk=8,
                        host_kv_mb=32, disk_kv_dir=ctx["tmpdir"],
                        disk_kv_gb=1.0, quantize_kv=True)
         ctx["backend"] = b
@@ -785,7 +784,7 @@ class ScaleStorm(Scenario):
         from quoracle_tpu.serving.cluster import ClusterPlane
         from quoracle_tpu.serving.fleet import FleetConfig, FleetController
         cl = ClusterPlane.build([MEMBER], replicas=4, disaggregate=True,
-                                continuous=True, continuous_chunk=8)
+                                continuous_chunk=8)
         ctx["cluster"] = cl
         ctx["fleet"] = FleetController(cl, FleetConfig(
             min_replicas=1, max_replicas=4, hysteresis_ticks=2,

@@ -77,7 +77,7 @@ def runtime_from_args(args: argparse.Namespace) -> Runtime:
         process_id=args.process_id,
         draft_map=_parse_drafts(args.drafts) or None,
         draft_k=args.draft_k,
-        continuous=args.continuous, qos=args.qos or None,
+        qos=args.qos or None,
         host_kv_mb=args.host_kv_mb, disk_kv_dir=args.disk_kv_dir,
         disk_kv_gb=args.disk_kv_gb,
         replicas=args.replicas, disaggregate=args.disaggregate,
@@ -205,9 +205,9 @@ def build_parser() -> argparse.ArgumentParser:
                              "(repeatable; models/speculative.py)")
         sp.add_argument("--draft-k", dest="draft_k", type=int, default=6,
                         help="speculative serving: initial draft length K "
-                             "per round (adaptive under --continuous — "
-                             "shrinks on low acceptance, falls back to "
-                             "vanilla below the floor and re-probes)")
+                             "per round (adaptive: shrinks on low "
+                             "acceptance, falls back to vanilla below "
+                             "the floor and re-probes)")
         sp.add_argument("--coordinator", dest="coordinator", default=None,
                         help="multi-host: coordinator address "
                              "(host:port) to join the JAX distributed "
@@ -217,8 +217,11 @@ def build_parser() -> argparse.ArgumentParser:
         sp.add_argument("--process-id", dest="process_id", type=int,
                         default=None)
         sp.add_argument("--continuous", action="store_true",
-                        help="decode-level continuous batching for the "
-                             "TPU backend (models/scheduler.py)")
+                        help="accepted and read nowhere: every member "
+                             "serves through the one batcher "
+                             "(models/scheduler.py); kept until a "
+                             "`benchmark` PR drops it from the argv "
+                             "benchmark/run.py:405 builds")
         sp.add_argument("--host-kv-mb", dest="host_kv_mb", type=int,
                         default=0,
                         help="tiered KV (serving/kvtier.py): host-RAM "
@@ -261,7 +264,7 @@ def build_parser() -> argparse.ArgumentParser:
         sp.add_argument("--disaggregate", action="store_true",
                         help="role-tag the replicas into prefill "
                              "(MFU-optimized, first token + KV) and "
-                             "decode (continuous batching + "
+                             "decode (long decode loops + "
                              "speculation) tiers with KV handoff "
                              "between them; implies --replicas 2 when "
                              "unset")

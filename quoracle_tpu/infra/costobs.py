@@ -42,8 +42,8 @@ row content — temp-0 outputs are bit-identical with accounting on or
 off (``QUORACLE_COST_ACCOUNTING=0`` disables the whole plane), the
 tier-1 equality gate for this plane.
 
-Attribution context travels on a thread-local: the scheduler / baton
-batcher / speculator set the imminent engine call's row keys with
+Attribution context travels on a thread-local: the scheduler and the
+speculator set the imminent engine call's row keys with
 :func:`set_row_keys` on the same thread that calls into the engine,
 and the engine's charge site consumes them. A missing or mis-sized
 context degrades to the default key — the charge still lands (the sum
@@ -128,7 +128,7 @@ def set_row_keys(keys: Optional[Sequence[tuple]]) -> None:
 
 def set_rows(rows: Sequence[Any]) -> None:
     """``set_row_keys([key_of(r) for r in rows])`` — the caller-side
-    one-liner (scheduler steps, baton batcher, speculator rounds)."""
+    one-liner (scheduler steps, speculator rounds)."""
     set_row_keys([key_of(r) for r in rows])
 
 

@@ -175,22 +175,6 @@ def hbm_attribution(backend) -> dict:
     pool = set(getattr(backend, "pool", None) or ())
     draft_map = dict(getattr(backend, "draft_map", None) or {})
     draft_for = {d: t for t, d in draft_map.items()}
-    # v1 batch-1 speculative decoders hold DENSE session caches (two
-    # full-size KV caches per resident session — models/runtime.py) that
-    # live outside any engine's page pool; attribute them to their TARGET
-    # member instead of leaving them as unattributed tail.
-    spec_cache = {}
-    for tspec, dec in (getattr(backend, "_spec_decoders", None)
-                       or {}).items():
-        try:
-            with dec.lock:
-                n_b = sum(
-                    int(s[w].k.nbytes) + int(s[w].v.nbytes)
-                    for s in dec._sessions.values() for w in ("t", "d"))
-                spec_cache[tspec] = {"bytes": n_b,
-                                     "sessions": len(dec._sessions)}
-        except Exception:             # noqa: BLE001 — partial is fine
-            logger.exception("spec cache attribution failed for %s", tspec)
     for spec, e in engines.items():
         try:
             params_b = sum(
@@ -256,11 +240,6 @@ def hbm_attribution(backend) -> dict:
                         ts["disk"]["entries"]
                 members[spec]["kv_demotable_bytes"] = \
                     tier.demotable_bytes(page_b)
-            if spec in spec_cache:
-                members[spec]["spec_cache_bytes"] = \
-                    spec_cache[spec]["bytes"]
-                members[spec]["spec_cache_sessions"] = \
-                    spec_cache[spec]["sessions"]
         except Exception:                 # noqa: BLE001 — partial is fine
             logger.exception("hbm attribution failed for %s", spec)
     totals = {
@@ -271,8 +250,6 @@ def hbm_attribution(backend) -> dict:
         "draft_params_bytes": sum(
             m["params_bytes"] for m in members.values()
             if m.get("role") == "draft"),
-        "spec_cache_bytes": sum(m.get("spec_cache_bytes", 0)
-                                for m in members.values()),
         "kv_host_bytes": sum(m.get("kv_host_bytes", 0)
                              for m in members.values()),
         "kv_disk_bytes": sum(m.get("kv_disk_bytes", 0)
@@ -320,9 +297,6 @@ class ResourceCollector:
                                     component="kv_pool")
             HBM_COMPONENT_BYTES.set(m["prefix_cache_bytes"], model=spec,
                                     component="prefix_cache")
-            if "spec_cache_bytes" in m:
-                HBM_COMPONENT_BYTES.set(m["spec_cache_bytes"], model=spec,
-                                        component="spec_cache")
             occ = m["prefix_cache"]
             PREFIX_CACHE_PAGES.set(occ["resident_pages"], model=spec,
                                    kind="resident")

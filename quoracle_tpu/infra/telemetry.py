@@ -16,7 +16,7 @@ path:
   sink broadcasts them on ``TOPIC_TRACE``, ring-buffered by
   infra/event_history.py and queryable at ``/api/trace?task_id=…``).
   Propagation across the thread hops of the serving path (agent executor
-  thread → pool-member threads → baton-batcher drain) is explicit:
+  thread → pool-member threads → the batcher's worker) is explicit:
   ``TRACER.use(parent)`` rebinds the current span in a foreign thread.
 
 Telemetry is the ONE deliberately process-wide component in a codebase
@@ -993,8 +993,8 @@ def tick_open(model: str) -> TickRecord:
 def tick_phase(name: str) -> Optional[TickRecord]:
     """THE phase helper: on a thread with an open tick record, close the
     phase under way and open ``name`` (one of TICK_PHASES); returns the
-    record. Elsewhere — the engine driven directly, the baton batcher —
-    one thread-local read and nothing else."""
+    record. Elsewhere — the engine driven directly — one thread-local
+    read and nothing else."""
     rec = _TICK.record
     if rec is not None:
         rec.phase(name)

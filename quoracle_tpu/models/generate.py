@@ -1485,40 +1485,6 @@ class GenerateEngine:
                                  chunk_lens, cache, kv_off=kv_off,
                                  shard=attn_shard)
 
-        if cfg.vision is not None:
-            @functools.partial(jax.jit, static_argnames=())
-            def step_paged_prefill_vlm(params, k_pool, v_pool, k_scale,
-                                       v_scale, src_pages,
-                                       tokens, prefix_lens, chunk_lens,
-                                       kv_off, pixels):
-                # VLM chunk through the PAGED machinery (image-keyed
-                # sessions): the ViT tower runs inside the jit and its
-                # projected patches replace the chunk's placeholder ids —
-                # resumed rounds take the TEXT paged prefill instead (their
-                # suffix carries no placeholders), so the tower only ever
-                # runs when an image is genuinely new.
-                from quoracle_tpu.models.vision import (
-                    splice_image_embeds, vision_encode,
-                )
-                B, maxp = src_pages.shape
-                kw, vw = _gather_work(k_pool, v_pool, k_scale, v_scale,
-                                      src_pages)
-                cache = _constrain(KVCache(k=kw, v=vw,
-                                           lens=jnp.zeros((B,), jnp.int32)))
-                img = vision_encode(params["vision"], cfg.vision, pixels)
-                embeds = params["embed"][tokens]
-                if cfg.scale_embeddings:
-                    embeds = (embeds.astype(jnp.float32)
-                              * (cfg.dim ** 0.5)).astype(embeds.dtype)
-                embeds = splice_image_embeds(embeds, tokens, img,
-                                             cfg.image_token_id)
-                return prefill_chunk(params, cfg, tokens, prefix_lens,
-                                     chunk_lens, cache, kv_off=kv_off,
-                                     input_embeds=embeds, shard=attn_shard)
-            self._step_paged_prefill_vlm = step_paged_prefill_vlm
-        else:
-            self._step_paged_prefill_vlm = None
-
         @functools.partial(jax.jit, static_argnames=("max_new",),
                            donate_argnums=(1, 2, 5, 6))
         def step_paged_decode(params, k_pool, v_pool, k_scale, v_scale,

@@ -133,7 +133,7 @@ class QueryResult:
     spec_accepted_tokens: int = 0
     # Chip economics (ISSUE 17): this result's measured share of device
     # wall (infra/costobs.ChipLedger row shares, ms). 0.0 with
-    # accounting off or on self-driving paths (v1 spec decoder).
+    # accounting off.
     chip_ms: float = 0.0
     error: Optional[str] = None        # None = success
     permanent_error: bool = False      # parity: only auth-type errors are
@@ -288,123 +288,6 @@ def _encode_multimodal(engine, messages) -> tuple[list[int], Optional[object]]:
     return tok.encode(rendered, add_bos=True), None
 
 
-class _MemberBatcher:
-    """Baton batching for one pool member: concurrent consensus rounds
-    (different agents, same model) coalesce into ONE engine.generate.
-
-    The serve lock's holder drains EVERYTHING queued while it served —
-    contention itself is the batching signal, so an uncontended call pays
-    zero added latency (no timer window): three agents' rows ride one
-    generate() call instead of three.
-    """
-
-    def __init__(self, engine: GenerateEngine):
-        from quoracle_tpu.analysis.lockdep import named_lock
-        self.engine = engine
-        self._serve = named_lock("member.serve")
-        self._plock = named_lock("member.pending")
-        # pending SUBMISSIONS (one per query() caller), not flattened rows:
-        # a merged-batch failure can then retry per submission, keeping one
-        # agent's pathological round from poisoning its neighbors'.
-        self._pending: list[tuple[list[dict], list]] = []
-
-    def submit(self, rows: list[dict]) -> list:
-        """rows: per-row generate kwargs dicts. Returns Futures resolving
-        to (GenResult, prefill_ms, decode_ms) — phase timings snapshot at
-        serve time (a later batch would overwrite the engine's last_*)."""
-        from concurrent.futures import Future, wait
-        futs = [Future() for _ in rows]
-        with self._plock:
-            self._pending.append((rows, futs))
-        while not all(f.done() for f in futs):
-            if self._serve.acquire(blocking=False):
-                try:
-                    self._drain(mine=futs)
-                finally:
-                    self._serve.release()
-            else:
-                # another thread holds the baton; it will drain us — the
-                # short timeout covers the narrow window where it swept
-                # pending just before our enqueue
-                wait(futs, timeout=0.005)
-        return futs
-
-    def _generate(self, subs: list[tuple[list[dict], list]]) -> None:
-        pairs = [(r, f) for sub_rows, sub_futs in subs
-                 for r, f in zip(sub_rows, sub_futs)]
-        # Deadline-aware drop at serve time (ISSUE 4): a row whose
-        # budget elapsed while waiting for the baton is failed here —
-        # the batch runs without it rather than decoding dead work.
-        live: list = []
-        now = time.monotonic()
-        for r, f in pairs:
-            dl = r.get("deadline_s")
-            if dl is not None and now >= dl:
-                if not f.done():
-                    f.set_exception(DeadlineExceededError(
-                        "deadline passed before the member batch served "
-                        "this row", tenant=r.get("tenant"),
-                        priority=r.get("priority")))
-            else:
-                live.append((r, f))
-        if not live:
-            return
-        rows = [r for r, _ in live]
-        # chip-economics attribution (ISSUE 17): declare the merged
-        # batch's row keys on the serving thread for the engine's
-        # charge site (dicts carry tenant/priority/task_id/decide)
-        from quoracle_tpu.infra import costobs
-        costobs.set_row_keys([_row_key(r) for r in rows])
-        gens = self.engine.generate(
-            [r["prompt"] for r in rows],
-            temperature=[r["temperature"] for r in rows],
-            top_p=[r["top_p"] for r in rows],
-            max_new_tokens=[r["budget"] for r in rows],
-            session_ids=([r["session_id"] for r in rows]
-                         if any(r["session_id"] for r in rows) else None),
-            constrain_json=([r["constrain_json"] for r in rows]
-                            if any(r["constrain_json"] for r in rows)
-                            else None),
-            action_enums=([r["action_enum"] for r in rows]
-                          if any(r["action_enum"] for r in rows) else None),
-            images=([r["image"] for r in rows]
-                    if any(r["image"] is not None for r in rows)
-                    else None))
-        phases = (self.engine.last_prefill_s * 1000,
-                  self.engine.last_decode_s * 1000)
-        for (_, f), g in zip(live, gens):
-            f.set_result((g, *phases))
-
-    def _drain(self, mine: list) -> None:
-        # Serve until OUR futures are done (plus whatever queued alongside
-        # them); once they are, stop — remaining submitters poll the baton
-        # themselves, so one thread never becomes the pool's permanent
-        # server while its own round sits finished.
-        while not all(f.done() for f in mine):
-            with self._plock:
-                subs, self._pending = self._pending[:], []
-            if not subs:
-                return
-            # QoS (ISSUE 4): serve urgent submissions first. All of a
-            # drain's submissions still merge into one generate, so this
-            # only matters when a failure forces the per-submission
-            # retry — the stable sort keeps arrival order within a class.
-            subs.sort(key=lambda s: min(
-                (r.get("priority") or 1 for r in s[0]), default=1))
-            try:
-                self._generate(subs)
-            except Exception:
-                # merged batch failed: retry per SUBMISSION so only the
-                # genuinely failing caller's rows error
-                for sub in subs:
-                    try:
-                        self._generate([sub])
-                    except Exception as e:
-                        for f in sub[1]:
-                            if not f.done():
-                                f.set_exception(e)
-
-
 class TPUBackend(ModelBackend):
     """Serves a pool of catalog models resident on the chip/mesh.
 
@@ -418,8 +301,7 @@ class TPUBackend(ModelBackend):
                  embedder=None,
                  submeshes: Optional[Sequence] = None,
                  overlap: bool = True,
-                 continuous: bool = False, continuous_chunk: int = 32,
-                 continuous_slots: int = 8,
+                 continuous_chunk: int = 32, continuous_slots: int = 8,
                  draft_map: Optional[dict] = None, draft_k: int = 6,
                  qos=None, host_kv_mb: int = 0,
                  disk_kv_dir: Optional[str] = None,
@@ -432,14 +314,12 @@ class TPUBackend(ModelBackend):
         instead of the sequential loop (SURVEY §7 hard part 1). None =
         single-device engines.
 
-        ``continuous`` replaces round-granularity baton batching with
-        DECODE-level continuous batching (models/scheduler.py): each
-        member runs a chunked decode loop that concurrent agents' text
-        rows join and leave at ``continuous_chunk``-token boundaries, up
-        to ``continuous_slots`` rows per step. Image rows (which skip KV
-        sessions by design) stay on the baton path. Under continuous
-        mode the per-call prefill/decode phase split is not meaningful
-        (many rows share each device step) and is reported as 0.
+        Every member serves through ONE batcher, DECODE-level continuous
+        batching (models/scheduler.py): each member runs a chunked decode
+        loop that concurrent agents' text rows join and leave at
+        ``continuous_chunk``-token boundaries, up to ``continuous_slots``
+        rows per step. Image rows (which skip KV sessions by design) take
+        a direct engine call.
 
         ``qos`` turns on serving QoS (ISSUE 4): pass True for defaults
         or a serving/qos.QoSConfig. Each member's continuous batcher
@@ -509,22 +389,15 @@ class TPUBackend(ModelBackend):
         # Speculative serving (models/speculative.py): draft_map routes a
         # member's decode through draft-K/verify-one-chunk decoding —
         # output stays token-exact at temperature 0. Draft engines load
-        # like members but never serve as pool members themselves. Two
-        # integrations by dispatch mode (ISSUE 6):
-        #   * continuous=True — the PRODUCTION path: one BatchedSpeculator
-        #     per drafted member rides the ContinuousBatcher's decode
-        #     ticks (batched draft scan + one chunked multi-row verify per
-        #     round against the paged session KV; adaptive K with vanilla
-        #     fallback). Built below, handed to the batcher.
-        #   * baton mode — the v1 batch-1 dense-cache SpeculativeDecoder
-        #     serves single uncontended text rows as before.
+        # like members but never serve as pool members themselves. One
+        # BatchedSpeculator per drafted member rides the ContinuousBatcher's
+        # decode ticks (ISSUE 6: batched draft scan + one chunked multi-row
+        # verify per round against the paged session KV; adaptive K with
+        # vanilla fallback). Built below, handed to the batcher.
         self.draft_map = dict(draft_map or {})
-        self._spec_decoders: dict = {}
         self._speculators: dict = {}
         if draft_map:
-            from quoracle_tpu.models.speculative import (
-                BatchedSpeculator, SpeculativeDecoder,
-            )
+            from quoracle_tpu.models.speculative import BatchedSpeculator
             for j, (tspec, dspec) in enumerate(sorted(draft_map.items())):
                 if tspec not in self.engines:
                     raise KeyError(f"draft_map target {tspec!r} is not in "
@@ -532,21 +405,9 @@ class TPUBackend(ModelBackend):
                 if dspec not in self.engines:
                     self.engines[dspec] = build_engine(
                         dspec, len(self.pool) + 100 + j)
-                te, de = self.engines[tspec], self.engines[dspec]
-                if continuous:
-                    self._speculators[tspec] = BatchedSpeculator(
-                        te, de, k=draft_k)
-                else:
-                    self._spec_decoders[tspec] = SpeculativeDecoder(
-                        te.cfg, te.params, de.cfg, de.params, te.tokenizer,
-                        k=draft_k, max_seq=te.max_seq)
+                self._speculators[tspec] = BatchedSpeculator(
+                    self.engines[tspec], self.engines[dspec], k=draft_k)
 
-        # One baton batcher per POOL member (draft engines never serve
-        # directly): concurrent agents' rounds coalesce
-        self._batchers = {spec: _MemberBatcher(self.engines[spec])
-                          for spec in self.pool}
-        self.continuous = continuous
-        self._cbatchers = {}
         # Serving QoS (ISSUE 4): ONE controller + SLO tracker shared
         # across members (overload and tail burn are system conditions),
         # one weighted-fair queue per member. qos=True → defaults.
@@ -579,22 +440,21 @@ class TPUBackend(ModelBackend):
                     aging_floor_s=qcfg.aging_floor_s,
                     weight_fn=self.slo.weight_multiplier, model=spec)
                 for spec in self.pool}
-        if continuous:
-            from quoracle_tpu.models.scheduler import ContinuousBatcher
-            self._cbatchers = {
-                spec: ContinuousBatcher(self.engines[spec],
-                                        chunk=continuous_chunk,
-                                        max_slots=continuous_slots,
-                                        policy=qos_policies.get(spec),
-                                        admission=self.qos_controller,
-                                        slo=self.slo,
-                                        speculator=self._speculators.get(
-                                            spec))
-                for spec in self.pool}
-            if self.qos_controller is not None:
-                for spec, pol in qos_policies.items():
-                    self.qos_controller.register_depth_source(
-                        spec, pol.qsize)
+        # One batcher per POOL member (draft engines never serve
+        # directly): every text row of the member rides its decode loop
+        from quoracle_tpu.models.scheduler import ContinuousBatcher
+        self._cbatchers = {
+            spec: ContinuousBatcher(self.engines[spec],
+                                    chunk=continuous_chunk,
+                                    max_slots=continuous_slots,
+                                    policy=qos_policies.get(spec),
+                                    admission=self.qos_controller,
+                                    slo=self.slo,
+                                    speculator=self._speculators.get(spec))
+            for spec in self.pool}
+        if self.qos_controller is not None:
+            for spec, pol in qos_policies.items():
+                self.qos_controller.register_depth_source(spec, pol.qsize)
 
         if embedder is not None:
             self.embedder = embedder
@@ -616,12 +476,11 @@ class TPUBackend(ModelBackend):
                                              shard=eshard)
 
     def close(self) -> None:
-        """Stop the continuous batcher threads (no-op otherwise). Queued
-        rows fail loudly rather than stranding waiters — scheduler.close()
-        semantics. Tiered engines drain their queued disk spills so a
-        clean shutdown hands its successor every persisted prefix (an
-        abrupt kill loses at most the queue — the store is an
-        optimization, never state)."""
+        """Stop the batcher threads. Queued rows fail loudly rather than
+        stranding waiters — scheduler.close() semantics. Tiered engines
+        drain their queued disk spills so a clean shutdown hands its
+        successor every persisted prefix (an abrupt kill loses at most
+        the queue — the store is an optimization, never state)."""
         for cb in self._cbatchers.values():
             cb.close()
         for eng in self.engines.values():
@@ -677,33 +536,27 @@ class TPUBackend(ModelBackend):
         return {spec: cb.stats() for spec, cb in self._cbatchers.items()}
 
     def swap_draft(self, tspec: str, engine, name: Optional[str] = None):
-        """Hot-swap the draft engine behind ``tspec``'s continuous-mode
-        speculator (ISSUE 19 promotion path) and return the incumbent
-        engine for instant rollback. The caller owns both engines'
-        lifecycles — the swapped-out incumbent is NOT closed (a rollback
-        re-installs the same object), and ``close()`` never reaches a
-        swapped-in engine. Draft KV is derived state: rows cold
-        re-prefill into the new draft's sessions on their next round."""
+        """Hot-swap the draft engine behind ``tspec``'s speculator
+        (ISSUE 19 promotion path) and return the incumbent engine for
+        instant rollback. The caller owns both engines' lifecycles — the
+        swapped-out incumbent is NOT closed (a rollback re-installs the
+        same object), and ``close()`` never reaches a swapped-in engine.
+        Draft KV is derived state: rows cold re-prefill into the new
+        draft's sessions on their next round."""
         speculator = self._speculators.get(tspec)
         if speculator is None:
-            raise KeyError(f"no continuous speculator for {tspec!r} "
+            raise KeyError(f"no speculator for {tspec!r} "
                            f"(draft_map: {sorted(self.draft_map)})")
         old = speculator.swap_draft(engine)
         self.draft_map[tspec] = name or engine.cfg.name
         return old
 
     def spec_stats(self) -> dict:
-        if not self._speculators and not self._spec_decoders:
+        if not self._speculators:
             return {"enabled": False}
-        members = {spec: s.stats() for spec, s in self._speculators.items()}
-        for spec, dec in self._spec_decoders.items():
-            # v1 batch-1 decoders have no rolling scorecard — report the
-            # wiring so /api/models shows which members are drafted
-            members.setdefault(spec, {
-                "mode": "batch1", "draft": dec.dc.name, "k": dec.k,
-            })
         return {"enabled": True, "draft_map": dict(self.draft_map),
-                "members": members}
+                "members": {spec: s.stats()
+                            for spec, s in self._speculators.items()}}
 
     def kv_stats(self) -> dict:
         if not self.kv_tiered:
@@ -800,8 +653,8 @@ class TPUBackend(ModelBackend):
                     n_rows=len(idxs),
                     cached_tokens=sum(r.cached_tokens for r in done))
                 if done and (done[0].prefill_ms or done[0].decode_ms):
-                    # phase timings are per-batch (identical across the
-                    # member's rows) — one retroactive span per phase
+                    # the first row's own device fences stand for the
+                    # member's slice — one retroactive span per phase
                     TRACER.emit("generate.prefill", done[0].prefill_ms,
                                 parent=msp, phase="prefill", model=spec)
                     TRACER.emit("generate.decode", done[0].decode_ms,
@@ -812,7 +665,7 @@ class TPUBackend(ModelBackend):
                            results: list[Optional[QueryResult]]) -> None:
         """Writes into disjoint ``results`` positions — safe from
         concurrent member threads."""
-        if spec not in self.engines or spec not in self._batchers:
+        if spec not in self._cbatchers:
             # not a pool member — includes draft engines, which load into
             # self.engines but never serve directly
             for i in idxs:
@@ -881,11 +734,6 @@ class TPUBackend(ModelBackend):
                     # assistant text would break the token match at the
                     # previous prompt's end (generate.splice_session_prompt).
                     sess_toks = engine.session_tokens(r.session_id)
-                    if not sess_toks and spec in self._spec_decoders:
-                        # speculative sessions live in the decoder, not
-                        # the engine — splice against ITS resident ids
-                        sess_toks = self._spec_decoders[
-                            spec].session_tokens(r.session_id)
                     if sess_toks:
                         spliced = splice_session_prompt(
                             engine.tokenizer, sess_toks, ids)
@@ -911,8 +759,7 @@ class TPUBackend(ModelBackend):
             deadline_s = (t0 + r.deadline_ms / 1000.0
                           if r.deadline_ms is not None else None)
             if deadline_s is not None and time.monotonic() >= deadline_s:
-                # already dead at build time — covers every dispatch path
-                # (speculative, baton, continuous) with one check
+                # already dead at build time
                 results[i] = QueryResult(
                     model_spec=spec,
                     error=f"deadline_exceeded: {r.deadline_ms:.0f}ms "
@@ -937,108 +784,9 @@ class TPUBackend(ModelBackend):
     def _dispatch_rows(self, spec: str, rows: list[dict],
                        live_idxs: list[int], results: list,
                        t0: float) -> None:
-        """Serve prepared rows through this backend's dispatch mode
-        (continuous / speculative batch-1 / baton)."""
-        engine = self.engines[spec]
-        if self.continuous:
-            self._query_member_continuous(spec, rows, live_idxs, results,
-                                          t0)
-            return
-        dec = self._spec_decoders.get(spec)
-        if (dec is not None and len(rows) == 1
-                and rows[0]["image"] is None
-                and (rows[0]["temperature"] <= 0
-                     or rows[0]["top_p"] >= 1.0)
-                # TRY-acquire: under concurrent agents the member
-                # batcher's cross-agent coalescing beats serialized
-                # speculation (batched decode already amortizes weight
-                # streaming) — contention falls through to the baton
-                # path; an uncontended single agent speculates
-                # the decoder asserts prompt + max_new < max_seq (its
-                # dense cache sizing); the OUTPUT_FLOOR-inflated budget
-                # must be clamped like generate.py's per-row limits, and
-                # a prompt leaving <1 token of room falls through to the
-                # baton path's proper context_overflow handling
-                and len(rows[0]["prompt"]) + 1 < engine.max_seq
-                and dec.lock.acquire(blocking=False)):
-            r0 = rows[0]
-            i0 = live_idxs[0]
-            cfg = engine.cfg
-            budget = min(r0["budget"],
-                         engine.max_seq - len(r0["prompt"]) - 1)
-            try:
-                g = dec.generate(
-                    r0["prompt"], temperature=r0["temperature"],
-                    top_p=r0["top_p"], max_new_tokens=budget,
-                    constrain_json=bool(r0["constrain_json"]),
-                    action_enum=r0["action_enum"],
-                    session_id=r0["session_id"])
-            except ContextOverflowError as e:
-                results[i0] = QueryResult(model_spec=spec,
-                                          error=f"context_overflow: {e}")
-                return
-            except Exception as e:    # noqa: BLE001 — row-level error
-                results[i0] = QueryResult(model_spec=spec,
-                                          error=f"generate failed: {e}")
-                return
-            finally:
-                dec.lock.release()
-            latency_ms = (time.monotonic() - t0) * 1000
-            cost = (g.n_prompt_tokens * cfg.input_cost_per_mtok
-                    + g.n_gen_tokens * cfg.output_cost_per_mtok) / 1e6
-            results[i0] = QueryResult(
-                model_spec=spec, text=g.text,
-                usage=Usage(g.n_prompt_tokens, g.n_gen_tokens, cost),
-                latency_ms=latency_ms,
-                # draft/verify interleave: a prefill/decode split is not
-                # meaningful
-                prefill_ms=0.0, decode_ms=0.0,
-                cached_tokens=getattr(g, "n_cached_tokens", 0),
-                spec_rounds=g.rounds,
-                spec_accepted_tokens=g.accepted)
-            return
-        # The member's baton batcher may merge these rows with concurrent
-        # agents' rounds into one generate.
-        futs = self._batchers[spec].submit(rows)
-        cfg = engine.cfg
-        for i, f in zip(live_idxs, futs):
-            try:
-                g, prefill_ms, decode_ms = f.result()
-            except ContextOverflowError as e:
-                results[i] = QueryResult(model_spec=spec,
-                                         error=f"context_overflow: {e}")
-                continue
-            except DeadlineExceededError as e:
-                results[i] = QueryResult(model_spec=spec,
-                                         error=f"deadline_exceeded: {e}")
-                continue
-            except AdmissionError as e:
-                results[i] = QueryResult(
-                    model_spec=spec,
-                    error=f"admission_rejected: {e} "
-                          f"(retry_after_ms={e.retry_after_ms})")
-                continue
-            except Exception as e:
-                results[i] = QueryResult(model_spec=spec,
-                                         error=f"generate failed: {e}")
-                continue
-            latency_ms = (time.monotonic() - t0) * 1000
-            cost = (g.n_prompt_tokens * cfg.input_cost_per_mtok
-                    + g.n_gen_tokens * cfg.output_cost_per_mtok) / 1e6
-            results[i] = QueryResult(
-                model_spec=spec, text=g.text,
-                usage=Usage(g.n_prompt_tokens, g.n_gen_tokens, cost),
-                latency_ms=latency_ms,
-                prefill_ms=prefill_ms, decode_ms=decode_ms,
-                cached_tokens=g.n_cached_tokens,
-                chip_ms=getattr(g, "chip_ms", 0.0))
-
-    def _query_member_continuous(self, spec: str, rows: list[dict],
-                                 live_idxs: list[int],
-                                 results: list, t0: float) -> None:
-        """Continuous mode: text rows join the member's shared decode loop
-        (models/scheduler.py) at chunk boundaries; image rows — which skip
-        KV sessions by design — take a direct engine call."""
+        """Serve prepared rows: text rows join the member's shared decode
+        loop (models/scheduler.py) at chunk boundaries; image rows — which
+        skip KV sessions by design — take a direct engine call."""
         engine = self.engines[spec]
         cfg = engine.cfg
         cb = self._cbatchers[spec]
@@ -1125,12 +873,6 @@ class TPUBackend(ModelBackend):
                 # generates — a bare store drop could free pages a running
                 # batch still references
                 engine.drop_session(session_id)
-        for spec, dec in self._spec_decoders.items():
-            if keep is None or spec in keep:
-                # speculative sessions hold two full-size dense caches —
-                # a dead session must not wait for LRU eviction, and a
-                # reused id must not splice against the stale ctx
-                dec.drop_session(session_id)
 
     def count_tokens(self, model_spec: str, text: str) -> int:
         return self.engines[model_spec].tokenizer.count(text)

@@ -1,11 +1,10 @@
 """Decode-level continuous batching across agents (VERDICT r4 item 4).
 
-The baton batcher (models/runtime.py) coalesces concurrent agents' rounds
-at ROUND granularity: rows that arrive while a member is mid-generate wait
-for the whole call. Here rows join and leave a shared decode loop at CHUNK
-granularity instead — the classic continuous-batching scheme (reference
-never executes attention, SURVEY §2.8; the pattern is Orca/vLLM's,
-re-derived for XLA's static shapes):
+THE batcher: every text row of every pool member (models/runtime.py)
+joins and leaves its member's shared decode loop at CHUNK granularity —
+the classic continuous-batching scheme (reference never executes
+attention, SURVEY §2.8; the pattern is Orca/vLLM's, re-derived for XLA's
+static shapes):
 
   * each engine gets ONE worker thread running a chunked loop: every
     iteration batches all live rows into a single ``engine.generate``

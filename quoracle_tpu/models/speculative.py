@@ -119,9 +119,10 @@ class SpecResult:
         return self.n_gen_tokens / max(1, self.rounds)
 
 
-# Bench/baton-path decoder: compiles once at _build per (which,
-# cache_len); the production continuous path ledgers through the owning
-# engine's CompileRegistry instead (BatchedSpeculator + verify_chunk).
+# The batch-1 decoder of training/draft_check.py (the backend serves no
+# row through it): compiles once at _build per (which, cache_len); the
+# serving path ledgers through the owning engine's CompileRegistry
+# instead (BatchedSpeculator + verify_chunk).
 # qlint: allow[jit-unregistered] batch-1 decoder; engines own the ledger
 class SpeculativeDecoder:
     """Draft/verify decoder over two models sharing one tokenizer.

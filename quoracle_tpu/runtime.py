@@ -222,10 +222,10 @@ class RuntimeConfig:
     # Speculative serving (models/speculative.py): {target_spec:
     # draft_spec} — eligible member queries draft-K/verify-one-chunk;
     # drafts load like members but never serve directly. Also settable
-    # via the DB setting "draft_map" (dashboard /api/settings). Under
-    # ``continuous`` the drafted members speculate INSIDE the shared
-    # decode loop (BatchedSpeculator, ISSUE 6) with ``draft_k`` as the
-    # initial adaptive draft length.
+    # via the DB setting "draft_map" (dashboard /api/settings). The
+    # drafted members speculate INSIDE the shared decode loop
+    # (BatchedSpeculator, ISSUE 6) with ``draft_k`` as the initial
+    # adaptive draft length.
     draft_map: Optional[dict] = None
     draft_k: int = 6
     # Multi-host: join the JAX distributed system before building the
@@ -235,9 +235,6 @@ class RuntimeConfig:
     coordinator_address: Optional[str] = None
     num_processes: Optional[int] = None
     process_id: Optional[int] = None
-    # Decode-level continuous batching (models/scheduler.py) for the TPU
-    # backend's pool members (round-granularity baton batching otherwise).
-    continuous: bool = False
     # Serving QoS (ISSUE 4): True for defaults, or a serving/qos.QoSConfig
     # (a dict of its fields also works — handy from CLI/JSON config).
     # Turns on weighted-fair admission + overload shedding; implies
@@ -578,7 +575,6 @@ class Runtime:
                 submeshes_by_replica=submeshes_by_replica,
                 qos=qos, draft_map=draft_map or None,
                 draft_k=config.draft_k,
-                continuous=config.continuous or config.disaggregate,
                 host_kv_mb=config.host_kv_mb,
                 disk_kv_dir=config.disk_kv_dir,
                 disk_kv_gb=config.disk_kv_gb,
@@ -609,7 +605,6 @@ class Runtime:
                 embed_model=config.embed_model,
                 submeshes=submeshes,
                 draft_map=draft_map or None,
-                continuous=config.continuous,
                 qos=qos, host_kv_mb=config.host_kv_mb,
                 disk_kv_dir=config.disk_kv_dir,
                 disk_kv_gb=config.disk_kv_gb,

@@ -9,7 +9,7 @@ role-tagged into tiers:
   * **prefill** replicas — MFU-optimized: chunked ragged prefill only
     (engines carry ``role='prefill'``, which hard-caps generates at one
     emitted token — the first-token semantics of disaggregated serving);
-    no continuous batcher, no draft models.
+    no draft models.
   * **decode** replicas — HBM-bandwidth-optimized: continuous batching
     plus speculation, exactly the single-Runtime production decode path.
   * **unified** replicas — the non-disaggregated data-parallel mode
@@ -371,7 +371,7 @@ class ClusterPlane(ModelBackend):
               disaggregate: bool = True, seed: int = 0,
               submeshes_by_replica: Optional[Sequence] = None,
               qos=None, draft_map: Optional[dict] = None,
-              draft_k: int = 6, continuous: bool = True,
+              draft_k: int = 6,
               continuous_chunk: int = 32, continuous_slots: int = 8,
               host_kv_mb: int = 0, disk_kv_dir: Optional[str] = None,
               disk_kv_gb: float = 8.0, embed_model: Optional[str] = None,
@@ -407,9 +407,8 @@ class ClusterPlane(ModelBackend):
             backend = TPUBackend(
                 pool, seed=seed, embed_model=embed_model,
                 embedder=embedder, submeshes=mesh,
-                # prefill tier: no decode loop, no drafts — one ragged
-                # prefill call per placement is its whole job
-                continuous=continuous and not prefill,
+                # prefill tier: no drafts — one ragged prefill call per
+                # placement is its whole job
                 continuous_chunk=continuous_chunk,
                 continuous_slots=continuous_slots,
                 draft_map=None if prefill else draft_map,
@@ -441,7 +440,7 @@ class ClusterPlane(ModelBackend):
         plane._replica_args = dict(
             pool=list(pool), seed=seed, embed_model=embed_model,
             qos=qos, draft_map=draft_map,
-            draft_k=draft_k, continuous=continuous,
+            draft_k=draft_k,
             continuous_chunk=continuous_chunk,
             continuous_slots=continuous_slots, host_kv_mb=host_kv_mb,
             disk_kv_dir=disk_kv_dir, disk_kv_gb=disk_kv_gb,
@@ -525,14 +524,12 @@ class ClusterPlane(ModelBackend):
                 "ClusterPlane.build (or set _replica_args) before "
                 "scaling")
         a = dict(self._replica_args)
-        prefill = role == "prefill"
         backend = TPUBackend(
             a["pool"], seed=a["seed"], embed_model=a.get("embed_model"),
             embedder=self._embedder,
-            continuous=a["continuous"] and not prefill,
             continuous_chunk=a["continuous_chunk"],
             continuous_slots=a["continuous_slots"],
-            draft_map=None if prefill else a["draft_map"],
+            draft_map=None if role == "prefill" else a["draft_map"],
             draft_k=a["draft_k"], qos=a["qos"],
             host_kv_mb=a["host_kv_mb"] or 256,
             disk_kv_dir=a["disk_kv_dir"], disk_kv_gb=a["disk_kv_gb"],
@@ -824,7 +821,7 @@ class ClusterPlane(ModelBackend):
             usage=Usage(n_prompt, len(g_ids), cost),
             latency_ms=latency_ms,
             # split-phase serving: the per-call prefill/decode split is
-            # not meaningful (same convention as continuous mode)
+            # not meaningful
             prefill_ms=0.0, decode_ms=0.0,
             cached_tokens=g1.n_cached_tokens,
             spec_rounds=getattr(g2, "spec_rounds", 0),
@@ -832,10 +829,8 @@ class ClusterPlane(ModelBackend):
 
     def _decode_on(self, dec: Replica, spec: str, row: dict, g1,
                    hid: str):
-        """The continuation (prompt + first token) on the decode
-        replica: through its continuous batcher when it runs one (the
-        production path — speculation included), a direct engine call
-        otherwise."""
+        """The continuation (prompt + first token) through the decode
+        replica's batcher (speculation included)."""
         # Chaos seam (ISSUE 11): decode-replica death AFTER the handoff
         # landed — the retained envelope must re-place the row onto a
         # survivor with bit-identical output (kv_handoff_replace), or
@@ -844,26 +839,16 @@ class ClusterPlane(ModelBackend):
         continuation = list(row["prompt"]) + list(g1.token_ids)
         remaining = row["budget"] - len(g1.token_ids)
         js = g1.json_state if row["constrain_json"] else None
-        cb = dec.backend._cbatchers.get(spec)
-        if cb is not None:
-            fut = cb.submit(
-                continuation, temperature=row["temperature"],
-                top_p=row["top_p"], max_new_tokens=remaining,
-                session_id=hid, constrain_json=row["constrain_json"],
-                action_enum=row["action_enum"],
-                priority=row["priority"], tenant=row["tenant"],
-                deadline_s=row["deadline_s"],
-                initial_json_state=js,
-                task_id=row.get("task_id"), decide=row.get("decide"),
-                tree=row.get("tree"))
-            return fut.result()
-        de = dec.backend.engines[spec]
-        return de.generate(
-            [continuation], temperature=row["temperature"],
+        return dec.backend._cbatchers[spec].submit(
+            continuation, temperature=row["temperature"],
             top_p=row["top_p"], max_new_tokens=remaining,
-            session_ids=[hid], constrain_json=[row["constrain_json"]],
-            action_enums=[row["action_enum"]],
-            initial_json_state=[js])[0]
+            session_id=hid, constrain_json=row["constrain_json"],
+            action_enum=row["action_enum"],
+            priority=row["priority"], tenant=row["tenant"],
+            deadline_s=row["deadline_s"],
+            initial_json_state=js,
+            task_id=row.get("task_id"), decide=row.get("decide"),
+            tree=row.get("tree")).result()
 
     # -- pool-wide backend surface ---------------------------------------
 

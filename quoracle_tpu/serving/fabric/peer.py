@@ -74,7 +74,7 @@ class FabricPeer:
     def build(cls, pool: Sequence[str], *, role: str = "unified",
               replica_id: Optional[str] = None, seed: int = 0,
               qos=None, draft_map: Optional[dict] = None,
-              draft_k: int = 6, continuous: bool = True,
+              draft_k: int = 6,
               continuous_chunk: int = 32, continuous_slots: int = 8,
               host_kv_mb: int = 0, disk_kv_dir: Optional[str] = None,
               disk_kv_gb: float = 8.0,
@@ -82,19 +82,17 @@ class FabricPeer:
               quantize_weights: bool = False,
               quantize_kv: bool = False) -> "FabricPeer":
         """One role-tagged replica backend, mirroring ClusterPlane.build
-        exactly: prefill peers run no batcher and no drafts (one ragged
-        prefill per placement is their whole job) and every peer gets a
-        KV tier — the handoff transport medium."""
+        exactly: prefill peers run no drafts (one ragged prefill per
+        placement is their whole job) and every peer gets a KV tier —
+        the handoff transport medium."""
         from quoracle_tpu.models.runtime import TPUBackend
-        prefill = role == "prefill"
         if not host_kv_mb:
             host_kv_mb = 256              # the handoff transport medium
         backend = TPUBackend(
             pool, seed=seed, embed_model=embed_model,
-            continuous=continuous and not prefill,
             continuous_chunk=continuous_chunk,
             continuous_slots=continuous_slots,
-            draft_map=None if prefill else draft_map,
+            draft_map=None if role == "prefill" else draft_map,
             draft_k=draft_k, qos=qos, host_kv_mb=host_kv_mb,
             disk_kv_dir=disk_kv_dir, disk_kv_gb=disk_kv_gb,
             quantize_weights=quantize_weights, quantize_kv=quantize_kv)
@@ -379,7 +377,7 @@ class FabricPeer:
                 if done:
                     g_ids = list(g1_ids)
                 else:
-                    g2 = self._continue(de, spec, header, row, g1, hid)
+                    g2 = self._continue(spec, header, row, g1, hid)
                     g_ids = g1_ids + [int(t) for t in g2.token_ids]
             except BaseException:
                 # a failed continuation must not strand the adopted
@@ -409,11 +407,10 @@ class FabricPeer:
                                             0),
         })
 
-    def _continue(self, de, spec: str, header: dict, row: dict, g1: dict,
+    def _continue(self, spec: str, header: dict, row: dict, g1: dict,
                   hid: str):
         """The continuation (prompt + first token) through this peer's
-        continuous batcher when it runs one (the production path —
-        speculation included), a direct engine call otherwise."""
+        batcher (speculation included)."""
         continuation = [int(t) for t in header["prompt"]] \
             + [int(t) for t in g1["token_ids"]]
         remaining = row["budget"] - len(g1["token_ids"])
@@ -423,21 +420,13 @@ class FabricPeer:
             deadline_s = time.monotonic() \
                 + row["deadline_ms_left"] / 1000.0
         ae = tuple(row["action_enum"]) if row.get("action_enum") else None
-        cb = self.backend._cbatchers.get(spec)
-        if cb is not None:
-            fut = cb.submit(
-                continuation, temperature=row["temperature"],
-                top_p=row["top_p"], max_new_tokens=remaining,
-                session_id=hid, constrain_json=row["constrain_json"],
-                action_enum=ae, priority=row["priority"],
-                tenant=row["tenant"], deadline_s=deadline_s,
-                initial_json_state=js, tree=row.get("tree"))
-            return fut.result()
-        return de.generate(
-            [continuation], temperature=row["temperature"],
+        return self.backend._cbatchers[spec].submit(
+            continuation, temperature=row["temperature"],
             top_p=row["top_p"], max_new_tokens=remaining,
-            session_ids=[hid], constrain_json=[row["constrain_json"]],
-            action_enums=[ae], initial_json_state=[js])[0]
+            session_id=hid, constrain_json=row["constrain_json"],
+            action_enum=ae, priority=row["priority"],
+            tenant=row["tenant"], deadline_s=deadline_s,
+            initial_json_state=js, tree=row.get("tree")).result()
 
     # -- signals / admission ---------------------------------------------
 

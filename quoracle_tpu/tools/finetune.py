@@ -176,6 +176,7 @@ def eval_format(out_dir: str, n_eval: int, seed: int, log) -> dict:
                       and isinstance(obj.get("params"), dict))
         if i < 3:
             log(f"sample {i}: {r.text[:100]!r}")
+    backend.close()
     return {"json_compliance": round(ok / max(1, n_eval), 4),
             "strict_action_compliance": round(strict / max(1, n_eval), 4),
             "n_eval": n_eval, "greedy": n_greedy,

@@ -18,26 +18,34 @@ os.environ["XLA_FLAGS"] = (
 # Suite-wide persistent compilation cache (VERDICT r4 item 6): dozens of
 # test files build their own GenerateEngine over the same tiny configs,
 # and each construction recompiles identical (prefill, decode) HLO — the
-# persistent cache dedupes those across files, processes, AND xdist
-# workers (JAX's cache writes are atomic renames, safe under -n). Where
+# persistent cache dedupes those across files and runs. Under xdist each
+# worker keeps a directory of its own beneath the one parent
+# (PYTEST_XDIST_WORKER): six workers writing one directory lost a worker
+# to a segfault inside jax's cache write. Where
 # JAX_COMPILATION_CACHE_DIR does not already place it, it goes to a TEMP
 # dir, so the hundreds of tiny-test-model entries stay out of the
 # checkout's own cache (utils/compile_cache.py).
 import tempfile
 
-if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
-    # The dir must be OWNED by us, mode 0700: /tmp's sticky bit stops
+_cache = os.path.join(tempfile.gettempdir(),
+                      f"quoracle-test-xla-cache-{os.getuid()}")
+# unset, or set by the xdist controller's own import of this file (its
+# workers inherit the controller's environment)
+if (os.environ.get("JAX_COMPILATION_CACHE_DIR") or _cache) == _cache:
+    # The parent must be OWNED by us, mode 0700: /tmp's sticky bit stops
     # deletion, not creation — another user could pre-create a
     # predictable path and plant compiled-executable cache entries this
     # process would load. Refuse a foreign dir (in-checkout cache then).
-    _cache = os.path.join(tempfile.gettempdir(),
-                          f"quoracle-test-xla-cache-{os.getuid()}")
     try:
         os.makedirs(_cache, mode=0o700, exist_ok=True)
         _st = os.stat(_cache)
         if _st.st_uid != os.getuid():
             raise PermissionError(f"{_cache} owned by uid {_st.st_uid}")
         os.chmod(_cache, 0o700)
+        _worker = os.environ.get("PYTEST_XDIST_WORKER")
+        if _worker:
+            _cache = os.path.join(_cache, _worker)
+            os.makedirs(_cache, mode=0o700, exist_ok=True)
         os.environ["JAX_COMPILATION_CACHE_DIR"] = _cache
     except OSError:
         pass

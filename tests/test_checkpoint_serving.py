@@ -77,6 +77,7 @@ def test_backend_serves_checkpoint_with_sessions_and_grammar(ckpt_dirs):
     # dropping the session forgets the prefix
     backend.drop_session("agent-e2e")
     assert len(engine.sessions) == 0
+    backend.close()
 
 
 def test_runtime_builds_tpu_backend_from_checkpoints(ckpt_dirs):

@@ -44,7 +44,7 @@ def req(msgs=MSGS, sid=None, cj=False, temperature=0.0, max_tokens=20,
 
 @pytest.fixture(scope="module")
 def mono():
-    b = TPUBackend([MEMBER], continuous=True, continuous_chunk=8)
+    b = TPUBackend([MEMBER], continuous_chunk=8)
     yield b
     b.close()
 
@@ -52,7 +52,7 @@ def mono():
 @pytest.fixture(scope="module")
 def cluster():
     c = ClusterPlane.build([MEMBER], replicas=2, disaggregate=True,
-                           continuous=True, continuous_chunk=8)
+                           continuous_chunk=8)
     yield c
     c.close()
 
@@ -78,14 +78,51 @@ def test_disagg_constrained_json_bit_equal(mono, cluster):
     assert b.text == a.text
 
 
+def test_prefill_replica_serves_through_its_own_batcher(mono, cluster):
+    """A ``role='prefill'`` replica has a batcher like any other: a row of
+    its own backend rides the batcher, the engine's role holds it to a
+    budget of one token (so it retires after its first tick; a larger
+    budget is refused, that row alone), and the session it leaves
+    is adoptable by the decode replica, which then resumes it to the
+    monolithic backend's bits."""
+    pre = next(r for r in cluster.replicas if r.role == "prefill")
+    dec = next(r for r in cluster.replicas if r.role == "decode")
+    pe, de = pre.backend.engines[MEMBER], dec.backend.engines[MEMBER]
+    assert pe.role == "prefill"
+    # a budget over one token is refused by the engine's role, row-level
+    over = pre.backend.query([req(sid="pf0")])[0]
+    assert not over.ok and "prefill-tier replica" in over.error
+    before = pre.backend.scheduler_stats()[MEMBER]
+    first = pre.backend.query([req(sid="pf1", max_tokens=1)])[0]
+    assert first.ok, first.error
+    assert first.usage.completion_tokens == 1
+    after = pre.backend.scheduler_stats()[MEMBER]
+    assert after["retired"] == before["retired"] + 1
+    assert after["steps"] == before["steps"] + 1
+    env = cluster.handoff.export(pe, "pf1", MEMBER,
+                                 src_replica=pre.replica_id)
+    try:
+        cluster.handoff.adopt(de, env, dst_replica=dec.replica_id)
+        want = mono.query([req()])[0]
+        got = dec.backend.query([req(sid="pf1")])[0]
+        assert got.ok and want.ok, (got.error, want.error)
+        assert got.text == want.text
+        assert want.text.startswith(first.text)
+        assert got.cached_tokens > 0       # resumed, not re-prefilled
+    finally:
+        cluster.handoff.forget(MEMBER, "pf1")
+        de.drop_session("pf1")
+        pe.drop_session("pf1")
+
+
 def test_disagg_speculative_bit_equal():
     """Decode replicas run the production continuous+speculative path;
     the handed-off row's grammar state and session resume compose with
     draft/verify rounds bit-exactly."""
-    mono = TPUBackend([MEMBER], continuous=True, continuous_chunk=8,
+    mono = TPUBackend([MEMBER], continuous_chunk=8,
                       draft_map={MEMBER: MEMBER}, draft_k=4)
     cl = ClusterPlane.build([MEMBER], replicas=2, disaggregate=True,
-                            continuous=True, continuous_chunk=8,
+                            continuous_chunk=8,
                             draft_map={MEMBER: MEMBER}, draft_k=4)
     try:
         a = mono.query([req(sid="sp1", cj=True, max_tokens=24)])[0]
@@ -135,9 +172,9 @@ def test_decode_replica_death_replaces_row():
     adopts into the survivor and the output is still bit-identical; a
     second death with no survivor left fails the row with a STRUCTURED
     error naming the replica — never a silent loss."""
-    mono = TPUBackend([MEMBER], continuous=True, continuous_chunk=8)
+    mono = TPUBackend([MEMBER], continuous_chunk=8)
     cl = ClusterPlane.build([MEMBER], replicas=3, disaggregate=True,
-                            continuous=True, continuous_chunk=8)
+                            continuous_chunk=8)
     try:
         want = mono.query([req()])[0]
         decs = _decode_reps(cl)
@@ -224,7 +261,7 @@ def test_all_decode_replicas_shed_propagates_max_retry_after():
     from quoracle_tpu.serving.qos import Priority
 
     cl = ClusterPlane.build([MEMBER], replicas=3, disaggregate=True,
-                            continuous=True, continuous_chunk=8,
+                            continuous_chunk=8,
                             qos=True)
     try:
         decs = _decode_reps(cl)
@@ -370,7 +407,7 @@ def test_prefill_role_engine_rejects_decode(cluster):
 
 def test_unified_replicas_serve_bit_equal(mono):
     cl = ClusterPlane.build([MEMBER], replicas=2, disaggregate=False,
-                            continuous=True, continuous_chunk=8)
+                            continuous_chunk=8)
     try:
         assert not cl.disaggregated
         a = mono.query([req(sid="u1")])[0]
@@ -483,9 +520,9 @@ def test_kv_and_qos_stats_aggregate_per_replica(cluster):
                                    for r in cluster.replicas}
     assert "handoff" in kv
     sched = cluster.scheduler_stats()
-    # prefill replicas run no batcher; decode replicas one per member
-    assert any(k.startswith("decode-") for k in sched)
-    assert not any(k.startswith("prefill-") for k in sched)
+    # every replica, whatever its role, runs one batcher per member
+    assert {k.split("/", 1)[0] for k in sched} \
+        == {r.replica_id for r in cluster.replicas}
     # engines surface is replica-qualified for HBM attribution
     assert {k.split("@", 1)[0] for k in cluster.engines} \
         == {r.replica_id for r in cluster.replicas}

@@ -180,6 +180,7 @@ def test_backend_consensus_never_parse_fails():
     # action name), but no response may fail JSON PARSING
     for f in outcome.failures:
         assert "parse" not in f.error, f.error
+    backend.close()
 
 
 # ---------------------------------------------------------------------------

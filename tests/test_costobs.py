@@ -156,7 +156,7 @@ def test_backend_scheduler_temp0_bit_equal_and_attributed():
     bit-equality, chip-ms on the QueryResult, and the ledger's cells
     keyed by the submitted tenant / task / decide."""
     from quoracle_tpu.models.runtime import QueryRequest, TPUBackend
-    b = TPUBackend([MEMBER], continuous=True, continuous_chunk=8)
+    b = TPUBackend([MEMBER], continuous_chunk=8)
     try:
         def q():
             return b.query([QueryRequest(
@@ -192,7 +192,7 @@ def test_cluster_temp0_bit_equal_accounting_on_off():
     from quoracle_tpu.models.runtime import QueryRequest
     from quoracle_tpu.serving.cluster import ClusterPlane
     cl = ClusterPlane.build([MEMBER], replicas=2, disaggregate=True,
-                            continuous=True, continuous_chunk=8)
+                            continuous_chunk=8)
     try:
         def q():
             return cl.query([QueryRequest(

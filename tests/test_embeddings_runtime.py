@@ -102,18 +102,21 @@ def test_tpu_backend_pool_query_batches_per_model():
     for r in res:
         assert r.ok and r.usage.completion_tokens <= 8
         assert r.usage.prompt_tokens > 0 and r.usage.cost > 0
+    backend.close()
 
 
 def test_tpu_backend_unknown_model_is_permanent_error():
     backend = TPUBackend(pool=["xla:tiny"], seed=0)
     res = backend.query([QueryRequest("xla:nope", [{"role": "user", "content": "x"}])])
     assert not res[0].ok and res[0].permanent_error
+    backend.close()
 
 
 def test_tpu_backend_embed():
     backend = TPUBackend(pool=["xla:tiny"], seed=0)
     v = backend.embed(["abc"])[0]
     assert v.shape == (64,)
+    backend.close()
 
 
 def test_tpu_backend_per_request_budget_enforced():
@@ -126,6 +129,7 @@ def test_tpu_backend_per_request_budget_enforced():
     ])
     assert res[0].usage.completion_tokens <= 4
     assert res[1].usage.completion_tokens <= 32
+    backend.close()
 
 
 def test_tpu_backend_per_row_overflow_isolates():
@@ -139,3 +143,4 @@ def test_tpu_backend_per_row_overflow_isolates():
     ])
     assert not res[0].ok and "context_overflow" in res[0].error
     assert res[1].ok
+    backend.close()

@@ -54,7 +54,7 @@ def _remote(peer, **kw):
 
 @pytest.fixture(scope="module")
 def mono():
-    b = TPUBackend([MEMBER], continuous=True, continuous_chunk=8)
+    b = TPUBackend([MEMBER], continuous_chunk=8)
     yield b
     b.close()
 
@@ -104,7 +104,7 @@ def test_fabric_speculative_bit_equal():
     """Decode peers run the production continuous+speculative path; the
     wire-handed-off row's grammar state and session resume compose with
     draft/verify rounds bit-exactly."""
-    mono = TPUBackend([MEMBER], continuous=True, continuous_chunk=8,
+    mono = TPUBackend([MEMBER], continuous_chunk=8,
                       draft_map={MEMBER: MEMBER}, draft_k=4)
     pre = FabricPeer.build([MEMBER], role="prefill",
                            replica_id="prefill-0", continuous_chunk=8,
@@ -526,7 +526,6 @@ def test_runtime_peer_and_frontdoor_over_real_tcp(mono):
     from quoracle_tpu.runtime import Runtime, RuntimeConfig
 
     rt = Runtime(RuntimeConfig(backend="tpu", model_pool=[MEMBER],
-                               continuous=True,
                                fabric_listen="unified@127.0.0.1:0"))
     try:
         addr = rt._fabric_peer._server.addr

@@ -43,7 +43,7 @@ def req(msgs=MSGS, sid=None, cj=False, max_tokens=20):
 
 @pytest.fixture(scope="module")
 def mono():
-    b = TPUBackend([MEMBER], continuous=True, continuous_chunk=8)
+    b = TPUBackend([MEMBER], continuous_chunk=8)
     yield b
     b.close()
 
@@ -53,7 +53,7 @@ def cluster():
     """1 prefill + 2 decode replicas: a drain always has a live
     migration target."""
     c = ClusterPlane.build([MEMBER], replicas=3, disaggregate=True,
-                           continuous=True, continuous_chunk=8)
+                           continuous_chunk=8)
     yield c
     c.close()
 
@@ -114,10 +114,10 @@ def test_drain_migration_speculative_bit_equal():
     """Sessions migrated mid-stream compose with the decode tier's
     speculative path bit-exactly: the migrated pages resume under
     draft/verify rounds."""
-    mono = TPUBackend([MEMBER], continuous=True, continuous_chunk=8,
+    mono = TPUBackend([MEMBER], continuous_chunk=8,
                       draft_map={MEMBER: MEMBER}, draft_k=4)
     cl = ClusterPlane.build([MEMBER], replicas=3, disaggregate=True,
-                            continuous=True, continuous_chunk=8,
+                            continuous_chunk=8,
                             draft_map={MEMBER: MEMBER}, draft_k=4)
     fc = FleetController(cl)
     try:
@@ -380,7 +380,7 @@ def test_policy_tick_executes_on_live_plane(cluster, fleet):
 def test_drain_killed_mid_drain_degrades_structurally(mono):
     from quoracle_tpu.chaos.faults import CHAOS, FaultPlan, FaultRule
     cl = ClusterPlane.build([MEMBER], replicas=3, disaggregate=True,
-                            continuous=True, continuous_chunk=8)
+                            continuous_chunk=8)
     fc = FleetController(cl)
     try:
         a1 = mono.query([req(sid="fleet-kill")])[0]

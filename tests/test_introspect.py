@@ -342,7 +342,7 @@ def test_backend_rows_book_exact_waits_on_decode_spans():
     from quoracle_tpu.models.runtime import QueryRequest, TPUBackend
     fleetobs.ensure_ring()
     fleetobs.SPANS.clear()
-    b = TPUBackend([MEMBER], continuous=True, continuous_chunk=8)
+    b = TPUBackend([MEMBER], continuous_chunk=8)
     try:
         out = b.query([QueryRequest(
             MEMBER, [{"role": "user", "content":
@@ -380,7 +380,7 @@ def test_backend_rows_book_exact_waits_on_decode_spans():
 
 def test_backend_temp0_bit_equal_introspect_on_off():
     from quoracle_tpu.models.runtime import QueryRequest, TPUBackend
-    b = TPUBackend([MEMBER], continuous=True, continuous_chunk=8)
+    b = TPUBackend([MEMBER], continuous_chunk=8)
     try:
         def q():
             return b.query([QueryRequest(

@@ -163,6 +163,7 @@ def test_backend_threads_sessions_through(monkeypatch):
     # round 2 prefilled strictly fewer tokens than the full prompt
     full = len(eng.tokenizer.encode_chat(msgs2))
     assert eng.last_prefill_tokens < full
+    backend.close()
 
 
 def test_mixed_batch_long_fresh_row_does_not_corrupt_resumed_row(paged_path):
@@ -329,6 +330,7 @@ def test_backend_splices_response_kv(monkeypatch):
     assert eng.last_prefill_tokens <= glue + 8
     # and the resident session grew on top of the old one, not from scratch
     assert len(eng.session_tokens("ag")) > sess_len
+    backend.close()
 
 
 def test_drop_session_frees_engine_state():
@@ -340,6 +342,7 @@ def test_drop_session_frees_engine_state():
     assert len(backend.engines["xla:tiny"].sessions) == 1
     backend.drop_session("gone")
     assert len(backend.engines["xla:tiny"].sessions) == 0
+    backend.close()
 
 
 # ---------------------------------------------------------------------------

@@ -644,6 +644,7 @@ def test_backend_prefetch_sessions():
     assert backend.prefetch_sessions("agent-1") == 1
     assert eng.sessions.get("agent-1") is not None
     assert backend.prefetch_sessions("agent-1") == 0
+    backend.close()
 
 
 # ---------------------------------------------------------------------------
@@ -675,6 +676,8 @@ def test_effective_headroom_counts_demotable_pages(monkeypatch):
                    - 0.1) < 1e-9
     finally:
         eng.sessions.tier = untiered_eng_tier
+    backend.close()
+    untiered.close()
 
 
 def test_demotable_bytes_excludes_unreclaimable_pages():
@@ -783,6 +786,7 @@ def test_kv_stats_and_prometheus_exposition():
     assert "quoracle_kv_restores_total" in text
     assert "quoracle_kv_restore_ms" in text
     assert 'kind="session"' in text
+    backend.close()
 
 
 def test_api_kv_payload_shapes():
@@ -806,6 +810,7 @@ def test_api_kv_payload_shapes():
     payload = d.kv_payload()
     assert payload["enabled"] is True
     assert "xla:tiny" in payload["members"]
+    backend.close()
 
 
 def test_kv_panel_renders():

@@ -209,6 +209,7 @@ def test_register_and_backend_serves_checkpoint(tmp_path):
         temperature=0.0, max_tokens=4)])
     assert len(out) == 1 and out[0].ok, out[0].error
     assert out[0].usage.prompt_tokens > 0
+    backend.close()
 
 
 def test_vlm_checkpoint_roundtrip_and_serves_images(tmp_path):
@@ -246,6 +247,7 @@ def test_vlm_checkpoint_roundtrip_and_serves_images(tmp_path):
                                     temperature=0.0, max_tokens=6)])[0]
     assert r.ok, r.error
     assert r.usage.prompt_tokens > cfg.vision.n_patches
+    backend.close()
 
 
 # ---------------------------------------------------------------------------

@@ -90,7 +90,7 @@ def test_blocking_under_bookkeeping_lock_and_coarse_exempt():
         class Q:
             def __init__(self):
                 self._lock = named_lock("batcher")
-                self._serve = named_lock("member.serve")
+                self._serve = named_lock("engine.paged")
 
             def bad(self):
                 with self._lock:

@@ -319,8 +319,7 @@ def test_backend_deadline_maps_to_member_miss_error():
     """TPUBackend continuous + deadline_ms=0: the row comes back as a
     deadline_exceeded QueryResult error (a member miss), never a raise."""
     from quoracle_tpu.models.runtime import QueryRequest, TPUBackend
-    backend = TPUBackend(pool=["xla:tiny"], continuous=True,
-                         continuous_chunk=4)
+    backend = TPUBackend(pool=["xla:tiny"], continuous_chunk=4)
     try:
         msgs = [{"role": "user", "content": "hello"}]
         res = backend.query([
@@ -332,6 +331,36 @@ def test_backend_deadline_maps_to_member_miss_error():
         assert res[0].error.startswith("deadline_exceeded")
         assert not res[0].permanent_error
         assert res[1].ok, res[1].error
+    finally:
+        backend.close()
+
+
+def test_qos_alone_admits_every_row_through_the_controller():
+    """``TPUBackend(qos=True)`` and no other argument: the controller it
+    builds is the one its member's batcher asks on every submit, and the
+    member's weighted-fair queue is the controller's depth source."""
+    from quoracle_tpu.models.runtime import QueryRequest, TPUBackend
+    backend = TPUBackend(pool=["xla:tiny"], qos=True)
+    try:
+        ctrl = backend.qos_controller
+        cb = backend._cbatchers["xla:tiny"]
+        assert cb.admission is ctrl
+        assert ctrl.stats()["admitted"] == 0
+        assert ctrl.stats()["depth_sources"] == ["xla:tiny"]
+        msgs = [{"role": "user", "content": "admit me"}]
+        res = backend.query([
+            QueryRequest("xla:tiny", msgs, temperature=0.0, max_tokens=4,
+                         tenant="t1"),
+            QueryRequest("xla:tiny", msgs, temperature=0.0, max_tokens=4,
+                         tenant="t2")])
+        assert all(r.ok for r in res), [r.error for r in res]
+        assert ctrl.stats()["admitted"] == 2
+        q = backend.qos_stats()
+        assert q["enabled"] and q["admission"]["admitted"] == 2
+        assert q["queues"]["xla:tiny"] is not None
+        # the SLO tracker saw both rows retire
+        assert sum(c["observed"]
+                   for c in q["slo"]["classes"].values()) == 2
     finally:
         backend.close()
 

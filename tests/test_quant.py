@@ -165,11 +165,11 @@ def test_quantized_mono_vs_cluster_selfconsistency():
     constrained-JSON and speculative decoding."""
     from quoracle_tpu.models.runtime import TPUBackend
     from quoracle_tpu.serving.cluster import ClusterPlane
-    mono = TPUBackend([MEMBER], continuous=True, continuous_chunk=8,
+    mono = TPUBackend([MEMBER], continuous_chunk=8,
                       draft_map={MEMBER: MEMBER}, draft_k=4,
                       quantize_weights=True, quantize_kv=True)
     cl = ClusterPlane.build([MEMBER], replicas=2, disaggregate=True,
-                            continuous=True, continuous_chunk=8,
+                            continuous_chunk=8,
                             draft_map={MEMBER: MEMBER}, draft_k=4,
                             quantize_weights=True, quantize_kv=True)
     try:
@@ -203,7 +203,7 @@ def test_quantized_mono_vs_wire_peer_selfconsistency():
     from quoracle_tpu.serving.fabric.frontdoor import FabricPlane
     from quoracle_tpu.serving.fabric.peer import FabricPeer
     from quoracle_tpu.serving.fabric.transport import LoopbackTransport
-    mono = TPUBackend([MEMBER], continuous=True, continuous_chunk=8,
+    mono = TPUBackend([MEMBER], continuous_chunk=8,
                       quantize_weights=True, quantize_kv=True)
     peers = [FabricPeer.build([MEMBER], role="prefill",
                               replica_id="prefill-0", continuous_chunk=8,

@@ -431,8 +431,7 @@ def test_tpu_backend_resources_attribution_live():
     from quoracle_tpu.web import DashboardServer
 
     async def main():
-        backend = TPUBackend(pool=["xla:tiny"], continuous=True,
-                             continuous_chunk=4)
+        backend = TPUBackend(pool=["xla:tiny"], continuous_chunk=4)
         rt = Runtime(RuntimeConfig(), backend=backend)
         server = await DashboardServer(rt, port=0).start()
         try:

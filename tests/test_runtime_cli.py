@@ -93,3 +93,24 @@ def test_cluster_flags_require_tpu_backend():
                {"process_id": 0}):
         with pytest.raises(ValueError, match="require --backend tpu"):
             Runtime(RuntimeConfig(**kw))
+
+
+def test_continuous_flag_is_accepted_and_read_nowhere(monkeypatch):
+    """``serve --continuous`` still parses (benchmark/run.py builds it into
+    every cell's argv) and changes nothing: the runtime an argv describes
+    is the same with the flag and without it, and no option of the
+    runtime's is left for it to set."""
+    import dataclasses
+
+    from quoracle_tpu import cli
+
+    monkeypatch.setattr(cli, "Runtime", lambda config: config)
+    base = ["serve", "--backend", "tpu", "--pool", "xla:tiny", "--port", "0"]
+    parse = cli.build_parser().parse_args
+    with_flag = cli.runtime_from_args(
+        parse(base[:3] + ["--continuous"] + base[3:]))
+    without = cli.runtime_from_args(parse(base))
+    assert isinstance(without, RuntimeConfig)
+    assert with_flag == without
+    assert "continuous" not in {f.name
+                                for f in dataclasses.fields(RuntimeConfig)}

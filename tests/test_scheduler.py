@@ -113,12 +113,11 @@ def test_mixed_action_enums_across_chunks():
 
 
 def test_backend_continuous_mode_end_to_end():
-    """TPUBackend(continuous=True): consensus-shaped sessioned requests
+    """TPUBackend: consensus-shaped sessioned requests
     flow through the shared decode loop; refinement rounds keep their
     session residency."""
     from quoracle_tpu.models.runtime import QueryRequest, TPUBackend
-    backend = TPUBackend(pool=["xla:tiny"], continuous=True,
-                         continuous_chunk=4)
+    backend = TPUBackend(pool=["xla:tiny"], continuous_chunk=4)
     msgs = [{"role": "user", "content": "hello continuous world"}]
     r1 = backend.query([
         QueryRequest("xla:tiny", msgs, temperature=0.0, max_tokens=12,
@@ -135,6 +134,7 @@ def test_backend_continuous_mode_end_to_end():
     assert r2[0].ok, r2[0].error
     eng = backend.engines["xla:tiny"]
     assert eng.sessions.get("agent-1") is not None   # session retained
+    backend.close()
 
 
 def test_row_at_context_edge_retires_without_poisoning_batch():
@@ -299,3 +299,32 @@ def test_sessionless_generate_runs_without_paged_lock():
         assert done.wait(120), \
             "sessionless generate blocked on engine._paged_lock"
     assert out["r"].n_gen_tokens >= 1
+
+
+def test_default_backend_serves_a_text_row_through_the_batcher():
+    """One batcher (ISSUE 46): a TPUBackend built with no argument but its
+    pool serves a text row through the member's ContinuousBatcher — the
+    batcher retires it and the row ring holds its record."""
+    from quoracle_tpu.infra import introspect
+    from quoracle_tpu.models.runtime import QueryRequest, TPUBackend
+    from quoracle_tpu.models.scheduler import ContinuousBatcher
+    backend = TPUBackend(pool=["xla:tiny"])
+    try:
+        assert isinstance(backend._cbatchers["xla:tiny"], ContinuousBatcher)
+        before = backend.scheduler_stats()["xla:tiny"]
+        assert before["retired"] == 0 and before["steps"] == 0
+        introspect.reset()
+        introspect.enable()
+        r = backend.query([QueryRequest(
+            "xla:tiny", [{"role": "user", "content": "one batcher"}],
+            temperature=0.0, max_tokens=6)])[0]
+        assert r.ok, r.error
+        after = backend.scheduler_stats()["xla:tiny"]
+        assert after["retired"] == 1 and after["steps"] >= 1
+        rows = introspect.row_ring()
+        assert len(rows) == 1 and rows[0]["model"] == "tiny"
+        assert rows[0]["emitted_tokens"] == r.usage.completion_tokens
+        assert rows[0]["prompt_tokens"] == r.usage.prompt_tokens
+    finally:
+        backend.close()
+        introspect.reset()

@@ -142,12 +142,14 @@ def test_backend_overlapped_members_on_submeshes(eight_devices):
     b = par_backend.query(reqs)
     assert [r.ok for r in a] == [r.ok for r in b] == [True] * 4
     assert [r.text for r in a] == [r.text for r in b]
+    seq_backend.close()
+    par_backend.close()
 
 
 def test_member_batcher_coalesces_concurrent_rounds():
-    """Baton batching: concurrent query() calls for the same member merge
-    into fewer generate() calls (several agents' rows in one), made
-    available to real agent trees."""
+    """The member's batcher: concurrent query() calls for the same member
+    merge into fewer generate() calls (several agents' rows in one tick),
+    made available to real agent trees."""
     import threading
     from concurrent.futures import ThreadPoolExecutor
     from quoracle_tpu.models.runtime import QueryRequest, TPUBackend
@@ -161,7 +163,7 @@ def test_member_batcher_coalesces_concurrent_rounds():
     def slow_generate(prompts, **kw):
         batch_sizes.append(len(prompts))
         if len(batch_sizes) == 1:
-            gate.set()          # signal: the baton holder is inside
+            gate.set()          # signal: the first tick is inside
             time.sleep(0.5)     # let the other callers enqueue
         return orig(prompts, **kw)
 
@@ -174,20 +176,21 @@ def test_member_batcher_coalesces_concurrent_rounds():
 
     with ThreadPoolExecutor(max_workers=3) as ex:
         f0 = ex.submit(one_round, 0)
-        gate.wait(timeout=30)             # holder is mid-generate
+        gate.wait(timeout=30)             # the worker is mid-generate
         f1 = ex.submit(one_round, 1)
         f2 = ex.submit(one_round, 2)
         all_res = [f.result(timeout=120) for f in (f0, f1, f2)]
 
     for res in all_res:
         assert res[0].ok, res[0].error
-    # rounds 1+2 queued while 0 served -> drained as ONE merged batch
+    # rounds 1+2 queued while 0 served -> admitted into ONE tick
     assert batch_sizes[0] == 1
     assert max(batch_sizes) >= 2
     assert sum(batch_sizes) == 3
     # sessions stored per agent despite the merge
     assert all(engine.sessions.get(f"agent-{a}") is not None
                for a in range(3))
+    backend.close()
 
 
 def _shape_kinds(eng) -> set:

@@ -313,8 +313,8 @@ def test_pool_continuous_qos_spec_on_off_bit_identical():
     from quoracle_tpu.models.runtime import QueryRequest, TPUBackend
 
     pool = ["xla:tiny"]
-    off = TPUBackend(pool, continuous=True, continuous_chunk=8, qos=True)
-    on = TPUBackend(pool, continuous=True, continuous_chunk=8, qos=True,
+    off = TPUBackend(pool, continuous_chunk=8, qos=True)
+    on = TPUBackend(pool, continuous_chunk=8, qos=True,
                     draft_map={"xla:tiny": "xla:tiny"}, draft_k=4)
     try:
         assert "xla:tiny" in on._speculators
@@ -345,18 +345,23 @@ def test_pool_continuous_qos_spec_on_off_bit_identical():
         on.close()
 
 
-def test_draft_map_with_continuous_no_longer_raises():
-    """ISSUE 6 acceptance: the PoolRuntime mutual exclusion is gone —
-    draft_map + continuous=True builds a BatchedSpeculator per drafted
-    member instead of raising ValueError."""
-    from quoracle_tpu.models.runtime import TPUBackend
-    b = TPUBackend(["xla:tiny"], continuous=True,
-                   draft_map={"xla:tiny": "xla:tiny"})
+def test_draft_map_builds_a_batched_speculator_a_member():
+    """A draft_map builds ONE BatchedSpeculator per drafted member and
+    hands it to that member's batcher; the draft engine loads but is not
+    a servable pool member."""
+    from quoracle_tpu.models.runtime import QueryRequest, TPUBackend
+    b = TPUBackend(["xla:tiny"],
+                   draft_map={"xla:tiny": "xla:tiny-gemma"})
     try:
-        assert "xla:tiny" in b._speculators
-        assert not b._spec_decoders          # v1 path reserved for baton
+        assert list(b._speculators) == ["xla:tiny"]
         assert b._cbatchers["xla:tiny"].speculator \
             is b._speculators["xla:tiny"]
+        assert "xla:tiny-gemma" in b.engines
+        assert "xla:tiny-gemma" not in b.pool
+        bad = b.query([QueryRequest(
+            "xla:tiny-gemma", [{"role": "user", "content": "hi"}],
+            max_tokens=8)])[0]
+        assert not bad.ok and bad.permanent_error
     finally:
         b.close()
 
@@ -366,18 +371,19 @@ def test_draft_map_with_continuous_no_longer_raises():
 # ---------------------------------------------------------------------------
 
 
-def test_hbm_attribution_tags_draft_engines_and_spec_caches():
+def test_hbm_attribution_tags_draft_engines():
     """ISSUE 6 satellite: draft params must show up ROLE-TAGGED in the
-    per-engine HBM breakdown (never unattributed tail), and the v1
-    decoder's dense session caches attribute to their target member."""
+    per-engine HBM breakdown (never unattributed tail); the draft's
+    shadow sessions live in its own page pool, so nothing of a drafted
+    member is resident outside an engine's attribution."""
     from quoracle_tpu.infra.resources import hbm_attribution
     from quoracle_tpu.models.runtime import QueryRequest, TPUBackend
 
     b = TPUBackend(["xla:tiny"],
                    draft_map={"xla:tiny": "xla:tiny-gemma"})
     try:
-        # one speculative, sessioned query so the v1 decoder holds a
-        # dense cache pair worth attributing
+        # one speculative, sessioned query so the draft engine holds a
+        # shadow session
         r = b.query([QueryRequest(
             "xla:tiny",
             [{"role": "user", "content": "attribute me"}],
@@ -389,12 +395,12 @@ def test_hbm_attribution_tags_draft_engines_and_spec_caches():
         assert members["xla:tiny-gemma"]["role"] == "draft"
         assert members["xla:tiny-gemma"]["draft_for"] == "xla:tiny"
         assert members["xla:tiny-gemma"]["params_bytes"] > 0
-        assert members["xla:tiny"]["spec_cache_bytes"] > 0
-        assert members["xla:tiny"]["spec_cache_sessions"] == 1
+        assert b.engines["xla:tiny-gemma"].sessions.get("hbm1") \
+            is not None
+        assert members["xla:tiny-gemma"]["kv_pool_bytes"] > 0
         assert att["totals"]["draft_params_bytes"] \
             == members["xla:tiny-gemma"]["params_bytes"]
-        assert att["totals"]["spec_cache_bytes"] \
-            == members["xla:tiny"]["spec_cache_bytes"]
+        assert "spec_cache_bytes" not in att["totals"]
     finally:
         b.close()
 

@@ -212,134 +212,6 @@ def test_session_resume_constrained(models, target_engine):
     assert r2.text.lstrip().startswith("{")
 
 
-def test_backend_draft_map_serves_speculatively(tmp_path):
-    """TPUBackend(draft_map=...): eligible queries (single text row,
-    greedy) route through speculative decoding — results are
-    token-identical to a vanilla backend, constrained JSON and sessions
-    included, and the decoder's sessions accumulate residency across
-    refinement-shaped rounds."""
-    from quoracle_tpu.models.loader import register_hf_checkpoint
-    from quoracle_tpu.models.make_checkpoint import make_checkpoint
-    from quoracle_tpu.models.runtime import QueryRequest, TPUBackend
-
-    # tiny target + tiny draft: make_checkpoint's tokenizer training is
-    # deterministic in (corpus, vocab), so both share token ids
-    t_dir = make_checkpoint(str(tmp_path / "t"), family="llama",
-                            scale="tiny", seed=0)
-    d_dir = make_checkpoint(str(tmp_path / "d"), family="llama",
-                            scale="tiny", seed=9)
-    tcfg = register_hf_checkpoint(t_dir, name="specb-t")
-    dcfg = register_hf_checkpoint(d_dir, name="specb-d")
-
-    vanilla = TPUBackend([f"xla:{tcfg.name}"])
-    spec = TPUBackend([f"xla:{tcfg.name}"],
-                      draft_map={f"xla:{tcfg.name}": f"xla:{dcfg.name}"},
-                      draft_k=4)
-    assert f"xla:{tcfg.name}" in spec._spec_decoders
-
-    msgs1 = [{"role": "system", "content": "Respond with JSON."},
-             {"role": "user", "content": "report status"}]
-
-    def ask(backend, msgs, session=None):
-        return backend.query([QueryRequest(
-            f"xla:{tcfg.name}", msgs, temperature=0.0, max_tokens=32,
-            constrain_json=True, session_id=session)])[0]
-
-    # draft engines load but are NOT servable pool members: direct
-    # queries error cleanly, and pool-derived surfaces (Runtime
-    # default_pool, metrics) must use .pool, not .engines
-    assert f"xla:{dcfg.name}" in spec.engines
-    assert f"xla:{dcfg.name}" not in spec.pool
-    bad = spec.query([QueryRequest(f"xla:{dcfg.name}",
-                                   msgs1, max_tokens=8)])[0]
-    assert not bad.ok and bad.permanent_error
-    # a prompt with <1 token of room falls through to the baton path's
-    # context_overflow (the decoder's assert must not surface)
-    long_prompt = [{"role": "user", "content": "x " * 3000}]
-    over = spec.query([QueryRequest(f"xla:{tcfg.name}", long_prompt,
-                                    max_tokens=8)])[0]
-    assert not over.ok and "context_overflow" in (over.error or "")
-
-    want = ask(vanilla, msgs1)
-    got = ask(spec, msgs1)
-    assert got.ok and want.ok
-    assert got.text == want.text, "speculative backend diverged"
-    assert got.usage.completion_tokens == want.usage.completion_tokens
-
-    # session flow: round 2 resumes the decoder session
-    r1 = ask(spec, msgs1, session="ag1")
-    dec = spec._spec_decoders[f"xla:{tcfg.name}"]
-    assert "ag1" in dec._sessions
-    resident = len(dec._sessions["ag1"]["ctx"])
-    msgs2 = msgs1 + [{"role": "assistant", "content": r1.text},
-                     {"role": "user", "content": "refine it"}]
-    r2 = ask(spec, msgs2, session="ag1")
-    assert r2.ok
-    assert len(dec._sessions["ag1"]["ctx"]) > resident
-    # vanilla backend with the same two-round flow agrees at temp 0
-    v1 = ask(vanilla, msgs1, session="vg1")
-    assert v1.text == r1.text
-    v2 = ask(vanilla, msgs2, session="vg1")
-    assert v2.text == r2.text
-    vanilla.close()
-    spec.close()
-
-
-def test_backend_contention_falls_back_to_batching(tmp_path):
-    """Concurrent agents on a draft_map member: the decoder lock is
-    TRY-acquired, so contended rounds take the baton path (cross-agent
-    batch) instead of serializing — every caller gets a correct result
-    either way."""
-    import threading
-
-    from quoracle_tpu.models.loader import register_hf_checkpoint
-    from quoracle_tpu.models.make_checkpoint import make_checkpoint
-    from quoracle_tpu.models.runtime import QueryRequest, TPUBackend
-
-    t_dir = make_checkpoint(str(tmp_path / "t"), family="llama",
-                            scale="tiny", seed=0)
-    tcfg = register_hf_checkpoint(t_dir, name="contend-t")
-    spec = TPUBackend([f"xla:{tcfg.name}"],
-                      draft_map={f"xla:{tcfg.name}": f"xla:{tcfg.name}"},
-                      draft_k=3)
-    vanilla = TPUBackend([f"xla:{tcfg.name}"])
-
-    def ask(backend, i):
-        return backend.query([QueryRequest(
-            f"xla:{tcfg.name}",
-            [{"role": "user", "content": f"concurrent task {i}"}],
-            temperature=0.0, max_tokens=16)])[0]
-
-    # warm compiles single-threaded first (both paths). NOTE: batched
-    # and single-row greedy can legitimately flip near-ties (different
-    # XLA reduction shapes), so the contract under contention is
-    # "every caller gets a correct, complete result from whichever path
-    # served it" — not cross-path text equality.
-    r0 = ask(spec, 0)
-    assert r0.ok
-    uncontended = ask(vanilla, 1)
-
-    results: list = [None] * 4
-
-    def worker(i):
-        results[i] = ask(spec, i)
-
-    threads = [threading.Thread(target=worker, args=(i,))
-               for i in range(4)]
-    for t in threads:
-        t.start()
-    for t in threads:
-        t.join(timeout=120)
-    assert all(r is not None and r.ok and r.text for r in results), results
-    assert all(r.usage.completion_tokens > 0 for r in results)
-    # determinism within a path: re-asking row 0 uncontended reproduces
-    # the speculative path's earlier answer exactly
-    assert ask(spec, 0).text == r0.text
-    assert ask(vanilla, 1).text == uncontended.text
-    spec.close()
-    vanilla.close()
-
-
 def test_property_greedy_equality_random_shapes(models, target_engine):
     """Randomized edge shapes (seeded, not hypothesis — each case costs a
     device call): prompt lengths down to 1, K from 1 up, max_new down to

@@ -81,7 +81,7 @@ def served():
     listening: (result, spans emitted, model name)."""
     events: list = []
     TRACER.add_sink(events.append)
-    b = TPUBackend([MEMBER], continuous=True, continuous_chunk=8)
+    b = TPUBackend([MEMBER], continuous_chunk=8)
     try:
         out = ask(b)
         name = b.engines[MEMBER].cfg.name
@@ -158,7 +158,7 @@ def test_tick_books_the_rows_that_ask_for_a_nucleus():
     not among them."""
     events: list = []
     TRACER.add_sink(events.append)
-    b = TPUBackend([MEMBER], continuous=True, continuous_chunk=8)
+    b = TPUBackend([MEMBER], continuous_chunk=8)
     name = b.engines[MEMBER].cfg.name
     worker = b._cbatchers[MEMBER]
     booked = telemetry.SCHED_NUCLEUS_ROWS_TOTAL
@@ -261,7 +261,7 @@ def test_row_ring_is_bounded_and_gated():
 
 def test_profiler_trace_holds_tick_and_phases_on_one_thread_line(tmp_path):
     from jax.profiler import ProfileData
-    b = TPUBackend([MEMBER], continuous=True, continuous_chunk=8)
+    b = TPUBackend([MEMBER], continuous_chunk=8)
     try:
         closed = ask(b, "bit equality probe").text
         opts = jax.profiler.ProfileOptions()
@@ -365,7 +365,7 @@ def reached(tmp_path_factory):
     events: list = []
     TRACER.add_sink(events.append)
     trace_dir = tmp_path_factory.mktemp("ops")
-    b = TPUBackend([MEMBER], continuous=True, continuous_chunk=8)
+    b = TPUBackend([MEMBER], continuous_chunk=8)
     try:
         start_trace(trace_dir)
         try:

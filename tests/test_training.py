@@ -229,7 +229,7 @@ def _on_off_gate(backend, tmp_path):
 
 
 def test_capture_on_off_bit_identical_mono(tmp_path):
-    b = TPUBackend([MEMBER], continuous=True, continuous_chunk=8,
+    b = TPUBackend([MEMBER], continuous_chunk=8,
                    draft_map={MEMBER: MEMBER}, draft_k=4)
     try:
         _on_off_gate(b, tmp_path)
@@ -239,8 +239,7 @@ def test_capture_on_off_bit_identical_mono(tmp_path):
 
 def test_capture_on_off_bit_identical_cluster(tmp_path):
     from quoracle_tpu.serving.cluster import ClusterPlane
-    cl = ClusterPlane.build([MEMBER], replicas=2, continuous=True,
-                            continuous_chunk=8,
+    cl = ClusterPlane.build([MEMBER], replicas=2, continuous_chunk=8,
                             draft_map={MEMBER: MEMBER}, draft_k=4)
     try:
         _on_off_gate(cl, tmp_path)
@@ -272,7 +271,7 @@ def test_capture_on_off_bit_identical_wire_peer(tmp_path):
 def test_chaos_capture_crash_never_reaches_serving(tmp_path):
     from quoracle_tpu.chaos.faults import CHAOS, FaultPlan, FaultRule
     from quoracle_tpu.infra.flightrec import FLIGHT
-    b = TPUBackend([MEMBER], continuous=True, continuous_chunk=8,
+    b = TPUBackend([MEMBER], continuous_chunk=8,
                    draft_map={MEMBER: MEMBER}, draft_k=4)
     try:
         want = _ask(b, "chaos-w")
@@ -294,7 +293,7 @@ def test_chaos_capture_crash_never_reaches_serving(tmp_path):
 
 def test_chaos_capture_drop_loses_records_not_output(tmp_path):
     from quoracle_tpu.chaos.faults import CHAOS, FaultPlan, FaultRule
-    b = TPUBackend([MEMBER], continuous=True, continuous_chunk=8,
+    b = TPUBackend([MEMBER], continuous_chunk=8,
                    draft_map={MEMBER: MEMBER}, draft_k=4)
     try:
         want = _ask(b, "drop-w")
@@ -476,7 +475,7 @@ def test_flywheel_promote_drain_rollback_live(tmp_path):
     # unified replicas: a disaggregated prefill tier carries no drafts,
     # so promotion would (correctly) skip it — here we want both swapped
     cl = ClusterPlane.build([MEMBER], replicas=2, disaggregate=False,
-                            continuous=True, continuous_chunk=8,
+                            continuous_chunk=8,
                             draft_map={MEMBER: MEMBER}, draft_k=4)
     fc = FleetController(cl)
     try:

@@ -397,7 +397,7 @@ def test_tree_survives_fleet_drain_migration():
     from quoracle_tpu.serving.cluster import ClusterPlane
     from quoracle_tpu.serving.fleet import FleetConfig, FleetController
     cl = ClusterPlane.build([MEMBER], replicas=3, disaggregate=True,
-                            continuous=True, continuous_chunk=8)
+                            continuous_chunk=8)
     fleet = FleetController(cl, FleetConfig(
         min_replicas=1, max_replicas=4, hysteresis_ticks=2,
         cooldown_ticks=2, seed=7))

@@ -151,6 +151,7 @@ def test_backend_serves_multimodal_messages(tmp_path):
                                      messages=msgs2, temperature=0.0,
                                      max_tokens=8)])[0]
     assert r2.ok and r2.text != r.text
+    backend.close()
 
 
 def test_backend_degrades_bad_image_to_text(tmp_path):
@@ -164,6 +165,7 @@ def test_backend_degrades_bad_image_to_text(tmp_path):
                                     messages=msgs, temperature=0.0,
                                     max_tokens=6)])[0]
     assert r.ok, r.error                      # served as text with [image]
+    backend.close()
 
 
 # ---------------------------------------------------------------------------
