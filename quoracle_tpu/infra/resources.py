@@ -185,9 +185,10 @@ def hbm_attribution(backend) -> dict:
             page_b = _kv_page_bytes(e)
             pool_b = 0
             if st.k is not None:
-                pool_b = sum(int(a.nbytes) for a in (st.k, st.v, st.state)
-                             if a is not None)   # a latent pool has no v;
-                                                 # conv layers: state
+                # a latent pool has no v; conv layers: state; a window
+                # group or an ssm model's records: tuples of pools
+                pool_b = sum(int(a.nbytes) for a in jax.tree.leaves(
+                    (st.k, st.v, st.state)))
                 if st.k_scale is not None:
                     pool_b += (int(st.k_scale.nbytes)
                                + int(st.v_scale.nbytes))

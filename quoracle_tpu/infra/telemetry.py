@@ -756,6 +756,34 @@ CONV_STATE_REPREFILL_TOKENS_TOTAL = METRICS.counter(
     "prompt tokens whose K/V was resident and matched but which ran "
     "through the chunk forward again because no conv state is held at the "
     "match's end (reuse is rounded down to a page boundary), per model")
+# -- records of recurrent state in a pool of their own (ISSUE 47) -------------
+# A model with ssm layers holds megabytes of state a session (a matrix a
+# head a layer): ONE live record a session, updated in place by decode, and
+# snapshots the prefix cache keeps at boundaries it chose (generate.py
+# ``_ensure_pool``, ``SessionStore.records``); booked once a tick.
+SSM_STATE_ROWS_TOTAL = METRICS.counter(
+    "quoracle_ssm_state_rows_total",
+    "rows of a tick of a model with ssm layers, per model, by where the "
+    "row's chunk took its state from: source = carried (the session's own "
+    "live record), adopted (a snapshot the prefix cache holds, copied into "
+    "the row's own record by the chunk forward), zero (a sequence's start)")
+SSM_STATE_REPREFILL_TOKENS_TOTAL = METRICS.counter(
+    "quoracle_ssm_state_reprefill_tokens_total",
+    "prompt tokens whose K/V was resident and matched but which ran "
+    "through the chunk forward again because no record holds the state at "
+    "the match's end (a match is cut back to the deepest boundary that "
+    "has a snapshot; a session whose live record lies past the match is "
+    "forgotten), per model")
+SSM_STATE_RECORDS_TOTAL = METRICS.counter(
+    "quoracle_ssm_state_records_total",
+    "records of the ssm state pool, per model, by kind: snapshot (a state "
+    "written at a page boundary and handed to the prefix cache), copy (a "
+    "snapshot read into an adopting row's own record), evicted (a "
+    "snapshot or a session's live record given up under pressure)")
+SSM_STATE_RECORDS_HELD = METRICS.gauge(
+    "quoracle_ssm_state_records_held",
+    "records of the ssm state pool in use, per model and holder (session "
+    "| snapshot | total | pool: the pool's size)")
 # -- retention groups of attention layers (ISSUE 39) -------------------------
 # A model whose window and full attention layers are mixed holds a session's
 # pages in two groups of pools (config.kv_groups; generate.py ``_run_paged``):

@@ -74,13 +74,18 @@ class MoEConfig:
     ``n_routed`` experts; this process HOLDS experts ``held_start ..
     held_start + n_held`` (its share of an expert-parallel deployment; the
     whole set when ``n_held == n_routed``) and computes only their part of
-    a token's result plus the shared expert."""
+    a token's result plus the shared expert. An expert's body is gated,
+    ``act(x W_g) ⊙ (x W_u)`` then ``W_d`` (three matrices), or with
+    ``gated`` false plain, ``act(x W_u) W_d`` (two; Nemotron-H: ``relu2``);
+    the shared expert is ``n_shared`` experts of ``expert_dim`` side by
+    side, or ONE of a width of its own (``shared_dim``)."""
 
     n_routed: int            # the router's width, as published
     n_held: int              # experts held here
     per_token: int           # num_experts_per_tok
     expert_dim: int          # moe_intermediate_size
-    n_shared: int = 1        # shared experts (each expert_dim wide)
+    n_shared: int = 1        # shared experts, expert_dim wide each
+                             # unless ``shared_dim`` gives the width
     n_group: int = 1
     topk_group: int = 1
     routed_scale: float = 1.0
@@ -97,12 +102,72 @@ class MoEConfig:
     # time (DeepSeek-V3, LFM2, Laguna), or "softmax" over all ``n_routed``
     # (Mellum: softmax, then the top k, then ``norm_topk``)
     score: str = "sigmoid"
+    # False: no gate matrix, ``act(x W_u) W_d`` (routed and shared alike)
+    gated: bool = True
+    # the shared expert's width where it is not ``n_shared · expert_dim``
+    shared_dim: Optional[int] = None
+
+    @property
+    def shared_width(self) -> int:
+        """Width of the shared expert's hidden layer (0: the model has
+        none)."""
+        if not self.n_shared:
+            return 0
+        return self.shared_dim or self.expert_dim * self.n_shared
+
+    @property
+    def n_matrices(self) -> int:
+        return 3 if self.gated else 2
 
     def __post_init__(self):
         assert self.n_routed % self.n_group == 0
         assert self.score in ("sigmoid", "softmax"), self.score
         assert 0 <= self.held_start \
             and self.held_start + self.n_held <= self.n_routed
+
+
+@dataclasses.dataclass(frozen=True)
+class SSMConfig:
+    """A recurrent-matrix mixer (Mamba-2, the Nemotron-H form): ``n_heads``
+    heads of ``head_dim`` channels, each with a STATE matrix ``[head_dim,
+    state_dim]`` in float32 that a token decays by ``exp(Δ A)`` and adds
+    ``Δ x ⊗ B`` to; ``B`` and ``C`` (``state_dim`` values each) are shared
+    by the ``n_heads / n_groups`` heads of a group; a causal depthwise
+    convolution of ``conv_kernel`` taps runs over ``[x | B | C]`` first.
+    What a session holds of such a layer is one record whatever its
+    length: the state matrices and the last ``conv_kernel - 1`` conv
+    inputs (``ModelConfig.state_record``). ``chunk``: tokens the chunk
+    forward's scan takes at a time (ops/ssm_scan.py), the engine's page."""
+
+    n_heads: int         # mamba_num_heads
+    head_dim: int        # mamba_head_dim
+    n_groups: int        # n_groups (of B and C; NOT the router's n_group)
+    state_dim: int       # ssm_state_size
+    conv_kernel: int = 4
+    chunk: int = 128
+    # a seeded model's ``dt_bias`` is drawn between these (initialisation
+    # only: time_step_min, time_step_max, time_step_floor; Δ itself is
+    # not clamped)
+    dt_min: float = 0.001
+    dt_max: float = 0.1
+    dt_floor: float = 1e-4
+
+    def __post_init__(self):
+        assert self.n_heads % self.n_groups == 0 and self.conv_kernel >= 2
+
+    @property
+    def d_inner(self) -> int:
+        return self.n_heads * self.head_dim
+
+    @property
+    def conv_dim(self) -> int:
+        """Channels the convolution runs over: x, B and C side by side."""
+        return self.d_inner + 2 * self.n_groups * self.state_dim
+
+    @property
+    def in_dim(self) -> int:
+        """Outputs of the input projection: ``[z | xBC | dt]``."""
+        return self.d_inner + self.conv_dim + self.n_heads
 
 
 @dataclasses.dataclass(frozen=True)
@@ -134,9 +199,12 @@ class ModelConfig:
     hybrids whose layers differ in KIND (LFM2: gated short convolutions
     among per-head attention layers, a dense feed-forward first and routed
     experts after; Laguna: window and full attention layers with their own
-    head counts and rotary). Per-family quirks are data, not subclasses; which
-    forward serves a configuration follows from that data alone
-    (``plain``, ``latent``, ``layer_plan``).
+    head counts and rotary; Nemotron-H: layers that are ONE operator each,
+    a Mamba-2 mixer, an attention with no positional embedding or an
+    expert layer, ``layer_types`` beside ``ff_types``). Per-family quirks
+    are data, not subclasses; which forward serves a configuration follows
+    from that data alone (``plain``, ``latent``, ``layer_plan``), and what
+    a session holds beside its pages from ``state_record``.
     """
 
     name: str
@@ -149,7 +217,8 @@ class ModelConfig:
     head_dim: Optional[int] = None  # defaults to dim // n_heads
     rope_theta: float = 10000.0
     norm_eps: float = 1e-5
-    activation: str = "silu"  # "silu" (llama/mistral) or "gelu" (gemma)
+    # "silu" (llama/mistral), "gelu" (gemma), "relu2" (relu squared)
+    activation: str = "silu"
     tie_embeddings: bool = False
     # Gemma multiplies token embeddings by sqrt(dim) (data, not code, per-family).
     scale_embeddings: bool = False
@@ -178,12 +247,27 @@ class ModelConfig:
     # Learned top-k selection inside the latent attention (latent models
     # only): a second pool holds each token's index key.
     indexer: Optional[IndexerConfig] = None
-    # The token mixer of each layer, "attention", "conv" or the name of one
-    # of ``attn_kinds`` (None: attention everywhere). A "conv" layer is
-    # LFM2's gated short convolution: what a session holds for it is the
-    # last ``conv_cache - 1`` conv inputs, a fixed block whatever the
-    # session's length (``state_lanes``), not per-token rows.
+    # The token mixer of each layer, "attention", "conv", "ssm", the name of
+    # one of ``attn_kinds``, or None: the layer has no mixer (None for the
+    # tuple: attention everywhere). A "conv" layer is LFM2's gated short
+    # convolution: what a session holds for it is the last ``conv_cache -
+    # 1`` conv inputs, a fixed block whatever the session's length
+    # (``state_lanes``), not per-token rows. An "ssm" layer is ``ssm``'s
+    # recurrent-matrix mixer: a record of megabytes a session, in a pool
+    # of records of its own (generate.py ``_ensure_pool``).
     layer_types: Optional[tuple] = None
+    # The feed-forward part of each layer, "dense", "experts" or None: the
+    # layer has none (None for the tuple: every layer has one, dense in
+    # the leading ``moe.first_dense`` layers and experts after). A layer
+    # with one part has one norm.
+    ff_types: Optional[tuple] = None
+    ssm: Optional[SSMConfig] = None
+    # False: attention applies no positional embedding at all
+    rope: bool = True
+    # Records the pool of an "ssm" model holds (generate.py
+    # ``SessionStore.records``): a live one a session, the snapshots the
+    # prefix cache keeps, one a sessionless row of a tick
+    state_records: int = 64
     # ((name, AttnKind), ...): the kinds of attention layer of a model that
     # has several, by the name ``layer_types`` gives a layer (a tuple of
     # pairs, so the config stays hashable). None: one kind, from the
@@ -246,11 +330,27 @@ class ModelConfig:
         if self.layer_types is not None:
             assert len(self.layer_types) == self.n_layers \
                 and set(self.layer_types) <= {
-                    "conv", *(dict(self.attn_kinds) if self.attn_kinds
-                              else ("attention",))}, self.layer_types
-            assert self.latent is None or "conv" not in self.layer_types, \
-                "short-conv layers stand among per-head attention layers"
+                    "conv", "ssm", None,
+                    *(dict(self.attn_kinds) if self.attn_kinds
+                      else ("attention",))}, self.layer_types
+            assert self.latent is None or not (
+                {"conv", "ssm", None} & set(self.layer_types)), \
+                "conv and ssm layers stand among per-head attention layers"
             assert self.conv_cache >= 2
+            assert ("ssm" in self.layer_types) <= (self.ssm is not None)
+            assert not ("ssm" in self.layer_types
+                        and "conv" in self.layer_types), \
+                "one manager of recurrent state a model"
+        if self.ff_types is not None:
+            assert self.latent is None and len(self.ff_types) \
+                == self.n_layers and set(self.ff_types) <= {
+                    "dense", "experts", None}, self.ff_types
+            assert ("experts" in self.ff_types) <= (self.moe is not None)
+            assert all(m is not None or f is not None
+                       for m, f in zip(self.mixers, self.ff_types)), \
+                "a layer is a mixer, a feed-forward part or both"
+        else:
+            assert None not in self.mixers
         assert not self.qk_norm or self.latent is None
 
     @property
@@ -259,17 +359,36 @@ class ModelConfig:
 
     @property
     def plain(self) -> bool:
-        """Dense decoder with per-head K and V in every layer: every path
-        serves it. A model with latent attention, routed experts, conv
-        layers, a q/k norm, kinds of attention layer or a gate on its
-        heads runs on the ragged paged path alone."""
+        """Dense decoder with rotary per-head K and V and a gated MLP in
+        every layer: every path serves it. A model with latent attention,
+        routed experts, conv or ssm layers, layers of one part, attention
+        with no positional embedding, a q/k norm, kinds of attention
+        layer or a gate on its heads runs on the ragged paged path
+        alone."""
         return (self.latent is None and self.moe is None
                 and self.n_conv_layers == 0 and not self.qk_norm
-                and self.attn_kinds is None and not self.attn_gate)
+                and self.attn_kinds is None and not self.attn_gate
+                and self.n_ssm_layers == 0 and self.ff_types is None
+                and self.rope)
 
     @property
     def mixers(self) -> tuple:
         return self.layer_types or ("attention",) * self.n_layers
+
+    @property
+    def ffs(self) -> tuple:
+        """Each layer's feed-forward part: ``ff_types``, or the rule of a
+        model that does not name them."""
+        if self.ff_types is not None:
+            return self.ff_types
+        n_dense = self.n_layers if self.moe is None else self.moe.first_dense
+        return tuple("dense" if i < n_dense else "experts"
+                     for i in range(self.n_layers))
+
+    @staticmethod
+    def is_attention(mixer) -> bool:
+        """Whether a layer of type ``mixer`` holds per-token rows."""
+        return mixer not in ("conv", "ssm", None)
 
     def attn_kind(self, mixer: str = "attention") -> AttnKind:
         """The attention a layer of type ``mixer`` runs: the kind of that
@@ -288,7 +407,7 @@ class ModelConfig:
     @property
     def n_attn_layers(self) -> int:
         """Layers that hold per-token rows in pages (``kv_pools``)."""
-        return self.n_layers - self.n_conv_layers
+        return sum(map(self.is_attention, self.mixers))
 
     @property
     def kv_groups(self) -> tuple:
@@ -305,7 +424,7 @@ class ModelConfig:
             return ((self.sliding_window, self.n_attn_layers),)
         count: dict = {}
         for m in self.mixers:
-            if m != "conv":
+            if self.is_attention(m):
                 w = self.attn_kind(m).window
                 count[w] = count.get(w, 0) + 1
         return tuple(sorted(count.items(),
@@ -330,9 +449,34 @@ class ModelConfig:
         tokens."""
         return (self.conv_cache - 1) * self.dim if self.n_conv_layers else 0
 
+    @property
+    def n_ssm_layers(self) -> int:
+        """Layers that hold a state matrix a head (``ssm``)."""
+        return self.mixers.count("ssm")
+
+    @property
+    def state_record(self) -> tuple:
+        """THE statement of what one state record holds in ONE recurrent
+        layer, ``((lanes, type), ...)`` a part, ``type`` None where the
+        part is kept in the pool's own type. A conv layer: its last
+        ``conv_cache - 1`` conv inputs. An ssm layer: the heads' state
+        matrices in float32, and the last ``conv_kernel - 1`` inputs of
+        its convolution. Pool shapes (generate.py ``_ensure_pool``),
+        ``state_bytes_per_record`` and with it ``quant_stats`` and a
+        family's ``stated_precision`` read this."""
+        if self.n_ssm_layers:
+            m = self.ssm
+            return ((m.n_heads * m.head_dim * m.state_dim, "float32"),
+                    ((m.conv_kernel - 1) * m.conv_dim, None))
+        if self.n_conv_layers:
+            return ((self.state_lanes, None),)
+        return ()
+
     def state_bytes_per_record(self, dtype_bytes: int = 2) -> int:
-        """Bytes of one state record over all conv layers."""
-        return self.n_conv_layers * self.state_lanes * dtype_bytes
+        """Bytes of one state record over all conv or ssm layers."""
+        return (self.n_conv_layers + self.n_ssm_layers) * sum(
+            lanes * (dtype_bytes if t is None else 4)
+            for lanes, t in self.state_record)
 
     @property
     def layer_plan(self) -> tuple:
@@ -341,11 +485,11 @@ class ModelConfig:
         the shortest PERIOD of the layers after them as often as it fits
         whole (one ``lax.scan``: program size does not follow the depth),
         and what is left of a last period once. ``kinds`` is a tuple of
-        (mixer, feed-forward) pairs, "attention" | "conv" and "dense" |
-        "experts"."""
+        (mixer, feed-forward) pairs, what a layer IS: "attention" (or a
+        kind's name) | "conv" | "ssm" | None and "dense" | "experts" |
+        None."""
         n_dense = self.n_dense_layers
-        kinds = tuple((m, "dense" if i < n_dense else "experts")
-                      for i, m in enumerate(self.mixers))
+        kinds = tuple(zip(self.mixers, self.ffs))
         lead, rest = kinds[:n_dense], kinds[n_dense:]
         p = next((p for p in range(1, len(rest) + 1)
                   if all(rest[i] == rest[i + p]
@@ -372,7 +516,13 @@ class ModelConfig:
 
     @property
     def n_dense_layers(self) -> int:
-        return self.n_layers if self.moe is None else self.moe.first_dense
+        """Leading layers whose feed-forward part is the dense one."""
+        return next((i for i, f in enumerate(self.ffs) if f != "dense"),
+                    self.n_layers)
+
+    @property
+    def n_expert_layers(self) -> int:
+        return self.ffs.count("experts")
 
     def _attn_params(self, mixer: str = "attention") -> int:
         if self.latent is not None:
@@ -390,6 +540,8 @@ class ModelConfig:
                       + self.dim * ix.head_dim + self.dim * ix.n_heads
                       + 2 * ix.head_dim)
             return n
+        if not self.is_attention(mixer):
+            return 0
         hd, H = self.head_dim, self.attn_kind(mixer).n_heads
         q = self.dim * H * hd + (H * hd if self.attn_bias else 0)
         kv = 2 * (self.dim * self.n_kv_heads * hd
@@ -402,21 +554,33 @@ class ModelConfig:
         return (self.dim * 3 * self.dim + self.conv_cache * self.dim
                 + self.dim * self.dim)
 
-    def _layer_params(self, experts: Optional[int],
-                      mixer: str = "attention") -> int:
-        """One layer's parameters; ``experts`` None = the dense MLP, else
-        that many routed experts beside the router and the shared ones."""
-        norms = 2 * self.dim
-        if experts is None:
+    def _ssm_params(self) -> int:
+        """A Mamba-2 operator: in (to z, xBC, dt), the taps and their
+        bias, ``dt_bias``, ``A_log`` and ``D`` a head, the gated norm's
+        weight, out."""
+        m = self.ssm
+        return (self.dim * m.in_dim + (m.conv_kernel + 1) * m.conv_dim
+                + 3 * m.n_heads + m.d_inner + m.d_inner * self.dim)
+
+    def _layer_params(self, ff: Optional[str], mixer="attention") -> int:
+        """One layer's parameters: its mixer ``mixer`` (None: none), its
+        feed-forward part ``ff`` ("dense": the gated MLP, "experts": the
+        held routed experts beside the router and the shared one, None:
+        none), and a norm for each part it has."""
+        norms = ((mixer is not None) + (ff is not None)) * self.dim
+        mlp = 0
+        if ff == "dense":
             mlp = 3 * self.dim * self.ffn_dim      # gate + up + down
-        else:
+        elif ff == "experts":
             m = self.moe
             mlp = (self.dim * m.n_routed
                    + (m.n_routed if m.router_bias else 0)
-                   + 3 * self.dim * m.expert_dim * (experts + m.n_shared))
-        op = self._conv_params() if mixer == "conv" else \
-            self._attn_params(mixer) + (2 * self.head_dim
-                                        if self.qk_norm else 0)
+                   + m.n_matrices * self.dim
+                   * (m.expert_dim * m.n_held + m.shared_width))
+        op = (self._conv_params() if mixer == "conv"
+              else self._ssm_params() if mixer == "ssm"
+              else self._attn_params(mixer)
+              + (2 * self.head_dim if self.qk_norm else 0) if mixer else 0)
         return op + mlp + norms
 
     @property
@@ -427,9 +591,8 @@ class ModelConfig:
         embed = self.vocab_size * self.dim
         head = 0 if self.tie_embeddings else self.vocab_size * self.dim
         total = embed + self.dim + head + sum(
-            self._layer_params(
-                None if i < self.n_dense_layers else self.moe.n_held, m)
-            for i, m in enumerate(self.mixers))
+            self._layer_params(f, m)
+            for m, f in zip(self.mixers, self.ffs))
         if self.vision is not None:
             # ViT tower + projector come out of the same HBM budget
             # (models/vision.py init_vision_params structure)
@@ -452,8 +615,8 @@ class ModelConfig:
         if self.moe is None:
             return self.n_params
         m = self.moe
-        return self.n_params - (self.n_layers - m.first_dense) \
-            * 3 * self.dim * m.expert_dim * (m.n_held - m.per_token)
+        return self.n_params - self.n_expert_layers * m.n_matrices \
+            * self.dim * m.expert_dim * (m.n_held - m.per_token)
 
     def kv_bytes_per_token(self, tp: int = 1, dtype_bytes: int = 2,
                            group: Optional[int] = None) -> int:
@@ -477,6 +640,9 @@ def unsupported_path(cfg: ModelConfig, what: str) -> str:
         ("latent attention", cfg.latent is not None),
         ("a learned key selection", cfg.indexer is not None),
         ("short-conv state beside the paged KV", cfg.n_conv_layers > 0),
+        ("a pool of recurrent-state records beside the paged KV",
+         cfg.n_ssm_layers > 0),
+        ("attention with no positional embedding", not cfg.rope),
         ("a q/k norm", cfg.qk_norm),
         ("window and full attention layers mixed", len(cfg.kv_groups) > 1),
         ("a gate on the attention's heads", cfg.attn_gate),

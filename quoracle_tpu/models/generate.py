@@ -31,7 +31,8 @@ from quoracle_tpu.models.config import (
 )
 from quoracle_tpu.models.sampling import sample_tokens
 from quoracle_tpu.models.transformer import (
-    ConvTick, KVCache, forward_hidden, forward_hidden_ragged, init_cache,
+    ConvTick, KVCache, SsmTick, forward_hidden, forward_hidden_ragged,
+    init_cache, put_rows, take_rows,
     moe_stats_len, project_logits,
 )
 
@@ -276,7 +277,9 @@ def decode_ragged(
     k_scale: Optional[jax.Array] = None,   # [L, n_pages, KV, page] f32 —
     v_scale: Optional[jax.Array] = None,   # int8 pools (ISSUE 13)
     shared: Optional[jax.Array] = None,    # [2 + SHARED_ROWS, R] int32
-    state: Optional[jax.Array] = None,     # conv layers' state pool
+    state: Optional[jax.Array] = None,     # conv layers' state pool, or
+                                           # ssm layers' pair of pools
+    records: Optional[jax.Array] = None,   # [R] int32: each row's record
 ) -> tuple:
     """Autoregressive decode through the UNIFIED ragged kernel (ISSUE 8):
     same sampling/grammar semantics as decode(), but each
@@ -304,6 +307,14 @@ def decode_ragged(
     resident token and writes the new one under the page of the token it
     forwards, so a page that fills keeps the state at its end and a row
     that is done leaves its record alone.
+
+    With ``records`` (a model with ssm layers: ``state`` is its pair of
+    record pools, transformer.SsmTick) every row has ONE record — the
+    chunk forward left the state at the row's chunk's end there;
+    ``n_records`` or more: a slot with no row. The loop reads the rows'
+    records once into buffers of its own, every step updates them in
+    place (a row that is done leaves its state alone), and one write
+    behind the loop puts them back.
 
     A model with several retention groups of attention layers
     (config.kv_groups) hands in ``k_pool``, ``v_pool`` and ``tables`` as
@@ -333,6 +344,19 @@ def decode_ragged(
         fns, first_logits, rng, temperature, top_p, active, row_limit,
         json_state, max_new, pad_id)
     lens0 = pool_lens.astype(jnp.int32)
+    if records is not None:
+        # the rows' records, read ONCE into the loop's own buffers
+        # ``[n_ssm_layers, R, ...]`` (a slot with no row: the scratch
+        # record 0) and written back once behind the loop; they lie as
+        # the decode kernel takes them: the state transposed, the
+        # convolution's inputs float32 (transformer.SsmTick)
+        n_rec = state[0].shape[0] // cfg.n_ssm_layers
+        rec_ids = jnp.where(records < n_rec, records, 0)
+        pools, state = state, tuple(
+            jnp.stack([take_rows(pool, c * n_rec + rec_ids)
+                       for c in range(cfg.n_ssm_layers)]) for pool in state)
+        state = (state[0].transpose(0, 1, 3, 2),
+                 state[1].astype(jnp.float32))
 
     def cond(carry):
         i, done, *_ = carry
@@ -364,8 +388,10 @@ def decode_ragged(
                 jnp.arange(R, dtype=jnp.int32),   # one tq=1 block per row
             ])
             positions = lens + kv_off.astype(jnp.int32)
-            conv = None
-            if sp is not None:
+            conv = ssm = None
+            if records is not None:
+                ssm = SsmTick(sp[0], sp[1], None, live)
+            elif sp is not None:
                 # one token a row: it follows the record under the page of
                 # the token before it and is recorded under its own page
                 last = jnp.take_along_axis(
@@ -378,7 +404,7 @@ def decode_ragged(
         hidden, kp, vp, ks, vs, st, *rest = forward_hidden_ragged(
             params, cfg, cur[None], positions[None], kp, vp, tables,
             meta, flat, tq=1, interpret=interpret, shard=shard,
-            k_scale=ks, v_scale=vs, shared=shared, conv=conv)
+            k_scale=ks, v_scale=vs, shared=shared, conv=conv, ssm=ssm)
         if sp is not None:
             sp = rest[0]
         if st is not None:
@@ -409,6 +435,13 @@ def decode_ragged(
         (_, done, _, out, n_emitted, lens, k_pool, v_pool, k_scale,
          v_scale, _, jstate, moe, state) = jax.lax.while_loop(cond, body,
                                                               init)
+    if records is not None:
+        with jax.named_scope("decode_loop"), jax.named_scope("state_write"):
+            state = (state[0].transpose(0, 1, 3, 2), state[1])
+            for c in range(cfg.n_ssm_layers):
+                pools = tuple(put_rows(pool, c * n_rec + rec_ids, rows[c])
+                              for pool, rows in zip(pools, state))
+        state = pools
     return (out, n_emitted, lens, k_pool, v_pool, k_scale, v_scale, jstate,
             moe, state)
 
@@ -521,28 +554,33 @@ class _Session:
     # which it lets go at every store-back, and of an adopted prefix all
     # but the last window's. None: the model has one group.
     wpages: Optional[list[int]] = None
+    # A model with ssm layers (SessionStore.records): the session's ONE
+    # live record, the state at its end, which its decode steps update in
+    # place. On a prefix marker: the snapshot the match ends at, and
+    # ``matched`` the tokens the radix cache matched in all — more than
+    # ``tokens`` where no snapshot stands at the match's end.
+    record: int = 0
+    matched: int = 0
 
     @property
     def resident_len(self) -> int:
         return len(self.tokens) - self.start_pos
 
 
-class _WindowPages:
-    """The page ids of a WINDOW group's pools (config.kv_groups): a second
-    id space beside the store's own, with its own free list and reference
-    counts (absent key = 1, as the store's). Page 0 is scratch here too: a
-    table entry of 0 is a page the session does not hold. The store's lock
-    guards it."""
+class _IdPool:
+    """An id space beside the store's own pages, with its own free list
+    and reference counts (absent key = 1, as the store's): the page ids of
+    a WINDOW group's pools (``_WindowPages``) and the RECORDS of a model
+    with ssm layers (``SessionStore.records``: a record has one owner — a
+    session, or a node of the radix cache — and a second reference only
+    while a tick reads it, so that no eviction hands it out meanwhile). Id
+    0 is scratch: never handed out, and 0 in a table or on a session means
+    none. The store's lock guards it."""
 
-    def __init__(self, n_pages: int, window: int):
-        self.n_pages = n_pages
-        self.tokens = window          # positions a query reaches back
-        self._free: list[int] = list(range(n_pages - 1, 0, -1))
+    def __init__(self, n_ids: int):
+        self.n_ids = n_ids
+        self._free: list[int] = list(range(n_ids - 1, 0, -1))
         self._refs: dict[int, int] = {}
-
-    def first_page(self, pos: int, page: int) -> int:
-        """The first page a query at position ``pos`` still reaches."""
-        return max(pos - self.tokens + 1, 0) // page
 
     def take(self, n: int) -> Optional[list[int]]:
         if n > len(self._free):
@@ -570,6 +608,24 @@ class _WindowPages:
         return freed
 
 
+class _WindowPages(_IdPool):
+    """The page ids of a WINDOW group's pools (config.kv_groups). Page 0
+    is scratch here too: a table entry of 0 is a page the session does not
+    hold."""
+
+    def __init__(self, n_pages: int, window: int):
+        super().__init__(n_pages)
+        self.tokens = window          # positions a query reaches back
+
+    @property
+    def n_pages(self) -> int:
+        return self.n_ids
+
+    def first_page(self, pos: int, page: int) -> int:
+        """The first page a query at position ``pos`` still reaches."""
+        return max(pos - self.tokens + 1, 0) // page
+
+
 class SessionStore:
     """Paged session cache (VERDICT r2 item 4): sessions are PAGE LISTS
     into one pool; resume moves no KV data host-side — the jitted step
@@ -580,7 +636,7 @@ class SessionStore:
     paged steps (the pool buffers are donated through them)."""
 
     def __init__(self, max_tokens: int = 262_144, page: int = PAGE,
-                 window: Optional[tuple] = None):
+                 window: Optional[tuple] = None, records: int = 0):
         from quoracle_tpu.analysis.lockdep import named_lock
         self.page = page
         self.n_pages = max(3, -(-max_tokens // page) + 1)   # +1 scratch
@@ -595,6 +651,13 @@ class SessionStore:
         if window is not None:
             self.window = _WindowPages(
                 max(3, -(-window[0] // page) + 1), window[1])
+        # ``records``: the size of the record pool of a model with ssm
+        # layers (config.state_records; GenerateEngine._ensure_pool has
+        # what a record holds). A session owns ONE live record; the radix
+        # cache owns the snapshots it keeps; ``alloc_record`` hands them
+        # out and evicts.
+        self.records: Optional[_IdPool] = \
+            _IdPool(max(3, records)) if records else None
         self.lock = named_lock("session.store", rlock=True)
         self._sessions: dict[str, _Session] = {}
         self._free: list[int] = list(range(self.n_pages - 1, 0, -1))
@@ -619,8 +682,10 @@ class SessionStore:
         # A model with conv layers: one state record a page a conv layer,
         # addressed by the same page ids (GenerateEngine._ensure_pool), so
         # adoption, copy-on-write, reference counts and eviction carry a
-        # page's record with no bookkeeping of its own.
-        self.state: Optional[jax.Array] = None
+        # page's record with no bookkeeping of its own. A model with ssm
+        # layers: the PAIR of record pools, addressed by record ids
+        # (``records``), not by pages.
+        self.state = None
         # Tiered KV (ISSUE 7, serving/kvtier.py): when attached, alloc's
         # eviction ladder DEMOTES victims to the host tier instead of
         # destroying them, and the engine's session lookup restores
@@ -755,11 +820,47 @@ class SessionStore:
                     if p not in cached and c >= w._refs.get(p, 1))
         return len(w._free) + n_tree + extra
 
+    def alloc_record(self, n: int = 1, protect: tuple = (),
+                     evict: bool = True) -> Optional[list[int]]:
+        """``alloc`` for RECORDS (a model with ssm layers). The ladder
+        when the free list runs dry: first the radix cache gives up
+        snapshots no tick is reading, least recently matched first (the
+        node keeps its page: a later match there is cut back to a deeper
+        snapshot or re-prefilled), then LRU sessions go (never the
+        ``protect`` keys), whole, pages and record. None — with nothing
+        evicted — when even that cannot make ``n`` records."""
+        r = self.records
+        with self.lock:
+            if n <= len(r._free) or not evict:
+                return r.take(n)
+            victims = [k for k, s in self._sessions.items()
+                       if k not in protect and s.record]
+            if n > len(r._free) + self.prefix_cache.idle_records() \
+                    + len(victims):
+                return None
+            from quoracle_tpu.infra.telemetry import SSM_STATE_RECORDS_TOTAL
+            while len(r._free) < n:
+                freed = self.prefix_cache.strip_records(n - len(r._free))
+                if not freed:
+                    if not victims:
+                        break
+                    lru = min(victims,
+                              key=lambda k: self._sessions[k].last_used)
+                    victims.remove(lru)
+                    self._release_session(self._sessions.pop(lru))
+                    freed = 1
+                SSM_STATE_RECORDS_TOTAL.inc(freed, model=self.model,
+                                            kind="evicted")
+            return r.take(n)
+
     def _release_session(self, sess: "_Session") -> None:
-        """Give up a session's references in every group."""
+        """Give up a session's references in every group, and its live
+        record."""
         self._release(sess.pages)
         if sess.wpages:
             self.window.release(sess.wpages)
+        if sess.record:
+            self.records.release([sess.record])
 
     def _release(self, pages: list[int]) -> None:
         for p in pages:
@@ -810,6 +911,19 @@ class SessionStore:
             pages, matched = self.prefix_cache.match(tokens, max_reuse)
             if matched < self.page:
                 return None
+            if self.records is not None:
+                # a new session starts from a SNAPSHOT: the match is cut
+                # back to the deepest boundary that holds one (none: to
+                # nothing), and the marker says how far the pages matched
+                # — the engine prefills the rest again and takes a
+                # snapshot where the match ended
+                recs = self.prefix_cache.records_of(pages)
+                held = max((j + 1 for j, r in enumerate(recs) if r),
+                           default=0)
+                return _Session(
+                    tokens=list(tokens[:held * self.page]),
+                    pages=pages[:held], start_pos=0, shared_prefix=True,
+                    record=recs[held - 1] if held else 0, matched=matched)
             wpages = None
             if self.window is not None:
                 # the full group's pages whole; of the window group's what
@@ -852,6 +966,8 @@ class SessionStore:
             old = self._sessions.get(key)
             if old is not None and old is not sess:
                 self._release([p for p in old.pages if p not in sess.pages])
+                if old.record and old.record != sess.record:
+                    self.records.release([old.record])
                 if old.wpages:
                     self.window.release([p for p in old.wpages
                                          if p not in (sess.wpages or ())])
@@ -1209,7 +1325,8 @@ class GenerateEngine:
                       cfg.kv_groups[1][0])
         self.sessions = SessionStore(
             max_tokens=max(PAGE, min(session_max_bytes // token_bytes,
-                                     32 * self.max_seq)), window=window)
+                                     32 * self.max_seq)), window=window,
+            records=cfg.state_records if cfg.n_ssm_layers else 0)
         self.sessions.model = cfg.name     # metric label (alloc drift,
                                            # tier counters)
         # The paged steps donate the pool buffers; calls that touch the pool
@@ -1221,6 +1338,10 @@ class GenerateEngine:
         # the adoption site. Tests flip it off to compare. The flag gates
         # both cache lookups and store-back inserts.
         self.prefix_sharing = True
+        # a model with ssm layers: {session id: tokens} where a first-wave
+        # row of a batch is to leave a snapshot for the rows deferred
+        # behind it (``_prefix_wave_split``; read under ``_paged_lock``)
+        self._wave_snaps: dict = {}
         # Grammar-table cache has its OWN lock so sessionless calls (image
         # rows, models/runtime.py) can run concurrently with the continuous
         # batcher's sessioned chunks without serializing on _paged_lock —
@@ -1453,8 +1574,8 @@ class GenerateEngine:
         )
         # (kinds of attention layer share the one tile table: the widest's)
         self._ragged_tile = ragged_tile(
-            cfg.max_heads, cfg.head_dim, RAGGED_TQ) if cfg.latent is None \
-            else 0
+            cfg.max_heads, cfg.head_dim, RAGGED_TQ,
+            cfg.max_heads // cfg.n_kv_heads) if cfg.latent is None else 0
         # pages a loop iteration of the decode program's walks carries, as
         # the kernel reckons it: from a page's bytes on one shard, or for a
         # latent pool from the keys a block scores at once (its chunk
@@ -1631,14 +1752,20 @@ class GenerateEngine:
             # ``state`` / ``conv``: a model with conv layers hands in its
             # state pool (donated like the others) and where the tick's
             # rows read and write it (ConvTick's fields after the pool).
-            if state is not None:
+            # A model with ssm layers hands in its PAIR of record pools
+            # and SsmTick's fields after them.
+            ssm = None
+            if cfg.n_ssm_layers:
+                ssm, conv = SsmTick(*state, *conv), None
+            elif state is not None:
                 conv = ConvTick(state, *conv)
             hidden, k_pool, v_pool, k_scale, v_scale, moe, *rest = \
                 forward_hidden_ragged(
                     params, cfg, tokens_flat[None], positions_flat[None],
                     k_pool, v_pool, row_tables, block_meta, flat_dst,
                     tq=tq, shard=ragged_shard, k_scale=k_scale,
-                    v_scale=v_scale, tiles=tiles, tile=tile, conv=conv)
+                    v_scale=v_scale, tiles=tiles, tile=tile, conv=conv,
+                    ssm=ssm)
             last_h = hidden[0][last_idx]                  # [R, D]
             last = project_logits(params, cfg, last_h[:, None])[:, 0, :]
             return (last, k_pool, v_pool, k_scale, v_scale, moe,
@@ -1706,7 +1833,8 @@ class GenerateEngine:
                                      pool_lens, kv_off, last_logits, rng,
                                      temperature, top_p, active,
                                      row_limit, json_table, json_state,
-                                     state=None, *, max_new: int):
+                                     state=None, records=None, *,
+                                     max_new: int):
             # Decode continuation of the unified tick: KV written straight
             # to pages inside the loop (no tail buffer, no tail scatter);
             # attention is the same ragged kernel at tq=1 (int8 pools
@@ -1719,7 +1847,7 @@ class GenerateEngine:
                 pad_id=self.tokenizer.pad_id, stop_ids=cfg.stop_token_ids,
                 json_table=json_table, json_state=json_state,
                 shard=ragged_shard, k_scale=k_scale, v_scale=v_scale,
-                shared=shared, state=state)
+                shared=shared, state=state, records=records)
 
         self._step_paged_ragged = step_paged_ragged
         self._step_paged_ragged_verify = step_paged_ragged_verify
@@ -1875,6 +2003,7 @@ class GenerateEngine:
         page = st.page
         first: list[int] = []
         later: list[int] = []
+        self._wave_snaps.clear()
         from collections import Counter
         sid_counts = Counter(s for s in session_ids if s)
         with st.lock:
@@ -1891,16 +2020,23 @@ class GenerateEngine:
                 if st._sessions.get(sid) is not None:
                     continue        # resident: resumes off its own pages
                 cap = len(prompts[i]) - 1
-                best = 0
+                best, donor = 0, None
                 for j in first:
                     l = min(_lcp(prompts[j], prompts[i]), cap)
-                    best = max(best, (l // page) * page)
+                    if (l // page) * page > best:
+                        best, donor = (l // page) * page, j
                 # defer only when waiting gains >= 1 full page over what
                 # the cache would already serve this row today
                 if (best >= page and
                         st.prefix_cache.match_len(prompts[i], cap)
                         < best):
                     later.append(i)
+                    # a model with ssm layers: the first wave's row leaves
+                    # a snapshot where the deferred row will start from
+                    # (its deepest such boundary: a row takes one a tick)
+                    sid_j = session_ids[donor]
+                    self._wave_snaps[sid_j] = max(
+                        self._wave_snaps.get(sid_j, 0), best)
                 else:
                     first.append(i)
         return later
@@ -1965,6 +2101,9 @@ class GenerateEngine:
         if cfg.n_conv_layers:
             geometry += (f"-A{cfg.n_attn_layers}"
                          f"-conv{cfg.n_conv_layers}x{cfg.state_lanes}")
+        if cfg.n_ssm_layers:
+            geometry += (f"-A{cfg.n_attn_layers}-ssm{cfg.n_ssm_layers}x"
+                         + "+".join(str(n) for n, _ in cfg.state_record))
         if len(cfg.kv_groups) > 1:
             geometry += "-G" + "+".join(
                 f"{layers}w{window or 0}" for window, layers in cfg.kv_groups)
@@ -2090,9 +2229,10 @@ class GenerateEngine:
         (the scheduler's relative-state convention). Every row must be
         sessioned; speculative serving never runs on sliding-window or
         vision engines (the BatchedSpeculator enforces eligibility)."""
-        if self.cfg.n_conv_layers or self.sessions.window is not None:
-            # a rejected draft's tokens would have advanced the conv
-            # state, and no record is kept to roll it back to; a window
+        if self.cfg.n_conv_layers or self.cfg.n_ssm_layers \
+                or self.sessions.window is not None:
+            # a rejected draft's tokens would have advanced the conv or
+            # ssm state, and no record is kept to roll it back to; a window
             # group's pages behind a rejected draft are gone by then
             raise ValueError(unsupported_path(
                 self.cfg, "verify_chunk (speculative drafts)"))
@@ -2152,7 +2292,12 @@ class GenerateEngine:
 
         sess_rows: list[Optional[_Session]] = [None] * n
         reuse_abs = [0] * n
-        reprefill = 0        # matched tokens that hold no conv state
+        reprefill = 0        # matched tokens that hold no conv or ssm state
+        # a model with ssm layers: where each new row's chunk forward is to
+        # take a SNAPSHOT for the radix cache (tokens from the prompt's
+        # start, a whole number of pages; 0: nowhere) — the end of a cached
+        # prefix that the row matched and found no snapshot at
+        snap_at = [0] * n
         kv_off_host = [0] * n
         store_sids: list[Optional[str]] = [None] * n
         paged = False
@@ -2193,6 +2338,19 @@ class GenerateEngine:
                             # radix cache, where that still holds its prompt)
                             self.sessions.drop(sid)
                             s = None
+                    lost = 0
+                    if s is not None and self.cfg.n_ssm_layers:
+                        # the session's ONE record is the state at its
+                        # end: a prompt that parts from its tokens before
+                        # that cannot go on from it. The session is
+                        # forgotten, and the row starts over as a new one,
+                        # from the deepest snapshot the radix cache holds
+                        # on its way
+                        lost = min(_lcp(s.tokens, prompts[i]),
+                                   len(prompts[i]) - 1)
+                        if lost < len(s.tokens):
+                            self.sessions.drop(sid)
+                            s = None
                     if s is None:
                         # Cross-session prefix sharing: a NEW session whose
                         # prompt starts with a RADIX-CACHED page-aligned
@@ -2221,10 +2379,21 @@ class GenerateEngine:
                             with tick_op("prefix_match"):
                                 d = (self.sessions.match_prefix(
                                     prompts[i], cap) if cap > 0 else None)
-                            if d is not None:
+                            if d is not None and d.tokens:
                                 sess_rows[i] = d
                                 reuse_abs[i] = len(d.tokens)
                                 kv_off_host[i] = 0
+                            if self.cfg.n_ssm_layers:
+                                matched = d.matched if d is not None else 0
+                                # where a later wave's rows will start
+                                # from, or the end of a match that found
+                                # no snapshot: the deeper of the two
+                                snap = max(matched,
+                                           self._wave_snaps.pop(sid, 0))
+                                if snap > reuse_abs[i]:
+                                    snap_at[i] = snap
+                                reprefill += max(matched, lost) \
+                                    - reuse_abs[i]
                         continue
                     # ≥1 suffix token must run to produce last-position
                     # logits (verify mode: the whole K_i window must run —
@@ -2258,7 +2427,7 @@ class GenerateEngine:
                 require_plain(self.cfg,
                               "the sequence-parallel ring / image rows")
             paged = True
-        if self.cfg.n_conv_layers:
+        if self.cfg.n_conv_layers or self.cfg.n_ssm_layers:
             with tick_op("account"):
                 self._note_state(sess_rows, reuse_abs, reprefill)
         prefixes = [r - o for r, o in zip(reuse_abs, kv_off_host)]  # buffer
@@ -2385,7 +2554,7 @@ class GenerateEngine:
                     chunk_arr, limits, rng_key, samp, json_args, max_new,
                     put, mat, row,
                     (temp_arr, top_arr, active, limits), jstate_np,
-                    verify=vrun)
+                    verify=vrun, snap_at=snap_at)
         else:
             if images is not None and any(i is not None for i in images):
                 vc = self.cfg.vision
@@ -2608,9 +2777,14 @@ class GenerateEngine:
         session's own below a match that ended inside a page), or zeros
         (``cold``: a sequence's start); and the matched tokens that ran
         again for want of a record where the match ended."""
-        from quoracle_tpu.infra.telemetry import (
-            CONV_STATE_REPREFILL_TOKENS_TOTAL, CONV_STATE_ROWS_TOTAL,
-        )
+        from quoracle_tpu.infra import telemetry
+        # a model with ssm layers books the same three sources under the
+        # record pool's own names (``adopted``: a snapshot of the radix
+        # cache's, copied)
+        kind = "SSM" if self.cfg.n_ssm_layers else "CONV"
+        rows_total = getattr(telemetry, f"{kind}_STATE_ROWS_TOTAL")
+        reprefill_total = getattr(
+            telemetry, f"{kind}_STATE_REPREFILL_TOKENS_TOTAL")
         rows = {"carried": 0, "adopted": 0, "zero": 0}
         for s, r in zip(sess_rows, reuse_abs):
             rows["zero" if not r else
@@ -2618,8 +2792,8 @@ class GenerateEngine:
                  else "adopted"] += 1
         name = self.cfg.name
         for source, k in rows.items():
-            CONV_STATE_ROWS_TOTAL.inc(k, model=name, source=source)
-        CONV_STATE_REPREFILL_TOKENS_TOTAL.inc(reprefill, model=name)
+            rows_total.inc(k, model=name, source=source)
+        reprefill_total.inc(reprefill, model=name)
         tick_note(state_rows_carried=rows["carried"],
                   state_rows_adopted=rows["adopted"],
                   state_rows_cold=rows["zero"],
@@ -2740,8 +2914,23 @@ class GenerateEngine:
         the state at the session's end, which is what its next turn
         continues from. The page id addresses both, so reference counts,
         copy-on-write, the radix cache and eviction carry a page's record
-        with the page. The serving programs carry
-        these two buffers through their layer scan and decode loop and
+        with the page. A model with SSM layers (Mamba-2) holds megabytes
+        of state a session — a float32 matrix ``[head_dim, state_dim]`` a
+        head a layer, 2 MiB a layer at 64 heads of 64 × 128 — which no
+        page could carry (a record a page would be tens of GiB): its
+        ``st.state`` is a PAIR of pools of RECORDS, ``[n_ssm_layers ·
+        n_records, H·P, N]`` float32 and ``[n_ssm_layers · n_records,
+        (conv_kernel - 1) · conv_dim]``, addressed by record ids of their
+        own (``SessionStore.records``, ``cfg.state_records`` of them). A
+        session owns ONE live record, the state at its end, which its
+        chunk forwards and decode steps update in place; the radix cache
+        owns SNAPSHOTS at the boundaries the engine chose (prefix_cache.py;
+        ``_run_paged``: the end of a cached prefix that a new session
+        matched and found none at); a row without a session takes a record
+        for the length of its tick. Adoption is a copy: the chunk forward
+        reads the snapshot and writes the row's own record. The serving
+        programs carry
+        these buffers through their layer scan and decode loop and
         update them in place; who wants ``[…, KV, hd]`` takes a view —
         a reshape of the fresh rows on the device, of the pages on the
         host (serving/kvtier.py).
@@ -2788,6 +2977,19 @@ class GenerateEngine:
         if self.cfg.n_conv_layers:
             st.state = jnp.zeros((self.cfg.n_conv_layers * st.n_pages,
                                   self.cfg.state_lanes), self.pool_dtype)
+        if self.cfg.n_ssm_layers:
+            # the RECORD pool (config.state_record has what a record holds
+            # in a layer): a layer's state matrices, float32, ``[H·P, N]``
+            # a record — the form the scan kernel reads and writes — and
+            # its convolution's last inputs, flat over (layer, record) as
+            # the conv layers' pool is
+            m, n_rec = self.cfg.ssm, self.cfg.n_ssm_layers \
+                * st.records.n_ids
+            (ssm_lanes, _), (conv_lanes, _) = self.cfg.state_record
+            st.state = (
+                jnp.zeros((n_rec, ssm_lanes // m.state_dim, m.state_dim),
+                          jnp.float32),
+                jnp.zeros((n_rec, conv_lanes), self.pool_dtype))
         st.k, st.v = k, v
 
     def _window_row(self, s: Optional[_Session], pre: int, need: int,
@@ -2893,7 +3095,7 @@ class GenerateEngine:
                    kv_off_host, store_sids, B, maxp, tokens, pre_arr,
                    off_arr, chunk_arr, limits, rng_key, samp, json_args,
                    max_new, put, mat, row, samp_np, jstate_np,
-                   verify=None):
+                   verify=None, snap_at=None):
         """The paged-session call: the ragged programs write the suffix's
         and the response's KV straight to the rows' pages; where the
         tick cannot take them (``ragged_fallback``) the gather programs
@@ -3072,6 +3274,64 @@ class GenerateEngine:
                             win.release(wtmp)
                     wtemps = [[] for _ in range(n)]
 
+            # A model with ssm layers: each row's records (_ensure_pool).
+            # ``rec_src`` the record a row's state starts from (its
+            # session's own, a snapshot's, or -1: zeros), ``rec_dst`` the
+            # one its state at the tick's end is written to (a storing
+            # row's own — the session's, or a fresh one that the stored
+            # session will own — a sessionless row's for the tick), and
+            # ``snaps`` (tokens into the row's chunk, record) where the
+            # chunk forward also writes a snapshot for the radix cache.
+            # A snapshot a row adopts is held by a second reference until
+            # the steps have run: no eviction below may hand it out.
+            rec = st.records
+            rec_taken: list[int] = []     # fresh records no session owns yet
+            rec_pinned: list[int] = []
+            rec_temp: list[int] = []
+            ssm_rows = None
+            if rec is not None and fallback is None:
+                rec_src = np.full((n,), -1, np.int32)
+                rec_dst = np.full((n,), rec.n_ids, np.int32)
+                snaps = [(0, 0)] * n
+                for i in range(n):
+                    s = sess_rows[i]
+                    if s is not None and s.record:
+                        rec_src[i] = s.record
+                        if s.shared_prefix:
+                            rec.acquire([s.record])
+                            rec_pinned.append(s.record)
+                for i in range(n):
+                    stored = st._sessions.get(store_sids[i] or "")
+                    if dst_lists[i] is None:
+                        got = st.alloc_record(1, evict=False)
+                        rec_temp += got or []
+                    elif stored is not None and stored.record:
+                        got = [stored.record]
+                    else:
+                        got = st.alloc_record(1, protect=protect)
+                        rec_taken += got or []
+                    if got is None:
+                        fallback = "no free record for a row's state"
+                        break
+                    rec_dst[i] = got[0]
+                    at = (snap_at[i] if snap_at else 0) - reuse_abs[i]
+                    seg = min(len(suffixes[i]),
+                              maxp * page - int(pre_arr[i]))
+                    if dst_lists[i] is not None and 0 < at < seg \
+                            and reuse_abs[i] % page == 0:
+                        got = st.alloc_record(1, protect=protect)
+                        if got is not None:
+                            snaps[i] = (at, got[0])
+                            rec_taken += got
+                ssm_rows = (rec_src, rec_dst, snaps)
+                if fallback is not None:
+                    rec.release(rec_taken + rec_pinned + rec_temp)
+                    rec_taken, rec_pinned, rec_temp = [], [], []
+                    for i, tmp in enumerate(temp_lists):
+                        if tmp:
+                            st._release(tmp)
+                        temp_lists[i] = None
+
         vout = None
         if fallback is not None and not self.cfg.plain:
             # give back what this call took, then refuse: the gather
@@ -3110,7 +3370,7 @@ class GenerateEngine:
              now) = self._run_unified(
                  n, suffixes, dst, pre_arr, off_arr, chunk_arr,
                  samp_np, jstate_np, json_args[0], rng_key, max_new,
-                 maxp, verify, wdst)
+                 maxp, verify, wdst, ssm_rows)
         elif verify is not None:
             # Speculative verify: ONE teacher-forced chunk forward with
             # window logits (no decode loop). The chunk KV scatters back
@@ -3193,8 +3453,16 @@ class GenerateEngine:
                 # put_raw: page lifecycle handled explicitly above (the old
                 # session's pages are all in dst_lists + spills, so the
                 # releases above cover exactly the no-longer-referenced ones)
+                record = 0
+                if rec is not None:
+                    # the record the tick wrote the row's end state to is
+                    # the session's live one from now on
+                    record = int(ssm_rows[1][i])
+                    if record in rec_taken:
+                        rec_taken.remove(record)
                 st.put_raw(sid, _Session(tokens=toks, pages=pages,
-                                         start_pos=start, wpages=wpages))
+                                         start_pos=start, wpages=wpages,
+                                         record=record))
                 # Radix prefix cache insert: every FULL page of the stored
                 # conversation (prompt + retained response KV) becomes
                 # adoptable by future sessions. Windowed/trimmed sessions are
@@ -3223,6 +3491,9 @@ class GenerateEngine:
                 for i in range(n):
                     with st.lock:
                         win.release(wtemps[i] + wadopted[i])
+            if rec is not None:
+                self._commit_records(prompts, snap_at, ssm_rows, rec_taken,
+                                     rec_pinned, rec_temp)
             # temp pages (sessionless rows of a ragged tick) die with the call
             for tmp in temp_lists:
                 if tmp:
@@ -3235,9 +3506,42 @@ class GenerateEngine:
                     st.release(pages)
         return out, n_emitted, jstate_f, t_prefill, now, vout
 
+    def _commit_records(self, prompts, snap_at, ssm_rows, taken: list,
+                        pinned: list, temps: list) -> None:
+        """After a tick of a model with ssm layers: the snapshots its
+        chunk forward wrote go to the radix cache (the node at the
+        snapshot's depth takes the record; where it has one by now, or is
+        gone, the record goes back), what the tick borrowed goes back (the
+        second reference on each adopted snapshot, ``pinned``: one a copy
+        the chunk forward made; a sessionless row's record, ``temps``),
+        and the pool's occupancy is booked."""
+        from quoracle_tpu.infra.telemetry import (
+            SSM_STATE_RECORDS_HELD, SSM_STATE_RECORDS_TOTAL,
+        )
+        st, name = self.sessions, self.cfg.name
+        rec = st.records
+        with st.lock:
+            kept = 0
+            for i, (_, rid) in enumerate(ssm_rows[2]):
+                if rid and st.prefix_cache.attach_record(
+                        prompts[i][:snap_at[i]], rid):
+                    taken.remove(rid)
+                    kept += 1
+            rec.release(taken + pinned + temps)
+            n_sess = sum(1 for s in st._sessions.values() if s.record)
+            n_snap = st.prefix_cache.stats()["cached_records"]
+        SSM_STATE_RECORDS_TOTAL.inc(kept, model=name, kind="snapshot")
+        SSM_STATE_RECORDS_TOTAL.inc(len(pinned), model=name, kind="copy")
+        for holder, v in (("session", n_sess), ("snapshot", n_snap),
+                          ("total", rec.n_ids - 1 - len(rec._free)),
+                          ("pool", rec.n_ids - 1)):
+            SSM_STATE_RECORDS_HELD.set(v, model=name, holder=holder)
+        tick_note(ssm_records_held=rec.n_ids - 1 - len(rec._free),
+                  ssm_records_pool=rec.n_ids - 1, ssm_snapshots=kept)
+
     def _run_unified(self, n, suffixes, dst, pre_arr, off_arr, chunk_arr,
                      samp_np, jstate_np, json_table, rng_key,
-                     max_new, maxp, verify, wdst=None):
+                     max_new, maxp, verify, wdst=None, ssm_rows=None):
         """One UNIFIED ragged tick (ISSUE 8): lay every row's suffix out
         token-major (segments padded to RAGGED_TQ blocks so a block never
         spans rows), run ONE mixed chunk forward through the ragged
@@ -3341,8 +3645,11 @@ class GenerateEngine:
                 starts.append(cur)
                 cur += nb * TQ
             self._pending.padded_tokens = TB
-        conv = None
-        if st.state is not None:
+        conv = records = None
+        if ssm_rows is not None:
+            conv, records = self._ssm_tick(n, R, TB, segs, starts,
+                                           last_idx, ssm_rows)
+        elif st.state is not None:
             # transformer.ConvTick's fields after the pool: the record each
             # row starts from, where each flat token's predecessors lie
             # (from its row and its place in its chunk; padding: its own
@@ -3462,7 +3769,7 @@ class GenerateEngine:
                 self._step_paged_decode_ragged(
                     self.params, st.k, st.v, st.k_scale, st.v_scale,
                     *tables, last_logits, rng_key, *samp, json_table,
-                    js_dev, st.state, max_new=max_new)
+                    js_dev, st.state, records, max_new=max_new)
         tick_phase("wait_decode")
         # the first fetch blocks until the program has ended (its copy is
         # queued behind the program: a fence before it would put the
@@ -3477,6 +3784,11 @@ class GenerateEngine:
             final_lens = np.asarray(final_lens)
             jax.block_until_ready(st.k)
         now = time.monotonic()
+        if records is not None:
+            # the rows' forwards in the decode loop: each read and wrote
+            # its record once (a row's first token is the chunk forward's)
+            tick_note(ssm_row_steps=int(np.maximum(
+                n_emitted[:n] - 1, 0).sum()))
         if moe_pre is not None:
             with tick_op("fetch"):
                 moe = np.asarray(moe_pre) + np.asarray(moe_dec)
@@ -3486,6 +3798,61 @@ class GenerateEngine:
             self._note_attention(n, segs, r_pool_lens, final_lens, walked,
                                  shared, page)
         return out, n_emitted, final_lens, jstate_f, None, t_prefill, now
+
+    def _ssm_tick(self, n, R, TB, segs, starts, last_idx,
+                  ssm_rows) -> tuple:
+        """transformer.SsmTick's fields after the pools for one chunk
+        forward, and the rows' records for the decode loop behind it: the
+        records each row reads and writes, its tokens' predecessors for
+        the convolution (``conv_past``), and the SCAN layout — every
+        row's tokens from a chunk's first slot, ``cfg.ssm.chunk`` to a
+        chunk, ``TB // chunk + R`` chunks (each row wastes less than
+        one). A row that takes a snapshot starts on a page boundary, and
+        the chunk divides the page: the snapshot's place is a chunk's
+        end."""
+        m = self.cfg.ssm
+        Q, NR = m.chunk, self.sessions.records.n_ids
+        assert self.sessions.page % Q == 0
+        rec_src, rec_dst, snaps = ssm_rows
+        with tick_op("state_adopt"):
+            NC = TB // Q + R
+            src = np.full((R,), -1, np.int32)
+            dst = np.full((R,), NR, np.int32)
+            src[:n], dst[:n] = rec_src, rec_dst
+            c_row = np.zeros((TB,), np.int32)
+            c_idx = np.full((TB,), TB, np.int32)
+            scan_idx = np.full((NC * Q,), TB, np.int32)
+            scan_pos = np.zeros((TB,), np.int32)
+            chunk_row = np.zeros((NC,), np.int32)
+            chunk_first = np.zeros((NC,), np.int32)
+            row_end = np.zeros((R,), np.int32)
+            snap = np.zeros((2, R), np.int32)
+            snap_dst = np.full((R,), NR, np.int32)
+            ck = 0
+            for i, cur in enumerate(starts):
+                s = segs[i]
+                c_row[cur:cur + s] = i
+                c_idx[cur:cur + s] = np.arange(s)
+                nck = -(-s // Q)
+                scan_idx[ck * Q:ck * Q + s] = cur + np.arange(s)
+                scan_pos[cur:cur + s] = ck * Q + np.arange(s)
+                chunk_row[ck:ck + nck] = i
+                chunk_first[ck] = 1
+                row_end[i] = ck + nck - 1
+                at, rid = snaps[i]
+                if rid:
+                    snap[:, i] = ck + at // Q - 1, cur + at - 1
+                    snap_dst[i] = rid
+                ck += nck
+            chunk_row[ck:] = chunk_row[max(ck - 1, 0)]
+            past = conv_past(c_row, c_idx, m.conv_kernel)
+        tick_note(ssm_scan_tokens=int(sum(segs)), ssm_scan_chunks=ck,
+                  ssm_decode_rows=n)
+        with tick_op("h2d"):
+            return tuple(jnp.asarray(a) for a in (
+                src, dst, past, last_idx, scan_idx, scan_pos, chunk_row,
+                chunk_first, row_end, snap[0], snap[1], snap_dst,
+                np.asarray([ck], np.int32))), jnp.asarray(dst)
 
     def _note_attention(self, n: int, segs, r_pool_lens, final_lens, walked,
                         shared, page: int) -> None:

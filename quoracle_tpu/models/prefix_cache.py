@@ -56,6 +56,21 @@ first. A session holding a node's window page holds its full-group page
 too, so leaf eviction's rule (no reference but the tree's on the full-group
 page) covers both.
 
+A model with ssm layers (the store's ``records``) holds megabytes of
+recurrent state a session, so a node does NOT carry the state at its end as
+a matter of course: a node MAY hold a SNAPSHOT (``record``, an id of the
+store's record pool, owned by the tree), taken by the engine at a boundary
+it chose — the end of a cached prefix that a new session matched and found
+no snapshot at (generate.py ``_run_paged``). A match is cut back to the
+deepest node that holds one (SessionStore.match_prefix) and the rest is
+prefilled again. I2 covers a snapshot too: it is written once, by the tick
+that took it, and only ever read after — an adopting row's chunk forward
+reads it and writes the row's OWN record (the copy that adoption is), and a
+tick holds a second reference while it reads, so ``strip_records`` (the
+record pool's pressure valve: least recently matched first, the node keeps
+its page) never frees a record under a reader. A node that goes
+(``evict``, ``clear``) gives its snapshot back.
+
 Locking: all mutating/inspecting methods assume the owning SessionStore's
 RLock is held (the store re-enters it freely); the store's public wrappers
 (`match_prefix`, `insert_prefix`, `alloc`) take it.
@@ -72,13 +87,14 @@ class _Node:
     """One cached page: edge label ``block`` (page-length token tuple,
     relative to the parent path), pool page id, LRU stamp."""
 
-    __slots__ = ("block", "page", "wpage", "children", "parent",
+    __slots__ = ("block", "page", "wpage", "record", "children", "parent",
                  "last_used")
 
     def __init__(self, block: tuple, page: int, parent: "Optional[_Node]"):
         self.block = block
         self.page = page
         self.wpage = 0      # its page in the store's window group, if any
+        self.record = 0     # a snapshot of the ssm state at its end, if any
         self.children: dict[tuple, _Node] = {}
         self.parent = parent
         self.last_used = time.monotonic()
@@ -219,6 +235,46 @@ class RadixPrefixCache:
         del node.parent.children[node.block]
         self._pages.pop(node.page, None)
         self._drop_window(node)
+        self._drop_record(node)
+
+    # -- snapshots of recurrent state (a store with ``records``) ------------
+
+    def _drop_record(self, node: _Node) -> int:
+        """Give up a node's snapshot; returns 1 where that freed it."""
+        if not node.record:
+            return 0
+        rec, node.record = node.record, 0
+        return self.store.records.release([rec])
+
+    def records_of(self, pages: Sequence[int]) -> list[int]:
+        """The snapshots (0: none) of the nodes that hold ``pages``."""
+        return [self._pages[p].record for p in pages]
+
+    def attach_record(self, tokens: Sequence[int], rec: int) -> bool:
+        """Hand the tree a snapshot of the state after ``tokens`` (a whole
+        number of pages): the node at that depth takes it, unless it has
+        one or is gone — then the caller keeps (and releases) it."""
+        path = self._walk(tokens, len(tokens))
+        if len(path) * self.page != len(tokens) or path[-1].record:
+            return False
+        path[-1].record = rec
+        return True
+
+    def _idle_snapshots(self) -> list:
+        refs = self.store.records._refs
+        return [n for n in self._pages.values()
+                if n.record and refs.get(n.record, 1) == 1]
+
+    def idle_records(self) -> int:
+        """Snapshots no tick is reading: what ``strip_records`` can free."""
+        return len(self._idle_snapshots())
+
+    def strip_records(self, n: int) -> int:
+        """Free up to ``n`` snapshots that only the tree references, least
+        recently matched nodes first; the nodes stay, with their pages."""
+        idle = sorted(self._idle_snapshots(),
+                      key=lambda node: node.last_used)[:n]
+        return sum(self._drop_record(node) for node in idle)
 
     def _drop_window(self, node: _Node) -> int:
         """Give up the tree's reference on a node's window-group page;
@@ -292,6 +348,7 @@ class RadixPrefixCache:
             stack.extend(node.children.values())
             self.store._release([node.page])
             self._drop_window(node)
+            self._drop_record(node)
             dropped += 1
         self._root.children.clear()
         self._pages.clear()
@@ -345,6 +402,9 @@ class RadixPrefixCache:
             # those given back under its pressure
             "cached_window_pages": len(self._wpages),
             "stripped_window_pages": self.stripped_window_pages,
+            # a store with a record pool: snapshots the tree holds
+            "cached_records": sum(1 for n in self._pages.values()
+                                  if n.record),
         }
 
     def occupancy(self) -> dict:

@@ -260,33 +260,74 @@ def _init_params_stacks(cfg: ModelConfig, k_embed, k_layers, k_head,
 # Leaves of one layer of a PATTERN model (per-head attention or short conv,
 # dense feed-forward or experts), in the order that numbers their keys
 # (``_init_params_pattern``): (name, shape, fan-in).
-def _pattern_leaves(cfg: ModelConfig, mixer: str, ff: str) -> list:
+def _pattern_leaves(cfg: ModelConfig, mixer, ff) -> list:
     D, KV, HD = cfg.dim, cfg.n_kv_heads, cfg.head_dim
-    if mixer == "conv":
+    if mixer is None:
+        leaves = []
+    elif mixer == "conv":
         K = cfg.conv_cache
         leaves = [("w_in", (D, 3 * D), D), ("w_conv", (K, D), K),
                   ("w_out", (D, D), D)]
+    elif mixer == "ssm":
+        m = cfg.ssm
+        K, H = m.conv_kernel, m.n_heads
+        # a_log, dt_bias and d_skip are float32 and drawn by rules of
+        # their own (``_ssm_leaf``); the "fan-in" names the rule
+        # ``W_in`` as its three column blocks, [z | xBC | dt]: 10,304
+        # columns in one are no multiple of 128 lanes
+        leaves = [("w_z", (D, m.d_inner), D), ("w_xbc", (D, m.conv_dim), D),
+                  ("w_dt", (D, H), D), ("w_conv", (K, m.conv_dim), K),
+                  ("b_conv", (m.conv_dim,), K), ("a_log", (H,), "a_log"),
+                  ("dt_bias", (H,), "dt_bias"), ("d_skip", (H,), "ones"),
+                  ("w_out", (m.d_inner, D), m.d_inner)]
     else:
         H = cfg.attn_kind(mixer).n_heads
         leaves = [("wq", (D, H * HD), D), ("wk", (D, KV * HD), D),
                   ("wv", (D, KV * HD), D), ("wo", (H * HD, D), H * HD)]
         if cfg.attn_gate:
             leaves += [("wg", (D, H), D)]
+    if ff is None:
+        return leaves
     if ff == "dense":
         F = cfg.ffn_dim
         return leaves + [("w_gate", (D, F), D), ("w_up", (D, F), D),
                          ("w_down", (F, D), F)]
     m = cfg.moe
-    Fe, Fs = m.expert_dim, m.expert_dim * m.n_shared
+    Fe, Fs = m.expert_dim, m.shared_width
     if m.router_bias:
         leaves += [("router_bias", (m.n_routed,), 10_000)]
-    leaves += [("router", (D, m.n_routed), D),
-               ("we_gate", (D, Fe), D), ("we_up", (D, Fe), D),
+    leaves += [("router", (D, m.n_routed), D)]
+    if not m.gated:
+        # an ungated body has no gate matrix, and its up matrix lies
+        # TRANSPOSED, ``[F, D]`` as the down matrix does (the width is
+        # then rows, ops/grouped_experts.py)
+        leaves += [("we_up", (Fe, D), D), ("we_down", (Fe, D), Fe)]
+        if m.n_shared:
+            leaves += [("ws_up", (Fs, D), D), ("ws_down", (Fs, D), Fs)]
+        return leaves
+    leaves += [("we_gate", (D, Fe), D), ("we_up", (D, Fe), D),
                ("we_down", (Fe, D), Fe)]
     if m.n_shared:
         leaves += [("ws_gate", (D, Fs), D), ("ws_up", (D, Fs), D),
                    ("ws_down", (Fs, D), Fs)]
     return leaves
+
+
+@functools.partial(jax.jit, static_argnames=("shape", "rule", "lo", "hi",
+                                             "floor"))
+def _ssm_leaf(key, shape, rule, lo=0.0, hi=0.0, floor=0.0):
+    """A Mamba-2 head's float32 scalars (``_init_params_pattern`` states
+    the rules): ``a_log`` = log of a uniform draw in [1, 16]; ``dt_bias``
+    = the inverse softplus of a log-uniform draw in [``lo``, ``hi``]
+    floored at ``floor``; ``ones``."""
+    if rule == "ones":
+        return jnp.ones(shape, jnp.float32)
+    u = jax.random.uniform(key, shape, jnp.float32)
+    if rule == "a_log":
+        return jnp.log(1.0 + 15.0 * u)
+    dt = jnp.maximum(jnp.exp(u * (math.log(hi) - math.log(lo))
+                             + math.log(lo)), floor)
+    return dt + jnp.log(-jnp.expm1(-dt))
 
 
 def _init_params_pattern(cfg: ModelConfig, k_embed, k_layers, k_head,
@@ -302,7 +343,23 @@ def _init_params_pattern(cfg: ModelConfig, k_embed, k_layers, k_head,
     k_layers, s), q), i)``; a routed-expert leaf ``[repeats, n_held, ...]``
     draws expert ``e`` (its global number) at ``[repeats, ...]`` from
     ``fold_in(that key, e)``; ``router_bias`` is float32, 0.01 × normal;
-    norms are one; embed and lm_head as for every model."""
+    norms are one (a layer has one for each part it has: ``attn_norm``
+    before a mixer, ``mlp_norm`` before a feed-forward part); embed and
+    lm_head as for every model. An UNGATED expert body (``MoEConfig.gated``
+    false) has two leaves, ``we_up`` and ``we_down`` (``ws_up``,
+    ``ws_down``), both drawn at ``[F, D]``: the up matrix lies transposed.
+    A Mamba-2 mixer's leaves (``"ssm"``): ``W_in`` as its three column
+    blocks ``w_z``, ``w_xbc``, ``w_dt`` (fan-in D), ``w_conv`` (fan-in:
+    the taps), ``b_conv`` (normal/sqrt(taps)
+    too) and ``w_out`` by the rule above; its float32 scalars a head from
+    the same numbered keys, drawn uniform ``u`` in [0, 1) at ``[repeats,
+    heads]``: ``a_log = log(1 + 15 u)`` (so ``A = −exp(a_log)`` lies in
+    [−16, −1]), ``dt_bias = softplus⁻¹(max(exp(u · log(dt_max / dt_min) +
+    log dt_min), dt_floor))`` with softplus⁻¹(x) = x + log(−expm1(−x))
+    (``SSMConfig.dt_min/dt_max/dt_floor``: 0.001, 0.1, 1e-4 as
+    published), ``d_skip`` ones; the gated norm's weight ``ssm_norm``
+    ones — a step's decay ``exp(Δ A)`` is then 0.2–0.999 at Δ = the drawn
+    value: the state is neither dead nor frozen."""
     D = cfg.dim
     params = {
         "embed": _normal_leaf(k_embed, (cfg.vocab_size, D), D, dtype, None),
@@ -316,15 +373,24 @@ def _init_params_pattern(cfg: ModelConfig, k_embed, k_layers, k_head,
         positions = []
         for q, (mixer, ff) in enumerate(kinds if n else ()):
             kq = jax.random.fold_in(jax.random.fold_in(k_layers, s), q)
-            leaves = {"attn_norm": jnp.ones((n, D), dtype),
-                      "mlp_norm": jnp.ones((n, D), dtype)}
-            if mixer != "conv" and cfg.qk_norm:
+            leaves = {}
+            if mixer is not None:
+                leaves["attn_norm"] = jnp.ones((n, D), dtype)
+            if ff is not None:
+                leaves["mlp_norm"] = jnp.ones((n, D), dtype)
+            if cfg.is_attention(mixer) and cfg.qk_norm:
                 leaves["q_norm"] = jnp.ones((n, cfg.head_dim), dtype)
                 leaves["k_norm"] = jnp.ones((n, cfg.head_dim), dtype)
+            if mixer == "ssm":
+                leaves["ssm_norm"] = jnp.ones((n, cfg.ssm.d_inner), dtype)
             for i, (leaf, shape, fan_in) in enumerate(
                     _pattern_leaves(cfg, mixer, ff)):
                 k = jax.random.fold_in(kq, i)
-                if leaf == "router_bias":
+                if isinstance(fan_in, str):
+                    m = cfg.ssm
+                    leaves[leaf] = _ssm_leaf(k, (n, *shape), fan_in,
+                                             m.dt_min, m.dt_max, m.dt_floor)
+                elif leaf == "router_bias":
                     leaves[leaf] = _normal_leaf(k, (n, *shape), fan_in,
                                                 jnp.float32, None)
                 elif leaf.startswith("we_"):
@@ -442,8 +508,15 @@ def rope(x: jax.Array, positions: jax.Array, theta: float,
     return out.astype(x.dtype)
 
 
+def _relu2(x: jax.Array) -> jax.Array:
+    return jnp.square(jax.nn.relu(x))
+
+
+# "relu2", the square of relu, is the activation of Nemotron-H's UNGATED
+# feed-forward bodies (``MoEConfig.gated`` false: ``relu2(x W_u) W_d``)
 _ACTIVATIONS = {"silu": jax.nn.silu,
-                "gelu": functools.partial(jax.nn.gelu, approximate=True)}
+                "gelu": functools.partial(jax.nn.gelu, approximate=True),
+                "relu2": _relu2}
 
 
 def _activation(x: jax.Array, kind: str) -> jax.Array:
@@ -516,6 +589,8 @@ def _qkv(x: jax.Array, p: dict, cfg: ModelConfig, B: int, T: int,
                             cfg.rmsnorm_plus_one)
                 k = rmsnorm(k, p["k_norm"], cfg.norm_eps,
                             cfg.rmsnorm_plus_one)
+    if not cfg.rope:        # no positional embedding at all (Nemotron-H)
+        return q, k, v
     with jax.named_scope("rope"):
         q = rope(q, positions, kind.rope_theta, kind.rope_scaling,
                  kind.rotary_dim)
@@ -723,6 +798,21 @@ def _gated(h: jax.Array, wg: jax.Array, wu: jax.Array, wd: jax.Array,
     return (_activation(h @ wg, act) * (h @ wu)) @ wd
 
 
+def _ffn_body(h: jax.Array, w: tuple, act: str) -> jax.Array:
+    """An expert's body from its matrices: three, the gated form; two
+    (``MoEConfig.gated`` false), ``act(h W_uᵀ) W_d``, both ``[F, D]``."""
+    if len(w) == 3:
+        return _gated(h, *w, act)
+    return _activation(jnp.einsum("td,fd->tf", h, w[0]), act) @ w[1]
+
+
+def _expert_leaves(m) -> tuple:
+    """The names of a routed expert's stacked matrices, in the order an
+    expert's body takes them."""
+    return ("we_gate", "we_up", "we_down") if m.gated \
+        else ("we_up", "we_down")
+
+
 # Rows of one block of the grouped matmul: a block holds assignments to ONE
 # expert (an expert's assignments are padded up to whole blocks).
 MOE_BLOCK = 256
@@ -765,7 +855,6 @@ def _routed_experts(h: jax.Array, idx: jax.Array, gates: jax.Array,
     n_blk = (counts + blk - 1) // blk
     blk_end = jnp.cumsum(n_blk)
     flat_gates = gates.reshape(A)
-    wg, wu, wd = w
 
     def body(b, out):
         e = jnp.sum(blk_end <= b).astype(jnp.int32)          # b's expert
@@ -774,7 +863,7 @@ def _routed_experts(h: jax.Array, idx: jax.Array, gates: jax.Array,
         ok = pos < starts[e] + counts[e]
         a = order[jnp.minimum(pos, A - 1)]
         tok = a // k
-        y = _gated(h[tok], wg[layer, e], wu[layer, e], wd[layer, e], act)
+        y = _ffn_body(h[tok], tuple(m_[layer, e] for m_ in w), act)
         y = y.astype(jnp.float32) * jnp.where(ok, flat_gates[a], 0.0)[:, None]
         # a block's tokens ascend (stable sort), the dropped rows last
         return out.at[jnp.where(ok, tok, T)].add(y, mode="drop",
@@ -830,8 +919,9 @@ def _routed_experts_grouped(h: jax.Array, idx: jax.Array, gates: jax.Array,
     tok = jnp.zeros((NB * blk,), jnp.int32).at[row].set(
         jnp.arange(A, dtype=jnp.int32) // k, mode="drop",
         unique_indices=True)                 # rows no one has: token 0
-    y = grouped_ffn(h[tok].reshape(NB, blk, D), *w, layer, e_b, blk_end[-1],
-                    act=_ACTIVATIONS[act], interpret=interpret)
+    y = grouped_ffn(h[tok].reshape(NB, blk, D), *(None,) * (3 - len(w)), *w,
+                    layer, e_b, blk_end[-1], act=_ACTIVATIONS[act],
+                    interpret=interpret)
     mine = y.reshape(NB * blk, D)[jnp.minimum(row, NB * blk - 1)]
     out = jnp.where(held[..., None], gates[..., None]
                     * mine.reshape(T, k, D).astype(jnp.float32), 0.0).sum(1)
@@ -924,8 +1014,8 @@ def _moe(x: jax.Array, p: dict, experts: tuple, layer, cfg: ModelConfig,
                                             m, cfg.activation, valid)
     if m.n_shared:
         with jax.named_scope("shared_expert"):
-            shared = _gated(h, p["ws_gate"], p["ws_up"], p["ws_down"],
-                            cfg.activation)
+            shared = _ffn_body(h, tuple(p[k] for k in (
+                "ws_gate", "ws_up", "ws_down") if k in p), cfg.activation)
         routed = routed + shared.astype(jnp.float32)
     return x + routed.astype(x.dtype)[None], stats
 
@@ -1048,6 +1138,8 @@ def forward_hidden_ragged(
     shared: Optional[jax.Array] = None,    # [2 + SHARED_ROWS, R] int32
     conv: Optional["ConvTick"] = None,     # a model with conv layers: its
                                            # state pool and the tick's use
+    ssm: Optional["SsmTick"] = None,       # a model with ssm layers: its
+                                           # record pools and the tick's use
 ) -> tuple:
     """UNIFIED ragged forward (ISSUE 8): one launch per layer over a
     token-major flattened batch of rows with arbitrary query lengths —
@@ -1064,7 +1156,8 @@ def forward_hidden_ragged(
     expert layers under this same contract, as
     ``_forward_hidden_ragged_pattern`` serves a model whose layers differ
     in kind; with ``conv``, what a model with conv layers hands in, the
-    tuple has a seventh member, the state pool updated).
+    tuple has a seventh member, the state pool updated; with ``ssm``, the
+    pair of record pools).
 
     The pools never move (PR 25): they ride the layer scan as a CARRY,
     whole and in their stored lane-flat layout, each layer writes its Tp
@@ -1099,7 +1192,8 @@ def forward_hidden_ragged(
             "expert/hybrid models: no tp shards, no int8 pages"
         return _forward_hidden_ragged_pattern(
             params, cfg, tokens, positions, k_pool, v_pool, row_tables,
-            block_meta, flat_dst, tq, interpret, tiles, tile, shared, conv)
+            block_meta, flat_dst, tq, interpret, tiles, tile, shared, conv,
+            ssm)
     from quoracle_tpu.ops.paged_attention import ragged_attend_auto
     B, Tp = tokens.shape       # B == 1: the flat layout is the batch
     L, n_pages, page, lanes = k_pool.shape
@@ -1385,14 +1479,213 @@ def _short_conv(x: jax.Array, p: dict, cfg: ModelConfig, prev: jax.Array,
     return x, last
 
 
+class SsmTick(NamedTuple):
+    """What a tick needs of the ssm layers' state (generate.py
+    ``_ensure_pool``: a pool of RECORDS beside the pages, one record a
+    session and one a snapshot the prefix cache keeps) — the two pools,
+    updated in place as the K/V pools are, and where this tick's rows read
+    and write them. A row reads ONE record (``src``) and writes one
+    (``dst``); they are the same record where a session goes on from its
+    own end, and differ where it starts from a snapshot (adoption IS this
+    copy). Every layer reads and writes its own part of the records.
+
+    ``src [R]``   the record each row's state starts from; negative: zeros
+                  (a sequence's start)
+    ``dst [R]``   the record each row's state at its end is written to;
+                  ``n_records`` or more drops the write (unused slots)
+
+    A DECODE STEP (one token a row, flat token ``r`` row ``r``'s; ``past``
+    is None) works on the loop's own buffers: ``decode_ragged`` reads its
+    rows' records ONCE before the loop into buffers that lie as the
+    step's one kernel takes them (``ops/ssm_scan.ssm_decode``: the
+    convolution, the recurrence and the norm) — ``ssm [n_ssm_layers, R,
+    N, H·P]``, the state TRANSPOSED, and ``conv [n_ssm_layers, R, ·]``
+    float32 —, a step updates row ``r`` of layer ``c`` there where
+    ``dst[r]`` is 1 (0: a row that is done, whose state stands), and one
+    write behind the loop puts them back: a record read and a record
+    written a row a layer a step either way, as two device operations a
+    layer and not four a row. A chunk forward works on the pools and also
+    says how its tokens lie:
+
+    ``past [Tp, K-1]``  as ``ConvTick.past``, for the convolution's taps
+    ``last [R]``        each row's last flat token (its conv inputs end
+                        the row's new record)
+    ``scan_idx [NC·Q]`` the flat token in each slot of the SCAN layout
+                        (ops/ssm_scan.py: a row's tokens from a chunk's
+                        first slot, ``Q`` to a chunk); ``Tp``: padding
+    ``scan_pos [Tp]``   each flat token's slot there
+    ``chunk_row``, ``chunk_first [NC]``  each chunk's row, and 1 where it
+                        starts its row
+    ``row_end [R]``     the chunk that holds each row's last token
+    ``snap_chunk``, ``snap_tok``, ``snap_dst [R]``  a SNAPSHOT a row at
+                        most: the chunk after which and the flat token
+                        behind which the state is also written to record
+                        ``snap_dst`` (``n_records`` or more: none) — the
+                        chunk's end is the token's place, since a row that
+                        takes one starts on a page boundary
+    ``n_chunks [1]``    the chunks that hold a token (the layout's first):
+                        the scan kernel skips the rest"""
+
+    ssm: jax.Array     # [n_ssm_layers · n_records, H·P, N] float32
+    conv: jax.Array    # [n_ssm_layers · n_records, (K-1) · conv_dim]
+    src: jax.Array
+    dst: jax.Array
+    past: Optional[jax.Array] = None
+    last: Optional[jax.Array] = None
+    scan_idx: Optional[jax.Array] = None
+    scan_pos: Optional[jax.Array] = None
+    chunk_row: Optional[jax.Array] = None
+    chunk_first: Optional[jax.Array] = None
+    row_end: Optional[jax.Array] = None
+    snap_chunk: Optional[jax.Array] = None
+    snap_tok: Optional[jax.Array] = None
+    snap_dst: Optional[jax.Array] = None
+    n_chunks: Optional[jax.Array] = None   # [1]: the chunks that hold a token
+
+
+def take_rows(pool: jax.Array, ids: jax.Array) -> jax.Array:
+    """``pool[ids]`` for a few rows of megabytes each, as one dynamic
+    slice a row: the compiler's gather of such rows first copies the pool
+    whole in two halves (590 MB a Mamba layer a decode step at the
+    benchmark's widths, read off the compiled program), a dynamic slice
+    reads the row."""
+    return jnp.concatenate([jax.lax.dynamic_slice_in_dim(pool, ids[r], 1, 0)
+                            for r in range(ids.shape[0])])
+
+
+def put_rows(pool: jax.Array, ids: jax.Array, rows: jax.Array) -> jax.Array:
+    """``pool.at[ids].set(rows)``, a dynamic update a row, in place."""
+    for r in range(ids.shape[0]):
+        pool = jax.lax.dynamic_update_slice_in_dim(
+            pool, rows[r:r + 1].astype(pool.dtype), ids[r], 0)
+    return pool
+
+
+@jax.named_scope("ssm")
+def _ssm(x: jax.Array, p: dict, cfg: ModelConfig, tick: SsmTick, c,
+         interpret) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """The Mamba-2 mixer of a flat tick ``x [1, Tp, D]``, the ``c``-th ssm
+    layer of the model (config.SSMConfig has the sizes): ``[z | xBC | dt]
+    = norm(x) W_in`` (scope ``ssm_in``); ``xBC ← silu(conv(xBC) + b)``, a
+    causal depthwise convolution along each ROW's tokens whose taps before
+    a chunk's first token read from the row's record (``ssm_conv``); the
+    heads' recurrence ``S ← exp(Δ A) S + Δ x ⊗ B``, ``y = S C + D x`` with
+    ``Δ = softplus(dt + dt_bias)`` and ``A = −exp(a_log)`` — a chunk
+    forward through the scan kernel in the scan layout (``ssm_scan``; a
+    decode step's convolution, recurrence and norm are one kernel under
+    that scope, ``ops/ssm_scan.ssm_decode``); ``y ⊙ silu(z)`` through an
+    RMSNorm over each group's values (``ssm_norm``); residual + ``y
+    W_out`` (``ssm_out``); and the rows' new records written under
+    ``tick.dst`` and a snapshot's under ``tick.snap_dst``, in place
+    (``state_write``; a write that is dropped goes to the layer's record
+    0, the pool's scratch). The conv inputs are rounded to the activation type
+    before the taps, so a value read back from a record is the value a
+    longer chunk would have had in hand. Returns (x, the two pools)."""
+    from quoracle_tpu.ops.ssm_scan import ssm_decode_auto, ssm_scan_auto
+    m = cfg.ssm
+    H, P, G, N, K = m.n_heads, m.head_dim, m.n_groups, m.state_dim, \
+        m.conv_kernel
+    Tp, CD = x.shape[1], m.conv_dim
+    R = tick.dst.shape[0]
+    f32 = jnp.float32
+    with jax.named_scope("ssm_in"):
+        u = rmsnorm(x, p["attn_norm"], cfg.norm_eps, cfg.rmsnorm_plus_one)
+        z, xbc, dt = (jnp.einsum("btd,df->btf", u, p[k])[0]
+                      for k in ("w_z", "w_xbc", "w_dt"))
+        dt = jax.nn.softplus(dt.astype(f32) + p["dt_bias"])   # [Tp, H]
+    if tick.past is None:
+        # a decode step, on the decode loop's buffers: the state
+        # TRANSPOSED, [n_ssm, R, N, H·P], the conv inputs float32
+        with jax.named_scope("ssm_scan"):
+            xb = xbc.astype(f32)        # rounded to the activation type
+            a_d = jnp.repeat(jnp.stack([-jnp.exp(p["a_log"]), p["d_skip"]]),
+                             P, axis=1)                       # [2, d_inner]
+            y, ssm_pool = ssm_decode_auto(
+                z.astype(f32), xb, tick.conv, p["w_conv"],
+                p["b_conv"][None], jnp.repeat(dt, P, axis=1), a_d,
+                p["ssm_norm"][None], tick.ssm, c, tick.dst,
+                G=G, N=N, K=K, eps=cfg.norm_eps, interpret=interpret)
+        with jax.named_scope("state_write"):
+            prev = jax.lax.dynamic_index_in_dim(tick.conv, c, 0, False)
+            conv_pool = jax.lax.dynamic_update_index_in_dim(
+                tick.conv, jnp.where((tick.dst > 0)[:, None], jnp.concatenate(
+                    [prev[:, CD:], xb], axis=-1), prev), c, 0)
+        with jax.named_scope("ssm_out"):
+            x = x + jnp.einsum("td,dD->tD", y.astype(x.dtype),
+                               p["w_out"])[None]
+        return x, ssm_pool, conv_pool
+    NR = tick.ssm.shape[0] // cfg.n_ssm_layers
+    rec = c * NR + jnp.maximum(tick.src, 0)
+    with jax.named_scope("ssm_conv"):
+        prev = jnp.where((tick.src >= 0)[:, None],
+                         take_rows(tick.conv, rec), 0)
+        # back[j-1][t] = xBC of the token j places before t in t's row
+        past = jnp.concatenate([xbc, prev.reshape(-1, CD).astype(
+            xbc.dtype)])[tick.past]
+        back = [past[:, j - 1] for j in range(1, K)]
+        w = p["w_conv"].astype(f32)                           # [K, CD]
+        conv = jax.nn.silu(
+            w[K - 1] * xbc.astype(f32) + sum(
+                w[K - 1 - j] * back[j - 1].astype(f32) for j in range(1, K))
+            + p["b_conv"].astype(f32)).astype(x.dtype)
+        new_conv = jnp.concatenate(
+            [back[j - 1] for j in range(K - 2, 0, -1)] + [xbc], axis=-1)
+    A = -jnp.exp(p["a_log"])
+    with jax.named_scope("ssm_scan"):
+        s0 = jnp.where((tick.src >= 0)[:, None, None],
+                       take_rows(tick.ssm, rec), 0.0).reshape(R, H, P, N)
+        Q = m.chunk
+        NC = tick.chunk_row.shape[0]
+        # the scan layout: a zero row behind the flat tokens is the
+        # padding (Δ = 0 leaves the state as it is)
+        lay = jnp.concatenate([conv, jnp.zeros((1, CD), conv.dtype)]
+                              )[tick.scan_idx].reshape(NC, Q, CD)
+        dts = jnp.concatenate([dt, jnp.zeros((1, H), f32)]
+                              )[tick.scan_idx].reshape(NC, Q, H)
+        ys, states = ssm_scan_auto(
+            lay[..., :m.d_inner].reshape(NC, Q, H, P), dts, A,
+            lay[..., m.d_inner:m.d_inner + G * N].reshape(NC, Q, G, N),
+            lay[..., m.d_inner + G * N:].reshape(NC, Q, G, N), s0,
+            tick.chunk_row, tick.chunk_first, tick.n_chunks, interpret)
+        y = ys.reshape(NC * Q, H, P)[tick.scan_pos] \
+            + p["d_skip"][:, None] * conv[:, :m.d_inner].reshape(
+                Tp, H, P).astype(f32)
+        states = states.reshape(NC, H * P, N)
+        ends = take_rows(states, tick.row_end)
+    with jax.named_scope("ssm_norm"):
+        y = (y.reshape(Tp, G, -1) * jax.nn.silu(z.astype(f32)).reshape(
+            Tp, G, -1))
+        y = y * jax.lax.rsqrt(jnp.mean(y * y, axis=-1, keepdims=True)
+                              + cfg.norm_eps)
+        y = (y.reshape(Tp, m.d_inner)
+             * p["ssm_norm"].astype(f32)).astype(x.dtype)
+    with jax.named_scope("ssm_out"):
+        x = x + jnp.einsum("td,dD->tD", y, p["w_out"])[None]
+    with jax.named_scope("state_write"):
+        def at(ids):
+            return c * NR + jnp.where(ids < NR, ids, 0)
+
+        ssm_pool = put_rows(tick.ssm, at(tick.dst), ends)
+        conv_pool = put_rows(tick.conv, at(tick.dst), new_conv[tick.last])
+        if tick.snap_dst is not None:
+            ssm_pool = put_rows(ssm_pool, at(tick.snap_dst),
+                                take_rows(states, tick.snap_chunk))
+            conv_pool = put_rows(conv_pool, at(tick.snap_dst),
+                                 new_conv[tick.snap_tok])
+    return x, ssm_pool, conv_pool
+
+
 def _forward_hidden_ragged_pattern(params, cfg, tokens, positions, k_pool,
                                    v_pool, row_tables, block_meta, flat_dst,
                                    tq, interpret, tiles, tile, shared,
-                                   conv) -> tuple:
+                                   conv, ssm=None) -> tuple:
     """``forward_hidden_ragged`` for a model whose layers differ in KIND
     (``cfg.layer_plan``): per-head attention (of one kind or of several,
-    ``cfg.attn_kinds``) or a gated short convolution, a dense feed-forward
-    or routed experts. The same contract and the same in-place pools, with
+    ``cfg.attn_kinds``), a gated short convolution, a Mamba-2 mixer
+    (``_ssm``, whose records ``ssm`` hands in: every ssm layer reads and
+    writes its part of the two record pools in place, which ride the
+    scans as the K/V pools do) or no mixer; a dense feed-forward, routed
+    experts or none. The same contract and the same in-place pools, with
     these differences. The K/V pools hold the ATTENTION layers only,
     ``[n_attn_layers, n_pages, page, KV·hd]``, indexed by a layer's place
     among them. A model whose attention layers fall into several retention
@@ -1415,14 +1708,15 @@ def _forward_hidden_ragged_pattern(params, cfg, tokens, positions, k_pool,
     of the plan is one ``lax.scan`` over its repeats with the segment's
     layers unrolled in the body: the leading dense layers once, the period
     as often as it fits, the rest of a last period once — so program size
-    follows the period, not the depth. The routed experts' weights stay
+    follows the period, not the depth (but for the decode step of a model
+    with ssm layers, whose scans hold every repeat). The routed experts' weights stay
     out of the scanned slices. A layer's scopes are the dense forward's;
     where the model names kinds of attention, a layer's are inside one
     named for its kind (``full_attention`` ⊃ ``qkv`` …). Returns the dense
     function's tuple with the expert layers' counts (``moe_counts``' six
     where the grouped kernel ran them, else ``_routed_experts``' four; None
     without experts) and, seventh, the state pool (None without conv
-    layers)."""
+    layers; the pair of record pools with ssm layers)."""
     from quoracle_tpu.ops.paged_attention import ragged_attend_auto
     grouped = experts_grouped(cfg, interpret)
     n_groups = len(cfg.kv_groups)
@@ -1439,6 +1733,7 @@ def _forward_hidden_ragged_pattern(params, cfg, tokens, positions, k_pool,
     keep = keeps[0]
     n_conv = cfg.n_conv_layers
     assert (conv is not None) == (n_conv > 0)
+    assert (ssm is not None) == (cfg.n_ssm_layers > 0)
     prev = recs = None
     if conv is not None:
         of_layer = jnp.arange(n_conv, dtype=jnp.int32)[:, None] \
@@ -1474,20 +1769,20 @@ def _forward_hidden_ragged_pattern(params, cfg, tokens, positions, k_pool,
 
     def segment(carry, stacked, kinds, n, a0, c0):
         """``n`` repeats of ``kinds``, whose first attention layer of
-        group g is the ``a0[g]``-th of that group's and first conv layer
-        the ``c0``-th of the model's."""
-        group = [None if m == "conv" else cfg.kv_group_of(m)
+        group g is the ``a0[g]``-th of that group's and first conv (or
+        ssm) layer the ``c0``-th of the model's."""
+        group = [cfg.kv_group_of(m) if cfg.is_attention(m) else None
                  for m, _ in kinds]
         a_per = [group.count(g) for g in range(n_groups)]
-        c_per = group.count(None)
-        experts = [tuple(p[k] for k in ("we_gate", "we_up", "we_down"))
+        c_per = sum(m in ("conv", "ssm") for m, _ in kinds)
+        experts = [tuple(p[k] for k in _expert_leaves(cfg.moe))
                    if ff == "experts" else None
                    for p, (_, ff) in zip(stacked, kinds)]
         rest = tuple({k: v for k, v in p.items() if not k.startswith("we_")}
                      for p in stacked)
 
         def body(carry, scanned):
-            x, kps, vps, recs, stats = carry
+            x, kps, vps, recs, stats, pools = carry
             ps, rep = scanned
             a = [a0[g] + rep * a_per[g] for g in range(n_groups)]
             c = c0 + rep * c_per
@@ -1500,21 +1795,35 @@ def _forward_hidden_ragged_pattern(params, cfg, tokens, positions, k_pool,
                             x, kps[g], vps[g], p, a[g],
                             cfg.attn_kind(mixer), g)
                     a[g] = a[g] + 1
-                else:
+                elif mixer == "conv":
                     x, last = _short_conv(x, p, cfg, prev[c], conv)
                     recs = jax.lax.dynamic_update_index_in_dim(
                         recs, last.astype(recs.dtype), c, 0)
                     c = c + 1
+                elif mixer == "ssm":
+                    x, *pools = _ssm(x, p, cfg, ssm._replace(
+                        ssm=pools[0], conv=pools[1]), c, interpret)
+                    c = c + 1
                 if ff == "dense":
                     x = _mlp(x, p, cfg)
-                else:
+                elif ff == "experts":
                     x, st = _moe(x, p, w, rep, cfg, keep, grouped,
                                  bool(interpret))
                     stats = stats + st
-            return (x, tuple(kps), tuple(vps), recs, stats), None
+            return (x, tuple(kps), tuple(vps), recs, stats,
+                    pools if pools is None else tuple(pools)), None
 
+        # a decode step of a model with ssm layers holds every repeat in
+        # the scan's body: inside the decode loop the scan is a ``while``
+        # in a ``while``, and its slice of every stacked leaf is a device
+        # operation of its own every step (478 a step for 365 where seven
+        # one-operator layers repeat twice; PERF.md §6, PR 47). A chunk
+        # forward, one pass a tick, keeps the loop and a program of the
+        # period's size; so do the models without such layers
+        unroll = n if ssm is not None and ssm.past is None else 1
         carry, _ = jax.lax.scan(body, carry,
-                                (rest, jnp.arange(n, dtype=jnp.int32)))
+                                (rest, jnp.arange(n, dtype=jnp.int32)),
+                                unroll=unroll)
         return carry, [a0[g] + n * a_per[g] for g in range(n_groups)], \
             c0 + n * c_per
 
@@ -1522,16 +1831,16 @@ def _forward_hidden_ragged_pattern(params, cfg, tokens, positions, k_pool,
     if cfg.moe is not None:
         stats = jnp.zeros((3, cfg.moe.n_held) if grouped else (MOE_STATS,),
                           jnp.int32)
-    carry = (x, tuple(k_pool), tuple(v_pool), recs, stats)
+    carry = (x, tuple(k_pool), tuple(v_pool), recs, stats,
+             None if ssm is None else (ssm.ssm, ssm.conv))
     a0, c0 = [0] * n_groups, 0
     with jax.named_scope("layers"):
         for stacked, (kinds, n) in zip(params["segments"], cfg.layer_plan):
             if n:
                 carry, a0, c0 = segment(carry, stacked, kinds, n, a0, c0)
-    x, k_pool, v_pool, recs, stats = carry
+    x, k_pool, v_pool, recs, stats, state = carry
     if not multi:
         k_pool, v_pool = k_pool[0], v_pool[0]
-    state = None
     if conv is not None:
         with jax.named_scope("conv"), jax.named_scope("state_write"):
             dst = jnp.where(conv.rec_dst < n_pages, of_layer + conv.rec_dst,
@@ -1539,8 +1848,7 @@ def _forward_hidden_ragged_pattern(params, cfg, tokens, positions, k_pool,
             state = conv.pool.at[dst.reshape(-1)].set(
                 recs.reshape(-1, recs.shape[-1]), mode="drop")
     if grouped and stats is not None:
-        stats = moe_counts(stats, keep, cfg.moe,
-                           cfg.n_layers - cfg.n_dense_layers)
+        stats = moe_counts(stats, keep, cfg.moe, cfg.n_expert_layers)
     return (_final_norm(x, params, cfg), k_pool, v_pool, None, None, stats,
             state)
 

@@ -55,8 +55,25 @@ def width_slice(expert_dim: int, dim: int = 0, itemsize: int = 2) -> int:
     return s
 
 
-def _ffn_kernel(expert_ref, meta_ref, x_ref, wg_ref, wu_ref, wd_ref,
-                out_ref, acc_ref, *, act: Callable, n_slices: int):
+def rows_slice(expert_dim: int, dim: int, itemsize: int = 2) -> int:
+    """Rows of an UNGATED expert's two ``[F, D]`` matrices one grid
+    program takes: the width whole where both fit ``WHOLE_BYTES`` twice
+    over, else the largest divisor of it that is a multiple of 16 (a
+    bfloat16 tile's rows) and does (928 of 1,856 at a model 2,688 wide)."""
+    for n in range(1, expert_dim + 1):
+        tf = expert_dim // n
+        if expert_dim % n == 0 and (n == 1 or tf % 16 == 0) \
+                and 2 * 2 * dim * tf * itemsize <= WHOLE_BYTES:
+            return tf
+    return expert_dim
+
+
+def _ffn_kernel(expert_ref, meta_ref, x_ref, *refs, act: Callable,
+                n_slices: int):
+    # refs: the gate matrix's slice where the body is gated, then up,
+    # down, the result and the float32 sum
+    wg_ref = refs[0] if len(refs) == 5 else None
+    wu_ref, wd_ref, out_ref, acc_ref = refs[-4:]
     b, f = pl.program_id(0), pl.program_id(1)
 
     @pl.when(b < meta_ref[0])               # a block that exists
@@ -66,9 +83,15 @@ def _ffn_kernel(expert_ref, meta_ref, x_ref, wg_ref, wu_ref, wd_ref,
             acc_ref[...] = jnp.zeros_like(acc_ref)
 
         x = x_ref[...]                                        # [blk, D]
-        g = jnp.dot(x, wg_ref[...], preferred_element_type=jnp.float32)
-        u = jnp.dot(x, wu_ref[...], preferred_element_type=jnp.float32)
-        a = (act(g) * u).astype(x.dtype)                      # [blk, tf]
+        if wg_ref is not None:
+            g = jnp.dot(x, wg_ref[...], preferred_element_type=jnp.float32)
+            u = jnp.dot(x, wu_ref[...], preferred_element_type=jnp.float32)
+            a = (act(g) * u).astype(x.dtype)                  # [blk, tf]
+        else:
+            # the up matrix's slice lies [tf, D]: x W_uᵀ
+            a = act(jax.lax.dot_general(
+                x, wu_ref[...], (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)).astype(x.dtype)
         acc_ref[...] += jnp.dot(a, wd_ref[...],
                                 preferred_element_type=jnp.float32)
 
@@ -80,24 +103,36 @@ def _ffn_kernel(expert_ref, meta_ref, x_ref, wg_ref, wu_ref, wd_ref,
 @functools.partial(jax.jit, static_argnames=("act", "interpret"))
 def grouped_ffn(
     x: jax.Array,          # [NB, blk, D]: each block's tokens, gathered
-    wg: jax.Array,         # [L, E, D, F]  the experts' stacked weights,
+    wg,                    # [L, E, D, F]  the experts' stacked weights,
     wu: jax.Array,         # [L, E, D, F]  whole: they stay in HBM and a
-    wd: jax.Array,         # [L, E, F, D]  program reads its slice
+    wd: jax.Array,         # [L, E, F, D]  program reads its slice; ``wg``
+                           # None: the experts are not gated, and ``wu``
+                           # lies TRANSPOSED, [L, E, F, D]
     layer,                 # int32 scalar: which of the L layers
     block_expert: jax.Array,   # [NB] int32: each block's expert
     n_blocks,              # int32 scalar: blocks that exist (the first)
     act: Callable,
     interpret: bool = False,
 ) -> jax.Array:
-    """``act(x_b W_g[e_b]) ⊙ (x_b W_u[e_b])) W_d[e_b]`` for every block
+    """``act(x_b W_g[e_b]) ⊙ (x_b W_u[e_b])) W_d[e_b]`` — or, with ``wg``
+    None (a static choice of the body: two matmuls, no gate),
+    ``act(x_b W_u[e_b]ᵀ) W_d[e_b]``, which is Nemotron-H's ``relu2``
+    expert, both matrices ``[F, D]`` (a width of 1,856 is no multiple of
+    128 lanes: as the minor dimension of ``[D, F]`` it made the compiler
+    copy every stack into a padded layout, 1.2 GiB each; as rows it
+    slices by 16) — for every block
     ``b < n_blocks``, [NB, blk, D] in ``x``'s type; the blocks behind
     them are left unwritten (no program of theirs moves or multiplies
     anything: their index maps stay on the last block that exists, so the
     pipeline has nothing to fetch). float32 between the matmuls and in the
     sum over the width's slices, rounded once at the end."""
     NB, blk, D = x.shape
-    F = wg.shape[-1]
-    tf = width_slice(F, D, wg.dtype.itemsize)
+    if wg is None:
+        F = wu.shape[-2]
+        tf = rows_slice(F, D, wu.dtype.itemsize)
+    else:
+        F = wu.shape[-1]
+        tf = width_slice(F, D, wu.dtype.itemsize)
     n_slices = F // tf
     meta = jnp.stack([jnp.asarray(n_blocks, jnp.int32),
                       jnp.asarray(layer, jnp.int32)])
@@ -124,8 +159,9 @@ def grouped_ffn(
             grid=(NB, n_slices),
             in_specs=[
                 pl.BlockSpec((None, blk, D), x_map),
-                pl.BlockSpec((None, None, D, tf), up_map),
-                pl.BlockSpec((None, None, D, tf), up_map),
+                *([pl.BlockSpec((None, None, tf, D), down_map)]
+                  if wg is None else
+                  [pl.BlockSpec((None, None, D, tf), up_map)] * 2),
                 pl.BlockSpec((None, None, tf, D), down_map),
             ],
             out_specs=pl.BlockSpec((None, blk, D), x_map),
@@ -139,4 +175,5 @@ def grouped_ffn(
             vmem_limit_bytes=48 << 20),
         interpret=interpret,
         name="routed_experts_ffn",
-    )(block_expert.astype(jnp.int32), meta, x, wg, wu, wd)
+    )(block_expert.astype(jnp.int32), meta, x,
+      *(() if wg is None else (wg,)), wu, wd)

@@ -896,16 +896,25 @@ RAGGED_TILE = 128            # query tokens a grid program serves at most
 _TILE_VMEM = 12 << 20        # the tile's scratch, of 16 MiB scoped VMEM
 
 
-def ragged_tile(n_heads: int, head_dim: int, tq: int) -> int:
+_TILE_GROUP_ROWS = 1024      # query rows a kv head's scores take at most
+
+
+def ragged_tile(n_heads: int, head_dim: int, tq: int,
+                q_per_kv: int = 1) -> int:
     """Tokens of a query tile for this head geometry: ``RAGGED_TILE``,
     halved until the tile's scratch fits ``_TILE_VMEM`` — per token its
     queries as they arrive (2 bytes), scaled to float32, the accumulator
     and the output (4 each) at H·hd, and two softmax columns that pad to
-    128 lanes."""
+    128 lanes — and until a kv head's score block, ``tile · q_per_kv``
+    query rows against a block of keys in float32 on the kernel's stack,
+    has at most ``_TILE_GROUP_ROWS`` rows (16 query heads a kv head at a
+    tile of 128 took 18.45 MB of the 16 MiB; the widest group before, 8
+    at 128, is the bound)."""
     hd_p = -(-head_dim // 128) * 128
     per_token = n_heads * (hd_p * 14 + 2 * 128 * 4)
     tile = RAGGED_TILE
-    while tile > tq and tile * per_token > _TILE_VMEM:
+    while tile > tq and (tile * per_token > _TILE_VMEM
+                         or tile * q_per_kv > _TILE_GROUP_ROWS):
         tile //= 2
     return max(tile, tq)
 

@@ -1702,6 +1702,184 @@ def test_softmax_moe_programs_at_mellum_widths(on_v5e, monkeypatch):
     assert weight_moves(programs[1][0], 1 << 20) == []
 
 
+# --- Mamba-2 layers beside attention and ungated experts (ISSUE 47) -----------
+
+def test_ssm_scan_compiles_at_nemotron_widths(on_v5e):
+    """The scan kernel at the published widths (64 heads of 64 x 128 in 8
+    groups, chunks of 128) over the 40 chunks a 4,096-token tick of 8 rows
+    lays out: a grid program is one chunk of one group's 8 heads, the
+    group's state [512, 128] float32 in VMEM scratch."""
+    from quoracle_tpu.ops import ssm_scan as sc
+    S, bf, f = on_v5e, jnp.bfloat16, jnp.float32
+    NC, Q, H, P, G, N, R = 40, 128, 64, 64, 8, 128, 8
+    compiled = jax.jit(sc.ssm_scan).lower(
+        S((NC, Q, H, P), bf), S((NC, Q, H), f), S((H,), f),
+        S((NC, Q, G, N), bf), S((NC, Q, G, N), bf), S((R, H, P, N), f),
+        S((NC,), jnp.int32), S((NC,), jnp.int32)).compile()
+    assert "%ssm_scan" in compiled.as_text()
+
+
+@pytest.mark.parametrize("blk,nb", [(16, 72), (256, 80)],
+                         ids=["decode-16", "prefill-256"])
+def test_ungated_grouped_experts_compile_at_nemotron_widths(on_v5e, blk, nb):
+    """``grouped_ffn`` with no gate matrix at a model 2,688 wide and
+    experts 1,856 wide: 1,856 is no multiple of 128 lanes, so both
+    matrices lie ``[F, D]`` and the width is cut by ROWS, 928 a grid
+    program (two matrices of 5 MB twice over)."""
+    from quoracle_tpu.ops import grouped_experts as ge
+    assert ge.rows_slice(1856, 2688) == 928 and ge.rows_slice(32, 64) == 32
+    S, bf = on_v5e, jnp.bfloat16
+    D, F, E, L = 2688, 1856, 64, 2
+    compiled = ge.grouped_ffn.lower(
+        S((nb, blk, D), bf), None, S((L, E, F, D), bf), S((L, E, F, D), bf),
+        S((), jnp.int32), S((nb,), jnp.int32), S((), jnp.int32),
+        act=tr_relu2()).compile()
+    assert "routed_experts_ffn" in compiled.as_text()
+
+
+def tr_relu2():
+    from quoracle_tpu.models.transformer import _ACTIVATIONS
+    return _ACTIVATIONS["relu2"]
+
+
+def _mamba_programs(S, monkeypatch, cfg, tb, width, rows=8):
+    """Both serving programs of a model with ssm layers, compiled for the
+    v5e with donation as served."""
+    from quoracle_tpu.models.generate import RAGGED_TQ, GenerateEngine
+    from quoracle_tpu.models.tokenizer import ByteTokenizer
+    from quoracle_tpu.models.transformer import init_params
+    monkeypatch.setattr(pa, "_on_tpu", lambda: True)
+    params = jax.tree.map(
+        lambda x: S(x.shape, x.dtype),
+        jax.eval_shape(lambda k: init_params(cfg, k), jax.random.PRNGKey(0)))
+    eng = GenerateEngine(cfg, params, ByteTokenizer(),
+                         max_seq=min(cfg.context_window, 131072))
+    st = eng.sessions
+    m, R, i32, f32 = cfg.ssm, rows, jnp.int32, jnp.float32
+    kv = S((cfg.n_attn_layers, st.n_pages, st.page, cfg.kv_pools[0]),
+           eng.pool_dtype)
+    n_rec = cfg.n_ssm_layers * st.records.n_ids
+    state = (S((n_rec, m.d_inner, m.state_dim), f32),
+             S((n_rec, (m.conv_kernel - 1) * m.conv_dim), eng.pool_dtype))
+    slots = pa.ragged_tile_slots(tb // RAGGED_TQ, R, RAGGED_TQ,
+                                 eng._ragged_tile)
+    nc = tb // m.chunk + R
+    tick = (S((R,), i32), S((R,), i32), S((tb, m.conv_kernel - 1), i32),
+            S((R,), i32), S((nc * m.chunk,), i32), S((tb,), i32),
+            S((nc,), i32), S((nc,), i32)) + (S((R,), i32),) * 4 \
+        + (S((1,), i32),)
+    chunk = eng._step_paged_ragged.lower(
+        params, kv, kv, None, None, S((tb,), i32), S((tb,), i32),
+        S((R, width), i32), S((4, tb // RAGGED_TQ), i32),
+        S((6, slots), i32), S((tb,), i32), S((R,), i32), state, tick,
+        tq=RAGGED_TQ, tile=eng._ragged_tile).compile()
+    decode = eng._step_paged_decode_ragged.lower(
+        params, kv, kv, None, None, S((R, width), i32),
+        S((2 + pa.SHARED_ROWS, R), i32), S((R,), i32), S((R,), i32),
+        S((R, cfg.vocab_size), f32), S((2,), jnp.uint32), S((R,), f32),
+        S((R,), f32), S((R,), jnp.bool_), S((R,), i32), None, None, state,
+        S((R,), i32), max_new=32).compile()
+    return [(c.as_text(), c.memory_analysis()) for c in (chunk, decode)], eng
+
+
+def records_stay(hlo: str, mem, pool_elems: int, temps: bool = True) -> bool:
+    """The ssm record pool (``pool_elems`` float32 values) is carried in
+    place: donated into the program's result, no gather of it that copies
+    it whole (`mini-gather-slice`: what the compiler made of ``pool[ids]``
+    for a few megabyte-sized rows), and (``temps``: where the program's
+    activations are smaller than the pool) no temporaries as large as it
+    — a row's record is read by a dynamic slice and written by a dynamic
+    update, which ``pool_moves`` cannot tell from a move by name."""
+    return ("mini-gather" not in hlo
+            and (not temps or mem.temp_size_in_bytes < 4 * pool_elems)
+            and mem.alias_size_in_bytes >= 4 * pool_elems)
+
+
+def _narrow_mamba(periods):
+    """Nemotron-H's layer pattern (`MEMEM*E`), head geometry (2 kv heads of
+    128 under 16 query heads each; 64 Mamba heads of 64 in 8 groups, 128
+    state values) and record size under a narrow residual stream."""
+    from quoracle_tpu.models.config import ModelConfig, MoEConfig, SSMConfig
+    pat = "MEMEM*E" * periods
+    return ModelConfig(
+        name=f"narrow-mamba-{periods}", vocab_size=512, dim=256,
+        n_layers=len(pat), n_heads=32, n_kv_heads=2, head_dim=128,
+        ffn_dim=128, activation="relu2", rope=False, state_records=24,
+        layer_types=tuple({"M": "ssm", "*": "attention", "E": None}[c]
+                          for c in pat),
+        ff_types=tuple("experts" if c == "E" else None for c in pat),
+        ssm=SSMConfig(n_heads=64, head_dim=64, n_groups=8, state_dim=128),
+        moe=MoEConfig(n_routed=16, n_held=8, per_token=6, expert_dim=128,
+                      n_shared=1, shared_dim=256, gated=False,
+                      routed_scale=2.5, router_bias=True, first_dense=0))
+
+
+def test_mamba_programs_carry_pools_and_records_in_place(on_v5e,
+                                                         monkeypatch):
+    """A model with ssm layers on the v5e: both programs carry the K/V
+    pools AND the two record pools through the segment scans — and the
+    decode loop — in place; a row's record is read by a dynamic slice and
+    written by a dynamic update (the compiler's gather of such rows first
+    copied the pool whole: `mini-gather-slice`); the kernels are the
+    attention's, the experts' and the scan's — in the decode step, whose
+    scan holds every repeat of the period, the decode kernel's; and the
+    scan over whole periods holds at two periods and at three."""
+    counts = []
+    for periods in (2, 3):
+        cfg = _narrow_mamba(periods)
+        programs, eng = _mamba_programs(on_v5e, monkeypatch, cfg, 256, 8)
+        st = eng.sessions
+        assert eng._ragged_tile == 64     # 16 query heads a kv head
+        rec_elems = cfg.n_ssm_layers * st.records.n_ids * 4096 * 128
+        for i, (hlo, mem) in enumerate(programs):
+            calls = [ln for ln in hlo.splitlines()
+                     if "tpu_custom_call" in ln]
+            # the chunk forward scans the period; the decode step holds
+            # every repeat of it (no loop inside the decode loop)
+            n = 1 if i == 0 else periods
+            assert sum("%ragged_attend" in c for c in calls) == n
+            assert sum("%routed_experts_ffn" in c for c in calls) == 3 * n
+            # the chunk forward's scan, the decode step's fused kernel
+            assert sum("%ssm_scan" in c for c in calls) == (3 if i == 0
+                                                            else 0)
+            assert sum("%ssm_decode" in c for c in calls) == (0 if i == 0
+                                                              else 3 * n)
+            assert pool_moves(hlo, st.n_pages * st.page * 256) == []
+            assert records_stay(hlo, mem, rec_elems)
+        counts.append([len(re.findall(r" fusion\(", hlo))
+                       for hlo, _ in programs])
+    # the chunk forward's size follows the period, the decode program's
+    # the depth
+    assert counts[0][0] == counts[1][0]
+    assert 1.3 < counts[1][1] / counts[0][1] < 1.6
+
+
+@pytest.mark.slow
+def test_mamba_programs_at_nemotron_widths(on_v5e, monkeypatch):
+    """The benchmark's `nemotron-3-nano-30b-a3b-ep2-l14` at its published
+    widths (`-m slow`: a minute; run by hand before chip time): the chunk
+    forward at the 4,096-token tick and the decode program compile, pools
+    and records in place, no stack of expert weights copied into a padded
+    layout (1.2 GiB each before the up matrices lay [F, D]), and
+    arguments plus temporaries under 13 GiB of the chip's 16."""
+    from benchmark import configs
+    from benchmark.families import mamba_moe
+    cfg = get_model_config(mamba_moe.register(
+        configs.load_config("nemotron-3-nano-30b-a3b-ep2-l14")))
+    programs, eng = _mamba_programs(on_v5e, monkeypatch, cfg, 4096, 32)
+    st = eng.sessions
+    assert (st.n_pages, st.records.n_ids) == (8193, 48)
+    for hlo, mem in programs:
+        assert pool_moves(hlo, st.n_pages * st.page * 256) == []
+        # (the 4,096-token tick's activations outweigh the pool)
+        assert records_stay(hlo, mem, 6 * 48 * 4096 * 128,
+                            temps="decode" in hlo[:60])
+        print("nemotron AOT: arguments", mem.argument_size_in_bytes,
+              "temporaries", mem.temp_size_in_bytes)
+        assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+                < 13 * 2 ** 30)
+
+
 # --- tp wrappers: shard_map around a pallas_call ----------------------------
 
 
