@@ -696,6 +696,12 @@ SCHED_PADDED_TOKENS_TOTAL = METRICS.counter(
     "device chunk-token slots processed across generate ticks (real + "
     "padding), per model — [B·T] on the bucketed paths, the flat token "
     "budget on the unified ragged path")
+SCHED_LIVE_TOKEN_SLOTS_TOTAL = METRICS.counter(
+    "quoracle_sched_live_token_slots_total",
+    "of the padded slots, those whose per-token work (norms, projections, "
+    "MLP) ran, per model: the dense chunk forward of a tick of 2,048 "
+    "slots and more skips the blocks behind its last token; every other "
+    "tick computes its whole shape")
 SCHED_NUCLEUS_ROWS_TOTAL = METRICS.counter(
     "quoracle_sched_nucleus_rows_total",
     "rows of a batcher tick that ask for a nucleus (temperature > 0 and "

@@ -32,7 +32,7 @@ from quoracle_tpu.models.config import (
 from quoracle_tpu.models.sampling import sample_tokens
 from quoracle_tpu.models.transformer import (
     ConvTick, KVCache, SsmTick, forward_hidden, forward_hidden_ragged,
-    init_cache, put_rows, take_rows,
+    init_cache, live_token_slots, put_rows, take_rows,
     moe_stats_len, project_logits,
 )
 
@@ -476,6 +476,10 @@ def _round_up(n: int, buckets: Sequence[int]) -> int:
 RAGGED_TQ = 8
 RAGGED_TOKEN_BUCKETS = (16, 32, 64, 128, 256, 512, 1024, 2048, 4096,
                         8192, 16384, 32768)
+# What a bucket's padding costs a plain dense model from 2,048 slots up is
+# the padding inside its last block of transformer.LIVE_BLOCK slots: the
+# chunk forward's per-token work runs over the blocks that hold a token
+# (``live_token_slots``; the tick's ``token_slots_live``).
 # Row slots (page tables, sampling state, the decode loop's batch) round
 # to these, independent of the token budget: the ContinuousBatcher's
 # default 8 slots are one f32 sublane tile and one program.
@@ -1369,6 +1373,7 @@ class GenerateEngine:
         # ticks reclaim the difference; /api/resources serves the totals.
         self.pad_real_tokens = 0
         self.pad_padded_tokens = 0
+        self.pad_live_slots = 0
         self.pad_ticks = 0
         # Per-call hand-off from _run_unified to _record_telemetry /
         # _note_padding. THREAD-LOCAL: sessionless calls (image rows) run
@@ -2590,12 +2595,13 @@ class GenerateEngine:
         # unified path overrides the [B, T] rectangle with its flat token
         # budget (_run_unified sets the thread-local).
         padded_toks = getattr(self._pending, "padded_tokens", None)
-        self._pending.padded_tokens = None
+        live_slots = getattr(self._pending, "live_slots", None)
+        self._pending.padded_tokens = self._pending.live_slots = None
         from quoracle_tpu.infra import costobs, introspect
         with tick_op("account"):
             self._note_padding(
                 sum(max(1, len(s)) for s in suffixes),
-                B * T if padded_toks is None else padded_toks)
+                B * T if padded_toks is None else padded_toks, live_slots)
             # Chip-economics charge (ISSUE 17): split each phase's measured
             # wall across the live rows by real tokens; padding waste lands
             # on the overhead pseudo-tenant. Read-only — consumes the row
@@ -2727,23 +2733,31 @@ class GenerateEngine:
                 shape=f"B{B}xT{T}xC{cache_len}xN{max_new}"
                       + ("p" if paged else ""))
 
-    def _note_padding(self, real: int, padded: int) -> None:
+    def _note_padding(self, real: int, padded: int,
+                      live: Optional[int] = None) -> None:
         """Account one tick's chunk-token padding waste (ISSUE 8
         satellite): ``real`` tokens the caller actually submitted vs
-        ``padded`` device slots the chosen path processed ([B·T] for the
-        bucketed paths, the flat token budget for the unified kernel).
-        Counters feed Prometheus; the cumulative totals ride
-        /api/resources via padding_stats()."""
+        ``padded`` device slots of the chosen path's shape ([B·T] for the
+        bucketed paths, the flat token budget for the unified kernel) and
+        ``live``, those of them whose per-token work ran (None: all; the
+        dense chunk forward skips a bucket's dead blocks,
+        transformer.live_token_slots). Counters feed Prometheus; the
+        cumulative totals ride /api/resources via padding_stats()."""
         from quoracle_tpu.infra.telemetry import (
-            SCHED_PADDED_TOKENS_TOTAL, SCHED_REAL_TOKENS_TOTAL,
+            SCHED_LIVE_TOKEN_SLOTS_TOTAL, SCHED_PADDED_TOKENS_TOTAL,
+            SCHED_REAL_TOKENS_TOTAL,
         )
         name = self.cfg.name
+        live = int(padded if live is None else live)
         self.pad_real_tokens += int(real)
         self.pad_padded_tokens += int(padded)
+        self.pad_live_slots += live
         self.pad_ticks += 1
         SCHED_REAL_TOKENS_TOTAL.inc(int(real), model=name)
         SCHED_PADDED_TOKENS_TOTAL.inc(int(padded), model=name)
-        tick_note(real_tokens=int(real), padded_tokens=int(padded))
+        SCHED_LIVE_TOKEN_SLOTS_TOTAL.inc(live, model=name)
+        tick_note(real_tokens=int(real), padded_tokens=int(padded),
+                  token_slots_live=live)
 
     def _note_moe(self, stats) -> None:
         """Book one tick's expert-layer counts (the int32 vector the two
@@ -2837,6 +2851,9 @@ class GenerateEngine:
             "padded_tokens": padded,
             "waste_ratio": (round(1 - self.pad_real_tokens / padded, 4)
                             if padded else None),
+            # the share of those slots whose per-token work ran
+            "live_slot_share": (round(self.pad_live_slots / padded, 4)
+                                if padded else None),
         }
 
     def kv_token_pool_bytes(self) -> int:
@@ -3645,6 +3662,10 @@ class GenerateEngine:
                 starts.append(cur)
                 cur += nb * TQ
             self._pending.padded_tokens = TB
+            # ... of which the chunk forward's per-token work runs over
+            # these (``cur``: the first slot behind the last row)
+            self._pending.live_slots = TB if not self.cfg.plain else \
+                live_token_slots(TB, cur, self._ragged_shard is not None)
         conv = records = None
         if ssm_rows is not None:
             conv, records = self._ssm_tick(n, R, TB, segs, starts,

@@ -1115,6 +1115,24 @@ def forward_hidden(
             KVCache(k=new_k, v=new_v, lens=cache.lens))
 
 
+# Slots of a block of the dense chunk forward's per-token work: a tick of
+# two such blocks or more runs norms, projections, rope and MLP over the
+# blocks that hold a token, not over its bucket
+# (generate.RAGGED_TOKEN_BUCKETS; PERF.md §6, PR 49 has both candidates'
+# readings).
+LIVE_BLOCK = 1024
+
+
+def live_token_slots(Tp: int, filled: int, sharded: bool = False) -> int:
+    """The slots of a bucket of ``Tp`` whose per-token work the dense chunk
+    forward runs when the tick's rows fill the first ``filled``: the blocks
+    of ``LIVE_BLOCK`` that hold one, in a tick of two blocks or more that
+    is not built under a mesh; else the bucket."""
+    if Tp < 2 * LIVE_BLOCK or sharded:
+        return Tp
+    return min(Tp, -(-filled // LIVE_BLOCK) * LIVE_BLOCK)
+
+
 def forward_hidden_ragged(
     params: dict,
     cfg: ModelConfig,
@@ -1208,10 +1226,82 @@ def forward_hidden_ragged(
         pid = jnp.where(keep, flat_dst // page, n_pages)  # OOB page = drop
         off = flat_dst % page
 
+    # A tick of two blocks of LIVE_BLOCK slots or more runs a layer's
+    # per-token work over its LIVE blocks alone (the real segments lie from
+    # slot 0, the padding is one run behind them: generate._run_unified),
+    # a loop with a traced trip count; a shorter tick, and a program built
+    # under a mesh, keeps the whole-Tp form (``live_token_slots`` is the
+    # rule, shared with the host's counter: a tick has blocks where one
+    # filled slot does not make its whole bucket live).
+    blocked = live_token_slots(Tp, 1, shard is not None) < Tp
+    layers = params["layers"]
+    if blocked:
+        assert Tp % LIVE_BLOCK == 0, (Tp, LIVE_BLOCK)
+        last = jnp.max(jnp.where(block_meta[2] > 0, jnp.arange(
+            1, block_meta.shape[1] + 1, dtype=jnp.int32), 0))
+        n_live = (last * tq + LIVE_BLOCK - 1) // LIVE_BLOCK
+        zeros = tuple(jnp.zeros((B, Tp, n, cfg.head_dim), x.dtype)
+                      for n in (cfg.attn_kind().n_heads, cfg.n_kv_heads,
+                                cfg.n_kv_heads))
+        # The MLP's matrices are sliced from their stacks INSIDE a block's
+        # turn, where each matmul reads its slice as it lies: as the scan's
+        # slices they would be copied through HBM once a layer for the
+        # loop to read (PR 36). The barrier ties the layer's index to the
+        # block's, or the compiler hoists the slices out of the loop all
+        # the same. The attention's projections and the norms stay the
+        # scan's slices: they fit fast memory.
+        mlp_stacks = {k: layers[k] for k in ("w_gate", "w_up", "w_down")}
+        layers = {k: w for k, w in layers.items() if k not in mlp_stacks}
+
+        def mlp_weights(layer, start):
+            at, _ = jax.lax.optimization_barrier((layer, start))
+            return jax.tree.map(lambda w: jax.lax.dynamic_index_in_dim(
+                w, at, keepdims=False), mlp_stacks)
+
+        def live_blocks(body, init):
+            """``body(start, carry)`` for the first slot of each live
+            block, in order."""
+            return jax.lax.fori_loop(
+                0, n_live, lambda i, c: body(i * LIVE_BLOCK, c), init)
+
+        def block_of(a, start):
+            return jax.lax.dynamic_slice_in_dim(a, start, LIVE_BLOCK, axis=1)
+
+        def put_block(a, blk, start):
+            return jax.lax.dynamic_update_slice_in_dim(a, blk, start, axis=1)
+
+    def qkv(x, p):
+        """``_qkv`` of the tick: of its live blocks, where it has several
+        (a dead block's q, k and v are zeros: its writes drop, its tiles
+        write zeros)."""
+        if not blocked:
+            return _qkv(x, p, cfg, B, Tp, positions)
+
+        def body(start, qkv_all):
+            blk = _qkv(block_of(x, start), p, cfg, B, LIVE_BLOCK,
+                       block_of(positions, start))
+            return tuple(put_block(a, b, start)
+                         for a, b in zip(qkv_all, blk))
+        return live_blocks(body, zeros)
+
+    def out_mlp(x, attn, p, layer):
+        """The layer behind its attention, ``_attn_out`` and ``_mlp``, the
+        same way: a dead block's hidden state stays what it was (nothing
+        reads it: a row's logits come from its last real token)."""
+        if not blocked:
+            return _mlp(_attn_out(x, attn, p, cfg), p, cfg)
+
+        def body(start, x):
+            blk = _attn_out(block_of(x, start), block_of(attn, start), p,
+                            cfg)
+            return put_block(
+                x, _mlp(blk, {**p, **mlp_weights(layer, start)}, cfg), start)
+        return live_blocks(body, x)
+
     def layer_body(carry, scanned):
         x, kp, vp, ks, vs = carry     # the pools, whole: [L, n_pages, ...]
         p, layer = scanned
-        q, k, v = _qkv(x, p, cfg, B, Tp, positions)
+        q, k, v = qkv(x, p)
         # KV → pages BEFORE attention (padding/overflow slots drop):
         # intra-chunk visibility is then pure causal masking inside the
         # one kernel — no dense second piece.
@@ -1237,15 +1327,14 @@ def forward_hidden_ragged(
                 sliding_window=cfg.sliding_window, interpret=interpret,
                 shard=shard, k_scale=ks, v_scale=vs, tiles=tiles,
                 tile=tile, shared=shared)[None]             # [1,Tp,H,hd]
-        x = _attn_out(x, attn.astype(x.dtype), p, cfg)
-        x = _mlp(x, p, cfg)
+        x = out_mlp(x, attn.astype(x.dtype), p, layer)
         return (x, kp, vp, ks, vs), None
 
     with jax.named_scope("layers"):
         # unquantized engines carry the scale slots as empty pytrees
         (x, k_pool, v_pool, k_scale, v_scale), _ = jax.lax.scan(
             layer_body, (x, k_pool, v_pool, k_scale, v_scale),
-            (params["layers"], jnp.arange(L, dtype=jnp.int32)))
+            (layers, jnp.arange(L, dtype=jnp.int32)))
     return (_final_norm(x, params, cfg), k_pool, v_pool, k_scale, v_scale,
             None)
 

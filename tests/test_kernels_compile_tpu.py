@@ -786,6 +786,111 @@ ENTRY %main (wo: bf16[4,16384,7168], wq: bf16[4,1536,24576], o: bf16[8,16384]) -
         "constant_dynamic-slice_fusion.13", "dynamic-slice.3", "copy.163"]
 
 
+# --- the chunk forward over its live blocks (ISSUE 49) ----------------------
+
+
+def _prefill_program(S, monkeypatch, cfg, max_seq, tb, width, rows=8,
+                     live_block=None):
+    """``step_paged_ragged`` of an engine at ``cfg`` as it is served (tile
+    table, pools donated), compiled for the v5e at a tick of ``tb`` slots
+    and a table ``width`` pages wide: (optimized HLO, memory analysis).
+    ``live_block``: the forward's block in place of its own (a block no
+    bucket holds twice gives the whole-bucket form)."""
+    from quoracle_tpu.models import transformer as tr
+    from quoracle_tpu.models.generate import RAGGED_TQ, GenerateEngine
+    from quoracle_tpu.models.tokenizer import ByteTokenizer
+    monkeypatch.setattr(pa, "_on_tpu", lambda: True)
+    if live_block is not None:
+        monkeypatch.setattr(tr, "LIVE_BLOCK", live_block)
+    params = jax.tree.map(
+        lambda x: S(x.shape, x.dtype),
+        jax.eval_shape(lambda k: tr.init_params(cfg, k),
+                       jax.random.PRNGKey(0)))
+    eng = GenerateEngine(cfg, params, ByteTokenizer(), max_seq=max_seq)
+    st = eng.sessions
+    pool = S((cfg.n_layers, st.n_pages, st.page,
+              cfg.n_kv_heads * cfg.head_dim), eng.pool_dtype)
+    i32 = jnp.int32
+    slots = pa.ragged_tile_slots(tb // RAGGED_TQ, rows, RAGGED_TQ,
+                                 eng._ragged_tile)
+    compiled = eng._step_paged_ragged.lower(
+        params, pool, pool, None, None, S((tb,), i32), S((tb,), i32),
+        S((rows, width), i32), S((4, tb // RAGGED_TQ), i32),
+        S((6, slots), i32), S((tb,), i32), S((rows,), i32), tq=RAGGED_TQ,
+        tile=eng._ragged_tile).compile()
+    return compiled.as_text(), compiled.memory_analysis()
+
+
+def _whiles(hlo: str) -> int:
+    return len(re.findall(r" while\(", hlo))
+
+
+@pytest.mark.parametrize("cfg,max_seq", [
+    (_narrow("narrow-kv8-window-live", 8, sliding_window=4096), 128),
+    (_narrow("narrow-kv2-bias-live", 2, attn_bias=True,
+             tie_embeddings=True), 512),
+], ids=lambda c: getattr(c, "name", None))
+def test_prefill_program_reads_its_weights_inside_the_live_blocks(
+        on_v5e, monkeypatch, cfg, max_seq):
+    """A tick of two blocks and more runs a layer's per-token work in two
+    loops over its live blocks, inside the layer scan: the compiled
+    program reads the layer's weights there where they lie (no slice or
+    copy of a stacked weight goes through HBM) and holds one kernel a
+    layer body."""
+    from quoracle_tpu.models.transformer import LIVE_BLOCK
+    tb = 4 * LIVE_BLOCK
+    hlo, _ = _prefill_program(on_v5e, monkeypatch, cfg, max_seq, tb, 4)
+    whole, _ = _prefill_program(on_v5e, monkeypatch, cfg, max_seq, tb, 4,
+                                live_block=tb)
+    assert _whiles(hlo) == _whiles(whole) + 2
+    assert hlo.count("tpu_custom_call") == 1
+    assert weight_moves(hlo, 1 << 20) == []
+    # a tick of one block is the whole-bucket form: the same program
+    one, _ = _prefill_program(on_v5e, monkeypatch, cfg, max_seq,
+                              LIVE_BLOCK, 4)
+    assert _whiles(one) == _whiles(whole)
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("tb,width", [(8192, 32), (16384, 64)],
+                         ids=["tb8192-w32", "tb16384-w64"])
+@pytest.mark.parametrize("name,max_seq", [
+    ("mistral-7b-l16", 8192), ("qwen2.5-3b", 32768)])
+def test_dense_prefill_programs_at_benchmark_widths(on_v5e, monkeypatch,
+                                                    name, max_seq, tb,
+                                                    width):
+    """The prefill programs of the two dense configurations the benchmark
+    serves at the `cold-prompts` cells' widest keys (`-m slow`, a minute
+    each, by hand before chip time). The live blocks' loops read the MLP's
+    matrices (117 / 45 MB each a layer: four fifths of a layer's weights)
+    inside their matmuls, where they lie; what is left to move is the
+    re-layout of a layer's q/k/v projections where fast memory has no room
+    to keep them across the blocks' loop, never more than their own size
+    (Mistral: wq's 33.5 MB at 16,384 slots, all three, 50.3 MB, at 8,192;
+    the whole-bucket form, which is the parent's program, re-lays them too
+    and at 8,192 slots sends wq through HBM as well; Qwen: nothing); and
+    the temporaries do not exceed the whole-bucket form's."""
+    from benchmark import configs
+    from benchmark.families import dense
+    cfg = get_model_config(dense.register(configs.load_config(name)))
+    hlo, mem = _prefill_program(on_v5e, monkeypatch, cfg, max_seq, tb,
+                                width)
+    whole_hlo, whole = _prefill_program(on_v5e, monkeypatch, cfg, max_seq,
+                                        tb, width, live_block=tb)
+    moves = weight_moves(hlo, 1 << 20)
+    print(name, tb, "temp bytes:", mem.temp_size_in_bytes, "whole form:",
+          whole.temp_size_in_bytes, "code bytes:",
+          mem.generated_code_size_in_bytes, "moves:", moves,
+          "whole form's:", weight_moves(whole_hlo, 1 << 20))
+    assert weight_moves(hlo, 40 << 20) == []
+    qkv_bytes = 2 * cfg.dim * cfg.head_dim * (cfg.n_heads
+                                              + 2 * cfg.n_kv_heads)
+    assert {op for op, _, _ in moves} <= {"copy"}
+    assert sum(n for _, _, n in moves) <= qkv_bytes
+    assert hlo.count("tpu_custom_call") == 1
+    assert mem.temp_size_in_bytes <= whole.temp_size_in_bytes
+
+
 # --- what the decode program runs on every step, and what only on request ---
 
 
